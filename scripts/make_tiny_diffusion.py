@@ -13,11 +13,14 @@ from __future__ import annotations
 import sys
 from pathlib import Path
 
-import jax
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # a toy checkpoint needs no chip — and must not take one from a daemon
+
+import jax  # noqa: E402
 
 
 def main() -> None:
-  jax.config.update("jax_platforms", "cpu")
   sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
   from xotorch_support_jetson_tpu.models.diffusion import tiny_diffusion_config
   from xotorch_support_jetson_tpu.models.diffusion_loader import (

@@ -19,11 +19,10 @@ import sys
 
 
 def worker(process_id: int, num_processes: int, port: int) -> None:
+  # Two CPU processes by design: a chip belongs to one process at a time.
   os.environ["JAX_PLATFORMS"] = "cpu"
   os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"
   import jax
-
-  jax.config.update("jax_platforms", "cpu")
 
   from types import SimpleNamespace
 

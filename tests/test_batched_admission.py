@@ -126,9 +126,11 @@ def _count_prefills(server):
   wrap("prefill_into_pages_many")
   # Fused sampling epilogue (ISSUE 11): the default admission path now
   # dispatches the prefill+sample programs — same batched-prefill semantics,
-  # counted identically.
-  wrap("prefill_into_slots_sampled")
-  wrap("prefill_into_pages_many_sampled")
+  # counted identically. The pp/sp backends have no fused variants
+  # (fused_sampling_supported() is False there) and keep the two above.
+  if server.ops.fused_sampling_supported():
+    wrap("prefill_into_slots_sampled")
+    wrap("prefill_into_pages_many_sampled")
 
   def poisoned(*a, **k):
     raise AssertionError("scheduler used a single-row prefill entry point")
@@ -401,10 +403,6 @@ def test_chunked_prefill_interleaves_decode(monkeypatch):
 
 
 def test_pp_engine_batched_admission(monkeypatch):
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
-
-  require_partial_manual(_MP(pp=2, tp=4))
   """XOT_TPU_PP=2: the pp-pipelined backend admits a burst in one dispatch
   too (dense slots), outputs exact."""
   monkeypatch.setenv("XOT_TPU_PAGED", "0")

@@ -43,3 +43,17 @@ def test_live_probe_returns_something_sane():
   assert caps.chip
   # Round-trips through the wire dict format.
   assert DeviceCapabilities.from_dict(caps.to_dict()).memory == caps.memory
+
+
+def test_tpu_kind_lookup_is_exact_or_an_error():
+  """The chip's ``device_kind`` ("TPU v5 lite" on a v5e) resolves to its
+  published peaks; a kind the table does not hold raises instead of
+  reporting a zero-FLOP host."""
+  import pytest
+
+  from xotorch_support_jetson_tpu.topology.device_capabilities import _lookup_tpu_flops
+
+  assert _lookup_tpu_flops("TPU v5 lite").fp16 == 197.0
+  assert _lookup_tpu_flops("TPU v5p").fp16 == 459.0
+  with pytest.raises(ValueError, match="unknown TPU device_kind"):
+    _lookup_tpu_flops("TPU v99")

@@ -112,3 +112,25 @@ def test_batched_decode_over_local_mesh_matches():
       outs.append((firsts, np.asarray(toks)))
   assert outs[0][0] == outs[1][0]
   assert np.array_equal(outs[0][1], outs[1][1])
+
+
+@pytest.mark.parametrize(
+  "kwargs,kernels",
+  [({"use_local_mesh": False}, True), ({"use_local_mesh": True}, False), ({"use_local_mesh": True, "pp": 4}, False)],
+  ids=["one-device", "tp-default", "pp4xtp2"],
+)
+def test_gspmd_partitioned_plans_switch_the_pallas_kernels_off(kwargs, kernels):
+  """A Mosaic kernel cannot be partitioned automatically, so an engine whose
+  serving plan leaves an axis of more than one device to GSPMD (the tp
+  default, tp under pp on 8 devices) clears the config's kernel gate; a
+  one-device engine keeps it. (``--pp 4`` over exactly four chips keeps it
+  too: tests/test_tpu_compile.py compiles that case for a described 2x2.)"""
+  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan, auto_partitioned
+
+  cfg = tiny_test_config(n_layers=4)
+  params, shard = full_model_params(jax.random.PRNGKey(5), cfg, "m")
+  engine = JaxShardedInferenceEngine(**kwargs)
+  engine.load_test_model(shard, cfg, params)
+  engine._maybe_shard_over_local_mesh()
+  assert engine.cfg.mosaic_kernels is kernels and engine.cfg.plain_attention is kernels
+  assert not auto_partitioned(MeshPlan(pp=4), "pp") and auto_partitioned(MeshPlan(pp=2, tp=2), "pp") and not auto_partitioned(MeshPlan())

@@ -10,17 +10,8 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 
 def test_two_process_jax_distributed_train_step():
-  from xotorch_support_jetson_tpu.utils.helpers import multihost_cpu_collectives_supported
-
-  if not multihost_cpu_collectives_supported():
-    # jax 0.4.x cannot route CPU collectives through gloo: the two-process
-    # psum dies with "Multiprocess computations aren't implemented on the
-    # CPU backend". Environmental, not a regression — skip with the probe.
-    pytest.skip("this jax build has no CPU cross-process collectives (jax_cpu_collectives_implementation absent)")
   root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
   env = {k: v for k, v in os.environ.items() if k not in ("XLA_FLAGS",)}
   out = subprocess.run(

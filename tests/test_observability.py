@@ -319,6 +319,7 @@ def test_scheduler_gauges_counters_and_histograms(monkeypatch):
   monkeypatch.setenv("XOT_TPU_PAGE_SIZE", "8")  # force page growth mid-decode
   server = _tiny_batched_server(n_slots=2, chunk=2)
   assert server.paged
+  server._ensure_cache()  # resolves decode_path (a bare server still says "dense"), so before/after read ONE label
   before = {
     "admit": gm.counter_value("scheduler_admissions_total"),
     "grow": gm.counter_value("page_grow_events_total"),

@@ -77,9 +77,6 @@ def test_pipeline_forward_matches_single_device():
 
 
 def test_pipeline_with_tp_dp_matches():
-  from tests_support_stubs import require_partial_manual
-
-  require_partial_manual(MeshPlan(dp=2, pp=2, tp=2))
   plan = MeshPlan(dp=2, pp=2, tp=2)
   mesh = build_mesh(plan)
   params, _ = full_model_params(KEY, CFG)
@@ -145,9 +142,7 @@ def test_ring_sp_forward_matches_gemma2():
 
 def test_full_train_step_dp_pp_sp_tp():
   """One composed dp×pp×sp×tp training step: runs, loss finite, params move."""
-  from tests_support_stubs import require_partial_manual
 
-  require_partial_manual(MeshPlan(dp=2, pp=2, sp=1, tp=2), manual=("pp", "sp"))
   plan = MeshPlan(dp=2, pp=2, sp=1, tp=2)
   mesh = build_mesh(plan)
   params, _ = full_model_params(KEY, CFG)

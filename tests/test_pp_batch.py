@@ -82,10 +82,6 @@ def _prefill_paged(params, cfg, shard, prompts, ppb=None):
 @pytest.mark.parametrize("flavor", ["llama", "gemma2", "moe"])
 @pytest.mark.parametrize("plan", [MeshPlan(pp=2), MeshPlan(pp=2, tp=2)], ids=["pp2", "pp2xtp2"])
 def test_pp_batch_decode_matches_single_device(flavor, plan):
-  from tests_support_stubs import require_partial_manual
-
-  if plan.tp > 1:
-    require_partial_manual(plan)
   cfg = _cfg(flavor)
   params, shard = full_model_params(jax.random.PRNGKey(7), cfg, "m")
   ppb = PPBatchedServing(build_mesh(plan), cfg, params, plan.pp)
@@ -259,10 +255,6 @@ def test_supports_batched_allows_dense_prefix_moe_under_pp():
 
 
 def test_batch_scheduler_serves_concurrently_over_pp(monkeypatch):
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
-
-  require_partial_manual(_MP(pp=2, tp=4))
   """End-to-end: a pp=2 engine's batch scheduler (paged, the default) serves
   4 concurrent requests token-identically to solo single-device runs — the
   composition the round-2 engine refused (jax_engine get_batched_server)."""
@@ -298,10 +290,6 @@ def test_batch_scheduler_serves_concurrently_over_pp(monkeypatch):
 
 
 def test_chunked_prefill_over_pp(monkeypatch):
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
-
-  require_partial_manual(_MP(pp=2, tp=4))
   """XOT_TPU_PREFILL_CHUNK composes with pp-batched paged serving: a long
   arrival prefills in chunks (the pp paged program natively resumes from
   prefix_lens) with decode ticks between, and output stays token-identical

@@ -24,11 +24,7 @@ CFG = tiny_test_config(n_layers=4, max_seq_len=128)
 
 def _pp_engine(cfg=CFG, seed=0, pp=2):
   # Engine pp mode on 8 virtual devices builds a pp×tp mesh (leftover chips
-  # go to tp) — probe-gated on old jax (tests_support_stubs).
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
-
-  require_partial_manual(_MP(pp=pp, tp=4))
+  # go to tp).
   params, shard = full_model_params(jax.random.PRNGKey(seed), cfg, "tiny")
   engine = JaxShardedInferenceEngine(use_local_mesh=True, pp=pp)
   engine.load_test_model(shard, cfg, params)
@@ -147,10 +143,6 @@ def test_pp_checkpoint_interops_with_plain_engine(tmp_path):
 
 @pytest.mark.parametrize("mode", ["pp", "sp"])
 def test_mesh_engine_serves_llava(tmp_path, mode, monkeypatch):
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
-
-  require_partial_manual(_MP(pp=2, tp=4) if mode == "pp" else _MP(sp=2, tp=4), manual=(mode,))
   """A vision model loads under XOT_TPU_PP/SP without the old refusal; the
   tower runs outside the mesh and the merged embeddings prefill through the
   mesh token-identically to the single-device path."""
@@ -236,10 +228,6 @@ def test_pp_vision_checkpoint_keeps_tower(tmp_path):
 
 @pytest.mark.parametrize("mode", ["pp", "sp"])
 def test_mesh_engine_scores_logprobs(mode, monkeypatch):
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
-
-  require_partial_manual(_MP(pp=2, tp=4) if mode == "pp" else _MP(sp=2, tp=4), manual=(mode,))
   """score_tokens (OpenAI logprobs) works on pp/sp mesh engines through the
   flat params view — no more None for mesh serving modes — and matches the
   plain engine's numbers."""
@@ -290,10 +278,7 @@ def test_local_mesh_engine_trains(monkeypatch):
 
 def test_sp_train_and_checkpoint(tmp_path):
   """SP-mode engines train and checkpoint too (same mesh branch)."""
-  from tests_support_stubs import require_partial_manual
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan as _MP
 
-  require_partial_manual(_MP(sp=2, tp=4), manual=("sp",))
   import os
 
   os.environ["XOT_TPU_SP"] = "2"

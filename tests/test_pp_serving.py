@@ -61,10 +61,6 @@ def _pp_tokens(cfg, params, shard, prompt, n_steps, plan: MeshPlan):
   ids=["pp2", "pp2xtp2", "pp4", "pp2-bf16"],
 )
 def test_pp_serving_matches_single_device(plan, dtype):
-  from tests_support_stubs import require_partial_manual
-
-  if plan.tp > 1:
-    require_partial_manual(plan)
   cfg = tiny_test_config(n_layers=4, dtype=dtype)
   params, shard = full_model_params(jax.random.PRNGKey(7), cfg, "m")
   prompt = np.array([[5, 9, 2, 71, 33]], dtype=np.int32)
@@ -78,9 +74,6 @@ def test_pp_serving_matches_single_device(plan, dtype):
 
 
 def test_pp_step_decode_and_generate_match():
-  from tests_support_stubs import require_partial_manual
-
-  require_partial_manual(MeshPlan(pp=2, tp=2))
   """The engine's per-step path (infer_tensor semantics: prefill +
   decode_step) and the while_loop fused_generate, both under pp=2."""
   cfg = tiny_test_config(n_layers=4)
@@ -140,9 +133,6 @@ def test_pp_partial_shard_hidden_in_out():
 
 @pytest.mark.asyncio
 async def test_engine_pp_mode_matches_plain_engine():
-  from tests_support_stubs import require_partial_manual
-
-  require_partial_manual(MeshPlan(pp=2, tp=4))
   """End-to-end engine path: XOT_TPU_PP=2 engine vs plain engine, same tokens
   through infer_tensor (prefill + 3 decode steps) and generate_oneshot."""
   cfg = tiny_test_config(n_layers=4)
@@ -178,10 +168,7 @@ def test_pp_serving_dense_prefix_moe_matches(plan):
   """Deepseek-style dense-prefix MoE (+MLA) through PP serving: the prefix
   runs replicated on every stage, the MoE stack pipelines — token-identical
   to the single-device engine."""
-  from tests_support_stubs import require_partial_manual
 
-  if plan.tp > 1:
-    require_partial_manual(plan)
   cfg = tiny_test_config(
     n_layers=5, max_seq_len=64, n_heads=4, n_kv_heads=4,
     n_experts=4, n_active_experts=2, moe_hidden_dim=32, shared_expert_dim=32,

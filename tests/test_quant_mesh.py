@@ -43,19 +43,15 @@ def quantized():
 
 
 @pytest.mark.parametrize(
-  "builder,plan,manual",
+  "builder",
   [
-    (lambda qp: PPServing(build_mesh(MeshPlan(pp=2)), CFG, qp, 2, True, True), MeshPlan(pp=2), "pp"),
-    (lambda qp: PPServing(build_mesh(MeshPlan(pp=2, tp=2)), CFG, qp, 2, True, True), MeshPlan(pp=2, tp=2), "pp"),
-    (lambda qp: SPServing(build_mesh(MeshPlan(sp=2, tp=2)), CFG, qp, 2, True, True), MeshPlan(sp=2, tp=2), "sp"),
+    lambda qp: PPServing(build_mesh(MeshPlan(pp=2)), CFG, qp, 2, True, True),
+    lambda qp: PPServing(build_mesh(MeshPlan(pp=2, tp=2)), CFG, qp, 2, True, True),
+    lambda qp: SPServing(build_mesh(MeshPlan(sp=2, tp=2)), CFG, qp, 2, True, True),
   ],
   ids=["pp2", "pp2xtp2", "sp2xtp2"],
 )
-def test_int8_mesh_serving_matches_single_device(quantized, builder, plan, manual):
-  from tests_support_stubs import require_partial_manual
-
-  if plan.tp > 1:
-    require_partial_manual(plan, manual=(manual,))
+def test_int8_mesh_serving_matches_single_device(quantized, builder):
   qp, shard, first_ref, ref = quantized
   srv = builder(qp)
   S = PROMPT.shape[1]
@@ -70,10 +66,7 @@ def test_int8_mesh_serving_matches_single_device(quantized, builder, plan, manua
 @pytest.mark.parametrize("mode", ["pp", "sp"])
 def test_int8_batched_mesh_serving_matches_single_device(quantized, mode):
   """int8 through the BATCHED mesh paths (dense slot cache, 2 rows)."""
-  from tests_support_stubs import require_partial_manual
 
-  if mode == "sp":
-    require_partial_manual(MeshPlan(sp=2, tp=2), manual=("sp",))
   qp, shard, _, _ = quantized
   if mode == "pp":
     srv = PPBatchedServing(build_mesh(MeshPlan(pp=2)), CFG, qp, 2)

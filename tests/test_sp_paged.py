@@ -69,10 +69,6 @@ def _prefill_all(cfg, params, shard, pool, prefill_many, mp):
   (GEMMA, MeshPlan(sp=2)),
 ], ids=["dense-sp2", "dense-sp4", "dense-sp2tp2", "mla-sp2", "gemma-sp2"])
 def test_sp_paged_prefill_and_decode_match_single_device(cfg, plan):
-  from tests_support_stubs import require_partial_manual
-
-  if plan.tp > 1:
-    require_partial_manual(plan, manual=("sp",))
   params, shard = full_model_params(jax.random.PRNGKey(31), cfg, "tiny")
   spb = SPBatchedServing(SPServing(build_mesh(plan), cfg, params, plan.sp, True, True))
   B, mp, n_pages, n_steps = len(PROMPTS), 8, 40, 5
@@ -153,9 +149,7 @@ def test_sp_engine_default_batched_mode_serves_paged(monkeypatch):
   reports supports_batched() and serves concurrent requests through the
   striped pool token-identically to solo greedy (the round-3 silent
   degradation is gone)."""
-  from tests_support_stubs import require_partial_manual
 
-  require_partial_manual(MeshPlan(sp=2, tp=4), manual=("sp",))
   from tests.test_batched import _single_row_reference
   from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer
   from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine
@@ -212,9 +206,7 @@ def test_chunked_prefill_over_sp(monkeypatch):
   """XOT_TPU_PREFILL_CHUNK composes with the sp striped pool: chunked
   prefill resumes from prefix offsets across rank-striped page slots, decode
   ticks run between chunks, outputs token-identical to solo greedy."""
-  from tests_support_stubs import require_partial_manual
 
-  require_partial_manual(MeshPlan(sp=2, tp=4), manual=("sp",))
   from tests.test_batched import _single_row_reference
   from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer
   from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine

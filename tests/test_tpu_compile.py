@@ -1,0 +1,382 @@
+"""Ask the TPU's compiler before asking the chip.
+
+libtpu is installed here and compiles for a chip that is described, not
+attached (``jax.experimental.topologies``): what Mosaic or XLA:TPU refuses
+for a v5e it refuses here too, at no chip time — a slice off the tiling, a
+vector op v5e lacks (the int4-KV nibble unpack's int8 shift was one), more
+VMEM than a kernel may take, a step program that does not fit 16 GB of HBM.
+Interpret-mode tests cannot see any of these. Every kernel a dispatch table
+can select on a TPU is compiled at Llama-3.2-1B / 8B head shapes, and the
+whole batched decode step at Llama-3.2-1B width with the pool the scheduler
+sizes for ``chip_smoke.py``'s settings. Nothing runs: a compile that passes
+says nothing about results or times.
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the tests
+hand the jitted kernels and steps their shapes (and ``use_kernel``) directly.
+"""
+
+import os
+import pathlib
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+# libtpu lets one process at a time hold a chip and guards that with a lock
+# file. No chip is attached here, and under pytest-xdist several workers
+# describe the topology at once: without this all but one would skip.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+HQ, HKV, PS = 32, 8, 64  # Llama-3.2-1B (hd 64) and Llama-3.1-8B (hd 128) share 32/8 heads
+
+
+@pytest.fixture(autouse=True)
+def _fp32_matmuls():
+  """Overrides conftest's precision pin: the serving process sets no matmul
+  precision, and these tests compile what it compiles ("highest" turns the
+  flash-decode kernel's bf16 dots into fp32-precision ones Mosaic refuses)."""
+  yield
+
+
+@pytest.fixture(scope="module")
+def chip():
+  """A described v5e chip as a sharding for ShapeDtypeStructs, with the
+  persistent compile cache off: an entry written for an absent chip cannot
+  be read back and only warns."""
+  from jax.experimental import topologies
+  from jax.experimental.compilation_cache import compilation_cache
+  from jax.sharding import SingleDeviceSharding
+
+  try:
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+  except Exception as e:  # noqa: BLE001 — no libtpu in this installation: nothing to ask
+    pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+  was = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  yield SingleDeviceSharding(topo.devices[0])
+  jax.config.update("jax_enable_compilation_cache", was)
+  compilation_cache.reset_cache()
+
+
+def _sds(chip, shape, dtype):
+  return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _rows(chip, n: int):
+  """Per-row operand of a batched program: dtype → [n] shape."""
+  return lambda dtype: _sds(chip, (n,), dtype)
+
+
+def _compile(tracked, *args, **kwargs):
+  """Lower + compile a ``tracked_jit`` program for the described chip."""
+  compiled = tracked.xot_jitted.lower(*args, **kwargs).compile()
+  return compiled, compiled.as_text()
+
+
+# Every (quant, tile) the tables can pick: _PAGE_TILE_TABLE answers G=4/8/16
+# at B=16/48/96; G=16 quantized streams 64 block-spec'd operands per step.
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("batch,tile", [(16, 4), (48, 8), (96, 16)])
+@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, tile, hd):
+  from xotorch_support_jetson_tpu.inference.paging import _PAGE_TILE_TABLE
+  from xotorch_support_jetson_tpu.ops.paged import _paged_decode_attention_impl
+
+  assert tile in {row[-1] for row in _PAGE_TILE_TABLE}
+  mp = 16  # 1k context
+  n_pages = batch * mp + 1
+  kd = hd // 2 if quant == "int4" else hd
+  code = jnp.int8 if quant else jnp.bfloat16
+  pool = _sds(chip, (n_pages, HKV, PS, kd), code)
+  scale = _sds(chip, (n_pages, HKV, PS, 1), jnp.float32) if quant else None
+  _, text = _compile(
+    _paged_decode_attention_impl,
+    _sds(chip, (batch, HQ, hd), jnp.bfloat16), pool, pool, _sds(chip, (batch, mp), jnp.int32), _sds(chip, (batch,), jnp.int32), scale, scale,
+    page_size=PS, pages_per_step=tile, kv_quant=quant, interpret=False,
+  )  # fmt: skip
+  assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("quant", ["", "int8"])
+def test_flash_prefill_compiles_for_v5e(chip, quant):
+  """One PREFILL_BUCKET of queries against the default 4096-slot cache."""
+  from xotorch_support_jetson_tpu.ops.pallas_attention import flash_attention_prefill
+
+  sq, skv, hd = 128, 4096, 64
+  kv = _sds(chip, (1, skv, HKV, hd), jnp.int8 if quant else jnp.bfloat16)
+  scale = _sds(chip, (1, skv, HKV, 1), jnp.float32) if quant else None
+  _, text = _compile(flash_attention_prefill, _sds(chip, (1, sq, HQ, hd), jnp.bfloat16), kv, kv, _sds(chip, (1,), jnp.int32), scale, scale, interpret=False)
+  assert "tpu_custom_call" in text
+
+
+def test_flash_decode_32k_compiles_for_v5e(chip):
+  from xotorch_support_jetson_tpu.ops.pallas_attention import flash_decode_attention
+
+  kv = _sds(chip, (1, 32768, HKV, 64), jnp.bfloat16)
+  _, text = _compile(flash_decode_attention, _sds(chip, (1, 1, HQ, 64), jnp.bfloat16), kv, kv, _sds(chip, (1, 1), jnp.int32), interpret=False)
+  assert "tpu_custom_call" in text
+
+
+def test_int4_matmul_compiles_for_v5e(chip):
+  """A decode-sized w4a16 matmul at Llama-3.2-1B's MLP width."""
+  from xotorch_support_jetson_tpu.ops.pallas_int4 import int4_matmul
+
+  _, text = _compile(int4_matmul, _sds(chip, (16, 2048), jnp.bfloat16), _sds(chip, (1024, 8192), jnp.int8), _sds(chip, (8192,), jnp.float32), interpret=False)
+  assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------- whole step programs
+
+
+@pytest.fixture(scope="module")
+def llama_1b(chip):
+  """Llama-3.2-1B at full width and depth, as shapes: the published config
+  ``chip_smoke.py`` serves, under the engine's default 4096-token cap."""
+  from dataclasses import replace
+
+  from chip_smoke import LLAMA_32_1B, MODEL
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import full_model_params
+
+  cfg = replace(config_from_hf(LLAMA_32_1B), max_seq_len=4096)
+  shapes = jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg, MODEL)[0])
+  params = jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), shapes)
+  return cfg, Shard(MODEL, 0, cfg.n_layers - 1, cfg.n_layers), params
+
+
+def _smoke_pool(chip, cfg, n_slots: int, quant: str, scale_pages: int = 1):
+  """The pool ``BatchedServer._ensure_cache`` sizes by default for these
+  settings (inference/paging.py default_pool_pages), as shapes."""
+  from xotorch_support_jetson_tpu.inference.paging import default_pool_pages, pages_to_cover
+  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+  n_pages = scale_pages * default_pool_pages(cfg, cfg.n_layers, n_slots, cfg.max_seq_len, PS, quant) + 1
+  pool = jax.eval_shape(lambda: init_paged_pool(cfg, cfg.n_layers, n_pages, PS, quant=quant))
+  return jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), pool), pages_to_cover(cfg.max_seq_len, PS)
+
+
+def _decode_step_args(chip, llama_1b, n_slots: int, quant: str, scale_pages: int = 1):
+  cfg, shard, params = llama_1b
+  pool, mp = _smoke_pool(chip, cfg, n_slots, quant, scale_pages)
+  rows = _rows(chip, n_slots)
+  return (
+    params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), pool, _sds(chip, (n_slots, mp), jnp.int32), rows(jnp.int32), rows(jnp.bool_),
+    rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True, _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+
+
+def test_paged_batch_step_at_smoke_settings_fits_v5e(chip, llama_1b):
+  """``decode.paged_batch`` as ``chip_smoke.py``'s batched phase dispatches
+  it — 16 slots, int8 KV, the scheduler's own pool, the kernel path — is
+  accepted by XLA:TPU, which refuses a program whose arguments and
+  temporaries exceed the chip's HBM (next test). ``memory_analysis()`` is
+  printed for the record (see PERF.md: its ``temp_size`` over-counts)."""
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+
+  compiled, text = _compile(_fused_paged_batch_decode_impl, *_decode_step_args(chip, llama_1b, 16, "int8"))
+  assert "tpu_custom_call" in text, "the step must hold the Pallas paged kernel, not the gather reference"
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch B=16 int8: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.argument_size_in_bytes < 16 * 1024**3
+
+
+def test_compiler_refuses_a_pool_beyond_the_chip(chip, llama_1b):
+  """What makes the test above a fit check: the same step over three times
+  the pool is refused at compile time, not at run time."""
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+
+  with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+    _compile(_fused_paged_batch_decode_impl, *_decode_step_args(chip, llama_1b, 16, "int8", scale_pages=3))
+
+
+def test_spec_window_kernel_arm_compiles_for_v5e(chip, llama_1b):
+  """``spec.paged_batch`` draft-free (the n-gram default): the verify
+  window's kernel arm launches the paged kernel once per window position
+  (``paged_window_forward``), here gamma 2 → three launches a round."""
+  from xotorch_support_jetson_tpu.models.decoder import _fused_spec_paged_batch_decode_impl
+
+  cfg, shard, params = llama_1b
+  n, rounds, gamma = 16, 8, 2
+  pool, mp = _smoke_pool(chip, cfg, n, "int8")
+  rows = _rows(chip, n)
+  _, text = _compile(
+    _fused_spec_paged_batch_decode_impl,
+    params, None, pool, None, _sds(chip, (n, 1), jnp.int32), _sds(chip, (n, mp), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+    rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), _sds(chip, (n, rounds * (gamma + 1) + gamma), jnp.int32), rows(jnp.int32), None,
+    cfg, shard, None, None, rounds, gamma, 64, PS, True, False,
+  )  # fmt: skip
+  assert text.count("tpu_custom_call") >= gamma + 1
+
+
+def test_batched_prefill_takes_the_flash_kernel_on_tpu(chip, llama_1b, monkeypatch):
+  """``prefill.pages_many_sampled`` for one PREFILL_BUCKET-wide admission.
+  The flash gate asks ``jax.default_backend()``; answered "tpu" for the
+  trace, the program must come out holding the kernel."""
+  from xotorch_support_jetson_tpu.models.decoder import prefill_into_pages_many_sampled
+
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  cfg, shard, params = llama_1b
+  pool, _ = _smoke_pool(chip, cfg, 16, "int8")
+  k = 4
+  rows = _rows(chip, k)
+  _, text = _compile(
+    prefill_into_pages_many_sampled,
+    params, cfg, shard, _sds(chip, (k, 128), jnp.int32), pool, _sds(chip, (k, 2), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS,
+    rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None,
+  )  # fmt: skip
+  assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------- four chips (v5e 2x2)
+#
+# A Mosaic kernel cannot be partitioned automatically: lowered anywhere but a
+# one-device program or a region that is manual over every mesh axis, jax
+# refuses it ("wrap the call in a shard_map"). On the chip the flash gate
+# answers yes, so both multi-chip serving modes died at their first prefill
+# until PR 21; CPU meshes never take the kernel and never saw it.
+
+
+@pytest.fixture(scope="module")
+def four_chips(chip):
+  from jax.experimental import topologies
+
+  return list(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices)
+
+
+def _on(mesh, tree, specs):
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  return jax.tree.map(lambda leaf, spec: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec)), tree, specs, is_leaf=lambda x: isinstance(x, P))
+
+
+def test_tp_default_prefill_compiles_without_kernels(four_chips, llama_1b, monkeypatch):
+  """The no-flag default on a four-chip host is a tp mesh under GSPMD: the
+  engine clears ``cfg.mosaic_kernels`` for it, and the prefill compiles on
+  the XLA attention path. With the gate left open the same lowering is
+  refused — which is what the chip did."""
+  from dataclasses import replace
+
+  from jax.sharding import PartitionSpec as P
+
+  from xotorch_support_jetson_tpu.inference.jax_engine import _prefill
+  from xotorch_support_jetson_tpu.models.decoder import init_kv_cache
+  from xotorch_support_jetson_tpu.parallel.mesh import auto_partitioned, build_mesh, inference_plan, specs_for_params
+
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  cfg, shard, params = llama_1b
+  plan = inference_plan(4, n_heads=cfg.n_heads)
+  assert plan.tp == 4 and auto_partitioned(plan)
+  mesh = build_mesh(plan, devices=four_chips)
+  params = _on(mesh, params, specs_for_params(params, False))
+  cache = jax.eval_shape(lambda: init_kv_cache(cfg, cfg.n_layers, 1, cfg.max_seq_len))
+  cache = _on(mesh, cache, {k: P(None, None, None, "tp", None) for k in cache})
+  tokens, lens = _on(mesh, jax.ShapeDtypeStruct((1, 128), jnp.int32), P()), _on(mesh, jax.ShapeDtypeStruct((1,), jnp.int32), P())
+  text = _prefill.lower(params, replace(cfg, mosaic_kernels=False), shard, tokens, cache, lens).compile().as_text()
+  assert "tpu_custom_call" not in text
+  with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+    _prefill.lower(params, cfg, shard, tokens, cache, lens)
+
+
+def test_pp4_prefill_keeps_the_flash_kernel(four_chips, llama_1b, monkeypatch):
+  """``--pp 4`` over four chips: every other mesh axis has size 1, so the
+  stage shard_map is manual throughout (parallel/mesh.py manual_axes) and the
+  flash kernel stays in — the layer-split ring computes what one device does."""
+  from functools import partial
+
+  from jax.sharding import PartitionSpec as P
+
+  from xotorch_support_jetson_tpu.models.decoder import init_kv_cache
+  from xotorch_support_jetson_tpu.parallel import pp_serving
+  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan, auto_partitioned, build_mesh, decoder_param_specs, manual_axes
+
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  cfg, _, params = llama_1b
+  plan = MeshPlan(pp=4)
+  assert not auto_partitioned(plan, "pp")
+  mesh = build_mesh(plan, devices=four_chips)
+  assert manual_axes(mesh, "pp") == frozenset(mesh.axis_names)
+  # PPServing's constructor places real arrays; build its programs around shapes instead.
+  srv = object.__new__(pp_serving.PPServing)
+  srv.mesh, srv.cfg, srv.n_stages, srv.is_first, srv.is_last, srv.n_prefix = mesh, cfg, 4, True, True, 0
+  srv._sm = partial(jax.shard_map, mesh=mesh, axis_names=manual_axes(mesh, "pp"), check_vma=False)
+  srv._build()
+  specs = decoder_param_specs()
+  stage = {k: jax.ShapeDtypeStruct((4, v.shape[0] // 4, *v.shape[1:]), v.dtype) for k, v in params["layers"].items()}
+  stage = _on(mesh, stage, {k: P("pp", *specs["layers"].get(k, P())) for k in stage})
+  head = {k: v for k, v in params.items() if k != "layers"}
+  head = _on(mesh, head, {k: specs.get(k, P()) for k in head})
+  cache = jax.eval_shape(lambda: init_kv_cache(cfg, cfg.n_layers, 1, cfg.max_seq_len))
+  cache = _on(mesh, cache, {k: pp_serving.pp_cache_spec(cfg, mesh) for k in cache})
+  tokens, lens = _on(mesh, jax.ShapeDtypeStruct((1, 128), jnp.int32), P()), _on(mesh, jax.ShapeDtypeStruct((1,), jnp.int32), P())
+  text = srv._prefill_fn.lower(stage, head, tokens, tokens, cache, lens).compile().as_text()
+  assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------- compile cache placement
+
+_PLACE = "from xotorch_support_jetson_tpu.utils.helpers import configure_compile_cache as c; import jax; print(c()); print(jax.config.jax_compilation_cache_dir)"
+
+
+def _placed(env: dict, cwd) -> list[str]:
+  env = {**os.environ, "PYTHONPATH": str(ROOT), "JAX_PLATFORMS": "cpu", **env}
+  env = {k: v for k, v in env.items() if v is not None}
+  return subprocess.run([sys.executable, "-c", _PLACE], capture_output=True, text=True, timeout=120, cwd=cwd, env=env, check=True).stdout.split()
+
+
+def test_compile_cache_dir_from_outside_is_left_to_jax(tmp_path):
+  """``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it, the code sets nothing
+  (the config still holds exactly the variable's value)."""
+  assert _placed({"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")}, tmp_path) == [str(tmp_path / "cc")] * 2
+
+
+def test_compile_cache_default_dir_is_fixed_inside_the_checkout(tmp_path):
+  """Unset: one fixed directory at the root of the checkout, the same from
+  two processes started in different places — the path is part of the
+  cache's key, so a directory that moves never hits."""
+  first, second = _placed({"JAX_COMPILATION_CACHE_DIR": None}, ROOT), _placed({"JAX_COMPILATION_CACHE_DIR": None}, tmp_path)
+  assert first == second == [str(ROOT / ".xot_compile_cache")] * 2
+
+
+# ------------------------------------------------------- chip_smoke.py
+
+
+def _smoke(*args, env=None, timeout=600):
+  return subprocess.run([sys.executable, str(ROOT / "chip_smoke.py"), *args], capture_output=True, text=True, timeout=timeout, cwd=ROOT, env=env)
+
+
+def test_chip_smoke_without_a_tpu_fails(tmp_path):
+  """No accelerator and no rehearsal option: a non-zero exit and
+  ``"ok": false`` — never a CPU result under the chip's name."""
+  import json
+
+  env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+  out = _smoke(env=env)
+  last = json.loads(out.stdout.strip().splitlines()[-1])
+  assert out.returncode != 0 and last["ok"] is False, out.stdout[-2000:] + out.stderr[-2000:]
+  assert '"platform": "tpu"' not in out.stdout
+
+
+def test_chip_smoke_cpu_rehearsal(tmp_path):
+  """The whole control flow of ``chip_smoke.py`` — checkpoint from a seed,
+  kernels against references (interpret mode), a solo daemon and a batched
+  daemon each answering blocking, streamed and concurrent requests, the
+  program ledger read back — at tiny width behind its explicit option."""
+  import json
+
+  env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+  out = _smoke("--cpu-rehearsal", env=env)
+  assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]
+  lines = [json.loads(line) for line in out.stdout.strip().splitlines()]
+  assert lines[-1] == {"ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+  phases = {line["phase"]: line for line in lines if "phase" in line}
+  assert {"kernels", "solo", "batched"} <= set(phases) and all(p["ok"] for p in phases.values())
+  assert phases["solo"]["blocking_equals_streaming"] and phases["batched"]["blocking_equals_streaming"]
+  assert '"platform": "tpu"' not in out.stdout

@@ -3,25 +3,6 @@
 from xotorch_support_jetson_tpu.networking.discovery import Discovery
 
 
-def require_partial_manual(plan=None, manual=("pp",)):
-  """Skip (don't error) multi-axis partial-manual mesh tests on jax builds
-  that cannot run them: jax 0.4.x's experimental shard_map lowers a manual
-  region's collectives through PartitionId when any GSPMD-auto axis is >1,
-  which XLA's SPMD partitioner rejects — the pp×tp and sp×tp serving/train
-  meshes. ``parallel/mesh.py partial_manual_supported`` is the capability
-  probe; on jax >= 0.5 (top-level jax.shard_map) these tests all run."""
-  import pytest
-
-  from xotorch_support_jetson_tpu.parallel.mesh import MeshPlan, partial_manual_supported
-
-  plan = plan or MeshPlan(pp=2, tp=2)
-  if not partial_manual_supported(plan, manual):
-    pytest.skip(
-      f"jax build lacks partial-manual shard_map over a multi-axis mesh "
-      f"(manual={list(manual)}, plan: {plan.describe()}) — needs jax.shard_map (>= 0.5)"
-    )
-
-
 class NoDiscovery(Discovery):
   async def start(self):
     pass

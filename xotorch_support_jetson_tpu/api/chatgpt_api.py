@@ -24,7 +24,7 @@ from ..inference.engine import RequestStalledError
 from ..inference.qos import PRIORITY_CLASSES
 from ..inference.shard import Shard
 from ..inference.tokenizers import resolve_tokenizer
-from ..utils.helpers import DEBUG, PrefixDict, AsyncCallbackSystem
+from ..utils.helpers import DEBUG, AsyncCallbackSystem, PrefixDict, device_memory
 from ..utils.metrics import metrics
 
 
@@ -689,6 +689,7 @@ class ChatGPTAPI:
 
     local = ledger.snapshot()
     local["node_id"] = getattr(self.node, "id", None)
+    local["devices"] = device_memory()
     if request.query.get("scope") != "cluster":
       return web.json_response(local)
     peer_snaps: list[dict] = []

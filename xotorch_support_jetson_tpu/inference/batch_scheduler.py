@@ -847,31 +847,16 @@ class BatchedServer:
       metrics.set_gauge("kv_draft_slots", 0)
       metrics.set_gauge("kv_draft_pages_equivalent", 0)
     if self.paged:
-      from .paging import PageAllocator, kv_cache_bytes, pages_to_cover
+      from .paging import PageAllocator, default_pool_pages, kv_cache_bytes, pages_to_cover
 
       ps = self.page_size
       self.pages_per_row = pages_to_cover(self.max_seq, ps)
-      # Default pool size: the dense bf16 layout's HBM budget expressed in
-      # PAGES of the ACTUAL quant mode (kv_cache_bytes is the one block-math
-      # definition — the draft accounting below and the capacity tests pin
-      # the same formula). An int8-KV token costs hd code bytes + 4 scale
-      # bytes per head per side vs 2·hd bf16 bytes → the same budget holds
-      # 2·hd/(hd+4) ≈ 1.88x (hd=64) the pages; int4 packs two nibbles per
-      # byte → ≈ 3.6x, which is what moves the default admission knee past
-      # B=96 (ISSUE 11: a pool sized from the dense-48 budget covers 96
-      # full context windows under int4, where int8 could not). Admission
-      # at large batch is bounded by this paged block math instead of
-      # dense-slot math.
-      per_dense = self.n_slots * self.pages_per_row
-      if kv_quant:
-        n_layers = eng._effective_shard.n_shard_layers
-        # The budget baseline is the SERVING dense layout: bf16 K/V (2
-        # bytes/element) regardless of cfg.dtype — test configs run f32
-        # params, but the budget story (and the pinned capacity tests) is
-        # the production bf16 one.
-        heads, per_side = eng.cfg.cache_kv_heads, eng.cfg.cache_k_dim + eng.cfg.cache_v_dim
-        dense_budget = n_layers * per_dense * ps * heads * per_side * 2
-        per_dense = dense_budget // max(kv_cache_bytes(eng.cfg, n_layers, ps, kv_quant), 1)
+      # Default pool size: the dense bf16 layout's HBM budget in PAGES of
+      # the actual quant mode (inference/paging.py default_pool_pages — the
+      # draft accounting below and the capacity tests pin the same block
+      # math), so admission at large batch is bounded by paged block math
+      # instead of dense-slot math.
+      per_dense = default_pool_pages(eng.cfg, eng._effective_shard.n_shard_layers, self.n_slots, self.max_seq, ps, kv_quant)
       if draft_pages_equiv:
         # Draft-KV accounting (ISSUE 7): the draft cache rides in the SAME
         # HBM budget, so its page-equivalent comes out of the default pool —

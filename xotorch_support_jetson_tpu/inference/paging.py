@@ -387,6 +387,23 @@ def kv_cache_bytes(cfg, n_layers: int, n_tokens: int, quant: str = "") -> int:
   return int(n_layers) * int(n_tokens) * int(per_token)
 
 
+def default_pool_pages(cfg, n_layers: int, n_slots: int, max_seq: int, page_size: int, quant: str = "") -> int:
+  """Pages (trash page excluded) of the scheduler's DEFAULT pool: the dense
+  bf16 layout's HBM budget — ``n_slots`` windows of ``max_seq`` tokens at 2
+  bytes/element, whatever ``cfg.dtype`` is (test configs run f32 params; the
+  budget story and the pinned capacity tests are the production bf16 one) —
+  re-expressed in pages of the ACTUAL quant mode. An int8-KV token costs hd
+  code bytes + 4 scale bytes per head per side against 2·hd bf16 bytes, so
+  the same budget holds 2·hd/(hd+4) ≈ 1.88x (hd=64) the pages; int4 packs
+  two nibbles per byte → ≈ 3.6x (ISSUE 11: a pool sized from the dense-48
+  budget covers 96 full windows under int4)."""
+  per_dense = int(n_slots) * pages_to_cover(max_seq, page_size)
+  if not quant:
+    return per_dense
+  dense_budget = int(n_layers) * per_dense * int(page_size) * cfg.cache_kv_heads * (cfg.cache_k_dim + cfg.cache_v_dim) * 2
+  return dense_budget // max(kv_cache_bytes(cfg, n_layers, page_size, quant), 1)
+
+
 def lora_device_bytes(n_layers: int, d_in: int, d_out: int, rank: int, n_slots: int, itemsize: int = 4) -> int:
   """HBM bytes of ONE target projection's stacked LoRA slot factors
   (ISSUE 15): ``A [L, n_slots, d_in, r]`` + ``B [L, n_slots, r, d_out]``.

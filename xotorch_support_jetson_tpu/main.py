@@ -17,15 +17,11 @@ import sys
 import time
 import uuid
 
-from .utils.helpers import apply_platform_override
-
-apply_platform_override()
-
 from . import registry
 from .inference.engine import get_inference_engine, inference_engine_classes
 from .inference.shard import Shard
 from .topology.partitioning import RingMemoryWeightedPartitioningStrategy
-from .utils.helpers import DEBUG, find_available_port, get_or_create_node_id
+from .utils.helpers import DEBUG, apply_platform_override, configure_compile_cache, device_summary, find_available_port, get_or_create_node_id
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -238,8 +234,7 @@ def build_components(args):
 async def run_model_cli(node, engine_classname: str, model_name: str, prompt: str) -> None:
   shard = registry.build_base_shard(model_name, engine_classname)
   if shard is None:
-    print(f"Error: unsupported model '{model_name}' for engine {engine_classname}")
-    return
+    raise SystemExit(f"Error: unsupported model '{model_name}' for engine {engine_classname}")
   from .inference.tokenizers import resolve_tokenizer
 
   tokenizer = await resolve_tokenizer(registry.get_repo(model_name, engine_classname))
@@ -265,7 +260,7 @@ async def run_model_cli(node, engine_classname: str, model_name: str, prompt: st
   try:
     await asyncio.wait_for(done.wait(), timeout=300)
   except asyncio.TimeoutError:
-    print("\n[timeout]")
+    raise SystemExit(f"\n[timeout] no completion after 300 s ({len(tokens_out)} tokens)") from None
   elapsed = time.perf_counter() - t_start
   print(f"\n[{len(tokens_out)} tokens in {elapsed:.1f}s — {len(tokens_out)/max(elapsed,1e-9):.1f} tok/s]")
 
@@ -414,7 +409,12 @@ def run() -> None:
   if int(os.environ.get("XOT_TPU_PP", "0") or 0) > 1 and int(os.environ.get("XOT_TPU_SP", "0") or 0) > 1:
     print("error: --pp/XOT_TPU_PP and --sp/XOT_TPU_SP are mutually exclusive serving modes", file=sys.stderr)
     sys.exit(2)
+  apply_platform_override()
+  cache_dir = configure_compile_cache()
   maybe_init_jax_distributed(args)
+  # Logged once, before anything is loaded: the first device query is also
+  # where a host whose accelerator cannot be reached fails, loudly.
+  print(json.dumps({"event": "devices", **device_summary(), "compile_cache": cache_dir}), flush=True)
   try:
     asyncio.run(async_main(args))
   except KeyboardInterrupt:

@@ -137,6 +137,12 @@ class ModelConfig:
   # the placeholder token id the HF processor expands per image patch.
   vision: Any = None  # VisionConfig | None (Any keeps this module torch/vision-free)
   image_token_id: int = -1
+  # Cleared by the engine (never by a user) when the serving plan leaves a
+  # mesh axis of more than one device to GSPMD: a Mosaic kernel cannot be
+  # partitioned automatically ("wrap the call in a shard_map"), so programs
+  # that span such an axis take the XLA attention paths. Static like the rest
+  # of the config, so the choice keys the compiled programs.
+  mosaic_kernels: bool = True
 
   def layer_is_sliding(self, layer_idx: int) -> bool:
     """HF Gemma2: even-indexed layers use the sliding window."""
@@ -144,9 +150,11 @@ class ModelConfig:
 
   @property
   def plain_attention(self) -> bool:
-    """No per-config attention variations (softcap/window/scale override) —
-    the single gate for Pallas kernels, which implement none of them."""
-    return not self.attn_logit_softcap and not self.sliding_window and not self.query_pre_attn_scalar
+    """No per-config attention variations (softcap/window/scale override)
+    and no automatically partitioned mesh axis — the single gate for Pallas
+    kernels, which implement none of the former and cannot be lowered under
+    the latter."""
+    return self.mosaic_kernels and not self.attn_logit_softcap and not self.sliding_window and not self.query_pre_attn_scalar
 
   @property
   def is_mla(self) -> bool:

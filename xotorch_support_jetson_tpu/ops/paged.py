@@ -272,9 +272,11 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: f
         # Two-dot in-register dequant (see the int4 note above): lo/hi are
         # pure shifts of the SAME packed [ps, hd/2] tile — read from HBM
         # once at 0.5 byte/element, never materialized unpacked.
-        kp = k_refs[j][0, 0]
-        k_lo = ((kp << 4) >> 4).astype(jnp.float32)  # even channels, sign-extended
-        k_hi = (kp >> 4).astype(jnp.float32)  # odd channels
+        # Widened to int32 first: Mosaic has no int8 vector shift on v5e
+        # (same idiom as ops/pallas_int4.py).
+        kp = k_refs[j][0, 0].astype(jnp.int32)
+        k_lo = ((kp << 28) >> 28).astype(jnp.float32)  # even channels, sign-extended
+        k_hi = ((kp << 24) >> 28).astype(jnp.float32)  # odd channels
         s = jax.lax.dot_general(q[:, :half], k_lo, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         s = s + jax.lax.dot_general(q[:, half:], k_hi, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         s = s * scale
@@ -299,9 +301,9 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: f
       if quantized:
         p = p * jnp.transpose(vs_refs[j][0, 0], (1, 0))  # v's scale folds into probs (after the l update)
       if packed:
-        vp_ = v_refs[j][0, 0]
-        v_lo = ((vp_ << 4) >> 4).astype(jnp.float32)
-        v_hi = (vp_ >> 4).astype(jnp.float32)
+        vp_ = v_refs[j][0, 0].astype(jnp.int32)
+        v_lo = ((vp_ << 28) >> 28).astype(jnp.float32)
+        v_hi = ((vp_ << 24) >> 28).astype(jnp.float32)
         upd = jnp.concatenate(
           [
             jax.lax.dot_general(p, v_lo, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32),
@@ -439,4 +441,4 @@ def paged_kernel_supported(cfg, platform: str | None = None) -> bool:
   if os.getenv("XOT_TPU_NO_FLASH") or not env_flag("XOT_TPU_PAGED_KERNEL", default=True):
     return False
   platform = platform or jax.default_backend()
-  return platform == "tpu" and not cfg.is_mla and cfg.head_dim in (64, 128, 256)
+  return platform == "tpu" and cfg.plain_attention and not cfg.is_mla and cfg.head_dim in (64, 128, 256)

@@ -31,6 +31,7 @@ import numpy as np
 
 from ..inference.shard import Shard
 from ..models.config import ModelConfig
+from ..utils.helpers import device_memory
 from .mesh import MeshPlan, pow2_degree
 
 _HEAD_KEYS = ("embed", "final_norm", "lm_head", "lm_head_scale")
@@ -221,17 +222,16 @@ def check_plan(cfg: ModelConfig, plan: MeshPlan, n_devices: int, hbm_bytes: int 
 
 
 def device_hbm_bytes() -> int | None:
-  """Per-chip HBM of the local accelerator, when the backend reports it."""
-  try:
-    dev = jax.devices()[0]
-    if dev.platform == "cpu":
-      return None
-    stats = dev.memory_stats()
-    if stats and "bytes_limit" in stats:
-      return int(stats["bytes_limit"])
-  except Exception:  # noqa: BLE001 — absent/failing stats just disable the check
-    pass
-  return None
+  """Per-chip HBM of the local accelerator as the runtime reports it; None
+  on CPU (no HBM to budget). An accelerator that reports no ``bytes_limit``
+  is an error — the check is switched off with ``XOT_TPU_HBM_CHECK=0``, never
+  by a failed probe."""
+  dev = device_memory()[0]
+  if dev["platform"] == "cpu":
+    return None
+  if not dev["bytes_limit"]:
+    raise RuntimeError(f"{dev['kind']!r} reports no bytes_limit in memory_stats(); set XOT_TPU_HBM_CHECK=0 to serve without the HBM budget check")
+  return int(dev["bytes_limit"])
 
 
 class RingBudgetError(RuntimeError):

@@ -41,54 +41,19 @@ class MeshPlan:
     return f"dp={self.dp} pp={self.pp} sp={self.sp} ep={self.ep} tp={self.tp}"
 
 
-def shard_map_compat(f, *, mesh, in_specs=None, out_specs=None, axis_names=frozenset(), check_vma=True):
-  """``jax.shard_map`` across jax versions, in the NEW API's spelling.
-
-  Newer jax exposes top-level ``jax.shard_map(f, ..., axis_names=manual,
-  check_vma=...)``; older releases (≤0.4.x) only have
-  ``jax.experimental.shard_map.shard_map`` with the equivalent knobs named
-  ``auto`` (the COMPLEMENT of axis_names) and ``check_rep``. Every partial-
-  manual program in this package routes through here so one tree runs on
-  both. Use exactly like ``partial(jax.shard_map, ...)`` — empty
-  ``axis_names`` means fully manual (the new API's default), normalized
-  here so the old-API complement doesn't invert the meaning.
-  """
-  axis_names = frozenset(axis_names) or frozenset(mesh.axis_names)
-  if hasattr(jax, "shard_map"):
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, axis_names=axis_names, check_vma=check_vma)
-  from jax.experimental.shard_map import shard_map as _shard_map
-
-  auto = frozenset(mesh.axis_names) - axis_names
-  if any(mesh.shape[a] > 1 for a in auto):
-    # Old-jax partial-auto shard_map lowers the manual region's
-    # axis_index/collectives through PartitionId, which XLA's SPMD
-    # partitioner rejects whenever a GSPMD-auto axis is actually >1 device.
-    # Fail at build time with the real reason instead of minutes into an
-    # XLA compile with an opaque UNIMPLEMENTED error.
-    raise NotImplementedError(
-      f"partial-manual shard_map (manual={sorted(axis_names)}) over a multi-device auto axis "
-      f"({ {a: mesh.shape[a] for a in sorted(auto) if mesh.shape[a] > 1} }) needs jax's top-level "
-      "jax.shard_map (>= 0.5); this jax build only supports it when every auto axis is size 1"
-    )
-  return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma, auto=auto)
+def manual_axes(mesh: Mesh, *axes: str) -> frozenset:
+  """The manual set of a serving shard_map: ``axes`` plus every mesh axis of
+  size 1. A size-1 axis has nothing to partition, and with it manual a mesh
+  whose remaining axes are all 1 (``--pp 4`` on four chips) is manual
+  throughout — the only kind of region a Mosaic kernel can be lowered in.
+  Axes of more than one device stay GSPMD-auto (tp under pp/sp)."""
+  return frozenset(axes) | {a for a in mesh.axis_names if mesh.shape[a] == 1}
 
 
-def partial_manual_supported(plan: MeshPlan, manual: tuple[str, ...] = ("pp",)) -> bool:
-  """Capability probe: can this jax build run the partial-manual shard_map
-  programs ``plan`` needs (manual over ``manual`` axes, the rest GSPMD-auto)?
-
-  Newer jax (top-level ``jax.shard_map``) always can. jax 0.4.x only has
-  ``jax.experimental.shard_map``, whose partial-auto lowering routes the
-  manual region's collectives through PartitionId — XLA's SPMD partitioner
-  rejects that whenever any auto axis is >1 device (``shard_map_compat``
-  raises NotImplementedError at build time). That is exactly the pp×tp and
-  sp×tp serving meshes; tests use this probe to SKIP those parametrizations
-  on old builds with an explicit reason instead of erroring mid-compile.
-  """
-  if hasattr(jax, "shard_map"):
-    return True
-  manual_set = frozenset(manual)
-  return all(getattr(plan, a) == 1 for a in AXES if a not in manual_set)
+def auto_partitioned(plan: MeshPlan, manual: str | None = None) -> bool:
+  """Whether a serving plan leaves an axis of more than one device to GSPMD
+  (every axis but ``manual``) — where Mosaic kernels cannot go."""
+  return plan.n_devices // (getattr(plan, manual) if manual else 1) > 1
 
 
 def build_mesh(plan: MeshPlan, devices: list | None = None) -> Mesh:

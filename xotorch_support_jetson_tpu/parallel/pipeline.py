@@ -30,7 +30,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models.config import ModelConfig
 from ..models.decoder import _layer_step
 from ..ops.rope import rope_inv_freq
-from .mesh import shard_map_compat
 
 
 def stack_stage_params(layer_params: dict, n_stages: int) -> dict:
@@ -94,7 +93,7 @@ def make_pipeline_layers_fn(mesh: Mesh, cfg: ModelConfig, n_stages: int, n_micro
   pp_spec = "pp" if n_stages > 1 else None
 
   @partial(
-    shard_map_compat,
+    jax.shard_map,
     mesh=mesh,
     in_specs=(P(pp_spec), P(None, seq, None), P(None, seq)),
     out_specs=(P(pp_spec, None, seq, None), P()),

@@ -59,9 +59,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.config import ModelConfig
 from ..models.decoder import _next_token_batched, embed_tokens, head_logits
 from ..ops.rope import rope_inv_freq
+from .mesh import manual_axes
 from ..utils.programs import tracked_jit
 from .pp_serving import _merge_written, _pp_tick_loop, _stage_forward, place_pp_params, pp_cache_spec, split_pp_params
-from .mesh import shard_map_compat
 
 
 def _take(arr: jnp.ndarray, g: jnp.ndarray) -> jnp.ndarray:
@@ -88,7 +88,7 @@ class PPBatchedServing:
     stack_name, stage_params, head, self.n_prefix = split_pp_params(params, n_stages)
     self.stage_params, self.head = place_pp_params(stage_params, head, mesh, stack_name)
     self._cache_spec = pp_cache_spec(cfg, mesh)
-    self._sm = partial(shard_map_compat, mesh=mesh, axis_names={"pp"}, check_vma=False)
+    self._sm = partial(jax.shard_map, mesh=mesh, axis_names=manual_axes(mesh, "pp"), check_vma=False)
     self._build()
 
   @classmethod
@@ -101,7 +101,7 @@ class PPBatchedServing:
     self.mesh, self.cfg, self.n_stages = pps.mesh, pps.cfg, pps.n_stages
     self.stage_params, self.head = pps.stage_params, pps.head
     self._cache_spec = pp_cache_spec(self.cfg, self.mesh)
-    self._sm = partial(shard_map_compat, mesh=self.mesh, axis_names={"pp"}, check_vma=False)
+    self._sm = partial(jax.shard_map, mesh=self.mesh, axis_names=manual_axes(self.mesh, "pp"), check_vma=False)
     self._build()
     return self
 

@@ -41,7 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.config import ModelConfig
 from ..models.decoder import _layer_step, _next_token, embed_tokens, head_logits
 from ..ops.rope import rope_inv_freq
-from .mesh import shard_map_compat
+from .mesh import manual_axes
 
 _HEAD_KEYS = ("embed", "final_norm", "lm_head", "lm_head_scale")
 
@@ -217,7 +217,7 @@ class PPServing:
     self._stack_name = stack_name
     self.stage_params, self.head = place_pp_params(stage_params, head, mesh, stack_name)
     self._cache_spec = pp_cache_spec(cfg, mesh)
-    self._sm = partial(shard_map_compat, mesh=mesh, axis_names={"pp"}, check_vma=False)
+    self._sm = partial(jax.shard_map, mesh=mesh, axis_names=manual_axes(mesh, "pp"), check_vma=False)
     self._build()
 
   # ------------------------------------------------ flat-params round trip

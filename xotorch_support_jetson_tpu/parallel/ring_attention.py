@@ -20,7 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .mesh import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -108,7 +107,7 @@ def make_sharded_ring_attention(mesh: Mesh, **attn_opts):
   spec_pos = P(None, "sp")
 
   @partial(
-    shard_map_compat,
+    jax.shard_map,
     mesh=mesh,
     in_specs=(spec_q, spec_q, spec_q, spec_pos, P("sp")),
     out_specs=spec_q,

@@ -38,9 +38,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.config import ModelConfig
 from ..models.decoder import _next_token_batched, embed_tokens, head_logits
 from ..ops.rope import rope_inv_freq
+from .mesh import manual_axes
 from ..utils.programs import tracked_jit
 from .sp_serving import AXIS, SPServing, _sp_forward, _sp_layer_step
-from .mesh import shard_map_compat
 
 
 def _stripe_positions(mp: int, stripe: int, page_size: int, rank) -> jnp.ndarray:
@@ -126,7 +126,7 @@ class SPBatchedServing:
     self.cfg: ModelConfig = sps.cfg
     self.n_ranks = sps.n_ranks
     self.params = sps.params
-    self._sm = partial(shard_map_compat, mesh=self.mesh, axis_names={AXIS}, check_vma=False)
+    self._sm = partial(jax.shard_map, mesh=self.mesh, axis_names=manual_axes(self.mesh, AXIS), check_vma=False)
     self._build()
 
   def place_cache(self, cache: dict) -> dict:

@@ -34,7 +34,7 @@ from ..models.decoder import _dense_qkv, _mla_latents, _mla_w_kv_b, _mlp_block, 
 from ..ops.attention import NEG_INF, cap_and_mask_scores
 from ..ops.norm import rms_norm
 from ..ops.rope import rope_inv_freq
-from .mesh import shard_map_compat
+from .mesh import manual_axes
 
 AXIS = "sp"
 
@@ -259,7 +259,7 @@ class SPServing:
     heads = cfg.cache_kv_heads
     tp = "tp" if "tp" in mesh.shape and heads > 1 and heads % mesh.shape["tp"] == 0 else None
     self._cache_spec = P(None, None, AXIS, tp, None)
-    self._sm = partial(shard_map_compat, mesh=mesh, axis_names={AXIS}, check_vma=False)
+    self._sm = partial(jax.shard_map, mesh=mesh, axis_names=manual_axes(mesh, AXIS), check_vma=False)
     self._build()
 
   def place_cache(self, cache: dict) -> dict:

@@ -7,7 +7,8 @@ CUDA GPUs and Jetson boards; here the first-class citizen is the TPU: chip
 kind, count, and per-chip HBM come from live JAX runtime metadata
 (``jax.devices()``, ``device.memory_stats()``), with a small public-spec
 TFLOPS table for capability *estimates* (used only for placement weighting
-and viz, never for correctness). CPU fallback uses ``os.sysconf``.
+and viz, never for correctness). A host with no accelerator is described
+from ``os.sysconf``.
 """
 
 from __future__ import annotations
@@ -15,7 +16,7 @@ from __future__ import annotations
 import os
 from dataclasses import asdict, dataclass
 
-from ..utils.helpers import DEBUG
+from ..utils.helpers import DEBUG, device_memory
 
 TFLOPS = 1.0
 
@@ -76,27 +77,15 @@ TPU_CHIP_FLOPS: dict[str, DeviceFlops] = {
   "tpu7x": DeviceFlops(fp32=1153.0, fp16=2307.0, int8=4614.0),
 }
 
-# Default per-chip HBM when memory_stats() is unavailable on the platform (MB).
-TPU_CHIP_HBM_MB: dict[str, int] = {
-  "tpu v2": 8 * 1024,
-  "tpu v3": 16 * 1024,
-  "tpu v4": 32 * 1024,
-  "tpu v5 lite": 16 * 1024,
-  "tpu v5e": 16 * 1024,
-  "tpu v5": 96 * 1024,
-  "tpu v5p": 96 * 1024,
-  "tpu v6 lite": 32 * 1024,
-  "tpu v6e": 32 * 1024,
-  "tpu7x": 192 * 1024,
-}
-
-
-def _lookup_chip(device_kind: str) -> tuple[DeviceFlops, int]:
+def _lookup_tpu_flops(device_kind: str) -> DeviceFlops:
+  """Peak-compute estimate for a TPU ``device_kind``. An unknown kind is an
+  error, not a zero: a ring would silently weight this host as having no
+  compute at all."""
   kind = device_kind.lower().strip()
   for key in sorted(TPU_CHIP_FLOPS, key=len, reverse=True):
     if kind.startswith(key) or key in kind:
-      return TPU_CHIP_FLOPS[key], TPU_CHIP_HBM_MB.get(key, 16 * 1024)
-  return DeviceFlops(fp32=0, fp16=0, int8=0), 16 * 1024
+      return TPU_CHIP_FLOPS[key]
+  raise ValueError(f"unknown TPU device_kind {device_kind!r}: add it to TPU_CHIP_FLOPS (topology/device_capabilities.py)")
 
 
 def _host_memory_mb() -> int:
@@ -109,23 +98,18 @@ def _host_memory_mb() -> int:
 
 
 def _tpu_device_capabilities() -> DeviceCapabilities | None:
-  try:
-    import jax
-
-    devices = [d for d in jax.local_devices() if d.platform != "cpu"]
-  except Exception:  # noqa: BLE001 — no JAX backend is a soft failure
-    return None
+  """Capabilities of the local TPU chips; None when JAX runs on anything
+  else (the later probes then describe the host). On a TPU nothing is
+  assumed: the kind must be in the table and the runtime must report the
+  chip's ``bytes_limit``."""
+  devices = [d for d in device_memory() if d["platform"] == "tpu"]
   if not devices:
     return None
-  kind = devices[0].device_kind
-  flops, default_hbm = _lookup_chip(kind)
-  per_chip_mb = default_hbm
-  try:
-    stats = devices[0].memory_stats()
-    if stats and stats.get("bytes_limit"):
-      per_chip_mb = int(stats["bytes_limit"] / (1024 * 1024))
-  except Exception:  # noqa: BLE001 — memory_stats unsupported on some platforms
-    pass
+  kind = devices[0]["kind"]
+  flops = _lookup_tpu_flops(kind)
+  if not devices[0]["bytes_limit"]:
+    raise RuntimeError(f"TPU {kind!r} reports no bytes_limit in memory_stats()")
+  per_chip_mb = int(devices[0]["bytes_limit"] / (1024 * 1024))
   n = len(devices)
   return DeviceCapabilities(
     model=f"TPU host ({n}x {kind})",

@@ -15,12 +15,7 @@ import random
 import socket
 import uuid
 from pathlib import Path
-from typing import Any, Callable, Generic, TypeVar
-
-try:  # TypeVarTuple/Unpack land in typing at 3.11; 3.10 runs on the backport
-  from typing import TypeVarTuple, Unpack
-except ImportError:
-  from typing_extensions import TypeVarTuple, Unpack
+from typing import Any, Callable, Generic, TypeVar, TypeVarTuple, Unpack
 
 DEBUG = int(os.getenv("DEBUG", "0"))
 DEBUG_DISCOVERY = int(os.getenv("DEBUG_DISCOVERY", "0"))
@@ -46,35 +41,66 @@ def env_float(name: str, default: float) -> float:
 
 
 def apply_platform_override() -> None:
-  """Honor XOT_TPU_PLATFORM / JAX_PLATFORMS as the device override, parity
-  with the reference's TORCH_DEVICE knob (sharded_inference_engine.py:58-65).
-
-  Some TPU plugins clobber the JAX_PLATFORMS env var at import time; the
-  config API still wins, so entrypoints call this before touching devices
-  (e.g. ``JAX_PLATFORMS=cpu`` runs the daemon or bench without an
-  accelerator).
-  """
+  """The one rule for which device JAX uses: ``XOT_TPU_PLATFORM`` (else
+  ``JAX_PLATFORMS``) when set, otherwise JAX's own choice. Parity with the
+  reference's TORCH_DEVICE knob (sharded_inference_engine.py:58-65). Entry
+  points call this before touching devices; nothing else in the package
+  picks or changes the platform."""
   platform = os.getenv("XOT_TPU_PLATFORM") or os.getenv("JAX_PLATFORMS")
   if platform:
     import jax
 
     jax.config.update("jax_platforms", platform)
 
-def multihost_cpu_collectives_supported() -> bool:
-  """Capability probe: can THIS jax build run cross-process collectives on
-  the CPU backend (what the multihost smoke's gradient all-reduce needs)?
 
-  Real accelerator backends do collectives natively. On CPU, cross-process
-  psum only works when jax routes CPU collectives through gloo — jax 0.4.x
-  has no ``jax_cpu_collectives_implementation`` config and its multiprocess
-  CPU psum fails with "Multiprocess computations aren't implemented on the
-  CPU backend". Tests skip (with this reason) instead of erroring there.
-  """
+# Default home of JAX's persistent compilation cache: one fixed directory at
+# the root of the checkout (git-ignored). The path is part of the cache key,
+# so it must not move between runs — never a temp dir, a pid or a timestamp.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".xot_compile_cache"
+
+
+def configure_compile_cache() -> str:
+  """Place JAX's persistent compilation cache; returns the directory in use.
+
+  ``JAX_COMPILATION_CACHE_DIR`` set → JAX reads it itself and no directory is
+  set in code; unset → ``COMPILE_CACHE_DIR``. Every entry point calls this next
+  to ``apply_platform_override`` so a daemon, the bench and ``chip_smoke.py``
+  children all share one cache (a 16-layer model's programs compile once per
+  checkout instead of once per process)."""
   import jax
 
-  if jax.default_backend() != "cpu":
-    return True
-  return hasattr(jax.config, "jax_cpu_collectives_implementation")
+  if os.getenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS") is None:
+    # JAX keeps only programs that took over a second to compile; a cold
+    # daemon also builds dozens of smaller ones, every start.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+  placed = os.getenv("JAX_COMPILATION_CACHE_DIR")
+  if placed:
+    return placed
+  jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+  return str(COMPILE_CACHE_DIR)
+
+
+def device_summary() -> dict:
+  """Platform, kind and count of the devices JAX gave this process — what an
+  entry point logs once at start, and what ``chip_smoke.py`` reports."""
+  import jax
+
+  devices = jax.devices()
+  return {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def device_memory() -> list[dict]:
+  """Per local device, what the runtime says it holds: ``bytes_in_use``,
+  ``peak_bytes_in_use`` and ``bytes_limit`` from ``memory_stats()`` (all
+  None on a backend that reports none, as the CPU's) — how ``/v1/programs``
+  shows which chips a serving plan really put weights and cache on."""
+  import jax
+
+  out = []
+  for d in jax.local_devices():
+    stats = d.memory_stats() or {}
+    out.append({"id": d.id, "platform": d.platform, "kind": d.device_kind, **{k: stats.get(k) for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}})
+  return out
 
 
 XOT_HOME = Path(os.getenv("XOT_TPU_HOME", Path.home() / ".cache" / "xot_tpu"))

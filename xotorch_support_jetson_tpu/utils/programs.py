@@ -15,9 +15,9 @@ module turns them into a gated measurement (ISSUE 19):
   (wall time of the compiling dispatch: trace + lower + backend compile),
   dispatch count, and ``program_device_seconds`` (wall time of steady
   dispatches, attributing tick time across dense/paged/spec/mixed/LoRA
-  program variants). Where the installed jax supports it, a
-  ``jax.monitoring`` duration listener additionally records the backend's
-  own compile seconds into the ledger snapshot (``xla_compile_s``).
+  program variants). A ``jax.monitoring`` duration listener additionally
+  records the backend's own compile seconds into the ledger snapshot
+  (``xla_compile_s``).
 - **Warmup manifest**: the scheduler enumerates the program set expected for
   the active config; ``POST /v1/warmup`` pre-compiles it off the serving
   path and calls :meth:`ProgramLedger.mark_steady`.
@@ -404,31 +404,25 @@ def current_dispatch_context() -> dict | None:
 # --------------------------------------------------- jax.monitoring bridge
 
 _MON_INSTALLED = False
-# Event names vary across jax releases; match any backend-compile duration.
-_MON_EVENT_MARKERS = ("backend_compile", "/jax/core/compile")
+# jax's duration event for the backend's own compile (XLA + Mosaic). It is
+# not emitted for a program the persistent compilation cache served, so
+# ``xla_compile_s`` falls to near zero on a warm start while the trace-side
+# ``compiles`` count stays what it was.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _install_monitoring_listener() -> None:
   global _MON_INSTALLED
   if _MON_INSTALLED:
     return
-  try:
-    from jax import monitoring
+  from jax import monitoring
 
-    reg = getattr(monitoring, "register_event_duration_secs_listener", None)
-    if reg is None:
-      return
+  def _listener(event: str, duration: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE_EVENT and programs_enabled():
+      ledger.note_xla_compile_seconds(duration)
 
-    def _listener(event: str, duration: float, **_kw) -> None:
-      if not programs_enabled():
-        return
-      if any(m in event for m in _MON_EVENT_MARKERS):
-        ledger.note_xla_compile_seconds(duration)
-
-    reg(_listener)
-    _MON_INSTALLED = True
-  except Exception:
-    pass
+  monitoring.register_event_duration_secs_listener(_listener)
+  _MON_INSTALLED = True
 
 
 # ---------------------------------------------------------------- wrapper
