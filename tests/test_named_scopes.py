@@ -1,0 +1,99 @@
+"""The model-component scopes of the fused programs (ISSUE 24).
+
+``jax.named_scope`` names are HLO metadata: the profiler's trace shows them as
+each device op's ``op_name``, which is how ``benchmark/span_lib.py`` splits a
+decode step's device time by component. Two claims, both on the CPU compile of
+the tiny ``decode.paged_batch``: every name of the vocabulary reaches the
+compiled program, and the optimised program is the same with and without them.
+"""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from xotorch_support_jetson_tpu.models.config import tiny_test_config
+from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, full_model_params
+from xotorch_support_jetson_tpu.models.quantize import quantize_params
+from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+PS = 16
+COMMON = {"xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.dequant", "xot.head", "xot.sample"}
+CONFIGS = {
+  "dense": (dict(n_layers=2, max_seq_len=128), "int8", COMMON | {"xot.ffn"}),
+  "mla_moe": (
+    dict(
+      n_layers=2, max_seq_len=128, n_heads=4, n_kv_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+      n_experts=4, n_active_experts=2, moe_hidden_dim=32, first_k_dense=1, shared_expert_dim=32,
+    ),
+    None,
+    COMMON | {"xot.ffn", "xot.moe_router", "xot.moe_experts", "xot.moe_shared"},
+  ),
+}
+
+
+def _lowered(flavor: str):
+  """``decode.paged_batch`` for a tiny int8 model, lowered and not yet compiled."""
+  overrides, kv_quant, _ = CONFIGS[flavor]
+  cfg = tiny_test_config(**overrides)
+  params, shard = full_model_params(jax.random.PRNGKey(0), cfg)
+  params = quantize_params(params)
+  B, mp = 2, 128 // PS
+  pool = init_paged_pool(cfg, shard.n_shard_layers, 1 + B * mp, PS, quant=kv_quant)
+  bt = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
+  args = (
+    params, cfg, shard, jnp.ones((B, 1), jnp.int32), pool, bt, jnp.asarray([3, 5], jnp.int32), jnp.ones((B,), bool),
+    jnp.zeros((B,), jnp.float32), jnp.full((B,), 8, jnp.int32), 4, 8, PS, False, jax.random.PRNGKey(1), None,
+  )
+  return _fused_paged_batch_decode_impl.xot_jitted.lower(*args)
+
+
+def _scopes_in(text: str) -> set[str]:
+  return set(re.findall(r"xot\.[a-z_]+", " ".join(re.findall(r'op_name="([^"]*)"', text))))
+
+
+_METADATA = re.compile(r",? ?metadata=\{[^{}]*\}")
+_STACK_TABLES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames|\d+ [\"{].*)\n", re.M)
+
+
+_NUMBERED = re.compile(r"[A-Za-z_][\w-]*\.\d+")
+
+
+def _program(text: str) -> str:
+  """Optimised HLO text without what only describes its source: per-instruction
+  metadata, the stack-frame tables, and the serial numbers of instruction names
+  (renumbered in order of appearance: the scopes change how many instructions
+  the unoptimised module holds, hence every later serial, and nothing else)."""
+  seen: dict[str, str] = {}
+
+  def renumber(m):
+    stem = m.group(0).rsplit(".", 1)[0]
+    return seen.setdefault(m.group(0), f"{stem}.#{len(seen)}")
+
+  return _NUMBERED.sub(renumber, _STACK_TABLES.sub("", _METADATA.sub("", text)))
+
+
+@pytest.mark.parametrize("flavor", sorted(CONFIGS))
+def test_every_scope_reaches_the_compiled_decode_program(flavor):
+  text = _lowered(flavor).compile().as_text()
+  assert _scopes_in(text) >= CONFIGS[flavor][2], sorted(CONFIGS[flavor][2] - _scopes_in(text))
+  # nested: a dequantisation names its component first, so a reader can split it from the einsum beside it
+  assert re.search(r'op_name="[^"]*xot\.(attn_proj|ffn|moe_experts|moe_shared|head|attn)/[^"]*xot\.dequant', text)
+
+
+@pytest.mark.parametrize("flavor", sorted(CONFIGS))
+def test_scopes_do_not_change_the_optimised_program(flavor, monkeypatch):
+  with_scopes = _lowered(flavor).compile().as_text()
+  monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+  jax.clear_caches()  # the traced jaxpr carries the name stack
+  try:
+    lowered = _lowered(flavor)
+  finally:
+    monkeypatch.undo()
+    jax.clear_caches()
+  without = lowered.compile().as_text()
+  assert not _scopes_in(without)
+  assert _program(with_scopes) == _program(without)

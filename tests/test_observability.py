@@ -705,6 +705,8 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_requests_stalled_total",
   # Mixed prefill+decode ticks (ISSUE 14)
   "xot_tpu_sched_tick_prefill_tokens_total",
+  "xot_tpu_sched_ticks_total",  # one per program dispatch of the scheduler loop (ISSUE 24)
+  "xot_tpu_sched_phase_seconds_total",  # {phase}: host seconds of a tick by phase (ISSUE 24)
   # Disaggregated prefill/decode (ISSUE 10)
   "xot_tpu_kv_stream_pages_total",
   "xot_tpu_kv_stream_bytes_total",
@@ -788,7 +790,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_decode_step_seconds",
   # Device-program ledger (ISSUE 19; compile/device labeled {family})
   "xot_tpu_program_compile_seconds",
-  "xot_tpu_program_device_seconds",
+  "xot_tpu_program_dispatch_seconds",
   "xot_tpu_warmup_compile_seconds",
   # per-peer-link RPC attribution (ISSUE 4; labeled {peer,method} / {method})
   "xot_tpu_peer_rpc_seconds",
@@ -1066,6 +1068,27 @@ async def test_traces_endpoint_query_hardening():
     assert resp.status == 200
     spans = (await resp.json())["spans"]
     assert len(spans) <= tracer.spans.maxlen
+  finally:
+    await client.close()
+    await node.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("body,python_level", [({}, 0), ({"python_tracer": True}, 1), ({"python_tracer": "yes"}, 0)])
+async def test_profile_endpoint_python_tracer_is_off_unless_asked(tmp_path, monkeypatch, body, python_level):
+  """An operator's capture must not slow the host it measures: the python
+  tracer is off by default, the host tracer (the ``xot.*`` spans) at level 2."""
+  import jax.profiler
+
+  seen = []
+  monkeypatch.setattr(jax.profiler, "start_trace", lambda out_dir, profiler_options=None: seen.append(profiler_options))
+  monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+  node, api, client = await _dummy_api()
+  try:
+    resp = await client.post("/v1/profile", json={"duration_ms": 5, "dir": str(tmp_path / "prof"), **body})
+    assert resp.status == 200, await resp.text()
+    (opts,) = seen
+    assert (opts.python_tracer_level, opts.host_tracer_level) == (python_level, 2)
   finally:
     await client.close()
     await node.stop()

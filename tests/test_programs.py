@@ -468,12 +468,12 @@ def test_snapshot_is_json_safe_and_totaled():
 def test_merge_snapshots_sums_and_ands_steady():
   a = {
     "node_id": "n0", "steady": True,
-    "families": {"decode.batch": {"compiles": 2, "steady_compiles": 0, "dispatches": 10, "compile_s": 1.5, "device_s": 0.25, "xla_compile_s": 1.0, "signatures": ["int32[4,1]"]}},
+    "families": {"decode.batch": {"compiles": 2, "steady_compiles": 0, "dispatches": 10, "compile_s": 1.5, "dispatch_s": 0.25, "xla_compile_s": 1.0, "signatures": ["int32[4,1]"]}},
   }
   b = {
     "node_id": "n1", "steady": False,
     "families": {
-      "decode.batch": {"compiles": 1, "steady_compiles": 1, "dispatches": 4, "compile_s": 0.5, "device_s": 0.75, "xla_compile_s": 0.25, "signatures": ["int32[4,1]", "int32[8,1]"]},
+      "decode.batch": {"compiles": 1, "steady_compiles": 1, "dispatches": 4, "compile_s": 0.5, "dispatch_s": 0.75, "xla_compile_s": 0.25, "signatures": ["int32[4,1]", "int32[8,1]"]},
       "prefill.slots": {"compiles": 1, "dispatches": 2},
     },
   }
@@ -482,7 +482,7 @@ def test_merge_snapshots_sums_and_ands_steady():
   assert merged["steady"] is False  # steady only when EVERY node is
   db = merged["families"]["decode.batch"]
   assert db["compiles"] == 3 and db["dispatches"] == 14 and db["steady_compiles"] == 1
-  assert db["compile_s"] == 2.0 and db["device_s"] == 1.0
+  assert db["compile_s"] == 2.0 and db["dispatch_s"] == 1.0
   assert db["signatures"] == ["int32[4,1]", "int32[8,1]"]  # deduped
   assert merged["totals"]["dispatches"] == 16
   assert ProgramLedger.merge_snapshots([])["steady"] is False

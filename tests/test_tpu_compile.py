@@ -345,6 +345,37 @@ def test_compile_cache_default_dir_is_fixed_inside_the_checkout(tmp_path):
   assert first == second == [str(ROOT / ".xot_compile_cache")] * 2
 
 
+# One traced function under one component scope, compiled through the placed
+# cache: argv = blank lines before the function's source, the scope's name.
+_KEYED = """
+import os, sys, jax, jax.numpy as jnp
+from xotorch_support_jetson_tpu.utils.helpers import configure_compile_cache
+configure_compile_cache()
+src = "\\n" * int(sys.argv[1]) + "def f(x):\\n  with jax.named_scope('" + sys.argv[2] + "'):\\n    return jnp.dot(x, x) + 1\\n"
+ns = {"jax": jax, "jnp": jnp}
+exec(compile(src, "model_code.py", "exec"), ns)
+jax.jit(ns["f"])(jnp.ones((8, 8))).block_until_ready()
+print(sorted(n for n in os.listdir(os.environ["JAX_COMPILATION_CACHE_DIR"]) if n.startswith("jit_f-")))
+"""
+
+
+def test_compile_cache_key_holds_the_scope_names_and_no_source_lines(tmp_path):
+  """A cached executable carries the ``xot.*`` scope names of the code that
+  compiled it and the profiler reads them back, so an entry is never served
+  to code that names its ops otherwise; a line that only moves (every later
+  edit of a traced file) must find the entry again."""
+  env = {**os.environ, "PYTHONPATH": str(ROOT), "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")}
+
+  def entries(*argv: str) -> list[str]:
+    out = subprocess.run([sys.executable, "-c", _KEYED, *argv], capture_output=True, text=True, timeout=120, cwd=tmp_path, env=env, check=True).stdout
+    return eval(out.strip().splitlines()[-1])  # noqa: S307 — our own child's printed list
+
+  first = entries("0", "xot.attn")
+  assert len(first) == 1
+  assert entries("7", "xot.attn") == first  # the same code seven lines further down: a hit
+  assert len(entries("0", "xot.ffn")) == 2  # another scope name: its own entry
+
+
 # ------------------------------------------------------- chip_smoke.py
 
 

@@ -874,7 +874,10 @@ class ChatGPTAPI:
     {"steps": int} — a step capture runs until ``steps`` more decode chunks
     complete (the engine-wide ``decode_chunks_total`` counters advance) or
     the duration cap elapses. ``dir`` overrides the output directory
-    (default ``$XOT_TPU_PROFILE_DIR`` or XOT_HOME/profiles/<ts>). Guarded:
+    (default ``$XOT_TPU_PROFILE_DIR`` or XOT_HOME/profiles/<ts>). The
+    python tracer is off unless ``python_tracer`` is true: the program's own
+    ``xot.sched.*`` / ``xot.program:*`` spans (host tracer level 2) name the
+    host's time without slowing the host that is being measured. Guarded:
     one capture at a time (409), and a clean 503 no-op when the profiler is
     unavailable on this backend. Disable the endpoint entirely with
     XOT_TPU_PROFILE=0.
@@ -909,7 +912,10 @@ class ChatGPTAPI:
       import jax.profiler as jax_profiler
 
       Path(out_dir).mkdir(parents=True, exist_ok=True)
-      jax_profiler.start_trace(out_dir)
+      opts = jax_profiler.ProfileOptions()
+      opts.python_tracer_level = 1 if data.get("python_tracer") is True else 0
+      opts.host_tracer_level = 2
+      jax_profiler.start_trace(out_dir, profiler_options=opts)
     except Exception as e:  # noqa: BLE001 — profiler unavailable: no-op, not a crash
       return web.json_response({"detail": f"profiler unavailable: {e}"}, status=503)
     from ..orchestration.flightrec import flightrec

@@ -146,8 +146,10 @@ from __future__ import annotations
 import asyncio
 import os
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -172,6 +174,24 @@ PROPOSER_CODE = {"plain": 0, "ngram": 1, "model": 2}
 
 def _round_up(n: int, multiple: int) -> int:
   return ((n + multiple - 1) // multiple) * multiple
+
+
+@contextmanager
+def _phase(name: str, **args):
+  """One scheduler phase (admit | plan | stage | readback | settle) on two
+  clocks (ISSUE 24): a ``xot.sched.<name>`` span in the profiler's trace, where
+  it shares the device ops' clock and names the idle gap it overlaps, and the
+  always-on ``sched_phase_seconds_total{phase}`` (host ``perf_counter``; free of
+  the profiler, what an operator scrapes). A ``TraceAnnotation`` belongs to one
+  thread and must nest there: wrap synchronous sections only, never an ``await``.
+  ``stage`` on the executor thread runs to the end of the dispatch, so when a
+  dispatch compiles its seconds hold the compile (``program_compile_seconds``)."""
+  t0 = time.perf_counter()
+  try:
+    with jax.profiler.TraceAnnotation(f"xot.sched.{name}", **args):
+      yield
+  finally:
+    metrics.inc("sched_phase_seconds_total", time.perf_counter() - t0, labels={"phase": name})
 
 
 @dataclass
@@ -256,6 +276,7 @@ class _Chunk:
   starved: frozenset
   t_dispatch: float
   chained: bool  # dispatched on top of an in-flight chunk (device never idled)
+  tick: int = 0  # the scheduler tick that issued it (its ``xot.*`` spans and the ``decode_chunk`` stage carry the same number)
   # Batched speculation (ISSUE 7): variable-advance chunks. ``worst`` is the
   # chunk's worst-case per-row advance (== chunk for plain chunks) — what
   # the NEXT plan must assume while this chunk flies; ``counts``/``pos_dev``
@@ -434,6 +455,9 @@ class BatchedServer:
     # sched_host_gap_seconds (device-idle window a dispatch had to wait for
     # host work — 0 by construction for chained lookahead dispatches).
     self._t_last_ready: float | None = None
+    # Ticks issued: one per program dispatch of the loop (plain, mixed, spec
+    # or prefill group) — ``sched_ticks_total``, and the number its spans carry.
+    self._tick = 0
     # Graceful drain (ISSUE 8): once draining, submit() refuses new work
     # (typed "draining" 429) and the loop's next dispatch boundary offers
     # every resident row to the migration callback exactly once; rows the
@@ -932,16 +956,22 @@ class BatchedServer:
       for cls, depth in self.queue.class_depths().items():
         metrics.set_gauge("qos_queue_depth", depth, labels={"class": cls})
 
+  def _next_tick(self) -> int:
+    self._tick += 1
+    metrics.inc("sched_ticks_total")
+    return self._tick
+
   @staticmethod
-  def _attributed(run, request_ids):
+  def _attributed(run, request_ids, tick: int):
     """Wrap an executor ``run`` closure in the program-ledger dispatch
     context (ISSUE 19): a compile happens synchronously inside the jitted
     call on the executor thread, so a thread-local set here is visible to
     ``tracked_jit`` — a post-steady recompile can then name the request(s)
-    whose dispatch it stalled (flight ``compile`` event + timeline stage)."""
+    whose dispatch it stalled (flight ``compile`` event + timeline stage),
+    and the dispatch's ``xot.program:*`` span the tick that issued it."""
 
     def wrapped():
-      with dispatch_context(request_ids):
+      with dispatch_context(request_ids, tick=tick):
         return run()
 
     return wrapped
@@ -1286,8 +1316,14 @@ class BatchedServer:
     tracer.stage(req.request_id, "admitted", attrs)
 
   async def _admit_pending(self, woken: _Request | None = None) -> None:
+    with _phase("admit"):
+      ready = self._collect_admissions(woken)
+    if ready:
+      await self._dispatch(ready)
+
+  def _collect_admissions(self, woken: _Request | None) -> list[_Ready]:
     """Collect every admissible request — parked (page-starved) first, in
-    arrival order, then the queue — and prefill them in ONE batched dispatch
+    arrival order, then the queue — for ``_admit_pending`` to prefill in ONE batched dispatch
     (more only when the scatter-clamp grouping splits; see ``_dispatch``).
     ``woken`` is a request the idle wait already popped from the queue — it
     admits first. Every still-unmet parked request's page demand accumulates
@@ -1372,8 +1408,7 @@ class BatchedServer:
         else:
           still.append(r)
       ready = still
-    if ready:
-      await self._dispatch(ready)
+    return ready
 
   def _chunk_ready(self, r: _Ready) -> None:
     """Set this dispatch's padded span (the ONE source of pad_to), capping
@@ -1459,7 +1494,10 @@ class BatchedServer:
       kpad *= 2
     return max(min(kpad, self.n_slots), K)
 
-  async def _dispatch_group(self, group: list[_Ready], all_rows: set[int]) -> None:
+  def _stage_group(self, group: list[_Ready], all_rows: set[int], tick: int):
+    """Host operands of one prefill group and the ``run()`` closure that
+    stages them on the executor thread, dispatches the program and reads the
+    first tokens back."""
     eng = self.engine
     K = len(group)
     S_pad = max(r.pad_to for r in group)
@@ -1520,22 +1558,25 @@ class BatchedServer:
         # sampling in ONE device dispatch — same _next_token_batched math
         # on the same key, so the unfused path below is token-identical
         # (A/B-pinned; XOT_TPU_FUSED_SAMPLING=0 restores it).
-        if self.fused_sampling:
-          firsts, self.cache = self.ops.prefill_into_pages_many_sampled(
-            jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size,
-            temps, top_ks, self.k_max, sub, **lora_kw,
-          )
-          if draft_job is not None:
-            draft_job()
-          return np.asarray(firsts)
-        from ..models.decoder import sample_rows
+        with _phase("stage", tick=tick, rows=K):
+          if self.fused_sampling:
+            firsts, self.cache = self.ops.prefill_into_pages_many_sampled(
+              jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size,
+              temps, top_ks, self.k_max, sub, **lora_kw,
+            )
+            if draft_job is not None:
+              draft_job()
+          else:
+            from ..models.decoder import sample_rows
 
-        last, self.cache = self.ops.prefill_into_pages_many(
-          jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size, **lora_kw
-        )
-        if draft_job is not None:
-          draft_job()
-        return np.asarray(sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max))
+            last, self.cache = self.ops.prefill_into_pages_many(
+              jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size, **lora_kw
+            )
+            if draft_job is not None:
+              draft_job()
+            firsts = sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max)
+        with _phase("readback", tick=tick):  # the first tokens: waits for the prefill program
+          return np.asarray(firsts)
 
     else:
       rows = np.asarray([r.row for r in group] + spare[: n_rows - K], dtype=np.int32)
@@ -1546,29 +1587,39 @@ class BatchedServer:
       def run():
         # Prefill AND first-token sampling stay on the engine executor — the
         # single thread that serializes all device work.
-        if self.fused_sampling:
-          firsts, self.cache = self.ops.prefill_into_slots_sampled(
-            jnp.asarray(tok), self.cache, rows, prompt_lens, temps, top_ks, self.k_max, sub, **lora_kw,
-          )
-          if draft_job is not None:
-            draft_job()
-          return np.asarray(firsts)
-        from ..models.decoder import sample_rows
+        with _phase("stage", tick=tick, rows=K):
+          if self.fused_sampling:
+            firsts, self.cache = self.ops.prefill_into_slots_sampled(
+              jnp.asarray(tok), self.cache, rows, prompt_lens, temps, top_ks, self.k_max, sub, **lora_kw,
+            )
+            if draft_job is not None:
+              draft_job()
+          else:
+            from ..models.decoder import sample_rows
 
-        last, self.cache = self.ops.prefill_into_slots(jnp.asarray(tok), self.cache, rows, prompt_lens, **lora_kw)
-        if draft_job is not None:
-          draft_job()
-        return np.asarray(sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max))
+            last, self.cache = self.ops.prefill_into_slots(jnp.asarray(tok), self.cache, rows, prompt_lens, **lora_kw)
+            if draft_job is not None:
+              draft_job()
+            firsts = sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max)
+        with _phase("readback", tick=tick):  # the first tokens: waits for the prefill program
+          return np.asarray(firsts)
 
     # Stage marks go down BEFORE the dispatch so the timeline's
     # prefill_chunk duration covers the device work, not the gap after it.
     for r in group:
       end = r.chunk_end or int(r.req.tokens.shape[0])
       tracer.stage(r.req.request_id, "prefill_chunk", {"tokens": end - r.prefix_len, "batched_with": K - 1})
+    return run
+
+  async def _dispatch_group(self, group: list[_Ready], all_rows: set[int]) -> None:
+    eng = self.engine
+    tick = self._next_tick()
+    with _phase("stage", tick=tick, rows=len(group)):
+      run = self._stage_group(group, all_rows, tick)
     t_dispatch = time.perf_counter()
     try:
       firsts = await asyncio.get_event_loop().run_in_executor(
-        eng.executor, self._attributed(run, [r.req.request_id for r in group])
+        eng.executor, self._attributed(run, [r.req.request_id for r in group], tick)
       )
     except Exception as e:  # noqa: BLE001
       for r in group:
@@ -1584,20 +1635,21 @@ class BatchedServer:
       self._t_last_ready = time.perf_counter()
       for r in group:
         self._admitting.discard(r.req.request_id)
-    metrics.observe_hist("prefill_chunk_seconds", self._t_last_ready - t_dispatch)
-    metrics.inc("prefill_chunks_total")
-    for i, r in enumerate(group):
-      if r.chunk_end:  # intermediate chunk: advance and re-queue; no sample
-        r.prefix_len = r.chunk_end
-        if r.req.disagg_target and self.kv_stream is not None and self.paged:
-          # Disagg overlap (ISSUE 10): the chunk just written is final —
-          # stream its full pages to the decode node NOW, while the
-          # remaining prefill chunks still run, so the decode node's first
-          # token never waits for the whole context to cross the wire.
-          self._disagg_stream_chunk(r)
-        self._prefilling.append(r)
-        continue
-      self._finish_admission(r, int(firsts[i]))
+    with _phase("settle", tick=tick):
+      metrics.observe_hist("prefill_chunk_seconds", self._t_last_ready - t_dispatch)
+      metrics.inc("prefill_chunks_total")
+      for i, r in enumerate(group):
+        if r.chunk_end:  # intermediate chunk: advance and re-queue; no sample
+          r.prefix_len = r.chunk_end
+          if r.req.disagg_target and self.kv_stream is not None and self.paged:
+            # Disagg overlap (ISSUE 10): the chunk just written is final —
+            # stream its full pages to the decode node NOW, while the
+            # remaining prefill chunks still run, so the decode node's first
+            # token never waits for the whole context to cross the wire.
+            self._disagg_stream_chunk(r)
+          self._prefilling.append(r)
+          continue
+        self._finish_admission(r, int(firsts[i]))
 
   def _draft_prefill_job(self, group: list[_Ready]):
     """Host-side prep of the draft prefill that rides the SAME executor
@@ -2238,11 +2290,14 @@ class BatchedServer:
     if not s.req.future.done():
       s.req.future.set_exception(ServerOverloadedError("page pool exhausted with no runnable rows"))
 
-  async def _dispatch_decode(self, plan: _Plan, inflight: _Chunk | None) -> _Chunk:
-    """Dispatch one decode chunk and return its in-flight record WITHOUT
-    waiting for results: the executor call only enqueues the compiled
-    program plus the async device→host copy — the device runs while the
-    host loops back to settle the previous chunk.
+  def _stage_decode(self, plan: _Plan, inflight: _Chunk | None, tick: int):
+    """The synchronous half of one decode dispatch, on the event-loop thread:
+    builds the chunk's host operands and returns ``(run, request ids, the
+    host-side fields of its _Chunk)``. ``run()`` goes to the executor thread,
+    moves the operands to the device, enqueues the compiled program plus the
+    async device→host copy and returns its device handles WITHOUT waiting
+    for results — the device runs while the host loops back to settle the
+    previous chunk (``_dispatch_decode`` awaits it and makes the ``_Chunk``).
 
     ``plan.gmax > 0`` dispatches the SPEC program (``chunk`` draft/verify
     rounds, per-row depths from the slots, variable advance — ISSUE 7). A
@@ -2331,61 +2386,66 @@ class BatchedServer:
       metrics.observe_hist("sched_host_gap_seconds", 0.0 if inflight is not None else now - self._t_last_ready)
 
     def run():
-      counts = pos_dev = n_prop = None
-      # The draft cache rides the dispatch only when a MODEL-drafted row is
-      # in it (ISSUE 12): n-gram/plain-only chunks compile the draft-free
-      # program — no draft rounds, no donated draft cache (it stays valid
-      # for a later model re-probe; staleness only lowers that probe's
-      # acceptance, never correctness).
-      cd = self.draft_cache if (spec and use_draft) else None
-      pr = jnp.asarray(props_arr) if (spec and props_arr is not None) else None
-      pc = jnp.asarray(prop_counts) if (spec and prop_counts is not None) else None
-      if spec and self.paged:
-        toks, counts, n_prop, next_tok, pos_dev, self.cache, cd = self.ops.spec_paged_batch_decode(
-          jnp.asarray(tokens), self.cache, cd, jnp.asarray(self.block_tables), jnp.asarray(positions),
-          jnp.asarray(active), jnp.asarray(gammas), jnp.asarray(temps), self._h_top_ks, self.chunk, gmax,
-          k_max=self.k_max, page_size=self.page_size, key=sub, props=pr, prop_counts=pc, **lora_kw,
-        )
-      elif spec:
-        toks, counts, n_prop, next_tok, pos_dev, self.cache, cd = self.ops.spec_batch_decode(
-          jnp.asarray(tokens), self.cache, cd, jnp.asarray(positions), jnp.asarray(active),
-          jnp.asarray(gammas), jnp.asarray(temps), self._h_top_ks, self.chunk, gmax, k_max=self.k_max, key=sub,
-          props=pr, prop_counts=pc, **lora_kw,
-        )
-      elif pf_tokens is not None:
-        # Mixed tick: one dispatch advances every decode row by its chunk
-        # AND the staged admission's prefill by its budgeted slice (the
-        # slice carries ITS OWN adapter index — pf_adapter — so a mixed
-        # tick's prefill half applies the admission's adapter per-row too).
-        toks, next_tok, _pos, self.cache = self.ops.mixed_paged_batch_decode(
-          jnp.asarray(tokens), self.cache, jnp.asarray(self.block_tables), jnp.asarray(positions),
-          jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks), self.chunk,
-          k_max=self.k_max, page_size=self.page_size, key=sub,
-          pf_tokens=pf_tokens, pf_bt=pf_bt, pf_prefix=pf_prefix, pf_end=pf_end,
-          **({**lora_kw, "pf_adapter": np.asarray([getattr(mixed_r.req, "adapter_slot", 0)], np.int32)} if lora_kw else {}),
-        )
-      elif self.paged:
-        toks, next_tok, _pos, self.cache = self.ops.paged_batch_decode(
-          jnp.asarray(tokens), self.cache, jnp.asarray(self.block_tables), jnp.asarray(positions),
-          jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks), self.chunk,
-          k_max=self.k_max, page_size=self.page_size, key=sub, **lora_kw,
-        )
-      else:
-        toks, next_tok, _pos, self.cache = self.ops.batch_decode(
-          jnp.asarray(tokens), self.cache, jnp.asarray(positions), jnp.asarray(active),
-          jnp.asarray(temps), jnp.asarray(top_ks), self.chunk, k_max=self.k_max, key=sub, **lora_kw,
-        )
-      if spec and use_draft:
-        self.draft_cache = cd
-      try:
-        toks.copy_to_host_async()  # the readback overlaps the next chunk's compute
-        if counts is not None:
-          counts.copy_to_host_async()
-        if n_prop is not None:
-          n_prop.copy_to_host_async()
-      except AttributeError:  # backend without async copies
-        pass
-      return toks, next_tok, counts, pos_dev, n_prop
+      # ``stage`` on this thread runs to the end of the dispatch: the operands'
+      # transfers, the engine's own argument handling and the jitted call, which
+      # the nested ``xot.program:<family>`` span marks (measured, PR 24: the call
+      # is 0.5-1 ms of it, the engine's handling before it 1.4-2.7 ms).
+      with _phase("stage", tick=tick, rows=int(active.sum())):
+        counts = pos_dev = n_prop = None
+        # The draft cache rides the dispatch only when a MODEL-drafted row is
+        # in it (ISSUE 12): n-gram/plain-only chunks compile the draft-free
+        # program — no draft rounds, no donated draft cache (it stays valid
+        # for a later model re-probe; staleness only lowers that probe's
+        # acceptance, never correctness).
+        cd = self.draft_cache if (spec and use_draft) else None
+        pr = jnp.asarray(props_arr) if (spec and props_arr is not None) else None
+        pc = jnp.asarray(prop_counts) if (spec and prop_counts is not None) else None
+        if spec and self.paged:
+          toks, counts, n_prop, next_tok, pos_dev, self.cache, cd = self.ops.spec_paged_batch_decode(
+            jnp.asarray(tokens), self.cache, cd, jnp.asarray(self.block_tables), jnp.asarray(positions),
+            jnp.asarray(active), jnp.asarray(gammas), jnp.asarray(temps), self._h_top_ks, self.chunk, gmax,
+            k_max=self.k_max, page_size=self.page_size, key=sub, props=pr, prop_counts=pc, **lora_kw,
+          )
+        elif spec:
+          toks, counts, n_prop, next_tok, pos_dev, self.cache, cd = self.ops.spec_batch_decode(
+            jnp.asarray(tokens), self.cache, cd, jnp.asarray(positions), jnp.asarray(active),
+            jnp.asarray(gammas), jnp.asarray(temps), self._h_top_ks, self.chunk, gmax, k_max=self.k_max, key=sub,
+            props=pr, prop_counts=pc, **lora_kw,
+          )
+        elif pf_tokens is not None:
+          # Mixed tick: one dispatch advances every decode row by its chunk
+          # AND the staged admission's prefill by its budgeted slice (the
+          # slice carries ITS OWN adapter index — pf_adapter — so a mixed
+          # tick's prefill half applies the admission's adapter per-row too).
+          toks, next_tok, _pos, self.cache = self.ops.mixed_paged_batch_decode(
+            jnp.asarray(tokens), self.cache, jnp.asarray(self.block_tables), jnp.asarray(positions),
+            jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks), self.chunk,
+            k_max=self.k_max, page_size=self.page_size, key=sub,
+            pf_tokens=pf_tokens, pf_bt=pf_bt, pf_prefix=pf_prefix, pf_end=pf_end,
+            **({**lora_kw, "pf_adapter": np.asarray([getattr(mixed_r.req, "adapter_slot", 0)], np.int32)} if lora_kw else {}),
+          )
+        elif self.paged:
+          toks, next_tok, _pos, self.cache = self.ops.paged_batch_decode(
+            jnp.asarray(tokens), self.cache, jnp.asarray(self.block_tables), jnp.asarray(positions),
+            jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks), self.chunk,
+            k_max=self.k_max, page_size=self.page_size, key=sub, **lora_kw,
+          )
+        else:
+          toks, next_tok, _pos, self.cache = self.ops.batch_decode(
+            jnp.asarray(tokens), self.cache, jnp.asarray(positions), jnp.asarray(active),
+            jnp.asarray(temps), jnp.asarray(top_ks), self.chunk, k_max=self.k_max, key=sub, **lora_kw,
+          )
+        if spec and use_draft:
+          self.draft_cache = cd
+        try:
+          toks.copy_to_host_async()  # the readback overlaps the next chunk's compute
+          if counts is not None:
+            counts.copy_to_host_async()
+          if n_prop is not None:
+            n_prop.copy_to_host_async()
+        except AttributeError:  # backend without async copies
+          pass
+        return toks, next_tok, counts, pos_dev, n_prop
 
     if plan.starved:
       metrics.inc("scheduler_page_starved_total", len(plan.starved))
@@ -2393,16 +2453,22 @@ class BatchedServer:
     rids = [s.req.request_id for i, s in plan.rows if plan.active[i]]
     if mixed_r is not None:
       rids.append(mixed_r.req.request_id)
-    toks, next_tok, counts, pos_dev, n_prop = await asyncio.get_event_loop().run_in_executor(
-      eng.executor, self._attributed(run, rids)
-    )
-    return _Chunk(
-      toks=toks, next_tok=next_tok, rows=plan.rows, active=plan.active,
+    return run, rids, dict(
+      rows=plan.rows, active=plan.active,
       starved=frozenset(plan.starved), t_dispatch=t_dispatch, chained=inflight is not None,
-      spec=spec, worst=worst, rounds=self.chunk if spec else 0, counts=counts, pos_dev=pos_dev, gammas=gammas,
-      proposers=proposers, n_prop=n_prop,
+      spec=spec, worst=worst, rounds=self.chunk if spec else 0, gammas=gammas,
+      proposers=proposers,
       mixed_ready=mixed_r, mixed_start=m_start, mixed_end=m_end,
     )
+
+  async def _dispatch_decode(self, plan: _Plan, inflight: _Chunk | None) -> _Chunk:
+    tick = self._next_tick()
+    with _phase("stage", tick=tick, rows=int(plan.active.sum())):
+      run, rids, record = self._stage_decode(plan, inflight, tick)
+    toks, next_tok, counts, pos_dev, n_prop = await asyncio.get_event_loop().run_in_executor(
+      self.engine.executor, self._attributed(run, rids, tick)
+    )
+    return _Chunk(toks=toks, next_tok=next_tok, counts=counts, pos_dev=pos_dev, n_prop=n_prop, tick=tick, **record)
 
   def _note_spec_settle(self, row: int, slot: _Slot, record: _Chunk, avail: int, emitted: int, proposed: int) -> None:
     """Per-row spec-chunk bookkeeping at the settle: per-proposer acceptance
@@ -2435,7 +2501,7 @@ class BatchedServer:
     metrics.set_gauge("spec_proposer", PROPOSER_CODE[slot.spec_proposer], labels={"row": str(row)})
     tracer.stage(slot.req.request_id, "decode_chunk", {
       "tokens": emitted, "accepted": accepted, "gamma": g, "rounds": record.rounds, "proposer": prop,
-      "ewma": round(ewma, 4) if ewma is not None else None,
+      "ewma": round(ewma, 4) if ewma is not None else None, "tick": record.tick,
     })
 
   async def _settle(self, record: _Chunk) -> None:
@@ -2458,13 +2524,20 @@ class BatchedServer:
     eng = self.engine
 
     def fetch():
-      return (
-        np.asarray(record.toks),
-        np.asarray(record.counts) if record.counts is not None else None,
-        np.asarray(record.n_prop) if record.n_prop is not None else None,
-      )
+      with _phase("readback", tick=record.tick):  # waits for the chunk's program
+        return (
+          np.asarray(record.toks),
+          np.asarray(record.counts) if record.counts is not None else None,
+          np.asarray(record.n_prop) if record.n_prop is not None else None,
+        )
 
     rows_host, counts_host, n_prop_host = await asyncio.get_event_loop().run_in_executor(eng.executor, fetch)
+    with _phase("settle", tick=record.tick):
+      self._settle_host(record, rows_host, counts_host, n_prop_host)
+
+  def _settle_host(self, record: _Chunk, rows_host, counts_host, n_prop_host) -> None:
+    """The settle's host half, once the chunk's tokens are on the host: timing
+    attribution, the emit walk, finishes, page release."""
     t_ready = time.perf_counter()
     # Device-time attribution: while the pipeline is full the device runs
     # chunks back-to-back, so per-chunk device time is READY-TO-READY (==
@@ -2568,7 +2641,8 @@ class BatchedServer:
         # One mixed-budget verdict per loop iteration: the boundary gate,
         # the tick planner, and the admission sweep must agree within a
         # tick (and the policy read — gauge/histogram walk — runs once).
-        mixed_budget = self._mixed_budget() if (self._prefilling and self._mixed_active()) else None
+        with _phase("plan"):
+          mixed_budget = self._mixed_budget() if (self._prefilling and self._mixed_active()) else None
         if inflight is not None:
           # Membership changes happen only at dispatch boundaries: DRAIN the
           # pipeline whenever a waiting request could actually ADMIT —
@@ -2638,21 +2712,22 @@ class BatchedServer:
             await self._admit_pending(woken=req)
             continue
 
-        if mixed_budget is None and self._prefilling and self._mixed_active():
-          # The admission pass above just staged a prefill: pick up the
-          # verdict for this iteration's planner.
-          mixed_budget = self._mixed_budget()
-        mixed = self._mixed_intent(inflight, mixed_budget)
-        if mixed is not None:
-          # Spec rows fall back to plain chunks during a mixed tick (the
-          # mixed program composes with the PLAIN decode scan only); the
-          # settle semantics are exactly the existing spec↔plain switch —
-          # an in-flight spec chunk settles below before the mixed dispatch.
-          self._spec_props = None
-          self._spec_needs_host = False
-          gmax = 0
-        else:
-          gmax = self._spec_intent(inflight)
+        with _phase("plan"):
+          if mixed_budget is None and self._prefilling and self._mixed_active():
+            # The admission pass above just staged a prefill: pick up the
+            # verdict for this iteration's planner.
+            mixed_budget = self._mixed_budget()
+          mixed = self._mixed_intent(inflight, mixed_budget)
+          if mixed is not None:
+            # Spec rows fall back to plain chunks during a mixed tick (the
+            # mixed program composes with the PLAIN decode scan only); the
+            # settle semantics are exactly the existing spec↔plain switch —
+            # an in-flight spec chunk settles below before the mixed dispatch.
+            self._spec_props = None
+            self._spec_needs_host = False
+            gmax = 0
+          else:
+            gmax = self._spec_intent(inflight)
         if inflight is not None and (inflight.spec != (gmax > 0) or self._spec_needs_host):
           # Program-type switch (spec↔plain): a chained dispatch would need
           # the other program's chain contract (device positions vs host
@@ -2664,7 +2739,8 @@ class BatchedServer:
           await self._settle(inflight)
           inflight = None
           continue
-        plan = self._plan_chunk(inflight, gmax)
+        with _phase("plan"):
+          plan = self._plan_chunk(inflight, gmax)
         plan.mixed = mixed
         if inflight is not None and (not plan.rows or not plan.active.any()):
           # Nothing would step — a membership change is imminent (every row

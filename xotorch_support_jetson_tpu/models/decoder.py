@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..inference.shard import Shard
-from ..utils.programs import tracked_jit
+from ..utils.programs import component_scope, tracked_jit
 from ..ops.attention import gqa_attention
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope, apply_rope_interleaved, rope_attention_factor, rope_inv_freq
@@ -140,6 +140,7 @@ def init_kv_cache(cfg: ModelConfig, n_shard_layers: int, batch: int, max_seq: in
   return {"k": jnp.zeros(k_shape, dtype=dtype), "v": jnp.zeros(v_shape, dtype=dtype)}
 
 
+@component_scope("xot.kv_write")
 def _write_cache(cache: jnp.ndarray, new: jnp.ndarray, start: jnp.ndarray) -> jnp.ndarray:
   """cache [B,S,H,hd] ← new [B,Sn,H,hd] at per-row slot offsets start [B]."""
 
@@ -271,6 +272,7 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
 _MLA_NORM_EPS = 1e-6
 
 
+@component_scope("xot.attn_proj")
 def _mla_latents(x, p, cfg: ModelConfig, positions, inv_freq):
   """Multi-head latent attention projections (deepseek-v2/v3).
 
@@ -319,6 +321,7 @@ def _mla_w_kv_b(p, dtype):
   return w
 
 
+@component_scope("xot.attn_proj")
 def _mla_qkv(x, p, cfg: ModelConfig, positions, inv_freq):
   """Naive (non-absorbed) MLA q/k/v — the cache-less/training path."""
   B, S, D = x.shape
@@ -331,6 +334,7 @@ def _mla_qkv(x, p, cfg: ModelConfig, positions, inv_freq):
   return q, k, v
 
 
+@component_scope("xot.attn_proj")
 def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
   """Dense-attention q/k/v projections (+LoRA, qkv bias, rope applied).
 
@@ -394,7 +398,8 @@ def _attn_opts(cfg: ModelConfig, layer_sliding=None) -> dict:
 def _mlp_block(h, p, cfg: ModelConfig):
   """Post-attention norm + FFN (dense or MoE+shared-expert). Returns (h, aux)."""
   B, S, D = h.shape
-  x = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
+  with jax.named_scope("xot.ffn"):
+    x = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
   aux = jnp.float32(0.0)
   if "w_experts_gate" in p:  # routed MoE FFN (ops/moe.py) + optional shared expert
     from ..ops.moe import moe_ffn
@@ -411,12 +416,14 @@ def _mlp_block(h, p, cfg: ModelConfig):
       return w
 
     xt = x.reshape(B * S, D)
+    with jax.named_scope("xot.moe_experts"):  # the dequantised expert slabs are the experts' cost
+      w_gate, w_up, w_down = expert_w("w_experts_gate"), expert_w("w_experts_up"), expert_w("w_experts_down")
     out, aux = moe_ffn(
       xt,
       p["w_router"],
-      expert_w("w_experts_gate"),
-      expert_w("w_experts_up"),
-      expert_w("w_experts_down"),
+      w_gate,
+      w_up,
+      w_down,
       k=cfg.n_active_experts,
       scoring=cfg.router_scoring,
       norm_topk=cfg.norm_topk_prob,
@@ -429,18 +436,20 @@ def _mlp_block(h, p, cfg: ModelConfig):
       group_mode=cfg.group_mode,
     )
     if "w_shared_gate" in p:
-      shared = jax.nn.silu(_mm(xt, p, "w_shared_gate", cfg.quant_compute).astype(jnp.float32)).astype(h.dtype) * _mm(xt, p, "w_shared_up", cfg.quant_compute)
-      shared = _mm(shared, p, "w_shared_down", cfg.quant_compute)
-      if "w_shared_expert_gate" in p:  # qwen2-moe sigmoid-gated shared expert
-        shared = shared * jax.nn.sigmoid((xt @ p["w_shared_expert_gate"]).astype(jnp.float32)).astype(h.dtype)
-      out = out + shared
+      with jax.named_scope("xot.moe_shared"):
+        shared = jax.nn.silu(_mm(xt, p, "w_shared_gate", cfg.quant_compute).astype(jnp.float32)).astype(h.dtype) * _mm(xt, p, "w_shared_up", cfg.quant_compute)
+        shared = _mm(shared, p, "w_shared_down", cfg.quant_compute)
+        if "w_shared_expert_gate" in p:  # qwen2-moe sigmoid-gated shared expert
+          shared = shared * jax.nn.sigmoid((xt @ p["w_shared_expert_gate"]).astype(jnp.float32)).astype(h.dtype)
+        out = out + shared
     h = h + out.reshape(B, S, D)
   else:
-    gated = _mlp_act(_mm(x, p, "w_gate", cfg.quant_compute), cfg).astype(h.dtype) * _mm(x, p, "w_up", cfg.quant_compute)
-    out = _mm(gated, p, "w_down", cfg.quant_compute)
-    if "post_mlp_norm" in p:  # gemma2 post-feedforward layernorm
-      out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
-    h = h + out
+    with jax.named_scope("xot.ffn"):
+      gated = _mlp_act(_mm(x, p, "w_gate", cfg.quant_compute), cfg).astype(h.dtype) * _mm(x, p, "w_up", cfg.quant_compute)
+      out = _mm(gated, p, "w_down", cfg.quant_compute)
+      if "post_mlp_norm" in p:  # gemma2 post-feedforward layernorm
+        out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
+      h = h + out
   return h, aux
 
 
@@ -458,7 +467,8 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
   B, S, D = h.shape
   p = layer_params
 
-  x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+  with jax.named_scope("xot.attn_proj"):
+    x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
   if "wkv_a" in p and use_cache:
     # MLA with cache: write only the latent (+rope channel) and attend via
     # weight absorption (ops/attention.py mla_absorbed_attention) — the cache
@@ -471,12 +481,15 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
       "k": _write_cache(kv["k"], c_kv[:, :, None, :], start),
       "v": _write_cache(kv["v"], k_pe[:, :, None, :], start),
     }
+    with jax.named_scope("xot.attn"):  # the latent read and the absorbed up-projection are the core's operands
+      ckv, kpe = kv["k"][:, :, 0, :].astype(h.dtype), kv["v"][:, :, 0, :].astype(h.dtype)
+      w_kv_b = _mla_w_kv_b(p, h.dtype)
     attn = mla_absorbed_attention(
       q_nope,
       q_pe,
-      kv["k"][:, :, 0, :].astype(h.dtype),
-      kv["v"][:, :, 0, :].astype(h.dtype),
-      _mla_w_kv_b(p, h.dtype),
+      ckv,
+      kpe,
+      w_kv_b,
       positions,
       kv_positions,
       cfg.v_head_dim,
@@ -539,14 +552,16 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
       # ride through either path.
       attn = (attn_fn or gqa_attention)(q, k, v, positions, positions[0], **_attn_opts(cfg, p.get("is_sliding")))
 
-  attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
-  if "post_attn_norm" in p:  # gemma2 post-attention layernorm
-    attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-  h = h + attn_out
+  with jax.named_scope("xot.attn_proj"):
+    attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
+    if "post_attn_norm" in p:  # gemma2 post-attention layernorm
+      attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
+    h = h + attn_out
   h, aux = _mlp_block(h, p, cfg)
   return h, kv, aux
 
 
+@component_scope("xot.embed")
 def embed_tokens(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
   """Token ids [B,S] → embeddings [B,S,D] in model dtype."""
   h = jnp.take(params["embed"], x, axis=0).astype(cfg.dtype)
@@ -557,6 +572,7 @@ def embed_tokens(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarra
   return h
 
 
+@component_scope("xot.head")
 def head_logits(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
   """Final norm + LM head: hidden [B,S,D] → fp32 logits [B,S,V].
 
@@ -625,10 +641,13 @@ def shard_forward(
         h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, True, adapter_ids=adapter_ids)
         return h, kv
 
-      h, new_sub = jax.lax.scan(body, h, (stack, {key: val[off : off + L] for key, val in kv_cache.items()}))
+      with jax.named_scope("xot.kv_write"):  # a model of two stacks splits the cache per stack and joins it again: whole-cache copies
+        sub = {key: val[off : off + L] for key, val in kv_cache.items()}
+      h, new_sub = jax.lax.scan(body, h, (stack, sub))
       parts.append(new_sub)
       off += L
-    new_cache: Params | None = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
+    with jax.named_scope("xot.kv_write"):
+      new_cache: Params | None = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
   else:
 
     def body(carry, lp):
@@ -690,6 +709,7 @@ def shard_forward_aux(
   return h, a
 
 
+@component_scope("xot.sample")
 def _next_token(row, key, greedy: bool, temp, top_k: int):
   """greedy is STATIC (two compiled variants); temp is TRACED — client
   temperatures must not key the jit cache, or each distinct value would
@@ -1086,6 +1106,7 @@ def prefill_into_pages_many_sampled(params, cfg: ModelConfig, shard: Shard, toke
   return tok, pool
 
 
+@component_scope("xot.sample")
 def _next_token_batched(rows, key, temps, top_ks, k_max: int):
   """Per-row sampling: temp ≤ 0 rows greedy, others top-k at their own
   (traced) temperature and top_k (ops/sampling.py sample_logits_per_row)."""
@@ -1155,7 +1176,8 @@ def _paged_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cfg: Mode
   [B, 1]. Returns (h, pool_l).
   """
   B, S, D = h.shape
-  x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+  with jax.named_scope("xot.attn_proj"):
+    x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
   pos = positions[:, 0]
   lengths = pos + 1  # valid KV slots incl. the token written below
   from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref, write_token_kv
@@ -1165,7 +1187,9 @@ def _paged_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cfg: Mode
     q_nope, q_pe, c_kv, k_pe = _mla_latents(x, p, cfg, positions, inv_freq)
     k_pool = write_token_kv(pool_l["k"], c_kv[:, 0][:, None, :], block_tables, pos, page_size)
     v_pool = write_token_kv(pool_l["v"], k_pe[:, 0][:, None, :], block_tables, pos, page_size)
-    attn = paged_mla_attention_ref(q_nope, q_pe, k_pool.astype(h.dtype), v_pool.astype(h.dtype), block_tables, lengths, _mla_w_kv_b(p, h.dtype), cfg.v_head_dim, page_size)
+    with jax.named_scope("xot.attn"):  # the pool read and the absorbed up-projection are the core's operands
+      ckv_pool, kpe_pool, w_kv_b = k_pool.astype(h.dtype), v_pool.astype(h.dtype), _mla_w_kv_b(p, h.dtype)
+    attn = paged_mla_attention_ref(q_nope, q_pe, ckv_pool, kpe_pool, block_tables, lengths, w_kv_b, cfg.v_head_dim, page_size)
     pool_l = {"k": k_pool, "v": v_pool}
   else:
     q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
@@ -1205,10 +1229,11 @@ def _paged_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cfg: Mode
       else:
         attn = paged_gqa_attention_ref(q, k_pool.astype(h.dtype), v_pool.astype(h.dtype), block_tables, lengths, page_size, **_attn_opts(cfg, p.get("is_sliding")))
       pool_l = {"k": k_pool, "v": v_pool}
-  attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
-  if "post_attn_norm" in p:  # gemma2
-    attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-  h = h + attn_out
+  with jax.named_scope("xot.attn_proj"):
+    attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
+    if "post_attn_norm" in p:  # gemma2
+      attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
+    h = h + attn_out
   h, _ = _mlp_block(h, p, cfg)
   return h, pool_l
 
@@ -1232,10 +1257,13 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
       h, pool_l = _paged_layer_step(h, lp, pool_l, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids)
       return h, pool_l
 
-    h, new_sub = jax.lax.scan(body, h, (stack, {key: val[off : off + L] for key, val in pool.items()}))
+    with jax.named_scope("xot.kv_write"):  # a model of two stacks splits the pool per stack and joins it again: whole-pool copies
+      sub = {key: val[off : off + L] for key, val in pool.items()}
+    h, new_sub = jax.lax.scan(body, h, (stack, sub))
     parts.append(new_sub)
     off += L
-  new_pool = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
+  with jax.named_scope("xot.kv_write"):
+    new_pool = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
   return head_logits(params, cfg, h), new_pool
 
 
@@ -1416,7 +1444,8 @@ def _paged_window_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cf
   its plain chunks had. MLA is unsupported here (the scheduler keeps MLA
   models on the plain chunk program in paged mode)."""
   B, W, D = h.shape
-  x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+  with jax.named_scope("xot.attn_proj"):
+    x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
   from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref, write_token_kv
 
   q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
@@ -1467,10 +1496,11 @@ def _paged_window_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cf
       v_pool = write_token_kv(v_pool, v[:, j], block_tables, pos_j, page_size)
     attn = window_attn(k_pool, v_pool)
     pool_l = {"k": k_pool, "v": v_pool}
-  attn_out = _mm(attn.reshape(B, W, -1), p, "wo", cfg.quant_compute)
-  if "post_attn_norm" in p:  # gemma2
-    attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-  h = h + attn_out
+  with jax.named_scope("xot.attn_proj"):
+    attn_out = _mm(attn.reshape(B, W, -1), p, "wo", cfg.quant_compute)
+    if "post_attn_norm" in p:  # gemma2
+      attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
+    h = h + attn_out
   h, _ = _mlp_block(h, p, cfg)
   return h, pool_l
 
@@ -1497,10 +1527,13 @@ def paged_window_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
       h, pool_l = _paged_window_layer_step(h, lp, pool_l, block_tables, positions, inv_freq, cfg, page_size, use_kernel, interpret, adapter_ids)
       return h, pool_l
 
-    h, new_sub = jax.lax.scan(body, h, (stack, {key: val[off : off + L] for key, val in pool.items()}))
+    with jax.named_scope("xot.kv_write"):  # a model of two stacks splits the pool per stack and joins it again: whole-pool copies
+      sub = {key: val[off : off + L] for key, val in pool.items()}
+    h, new_sub = jax.lax.scan(body, h, (stack, sub))
     parts.append(new_sub)
     off += L
-  new_pool = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
+  with jax.named_scope("xot.kv_write"):
+    new_pool = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
   return head_logits(params, cfg, h), new_pool
 
 

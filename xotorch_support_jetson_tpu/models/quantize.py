@@ -25,6 +25,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..utils.programs import component_scope
+
 # Stacked weight leaves eligible for quantization (last two dims [in, out];
 # expert leaves carry extra leading axes) plus the top-level lm_head.
 # Norm gains, biases, routers, LoRA adapters and the embedding table stay in
@@ -142,6 +144,7 @@ def quantize_params(params: dict, mode: str = "int8") -> dict:
 # See ops/attention.py gqa_attention(k_scale=, v_scale=).
 
 
+@component_scope("xot.kv_write")
 def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
   """Symmetric per-(token, head) int8 for KV vectors.
 
@@ -176,6 +179,7 @@ def dequantize_kv(codes: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
 # unpacked back to int8 nibble values in [-8, 7].
 
 
+@component_scope("xot.kv_write")
 def quantize_kv_int4(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
   """Symmetric per-(token, head) int4, packed two nibbles per byte along hd.
 
@@ -230,8 +234,9 @@ def qdot(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray, compute: str = "w8a
     # mode — int8 decodes ~2x faster (BASELINE.md).
     xe = x[..., 0::2]
     xo = x[..., 1::2]
-    lo = ((w << 4) >> 4).astype(x.dtype)
-    hi = (w >> 4).astype(x.dtype)
+    with jax.named_scope("xot.dequant"):
+      lo = ((w << 4) >> 4).astype(x.dtype)
+      hi = (w >> 4).astype(x.dtype)
     dn = (((x.ndim - 1,), (0,)), ((), ()))
     acc = jax.lax.dot_general(xe, lo, dn, preferred_element_type=jnp.float32)
     acc = acc + jax.lax.dot_general(xo, hi, dn, preferred_element_type=jnp.float32)
@@ -243,7 +248,8 @@ def qdot(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray, compute: str = "w8a
     xq = jnp.round(xf / sx).astype(jnp.int8)
     acc = jax.lax.dot_general(xq, w, (((xq.ndim - 1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
     return (acc.astype(jnp.float32) * sx * scale.astype(jnp.float32)).astype(x.dtype)
-  up = w.astype(x.dtype)
+  with jax.named_scope("xot.dequant"):  # only the operand's conversion: where XLA does not fuse it into the dot it is its own op
+    up = w.astype(x.dtype)
   acc = jax.lax.dot_general(x, up, (((x.ndim - 1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
   return (acc * scale.astype(jnp.float32)).astype(x.dtype)
 
@@ -252,6 +258,7 @@ def is_quantized(p: dict, name: str) -> bool:
   return f"{name}_scale" in p
 
 
+@component_scope("xot.dequant")
 def dequantize_leaf(w: jnp.ndarray, scale: jnp.ndarray, in_dim: int, dtype) -> jnp.ndarray:
   """Materialize a quantized leaf (int8 OR packed int4, detected against the
   expected ``in_dim``) back to ``dtype`` — for the few sites that need the

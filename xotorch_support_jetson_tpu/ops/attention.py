@@ -18,6 +18,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..utils.programs import component_scope
+
 NEG_INF = -1e30
 
 
@@ -28,6 +30,7 @@ def kv_scale_to_scores(scale_leaf: jnp.ndarray) -> jnp.ndarray:
   return jnp.transpose(scale_leaf[..., 0], (0, 2, 1))[:, :, None, None, :]
 
 
+@component_scope("xot.attn")
 def gqa_attention(
   q: jnp.ndarray,  # [B, Sq, Hq, hd]
   k: jnp.ndarray,  # [B, Skv, Hkv, hd] (int8 codes when k_scale is given)
@@ -89,6 +92,7 @@ def cap_and_mask_scores(scores, q_positions, kv_positions, logit_softcap: float 
   return jnp.where(mask, scores, NEG_INF)
 
 
+@component_scope("xot.attn")
 def mla_absorbed_attention(
   q_nope: jnp.ndarray,  # [B, Sq, H, nope]
   q_pe: jnp.ndarray,  # [B, Sq, H, rope] (rope already applied)

@@ -105,17 +105,21 @@ def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, sel
   """One dispatch/compute/combine block over [T, D] tokens. Returns (out, aux)."""
   T, D = x.shape
   E = w_gate.shape[0]
-  logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
-  weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
-  C = expert_capacity(T, k, E, capacity_factor)
-  dispatch, combine = dispatch_combine_masks(idx, weights, E, C)
+  with jax.named_scope("xot.moe_router"):
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
+    C = expert_capacity(T, k, E, capacity_factor)
+    dispatch, combine = dispatch_combine_masks(idx, weights, E, C)
 
-  xin = jnp.einsum("td,tec->ecd", x, dispatch.astype(x.dtype))  # [E, C, D]
-  gated = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xin, w_gate).astype(jnp.float32)).astype(x.dtype)
-  up = jnp.einsum("ecd,edf->ecf", xin, w_up)
-  out = jnp.einsum("ecf,efd->ecd", gated * up, w_down)  # [E, C, D]
-  out = jnp.einsum("ecd,tec->td", out.astype(jnp.float32), combine).astype(x.dtype)
-  return out, load_balancing_loss(logits, idx, E)
+  with jax.named_scope("xot.moe_experts"):
+    xin = jnp.einsum("td,tec->ecd", x, dispatch.astype(x.dtype))  # [E, C, D]
+    gated = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xin, w_gate).astype(jnp.float32)).astype(x.dtype)
+    up = jnp.einsum("ecd,edf->ecf", xin, w_up)
+    out = jnp.einsum("ecf,efd->ecd", gated * up, w_down)  # [E, C, D]
+    out = jnp.einsum("ecd,tec->td", out.astype(jnp.float32), combine).astype(x.dtype)
+  with jax.named_scope("xot.moe_router"):
+    aux = load_balancing_loss(logits, idx, E)
+  return out, aux
 
 
 # Below this many tokens the gather path CAN replace the batched-einsum path:
@@ -141,17 +145,21 @@ def _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, se
   """
   T, D = x.shape
   E = w_gate.shape[0]
-  logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
-  weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
-  flat = idx.reshape(-1)  # [T·k]
-  g = jnp.take(w_gate, flat, axis=0).reshape(T, k, D, -1)
-  u = jnp.take(w_up, flat, axis=0).reshape(T, k, D, -1)
-  d = jnp.take(w_down, flat, axis=0).reshape(T, k, -1, D)
-  gated = jax.nn.silu(jnp.einsum("td,tjdf->tjf", x, g).astype(jnp.float32)).astype(x.dtype)
-  up = jnp.einsum("td,tjdf->tjf", x, u)
-  out_e = jnp.einsum("tjf,tjfd->tjd", gated * up, d)
-  out = jnp.einsum("tjd,tj->td", out_e.astype(jnp.float32), weights).astype(x.dtype)
-  return out, load_balancing_loss(logits, idx, E)
+  with jax.named_scope("xot.moe_router"):
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
+  with jax.named_scope("xot.moe_experts"):
+    flat = idx.reshape(-1)  # [T·k]
+    g = jnp.take(w_gate, flat, axis=0).reshape(T, k, D, -1)
+    u = jnp.take(w_up, flat, axis=0).reshape(T, k, D, -1)
+    d = jnp.take(w_down, flat, axis=0).reshape(T, k, -1, D)
+    gated = jax.nn.silu(jnp.einsum("td,tjdf->tjf", x, g).astype(jnp.float32)).astype(x.dtype)
+    up = jnp.einsum("td,tjdf->tjf", x, u)
+    out_e = jnp.einsum("tjf,tjfd->tjd", gated * up, d)
+    out = jnp.einsum("tjd,tj->td", out_e.astype(jnp.float32), weights).astype(x.dtype)
+  with jax.named_scope("xot.moe_router"):
+    aux = load_balancing_loss(logits, idx, E)
+  return out, aux
 
 
 def moe_ffn(

@@ -33,7 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..utils.programs import tracked_jit
+from ..utils.programs import component_scope, tracked_jit
 from .attention import NEG_INF, gqa_attention, mla_absorbed_attention
 
 DEFAULT_PAGE_SIZE = 64
@@ -75,6 +75,7 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   return {"k": jnp.zeros(k_shape, dtype=dtype), "v": jnp.zeros(v_shape, dtype=dtype)}
 
 
+@component_scope("xot.kv_write")
 def write_token_kv(pool_l: jnp.ndarray, new: jnp.ndarray, block_tables: jnp.ndarray, pos: jnp.ndarray, page_size: int) -> jnp.ndarray:
   """Scatter one decode step's KV into the pool (one layer).
 
@@ -98,6 +99,7 @@ def gather_pages(pool_l: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
   return jnp.swapaxes(g, 2, 3).reshape(B, mp * ps, Hkv, hd)
 
 
+@component_scope("xot.kv_write")
 def gather_row_pages(pool_part: jnp.ndarray, bt_rows: jnp.ndarray) -> jnp.ndarray:
   """All-layer per-row page gather: [L, P, H, slots, hd] × [K, mp] →
   position-ordered [L, K, mp·slots, H, hd].
@@ -122,6 +124,7 @@ def touched_page_targets(bt_rows: jnp.ndarray, prefix_lens: jnp.ndarray, prompt_
   return jnp.where(touched, bt_rows, 0)
 
 
+@component_scope("xot.kv_write")
 def scatter_row_pages(pool_part: jnp.ndarray, t: jnp.ndarray, target: jnp.ndarray) -> jnp.ndarray:
   """Inverse of ``gather_row_pages`` restricted to ``target`` pages:
   t [L, K, mp·slots, H, hd] scatters back into [L, P, H, slots, hd]."""
@@ -132,6 +135,7 @@ def scatter_row_pages(pool_part: jnp.ndarray, t: jnp.ndarray, target: jnp.ndarra
   return pool_part.at[:, target].set(pages.astype(pool_part.dtype))
 
 
+@component_scope("xot.attn")
 def paged_gqa_attention_ref(q, k_pool_l, v_pool_l, block_tables, lengths, page_size: int, k_scale_pool_l=None, v_scale_pool_l=None, q_positions=None, **attn_opts) -> jnp.ndarray:
   """Reference paged decode attention via gather (q [B, Sq, Hq, hd]; Sq is 1
   on the decode path). ``attn_opts`` forward gemma2's
@@ -159,6 +163,7 @@ def paged_gqa_attention_ref(q, k_pool_l, v_pool_l, block_tables, lengths, page_s
   return gqa_attention(q, k, v, q_positions, kv_positions, **attn_opts)
 
 
+@component_scope("xot.attn")
 def paged_mla_attention_ref(q_nope, q_pe, k_pool_l, v_pool_l, block_tables, lengths, w_kv_b, v_dim: int, page_size: int) -> jnp.ndarray:
   """Paged MLA decode attention: gather the latent pages, then the absorbed op."""
   ckv = gather_pages(k_pool_l, block_tables)[:, :, 0, :]  # [B, mp·ps, rank]
@@ -323,6 +328,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: f
     o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+@component_scope("xot.attn")
 def paged_decode_attention(
   q, k_pool_l, v_pool_l, block_tables, lengths, page_size: int,
   k_scale_pool_l=None, v_scale_pool_l=None, pages_per_step: int | None = None, interpret: bool = False,

@@ -22,7 +22,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from ..utils.programs import tracked_jit
+from ..utils.programs import component_scope, tracked_jit
 
 NEG_INF = -1e30
 BLOCK_Q = 128
@@ -100,6 +100,7 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
 
 
 @functools.partial(tracked_jit, "ops.flash_prefill", static_argnames=("interpret",))
+@component_scope("xot.attn")
 def flash_attention_prefill(q, k, v, q_offset=0, k_scale=None, v_scale=None, interpret: bool = False):
   """q [B,Sq,Hq,hd], k/v [B,Skv,Hkv,hd] → [B,Sq,Hq,hd].
 
@@ -248,6 +249,7 @@ def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, qb_ref, m_ref, l_r
 
 
 @functools.partial(tracked_jit, "ops.flash_decode", static_argnames=("interpret",))
+@component_scope("xot.attn")
 def flash_decode_attention(q, k, v, q_positions, interpret: bool = False):
   """One-token decode attention: q [B,1,Hq,hd], k/v [B,Skv,Hkv,hd] (slot-
   indexed cache, native layout), q_positions [B,1] → [B,1,Hq,hd].

@@ -73,6 +73,15 @@ def configure_compile_cache() -> str:
     # JAX keeps only programs that took over a second to compile; a cold
     # daemon also builds dozens of smaller ones, every start.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+  # A cached executable carries the ``op_name`` of every op as the code that
+  # compiled it named them, and JAX strips such metadata from the key: a profiler
+  # capture then names device ops by another version's ``xot.*`` component
+  # scopes, or by none (seen on the chip, PR 24: the parent commit's run traced
+  # with this one's scopes). So the names go into the key — and only the names:
+  # with no traceback in the locations a line that moves in a traced file changes
+  # no key (nor a Pallas kernel's serialized body), a renamed scope does.
+  jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+  jax.config.update("jax_traceback_in_locations_limit", 0)
   placed = os.getenv("JAX_COMPILATION_CACHE_DIR")
   if placed:
     return placed

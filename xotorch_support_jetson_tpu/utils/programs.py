@@ -13,11 +13,29 @@ module turns them into a gated measurement (ISSUE 19):
   count and captures the abstract shape signature that triggered the trace.
 - Per family the ledger records: compile count, ``program_compile_seconds``
   (wall time of the compiling dispatch: trace + lower + backend compile),
-  dispatch count, and ``program_device_seconds`` (wall time of steady
-  dispatches, attributing tick time across dense/paged/spec/mixed/LoRA
-  program variants). A ``jax.monitoring`` duration listener additionally
-  records the backend's own compile seconds into the ledger snapshot
-  (``xla_compile_s``).
+  dispatch count, and ``program_dispatch_seconds`` (host wall time of a
+  steady dispatch: JAX returns once the program is enqueued, so this is
+  never device time — the profiler's trace has that). A ``jax.monitoring``
+  duration listener additionally records the backend's own compile seconds
+  into the ledger snapshot (``xla_compile_s``).
+- **On the profiler's clock** (ISSUE 24): every top-level dispatch runs
+  under ``jax.profiler.TraceAnnotation("xot.program:<family>")`` carrying
+  the scheduler's tick number and row count (from :func:`dispatch_context`),
+  and the python body of a program being traced under
+  ``xot.trace:<family>``, so a capture shows which host span a device idle
+  gap fell into and tells a compiling dispatch from a steady one. Both cost
+  nothing while no capture runs.
+- **Component scopes** (:func:`component_scope`, ``jax.named_scope``): the
+  model code names its components in HLO metadata, one vocabulary for every
+  program — ``xot.embed``, ``xot.attn_proj`` (norm, q/k/v or latent
+  projections, rope, ``wo``), ``xot.kv_write`` (KV quantisation, cache and
+  page writes, the prefill's page gather/scatter, the split and join of the
+  cache between a model's two layer stacks), ``xot.attn`` (the
+  attention core), ``xot.ffn``, ``xot.moe_router`` / ``xot.moe_experts`` /
+  ``xot.moe_shared``, ``xot.dequant`` (nested: a weight conversion that XLA
+  did not fuse into its matmul), ``xot.head``, ``xot.sample``. The trace
+  reducer (benchmark/span_lib.py) reads them from each device op's
+  ``op_name``; they change no optimised program (tests/test_named_scopes.py).
 - **Warmup manifest**: the scheduler enumerates the program set expected for
   the active config; ``POST /v1/warmup`` pre-compiles it off the serving
   path and calls :meth:`ProgramLedger.mark_steady`.
@@ -40,10 +58,6 @@ Knobs:
 - ``XOT_TPU_PROGRAMS`` (default on) — ``0`` disables all recording at the
   dispatch wrapper; the jitted computation is byte-identical either way
   (poison-pinned in tests/test_programs.py).
-- ``XOT_TPU_PROGRAMS_BLOCK`` (default off) — ``1`` makes the dispatch
-  wrapper ``block_until_ready`` so ``program_device_seconds`` is device
-  time, not async-dispatch wall time. Off the serving path only: blocking
-  defeats the scheduler's dispatch pipelining.
 - ``XOT_TPU_ANOMALY_RECOMPILE_WINDOW_S`` / ``XOT_TPU_ANOMALY_RECOMPILES``
   (orchestration/flightrec.py) — the storm rule's window and threshold.
 """
@@ -64,8 +78,22 @@ def programs_enabled() -> bool:
   return os.getenv("XOT_TPU_PROGRAMS", "1") not in ("0", "false")
 
 
-def _blocking_enabled() -> bool:
-  return os.getenv("XOT_TPU_PROGRAMS_BLOCK", "0") in ("1", "true")
+def component_scope(name: str):
+  """Decorator: trace ``fn`` under ``jax.named_scope(name)``. A scope of its
+  own per call — ``jax.named_scope`` used as a decorator shares one context
+  object between threads that trace the same function at once."""
+
+  def deco(fn):
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+      import jax
+
+      with jax.named_scope(name):
+        return fn(*args, **kwargs)
+
+    return scoped
+
+  return deco
 
 
 def _describe_one(x) -> str:
@@ -126,7 +154,7 @@ class ProgramLedger:
         "steady_compiles": 0,
         "dispatches": 0,
         "compile_s": 0.0,
-        "device_s": 0.0,
+        "dispatch_s": 0.0,
         "xla_compile_s": 0.0,
         "signatures": [],
         "last_compile_ts": None,
@@ -228,7 +256,7 @@ class ProgramLedger:
           "steady_compiles": st["steady_compiles"],
           "dispatches": st["dispatches"],
           "compile_s": round(st["compile_s"], 6),
-          "device_s": round(st["device_s"], 6),
+          "dispatch_s": round(st["dispatch_s"], 6),
           "xla_compile_s": round(st["xla_compile_s"], 6),
           "signatures": list(st["signatures"]),
           "last_compile_ts": st["last_compile_ts"],
@@ -259,11 +287,11 @@ class ProgramLedger:
       nodes.append(p.get("node_id"))
       for f, st in (p.get("families") or {}).items():
         agg = fams.setdefault(
-          f, {"compiles": 0, "steady_compiles": 0, "dispatches": 0, "compile_s": 0.0, "device_s": 0.0, "xla_compile_s": 0.0, "signatures": []}
+          f, {"compiles": 0, "steady_compiles": 0, "dispatches": 0, "compile_s": 0.0, "dispatch_s": 0.0, "xla_compile_s": 0.0, "signatures": []}
         )
         for k in ("compiles", "steady_compiles", "dispatches"):
           agg[k] += int(st.get(k, 0))
-        for k in ("compile_s", "device_s", "xla_compile_s"):
+        for k in ("compile_s", "dispatch_s", "xla_compile_s"):
           agg[k] = round(agg[k] + float(st.get(k, 0.0)), 6)
         for sig in st.get("signatures", []):
           if sig not in agg["signatures"] and len(agg["signatures"]) < ProgramLedger.MAX_SIGNATURES:
@@ -308,15 +336,15 @@ class ProgramLedger:
       # program is tracing. The inner trace hook has already counted this
       # family's build; don't double-record a dispatch.
       return jitted(*args, **kwargs)
+    import jax
+
     self._tls.depth = 1
     self._tls.traced = traced = []
+    ctx = current_dispatch_context() or {}
     t0 = time.perf_counter()
     try:
-      out = jitted(*args, **kwargs)
-      if _blocking_enabled():
-        import jax
-
-        jax.block_until_ready(out)
+      with jax.profiler.TraceAnnotation(f"xot.program:{family}", tick=ctx.get("tick", -1), rows=len(ctx.get("request_ids") or ())):
+        out = jitted(*args, **kwargs)
     finally:
       self._tls.depth = 0
       self._tls.traced = None
@@ -329,14 +357,14 @@ class ProgramLedger:
       if traced:
         st["compile_s"] += dt
       else:
-        st["device_s"] += dt
+        st["dispatch_s"] += dt
     metrics.inc("program_dispatch_total", labels={"family": family})
     if traced:
       metrics.observe_hist("program_compile_seconds", dt, labels={"family": family})
       if self._steady:
         self._steady_compile_sentinel(family, traced, dt)
     else:
-      metrics.observe_hist("program_device_seconds", dt, labels={"family": family})
+      metrics.observe_hist("program_dispatch_seconds", dt, labels={"family": family})
     return out
 
   def _steady_compile_sentinel(self, family: str, traced: list, seconds: float) -> None:
@@ -385,12 +413,13 @@ _DISPATCH_TLS = threading.local()
 
 
 @contextmanager
-def dispatch_context(request_ids, node: str | None = None):
+def dispatch_context(request_ids, node: str | None = None, tick: int = -1):
   """Scheduler-side attribution: set inside the executor-thread ``run()``
   closure around device dispatches, so a compile triggered by that dispatch
-  can name the request(s) it stalled."""
+  can name the request(s) it stalled, and the dispatch's ``xot.program:*``
+  span the scheduler tick that issued it."""
   prev = getattr(_DISPATCH_TLS, "ctx", None)
-  _DISPATCH_TLS.ctx = {"request_ids": [r for r in (request_ids or []) if r], "node": node}
+  _DISPATCH_TLS.ctx = {"request_ids": [r for r in (request_ids or []) if r], "node": node, "tick": tick}
   try:
     yield
   finally:
@@ -449,7 +478,8 @@ def tracked_jit(family: str, fn=None, **jit_kwargs):
   @functools.wraps(fn)
   def _traced(*args, **kwargs):
     ledger._on_trace(family, args, kwargs)
-    return fn(*args, **kwargs)
+    with jax.profiler.TraceAnnotation(f"xot.trace:{family}"):
+      return fn(*args, **kwargs)
 
   jitted = jax.jit(_traced, **jit_kwargs)
 
