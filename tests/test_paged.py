@@ -56,8 +56,8 @@ def test_paged_kernel_matches_gather_reference():
 
 @pytest.mark.parametrize("pages_per_step", [1, 2, 4])
 def test_paged_kernel_page_tile_geometry_matches_reference(pages_per_step):
-  """Every page-tile width (including tiles that do not divide mp — trailing
-  slots clamp to the last valid page and mask) gives the same output as the
+  """Every page-tile width (including tiles that do not divide mp — a row's
+  last tile holds only the pages the row has) gives the same output as the
   single-page gather reference."""
   rng = np.random.default_rng(5)
   B, Hq, Hkv, hd, ps, P = 2, 4, 2, 64, 8, 16
@@ -92,6 +92,87 @@ def test_paged_kernel_int8kv_dequant_matches_gather_reference():
     paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool_l=ks, interpret=True)
 
 
+def _kernel_case_pools(rng, quant: str, P: int, Hkv: int, ps: int, hd: int):
+  """(k, v, scale kwargs) of a page pool in one of the three stored forms."""
+  if quant == "":
+    return jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.bfloat16), jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.bfloat16), {}
+  if quant == "int8":
+    codes = lambda: jnp.asarray(rng.integers(-127, 128, size=(P, Hkv, ps, hd)), jnp.int8)  # noqa: E731
+    scales = lambda: jnp.asarray(rng.uniform(0.005, 0.02, size=(P, Hkv, ps, 1)), jnp.float32)  # noqa: E731
+    return codes(), codes(), {"k_scale_pool_l": scales(), "v_scale_pool_l": scales()}
+  from xotorch_support_jetson_tpu.models.quantize import quantize_kv_int4
+
+  kp, ks = quantize_kv_int4(jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.float32))
+  vp, vs = quantize_kv_int4(jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.float32))
+  return kp, vp, {"k_scale_pool_l": ks, "v_scale_pool_l": vs}
+
+
+_RAGGED_PS, _RAGGED_MP, _RAGGED_TILE = 8, 6, 2
+_RAGGED_ROWS = {  # the middle row of three; its neighbours hold ordinary contexts
+  "empty": 0,  # does nothing, and the row after it fetches its own first tile
+  "one_token": 1,
+  "page_boundary": 2 * _RAGGED_PS,  # the last page is full: no page after it is touched
+  "partial_tile": 3 * _RAGGED_PS - 2,  # not a multiple of tile × page_size: the last tile holds one page
+  "all_pages": _RAGGED_MP * _RAGGED_PS,  # the whole block table
+}
+
+
+@pytest.mark.parametrize("row", list(_RAGGED_ROWS))
+@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+def test_paged_kernel_ragged_rows_match_reference(quant, row):
+  """The kernel's loop is bounded by each row's own length: rows of very
+  different occupancy in one batch, for every stored form of the pool,
+  equal the gather reference; a row of length 0 comes back as zeros."""
+  rng = np.random.default_rng(41)
+  B, Hq, Hkv, hd, ps, mp, P = 3, 4, 2, 64, _RAGGED_PS, _RAGGED_MP, 24
+  q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
+  kp, vp, scales = _kernel_case_pools(rng, quant, P, Hkv, ps, hd)
+  bt = jnp.asarray(1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp))
+  lens = np.asarray([ps + 3, _RAGGED_ROWS[row], 4 * ps - 1], np.int32)
+  ker = np.asarray(paged_decode_attention(q, kp, vp, bt, jnp.asarray(lens), ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales))
+  ref = np.asarray(paged_gqa_attention_ref(q[:, None], kp, vp, bt, jnp.asarray(np.maximum(lens, 1)), ps, **scales)[:, 0])
+  live = lens > 0
+  assert np.allclose(ker[live], ref[live], atol=2e-5), np.abs(ker[live] - ref[live]).max()
+  assert not ker[~live].any()
+
+
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (6, 2), (8, 1)])
+def test_paged_kernel_head_groupings_match_reference(hq, hkv):
+  """The kernel stacks every query head's scores for one softmax update and
+  hands each kv head its own group's rows: MHA (group 1), a group that is
+  not a power of two, and MQA equal the gather reference."""
+  rng = np.random.default_rng(47)
+  B, hd, ps, mp, P = 2, 64, _RAGGED_PS, _RAGGED_MP, 16
+  q = jnp.asarray(rng.normal(size=(B, hq, hd)), jnp.float32)
+  kp, vp, scales = _kernel_case_pools(rng, "int8", P, hkv, ps, hd)
+  bt = jnp.asarray(1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp))
+  lens = jnp.asarray([3 * ps + 5, ps - 1], jnp.int32)
+  ker = paged_decode_attention(q, kp, vp, bt, lens, ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales)
+  ref = paged_gqa_attention_ref(q[:, None], kp, vp, bt, lens, ps, **scales)[:, 0]
+  assert jnp.allclose(ker, ref, atol=2e-5), jnp.abs(ker - ref).max()
+
+
+@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+def test_paged_kernel_never_reads_past_a_rows_length(quant):
+  """Block-table entries past a row's length point at a page whose codes
+  and scales are poison (NaN where the dtype has one): nothing changes,
+  because neither the entry nor the page is ever fetched."""
+  rng = np.random.default_rng(43)
+  B, Hq, Hkv, hd, ps, mp, P = 3, 4, 2, 64, _RAGGED_PS, _RAGGED_MP, 24
+  q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
+  kp, vp, scales = _kernel_case_pools(rng, quant, P, Hkv, ps, hd)
+  poison = P - 1
+  bad = lambda x: x.at[poison].set(jnp.nan if jnp.issubdtype(x.dtype, jnp.floating) else 127)  # noqa: E731
+  kp, vp, scales = bad(kp), bad(vp), {name: bad(x) for name, x in scales.items()}
+  lens = np.asarray([ps + 3, 0, 2 * ps], np.int32)  # a partial page, an empty row, a full last page
+  clean = 1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp)
+  held = np.arange(mp)[None, :] * ps < lens[:, None]
+  run = lambda table: np.asarray(paged_decode_attention(q, kp, vp, jnp.asarray(table), jnp.asarray(lens), ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales))  # noqa: E731
+  got = run(np.where(held, clean, poison).astype(np.int32))
+  assert np.isfinite(got).all()
+  assert np.array_equal(got, run(clean))
+
+
 def test_decode_path_dispatch_table(monkeypatch):
   """Representative (batch, context, quant) points hit the measured winners;
   the env override forces either in-program path. Retuned in round 15
@@ -116,7 +197,7 @@ def test_decode_path_dispatch_table(monkeypatch):
       for ctx in (1024, 4096, 32768):
         assert select_decode_path(b, ctx, quant, platform="tpu") == "kernel", (b, ctx, quant)
   assert select_decode_path(8, 4096, "int8", platform="tpu") == "kernel"  # r15 retune: was gather
-  # Long contexts: the kernel's clamped-DMA design target, any quant.
+  # Long contexts: the kernel reads resident pages only, any quant.
   assert select_decode_path(8, 32768, "", platform="tpu") == "kernel"
   assert select_decode_path(16, 8192, "int8", platform="tpu") == "kernel"
   # int4 has no dense layout: no (batch, ctx) point may ever say "dense".

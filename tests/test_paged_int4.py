@@ -168,7 +168,7 @@ def test_page_tile_dispatch_table(monkeypatch):
   assert select_page_tile(16, 1024, "") == 4
   assert select_page_tile(16, 4096, "int8") == 8
   assert select_page_tile(8, 1024, "int4") == 8
-  # The dense-knee bucket and beyond: wider tiles cut sequential grid steps.
+  # The dense-knee bucket and beyond (wider tiles; since PR 25 no width from 4 up is faster, PERF.md §6).
   assert select_page_tile(48, 1024, "int8") == 8
   assert select_page_tile(48, 32768, "") == 8
   assert select_page_tile(96, 1024, "int8") == 16

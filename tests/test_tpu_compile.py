@@ -17,6 +17,7 @@ hand the jitted kernels and steps their shapes (and ``use_kernel``) directly.
 
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -80,28 +81,43 @@ def _compile(tracked, *args, **kwargs):
   return compiled, compiled.as_text()
 
 
-# Every (quant, tile) the tables can pick: _PAGE_TILE_TABLE answers G=4/8/16
-# at B=16/48/96; G=16 quantized streams 64 block-spec'd operands per step.
-@pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("batch,tile", [(16, 4), (48, 8), (96, 16)])
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
-def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, tile, hd):
-  from xotorch_support_jetson_tpu.inference.paging import _PAGE_TILE_TABLE
+def _compile_paged_kernel(chip, quant: str, batch: int, tile: int, hd: int, mp: int, n_pages: int, hq: int = HQ) -> str:
   from xotorch_support_jetson_tpu.ops.paged import _paged_decode_attention_impl
 
-  assert tile in {row[-1] for row in _PAGE_TILE_TABLE}
-  mp = 16  # 1k context
-  n_pages = batch * mp + 1
   kd = hd // 2 if quant == "int4" else hd
   code = jnp.int8 if quant else jnp.bfloat16
   pool = _sds(chip, (n_pages, HKV, PS, kd), code)
   scale = _sds(chip, (n_pages, HKV, PS, 1), jnp.float32) if quant else None
   _, text = _compile(
     _paged_decode_attention_impl,
-    _sds(chip, (batch, HQ, hd), jnp.bfloat16), pool, pool, _sds(chip, (batch, mp), jnp.int32), _sds(chip, (batch,), jnp.int32), scale, scale,
+    _sds(chip, (batch, hq, hd), jnp.bfloat16), pool, pool, _sds(chip, (batch, mp), jnp.int32), _sds(chip, (batch,), jnp.int32), scale, scale,
     page_size=PS, pages_per_step=tile, kv_quant=quant, interpret=False,
   )  # fmt: skip
-  assert "tpu_custom_call" in text
+  return text
+
+
+# Every (quant, tile) the tables can pick: _PAGE_TILE_TABLE answers G=4/8/16
+# at B=16/48/96. A tile is two VMEM slots of G whole pages (all kv heads).
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("batch,tile", [(16, 4), (48, 8), (96, 16)])
+@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, tile, hd):
+  from xotorch_support_jetson_tpu.inference.paging import _PAGE_TILE_TABLE
+
+  assert tile in {row[-1] for row in _PAGE_TILE_TABLE}
+  mp = 16  # 1k context
+  assert "tpu_custom_call" in _compile_paged_kernel(chip, quant, batch, tile, hd, mp, batch * mp + 1)
+
+
+@pytest.mark.parametrize(
+  "quant,batch,tile,hd,mp,n_pages,hq",
+  [
+    pytest.param("int8", 16, 8, 128, 64, 257, 32, id="mistral-7b-served"),  # the benchmark's cell: 32/8 heads, 4096-token window, 257 pages
+    pytest.param("", 96, 16, 256, 128, 1025, 16, id="hd256-bf16-widest-tile"),  # 16 MiB of tile buffers: past the default scoped VMEM
+  ],
+)
+def test_paged_decode_kernel_compiles_at_served_shapes(chip, quant, batch, tile, hd, mp, n_pages, hq):
+  assert "tpu_custom_call" in _compile_paged_kernel(chip, quant, batch, tile, hd, mp, n_pages, hq)
 
 
 @pytest.mark.parametrize("quant", ["", "int8"])
@@ -183,6 +199,10 @@ def test_paged_batch_step_at_smoke_settings_fits_v5e(chip, llama_1b):
 
   compiled, text = _compile(_fused_paged_batch_decode_impl, *_decode_step_args(chip, llama_1b, 16, "int8"))
   assert "tpu_custom_call" in text, "the step must hold the Pallas paged kernel, not the gather reference"
+  # The kernel takes the scales with tokens on lanes. Asked for as the pool stores them, [P, Hkv, ps, 1]
+  # row-major, each leaf was copied every layer into a layout that pads the trailing 1 to 128 lanes.
+  padded_scale_copy = re.search(rf"f32\[\d+,{HKV},{PS},1\]\{{3,2,1,0[^}}]*\}} copy\(", text)
+  assert padded_scale_copy is None, padded_scale_copy.group(0)
   mem = compiled.memory_analysis()
   print(f"decode.paged_batch B=16 int8: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.argument_size_in_bytes < 16 * 1024**3

@@ -134,18 +134,14 @@ def select_decode_path(batch: int, context: int, kv_quant: str = "", platform: s
 
 # ------------------------------------------------- page-tile dispatch table
 #
-# How many pages the paged kernel fetches per grid step (ops/paged.py G).
-# The old default (G=4, env-capped) was tuned at B=16×1K and applied to
-# every shape; the r6 sweep at the shapes the scheduler actually dispatches
-# showed the winner is shape-dependent: the kernel's innermost grid axis
-# runs ceil(mp/G) sequential steps per (row, kv-head), so at high batch —
-# where per-(row, head) programs multiply and each row's context share of
-# the pool shrinks — a wider tile amortizes the per-step scalar-prefetch
-# and DMA-issue overhead that G=4 left on the table (B=48/96 retune), while
-# at small batch the extra operand streams beyond G=4 stop paying (the
-# original v5e observation, re-held). Quant mode rides the verdict because
-# int8/int4 tiles are 1x/0.5x the DMA bytes of bf16: halved page bytes make
-# the wider tile profitable one batch bucket earlier.
+# How many pages the paged kernel fetches and computes per loop iteration
+# (ops/paged.py G). The values date from the kernel whose grid walked the
+# whole block table, ceil(mp/G) sequential steps per (row, kv-head), where a
+# wider tile amortized per-step overhead. The kernel now loops over each
+# row's resident pages with one DMA per page, and the PR 25 sweep on the
+# chip read 4, 8, 16 and 32 within 3 % at every shape tried, 1 some 7-12 %
+# slower (PERF.md §6): the table decides VMEM use (two slots of G pages),
+# hardly speed, and is a candidate for removal.
 #
 # Rows are (max_batch, max_context_tokens, kv_quant, pages_per_step);
 # None = any; first row whose bounds cover the query wins. The kernel
@@ -154,15 +150,15 @@ def select_decode_path(batch: int, context: int, kv_quant: str = "", platform: s
 # sweep knob).
 
 _PAGE_TILE_TABLE = (
-  (16, 8192, "", 4),  # small-batch bf16: beyond 4 the operand streams stop paying (r2 tune)
-  (16, 8192, None, 8),  # small-batch quantized pages: half the DMA bytes/tile — one bucket wider
-  (48, None, None, 8),  # the dense-knee bucket: 2x fewer sequential steps per (row, head) (r6)
-  (None, None, None, 16),  # B>48 or very long ctx: step count dominates; widest tile wins
+  (16, 8192, "", 4),  # small-batch bf16
+  (16, 8192, None, 8),  # small-batch quantized pages
+  (48, None, None, 8),  # the dense-knee bucket
+  (None, None, None, 16),  # B>48 or very long ctx
 )
 
 
 def select_page_tile(batch: int, context: int, kv_quant: str = "") -> int:
-  """Pages-per-grid-step verdict for a (batch, context, quant) point.
+  """Pages-per-loop-iteration verdict for a (batch, context, quant) point.
 
   The raw table verdict — the kernel (ops/paged.py ``_page_tile``) clamps it
   to a power of two <= mp and applies the ``XOT_TPU_PAGED_TILE`` force-cap.

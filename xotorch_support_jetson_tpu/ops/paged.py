@@ -13,13 +13,15 @@ No reference counterpart: the reference's torch engine has a dense per-request
 cache (``SURVEY.md §5.7`` marks long-context serving greenfield). The design
 target is TPU: static shapes everywhere (the block table is a traced [B, mp]
 int32 operand — one compiled program for every allocation state), and decode
-attention reads pages through a Pallas kernel whose block-table indirection
-rides scalar prefetch, clamped so out-of-range grid steps re-fetch the same
-page (no DMA) instead of touching unallocated memory.
+attention reads pages through a Pallas kernel whose work follows each row's
+resident pages: the block table and the lengths ride scalar prefetch, a
+loop bounded by the row's own length fetches its pages from HBM by DMA,
+double-buffered, and nothing past a row's length is read. What a call costs
+is set by the tokens it attends, not by the table's width or the pool's size.
 
 Pool layout: ``[L, P, Hkv, ps, hd]`` — one logical page id addresses the same
-page index in every layer, and the per-(page, head) ``[ps, hd]`` tile is
-contiguous for the kernel's DMA.
+page index in every layer, and a page's ``[Hkv, ps, hd]`` block is contiguous:
+one DMA brings it for every kv head.
 
 Page 0 is reserved as a trash page: gathers of unallocated block-table entries
 read it (positionally masked anyway) and masked scatters dump there, which
@@ -175,29 +177,33 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool_l, v_pool_l, block_tables, leng
 
 # ------------------------------------------------- Pallas paged decode kernel
 #
-# One-token-per-row decode attention straight off the page pool. Split-K
-# flash-decode over pages: grid (B, Hkv, ceil(mp/G)) — the innermost axis
-# runs sequentially per (row, kv-head) carrying online-softmax state in VMEM
-# scratch, so long contexts stream page tiles through VMEM without ever
-# materializing the gathered cache. Each grid step fetches a TILE of G pages
-# (G separate block-spec'd views of the same pool operand, one index map per
-# tile slot): at serving shapes (B=8-48, ctx 1K-32K, ps=64) the per-page
-# grid was step-overhead-bound — G=4 cuts the sequential step count 4× while
-# each page's DMA stays a contiguous [ps, hd] block. The block table and
-# per-row lengths are scalar-prefetched: the index map picks each step's
-# pages BEFORE the body runs, and clamps past-the-end steps to the last
-# valid page so their DMA is a no-op re-fetch (Pallas skips the copy when
-# the block index repeats).
+# One-token-per-row decode attention straight off the page pool, with work
+# proportional to the pages each row really holds. The grid runs over rows
+# only; inside, a loop with the dynamic trip count cdiv(length, G·ps) walks
+# the row's own block-table entries (scalar-prefetched into SMEM). No axis
+# is sized by the table's width mp: a row of length 0 does nothing, and
+# entries past a row's length are never read — not the table entry, not the
+# page behind it.
 #
-# int8-KV pools ride through IN-KERNEL: k/v hold int8 codes and the
-# per-(token, head) scale pools [P, Hkv, ps, 1] stream alongside as extra
-# [ps, 1] tiles — k's scale multiplies each score column, v's folds into the
-# probabilities after the denominator update (same factoring as
-# ops/pallas_attention.py _flash_kernel), so the HBM page reads stay
-# 1 byte/element and the paged path never materializes a dequantized cache.
-# (The previous design dequantized OUTSIDE the kernel path via the gather
-# reference — doubling cache-read bytes exactly where the paged path was
-# losing to dense slots.)
+# The pool operands stay in HBM (``pl.ANY``). A page's [Hkv, ps, hd] block
+# is contiguous in the [P, Hkv, ps, hd] layer, so one DMA brings a page for
+# ALL kv heads; a tile of G pages (``pages_per_step``) is fetched per loop
+# iteration into one of two VMEM slots while the other slot's tile is
+# computed, and a row's last iteration already fetches the next row's first
+# tile (the slot parity crosses grid steps in SMEM), so only the call's very
+# first fetch is exposed. All Hq query heads of the row are computed per
+# fetched page, in three phases over the kv heads (score dots, one softmax
+# update over the stacked scores, value dots), carrying the online-softmax
+# state (f32 running max, sum, accumulator per query head) in VMEM scratch.
+#
+# int8-KV pools ride through IN-KERNEL: k/v hold int8 codes, and the
+# per-(token, head) scales arrive lane-dense as [P, Hkv, ps] (a reshape of
+# the pool's [P, Hkv, ps, 1] leaf made outside the kernel — a trailing axis
+# of 1 would be padded 128× to lanes), one small DMA a page. k's scale
+# multiplies each score column, v's folds into the probabilities after the
+# denominator update (same factoring as ops/pallas_attention.py
+# _flash_kernel), so the HBM page reads stay 1 byte/element and the paged
+# path never materializes a dequantized cache. The probabilities stay f32.
 #
 # int4-KV pools (ISSUE 11) go one step further: the code tiles are PACKED
 # two nibbles per byte along hd ([ps, hd/2] int8 blocks — 0.5 byte/element
@@ -209,18 +215,17 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool_l, v_pool_l, block_tables, leng
 # accumulator is kept deinterleaved the same way (even/odd halves), with
 # one channel re-interleave applied to the tiny [B, Hq, hd] result OUTSIDE
 # the kernel. Scales are per (token, head) over the whole hd vector, so
-# one [ps, 1] scale column serves both halves.
+# one [1, ps] scale row serves both halves.
 
 _PAGE_TILE_DEFAULT = 4
 
 
 def _page_tile(mp: int, batch: int | None = None, context: int | None = None, kv_quant: str = "") -> int:
-  """Pages fetched per grid step: the largest power of two ≤ mp, capped at
-  the shape-aware dispatch verdict (inference/paging.py ``select_page_tile``
-  — the flat G=4 default was tuned at B=16 and left sequential-step
-  overhead on the table at B=48/96). ``XOT_TPU_PAGED_TILE`` force-caps
-  every shape (the in-process sweep knob). mp need not divide the tile:
-  trailing slots clamp to the last valid page and mask."""
+  """Pages fetched and computed per loop iteration: the largest power of two
+  ≤ mp, capped at the shape-aware dispatch verdict (inference/paging.py
+  ``select_page_tile``). ``XOT_TPU_PAGED_TILE`` force-caps every shape (the
+  in-process sweep knob). mp need not divide the tile: a row's last tile
+  holds only the pages the row has."""
   import os
 
   forced = os.getenv("XOT_TPU_PAGED_TILE")
@@ -240,92 +245,144 @@ def _page_tile(mp: int, batch: int | None = None, context: int | None = None, kv
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str):
   import jax.experimental.pallas as pl
+  from jax.experimental.pallas import tpu as pltpu
 
-  G = pages_per_step
+  G, ps = pages_per_step, page_size
   quantized = bool(kv_quant)
   packed = kv_quant == "int4"
-  k_refs, v_refs = refs[0:G], refs[G : 2 * G]
-  if quantized:
-    ks_refs, vs_refs = refs[2 * G : 3 * G], refs[3 * G : 4 * G]
-    o_ref, m_ref, l_ref, acc_ref = refs[4 * G :]
-  else:
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * G :]
-  b, i = pl.program_id(0), pl.program_id(2)
+  n_pools = 4 if quantized else 2  # k, v (+ their scales): HBM operands first, then the output, then their VMEM tiles
+  pools_hbm, o_ref, pools_buf = refs[:n_pools], refs[n_pools], refs[n_pools + 1 : 2 * n_pools + 1]
+  sem, slot_ref, m_ref, l_ref, acc_ref = refs[2 * n_pools + 1 :]
+  k_buf, v_buf, ks_buf, vs_buf = (*pools_buf, None, None)[:4]
+  n_rows, mp = bt_ref.shape
+  n_heads = k_buf.shape[2]
+  b = pl.program_id(0)
 
-  @pl.when(i == 0)
-  def _init():
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+  def tile_pages(row, tile):
+    """Resident pages of one tile of a row (the clamp to mp keeps a length
+    beyond the table inside it)."""
+    return jnp.clip(jnp.minimum(pl.cdiv(len_ref[row], ps), mp) - tile * G, 0, G)
+
+  def tile_dmas(row, tile, slot, act):
+    """Start or wait for (``act``) the DMAs of the resident pages of one
+    tile: per page one copy of its [Hkv, ps, hd] codes for k and for v, and
+    of its [Hkv, ps] scales when quantized (both padded to whole lanes), all
+    on the slot's semaphore."""
+
+    def page(j, carry):
+      p = bt_ref[row, tile * G + j]
+      for hbm, buf in zip(pools_hbm, pools_buf):
+        act(pltpu.make_async_copy(hbm.at[p], buf.at[slot, j], sem.at[slot]))
+      return carry
+
+    jax.lax.fori_loop(0, tile_pages(row, tile), page, 0)
+
+  def start(row, tile, slot):
+    tile_dmas(row, tile, slot, lambda dma: dma.start())
 
   length = len_ref[b]
-  # int4: q arrives DEINTERLEAVED (even channels in the first half, odd in
-  # the second — paged_decode_attention reorders outside the kernel), and
-  # acc/o stay in that layout until the caller re-interleaves.
-  q = q_ref[0, 0].astype(jnp.float32)  # [group, hd]
-  half = q.shape[-1] // 2
-  # Static unroll over the tile: each page's block chains the online-softmax
-  # state exactly like a dedicated grid step would (same math, G× fewer
-  # sequential steps). Pages clamped by the index map land with start >=
-  # length, so their whole block is skipped.
-  for j in range(G):
-    start = (i * G + j) * page_size
+  n_tiles = pl.cdiv(jnp.minimum(pl.cdiv(length, ps), mp), G)
 
-    @pl.when(start < length)
-    def _block(j=j, start=start):
-      if packed:
-        # Two-dot in-register dequant (see the int4 note above): lo/hi are
-        # pure shifts of the SAME packed [ps, hd/2] tile — read from HBM
-        # once at 0.5 byte/element, never materialized unpacked.
-        # Widened to int32 first: Mosaic has no int8 vector shift on v5e
-        # (same idiom as ops/pallas_int4.py).
-        kp = k_refs[j][0, 0].astype(jnp.int32)
-        k_lo = ((kp << 28) >> 28).astype(jnp.float32)  # even channels, sign-extended
-        k_hi = ((kp << 24) >> 28).astype(jnp.float32)  # odd channels
-        s = jax.lax.dot_general(q[:, :half], k_lo, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(q[:, half:], k_hi, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        s = s * scale
-      else:
-        k = k_refs[j][0, 0].astype(jnp.float32)  # [ps, hd]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale  # [group, ps]
+  @pl.when(b == 0)
+  def _first_row():
+    slot_ref[0] = 0
+
+  first_slot = slot_ref[0]
+  # The row before, if it held anything, started this row's first tile.
+  prefetched = jnp.logical_and(b > 0, len_ref[jnp.maximum(b - 1, 0)] > 0)
+
+  @pl.when(jnp.logical_not(prefetched))
+  def _fetch_first_tile():
+    start(b, 0, first_slot)
+
+  m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+  l_ref[...] = jnp.zeros_like(l_ref)
+  acc_ref[...] = jnp.zeros_like(acc_ref)
+  hd = q_ref.shape[-1]
+  kd = hd // 2 if packed else hd  # the lanes of a code tile that are codes, not padding
+  # The score dot runs in q's dtype over quantized pages: int8 codes are
+  # exact in bf16 and in f32, and products of bf16 pairs are exact in the
+  # f32 accumulator.
+  dot_dtype = q_ref.dtype if quantized else jnp.promote_types(q_ref.dtype, k_buf.dtype)
+
+  def code_halves(x):
+    """A [ps, kd] code tile as the dot's right operands. Packed int4: the
+    (even, odd) channel halves, pure shifts of the SAME packed bytes,
+    widened to int32 first — Mosaic has no int8 vector shift on v5e (same
+    idiom as ops/pallas_int4.py)."""
+    if not packed:
+      return (x,)
+    x = x.astype(jnp.int32)
+    return ((x << 28) >> 28, (x << 24) >> 28)
+
+  # Per kv head, its group of query heads [group, hd] as the dot's left
+  # operands. Packed int4: q arrives DEINTERLEAVED (even channels in the
+  # first half, odd in the second — paged_decode_attention reorders outside
+  # the kernel), and acc/o stay in that layout until the caller re-interleaves.
+  group = q_ref.shape[1] // n_heads
+  q = q_ref[0].astype(dot_dtype)
+  qs = [q[h * group : (h + 1) * group] for h in range(n_heads)]
+  qs = [(x[:, :kd], x[:, kd:]) if packed else (x,) for x in qs]
+
+  def attend_page(tile, slot, j):
+    """Fold page j of the slot's tile into the online softmax, in three
+    phases over the kv heads — every score dot, one softmax update over the
+    stacked [Hq, ps] scores, every value dot — so that the heads' matmuls
+    stand side by side: head by head, each dot waited for the softmax before
+    it and a page cost twice as much (PERF.md §6, PR 25)."""
+    valid = (tile * G + j) * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1) < length
+    scores = []
+    for h in range(n_heads):
+      s = sum(
+        jax.lax.dot_general(qx, kx.astype(dot_dtype), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        for qx, kx in zip(qs[h], code_halves(k_buf[slot, j, h, :, :kd]))
+      ) * scale  # [group, ps]
       if quantized:
         # codes·scale = true k: the per-token scale multiplies each score
-        # COLUMN ([ps, 1] transposed to a [1, ps] row broadcast). One scale
-        # covers the whole hd vector, so it applies after both int4 halves.
-        s = s * jnp.transpose(ks_refs[j][0, 0], (1, 0))
-      kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-      s = jnp.where(kv_pos < length, s, NEG_INF)
-      m_prev = m_ref[...]
-      blk_m = jnp.max(s, axis=1, keepdims=True)
-      m_new = jnp.maximum(m_prev, blk_m)
-      p = jnp.exp(s - m_new)
-      p = jnp.where(m_new <= NEG_INF / 2, 0.0, p)
-      alpha = jnp.exp(m_prev - m_new)
-      m_ref[...] = m_new
-      l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # COLUMN. One scale covers the whole hd vector, so it applies after
+        # both int4 halves.
+        s = s * ks_buf[slot, j, pl.ds(h, 1), :ps]
+      scores.append(jnp.where(valid, s, NEG_INF))  # a fetched page holds at least one valid slot: the max stays finite
+    s = jnp.concatenate(scores, axis=0)  # [Hq, ps]
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    upd = []
+    for h in range(n_heads):
+      ph = p[h * group : (h + 1) * group]
       if quantized:
-        p = p * jnp.transpose(vs_refs[j][0, 0], (1, 0))  # v's scale folds into probs (after the l update)
-      if packed:
-        vp_ = v_refs[j][0, 0].astype(jnp.int32)
-        v_lo = ((vp_ << 28) >> 28).astype(jnp.float32)
-        v_hi = ((vp_ << 24) >> 28).astype(jnp.float32)
-        upd = jnp.concatenate(
-          [
-            jax.lax.dot_general(p, v_lo, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32),
-            jax.lax.dot_general(p, v_hi, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32),
-          ],
-          axis=-1,
-        )  # deinterleaved [group, hd]: even half, then odd half
-        acc_ref[...] = acc_ref[...] * alpha + upd
-      else:
-        v = v_refs[j][0, 0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ph = ph * vs_buf[slot, j, pl.ds(h, 1), :ps]  # v's scale folds into probs (after the l update)
+      # packed: even half, then odd half
+      upd.append(jnp.concatenate([jax.lax.dot_general(ph, vx.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) for vx in code_halves(v_buf[slot, j, h, :, :kd])], axis=-1))
+    acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(upd, axis=0)
 
-  @pl.when(i == pl.num_programs(2) - 1)
-  def _finish():
-    l = l_ref[...]
-    l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+  def tile_body(i, carry):
+    slot = (first_slot + i) % 2
+
+    @pl.when(i + 1 < n_tiles)
+    def _fetch_next_tile():
+      start(b, i + 1, 1 - slot)
+
+    @pl.when(jnp.logical_and(i + 1 == n_tiles, b + 1 < n_rows))
+    def _fetch_next_rows_first_tile():
+      start(jnp.minimum(b + 1, n_rows - 1), 0, 1 - slot)
+
+    tile_dmas(b, i, slot, lambda dma: dma.wait())
+
+    def page(j, c):
+      attend_page(i, slot, j)
+      return c
+
+    jax.lax.fori_loop(0, tile_pages(b, i), page, 0)
+    return carry
+
+  jax.lax.fori_loop(0, n_tiles, tile_body, 0)
+  slot_ref[0] = (first_slot + n_tiles) % 2
+  l = l_ref[...]
+  o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 @component_scope("xot.attn")
@@ -336,12 +393,12 @@ def paged_decode_attention(
   """Decode attention off the page pool (dense GQA models).
 
   q [B, Hq, hd] (the single new token per row); k/v pool [P, Hkv, ps, hd];
-  block_tables [B, mp] int32 (unallocated entries may hold anything — steps
-  past ``lengths`` are clamped to the last valid page and masked);
-  lengths [B] int32 = number of valid KV slots INCLUDING the token just
-  written. With ``k_scale_pool_l``/``v_scale_pool_l`` [P, Hkv, ps, 1]
+  block_tables [B, mp] int32 (entries past a row's ``lengths`` may hold
+  anything — they are never read); lengths [B] int32 = number of valid KV
+  slots INCLUDING the token just written. With
+  ``k_scale_pool_l``/``v_scale_pool_l`` [P, Hkv, ps, 1]
   (int8-KV pools — init_paged_pool quant="int8"), k/v hold int8 codes
-  dequantized in-register per page tile; a pool whose code axis is HALVED
+  dequantized in-register per page; a pool whose code axis is HALVED
   ([P, Hkv, ps, hd/2] — init_paged_pool quant="int4") holds packed int4
   nibbles dequantized via the two-dot split (module note above).
   ``pages_per_step`` (static) overrides the shape-aware page-tile verdict
@@ -374,62 +431,56 @@ def _paged_decode_attention_impl(
   quantized = bool(kv_quant)
   packed = kv_quant == "int4"
   B, Hq, hd = q.shape
-  Hkv = k_pool_l.shape[1]
-  group = Hq // Hkv
-  mp = block_tables.shape[1]
-  kd = k_pool_l.shape[-1]  # hd, or hd/2 for packed int4 codes
   G = pages_per_step
-  n_steps = (mp + G - 1) // G
   scale = float(1.0 / (hd**0.5))
-  qg = q.reshape(B, Hkv, group, hd)
   if packed:
     # Deinterleave q once outside the kernel (even channels first, odd
     # second) so the in-kernel two-dot uses contiguous halves; the output
     # comes back in the same layout and is re-interleaved below.
-    qg = jnp.concatenate([qg[..., 0::2], qg[..., 1::2]], axis=-1)
+    q = jnp.concatenate([q[..., 0::2], q[..., 1::2]], axis=-1)
 
-  def page_index(j):
-    def index(b, h, i, bt_ref, len_ref):
-      # Clamp past-the-end tile slots to the row's last valid page: the
-      # repeated block index makes the DMA a no-op instead of fetching
-      # garbage (also covers mp % G != 0 trailing slots).
-      last = jnp.maximum(len_ref[b] - 1, 0) // page_size
-      return (bt_ref[b, jnp.minimum(i * G + j, last)], h, 0, 0)
+  row_block = pl.BlockSpec((1, Hq, hd), lambda b, bt, ln: (b, 0, 0))
+  in_hbm = pl.BlockSpec(memory_space=pl.ANY)
 
-    return index
+  def lane_dense(x):
+    """Mosaic slices a page out of an HBM operand only along whole lanes:
+    the minor axis padded to a multiple of 128 (a no-op for hd 128/256
+    codes; sub-128 code axes — hd 64, packed int4 — pay a copy of the layer)."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -x.shape[-1] % 128)])
 
-  in_specs = [pl.BlockSpec((1, 1, group, hd), lambda b, h, i, bt, ln: (b, h, 0, 0))]
-  in_specs += [pl.BlockSpec((1, 1, page_size, kd), page_index(j)) for j in range(G)]
-  in_specs += [pl.BlockSpec((1, 1, page_size, kd), page_index(j)) for j in range(G)]
-  operands = [qg] + [k_pool_l] * G + [v_pool_l] * G
+  operands = [q, lane_dense(k_pool_l), lane_dense(v_pool_l)]
+  tile = lambda pool: pltpu.VMEM((2, G, *pool.shape[1:]), pool.dtype)  # noqa: E731 — two slots of G pages
   if quantized:
-    in_specs += [pl.BlockSpec((1, 1, page_size, 1), page_index(j)) for j in range(G)]
-    in_specs += [pl.BlockSpec((1, 1, page_size, 1), page_index(j)) for j in range(G)]
-    operands += [k_scale_pool_l] * G + [v_scale_pool_l] * G
-
+    # The scales with tokens on lanes: [P, Hkv, ps, 1] → [P, Hkv, ps].
+    operands += [lane_dense(s.reshape(s.shape[:-1])) for s in (k_scale_pool_l, v_scale_pool_l)]
+  scratch = [tile(x) for x in operands[1:]]
+  tile_bytes = sum(2 * G * x[0].size * x.dtype.itemsize for x in operands[1:])  # wide tiles of wide pages pass the default 16 MiB
+  scratch += [
+    pltpu.SemaphoreType.DMA((2,)),
+    pltpu.SMEM((1,), jnp.int32),  # the slot the next tile goes to, across rows
+    pltpu.VMEM((Hq, 1), jnp.float32),  # running max, sum and accumulator of every query head
+    pltpu.VMEM((Hq, 1), jnp.float32),
+    pltpu.VMEM((Hq, hd), jnp.float32),
+  ]
   grid_spec = pltpu.PrefetchScalarGridSpec(
     num_scalar_prefetch=2,
-    grid=(B, Hkv, n_steps),
-    in_specs=in_specs,
-    out_specs=pl.BlockSpec((1, 1, group, hd), lambda b, h, i, bt, ln: (b, h, 0, 0)),
-    scratch_shapes=[
-      pltpu.VMEM((group, 1), jnp.float32),
-      pltpu.VMEM((group, 1), jnp.float32),
-      pltpu.VMEM((group, hd), jnp.float32),
-    ],
+    grid=(B,),
+    in_specs=[row_block] + [in_hbm] * (len(operands) - 1),
+    out_specs=row_block,
+    scratch_shapes=scratch,
   )
   out = pl.pallas_call(
     functools.partial(_paged_decode_kernel, page_size=page_size, scale=scale, pages_per_step=G, kv_quant=kv_quant),
-    out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
+    out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
     grid_spec=grid_spec,
+    # Rows in order: the prefetch chain crosses them.
+    compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=tile_bytes + (16 << 20)),
     interpret=interpret,
   )(block_tables, lengths, *operands)
   if packed:
-    # Undo the deinterleave on the [B, Hkv, group, hd] result: channel 2i
-    # from the even half, 2i+1 from the odd half.
-    half = hd // 2
-    out = jnp.stack([out[..., :half], out[..., half:]], axis=-1).reshape(B, Hkv, group, hd)
-  return out.reshape(B, Hq, hd)
+    # Undo the deinterleave: channel 2i from the even half, 2i+1 from the odd half.
+    out = jnp.stack([out[..., : hd // 2], out[..., hd // 2 :]], axis=-1).reshape(B, Hq, hd)
+  return out
 
 
 def paged_kernel_supported(cfg, platform: str | None = None) -> bool:
