@@ -27,36 +27,26 @@ the path the cells measure - are judged by (2) and (3) only: a decode step
 that picked a wrong expert or read a wrong page chooses a token the reference
 puts nats below its best.
 
-Tolerances and why. The served path keeps activations in bfloat16 (8 bits of
-mantissa: ~0.4 % per operation, accumulating over 14-32 layers) and, for the
-dense configuration, keys and values as int8 codes with one scale per token
-and head (~0.4 % of the largest entry). The reference is float32 on the same
-dequantised weights. The limits sit between what the chip read over the seeds
-of the proving runs and what the weakest probe of ``sensitivity`` reads (the
-last layer dropped; for mla_moe also two experts trading places): about three
-times the former and under half the latter (TOLERANCES, below; numbers in
-PERF.md). ``--probe-sensitivity`` runs the probes."""
+Limits and why. The three numbers compared — the mean |served - reference|
+log-prob over the compared entries, the worst single entry, and the reference's
+best log-prob minus its log-prob of the served token — are held to the limits
+of the configuration's kind: ``LIMITS`` in ``benchmark/arch_<kind>.py``, with the
+readings each was set from in ``LIMITS_WHY`` beside them (numbers in PERF.md):
+about three times what the chip read over the seeds of the proving runs, and
+under half what the weakest probe of the kind's ``probes`` reads.
+``--probe-sensitivity`` runs the probes."""
 
 from __future__ import annotations
 
 import numpy as np
 
+import arch
 from tokenizer import text_of, token_id
 
 PROMPT_TOKENS = 160
 N_GEN = 8
 TOP = 5
 SHORT_TOKENS = 48
-# Per arch_kind: (mean |served - reference| log-prob over the 48 compared entries,
-# the worst single entry, reference's best minus its log-prob of the served token).
-# Seen on the chip (PR 23): dense_gqa mean 0.013-0.018, worst 0.034-0.056, margin
-# <= 0.007 over seven seeds; weakest probe (last layer dropped) mean 0.19 / worst 0.57.
-# mla_moe (independent experts, token-topic router) mean 0.014, worst 0.049, margin
-# 0.0; weakest probes: rope base 100x too small mean 0.157 / worst 0.46 / margin 0.40,
-# two experts trading places 0.181 / 1.48 / 0.33, last layer dropped 0.197 / 0.71 / 0.36.
-# Each limit is about three times what was seen and under half the weakest probe.
-TOLERANCES = {"dense_gqa": (0.05, 0.20, 0.10), "mla_moe": (0.05, 0.20, 0.10)}
-
 
 def check_prompt(seed: int, vocab: int) -> np.ndarray:
   return np.random.default_rng([int(seed), 7]).integers(3, vocab, size=PROMPT_TOKENS, dtype=np.int64)
@@ -90,8 +80,12 @@ def compare(entries, gen, ref_lp: np.ndarray) -> dict:
 
 
 def verdict(c: dict, kind: str) -> bool:
-  mean_tol, max_tol, margin = TOLERANCES[kind]
-  return c["mean_abs"] <= mean_tol and c["max_abs"] <= max_tol and c["greedy_margin"] <= margin
+  return all(c[name] <= limit for name, limit in arch.load(kind).LIMITS.items())
+
+
+def compared(c: dict, kind: str) -> dict:
+  """Each number compared beside its limit: {name: [value, limit]}."""
+  return {name: [c.get(name), limit] for name, limit in arch.load(kind).LIMITS.items()}
 
 
 async def check(session, stack, hf: dict, params, seed: int, probe: bool = False) -> tuple[bool, dict]:
@@ -128,8 +122,5 @@ def sensitivity(params, hf: dict, tokens, entries, gen) -> dict:
   tolerances must still refuse."""
   import reference
 
-  probes = {"drop_last_layer": {"drop_layer": hf["num_hidden_layers"] - 1}, "drop_layer_1": {"drop_layer": 1}, "rope_base_100x_too_small": {"theta_scale": 0.01}}
-  if hf["arch_kind"] == "mla_moe":
-    probes["lose_one_expert_per_token"] = {"drop_expert": True}
-    probes["two_experts_trade_places"] = {"swap_experts": True}
+  probes = arch.load(hf["arch_kind"]).probes(hf)
   return {name: compare(entries, gen, np.asarray(reference.reference_logprobs(params, hf, tokens, N_GEN, **kw))) for name, kw in probes.items()}

@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import arch
+
 DECODE_FAMILIES = ("decode.paged_batch", "decode.mixed_paged_batch")
 
 
@@ -67,7 +69,9 @@ def resident(ctx: dict) -> tuple[float, float]:
 
 
 def kv_quant(ctx: dict) -> str:
-  return "" if ctx["hf"]["arch_kind"] == "mla_moe" else ctx["hf"]["serving_env"].get("XOT_TPU_KV_QUANT", "")
+  """The stored type of the cache: what the serving key the kind names says, "" where the kind names none."""
+  key = arch.load(ctx["hf"]["arch_kind"]).CACHE_TYPE_ENV
+  return ctx["hf"]["serving_env"].get(key, "") if key else ""
 
 
 def api_ttft_overhead_p50_ms(ctx: dict):

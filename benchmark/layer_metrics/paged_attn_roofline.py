@@ -12,6 +12,6 @@ def read(ctx):
   k = (ctx.get("trace") or {}).get("kernels", {}).get(KERNEL)
   if not k or not k["calls"] or not ctx.get("peaks"):
     return None
-  _rows, tokens = lib.resident(ctx)
-  least_s = flops_bytes.paged_attention_min_bytes(ctx["hf"], tokens, lib.kv_quant(ctx)) / ctx["peaks"]["hbm_bytes_per_s"]
+  rows, tokens = lib.resident(ctx)
+  least_s = flops_bytes.paged_attention_min_bytes(ctx["hf"], rows, tokens, lib.kv_quant(ctx)) / ctx["peaks"]["hbm_bytes_per_s"]
   return 100.0 * least_s / (k["device_s"] / k["calls"])

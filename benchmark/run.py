@@ -26,26 +26,18 @@ import sys  # noqa: E402
 
 import shutil  # noqa: E402
 
+import arch  # noqa: E402
 import common  # noqa: E402
 import client  # noqa: E402
 from layer_lib import pct as percentile  # noqa: E402
 from common import BENCH, ROOT  # noqa: E402
 
-REHEARSE_WIDTHS = {
-  "dense_gqa": {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 512},
-  "mla_moe": {
-    "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 3, "num_attention_heads": 4, "num_key_value_heads": 4, "vocab_size": 512,
-    "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16, "moe_intermediate_size": 32, "n_routed_experts": 8, "num_experts_per_tok": 2,
-  },
-}
-
-
 def log(**kw) -> None:
   print(json.dumps(kw, default=str), file=sys.stderr, flush=True)
 
 
-def rehearsal_shrink(hf: dict, traffic: dict) -> None:
-  hf.update(REHEARSE_WIDTHS[hf["arch_kind"]])
+def rehearsal_shrink(hf: dict, traffic: dict, widths: dict) -> None:
+  hf.update(widths)
   hf["serving_window_tokens"] = 1024
   hf["serving_env"] = {**hf["serving_env"], "XOT_TPU_BATCH_PAGES": "0", "XOT_TPU_MIXED_BUDGET": "256"}
   for key in ("prompt_tokens", "output_tokens"):
@@ -164,15 +156,16 @@ async def main_async(args) -> int:
   cell = common.cell_of(spec, args.workload)
   hf = common.load_config(cell["config"])
   traffic = common.load_traffic(cell["traffic"])
+  os.environ.setdefault("TPU_LOG_DIR", "disabled")
   if args.rehearse:
-    rehearsal_shrink(hf, traffic)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+  kind = arch.load(hf["arch_kind"])  # a kind's file that lacks a part is refused here, before anything is built
+  if args.rehearse:
+    rehearsal_shrink(hf, traffic, kind.REHEARSE_WIDTHS)
 
   import serve
 
   serve.apply_serving_env(hf)
-  os.environ.setdefault("TPU_LOG_DIR", "disabled")
-  if args.rehearse:
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
   import jax
 
@@ -252,6 +245,7 @@ async def main_async(args) -> int:
       dispatched = {f: a[1] - ctx["before"]["families"].get(f, (0, 0))[1] for f, a in ctx["after"]["families"].items() if a[1] != ctx["before"]["families"].get(f, (0, 0))[1]}
       ramp = {"ramp_compiles": ctx["before"]["compiles"] - ctx["start"]["compiles"], "first_tokens_s": ctx.get("first_tokens_s")}
       log(event="window", attempted=attempted, failed=failed, window_compiles=window_compiles, late_p95_ms=ctx.get("late_p95_ms"), dispatches=dispatched, **ramp, **client_view(ctx), **knee_view(plan["mode"], ctx, traffic))
+      log(event="compared", correct=bool(correct), **correctness.compared(detail, hf["arch_kind"]), stream_equals_blocking=[detail.get("stream_equals_blocking"), True])
       print(json.dumps(result), flush=True)
     except Exception:  # noqa: BLE001 — report, clean up, exit non-zero with no result line
       import traceback

@@ -5,6 +5,8 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
+import arch_dense_gqa as dense  # noqa: E402
+import arch_mla_moe as mla  # noqa: E402
 import common  # noqa: E402
 import flops_bytes as fb  # noqa: E402
 import pytest  # noqa: E402
@@ -15,10 +17,12 @@ def test_mistral_7b_int8_weights_and_cache():
   # a layer: q and o 4096x4096, k and v 4096x1024, three 4096x14336 -> 218.1 M parameters; 32 layers + the 4096x32768 head
   params = 32 * (2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336) + 4096 * 32768
   assert params == 7_113_539_584
-  assert fb.dense_gqa_weight_bytes(hf) == pytest.approx(params, rel=2e-3)  # + f32 scales and bf16 norms: under 0.2 %
+  assert dense.weight_bytes(hf) == pytest.approx(params, rel=2e-3)  # + f32 scales and bf16 norms: under 0.2 %
   # one cached token, int8 KV: 32 layers x 8 heads x 2 sides x (128 codes + one f32 scale)
-  assert fb.dense_gqa_kv_bytes_per_token(hf, "int8") == 32 * 8 * 2 * 132 == 67_584
-  assert fb.dense_gqa_kv_bytes_per_token(hf, "") == 32 * 8 * 2 * 256
+  assert sum(dense.cache_read_bytes(hf, 1, 1, "int8")) == 32 * 8 * 2 * 132 == 67_584
+  assert sum(dense.cache_read_bytes(hf, 1, 1, "")) == 32 * 8 * 2 * 256
+  # one call of the paged kernel reads one layer's share of it
+  assert fb.paged_attention_min_bytes(hf, 16, 12800, "int8") == 12800 * 8 * 2 * 132
 
 
 def test_moonlight_d14_weights_cache_and_experts():
@@ -29,11 +33,11 @@ def test_moonlight_d14_weights_cache_and_experts():
   moe_layer = attn + 64 * expert + 3 * 2048 * 2816 + 2048 * 64  # 585 M
   total = dense_layer + 13 * moe_layer + 2048 * 163840
   assert round(dense_layer / 1e6) == 83 and round(moe_layer / 1e6) == 585
-  assert fb.mla_moe_weight_bytes(hf, 16, all_experts=True) == pytest.approx(total, rel=3e-3)
+  assert mla.weight_bytes(hf, 16, all_experts=True) == pytest.approx(total, rel=3e-3)
   # 16 tokens x 6 of 64 experts touch 64 * (1 - (58/64)^16) = 50.75 distinct experts a layer
   assert fb.expected_distinct_experts(64, 6, 16) == pytest.approx(50.75, abs=0.01)
-  assert fb.mla_moe_weight_bytes(hf, 16) == pytest.approx(total - 13 * (64 - 50.75) * expert, rel=3e-3)
-  assert fb.mla_kv_bytes_per_token(hf) == 14 * (512 + 64) * 2 == 16_128
+  assert mla.weight_bytes(hf, 16) == pytest.approx(total - 13 * (64 - 50.75) * expert, rel=3e-3)
+  assert sum(mla.cache_read_bytes(hf, 1, 1, "")) == 14 * (512 + 64) * 2 == 16_128
 
 
 def test_roofline_names_the_bound():

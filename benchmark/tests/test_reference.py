@@ -10,19 +10,19 @@ from pathlib import Path
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
+import arch  # noqa: E402
 import common  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 import reference  # noqa: E402
-import run  # noqa: E402
 import weights  # noqa: E402
 
 
 def _tiny(name):
   hf = common.load_config(name)
-  hf.update(run.REHEARSE_WIDTHS[hf["arch_kind"]])
+  hf.update(arch.load(hf["arch_kind"]).REHEARSE_WIDTHS)
   hf["serving_window_tokens"] = 256
   return hf
 
@@ -46,7 +46,9 @@ def test_reference_matches_the_decoder(name):
   served = np.asarray(jax.nn.log_softmax(jnp.asarray(logits[-9:-1]), axis=-1))
   ref = np.asarray(reference.reference_logprobs(params, hf, tokens, 8))
   assert np.abs(served - ref).max() < 2e-3
-  for probe in ({"drop_layer": 1}, {"theta_scale": 0.01}, *([{"drop_expert": True}, {"swap_experts": True}] if hf["arch_kind"] == "mla_moe" else [])):
+  probes = arch.load(hf["arch_kind"]).probes(hf)
+  assert len(probes) >= 3
+  for probe in probes.values():
     wrong = np.asarray(reference.reference_logprobs(params, hf, tokens, 8, **probe))
     assert np.abs(wrong - ref).mean() > 10 * np.abs(served - ref).mean(), probe
 

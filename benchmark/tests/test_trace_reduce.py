@@ -43,3 +43,45 @@ def test_names():
   assert trace_reduce.module_base("jit__fused_paged_batch_decode_impl(1528432197634081039)") == "_fused_paged_batch_decode_impl"
   assert trace_reduce.op_base("%copy.152 = s8[257,8,64,128]{3,2,1,0} copy(%x)") == "copy"
   assert trace_reduce.self_times([(0.0, 10.0, "loop"), (1.0, 3.0, "a"), (4.0, 9.0, "b"), (5.0, 6.0, "c")]) == [(3.0, "loop"), (2.0, "a"), (4.0, "b"), (1.0, "c")]
+
+
+def test_program_families_finds_tracked_callables_wherever_they_live():
+  """The names the reduction mapped before PR 26 (three modules' attributes, recorded from
+  ``bb2de62``) map as they did; a tracked callable of another module and one made inside a
+  function, as the ring's programs are, are found too; a name two families share is left out."""
+  import json
+  import os
+
+  os.environ.setdefault("JAX_PLATFORMS", "cpu")
+  sys.path.insert(0, str(Path(__file__).resolve().parent.parent.parent))
+  from xotorch_support_jetson_tpu.models import decoder  # noqa: F401
+  from xotorch_support_jetson_tpu.ops import paged, pallas_attention, sampling  # noqa: F401
+  from xotorch_support_jetson_tpu.utils.programs import tracked_jit
+
+  def build():
+    @tracked_jit("test.closure_family")
+    def _made_inside_a_function(x):
+      return x + 1
+
+    @tracked_jit("test.one_family")
+    def _shared_name(x):
+      return x
+
+    return _made_inside_a_function, _shared_name
+
+  def build_again():
+    @tracked_jit("test.another_family")
+    def _shared_name(x):
+      return x
+
+    return _shared_name
+
+  keep = (build(), build_again())  # noqa: F841 - alive while the collector is asked
+  for fn in (*keep[0], keep[1]):
+    fn.__module__ = f"{trace_reduce.PROGRAM_PACKAGE}.made_up"
+  got = trace_reduce.program_families()
+  before = json.loads((Path(__file__).resolve().parent / "data" / "families_bb2de62.json").read_text())
+  assert {k: got.get(k) for k in before} == before
+  assert got["_made_inside_a_function"] == "test.closure_family"
+  assert got["sample_logits"] == "sample.logits" and "sample_logits" not in before  # ops/sampling.py: not among the three modules
+  assert "_shared_name" not in got

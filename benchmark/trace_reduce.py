@@ -184,21 +184,34 @@ def describe(pd, limit: int = 12) -> str:
   return "\n".join(rows)
 
 
-def program_families() -> dict[str, str]:
-  """wrapped ``__name__`` -> family, for every ``tracked_jit`` callable of the
-  program's model code: the device trace shows ``jit_<name>``, the ledger and
-  the metrics speak of families. Built at run time, so a refactor that keeps
-  the family keeps the metric."""
-  import importlib
+PROGRAM_PACKAGE = "xotorch_support_jetson_tpu"
 
-  out = {}
-  for mod in ("xotorch_support_jetson_tpu.models.decoder", "xotorch_support_jetson_tpu.ops.paged", "xotorch_support_jetson_tpu.ops.pallas_attention"):
-    m = importlib.import_module(mod)
-    for obj in vars(m).values():
-      fam = getattr(obj, "xot_family", None)
-      if fam:
-        inner = getattr(obj, "xot_jitted", obj)
-        name = getattr(inner, "__name__", None) or getattr(getattr(inner, "__wrapped__", None), "__name__", None)
-        if name:
-          out[name] = fam
-  return out
+
+def program_families() -> dict[str, str]:
+  """wrapped ``__name__`` -> family, for every ``tracked_jit`` callable the
+  process holds: the device trace shows ``jit_<name>``, the ledger and the
+  metrics speak of families. ``utils/programs.py`` keeps no list of what it
+  wrapped, and half of the program's tracked callables are closures made when
+  a batch is built (``parallel/pp_batch.py``, ``sp_batch.py``,
+  ``inference/kv_tier.py``), which no walk over module attributes reaches; so
+  this asks the collector for every function of the program's package that
+  carries ``xot_family``, wherever it lives. Built at run time, after the
+  window, so a refactor that keeps the family keeps the metric and a program a
+  new architecture defines in a new module is found. A name that two families
+  share is left out (its events then go by the function's own name)."""
+  import gc
+  import types
+
+  out: dict[str, str] = {}
+  clash: set[str] = set()
+  for obj in gc.get_objects():
+    if not isinstance(obj, types.FunctionType) or not (obj.__module__ or "").startswith(PROGRAM_PACKAGE):
+      continue
+    fam = getattr(obj, "xot_family", None)
+    if not fam:
+      continue
+    inner = getattr(obj, "xot_jitted", obj)
+    name = getattr(inner, "__name__", None) or getattr(getattr(inner, "__wrapped__", None), "__name__", None)
+    if name and out.setdefault(name, fam) != fam:
+      clash.add(name)
+  return {name: fam for name, fam in out.items() if name not in clash}
