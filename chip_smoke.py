@@ -309,7 +309,7 @@ def kernels_child(rehearsal: bool) -> None:
   for quant, pools in (("", (k.astype(dt), v.astype(dt))), ("int8", quantize_kv(k) + quantize_kv(v)), ("int4", quantize_kv_int4(k) + quantize_kv_int4(v))):
     if quant:
       kc, ks, vc, vs = pools
-      scales = {"k_scale_pool_l": ks, "v_scale_pool_l": vs}
+      scales = {"k_scale_pool": ks, "v_scale_pool": vs}
     else:
       (kc, vc), scales = pools, {}
     tile = min(select_page_tile(B, mp * ps, quant), mp)

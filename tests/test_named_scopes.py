@@ -97,3 +97,28 @@ def test_scopes_do_not_change_the_optimised_program(flavor, monkeypatch):
   without = lowered.compile().as_text()
   assert not _scopes_in(without)
   assert _program(with_scopes) == _program(without)
+
+
+def test_kernel_path_scopes_the_token_write_and_the_attention_kernel():
+  """On the kernel path both halves of a layer's cache traffic are Mosaic
+  calls (ISSUE 29): the token write under ``xot.kv_write`` — and not under a
+  name the roofline reader counts as the attention kernel — and the paged
+  kernel under ``xot.attn``. Lowered for the TPU platform on the CPU: the
+  Mosaic lowering needs no chip, and the locations carry the name stack."""
+  cfg = tiny_test_config(n_layers=2, max_seq_len=128, dim=512)  # head_dim 128: a pool leaf of whole lanes
+  params, shard = full_model_params(jax.random.PRNGKey(0), cfg)
+  B, mp = 2, 128 // PS
+  pool = init_paged_pool(cfg, shard.n_shard_layers, 1 + B * mp, PS, quant="int8")
+  bt = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
+  args = (
+    params, cfg, shard, jnp.ones((B, 1), jnp.int32), pool, bt, jnp.asarray([3, 5], jnp.int32), jnp.ones((B,), bool),
+    jnp.zeros((B,), jnp.float32), jnp.full((B,), 8, jnp.int32), 4, 8, PS, True, jax.random.PRNGKey(1), None,
+  )
+  text = _fused_paged_batch_decode_impl.xot_jitted.trace(*args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+  locs = dict(re.findall(r"(#loc\d+) = loc\((.*)\)", text))
+  kernels = [locs[ref] for ref in re.findall(r"stablehlo\.custom_call @tpu_custom_call.*?loc\((#loc\d+)\)", text)]
+  writes = [where for where in kernels if "kv_token_write" in where]
+  assert len(kernels) == 2 and len(writes) == 1, kernels  # one write call and one attention call in the layer loop's body
+  assert writes[0].startswith('"xot.kv_write/kv_token_write') and "paged_decode" not in writes[0]
+  attention = [locs[ref] for ref in re.findall(r"call @_paged_decode_attention_impl.*?loc\((#loc\d+)\)", text)]
+  assert len(attention) == 1 and "xot.attn/" in attention[0], attention

@@ -84,12 +84,12 @@ def test_paged_kernel_int8kv_dequant_matches_gather_reference():
   vs = jnp.asarray(rng.uniform(0.005, 0.02, size=(P, Hkv, ps, 1)), jnp.float32)
   bt = jnp.asarray([[3, 5, 7, 0], [1, 2, 0, 0]], jnp.int32)
   lengths = jnp.asarray([3 * ps - 2, ps + 3], jnp.int32)
-  ref = paged_gqa_attention_ref(q[:, None], kp, vp, bt, lengths, ps, k_scale_pool_l=ks, v_scale_pool_l=vs)[:, 0]
+  ref = paged_gqa_attention_ref(q[:, None], kp, vp, bt, lengths, ps, k_scale_pool=ks, v_scale_pool=vs)[:, 0]
   for g in (1, 2):
-    ker = paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool_l=ks, v_scale_pool_l=vs, pages_per_step=g, interpret=True)
+    ker = paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool=ks, v_scale_pool=vs, pages_per_step=g, interpret=True)
     assert jnp.allclose(ref, ker, atol=1e-5), f"int8 kernel (tile {g}) diverges"
   with pytest.raises(ValueError):
-    paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool_l=ks, interpret=True)
+    paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool=ks, interpret=True)
 
 
 def _kernel_case_pools(rng, quant: str, P: int, Hkv: int, ps: int, hd: int):
@@ -99,12 +99,12 @@ def _kernel_case_pools(rng, quant: str, P: int, Hkv: int, ps: int, hd: int):
   if quant == "int8":
     codes = lambda: jnp.asarray(rng.integers(-127, 128, size=(P, Hkv, ps, hd)), jnp.int8)  # noqa: E731
     scales = lambda: jnp.asarray(rng.uniform(0.005, 0.02, size=(P, Hkv, ps, 1)), jnp.float32)  # noqa: E731
-    return codes(), codes(), {"k_scale_pool_l": scales(), "v_scale_pool_l": scales()}
+    return codes(), codes(), {"k_scale_pool": scales(), "v_scale_pool": scales()}
   from xotorch_support_jetson_tpu.models.quantize import quantize_kv_int4
 
   kp, ks = quantize_kv_int4(jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.float32))
   vp, vs = quantize_kv_int4(jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.float32))
-  return kp, vp, {"k_scale_pool_l": ks, "v_scale_pool_l": vs}
+  return kp, vp, {"k_scale_pool": ks, "v_scale_pool": vs}
 
 
 _RAGGED_PS, _RAGGED_MP, _RAGGED_TILE = 8, 6, 2

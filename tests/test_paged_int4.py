@@ -83,9 +83,9 @@ def test_paged_kernel_int4_dequant_matches_gather_reference():
   kp, ks, vp, vs = _int4_pools(rng, P, Hkv, ps, hd)
   bt = jnp.asarray([[3, 5, 7, 9, 11, 0], [1, 2, 4, 0, 0, 0]], jnp.int32)
   lengths = jnp.asarray([5 * ps - 3, 2 * ps + 1], jnp.int32)
-  ref = paged_gqa_attention_ref(q[:, None], kp, vp, bt, lengths, ps, k_scale_pool_l=ks, v_scale_pool_l=vs)[:, 0]
+  ref = paged_gqa_attention_ref(q[:, None], kp, vp, bt, lengths, ps, k_scale_pool=ks, v_scale_pool=vs)[:, 0]
   for g in (1, 2, 4):
-    ker = paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool_l=ks, v_scale_pool_l=vs, pages_per_step=g, interpret=True)
+    ker = paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool=ks, v_scale_pool=vs, pages_per_step=g, interpret=True)
     assert jnp.allclose(ref, ker, atol=1e-5), f"int4 kernel (tile {g}) diverges"
 
 
@@ -105,8 +105,8 @@ def test_paged_kernel_wide_tiles_match_reference(pages_per_step):
   bt[0, :15] = np.arange(1, 16)
   bt[1, :7] = np.arange(20, 27)
   lengths = jnp.asarray([15 * ps - 1, 6 * ps + 2], jnp.int32)
-  ref = paged_gqa_attention_ref(q[:, None], kp, vp, jnp.asarray(bt), lengths, ps, k_scale_pool_l=ks, v_scale_pool_l=vs)[:, 0]
-  ker = paged_decode_attention(q, kp, vp, jnp.asarray(bt), lengths, ps, k_scale_pool_l=ks, v_scale_pool_l=vs, pages_per_step=pages_per_step, interpret=True)
+  ref = paged_gqa_attention_ref(q[:, None], kp, vp, jnp.asarray(bt), lengths, ps, k_scale_pool=ks, v_scale_pool=vs)[:, 0]
+  ker = paged_decode_attention(q, kp, vp, jnp.asarray(bt), lengths, ps, k_scale_pool=ks, v_scale_pool=vs, pages_per_step=pages_per_step, interpret=True)
   assert jnp.allclose(ref, ker, atol=1e-5), f"tile {pages_per_step} diverges"
 
 

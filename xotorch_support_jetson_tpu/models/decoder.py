@@ -14,7 +14,11 @@ TPU-first design (deliberately different from the reference's per-layer
 - **Stacked layer params + ``lax.scan``**: every layer leaf carries a leading
   ``[n_shard_layers, ...]`` axis and the layer stack runs as a scan, so
   compile time is O(1) in depth (an 80-layer 70B shard traces one layer) and
-  the layer axis is directly shardable for pipeline stages.
+  the layer axis is directly shardable for pipeline stages. The layer
+  parameters are the scan's ``xs``; the PAGE POOL of the paged decode
+  programs is not — it rides the scan's carry, stacked, and a layer writes
+  and reads it by ``(layer, page)`` (``_scan_layers_over_pool``): as
+  ``xs``/``ys`` every step moved the whole pool and computed nothing with it.
 - **Fixed shapes everywhere**: prefill pads to a bucket, decode is [B, 1];
   the KV cache is a preallocated slot-indexed buffer functionally updated
   with ``dynamic_update_slice`` (donated by the engine between steps).
@@ -627,7 +631,7 @@ def shard_forward(
   # Layer stacks run in order: dense prefix ("layers", e.g. deepseek's
   # first_k_dense), then the MoE stack ("moe_layers"). Each stack is one
   # lax.scan; MoE models with no dense prefix simply have no "layers" key.
-  stacks = [params[name] for name in ("layers", "moe_layers") if name in params]
+  stacks = _layer_stacks(params)
 
   if use_cache:
     parts = []
@@ -1165,106 +1169,127 @@ def fused_batch_decode(params, cfg: ModelConfig, shard: Shard, token, cache, pos
 # aggregate context and page-aligned prompt prefixes can be shared. Block
 # tables are TRACED [B, mp] operands — one compiled program covers every
 # allocation state. Rows without a request must keep their table zeroed (all
-# writes land in the reserved trash page 0).
+# writes land in the reserved trash page 0). The pool [L, P, Hkv, ps, hd] is
+# one buffer a leaf from the donated argument to the result: the step loop
+# and the layer loop carry it, a token write touches (layer, page, :, slot),
+# and the attention reads its pages by (layer, page).
 
 
-def _paged_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cfg: ModelConfig, page_size: int, use_kernel: bool, adapter_ids=None):
+def _scan_layers_over_pool(step, h, stacks, pool: Params):
+  """Run ``step(h, pool, layer_params, layer) → (h, pool)`` over the layers of
+  ``stacks`` (stacked-parameter dicts, in model order) with the STACKED pool
+  in the loop's carry.
+
+  The pool is never a scan ``xs``/``ys``: as ``xs`` every layer's whole
+  [P, Hkv, ps, hd] slice was cut out of the stacked leaf and as ``ys`` written
+  back into a fresh one, every step, for every leaf (PERF.md §6, PR 29). In
+  the carry, XLA has one buffer from the donated argument to the result, and
+  a layer's step touches the token rows it writes and the pages it reads.
+  ``layer`` is the pool's own layer index and runs on across the stacks, so a
+  model of two (dense prefix + experts) indexes the one pool from both, with
+  no split and no join."""
+
+  def body(carry, per_layer):
+    lp, layer = per_layer
+    return step(*carry, lp, layer), None
+
+  first = 0
+  for stack in stacks:
+    n = next(iter(stack.values())).shape[0]
+    (h, pool), _ = jax.lax.scan(body, (h, pool), (stack, first + jnp.arange(n, dtype=jnp.int32)))
+    first += n
+  return h, pool
+
+
+def _layer_stacks(params: Params) -> list:
+  """A full model's stacked layer parameters in model order: the dense layers, then the expert layers."""
+  return [params[name] for name in ("layers", "moe_layers") if name in params]
+
+
+def _write_kv(pool: Params, k, v, layer, block_tables, pos, page_size: int, kv_quant: str, kernel: bool = False, interpret: bool = False) -> Params:
+  """One token a row (k/v [B, Hkv, hd]) of one layer into the stacked pool:
+  as it is for float pools, as per-(token, head) codes and scales for
+  int8/int4 pages (models/quantize.py). ``kernel``: the pool is in the
+  kernel's form and Mosaic writes it (ops/paged.py ``write_token_kv``)."""
+  from ..ops.paged import write_token_kv
+
+  new = {"k": k, "v": v}
+  if kv_quant:
+    from .quantize import quantize_kv, quantize_kv_int4
+
+    quant_fn = quantize_kv_int4 if kv_quant == "int4" else quantize_kv
+    (new["k"], new["k_scale"]), (new["v"], new["v_scale"]) = quant_fn(k), quant_fn(v)
+  return write_token_kv(pool, new, layer, block_tables, pos, page_size, kernel, interpret)
+
+
+def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg: ModelConfig, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None):
   """One decoder layer against the page pool — decode only (S == 1).
 
-  ``pool_l`` is this layer's page dict: {"k", "v"} [P, Hkv, ps, hd]
-  (+ "k_scale"/"v_scale" [P, Hkv, ps, 1] when int8-quantized); positions
-  [B, 1]. Returns (h, pool_l).
+  ``pool`` is the STACKED page dict: {"k", "v"} [L, P, Hkv, ps, hd]
+  (+ "k_scale"/"v_scale" [L, P, Hkv, ps, 1] when quantized; in the kernel's
+  form on the kernel path — ops/paged.py ``kernel_pool_form``), ``layer``
+  this layer's index into it; positions [B, 1]. ``kv_quant`` names the
+  pool's mode where its shapes cannot (the kernel's form pads the code
+  axis); None reads it off the stored shapes. Returns (h, pool).
   """
   B, S, D = h.shape
   with jax.named_scope("xot.attn_proj"):
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
   pos = positions[:, 0]
   lengths = pos + 1  # valid KV slots incl. the token written below
-  from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref, write_token_kv
+  from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref
 
+  if kv_quant is None:
+    kv_quant = pool_kv_quant(pool, cfg)
   if "wkv_a" in p:
     # MLA: pages hold the latent ("k") and rope channel ("v"), one head entry.
     q_nope, q_pe, c_kv, k_pe = _mla_latents(x, p, cfg, positions, inv_freq)
-    k_pool = write_token_kv(pool_l["k"], c_kv[:, 0][:, None, :], block_tables, pos, page_size)
-    v_pool = write_token_kv(pool_l["v"], k_pe[:, 0][:, None, :], block_tables, pos, page_size)
-    with jax.named_scope("xot.attn"):  # the pool read and the absorbed up-projection are the core's operands
-      ckv_pool, kpe_pool, w_kv_b = k_pool.astype(h.dtype), v_pool.astype(h.dtype), _mla_w_kv_b(p, h.dtype)
-    attn = paged_mla_attention_ref(q_nope, q_pe, ckv_pool, kpe_pool, block_tables, lengths, w_kv_b, cfg.v_head_dim, page_size)
-    pool_l = {"k": k_pool, "v": v_pool}
+    pool = _write_kv(pool, c_kv[:, 0][:, None, :], k_pe[:, 0][:, None, :], layer, block_tables, pos, page_size, "")
+    with jax.named_scope("xot.attn"):  # the absorbed up-projection is the core's operand
+      w_kv_b = _mla_w_kv_b(p, h.dtype)
+    attn = paged_mla_attention_ref(q_nope, q_pe, pool["k"], pool["v"], block_tables, lengths, w_kv_b, cfg.v_head_dim, page_size, layer=layer)
   else:
     q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
-    if "k_scale" in pool_l:  # int8/int4 KV pages (models/quantize.py)
-      from .quantize import quantize_kv, quantize_kv_int4
-
-      packed = pool_l["k"].shape[-1] * 2 == k.shape[-1]  # int4: halved code axis
-      quant_fn = quantize_kv_int4 if packed else quantize_kv
-      kq, ks = quant_fn(k[:, 0])
-      vq, vs = quant_fn(v[:, 0])
-      pool_l = {
-        "k": write_token_kv(pool_l["k"], kq, block_tables, pos, page_size),
-        "k_scale": write_token_kv(pool_l["k_scale"], ks, block_tables, pos, page_size),
-        "v": write_token_kv(pool_l["v"], vq, block_tables, pos, page_size),
-        "v_scale": write_token_kv(pool_l["v_scale"], vs, block_tables, pos, page_size),
-      }
-      if use_kernel and cfg.plain_attention:
-        # int8/int4-KV pages straight through the kernel: codes + scales
-        # stream per page tile with in-register dequant — the pool read
-        # stays 1 byte/element (0.5 for packed int4; the gather fallback
-        # below moves the same quantized bytes but materializes the
-        # gathered window).
-        attn = paged_decode_attention(
-          q[:, 0], pool_l["k"], pool_l["v"], block_tables, lengths, page_size,
-          k_scale_pool_l=pool_l["k_scale"], v_scale_pool_l=pool_l["v_scale"],
-        )[:, None]
-      else:
-        attn = paged_gqa_attention_ref(
-          q, pool_l["k"], pool_l["v"], block_tables, lengths, page_size,
-          k_scale_pool_l=pool_l["k_scale"], v_scale_pool_l=pool_l["v_scale"], **_attn_opts(cfg, p.get("is_sliding"))
-        )
+    kernel = use_kernel and cfg.plain_attention  # the Pallas kernel has no softcap/window
+    pool = _write_kv(pool, k[:, 0], v[:, 0], layer, block_tables, pos, page_size, kv_quant, kernel)
+    scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if kv_quant else {}
+    if kernel:
+      # int8/int4-KV pages go straight through the kernel: codes + scales
+      # stream per page tile with in-register dequant — the pool read stays
+      # 1 byte/element (0.5 for packed int4; the gather fallback below moves
+      # the same quantized bytes but materializes the gathered window).
+      attn = paged_decode_attention(q[:, 0], pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, kv_quant=kv_quant, **scales)[:, None]
     else:
-      k_pool = write_token_kv(pool_l["k"], k[:, 0], block_tables, pos, page_size)
-      v_pool = write_token_kv(pool_l["v"], v[:, 0], block_tables, pos, page_size)
-      if use_kernel and cfg.plain_attention:  # the Pallas kernel has no softcap/window
-        attn = paged_decode_attention(q[:, 0], k_pool, v_pool, block_tables, lengths, page_size)[:, None]
-      else:
-        attn = paged_gqa_attention_ref(q, k_pool.astype(h.dtype), v_pool.astype(h.dtype), block_tables, lengths, page_size, **_attn_opts(cfg, p.get("is_sliding")))
-      pool_l = {"k": k_pool, "v": v_pool}
+      attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding")))
   with jax.named_scope("xot.attn_proj"):
     attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
     if "post_attn_norm" in p:  # gemma2
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
     h = h + attn_out
   h, _ = _mlp_block(h, p, cfg)
-  return h, pool_l
+  return h, pool
 
 
-def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool, adapter_ids=None):
+def _kernel_attends(cfg: ModelConfig, use_kernel: bool) -> bool:
+  """Whether a paged program's attention core is the Pallas kernel — the
+  layer steps' own test; such a program carries its pool in the kernel's form."""
+  return bool(use_kernel) and cfg.plain_attention and not cfg.is_mla
+
+
+def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None):
   """One decode step for all rows against the page pool.
 
   tokens [B, 1] int32 → (logits [B, 1, V], updated pool). Full shard only
-  (the batched server is single-node)."""
+  (the batched server is single-node). The pool comes back in the form it
+  came in (``_paged_layer_step``)."""
   h = embed_tokens(params, cfg, tokens)
   inv_freq = rope_inv_freq(cfg)
-  stacks = [params[name] for name in ("layers", "moe_layers") if name in params]
-  parts = []
-  off = 0
-  for stack in stacks:
-    L = next(iter(stack.values())).shape[0]
 
-    def body(carry, per_layer):
-      h = carry
-      lp, pool_l = per_layer
-      h, pool_l = _paged_layer_step(h, lp, pool_l, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids)
-      return h, pool_l
+  def step(h, pool, lp, layer):
+    return _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
 
-    with jax.named_scope("xot.kv_write"):  # a model of two stacks splits the pool per stack and joins it again: whole-pool copies
-      sub = {key: val[off : off + L] for key, val in pool.items()}
-    h, new_sub = jax.lax.scan(body, h, (stack, sub))
-    parts.append(new_sub)
-    off += L
-  with jax.named_scope("xot.kv_write"):
-    new_pool = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
-  return head_logits(params, cfg, h), new_pool
+  h, pool = _scan_layers_over_pool(step, h, _layer_stacks(params), pool)
+  return head_logits(params, cfg, h), pool
 
 
 def _paged_decode_scan(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, top_ks, n_steps: int, k_max: int, page_size: int, use_kernel: bool, key, adapter_ids=None):
@@ -1273,20 +1298,26 @@ def _paged_decode_scan(params, cfg: ModelConfig, shard: Shard, token, pool, bloc
   the mixed tick's decode half is the plain program's decode half by
   construction (the token-identity contract of ISSUE 14)."""
 
+  from ..ops.paged import kernel_pool_form, stored_pool_form
+
+  stored, kv_quant, kernel_form = pool, pool_kv_quant(pool, cfg), _kernel_attends(cfg, use_kernel)
+  if kernel_form:
+    pool = kernel_pool_form(pool)  # once a dispatch, not once a layer: the steps write and read this form
+
   def body(carry, _):
     tok, pos, pool, key = carry
     # Inactive rows would write into whatever page their table names; pin
     # their table to the trash page so held-token rewrites can't land on a
     # page another row now owns.
     bt = jnp.where(active[:, None], block_tables, 0)
-    logits, pool = paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, bt, page_size, use_kernel, adapter_ids)
+    logits, pool = paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, bt, page_size, use_kernel, adapter_ids, kv_quant)
     nxt, key = _next_token_batched(logits[:, 0, :], key, temps, top_ks, k_max)
     nxt = jnp.where(active, nxt, tok[:, 0])  # inactive rows hold their token
     pos = jnp.where(active, pos + 1, pos)  # ...and their position
     return (nxt[:, None], pos, pool, key), nxt
 
   (next_tok, pos, pool, _), toks = jax.lax.scan(body, (token, positions, pool, key), None, length=n_steps)
-  return jnp.moveaxis(toks, 0, 1), next_tok, pos, pool
+  return jnp.moveaxis(toks, 0, 1), next_tok, pos, stored_pool_form(pool, stored) if kernel_form else pool
 
 
 @partial(tracked_jit, "decode.paged_batch", static_argnames=("cfg", "shard", "n_steps", "k_max", "page_size", "use_kernel"), donate_argnums=(4,))
@@ -1429,112 +1460,72 @@ def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token
 # one-split-per-step exactly.
 
 
-def _paged_window_layer_step(h, p, pool_l, block_tables, positions, inv_freq, cfg: ModelConfig, page_size: int, use_kernel: bool = False, interpret: bool = False, adapter_ids=None):
+def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg: ModelConfig, page_size: int, use_kernel: bool = False, interpret: bool = False, adapter_ids=None, kv_quant: str | None = None):
   """One decoder layer for a multi-token VERIFY window against the page pool.
 
-  positions [B, W] are each row's own absolute window positions (rows are at
-  different depths). Writes all W tokens' KV through the block tables, then
-  attends per window position through the tuned Pallas kernel when the
-  dispatch table said kernel (``use_kernel`` — W is small and static, so the
-  window unrolls into W one-query kernel launches; each query's ``lengths``
-  is its own position+1, the same mask the reference's causal window
-  applies, and the batched pool read per launch is exactly a decode step's),
-  or via the gather reference otherwise. Before ISSUE 11 the verify ALWAYS
-  took the gather reference — batched speculation forfeited the kernel win
-  its plain chunks had. MLA is unsupported here (the scheduler keeps MLA
-  models on the plain chunk program in paged mode)."""
+  ``pool`` is the stacked page dict and ``layer`` this layer's index into
+  it, as in ``_paged_layer_step``. positions [B, W] are each row's own
+  absolute window positions (rows are at different depths). Writes all W
+  tokens' KV through the block tables, then attends per window position
+  through the tuned Pallas kernel when the dispatch table said kernel
+  (``use_kernel`` — W is small and static, so the window unrolls into W
+  one-query kernel launches; each query's ``lengths`` is its own
+  position+1, the same mask the reference's causal window applies, and the
+  batched pool read per launch is exactly a decode step's), or via the
+  gather reference otherwise. Before ISSUE 11 the verify ALWAYS took the
+  gather reference — batched speculation forfeited the kernel win its plain
+  chunks had. MLA is unsupported here (the scheduler keeps MLA models on the
+  plain chunk program in paged mode)."""
   B, W, D = h.shape
   with jax.named_scope("xot.attn_proj"):
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
-  from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref, write_token_kv
+  from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref
 
+  if kv_quant is None:
+    kv_quant = pool_kv_quant(pool, cfg)
   q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
   lengths = positions[:, -1] + 1  # valid KV slots incl. the window's writes
-
-  def window_attn(k_pool, v_pool, ks_pool=None, vs_pool=None):
-    """Kernel route: one tuned-kernel launch per window position, each
-    masked by its own query's length; gather route: one multi-query
-    reference call. Token-exact either way (A/B-pinned)."""
-    if use_kernel and cfg.plain_attention:
-      outs = []
-      for j in range(W):
-        outs.append(paged_decode_attention(
-          q[:, j], k_pool, v_pool, block_tables, positions[:, j] + 1, page_size,
-          k_scale_pool_l=ks_pool, v_scale_pool_l=vs_pool, interpret=interpret,
-        ))
-      return jnp.stack(outs, axis=1)  # [B, W, Hq, hd]
-    scales = {} if ks_pool is None else {"k_scale_pool_l": ks_pool, "v_scale_pool_l": vs_pool}
-    kk = k_pool if ks_pool is not None else k_pool.astype(h.dtype)
-    vv = v_pool if ks_pool is not None else v_pool.astype(h.dtype)
-    return paged_gqa_attention_ref(
-      q, kk, vv, block_tables, lengths, page_size,
-      q_positions=positions, **scales, **_attn_opts(cfg, p.get("is_sliding")),
-    )
-
-  if "k_scale" in pool_l:  # int8/int4 KV pages — per-token scales, same values
-    # a one-token-at-a-time write would produce (quantize_kv[_int4] is
-    # per-(token, head))
-    from .quantize import quantize_kv, quantize_kv_int4
-
-    packed = pool_l["k"].shape[-1] * 2 == k.shape[-1]
-    quant_fn = quantize_kv_int4 if packed else quantize_kv
-    kq, ks = quant_fn(k)
-    vq, vs = quant_fn(v)
-    pool_l = dict(pool_l)
-    for j in range(W):  # W is small (gamma_max+1) and static
-      pos_j = positions[:, j]
-      pool_l["k"] = write_token_kv(pool_l["k"], kq[:, j], block_tables, pos_j, page_size)
-      pool_l["k_scale"] = write_token_kv(pool_l["k_scale"], ks[:, j], block_tables, pos_j, page_size)
-      pool_l["v"] = write_token_kv(pool_l["v"], vq[:, j], block_tables, pos_j, page_size)
-      pool_l["v_scale"] = write_token_kv(pool_l["v_scale"], vs[:, j], block_tables, pos_j, page_size)
-    attn = window_attn(pool_l["k"], pool_l["v"], pool_l["k_scale"], pool_l["v_scale"])
-  else:
-    k_pool, v_pool = pool_l["k"], pool_l["v"]
-    for j in range(W):
-      pos_j = positions[:, j]
-      k_pool = write_token_kv(k_pool, k[:, j], block_tables, pos_j, page_size)
-      v_pool = write_token_kv(v_pool, v[:, j], block_tables, pos_j, page_size)
-    attn = window_attn(k_pool, v_pool)
-    pool_l = {"k": k_pool, "v": v_pool}
+  kernel = use_kernel and cfg.plain_attention
+  for j in range(W):  # W is small (gamma_max+1) and static; per-token scales, the values a one-token-at-a-time write produces
+    pool = _write_kv(pool, k[:, j], v[:, j], layer, block_tables, positions[:, j], page_size, kv_quant, kernel, interpret)
+  scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if kv_quant else {}
+  if kernel:
+    # Kernel route: one tuned-kernel launch per window position, each masked
+    # by its own query's length. Token-exact against the gather route (A/B-pinned).
+    attn = jnp.stack(
+      [
+        paged_decode_attention(q[:, j], pool["k"], pool["v"], block_tables, positions[:, j] + 1, page_size, interpret=interpret, layer=layer, kv_quant=kv_quant, **scales)
+        for j in range(W)
+      ],
+      axis=1,
+    )  # [B, W, Hq, hd]
+  else:  # gather route: one multi-query reference call
+    attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, q_positions=positions, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding")))
   with jax.named_scope("xot.attn_proj"):
     attn_out = _mm(attn.reshape(B, W, -1), p, "wo", cfg.quant_compute)
     if "post_attn_norm" in p:  # gemma2
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
     h = h + attn_out
   h, _ = _mlp_block(h, p, cfg)
-  return h, pool_l
+  return h, pool
 
 
-def paged_window_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool = False, interpret: bool = False, adapter_ids=None):
+def paged_window_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool = False, interpret: bool = False, adapter_ids=None, kv_quant: str | None = None):
   """W-token forward for every row against the page pool — the batched
   speculative VERIFY pass. tokens/positions [B, W] → (logits [B, W, V],
-  updated pool). Full shard only. ``use_kernel`` routes each window
-  position through the tuned Pallas kernel instead of the gather reference
-  (``_paged_window_layer_step``; A/B-pinned token-exact)."""
+  updated pool, in the form it came in). Full shard only. ``use_kernel``
+  routes each window position through the tuned Pallas kernel instead of
+  the gather reference (``_paged_window_layer_step``; A/B-pinned token-exact)."""
   if cfg.is_mla:
     raise ValueError("paged_window_forward does not support MLA models")
   h = embed_tokens(params, cfg, tokens)
   inv_freq = rope_inv_freq(cfg)
-  stacks = [params[name] for name in ("layers", "moe_layers") if name in params]
-  parts = []
-  off = 0
-  for stack in stacks:
-    L = next(iter(stack.values())).shape[0]
 
-    def body(carry, per_layer):
-      h = carry
-      lp, pool_l = per_layer
-      h, pool_l = _paged_window_layer_step(h, lp, pool_l, block_tables, positions, inv_freq, cfg, page_size, use_kernel, interpret, adapter_ids)
-      return h, pool_l
+  def step(h, pool, lp, layer):
+    return _paged_window_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, interpret, adapter_ids, kv_quant)
 
-    with jax.named_scope("xot.kv_write"):  # a model of two stacks splits the pool per stack and joins it again: whole-pool copies
-      sub = {key: val[off : off + L] for key, val in pool.items()}
-    h, new_sub = jax.lax.scan(body, h, (stack, sub))
-    parts.append(new_sub)
-    off += L
-  with jax.named_scope("xot.kv_write"):
-    new_pool = parts[0] if len(parts) == 1 else {key: jnp.concatenate([p[key] for p in parts], axis=0) for key in parts[0]}
-  return head_logits(params, cfg, h), new_pool
+  h, pool = _scan_layers_over_pool(step, h, _layer_stacks(params), pool)
+  return head_logits(params, cfg, h), pool
 
 
 def _spec_batch_rounds(params_d, cfg_d: ModelConfig, shard_d: Shard, verify, token, carry_t, cache_d, positions, active, gammas, temps, top_ks, n_rounds: int, gamma_max: int, k_max: int, key, props=None, prop_counts=None):
@@ -1673,12 +1664,18 @@ def _fused_spec_batch_decode_impl(params, params_d, cache, cache_d, token, posit
 def _fused_spec_paged_batch_decode_impl(params, params_d, pool, cache_d, token, block_tables, positions, active, gammas, temps, top_ks, key, props, prop_counts, adapter_ids, cfg: ModelConfig, shard: Shard, cfg_d: ModelConfig, shard_d: Shard, n_rounds: int, gamma_max: int, k_max: int, page_size: int, use_kernel: bool, interpret: bool):
   # Inactive rows' window writes must not land on pages another row may now
   # own: pin their tables to the trash page once (tables are chunk-constant).
+  from ..ops.paged import kernel_pool_form, stored_pool_form
+
   bt = jnp.where(active[:, None], block_tables, 0)
+  stored, kv_quant, kernel_form = pool, pool_kv_quant(pool, cfg), _kernel_attends(cfg, use_kernel)
+  if kernel_form:
+    pool = kernel_pool_form(pool)  # once a dispatch, as in _paged_decode_scan
 
   def verify(window, wpos, pool):
-    return paged_window_forward(params, cfg, shard, window, wpos, pool, bt, page_size, use_kernel, interpret, adapter_ids)
+    return paged_window_forward(params, cfg, shard, window, wpos, pool, bt, page_size, use_kernel, interpret, adapter_ids, kv_quant)
 
-  return _spec_batch_rounds(params_d, cfg_d, shard_d, verify, token, pool, cache_d, positions, active, gammas, temps, top_ks, n_rounds, gamma_max, k_max, key, props, prop_counts)
+  *out, pool, cache_d = _spec_batch_rounds(params_d, cfg_d, shard_d, verify, token, pool, cache_d, positions, active, gammas, temps, top_ks, n_rounds, gamma_max, k_max, key, props, prop_counts)
+  return (*out, stored_pool_form(pool, stored) if kernel_form else pool, cache_d)
 
 
 def _spec_batch_args(shard: Shard, token, active, gammas, temps, top_k, k_max: int, key):
@@ -1842,7 +1839,7 @@ def score_last_tokens(params, cfg: ModelConfig, shard: Shard, tokens, seq_len, n
     h, _, aux = _layer_step(h, lp, None, positions, positions[0], inv_freq, cfg, False)
     return (h, _aux + aux), None
 
-  stacks = [params[name] for name in ("layers", "moe_layers") if name in params]
+  stacks = _layer_stacks(params)
   for stack in stacks:
     (h, _), _ = jax.lax.scan(body, (h, jnp.float32(0.0)), stack)
 

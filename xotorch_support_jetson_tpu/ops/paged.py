@@ -21,7 +21,12 @@ is set by the tokens it attends, not by the table's width or the pool's size.
 
 Pool layout: ``[L, P, Hkv, ps, hd]`` — one logical page id addresses the same
 page index in every layer, and a page's ``[Hkv, ps, hd]`` block is contiguous:
-one DMA brings it for every kv head.
+one DMA brings it for every kv head. The decode programs carry the stacked
+leaves through their layer loop and address them by ``(layer, page)``: a
+token write touches ``(layer, page, :, slot)`` alone, the kernel's DMA source
+is ``pool.at[layer, page]`` and the gather reference reads
+``pool[layer, block_table]`` — no layer is ever sliced out of the pool or
+written back whole (PERF.md §6, PR 29).
 
 Page 0 is reserved as a trash page: gathers of unallocated block-table entries
 read it (positionally masked anyway) and masked scatters dump there, which
@@ -31,6 +36,7 @@ keeps every shape static without conditional writes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -77,26 +83,163 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   return {"k": jnp.zeros(k_shape, dtype=dtype), "v": jnp.zeros(v_shape, dtype=dtype)}
 
 
-@component_scope("xot.kv_write")
-def write_token_kv(pool_l: jnp.ndarray, new: jnp.ndarray, block_tables: jnp.ndarray, pos: jnp.ndarray, page_size: int) -> jnp.ndarray:
-  """Scatter one decode step's KV into the pool (one layer).
+def _stacked(leaf, layer):
+  """``(leaf, layer)`` of a stacked pool leaf; a single layer's leaf (``layer``
+  None: the direct callers — tests, ``chip_smoke.py``) is a stack of one."""
+  return (leaf[None], 0) if layer is None else (leaf, layer)
 
-  pool_l [P, Hkv, ps, hd]; new [B, Hkv, hd]; block_tables [B, mp] int32;
-  pos [B] int32 (the logical position being written). Rows own disjoint
-  pages, so the scatter indices never collide.
+
+@component_scope("xot.kv_write")
+def write_token_kv(pool: dict, new: dict, layer, block_tables: jnp.ndarray, pos: jnp.ndarray, page_size: int, kernel: bool = False, interpret: bool = False) -> dict:
+  """Write one decode step's KV of one layer into the stacked pool, in place.
+
+  pool: the stacked leaves {"k", "v"} [L, P, Hkv, ps, hd] (+ "k_scale" /
+  "v_scale" [L, P, Hkv, ps, 1]); new: the same keys, [B, Hkv, hd]
+  ([B, Hkv, 1] for a scale); layer a traced scalar; block_tables [B, mp]
+  int32; pos [B] int32 (the logical position being written). Only the
+  ``B × Hkv`` token rows at ``(layer, page, :, slot)`` are touched. Rows own
+  disjoint pages, so the writes never collide (inactive rows all land in the
+  trash page 0 of this layer).
+
+  ``kernel``: the pool is in the kernel's form (``kernel_pool_form``) and
+  the write is Mosaic's too (``_token_write_kernel``) — on a TPU the XLA
+  scatter wants the stacked code leaves in another layout than the attention
+  kernel, and a carry both touch is then copied whole, per layer (PERF.md §6,
+  PR 29). Otherwise one XLA scatter a leaf.
   """
   page = jnp.take_along_axis(block_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
   off = pos % page_size
-  return pool_l.at[page, :, off].set(new.astype(pool_l.dtype))
+  if kernel:
+    return _write_token_kv_kernel(pool, new, layer, page, off, interpret)
+  return {name: leaf.at[layer, page, :, off].set(new[name].astype(leaf.dtype)) for name, leaf in pool.items()}
 
 
-def gather_pages(pool_l: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-  """[P, Hkv, ps, hd] × [B, mp] → position-ordered KV [B, mp·ps, Hkv, hd].
+# ------------------------------------------------- Pallas token-write kernel
+#
+# The kernel path's token write: per row, the aligned group of slots that
+# holds the token's slot — [Hkv, g, lanes] of a code leaf, g the rows of one
+# packed tile (32 for int8, 16 for bf16, 8 for f32), and the page's
+# [Hkv, lanes] tile of a lane-dense scale leaf — comes from HBM into VMEM by
+# DMA, the token's row (a scale's lane) is put in by a select, and the group
+# goes back: a single row of a packed dtype is under what a DMA or a store
+# addresses. The pool leaves stay in HBM (``pl.ANY``) and alias their outputs,
+# so the program's pool is ONE buffer per leaf that this call and the
+# attention kernel both address by (layer, page): XLA never relays or copies
+# it. Every row's inbound DMAs start before the first is waited for. A row
+# on the trash page 0 (inactive) is skipped: several such rows would race on
+# one page, and nothing reads what they write.
 
-  The XLA fallback path (CPU tests, MLA models): materializes the gathered
-  cache per layer. The Pallas kernel below avoids this copy on TPU.
+_WRITE_ROWS = 16  # rows of one grid step: their groups are in flight together
+
+
+def _token_write_kernel(page_ref, off_ref, layer_ref, *refs, n: int, rows: int, groups: tuple):
+  import jax.experimental.pallas as pl
+  from jax.experimental.pallas import tpu as pltpu
+
+  news, pools, bufs, sem = refs[:n], refs[2 * n : 3 * n], refs[3 * n : 4 * n], refs[4 * n]  # refs[n:2n]: the pools as inputs, aliased to the outputs
+  layer = layer_ref[0]
+  first = pl.program_id(0) * rows
+
+  def dmas(r, inbound: bool):
+    """The copies of row r's groups, HBM → VMEM or back, on the row's semaphore of that direction."""
+    page, off = page_ref[first + r], off_ref[first + r]
+    out = []
+    for pool, buf, g in zip(pools, bufs, groups):
+      hbm = pool.at[layer, page] if g is None else pool.at[layer, page, :, pl.ds(pl.multiple_of(off // g * g, g), g), :]
+      src, dst = (hbm, buf.at[r]) if inbound else (buf.at[r], hbm)
+      out.append(pltpu.make_async_copy(src, dst, sem.at[0 if inbound else 1, r]))
+    return out
+
+  def each_real_row(fn):
+    """``fn(r)`` for every row of this grid step that is not on the trash page: a loop, not an unrolled body —
+    the kernel is traced and lowered once per program variant, on the serving host."""
+
+    def body(r, carry):
+      pl.when(page_ref[first + r] != 0)(lambda: fn(r))
+      return carry
+
+    jax.lax.fori_loop(0, rows, body, 0)
+
+  def fetch(r):
+    for dma in dmas(r, True):
+      dma.start()
+
+  def insert_and_store(r):
+    for dma in dmas(r, True):
+      dma.wait()
+    off = off_ref[first + r]
+    for new, buf, g in zip(news, bufs, groups):
+      if g is None:  # lane-dense scales [Hkv, lanes]: the slot is a lane; new[r] is [Hkv, 1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, buf.shape[1:], 1)
+        buf[r] = jnp.where(lane == off, new[r], buf[r])
+      else:  # codes [Hkv, g, lanes]: the slot is a row of the group; new[r] is [Hkv, 1, lanes]
+        # Packed dtypes are widened to 32 bits for the select (Mosaic has few 8- and 16-bit vector ops on v5e); every value survives the round trip.
+        wide = jnp.int32 if jnp.issubdtype(buf.dtype, jnp.integer) else jnp.float32
+        row = jax.lax.broadcasted_iota(jnp.int32, buf.shape[1:], 1)
+        buf[r] = jnp.where(row == off % g, new[r].astype(wide), buf[r].astype(wide)).astype(buf.dtype)
+    for dma in dmas(r, False):
+      dma.start()
+
+  def stored(r):
+    for dma in dmas(r, False):
+      dma.wait()
+
+  each_real_row(fetch)
+  each_real_row(insert_and_store)
+  each_real_row(stored)
+
+
+def _write_token_kv_kernel(pool: dict, new: dict, layer, page, off, interpret: bool) -> dict:
+  import jax.experimental.pallas as pl
+  from jax.experimental.pallas import tpu as pltpu
+
+  names = list(pool)
+  stored, pool = pool, kernel_pool_form(pool)  # stored leaves are converted per call, as in paged_decode_attention: a direct caller's cost
+  B = page.shape[0]
+  rows = max(r for r in range(1, min(B, _WRITE_ROWS) + 1) if B % r == 0)
+  news, groups, scratch = [], [], []
+  for name in names:
+    leaf, x = pool[name], new[name].astype(pool[name].dtype)
+    if leaf.ndim == 4:  # lane-dense scales: one [Hkv, lanes] tile a page
+      groups.append(None)
+      scratch.append(pltpu.VMEM((rows, *leaf.shape[2:]), leaf.dtype))
+    else:
+      x = jnp.pad(x, [(0, 0), (0, 0), (0, leaf.shape[-1] - x.shape[-1])])[:, :, None, :]  # the leaf's lanes; a row of a group
+      ps, tile = leaf.shape[3], 8 * (4 // leaf.dtype.itemsize)
+      groups.append(tile if ps % tile == 0 else ps)
+      scratch.append(pltpu.VMEM((rows, leaf.shape[2], groups[-1], leaf.shape[4]), leaf.dtype))
+    news.append(x)
+  n = len(names)
+  in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+  grid_spec = pltpu.PrefetchScalarGridSpec(
+    num_scalar_prefetch=3,
+    grid=(B // rows,),
+    in_specs=[pl.BlockSpec((rows, *x.shape[1:]), lambda c, *_, nd=x.ndim: (c,) + (0,) * (nd - 1)) for x in news] + [in_hbm] * n,
+    out_specs=[in_hbm] * n,
+    scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2, rows))],
+  )
+  out = pl.pallas_call(
+    functools.partial(_token_write_kernel, n=n, rows=rows, groups=tuple(groups)),
+    out_shape=[jax.ShapeDtypeStruct(pool[name].shape, pool[name].dtype) for name in names],
+    grid_spec=grid_spec,
+    input_output_aliases={3 + n + i: i for i in range(n)},  # after the three scalar-prefetch operands and the new rows
+    compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    interpret=interpret,
+    name="kv_token_write",  # not the attention kernel's: the roofline reader counts calls by that name
+  )(page, off, jnp.asarray(layer, jnp.int32).reshape(1), *news, *(pool[name] for name in names))
+  return stored_pool_form(dict(zip(names, out)), stored)
+
+
+def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray, layer=None) -> jnp.ndarray:
+  """[L, P, Hkv, ps, hd] at ``layer`` × [B, mp] → position-ordered KV
+  [B, mp·ps, Hkv, hd].
+
+  The XLA fallback path (CPU tests, MLA models): one gather by
+  ``(layer, page)`` reads the rows' pages only and materializes the gathered
+  window. The Pallas kernel below avoids this copy on TPU.
   """
-  g = jnp.take(pool_l, block_tables, axis=0)  # [B, mp, Hkv, ps, hd]
+  pool, layer = _stacked(pool, layer)
+  g = pool[layer, block_tables]  # [B, mp, Hkv, ps, hd]
   B, mp, Hkv, ps, hd = g.shape
   return jnp.swapaxes(g, 2, 3).reshape(B, mp * ps, Hkv, hd)
 
@@ -138,9 +281,11 @@ def scatter_row_pages(pool_part: jnp.ndarray, t: jnp.ndarray, target: jnp.ndarra
 
 
 @component_scope("xot.attn")
-def paged_gqa_attention_ref(q, k_pool_l, v_pool_l, block_tables, lengths, page_size: int, k_scale_pool_l=None, v_scale_pool_l=None, q_positions=None, **attn_opts) -> jnp.ndarray:
+def paged_gqa_attention_ref(q, k_pool, v_pool, block_tables, lengths, page_size: int, k_scale_pool=None, v_scale_pool=None, q_positions=None, layer=None, **attn_opts) -> jnp.ndarray:
   """Reference paged decode attention via gather (q [B, Sq, Hq, hd]; Sq is 1
-  on the decode path). ``attn_opts`` forward gemma2's
+  on the decode path). The pools are stacked leaves [L, P, Hkv, ps, hd] read
+  at ``layer`` (``layer`` None: one layer's [P, Hkv, ps, hd]); float pools
+  are cast to q's dtype after the gather. ``attn_opts`` forward gemma2's
   scale/softcap/sliding-window (models/decoder.py _attn_opts). With scale
   pools (int8/int4 KV), the gathered codes stay the einsum operand and the
   scales gather alongside — the page gather itself moves the quantized
@@ -150,26 +295,29 @@ def paged_gqa_attention_ref(q, k_pool_l, v_pool_l, block_tables, lengths, page_s
   ``q_positions`` [B, Sq] overrides the single-query default — the batched
   speculative VERIFY window (models/decoder.py paged_window_forward) passes
   each row's own window positions."""
-  k = gather_pages(k_pool_l, block_tables)
-  v = gather_pages(v_pool_l, block_tables)
+  k = gather_pages(k_pool, block_tables, layer)
+  v = gather_pages(v_pool, block_tables, layer)
   kv_positions = jnp.arange(k.shape[1], dtype=jnp.int32)
   if q_positions is None:
     q_positions = (lengths - 1)[:, None]  # current token's position
-  if k_scale_pool_l is not None:
+  if k_scale_pool is not None:
     if k.shape[-1] * 2 == q.shape[-1]:  # packed int4 codes (ISSUE 11)
       from ..models.quantize import unpack_int4_kv
 
       k = unpack_int4_kv(k)
       v = unpack_int4_kv(v)
-    attn_opts = dict(attn_opts, k_scale=gather_pages(k_scale_pool_l, block_tables), v_scale=gather_pages(v_scale_pool_l, block_tables))
+    attn_opts = dict(attn_opts, k_scale=gather_pages(k_scale_pool, block_tables, layer), v_scale=gather_pages(v_scale_pool, block_tables, layer))
+  else:
+    k, v = k.astype(q.dtype), v.astype(q.dtype)
   return gqa_attention(q, k, v, q_positions, kv_positions, **attn_opts)
 
 
 @component_scope("xot.attn")
-def paged_mla_attention_ref(q_nope, q_pe, k_pool_l, v_pool_l, block_tables, lengths, w_kv_b, v_dim: int, page_size: int) -> jnp.ndarray:
-  """Paged MLA decode attention: gather the latent pages, then the absorbed op."""
-  ckv = gather_pages(k_pool_l, block_tables)[:, :, 0, :]  # [B, mp·ps, rank]
-  kpe = gather_pages(v_pool_l, block_tables)[:, :, 0, :]
+def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths, w_kv_b, v_dim: int, page_size: int, layer=None) -> jnp.ndarray:
+  """Paged MLA decode attention: gather the latent pages of ``layer`` out of
+  the stacked leaves, then the absorbed op."""
+  ckv = gather_pages(k_pool, block_tables, layer)[:, :, 0, :].astype(q_nope.dtype)  # [B, mp·ps, rank]
+  kpe = gather_pages(v_pool, block_tables, layer)[:, :, 0, :].astype(q_nope.dtype)
   kv_positions = jnp.arange(ckv.shape[1], dtype=jnp.int32)
   q_positions = (lengths - 1)[:, None]
   return mla_absorbed_attention(q_nope, q_pe, ckv, kpe, w_kv_b, q_positions, kv_positions, v_dim)
@@ -185,8 +333,10 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool_l, v_pool_l, block_tables, leng
 # entries past a row's length are never read — not the table entry, not the
 # page behind it.
 #
-# The pool operands stay in HBM (``pl.ANY``). A page's [Hkv, ps, hd] block
-# is contiguous in the [P, Hkv, ps, hd] layer, so one DMA brings a page for
+# The pool operands are the STACKED leaves and stay in HBM (``pl.ANY``); the
+# layer rides scalar prefetch beside the block table and the lengths, and the
+# DMA source of a page is ``pool.at[layer, page]``. A page's [Hkv, ps, hd]
+# block is contiguous in [L, P, Hkv, ps, hd], so one DMA brings a page for
 # ALL kv heads; a tile of G pages (``pages_per_step``) is fetched per loop
 # iteration into one of two VMEM slots while the other slot's tile is
 # computed, and a row's last iteration already fetches the next row's first
@@ -197,9 +347,13 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool_l, v_pool_l, block_tables, leng
 # state (f32 running max, sum, accumulator per query head) in VMEM scratch.
 #
 # int8-KV pools ride through IN-KERNEL: k/v hold int8 codes, and the
-# per-(token, head) scales arrive lane-dense as [P, Hkv, ps] (a reshape of
-# the pool's [P, Hkv, ps, 1] leaf made outside the kernel — a trailing axis
-# of 1 would be padded 128× to lanes), one small DMA a page. k's scale
+# per-(token, head) scales arrive lane-dense as [L, P, Hkv, lanes] (tokens
+# on lanes, padded to whole lanes: a trailing axis of 1 would be padded 128×),
+# one small DMA a page. The decode programs make that form of the stored
+# [L, P, Hkv, ps, 1] leaf ONCE a dispatch, outside their step loop, carry and
+# write it in that form and turn it back at the end (``kernel_pool_form`` /
+# ``stored_pool_form``): made per call it is a copy of the whole stacked leaf
+# per layer. k's scale
 # multiplies each score column, v's folds into the probabilities after the
 # denominator update (same factoring as ops/pallas_attention.py
 # _flash_kernel), so the HBM page reads stay 1 byte/element and the paged
@@ -243,7 +397,7 @@ def _page_tile(mp: int, batch: int | None = None, context: int | None = None, kv
   return g
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str):
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str):
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
@@ -257,6 +411,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: f
   n_rows, mp = bt_ref.shape
   n_heads = k_buf.shape[2]
   b = pl.program_id(0)
+  layer = layer_ref[0]
 
   def tile_pages(row, tile):
     """Resident pages of one tile of a row (the clamp to mp keeps a length
@@ -272,7 +427,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: f
     def page(j, carry):
       p = bt_ref[row, tile * G + j]
       for hbm, buf in zip(pools_hbm, pools_buf):
-        act(pltpu.make_async_copy(hbm.at[p], buf.at[slot, j], sem.at[slot]))
+        act(pltpu.make_async_copy(hbm.at[layer, p], buf.at[slot, j], sem.at[slot]))
       return carry
 
     jax.lax.fori_loop(0, tile_pages(row, tile), page, 0)
@@ -385,50 +540,89 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *refs, page_size: int, scale: f
   o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+def _kernel_leaf(x: jnp.ndarray) -> jnp.ndarray:
+  """A stacked pool leaf as the kernel's DMA takes it: Mosaic slices a page
+  out of an HBM operand only along whole lanes, so the minor axis is padded
+  to a multiple of 128 (a no-op for hd 128/256 codes; sub-128 code axes —
+  hd 64, packed int4 — pay a copy of the leaf), and a scale leaf
+  [L, P, Hkv, ps, 1] puts its tokens on lanes first: [L, P, Hkv, ps → lanes].
+  A leaf already in that form passes through untouched."""
+  if x.ndim == 5 and x.shape[-1] == 1:
+    x = x.reshape(x.shape[:-1])
+  return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -x.shape[-1] % 128)])
+
+
+@component_scope("xot.kv_write")
+def kernel_pool_form(pool: dict) -> dict:
+  """The stacked pool in the kernel's form (``_kernel_leaf``), made ONCE a
+  dispatch outside the step loop by the programs whose attention is the
+  Pallas kernel: the token writes and the kernel's reads then share one
+  buffer per leaf from the first step to the last. Costs nothing for code
+  leaves of whole lanes (int8/bf16 at hd 128/256); the scale leaves (3 % of
+  an int8 pool) are relaid, and code leaves under 128 lanes copied, once."""
+  return {name: _kernel_leaf(leaf) for name, leaf in pool.items()}
+
+
+@component_scope("xot.kv_write")
+def stored_pool_form(pool: dict, like: dict) -> dict:
+  """Inverse of ``kernel_pool_form``: back to the shapes of ``like`` (the
+  pool as ``init_paged_pool`` lays it out), at the program's end."""
+  out = {}
+  for name, leaf in pool.items():
+    want = like[name].shape
+    if leaf.ndim < len(want):  # lane-dense scales → [L, P, Hkv, ps, 1]
+      leaf = leaf[..., : want[-2], None]
+    out[name] = leaf if leaf.shape == want else leaf[..., : want[-1]]
+  return out
+
+
 @component_scope("xot.attn")
 def paged_decode_attention(
-  q, k_pool_l, v_pool_l, block_tables, lengths, page_size: int,
-  k_scale_pool_l=None, v_scale_pool_l=None, pages_per_step: int | None = None, interpret: bool = False,
+  q, k_pool, v_pool, block_tables, lengths, page_size: int,
+  k_scale_pool=None, v_scale_pool=None, pages_per_step: int | None = None, interpret: bool = False, layer=None, kv_quant: str | None = None,
 ):
   """Decode attention off the page pool (dense GQA models).
 
-  q [B, Hq, hd] (the single new token per row); k/v pool [P, Hkv, ps, hd];
-  block_tables [B, mp] int32 (entries past a row's ``lengths`` may hold
-  anything — they are never read); lengths [B] int32 = number of valid KV
-  slots INCLUDING the token just written. With
-  ``k_scale_pool_l``/``v_scale_pool_l`` [P, Hkv, ps, 1]
+  q [B, Hq, hd] (the single new token per row); k/v pool the stacked leaves
+  [L, P, Hkv, ps, hd] read at ``layer`` (a traced scalar; ``layer`` None: one
+  layer's [P, Hkv, ps, hd]); block_tables [B, mp] int32 (entries past a
+  row's ``lengths`` may hold anything — they are never read); lengths [B]
+  int32 = number of valid KV slots INCLUDING the token just written. With
+  ``k_scale_pool``/``v_scale_pool`` [L, P, Hkv, ps, 1]
   (int8-KV pools — init_paged_pool quant="int8"), k/v hold int8 codes
   dequantized in-register per page; a pool whose code axis is HALVED
-  ([P, Hkv, ps, hd/2] — init_paged_pool quant="int4") holds packed int4
-  nibbles dequantized via the two-dot split (module note above).
+  ([…, ps, hd/2] — init_paged_pool quant="int4") holds packed int4
+  nibbles dequantized via the two-dot split (module note above). Leaves in
+  the kernel's form (``kernel_pool_form``) are taken as they are — their
+  padded code axis no longer tells int8 from packed int4, so their caller
+  names the mode (``kv_quant``: "", "int8", "int4"; None reads it off stored
+  shapes); stored leaves that need it are converted per call — a copy of the
+  leaf, which a program with a layer loop must make outside it.
   ``pages_per_step`` (static) overrides the shape-aware page-tile verdict
   (inference/paging.py ``select_page_tile``). Returns [B, Hq, hd].
   """
-  if (k_scale_pool_l is None) != (v_scale_pool_l is None):
-    raise ValueError("paged_decode_attention: k_scale_pool_l and v_scale_pool_l must be passed together")
-  kv_quant = ""
-  if k_scale_pool_l is not None:
-    kv_quant = "int4" if jnp.shape(k_pool_l)[-1] * 2 == jnp.shape(q)[-1] else "int8"
+  if (k_scale_pool is None) != (v_scale_pool is None):
+    raise ValueError("paged_decode_attention: k_scale_pool and v_scale_pool must be passed together")
+  pools = [_stacked(x, layer) for x in (k_pool, v_pool, k_scale_pool, v_scale_pool) if x is not None]
+  layer = jnp.asarray(pools[0][1], jnp.int32).reshape(1)
+  if kv_quant is None:
+    kv_quant = "" if k_scale_pool is None else "int4" if jnp.shape(k_pool)[-1] * 2 == jnp.shape(q)[-1] else "int8"
   # Resolve the env-tunable tile width OUTSIDE the jitted body: baked-in-at-
   # first-trace env reads silently ignore later changes for identical shapes
   # (an in-process XOT_TPU_PAGED_TILE sweep would re-time one width forever).
   mp = jnp.shape(block_tables)[1]
   G = pages_per_step or _page_tile(mp, batch=jnp.shape(q)[0], context=mp * page_size, kv_quant=kv_quant)
   return _paged_decode_attention_impl(
-    q, k_pool_l, v_pool_l, block_tables, lengths, k_scale_pool_l, v_scale_pool_l,
+    q, block_tables, lengths, layer, *(x for x, _ in pools),
     page_size=page_size, pages_per_step=G, kv_quant=kv_quant, interpret=interpret,
   )
 
 
 @functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret"))
-def _paged_decode_attention_impl(
-  q, k_pool_l, v_pool_l, block_tables, lengths, k_scale_pool_l, v_scale_pool_l,
-  page_size: int, pages_per_step: int, kv_quant: str, interpret: bool,
-):
+def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool):
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
-  quantized = bool(kv_quant)
   packed = kv_quant == "int4"
   B, Hq, hd = q.shape
   G = pages_per_step
@@ -439,22 +633,14 @@ def _paged_decode_attention_impl(
     # comes back in the same layout and is re-interleaved below.
     q = jnp.concatenate([q[..., 0::2], q[..., 1::2]], axis=-1)
 
-  row_block = pl.BlockSpec((1, Hq, hd), lambda b, bt, ln: (b, 0, 0))
+  row_block = pl.BlockSpec((1, Hq, hd), lambda b, bt, ln, ly: (b, 0, 0))
   in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-
-  def lane_dense(x):
-    """Mosaic slices a page out of an HBM operand only along whole lanes:
-    the minor axis padded to a multiple of 128 (a no-op for hd 128/256
-    codes; sub-128 code axes — hd 64, packed int4 — pay a copy of the layer)."""
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -x.shape[-1] % 128)])
-
-  operands = [q, lane_dense(k_pool_l), lane_dense(v_pool_l)]
-  tile = lambda pool: pltpu.VMEM((2, G, *pool.shape[1:]), pool.dtype)  # noqa: E731 — two slots of G pages
-  if quantized:
-    # The scales with tokens on lanes: [P, Hkv, ps, 1] → [P, Hkv, ps].
-    operands += [lane_dense(s.reshape(s.shape[:-1])) for s in (k_scale_pool_l, v_scale_pool_l)]
-  scratch = [tile(x) for x in operands[1:]]
-  tile_bytes = sum(2 * G * x[0].size * x.dtype.itemsize for x in operands[1:])  # wide tiles of wide pages pass the default 16 MiB
+  pools = [_kernel_leaf(x) for x in pools]  # k, v (+ their scales), stacked: [L, P, Hkv, ps, lanes] / [L, P, Hkv, lanes]
+  if not interpret:  # (the interpreter cannot slice an array that carries a memory space)
+    # In HBM by constraint: left to XLA, a scale leaf (34 MB of Mistral's pool) is copied into VMEM before every call, once a layer.
+    pools = [pltpu.with_memory_space_constraint(x, pltpu.HBM) for x in pools]
+  scratch = [pltpu.VMEM((2, G, *x.shape[2:]), x.dtype) for x in pools]  # two slots of G pages
+  tile_bytes = sum(2 * G * math.prod(x.shape[2:]) * x.dtype.itemsize for x in pools)  # wide tiles of wide pages pass the default 16 MiB
   scratch += [
     pltpu.SemaphoreType.DMA((2,)),
     pltpu.SMEM((1,), jnp.int32),  # the slot the next tile goes to, across rows
@@ -463,9 +649,9 @@ def _paged_decode_attention_impl(
     pltpu.VMEM((Hq, hd), jnp.float32),
   ]
   grid_spec = pltpu.PrefetchScalarGridSpec(
-    num_scalar_prefetch=2,
+    num_scalar_prefetch=3,
     grid=(B,),
-    in_specs=[row_block] + [in_hbm] * (len(operands) - 1),
+    in_specs=[row_block] + [in_hbm] * len(pools),
     out_specs=row_block,
     scratch_shapes=scratch,
   )
@@ -476,7 +662,7 @@ def _paged_decode_attention_impl(
     # Rows in order: the prefetch chain crosses them.
     compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=tile_bytes + (16 << 20)),
     interpret=interpret,
-  )(block_tables, lengths, *operands)
+  )(block_tables, lengths, layer, q, *pools)
   if packed:
     # Undo the deinterleave: channel 2i from the even half, 2i+1 from the odd half.
     out = jnp.stack([out[..., : hd // 2], out[..., hd // 2 :]], axis=-1).reshape(B, Hq, hd)

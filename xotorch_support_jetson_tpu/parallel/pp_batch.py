@@ -247,7 +247,7 @@ class PPBatchedServing:
         buf0 = jnp.zeros((P_, G, n_steps), jnp.int32)
 
         if paged:
-          from ..models.decoder import _paged_layer_step
+          from ..models.decoder import _paged_layer_step, _scan_layers_over_pool
 
         def paged_bt(write_ok, g):
           # Masked rows (and fill/drain junk ticks) write to the trash page.
@@ -265,12 +265,11 @@ class PPBatchedServing:
           if paged:
             bt_eff = paged_bt(write_ok, g)
 
-            def body(h, per_layer):
-              lp, pool_l = per_layer
-              h, pool_l = _paged_layer_step(h, lp, pool_l, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)
-              return h, pool_l
+            def step(h, pool, lp, layer):
+              return _paged_layer_step(h, pool, lp, layer, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)
 
-            h_out, new = jax.lax.scan(body, h_in, (pre_layers, {key: cache[f"{key}_pre"][0] for key in kv_keys}))
+            # The prefix layers' stacked pool rides the layer loop's carry (decoder.py _scan_layers_over_pool).
+            h_out, new = _scan_layers_over_pool(step, h_in, [pre_layers], {key: cache[f"{key}_pre"][0] for key in kv_keys})
             cache = {**cache, **{f"{key}_pre": new[key][None] for key in kv_keys}}
           else:
             pre = {k: cache[f"{k}_pre"][0] for k in kv_keys}
@@ -288,12 +287,10 @@ class PPBatchedServing:
           if paged:
             bt_eff = paged_bt(write_ok, g)
 
-            def body(h, per_layer):
-              lp, pool_l = per_layer
-              h, pool_l = _paged_layer_step(h, lp, pool_l, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)
-              return h, pool_l
+            def step(h, pool, lp, layer):
+              return _paged_layer_step(h, pool, lp, layer, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)
 
-            h_out, new = jax.lax.scan(body, h_in, (stage_layers, {key: cache[key] for key in kv_keys}))
+            h_out, new = _scan_layers_over_pool(step, h_in, [stage_layers], {key: cache[key] for key in kv_keys})
             return h_out, {**cache, **{key: new[key] for key in kv_keys}}
           sub = {k: jax.lax.dynamic_slice_in_dim(cache[k], g * G, G, axis=1) for k in kv_keys}
           h_out, new_sub = _stage_forward(stage_layers, h_in, cur_pos[:, None], sub, inv_freq, cfg)
