@@ -273,10 +273,9 @@ def kernels_child(rehearsal: bool) -> None:
   import jax
   import jax.numpy as jnp
 
-  from xotorch_support_jetson_tpu.inference.paging import select_page_tile
   from xotorch_support_jetson_tpu.models.quantize import quantize_kv, quantize_kv_int4
   from xotorch_support_jetson_tpu.ops.attention import gqa_attention
-  from xotorch_support_jetson_tpu.ops.paged import paged_decode_attention, paged_gqa_attention_ref
+  from xotorch_support_jetson_tpu.ops.paged import PAGE_TILE, paged_decode_attention, paged_gqa_attention_ref
   from xotorch_support_jetson_tpu.ops.pallas_attention import flash_attention_prefill
 
   device = device_summary()
@@ -312,7 +311,7 @@ def kernels_child(rehearsal: bool) -> None:
       scales = {"k_scale_pool": ks, "v_scale_pool": vs}
     else:
       (kc, vc), scales = pools, {}
-    tile = min(select_page_tile(B, mp * ps, quant), mp)
+    tile = min(PAGE_TILE, mp)
     got = paged_decode_attention(q, kc, vc, tables, lengths, ps, pages_per_step=tile, interpret=interpret, **scales)
     want = reference(paged_gqa_attention_ref, q[:, None], kc, vc, tables, lengths, ps, **scales)[:, 0]
     check(f"ops.paged_attention[{quant or 'bf16'},G={tile}]", got, want, atol)

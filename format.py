@@ -14,7 +14,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-ROOTS = ("xotorch_support_jetson_tpu", "tests", "bench.py", "format.py", "__graft_entry__.py")
+ROOTS = ("xotorch_support_jetson_tpu", "tests", "format.py")
 
 
 def python_files(target: Path) -> list[str]:

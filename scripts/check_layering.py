@@ -27,9 +27,11 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 PACKAGE = "xotorch_support_jetson_tpu"
 
-# (constrained module, forbidden module, why) — paths relative to the repo
-# root; "module" matching covers both absolute and relative spellings.
-RULES: list[tuple[str, str, str]] = [
+# (constrained module or directory, forbidden module, why, *modules beneath
+# the forbidden one that are allowed all the same) — paths relative to the
+# repo root; a directory constrains every .py file under it; "module"
+# matching covers both absolute and relative spellings.
+RULES: list[tuple[str, ...]] = [
   (
     f"{PACKAGE}/inference/sched_admission.py",
     f"{PACKAGE}.inference.batch_scheduler",
@@ -70,6 +72,22 @@ RULES: list[tuple[str, str, str]] = [
     f"{PACKAGE}.networking",
     "the adapter registry is transport-agnostic: the node layer owns the x-adapter wire",
   ),
+  # The kernels and the model programs sit under the serving layer (PR 32):
+  # which attention core a paged program runs is ops/paged.py's to say, and
+  # inference/ asks it, never the reverse. ``Shard`` is the one type both
+  # sides share (spell it ``from ..inference.shard import Shard``).
+  (
+    f"{PACKAGE}/ops",
+    f"{PACKAGE}.inference",
+    "kernels and device ops know nothing of the scheduler or its policy tables",
+    f"{PACKAGE}.inference.shard",
+  ),
+  (
+    f"{PACKAGE}/models",
+    f"{PACKAGE}.inference",
+    "model programs know nothing of the scheduler or its policy tables",
+    f"{PACKAGE}.inference.shard",
+  ),
 ]
 
 
@@ -96,17 +114,22 @@ def _imported_modules(path: Path) -> set[str]:
   return out
 
 
+def _within(mod: str, root: str) -> bool:
+  return mod == root or mod.startswith(root + ".")
+
+
 def check() -> list[str]:
   """Returns a list of human-readable violations (empty = clean)."""
   problems: list[str] = []
-  for rel, forbidden, why in RULES:
+  for rel, forbidden, why, *allowed in RULES:
     path = REPO / rel
     if not path.exists():
       problems.append(f"{rel}: constrained module missing (split reverted?)")
       continue
-    for mod in sorted(_imported_modules(path)):
-      if mod == forbidden or mod.startswith(forbidden + "."):
-        problems.append(f"{rel} imports {mod} — {why}")
+    for file in sorted(path.rglob("*.py")) if path.is_dir() else [path]:
+      for mod in sorted(_imported_modules(file)):
+        if _within(mod, forbidden) and not any(_within(mod, a) for a in allowed):
+          problems.append(f"{file.relative_to(REPO)} imports {mod} — {why}")
   return problems
 
 

@@ -1,12 +1,11 @@
 """Test harness config: the CPU backend with 8 virtual devices.
 
 The tests check results and counts, which need no accelerator, and the
-sharding/pipeline tests need a mesh, which 8 virtual CPU devices give (the
-same path as ``__graft_entry__.dryrun_multichip``). The platform is set the
-one way the package honours (``JAX_PLATFORMS``, utils/helpers.py
-``apply_platform_override``) and before jax is imported anywhere, hence the
-env mutation at module import time. The chip is reached by ``chip_smoke.py``,
-never by a test.
+sharding/pipeline tests need a mesh, which 8 virtual CPU devices give. The
+platform is set the one way the package honours (``JAX_PLATFORMS``,
+utils/helpers.py ``apply_platform_override``) and before jax is imported
+anywhere, hence the env mutation at module import time. The chip is reached
+by ``chip_smoke.py``, never by a test.
 """
 
 import asyncio

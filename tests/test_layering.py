@@ -3,12 +3,15 @@
 The scheduler split is admission/placement (inference/sched_admission.py)
 vs device execution (inference/batch_scheduler.py); the split stays real
 only while the admission layer never imports the execution layer (or the
-networking transport). Wired next to tests/test_metrics_docs.py — same
-lexical-gate pattern, AST-based matcher."""
+networking transport). Likewise ``ops/`` and ``models/`` sit under
+``inference/`` and import nothing of it but ``Shard`` (PR 32). Wired next to
+tests/test_metrics_docs.py — same lexical-gate pattern, AST-based matcher."""
 
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -102,9 +105,29 @@ def test_checker_catches_planted_reverse_import_in_adapters(tmp_path):
     check_layering.REPO = old_repo
 
 
+@pytest.mark.parametrize("layer", ["ops", "models"])
+def test_checker_catches_a_planted_import_of_the_serving_layer(tmp_path, monkeypatch, layer):
+  """PR 32: nothing under ``ops/`` or ``models/`` imports ``inference/`` but
+  ``inference.shard`` — a file that also reaches for the page accounting
+  (function-local, aliased, relative) fails the gate, in a subdirectory
+  too; its ``Shard`` import alone does not."""
+  check_layering = _checker()
+  assert any(rel.endswith(f"/{layer}") for rel, *_ in check_layering.RULES)
+  pkg = tmp_path / "xotorch_support_jetson_tpu" / layer
+  (pkg / "deeper").mkdir(parents=True)
+  (pkg / "clean.py").write_text("from ..inference.shard import Shard\n")
+  (pkg / "deeper" / "planted.py").write_text(
+    "from ...inference.shard import Shard\n\n\ndef _smuggle():\n  from ...inference import paging as _p\n  return _p, Shard\n"
+  )
+  monkeypatch.setattr(check_layering, "REPO", tmp_path)
+  problems = [p for p in check_layering.check() if p.startswith(f"xotorch_support_jetson_tpu/{layer}/")]
+  assert problems and all("deeper/planted.py" in p and "inference" in p for p in problems), problems
+  assert any("inference.paging" in p for p in problems), problems
+
+
 def test_adapters_rule_is_active():
   check_layering = _checker()
-  assert any("adapters" in rel for rel, _f, _w in check_layering.RULES)
+  assert any("adapters" in rel for rel, *_ in check_layering.RULES)
   assert not [p for p in check_layering.check() if "adapters" in p]
 
 
@@ -112,7 +135,7 @@ def test_router_policy_rule_is_active():
   """The live module passes, and the rule set actually names it (deleting
   the rule would silently disable the gate)."""
   check_layering = _checker()
-  assert any("router_policy" in rel for rel, _f, _w in check_layering.RULES)
+  assert any("router_policy" in rel for rel, *_ in check_layering.RULES)
   assert not [p for p in check_layering.check() if "router_policy" in p]
 
 

@@ -2,7 +2,7 @@
 
 ISSUE 2 coverage: histogram bucket math and quantile edge cases, scheduler
 gauge/counter lifecycle under admit/evict/grow, decode-path attribution
-against the ``select_decode_path`` dispatch table, per-request stage
+(the scheduler's ``decode_path`` label), per-request stage
 timelines (+ the ``/v1/requests/{id}/timeline`` endpoint and slow-request
 log), the buffered-export / residual-token-group tracer fixes, cluster
 snapshot merging, and a metric-name snapshot so the ``/metrics`` exposition
@@ -268,27 +268,6 @@ def test_labeled_histograms_render_snapshot_merge():
   assert 'xot_tpu_ttft_seconds_bucket{le="0.025"} 1' in m2.render_prometheus()
 
 
-# -------------------------------------------------- decode-path attribution
-
-
-def test_resolved_decode_path_matches_dispatch_table():
-  from xotorch_support_jetson_tpu.inference.paging import resolved_decode_path, select_decode_path
-
-  # Fixture points straight from the dispatch table (TPU platform).
-  assert select_decode_path(16, 4096, "", platform="tpu") == "gather"
-  assert select_decode_path(48, 4096, "", platform="tpu") == "dense"
-  assert select_decode_path(48, 4096, "int8", platform="tpu") == "kernel"
-  assert select_decode_path(8, 32768, "", platform="tpu") == "kernel"
-  # Attribution: non-paged layouts are "dense"; a paged program degrades a
-  # "dense" verdict to "kernel" (same rule as fused_paged_batch_decode);
-  # non-TPU platforms always take the gather reference path.
-  assert resolved_decode_path(16, 4096, "", paged=False, platform="tpu") == "dense"
-  assert resolved_decode_path(16, 4096, "", paged=True, platform="tpu") == "gather"
-  assert resolved_decode_path(48, 4096, "", paged=True, platform="tpu") == "kernel"
-  assert resolved_decode_path(48, 4096, "int8", paged=True, platform="tpu") == "kernel"
-  assert resolved_decode_path(48, 4096, "int8", paged=True, platform="cpu") == "gather"
-
-
 # ------------------------------------------------------ scheduler telemetry
 
 
@@ -305,6 +284,25 @@ def _tiny_batched_server(n_slots=2, chunk=2):
   engine = JaxShardedInferenceEngine(use_local_mesh=False)
   engine.load_test_model(shard, cfg, params)
   return BatchedServer(engine, n_slots=n_slots, chunk=chunk)
+
+
+@pytest.mark.parametrize(
+  "paged,kernel_can_run,label",
+  [("0", True, "dense"), ("1", False, "gather"), ("1", True, "kernel")],
+  ids=["dense-slots", "paged-on-cpu", "paged-where-the-kernel-runs"],
+)
+def test_scheduler_decode_path_label(monkeypatch, paged, kernel_can_run, label):
+  """The label on ``decode_chunks_total{path=}`` / ``decode_tokens_total{path=}``
+  is the layout, then what the decode programs resolve ``use_kernel=None``
+  to (ops/paged.py ``paged_kernel_supported``): a CPU takes the gather."""
+  monkeypatch.setenv("XOT_TPU_PAGED", paged)
+  if kernel_can_run:
+    monkeypatch.setattr("xotorch_support_jetson_tpu.ops.paged.paged_kernel_supported", lambda cfg, platform=None: True)
+  server = _tiny_batched_server()
+  assert server.decode_path == "dense"  # a bare server; resolved with the cache
+  server._ensure_cache()
+  assert server.decode_path == label
+  server.shutdown()
 
 
 def test_scheduler_gauges_counters_and_histograms(monkeypatch):
@@ -757,7 +755,6 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_slo_attainment",  # {class}
   "xot_tpu_goodput_tok_s",  # {class}
   "xot_tpu_node_role",  # 0=both 1=prefill 2=decode (ISSUE 10)
-  "xot_tpu_paged_kernel_tile",  # shape-aware page-tile verdict for this pool (ISSUE 11)
   "xot_tpu_kv_quant_bits",  # 16=bf16 8=int8 4=int4 (ISSUE 11)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
   # Multi-LoRA serving (ISSUE 15; swaps labeled {direction}, requests

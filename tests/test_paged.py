@@ -173,44 +173,80 @@ def test_paged_kernel_never_reads_past_a_rows_length(quant):
   assert np.array_equal(got, run(clean))
 
 
-def test_decode_path_dispatch_table(monkeypatch):
-  """Representative (batch, context, quant) points hit the measured winners;
-  the env override forces either in-program path. Retuned in round 15
-  (ISSUE 11): with in-kernel dequant + the shape-aware page tile, QUANTIZED
-  pages dispatch the kernel at every batched shape — the r2 gather win only
-  survives for near-solo rows and small-batch bf16."""
-  from xotorch_support_jetson_tpu.inference.paging import select_decode_path
+# The one owner of "which attention core does a paged program run" (ops/paged.py):
+# name -> (config overrides, the kernel can run for it on a TPU, it attends through the kernel when told to).
+_MLA = dict(kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, family="deepseek-v2")
+_OWNER_CONFIGS = {
+  "gqa-hd64": (dict(head_dim=64), True, True),
+  "gqa-hd128": (dict(head_dim=128), True, True),
+  "gqa-hd256": (dict(head_dim=256), True, True),
+  "gqa-hd96": (dict(head_dim=96), False, True),  # no tiling for that width on the chip; interpret-mode tests may still ask for it
+  "softcap": (dict(head_dim=64, attn_logit_softcap=30.0), False, False),
+  "window": (dict(head_dim=64, sliding_window=32), False, False),
+  "mla": (_MLA, False, False),
+}
 
-  monkeypatch.delenv("XOT_TPU_PAGED_KERNEL", raising=False)
-  # Small-batch bf16 serving shapes: the fused XLA gather (round-2
-  # measurement, re-held in the round-15 retune for unquantized pages).
-  assert select_decode_path(16, 1024, "", platform="tpu") == "gather"
-  # Near-solo rows can't fill the kernel grid regardless of quant mode.
-  assert select_decode_path(4, 4096, "int8", platform="tpu") == "gather"
-  assert select_decode_path(2, 1024, "int4", platform="tpu") == "gather"
-  # Past the B=16 knee with bf16 KV: dense slots (round-5 knee study).
-  assert select_decode_path(48, 1024, "", platform="tpu") == "dense"
-  # Quantized pages at EVERY batched shape: the kernel (ISSUE 11 criterion —
-  # B in {16, 48, 96} under int8-KV and int4-KV).
-  for quant in ("int8", "int4"):
-    for b in (16, 48, 96):
-      for ctx in (1024, 4096, 32768):
-        assert select_decode_path(b, ctx, quant, platform="tpu") == "kernel", (b, ctx, quant)
-  assert select_decode_path(8, 4096, "int8", platform="tpu") == "kernel"  # r15 retune: was gather
-  # Long contexts: the kernel reads resident pages only, any quant.
-  assert select_decode_path(8, 32768, "", platform="tpu") == "kernel"
-  assert select_decode_path(16, 8192, "int8", platform="tpu") == "kernel"
-  # int4 has no dense layout: no (batch, ctx) point may ever say "dense".
-  for b in (1, 16, 48, 96, 256):
-    for ctx in (1024, 4096, 32768):
-      assert select_decode_path(b, ctx, "int4", platform="tpu") != "dense"
-  # Non-TPU platforms always take the gather reference.
-  assert select_decode_path(48, 32768, "int8", platform="cpu") == "gather"
-  # Env forcing keeps the old opt-in/off behaviors.
-  monkeypatch.setenv("XOT_TPU_PAGED_KERNEL", "1")
-  assert select_decode_path(16, 1024, "", platform="tpu") == "kernel"
-  monkeypatch.setenv("XOT_TPU_PAGED_KERNEL", "0")
-  assert select_decode_path(48, 32768, "int8", platform="tpu") == "gather"
+
+@pytest.mark.parametrize("no_flash", [False, True], ids=["flash", "XOT_TPU_NO_FLASH"])
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("name", list(_OWNER_CONFIGS))
+def test_paged_kernel_supported_is_the_resolver(monkeypatch, name, platform, no_flash):
+  """The kernel wherever it can run — a TPU, plain GQA attention at a head
+  width it tiles, ``XOT_TPU_NO_FLASH`` unset — and the gather elsewhere;
+  nothing else (batch, context, KV mode) has a say."""
+  from xotorch_support_jetson_tpu.ops.paged import paged_kernel_supported
+
+  overrides, can_run, _ = _OWNER_CONFIGS[name]
+  monkeypatch.delenv("XOT_TPU_NO_FLASH", raising=False)
+  if no_flash:
+    monkeypatch.setenv("XOT_TPU_NO_FLASH", "1")
+  assert paged_kernel_supported(tiny_test_config(**overrides), platform=platform) is (can_run and platform == "tpu" and not no_flash)
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+@pytest.mark.parametrize("name", list(_OWNER_CONFIGS))
+def test_kernel_attends_is_the_layer_steps_predicate(name, use_kernel):
+  from xotorch_support_jetson_tpu.ops.paged import kernel_attends
+
+  overrides, _, attends = _OWNER_CONFIGS[name]
+  assert kernel_attends(tiny_test_config(**overrides), use_kernel) is (use_kernel and attends)
+
+
+@pytest.mark.parametrize("mp,tile", [(6, 4), (64, 8), (3, 2)])
+def test_page_tile_is_the_constant_clamped_to_the_table(mp, tile):
+  from xotorch_support_jetson_tpu.ops.paged import PAGE_TILE, _page_tile
+
+  assert PAGE_TILE == 8
+  assert _page_tile(mp) == tile
+
+
+@pytest.mark.parametrize("can_run", [True, False], ids=["kernel", "gather"])
+@pytest.mark.parametrize("program", ["plain", "mixed", "spec"])
+def test_decode_programs_resolve_use_kernel_through_the_owner(monkeypatch, program, can_run):
+  """``use_kernel=None`` is ``paged_kernel_supported(cfg)`` in all three
+  public programs; an explicit value passes through."""
+  from xotorch_support_jetson_tpu.models import decoder
+
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+
+  params = pool = None  # the jitted programs are stood in for below: nothing reads them
+  shard = Shard("m", 0, CFG.n_layers - 1, CFG.n_layers)
+  B, mp = 2, 128 // PS
+  tok, bt = jnp.zeros((B, 1), jnp.int32), jnp.zeros((B, mp), jnp.int32)
+  pos, active, temps = jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool), jnp.zeros((B,), jnp.float32)
+  impl, at, call = {
+    "plain": ("_fused_paged_batch_decode_impl", 13, lambda **kw: decoder.fused_paged_batch_decode(params, CFG, shard, tok, pool, bt, pos, active, temps, 2, page_size=PS, **kw)),
+    "mixed": ("_fused_mixed_paged_batch_decode_impl", 17, lambda **kw: decoder.fused_mixed_paged_batch_decode(
+      params, CFG, shard, tok, pool, bt, pos, active, temps, jnp.zeros((1, 16), jnp.int32), bt[:1], jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), 2, page_size=PS, **kw)),
+    "spec": ("_fused_spec_paged_batch_decode_impl", -2, lambda **kw: decoder.fused_spec_paged_batch_decode(
+      params, CFG, shard, None, CFG, shard, tok, pool, None, bt, pos, active, jnp.zeros((B,), jnp.int32), temps, 1, 1, page_size=PS, **kw)),
+  }[program]
+  seen = []
+  monkeypatch.setattr(decoder, impl, lambda *a: seen.append(a[at]))
+  monkeypatch.setattr("xotorch_support_jetson_tpu.ops.paged.paged_kernel_supported", lambda cfg, platform=None: can_run)
+  call()
+  call(use_kernel=not can_run)
+  assert seen == [can_run, not can_run]
 
 
 def _prefill_both(params, shard, prompts, n_slots, max_seq=128):

@@ -1,18 +1,13 @@
-"""int4-KV pages + the retuned paged dispatch (ISSUE 11).
+"""int4-KV pages (ISSUE 11).
 
 Correctness claims:
 - int4 KV pack/unpack round-trips exactly and the dequant error is bounded;
 - the Pallas paged kernel's in-kernel int4 dequant (two-dot nibble split)
   == the gather reference consuming the SAME packed pools + scales —
   token-exact at the same quantization, across page-tile widths;
-- the new wide page tiles (8/16 — the shape-aware retune) stay exact for
-  int8 pools too;
+- the wide page tiles (8/16) stay exact for int8 pools too;
 - paged int4-KV decode == dense int4-KV decode, token for token (int4 is
   exact vs its OWN quantized reference — never vs int8/bf16);
-- the decision matrix: quantized pages dispatch the kernel at every batched
-  shape (B in {16, 48, 96} × {int8, int4}), and ``resolved_decode_path``
-  attribution can never disagree with ``select_decode_path`` across the
-  full (batch, context, quant, tile) grid;
 - scheduler pool block math under int4: ~2x the int8 pages at the same
   bf16 dense budget, enough that the dense-48 budget covers 96 FULL context
   windows (the B>=96 admission knee) — and requests still serve.
@@ -91,8 +86,8 @@ def test_paged_kernel_int4_dequant_matches_gather_reference():
 
 @pytest.mark.parametrize("pages_per_step", [8, 16])
 def test_paged_kernel_wide_tiles_match_reference(pages_per_step):
-  """The retuned wide tiles (select_page_tile's B=48/96 verdicts) stay exact
-  for int8 pools — including mp that the tile doesn't divide."""
+  """The wide tiles (the served ``PAGE_TILE`` and twice it) stay exact for
+  int8 pools — including mp that the tile doesn't divide."""
   rng = np.random.default_rng(31)
   B, Hq, Hkv, hd, ps, P = 2, 4, 2, 64, 4, 40
   mp = 18  # not a multiple of 8 or 16
@@ -155,59 +150,6 @@ def test_paged_int4kv_decode_matches_dense_int4kv():
   )
   assert np.array_equal(np.asarray(td), np.asarray(tp))
   assert np.array_equal(np.asarray(pd), np.asarray(pq))
-
-
-def test_page_tile_dispatch_table(monkeypatch):
-  """Shape-aware page-tile verdicts (the r15 retune) + the env force-cap."""
-  from xotorch_support_jetson_tpu.inference.paging import select_page_tile
-  from xotorch_support_jetson_tpu.ops.paged import _page_tile
-
-  monkeypatch.delenv("XOT_TPU_PAGED_TILE", raising=False)
-  # Small batch: bf16 keeps the original G=4; quantized pages (half/quarter
-  # the DMA bytes per tile) go one bucket wider.
-  assert select_page_tile(16, 1024, "") == 4
-  assert select_page_tile(16, 4096, "int8") == 8
-  assert select_page_tile(8, 1024, "int4") == 8
-  # The dense-knee bucket and beyond (wider tiles; since PR 25 no width from 4 up is faster, PERF.md §6).
-  assert select_page_tile(48, 1024, "int8") == 8
-  assert select_page_tile(48, 32768, "") == 8
-  assert select_page_tile(96, 1024, "int8") == 16
-  assert select_page_tile(96, 32768, "int4") == 16
-  # The kernel clamps the verdict to a power of two <= mp.
-  assert _page_tile(6, batch=96, context=6 * 64, kv_quant="int8") == 4
-  assert _page_tile(64, batch=96, context=64 * 64, kv_quant="int8") == 16
-  assert _page_tile(64, batch=16, context=64 * 64, kv_quant="") == 4
-  # XOT_TPU_PAGED_TILE force-caps every shape (the sweep knob).
-  monkeypatch.setenv("XOT_TPU_PAGED_TILE", "2")
-  assert _page_tile(64, batch=96, context=64 * 64, kv_quant="int8") == 2
-  monkeypatch.setenv("XOT_TPU_PAGED_TILE", "32")
-  assert _page_tile(64, batch=4, context=64 * 64) == 32
-
-
-@pytest.mark.parametrize("tile", [1, 4, 8, 16])
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
-def test_resolved_path_attribution_matches_dispatch_grid(monkeypatch, tile, quant):
-  """Satellite (ISSUE 11): ``resolved_decode_path`` — the metrics
-  attribution label — can never silently disagree with the
-  ``select_decode_path`` verdict it mirrors, across the full (batch,
-  context, quant-mode, tile) grid. The tile axis rides the env force-cap:
-  it must never change WHICH path is attributed, only the kernel's
-  geometry."""
-  from xotorch_support_jetson_tpu.inference.paging import resolved_decode_path, select_decode_path
-
-  monkeypatch.delenv("XOT_TPU_PAGED_KERNEL", raising=False)
-  monkeypatch.setenv("XOT_TPU_PAGED_TILE", str(tile))
-  for batch in (1, 4, 8, 16, 48, 96):
-    for context in (1024, 4096, 32768):
-      verdict = select_decode_path(batch, context, quant, platform="tpu")
-      resolved = resolved_decode_path(batch, context, quant, paged=True, platform="tpu")
-      if verdict == "gather":
-        assert resolved == "gather", (batch, context, quant, tile)
-      else:  # "kernel" directly; "dense" degrades to kernel inside a paged program
-        assert resolved == "kernel", (batch, context, quant, tile)
-      # A non-paged layout is always attributed dense; non-TPU pins gather.
-      assert resolved_decode_path(batch, context, quant, paged=False, platform="tpu") == "dense"
-      assert resolved_decode_path(batch, context, quant, paged=True, platform="cpu") == "gather"
 
 
 def test_int4_block_math_moves_admission_knee_past_96():

@@ -1,5 +1,6 @@
 """Flash-attention kernel correctness (interpret mode on CPU; the same kernel
-compiles for TPU via Mosaic — bench.py exercises that path)."""
+compiles for TPU via Mosaic — tests/test_tpu_compile.py compiles it for a
+described v5e and chip_smoke.py runs it on the chip)."""
 
 import jax
 import jax.numpy as jnp

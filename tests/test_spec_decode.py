@@ -85,8 +85,8 @@ def test_spec_decode_unrelated_draft_is_exact(gamma):
 def test_peaked_echo_model_hits_high_acceptance_and_stays_exact():
   """The peaked-logit synthetic model (utils/synthetic.py): the int8
   self-draft reaches near-full acceptance — the speculative win is
-  measurable OFFLINE (bench.py spec_peak_* fields record it) — while the
-  output stays token-identical to plain greedy.
+  measurable OFFLINE — while the output stays token-identical to plain
+  greedy.
 
   The acceptance assertion is a BUILD-VARIANCE CAPABILITY PROBE (ISSUE 7),
   not a loosened constant: the echo margin rides on int8-rounding noise and

@@ -97,17 +97,16 @@ def _compile_paged_kernel(chip, quant: str, batch: int, tile: int, hd: int, mp: 
   return text
 
 
-# Every (quant, tile) the tables can pick: _PAGE_TILE_TABLE answers G=4/8/16
-# at B=16/48/96. A tile is two VMEM slots of G whole pages (all kv heads).
+# Every KV mode at the served tile (ops/paged.py PAGE_TILE) and 16/48/96 rows.
+# A tile is two VMEM slots of G whole pages (all kv heads).
 @pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("batch,tile", [(16, 4), (48, 8), (96, 16)])
+@pytest.mark.parametrize("batch", [16, 48, 96])
 @pytest.mark.parametrize("quant", ["", "int8", "int4"])
-def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, tile, hd):
-  from xotorch_support_jetson_tpu.inference.paging import _PAGE_TILE_TABLE
+def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, hd):
+  from xotorch_support_jetson_tpu.ops.paged import _page_tile
 
-  assert tile in {row[-1] for row in _PAGE_TILE_TABLE}
   mp = 16  # 1k context
-  assert "tpu_custom_call" in _compile_paged_kernel(chip, quant, batch, tile, hd, mp, batch * mp + 1)
+  assert "tpu_custom_call" in _compile_paged_kernel(chip, quant, batch, _page_tile(mp), hd, mp, batch * mp + 1)
 
 
 @pytest.mark.parametrize(

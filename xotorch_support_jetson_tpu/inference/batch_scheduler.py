@@ -334,11 +334,8 @@ class BatchedServer:
     # layout's HBM budget in PAGES, which under int8-KV quantization is 2x
     # the dense slot count's worth of contexts; see _ensure_cache) — and
     # page-aligned prompt prefixes dedup across requests. XOT_TPU_PAGED=0
-    # restores the dense slot-per-max_seq cache; XOT_TPU_PAGED=auto defers
-    # the layout to the dispatch table (inference/paging.py
-    # select_decode_path) at cache-build time.
-    self._paged_mode = os.getenv("XOT_TPU_PAGED", "1")
-    self.paged = self._paged_mode not in ("0", "false")
+    # restores the dense slot-per-max_seq cache.
+    self.paged = os.getenv("XOT_TPU_PAGED", "1") not in ("0", "false")
     self.page_size = int(os.getenv("XOT_TPU_PAGE_SIZE", "64"))
     # Chunked prefill (paged mode): a prompt longer than this many tokens
     # prefills in chunks with DECODE TICKS interleaved between them, so one
@@ -825,14 +822,6 @@ class BatchedServer:
 
     kv_quant = kv_quant_mode(eng.cfg)
     self.max_seq = min(eng.max_seq_len, eng.cfg.max_seq_len)
-    if self._paged_mode == "auto":
-      # Defer the LAYOUT to the dispatch table: "dense" at this pool's
-      # (slots, window, quant) point means the dense slot cache beats both
-      # paged paths and per-slot HBM is affordable by construction (the
-      # dense pool is the budget the paged default is sized from).
-      from .paging import select_decode_path
-
-      self.paged = select_decode_path(self.n_slots, self.max_seq, kv_quant) != "dense"
     # Batched speculation verdict (module docstring): needs the resolved
     # layout (the paged program excludes MLA) and must land BEFORE pool
     # sizing so the draft cache's bytes can enter the page budget.
@@ -920,22 +909,11 @@ class BatchedServer:
     if self.spec and "model" in self.spec_proposers:
       self.draft_cache = self.ops.init_draft_cache(self.n_slots, self.max_seq)
     # Decode-path attribution label for this pool's compiled chunk program:
-    # fixed per (layout, slots, window, quant) — the same resolution
-    # fused_paged_batch_decode applies to use_kernel=None.
-    from .paging import resolved_decode_path, select_page_tile
+    # what fused_paged_batch_decode resolves use_kernel=None to.
+    from ..ops.paged import paged_kernel_supported
 
     self.kv_quant = kv_quant
-    self.decode_path = resolved_decode_path(
-      self.n_slots, (self.pages_per_row * self.page_size) if self.paged else self.max_seq,
-      kv_quant, paged=self.paged, cfg=eng.cfg,
-    )
-    # Kernel-geometry attribution (ISSUE 11): the page-tile verdict this
-    # pool's shape resolves to, and the KV quant width — regressions in
-    # either are diagnosable from /metrics without re-deriving the tables.
-    metrics.set_gauge(
-      "paged_kernel_tile",
-      select_page_tile(self.n_slots, self.pages_per_row * self.page_size, kv_quant) if self.paged else 0,
-    )
+    self.decode_path = "dense" if not self.paged else "kernel" if paged_kernel_supported(eng.cfg) else "gather"
     metrics.set_gauge("kv_quant_bits", {"": 16, "int8": 8, "int4": 4}[kv_quant])
     self._update_gauges()
 

@@ -2,11 +2,7 @@
 
 The device never sees this: pages are allocated/freed/shared here and the
 resulting block tables ride into the compiled decode program as traced
-operands. Three pieces:
-
-- ``select_decode_path`` — the (batch, context, quant-mode) dispatch table
-  that picks XLA-gather vs the Pallas paged kernel vs dense slots per shape
-  (the measured winner flips; see the table's provenance comments).
+operands. Two pieces:
 
 - ``PageAllocator`` — a free list over pages ``1..n_pages-1`` (page 0 is the
   device-side trash page and is never handed out).
@@ -49,152 +45,9 @@ from collections import OrderedDict
 
 import numpy as np
 
-# ------------------------------------------------- decode-path dispatch table
-#
-# Which decode attention path wins flips with (batch, context, KV quant mode)
-# — measured, not guessed — so neither path is hardwired:
-#
-# - "gather": XLA's fused jnp.take+attention over the page pool. Round-2
-#   measurement: 1000 vs kernel 854 vs dense 926 aggregate tok/s at B=16×1K
-#   — XLA fuses the gather without materializing pages, and at tiny batch
-#   the grid-step overhead of the kernel doesn't amortize.
-# - "kernel": the Pallas paged kernel (ops/paged.py) — block-table
-#   indirection via scalar prefetch, page-tiled split-K, in-kernel
-#   int8/int4-KV dequant. The round-2 gather win at B=16 was measured
-#   against the OLD kernel (out-of-kernel dequant, fixed G=4 tile);
-#   re-measured this round with in-kernel dequant, the shape-aware page
-#   tile (``select_page_tile``) and the fused sampling epilogue, the kernel
-#   takes every QUANTIZED batched shape — B=16 closed the last gap (the
-#   r2 854 number was paying a dequantized-cache copy the kernel no longer
-#   makes), and at B=48/96 the wider tile cuts the sequential grid steps
-#   that made the old kernel trail dense. Quantized-KV rows therefore
-#   dispatch "kernel" from B>4 up; the gather remains the near-solo
-#   (B<=4) winner where one row cannot fill the grid.
-# - "dense": advisory only — the dense slot layout still beats both paged
-#   paths for UNQUANTIZED (bf16) KV at mid batch/short context (round-5:
-#   dense bf16 B=48 vs the old paged knee; bf16 pages move 2x the bytes of
-#   int8 so the kernel's in-register dequant win doesn't apply). Only
-#   honorable where the LAYOUT is still a free choice (batch_scheduler
-#   _ensure_cache under XOT_TPU_PAGED=auto); inside an already-paged
-#   program the decoder degrades it to "kernel" (the closest-to-dense
-#   paged path — no materialized gather). int4-KV has no dense layout at
-#   all (packed pages only), so its rows can never say "dense".
-#
-# Rows are (max_batch, max_context_tokens, kv_quant, path); None = any.
-# First row whose bounds cover the query wins.
-
-_DECODE_PATH_TABLE = (
-  (4, 4096, None, "gather"),  # near-solo rows, serving ctx: fused XLA gather (r2 measurement)
-  (None, None, "int8", "kernel"),  # quantized pages: in-kernel dequant + shape-aware tile (r6 retune)
-  (None, None, "int4", "kernel"),  # int4 pages are kernel-or-gather by construction; kernel from B>4
-  (16, 4096, "", "gather"),  # small-batch bf16 serving ctx: gather still fuses best (r2, re-held r6)
-  (None, 4096, "", "dense"),  # bf16 KV past the B=16 knee: dense slots win when HBM affords
-  (None, None, None, "kernel"),  # large batch or long context
-)
-
-
-def _table_match(table, batch: int, context: int, kv_quant: str):
-  """First-row-wins walk shared by every (max_batch, max_context, quant,
-  verdict) dispatch table in this module — ONE definition of the matching
-  semantics, so a boundary fix can't land in one table's walk and not the
-  other's."""
-  for max_b, max_ctx, quant, verdict in table:
-    if max_b is not None and batch > max_b:
-      continue
-    if max_ctx is not None and context > max_ctx:
-      continue
-    if quant is not None and quant != kv_quant:
-      continue
-    return verdict
-  return table[-1][-1]
-
-
-def select_decode_path(batch: int, context: int, kv_quant: str = "", platform: str | None = None) -> str:
-  """Pick the decode attention path for a (batch, context, quant) point.
-
-  Returns "gather" | "kernel" | "dense" per the measured table above.
-  ``context`` is the per-row KV window in TOKENS (block-table width × page
-  size). ``XOT_TPU_PAGED_KERNEL=1`` forces "kernel", ``=0`` forces "gather"
-  (the old opt-in/off behaviors); non-TPU platforms always take the gather
-  reference path.
-  """
-  forced = os.getenv("XOT_TPU_PAGED_KERNEL")
-  if forced is not None:
-    from ..utils.helpers import env_flag
-
-    return "kernel" if env_flag("XOT_TPU_PAGED_KERNEL") else "gather"
-  if platform is None:
-    import jax
-
-    platform = jax.default_backend()
-  if platform != "tpu":
-    return "gather"
-  return _table_match(_DECODE_PATH_TABLE, batch, context, kv_quant)
-
-
-# ------------------------------------------------- page-tile dispatch table
-#
-# How many pages the paged kernel fetches and computes per loop iteration
-# (ops/paged.py G). The values date from the kernel whose grid walked the
-# whole block table, ceil(mp/G) sequential steps per (row, kv-head), where a
-# wider tile amortized per-step overhead. The kernel now loops over each
-# row's resident pages with one DMA per page, and the PR 25 sweep on the
-# chip read 4, 8, 16 and 32 within 3 % at every shape tried, 1 some 7-12 %
-# slower (PERF.md §6): the table decides VMEM use (two slots of G pages),
-# hardly speed, and is a candidate for removal.
-#
-# Rows are (max_batch, max_context_tokens, kv_quant, pages_per_step);
-# None = any; first row whose bounds cover the query wins. The kernel
-# clamps the verdict to the largest power of two <= mp either way, and
-# ``XOT_TPU_PAGED_TILE`` still force-caps every shape (the in-process
-# sweep knob).
-
-_PAGE_TILE_TABLE = (
-  (16, 8192, "", 4),  # small-batch bf16
-  (16, 8192, None, 8),  # small-batch quantized pages
-  (48, None, None, 8),  # the dense-knee bucket
-  (None, None, None, 16),  # B>48 or very long ctx
-)
-
-
-def select_page_tile(batch: int, context: int, kv_quant: str = "") -> int:
-  """Pages-per-loop-iteration verdict for a (batch, context, quant) point.
-
-  The raw table verdict — the kernel (ops/paged.py ``_page_tile``) clamps it
-  to a power of two <= mp and applies the ``XOT_TPU_PAGED_TILE`` force-cap.
-  Host-side and pure, so the scheduler can attribute the chosen geometry
-  (``paged_kernel_tile`` gauge) and bench can emit it per shape."""
-  return _table_match(_PAGE_TILE_TABLE, batch, context, kv_quant)
-
-
-def resolved_decode_path(batch: int, context: int, kv_quant: str = "", paged: bool = True, cfg=None, platform: str | None = None) -> str:
-  """The decode path a dispatch will ACTUALLY run — the attribution label
-  for per-chunk telemetry (utils/metrics.py ``decode_chunks_total{path=}``).
-
-  Mirrors ``models/decoder.py fused_paged_batch_decode``'s resolution of
-  ``use_kernel=None``: a non-paged layout is simply "dense"; inside an
-  already-paged program a "dense" table verdict degrades to "kernel" (the
-  layout is fixed), and an unsupported-kernel cfg (softcap/window attention)
-  pins "gather". Keeping this next to the table means the counters report
-  the path the compiled program really took, not the table's raw advice.
-  """
-  if not paged:
-    return "dense"
-  path = select_decode_path(batch, context, kv_quant, platform=platform)
-  if path == "gather":
-    return "gather"
-  if cfg is not None:
-    from ..ops.paged import paged_kernel_supported
-
-    if not paged_kernel_supported(cfg):
-      return "gather"
-  return "kernel"
-
-
 # ------------------------------------------- per-row speculation policy
 #
-# The same dispatch-table philosophy as _DECODE_PATH_TABLE, extended to a
-# PER-ROW policy (ISSUE 7): which speculation depth wins is a function of the
+# A PER-ROW policy (ISSUE 7): which speculation depth wins is a function of the
 # measured acceptance, so neither "always speculate" nor "never" is
 # hardwired — each batch row carries an acceptance EWMA and its gamma walks
 # this table every chunk. Provenance for the thresholds: with an ~4x-faster
@@ -329,8 +182,7 @@ def select_mixed_budget(cap: int, burn: float | None, residents: int = 1, backlo
   Under measured burn the table's shrink wins unscaled: smoothing is
   exactly what a burning ITL objective buys with the TTFT trade.
   ``XOT_TPU_MIXED_BUDGET`` (tokens) force-pins the verdict, clamped to
-  [1, cap] — the operator's escape hatch, same spirit as
-  ``XOT_TPU_PAGED_TILE``."""
+  [1, cap] — the operator's escape hatch."""
   cap = max(int(cap), 1)
   forced = int(os.getenv("XOT_TPU_MIXED_BUDGET", "0") or 0)
   if forced > 0:

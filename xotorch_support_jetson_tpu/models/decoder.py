@@ -927,9 +927,9 @@ def fused_speculative_generate(
   (slot-indexed cache, see init_kv_cache).
 
   Acceptance rate ≈ speedup. With a real checkpoint and an int8
-  self-draft, argmax agreement is high (peaked distributions); the
-  random-weight bench has near-uniform logits, so its acceptance — reported
-  as ``spec_acceptance`` in bench.py — understates real-model behavior.
+  self-draft, argmax agreement is high (peaked distributions); a
+  random-weight model has near-uniform logits, so its acceptance understates
+  real-model behavior.
 
   Returns (buf [max_steps+gamma+1], n_generated, n_rounds, cache_t,
   cache_d); trim to the first EOS within buf[:n] host-side. Acceptance rate
@@ -1237,7 +1237,7 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
   pos = positions[:, 0]
   lengths = pos + 1  # valid KV slots incl. the token written below
-  from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref
+  from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref
 
   if kv_quant is None:
     kv_quant = pool_kv_quant(pool, cfg)
@@ -1250,7 +1250,7 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
     attn = paged_mla_attention_ref(q_nope, q_pe, pool["k"], pool["v"], block_tables, lengths, w_kv_b, cfg.v_head_dim, page_size, layer=layer)
   else:
     q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
-    kernel = use_kernel and cfg.plain_attention  # the Pallas kernel has no softcap/window
+    kernel = kernel_attends(cfg, use_kernel)
     pool = _write_kv(pool, k[:, 0], v[:, 0], layer, block_tables, pos, page_size, kv_quant, kernel)
     scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if kv_quant else {}
     if kernel:
@@ -1268,12 +1268,6 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
     h = h + attn_out
   h, _ = _mlp_block(h, p, cfg)
   return h, pool
-
-
-def _kernel_attends(cfg: ModelConfig, use_kernel: bool) -> bool:
-  """Whether a paged program's attention core is the Pallas kernel — the
-  layer steps' own test; such a program carries its pool in the kernel's form."""
-  return bool(use_kernel) and cfg.plain_attention and not cfg.is_mla
 
 
 def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None):
@@ -1298,9 +1292,9 @@ def _paged_decode_scan(params, cfg: ModelConfig, shard: Shard, token, pool, bloc
   the mixed tick's decode half is the plain program's decode half by
   construction (the token-identity contract of ISSUE 14)."""
 
-  from ..ops.paged import kernel_pool_form, stored_pool_form
+  from ..ops.paged import kernel_attends, kernel_pool_form, stored_pool_form
 
-  stored, kv_quant, kernel_form = pool, pool_kv_quant(pool, cfg), _kernel_attends(cfg, use_kernel)
+  stored, kv_quant, kernel_form = pool, pool_kv_quant(pool, cfg), kernel_attends(cfg, use_kernel)
   if kernel_form:
     pool = kernel_pool_form(pool)  # once a dispatch, not once a layer: the steps write and read this form
 
@@ -1335,15 +1329,9 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
   ``next_token`` is the device-resident chain input for the following chunk
   (see ``fused_batch_decode``).
 
-  ``use_kernel=None`` resolves per shape through the dispatch table
-  (inference/paging.py select_decode_path): the XLA gather stays the
-  small-batch serving winner, the Pallas kernel takes large-batch and
-  long-context shapes (with in-kernel int8-KV dequant when the pool is
-  quantized). A "dense" verdict degrades to the kernel here — the layout is
-  already paged, and the kernel is the no-materialized-gather path closest
-  to dense behavior.
+  ``use_kernel=None`` resolves to the Pallas kernel wherever it can run
+  (ops/paged.py ``paged_kernel_supported``), the XLA gather elsewhere.
   """
-  from ..inference.paging import select_decode_path
   from ..ops.paged import paged_kernel_supported
 
   if not (shard.is_first_layer and shard.is_last_layer):
@@ -1351,8 +1339,7 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
   if key is None:
     key = jax.random.PRNGKey(0)
   if use_kernel is None:
-    context = int(jnp.shape(block_tables)[1]) * int(page_size)
-    use_kernel = paged_kernel_supported(cfg) and select_decode_path(token.shape[0], context, pool_kv_quant(pool, cfg)) != "gather"
+    use_kernel = paged_kernel_supported(cfg)
   B = token.shape[0]
   top_ks = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
   return _fused_paged_batch_decode_impl(
@@ -1410,10 +1397,9 @@ def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token
   (``pf_prefix + S_pad <= max_seq``, the scatter-clamp constraint of
   ``prefill_into_pages_many``). Returns the plain contract
   (tokens [B, n_steps], next_token [B, 1], positions [B], pool) — the slice
-  emits nothing; its pages simply advance. ``use_kernel=None`` resolves
-  through the same dispatch table as the plain program.
+  emits nothing; its pages simply advance. ``use_kernel=None`` resolves as
+  in the plain program.
   """
-  from ..inference.paging import select_decode_path
   from ..ops.paged import paged_kernel_supported
 
   if not (shard.is_first_layer and shard.is_last_layer):
@@ -1423,8 +1409,7 @@ def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token
   if key is None:
     key = jax.random.PRNGKey(0)
   if use_kernel is None:
-    context = int(jnp.shape(block_tables)[1]) * int(page_size)
-    use_kernel = paged_kernel_supported(cfg) and select_decode_path(token.shape[0], context, pool_kv_quant(pool, cfg)) != "gather"
+    use_kernel = paged_kernel_supported(cfg)
   B = token.shape[0]
   top_ks = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
   return _fused_mixed_paged_batch_decode_impl(
@@ -1467,8 +1452,8 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
   it, as in ``_paged_layer_step``. positions [B, W] are each row's own
   absolute window positions (rows are at different depths). Writes all W
   tokens' KV through the block tables, then attends per window position
-  through the tuned Pallas kernel when the dispatch table said kernel
-  (``use_kernel`` — W is small and static, so the window unrolls into W
+  through the Pallas kernel where it attends (ops/paged.py
+  ``kernel_attends`` — W is small and static, so the window unrolls into W
   one-query kernel launches; each query's ``lengths`` is its own
   position+1, the same mask the reference's causal window applies, and the
   batched pool read per launch is exactly a decode step's), or via the
@@ -1479,13 +1464,13 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
   B, W, D = h.shape
   with jax.named_scope("xot.attn_proj"):
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
-  from ..ops.paged import paged_decode_attention, paged_gqa_attention_ref
+  from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref
 
   if kv_quant is None:
     kv_quant = pool_kv_quant(pool, cfg)
   q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
   lengths = positions[:, -1] + 1  # valid KV slots incl. the window's writes
-  kernel = use_kernel and cfg.plain_attention
+  kernel = kernel_attends(cfg, use_kernel)
   for j in range(W):  # W is small (gamma_max+1) and static; per-token scales, the values a one-token-at-a-time write produces
     pool = _write_kv(pool, k[:, j], v[:, j], layer, block_tables, positions[:, j], page_size, kv_quant, kernel, interpret)
   scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if kv_quant else {}
@@ -1664,10 +1649,10 @@ def _fused_spec_batch_decode_impl(params, params_d, cache, cache_d, token, posit
 def _fused_spec_paged_batch_decode_impl(params, params_d, pool, cache_d, token, block_tables, positions, active, gammas, temps, top_ks, key, props, prop_counts, adapter_ids, cfg: ModelConfig, shard: Shard, cfg_d: ModelConfig, shard_d: Shard, n_rounds: int, gamma_max: int, k_max: int, page_size: int, use_kernel: bool, interpret: bool):
   # Inactive rows' window writes must not land on pages another row may now
   # own: pin their tables to the trash page once (tables are chunk-constant).
-  from ..ops.paged import kernel_pool_form, stored_pool_form
+  from ..ops.paged import kernel_attends, kernel_pool_form, stored_pool_form
 
   bt = jnp.where(active[:, None], block_tables, 0)
-  stored, kv_quant, kernel_form = pool, pool_kv_quant(pool, cfg), _kernel_attends(cfg, use_kernel)
+  stored, kv_quant, kernel_form = pool, pool_kv_quant(pool, cfg), kernel_attends(cfg, use_kernel)
   if kernel_form:
     pool = kernel_pool_form(pool)  # once a dispatch, as in _paged_decode_scan
 
@@ -1744,21 +1729,18 @@ def fused_spec_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, params
   ``n_rounds·(gamma_max+1)`` before dispatch
   (inference/paging.py ``spec_worst_advance`` — the gamma-deep analogue of
   the lookahead pipeline's one-extra-chunk headroom). ``use_kernel=None``
-  resolves through the SAME dispatch table as ``fused_paged_batch_decode``
-  — when the table says kernel, the verify window runs per-position through
-  the tuned Pallas kernel instead of the gather reference (ISSUE 11: spec
-  chunks no longer forfeit the kernel win; A/B-pinned token-exact); the
-  draft keeps its dense slot cache either way. ``props``/``prop_counts``/
+  resolves as in ``fused_paged_batch_decode`` — where the kernel attends, the
+  verify window runs per-position through it instead of the gather
+  reference (A/B-pinned token-exact); the draft keeps its dense slot cache
+  either way. ``props``/``prop_counts``/
   ``params_d=None`` as in ``fused_spec_batch_decode`` (ISSUE 12).
   """
-  from ..inference.paging import select_decode_path
   from ..ops.paged import paged_kernel_supported
 
   if cfg.is_mla:
     raise ValueError("fused_spec_paged_batch_decode does not support MLA models (use the dense layout)")
   if use_kernel is None:
-    context = int(jnp.shape(block_tables)[1]) * int(page_size)
-    use_kernel = paged_kernel_supported(cfg) and select_decode_path(jnp.shape(token)[0], context, pool_kv_quant(pool, cfg)) != "gather"
+    use_kernel = paged_kernel_supported(cfg)
   token, active, gammas, temps, top_ks, key = _spec_batch_args(shard, token, active, gammas, temps, top_k, k_max, key)
   props, prop_counts = _spec_props_args(props, prop_counts, token.shape[0], int(n_rounds), int(gamma_max))
   return _fused_spec_paged_batch_decode_impl(

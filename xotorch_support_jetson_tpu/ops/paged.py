@@ -371,28 +371,15 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths,
 # the kernel. Scales are per (token, head) over the whole hd vector, so
 # one [1, ps] scale row serves both halves.
 
-_PAGE_TILE_DEFAULT = 4
+PAGE_TILE = 8  # no width from 4 up wins (4-32 within 3 % at 16, 48 and 96 rows; PERF.md §6, PR 25); it sets the VMEM held: two slots of G pages
 
 
-def _page_tile(mp: int, batch: int | None = None, context: int | None = None, kv_quant: str = "") -> int:
+def _page_tile(mp: int) -> int:
   """Pages fetched and computed per loop iteration: the largest power of two
-  ≤ mp, capped at the shape-aware dispatch verdict (inference/paging.py
-  ``select_page_tile``). ``XOT_TPU_PAGED_TILE`` force-caps every shape (the
-  in-process sweep knob). mp need not divide the tile: a row's last tile
-  holds only the pages the row has."""
-  import os
-
-  forced = os.getenv("XOT_TPU_PAGED_TILE")
-  if forced is not None:
-    cap = int(forced)
-  elif batch is not None:
-    from ..inference.paging import select_page_tile
-
-    cap = select_page_tile(batch, context if context is not None else mp * DEFAULT_PAGE_SIZE, kv_quant)
-  else:
-    cap = _PAGE_TILE_DEFAULT
+  ≤ min(mp, PAGE_TILE). mp need not divide the tile: a row's last tile holds
+  only the pages the row has."""
   g = 1
-  while g * 2 <= min(mp, max(cap, 1)):
+  while g * 2 <= min(mp, PAGE_TILE):
     g *= 2
   return g
 
@@ -598,8 +585,8 @@ def paged_decode_attention(
   names the mode (``kv_quant``: "", "int8", "int4"; None reads it off stored
   shapes); stored leaves that need it are converted per call — a copy of the
   leaf, which a program with a layer loop must make outside it.
-  ``pages_per_step`` (static) overrides the shape-aware page-tile verdict
-  (inference/paging.py ``select_page_tile``). Returns [B, Hq, hd].
+  ``pages_per_step`` (static) overrides the tile (``PAGE_TILE`` clamped to
+  the table's width). Returns [B, Hq, hd].
   """
   if (k_scale_pool is None) != (v_scale_pool is None):
     raise ValueError("paged_decode_attention: k_scale_pool and v_scale_pool must be passed together")
@@ -607,11 +594,7 @@ def paged_decode_attention(
   layer = jnp.asarray(pools[0][1], jnp.int32).reshape(1)
   if kv_quant is None:
     kv_quant = "" if k_scale_pool is None else "int4" if jnp.shape(k_pool)[-1] * 2 == jnp.shape(q)[-1] else "int8"
-  # Resolve the env-tunable tile width OUTSIDE the jitted body: baked-in-at-
-  # first-trace env reads silently ignore later changes for identical shapes
-  # (an in-process XOT_TPU_PAGED_TILE sweep would re-time one width forever).
-  mp = jnp.shape(block_tables)[1]
-  G = pages_per_step or _page_tile(mp, batch=jnp.shape(q)[0], context=mp * page_size, kv_quant=kv_quant)
+  G = pages_per_step or _page_tile(jnp.shape(block_tables)[1])
   return _paged_decode_attention_impl(
     q, block_tables, lengths, layer, *(x for x, _ in pools),
     page_size=page_size, pages_per_step=G, kv_quant=kv_quant, interpret=interpret,
@@ -670,18 +653,22 @@ def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_s
 
 
 def paged_kernel_supported(cfg, platform: str | None = None) -> bool:
-  """Whether the Pallas paged kernel CAN run for this model/platform.
-
-  Capability + kill-switches only — whether it SHOULD run for a given
-  (batch, context, quant-mode) is the dispatch table's call
-  (inference/paging.py select_decode_path; models/decoder.py resolves
-  ``use_kernel`` through both). ``XOT_TPU_NO_FLASH`` and
-  ``XOT_TPU_PAGED_KERNEL=0`` force it off everywhere."""
+  """Whether a paged program's attention core is the Pallas kernel: wherever
+  it can run — a TPU, plain attention (no softcap, no window), not MLA, a
+  head width the kernel tiles — and ``XOT_TPU_NO_FLASH`` is unset. It is what
+  the decode programs resolve ``use_kernel=None`` to and what the scheduler
+  labels its chunks by; everything else takes the XLA gather."""
   import os
 
-  from ..utils.helpers import env_flag
-
-  if os.getenv("XOT_TPU_NO_FLASH") or not env_flag("XOT_TPU_PAGED_KERNEL", default=True):
+  if os.getenv("XOT_TPU_NO_FLASH"):
     return False
   platform = platform or jax.default_backend()
   return platform == "tpu" and cfg.plain_attention and not cfg.is_mla and cfg.head_dim in (64, 128, 256)
+
+
+def kernel_attends(cfg, use_kernel) -> bool:
+  """Whether a paged program told ``use_kernel`` attends through the Pallas
+  kernel: the one test of the layer steps, the token write and the pool-form
+  conversion. The kernel has no softcap or window and MLA has its own core,
+  so such a model takes the gather whatever it was told."""
+  return bool(use_kernel) and cfg.plain_attention and not cfg.is_mla

@@ -82,9 +82,15 @@ def simulate_spec_acceptance(bits: list[bool], gamma: int, max_steps: int) -> fl
   return (n / rounds - 1.0) / gamma if rounds else 0.0
 
 
-def peaked_echo_params(params: dict, damp: float = 0.05) -> dict:
+def peaked_echo_params(params: dict, damp: float = 0.01) -> dict:
   """A peaked-logit variant of ``params``: residual-stream writes scaled by
   ``damp``. Returns a shallow-copied tree (untouched leaves shared).
+
+  ``damp`` has to leave the layers' writes below the embedding they are
+  added to: a random tree's embedding has std 0.02, and at 0.05 the writes
+  still outweighed it — the logits did not peak at the current token and the
+  model did not echo (the two speculation-threshold tests that stood failing
+  until PR 32); at 0.01 it does.
 
   Works on QUANTIZED trees too: damping int8 codes would round them to
   nothing, so when a ``<name>_scale`` sibling exists the *scale* leaf is
