@@ -246,6 +246,38 @@ def test_paged_batch_step_at_smoke_settings_fits_v5e(chip, llama_1b):
   assert mem.argument_size_in_bytes < 16 * 1024**3
 
 
+@pytest.mark.parametrize("weights", ["bf16", "int8"])
+def test_qkv_weights_are_read_where_they_lie(chip, llama_1b, weights):
+  """``decode.paged_batch``: XLA:TPU reads each layer's slice of the stacked
+  ``wq``/``wk``/``wv`` inside the fusion that holds its dot, as it reads
+  ``wo`` and ``w_gate``. Without the barrier in ``_dense_qkv`` it folds the
+  head reshape into the projection's dot, wants the weight K-minor for the
+  dot it then has, relays two of the three stacks to a ``{1,2,0}`` layout
+  once a dispatch and copies the layer's slices out of them in every layer
+  of every step (``constant_dynamic-slice_fusion`` of a ``[1, D, N]`` array)
+  — and does the same to one merged leaf (AOT, PR 33; PERF.md §6)."""
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+  from xotorch_support_jetson_tpu.models.quantize import quantize_params
+
+  args = list(_decode_step_args(chip, llama_1b, 16, "int8"))
+  cfg = args[1]
+  if weights == "int8":
+    args[0] = jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), jax.eval_shape(quantize_params, args[0]))
+  code = "s8" if weights == "int8" else "bf16"
+  L, D = cfg.n_layers, cfg.dim
+  widths = "|".join(str(n) for n in sorted({cfg.q_dim, cfg.kv_dim}))
+  assert all(args[0]["layers"][n].shape == (L, D, w) for n, w in (("wq", cfg.q_dim), ("wk", cfg.kv_dim), ("wv", cfg.kv_dim)))
+  compiled, text = _compile(_fused_paged_batch_decode_impl, *args)
+  relaid = [line.strip()[:140] for line in text.splitlines() if re.search(rf"= {code}\[({L}|1),{D},({widths})\]\S* (copy|copy-start)\(", line) or re.search(rf"{code}\[({L}|1),{D},({widths})\]\{{1,2,0", line)]
+  assert not relaid, relaid
+  rebuilt = [line.strip()[:140] for line in text.splitlines() if re.search(rf"= {code}\[(1,)?{D},({widths})\]\S* fusion\(", line) and "constant_dynamic-slice_fusion" in line]
+  assert not rebuilt, rebuilt
+  dots = [block for block in text.split("\n\n") if re.match(rf"%\S+ \([^\n]*{code}\[{L},{D},({widths})\]", block) and " convolution(" in block]
+  assert len(dots) >= 1, "no fusion takes a stacked q/k/v leaf and holds its dot"
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch B=16 {weights}: temp={mem.temp_size_in_bytes}")
+
+
 def test_compiler_refuses_a_pool_beyond_the_chip(chip, llama_1b):
   """What makes the test above a fit check: the same step over three times
   the pool is refused at compile time, not at run time."""

@@ -354,6 +354,15 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
   q = _mm(x, p, "wq", cfg.quant_compute)
   k = _mm(x, p, "wk", cfg.quant_compute)
   v = _mm(x, p, "wv", cfg.quant_compute)
+  # Keep the three flat activations as the dots made them. Left free, XLA:TPU
+  # folds the head reshape below into the projection's dot, wants the weight
+  # K-minor for the dot it then has, and pays for it with a relayout of the
+  # stacked wq/wk once a dispatch plus a copy of the layer's slices in every
+  # layer of every step — each q/k/v weight byte moved three times, 1.5 ms of
+  # a 14.6 ms Mistral-7B decode step (PERF.md §6, PR 33; pinned in
+  # tests/test_tpu_compile.py). Behind the barrier the layer scan's slice of
+  # each leaf is read inside its dot, as it is for wo and w_gate.
+  q, k, v = jax.lax.optimization_barrier((q, k, v))
   # LoRA adapters (train/lora.py): alpha = 2·rank, so the scale is always 2.
   if "wq_lora_a" in p:
     q = q + ((x @ p["wq_lora_a"]) @ p["wq_lora_b"]) * 2.0
