@@ -667,6 +667,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_decode_chunks_total",
   "xot_tpu_decode_tokens_total",
   "xot_tpu_prefill_chunks_total",
+  "xot_tpu_recurrent_state_resets_total",  # slots a prefill from position 0 started from zeros (ISSUE 34)
   "xot_tpu_prefix_cache_hit_pages_total",
   "xot_tpu_page_grow_events_total",
   "xot_tpu_page_grow_pages_total",
@@ -756,6 +757,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_goodput_tok_s",  # {class}
   "xot_tpu_node_role",  # 0=both 1=prefill 2=decode (ISSUE 10)
   "xot_tpu_kv_quant_bits",  # 16=bf16 8=int8 4=int4 (ISSUE 11)
+  "xot_tpu_recurrent_state_bytes",  # per-slot state beside the page pool (ISSUE 34)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
   # Multi-LoRA serving (ISSUE 15; swaps labeled {direction}, requests
   # labeled {adapter} — adapter names are client-asserted, same trust note
@@ -837,6 +839,7 @@ def test_metric_name_snapshot_after_serving():
   gm.set_gauge("spec_gamma", 0, labels={"row": "0"})
   gm.set_gauge("spec_proposer", 0, labels={"row": "0"})
   gm.set_gauge("kv_draft_bytes", 0)
+  gm.inc("recurrent_state_resets_total", 0)  # event-driven: only a configuration with recurrent layers resets a slot's state (ISSUE 34)
   gm.set_gauge("kv_draft_slots", 0)
   gm.set_gauge("kv_draft_pages_equivalent", 0)
   # Mixed ticks (ISSUE 14): a short solo drive never stages a chunked

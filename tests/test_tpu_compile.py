@@ -278,6 +278,49 @@ def test_qkv_weights_are_read_where_they_lie(chip, llama_1b, weights):
   print(f"decode.paged_batch B=16 {weights}: temp={mem.temp_size_in_bytes}")
 
 
+def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
+  """granite-4.0-h-micro whole, as ``granite-4.0-h-micro.decode-closed-64`` serves it (ISSUE 34): 64 slots, 1537
+  pages, bf16, the kernel path. ``decode.paged_batch`` is accepted by XLA:TPU beside 6.4 GB of weights, 4.9 GB of
+  recurrent state and 0.8 GB of pages. The state leaf is one buffer from the donated argument to the result: no
+  instruction copies it (a copy is a second 4.8 GB, and PR 29's finding over again); it is read at (layer) and
+  written back by fusions the compiler aliases to it. No stacked state-space projection is relaid or copied:
+  HF's one ``in_proj`` of 8512 columns is no whole number of lanes, the TPU kept that stack column-major and
+  copied all 1.26 GB of it once a dispatch for the dot, so it is three leaves (AOT, PR 34; PERF.md section 6)."""
+  import json
+
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, full_model_params
+  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+  from dataclasses import replace
+
+  hf = json.loads((ROOT / "benchmark" / "configs" / "granite-4.0-h-micro-bf16.json").read_text())
+  n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"]["XOT_TPU_BATCH_PAGES"])
+  cfg = replace(config_from_hf({k: v for k, v in hf.items() if not isinstance(v, dict)}), max_seq_len=int(hf["serving_window_tokens"]))
+  on_chip = lambda tree: jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)  # noqa: E731
+  params = on_chip(jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg)[0]))
+  pool = on_chip(jax.eval_shape(lambda: init_paged_pool(cfg, cfg.n_layers, n_pages, PS, n_slots=n_slots)))
+  assert pool["k"].shape == (4, n_pages, 8, PS, 64) and pool["ssm"].shape == (36, n_slots, 64, 64, 128) and pool["conv"].shape == (36, n_slots, 3, 4352)
+  rows = _rows(chip, n_slots)
+  compiled, text = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, Shard("granite", 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (n_slots, 1), jnp.int32), pool,
+    _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
+    _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+  kernels = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
+  assert len(kernels) == 8, len(kernels)  # the paged kernel and the token write, once for each of the four attention layers' loops
+  state = r"f32\[36,64,64,64,128\]"
+  copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {state}\S* (copy|copy-start|transpose)\(", line)]
+  assert not copied, copied
+  relaid = [line.strip()[:160] for line in text.splitlines() if re.search(r"= bf16\[36,(2048|4096|8192),\d+\]\S* (copy|copy-start)\(", line) and "[36,2048,64]" not in line]
+  assert not relaid, relaid  # (w_dt's 64 columns, 9 MB, are the one stack the TPU still relays)
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch granite B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
 def test_compiler_refuses_a_pool_beyond_the_chip(chip, llama_1b):
   """What makes the test above a fit check: the same step over three times
   the pool is refused at compile time, not at run time."""

@@ -81,7 +81,16 @@ class DecoderBatchOps(_PageCopyMixin):
   integration yet — and the scheduler falls back to plain chunks there."""
 
   def __init__(self, engine):
+    from ..models import decoder
+
     self.engine = engine
+    # A pool with per-slot recurrent state is written in place by its prefill too: a second copy of the state
+    # (what a program that leaves its argument intact returns) does not fit beside the first. A failed prefill
+    # then costs the pool, as a failed decode chunk does. Chosen once: the ops are rebuilt with every load.
+    cfg = getattr(engine, "cfg", None)
+    self.prefill_donates_pool = bool(cfg is not None and cfg.recurrent_layers)
+    self._pages_many = decoder.prefill_into_pages_many_inplace if self.prefill_donates_pool else decoder.prefill_into_pages_many
+    self._pages_many_sampled = decoder.prefill_into_pages_many_sampled_inplace if self.prefill_donates_pool else decoder.prefill_into_pages_many_sampled
 
   def round_slots(self, n: int) -> int:
     return n
@@ -165,11 +174,12 @@ class DecoderBatchOps(_PageCopyMixin):
     eng = self.engine
     return init_kv_cache(eng.cfg, eng._effective_shard.n_shard_layers, n_slots, max_seq)
 
-  def init_pool(self, n_pages: int, page_size: int):
+  def init_pool(self, n_pages: int, page_size: int, n_slots: int = 0):
+    """``n_slots``: the rows whose recurrent state rides in the pool (a configuration with recurrent layers)."""
     from ..ops.paged import init_paged_pool
 
     eng = self.engine
-    return init_paged_pool(eng.cfg, eng._effective_shard.n_shard_layers, n_pages, page_size)
+    return init_paged_pool(eng.cfg, eng._effective_shard.n_shard_layers, n_pages, page_size, n_slots=n_slots)
 
   def prefill_into_slots(self, tokens, cache, rows, prompt_lens, adapter_ids=None):
     from ..models.decoder import prefill_into_slots
@@ -179,13 +189,14 @@ class DecoderBatchOps(_PageCopyMixin):
       eng.params, eng.cfg, eng._effective_shard, tokens, cache, jnp.asarray(rows, jnp.int32), jnp.asarray(prompt_lens, jnp.int32), adapter_ids
     )
 
-  def prefill_into_pages_many(self, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, adapter_ids=None):
-    from ..models.decoder import prefill_into_pages_many
-
+  def prefill_into_pages_many(self, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, adapter_ids=None, slot_rows=None):
+    """``slot_rows`` [K]: each row's slot, for a pool that holds per-slot state — whose program is the one
+    that writes the pool in place (``prefill_donates_pool``)."""
     eng = self.engine
-    return prefill_into_pages_many(
+    return self._pages_many(
       eng.params, eng.cfg, eng._effective_shard, tokens, pool, jnp.asarray(bt_rows, jnp.int32),
       jnp.asarray(prefix_lens, jnp.int32), jnp.asarray(prompt_lens, jnp.int32), int(page_size), adapter_ids,
+      slot_rows if slot_rows is None else jnp.asarray(slot_rows, jnp.int32),
     )
 
   # ------------------------------------------- fused sampling epilogue
@@ -205,14 +216,13 @@ class DecoderBatchOps(_PageCopyMixin):
       jnp.asarray(prompt_lens, jnp.int32), jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32), key, int(k_max), adapter_ids,
     )
 
-  def prefill_into_pages_many_sampled(self, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, temps, top_ks, k_max: int, key, adapter_ids=None):
-    from ..models.decoder import prefill_into_pages_many_sampled
-
+  def prefill_into_pages_many_sampled(self, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, temps, top_ks, k_max: int, key, adapter_ids=None, slot_rows=None):
     eng = self.engine
-    return prefill_into_pages_many_sampled(
+    return self._pages_many_sampled(
       eng.params, eng.cfg, eng._effective_shard, tokens, pool, jnp.asarray(bt_rows, jnp.int32),
       jnp.asarray(prefix_lens, jnp.int32), jnp.asarray(prompt_lens, jnp.int32), int(page_size),
       jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32), key, int(k_max), adapter_ids,
+      slot_rows if slot_rows is None else jnp.asarray(slot_rows, jnp.int32),
     )
 
   def batch_decode(self, token, cache, positions, active, temps, top_ks, n_steps: int, k_max: int, key, adapter_ids=None):
@@ -238,8 +248,10 @@ class DecoderBatchOps(_PageCopyMixin):
   def mixed_tick_supported(self) -> bool:
     """The mixed prefill+decode program needs the full-model single-device
     fused path (same reach as the spec programs); MLA models stay on the
-    alternating schedule (no paged multi-token prefill composition)."""
-    return not self.engine.cfg.is_mla
+    alternating schedule (no paged multi-token prefill composition), and so
+    does a configuration with recurrent layers (a slice would have to leave
+    its state where the decode half of the same program cannot touch it)."""
+    return not self.engine.cfg.is_mla and not self.engine.cfg.recurrent_layers
 
   def mixed_paged_batch_decode(self, token, pool, block_tables, positions, active, temps, top_ks, n_steps: int, k_max: int, page_size: int, key, pf_tokens, pf_bt, pf_prefix, pf_end, adapter_ids=None, pf_adapter=None):
     from ..models.decoder import fused_mixed_paged_batch_decode

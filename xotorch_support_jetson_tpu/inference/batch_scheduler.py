@@ -303,6 +303,13 @@ class _Chunk:
   mixed_end: int = 0
 
 
+# A server of more than ``GROUP_SLOTS_WHOLE`` slots holds a prefill group to ``GROUP_ROWS`` rows, so that its prefill
+# programs are those of 1, 2, 4 and 8 rows and no others; one of up to 16 slots never has a ready list split for its
+# size, and groups as it always did (``_dispatch_groups``).
+GROUP_SLOTS_WHOLE = 16
+GROUP_ROWS = 8
+
+
 class BatchedServer:
   """Owns the slot pool and the decode loop for one engine."""
 
@@ -838,7 +845,13 @@ class BatchedServer:
       proposers.append("model")
     if ngram_enabled() and getattr(self.ops, "spec_ngram_supported", lambda: False)():
       proposers.append("ngram")
-    self.spec = bool(want) and bool(proposers) and not (self.paged and eng.cfg.is_mla)
+    # A configuration with recurrent layers keeps, beside its pages, a per-slot state that pages do not carry
+    # (cfg.recurrent_layers, the one property read here and at the three gates below): until that state can be
+    # snapshotted and rolled back, whatever reuses, moves or rewinds pages without it is off.
+    recurrent = bool(eng.cfg.recurrent_layers)
+    if recurrent and not self.paged:
+      raise ValueError("a configuration with recurrent layers is served over the page pool: unset XOT_TPU_PAGED=0")
+    self.spec = bool(want) and bool(proposers) and not (self.paged and eng.cfg.is_mla) and not recurrent
     self.spec_proposers = tuple(proposers) if self.spec else ()
     draft_pages_equiv = 0
     if self.spec and "model" in self.spec_proposers:
@@ -891,11 +904,21 @@ class BatchedServer:
       n_pages = int(os.getenv("XOT_TPU_BATCH_PAGES", "0")) or per_dense + 1
       self.allocator = PageAllocator(n_pages, ps)
       self.block_tables = np.zeros((self.n_slots, self.pages_per_row), dtype=np.int32)
-      self.cache = self.ops.init_pool(n_pages, ps)
+      self.cache = self.ops.init_pool(n_pages, ps, **({"n_slots": self.n_slots} if recurrent else {}))
       metrics.set_gauge("page_pool_pages_total", n_pages - 1)  # page 0 = trash page
+      from ..ops.paged import state_leaves
+
+      metrics.set_gauge("recurrent_state_bytes", sum(leaf.size * leaf.dtype.itemsize for leaf in state_leaves(self.cache).values()))
       from .kv_tier import KvTierManager, kv_tier_enabled
 
-      if self.tier is None and kv_tier_enabled():
+      if recurrent:
+        self.tier = None
+        print(
+          f"[batch_scheduler] {eng.cfg.recurrent_layers} of {eng.cfg.n_layers} layers keep a recurrent state per slot "
+          f"({self.n_slots} slots beside {n_pages - 1} pages of the {eng.cfg.n_attn_layers} attention layers): "
+          "prefix reuse, the host KV tier, speculation and mixed ticks are off; a preempted row resumes by recomputing"
+        )
+      elif self.tier is None and kv_tier_enabled():
         self.tier = KvTierManager.from_env(page_size=ps, read_pages=self._tier_read, write_pages=self._tier_write)
       if self.tier is not None:
         # Rewire onto the (possibly rebuilt) allocator: device evictions
@@ -1186,7 +1209,8 @@ class BatchedServer:
       chain_keys = self.allocator.chain_keys(req.tokens, ps)
       # Reuse at most (S-1)//ps pages: at least one suffix token must run
       # through prefill to produce the last-position logits.
-      shared_pages = self.allocator.lookup_prefix(chain_keys[: (S - 1) // ps])
+      # (No reuse for a configuration with recurrent layers: cached pages come without the state they belong to.)
+      shared_pages = [] if self.engine.cfg.recurrent_layers else self.allocator.lookup_prefix(chain_keys[: (S - 1) // ps])
       prefix_len = len(shared_pages) * ps
       from .paging import pages_to_cover
 
@@ -1418,11 +1442,20 @@ class BatchedServer:
     ``prefix_len + S_pad <= max_seq`` (the scatter-clamp constraint: a row
     reusing a long cached prefix cannot share a dispatch with a fresh long
     prompt). Groups are seeded longest-first, so each group's S_pad is its
-    first member's pad_to; in practice one group."""
+    first member's pad_to; in practice one group.
+
+    On a server of more than ``GROUP_SLOTS_WHOLE`` slots a group also holds
+    at most ``GROUP_ROWS`` rows. A group is one program per (rows padded to
+    a power of two, longest member), and a program first met in service
+    stops every row for its compile (~10 s each at 64 slots): more rows are
+    met only when a lump of callers ends together, once in minutes (PR 34).
+    It also bounds the activations (8 rows x ``XOT_TPU_PREFILL_CHUNK``).
+    A server of up to 16 slots is never asked, so it groups as before."""
     groups: list[list[_Ready]] = []
     for r in sorted(ready, key=lambda x: x.pad_to, reverse=True):
       for g in groups:
-        if r.prefix_len + g[0].pad_to <= self.max_seq:
+        whole = self.n_slots <= GROUP_SLOTS_WHOLE or len(g) < GROUP_ROWS
+        if r.prefix_len + g[0].pad_to <= self.max_seq and whole:
           g.append(r)
           break
       else:
@@ -1522,6 +1555,14 @@ class BatchedServer:
       # Padding rows: all-zero block table (writes land in the trash page),
       # prefix 0, prompt_len 1.
       prompt_lens[K:] = 1
+      state_kw = {}
+      if eng.cfg.recurrent_layers:
+        # The rows' slots, for the state-space layers' per-slot state: a padding row names the slot past the last,
+        # so nothing is written for it. A prefill from position 0 starts its slot from zeros: the reset.
+        slot_rows = np.full((n_rows,), self.n_slots, dtype=np.int32)
+        slot_rows[:K] = [r.row for r in group]
+        state_kw = {"slot_rows": slot_rows}
+        metrics.inc("recurrent_state_resets_total", sum(1 for r in group if r.prefix_len == 0))
 
       # Key split on the EVENT-LOOP thread, before the dispatch crosses to
       # the executor: the worker thread never touches the engine's PRNG
@@ -1540,7 +1581,7 @@ class BatchedServer:
           if self.fused_sampling:
             firsts, self.cache = self.ops.prefill_into_pages_many_sampled(
               jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size,
-              temps, top_ks, self.k_max, sub, **lora_kw,
+              temps, top_ks, self.k_max, sub, **lora_kw, **state_kw,
             )
             if draft_job is not None:
               draft_job()
@@ -1548,7 +1589,7 @@ class BatchedServer:
             from ..models.decoder import sample_rows
 
             last, self.cache = self.ops.prefill_into_pages_many(
-              jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size, **lora_kw
+              jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size, **lora_kw, **state_kw
             )
             if draft_job is not None:
               draft_job()
@@ -1605,6 +1646,8 @@ class BatchedServer:
         if not r.req.future.done():
           r.req.future.set_exception(e)
         self._cancelled_ids.discard(r.req.request_id)
+      if getattr(self.ops, "prefill_donates_pool", False):
+        raise  # the pool went into the failed call: the loop drops it and fails the rest, as after a failed decode chunk
       return
     finally:
       # Device idle from here until the next dispatch — refreshed on the
@@ -1810,7 +1853,7 @@ class BatchedServer:
     on the transfer). ``quant`` is the sender's KV quant-mode tag (ISSUE
     11) — a mismatch with this pool's mode refuses the batch BEFORE the
     tier's byte-geometry guard could be seeded with foreign-layout pages."""
-    if not self.paged:
+    if not self.paged or self.engine.cfg.recurrent_layers:
       return 0
     if self.tier is None:
       from .kv_tier import KvTierManager, kv_tier_enabled
@@ -1890,7 +1933,7 @@ class BatchedServer:
     for p in slot.shared_pages:
       self.allocator.release(p)
     n_shared = len(slot.shared_pages)
-    keys = slot.chain_keys
+    keys = [] if self.engine.cfg.recurrent_layers else slot.chain_keys  # pages without their state are no prefix: all freed
     if extend is None:
       extend = self.tier is not None
     if extend and slot.pos // self.page_size > len(keys):

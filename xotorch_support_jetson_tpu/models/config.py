@@ -137,6 +137,31 @@ class ModelConfig:
   # the placeholder token id the HF processor expands per image patch.
   vision: Any = None  # VisionConfig | None (Any keeps this module torch/vision-free)
   image_token_id: int = -1
+  # --- hybrid state-space models (granitemoehybrid): ``layer_types`` names
+  # every layer's mixer in model order ("mamba" | "attention"; empty ⇒ all
+  # attention). A "mamba" layer is a Mamba-2 mixer (one group) over ``ssm_heads``
+  # heads of ``ssm_head_dim`` with a state of ``ssm_state`` a channel, a causal
+  # depthwise convolution of ``ssm_conv`` taps, scanned in chunks of
+  # ``ssm_chunk`` at prefill (models/decoder.py). The stacked parameters are
+  # ``ssm_layers`` [n "mamba"] beside ``layers`` [n "attention"], and the
+  # page pool keeps K/V pages for the attention layers only, with per-slot
+  # state leaves beside them (ops/paged.py init_paged_pool).
+  layer_types: tuple[str, ...] = ()
+  ssm_heads: int = 0
+  ssm_head_dim: int = 0
+  ssm_state: int = 0
+  ssm_conv: int = 0
+  ssm_chunk: int = 256
+  # Granite's four multipliers: ``embed_scale`` above is its
+  # embedding_multiplier; every block's output is scaled by
+  # ``residual_multiplier`` before it joins the residual; logits are divided
+  # by ``logits_scaling``; ``attn_multiplier`` (0 ⇒ 1/sqrt(head_dim)) is the
+  # softmax scale, folded into q (decoder.py _dense_qkv) so the attention
+  # cores and kernels keep their one scale.
+  residual_multiplier: float = 1.0
+  logits_scaling: float = 1.0
+  attn_multiplier: float = 0.0
+  use_rope: bool = True  # False: no position term at all ("nope")
   # Cleared by the engine (never by a user) when the serving plan leaves a
   # mesh axis of more than one device to GSPMD: a Mosaic kernel cannot be
   # partitioned automatically ("wrap the call in a shard_map"), so programs
@@ -159,6 +184,28 @@ class ModelConfig:
   @property
   def is_mla(self) -> bool:
     return self.kv_lora_rank > 0
+
+  @property
+  def recurrent_layers(self) -> int:
+    """How many layers keep a per-slot recurrent state instead of K/V pages.
+    The ONE property the scheduler's gates read: above 0, pages alone are not
+    a request's state, so whatever reuses or moves pages without it (prefix
+    reuse, the host tier, speculation, mixed ticks) is off."""
+    return sum(1 for t in self.layer_types if t == "mamba")
+
+  @property
+  def n_attn_layers(self) -> int:
+    """Layers that own K/V pages: the page pool's layer axis."""
+    return self.n_layers - self.recurrent_layers
+
+  @property
+  def ssm_inner(self) -> int:
+    return self.ssm_heads * self.ssm_head_dim
+
+  @property
+  def ssm_conv_dim(self) -> int:
+    """Channels the convolution runs over: x and the one group's B and C."""
+    return self.ssm_inner + 2 * self.ssm_state
 
   @property
   def qk_head_dim(self) -> int:
@@ -197,6 +244,14 @@ class ModelConfig:
     return replace(self, n_layers=n_layers)
 
 
+# HF ``model_type`` (or, with its underscores dropped, the ``architectures`` entry) -> family; first match wins, so
+# a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
+MODEL_FAMILIES = {
+  "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "llama": "llama",
+}
+
+
 def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
   """Map an HF ``config.json`` dict to ModelConfig.
 
@@ -224,27 +279,12 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
       vision_cfg = vision_config_from_hf(top["vision_config"], int(hf["hidden_size"]), top)
   arch = (hf.get("architectures") or [""])[0].lower()
   model_type = hf.get("model_type", "").lower()
-  family = "llama"
-  if "qwen3_moe" in model_type or "qwen3moe" in arch:
-    family = "qwen3-moe"
-  elif "qwen3" in model_type or "qwen3" in arch:
-    family = "qwen3"
-  elif "qwen2_moe" in model_type or "qwen2moe" in arch:
-    family = "qwen2-moe"
-  elif "qwen2" in model_type or "qwen2" in arch:
-    family = "qwen2"
-  elif "mixtral" in model_type or "mixtral" in arch:
-    family = "mixtral"
-  elif "mistral" in model_type or "mistral" in arch:
-    family = "mistral"
-  elif "phi3" in model_type or "phi3" in arch:
-    family = "phi3"
-  elif "deepseek_v3" in model_type or "deepseekv3" in arch:
-    family = "deepseek-v3"
-  elif "deepseek_v2" in model_type or "deepseekv2" in arch:
-    family = "deepseek-v2"
-  elif "gemma2" in model_type or "gemma2" in arch:
-    family = "gemma2"
+  family = next((fam for key, fam in MODEL_FAMILIES.items() if key in model_type or key.replace("_", "") in arch), None)
+  if family is None:
+    if model_type:
+      # An unknown architecture is not a llama: serving it as one answers with noise and no error.
+      raise ValueError(f"config_from_hf: unknown model_type {hf.get('model_type')!r} (known: {', '.join(MODEL_FAMILIES)})")
+    family = "llama"  # an absent model_type stays llama (bare test configs)
 
   rope_scaling = None
   rs = hf.get("rope_scaling")
@@ -366,6 +406,10 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
       embed_scale=math.sqrt(float(hf["hidden_size"])),
     )
 
+  hybrid: dict[str, Any] = {}
+  if family == "granite-hybrid":
+    hybrid = _granite_hybrid_fields(hf)
+
   n_heads = int(hf["num_attention_heads"])
   return ModelConfig(
     vocab_size=int(hf["vocab_size"]),
@@ -373,7 +417,7 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     n_layers=int(hf["num_hidden_layers"]),
     n_heads=n_heads,
     n_kv_heads=int(hf.get("num_key_value_heads", n_heads)),
-    hidden_dim=int(hf["intermediate_size"]),
+    hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if hybrid else int(hf["intermediate_size"]),
     head_dim=int(hf.get("head_dim") or 0),
     norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
     rope_theta=float(hf.get("rope_theta", 10000.0)),
@@ -382,7 +426,7 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     qkv_bias=family in ("qwen2", "qwen2-moe") or bool(hf.get("attention_bias", False)),
     qk_norm=family in ("qwen3", "qwen3-moe"),
     partial_rotary_factor=float(hf.get("partial_rotary_factor", 1.0)),
-    tied_embedding=bool(hf.get("tie_word_embeddings", family in ("gemma2",) or (family == "qwen2" and int(hf["hidden_size"]) < 2048))),
+    tied_embedding=bool(hf.get("tie_word_embeddings", family in ("gemma2", "granite-hybrid") or (family == "qwen2" and int(hf["hidden_size"]) < 2048))),
     family=family,
     dtype=dtype or dtype_map.get(torch_dtype, jnp.bfloat16),
     eos_token_ids=tuple(int(e) for e in eos),
@@ -393,6 +437,41 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     **moe,
     **mla,
     **gemma,
+    **hybrid,
+  )
+
+
+def _granite_hybrid_fields(hf: dict) -> dict:
+  """``GraniteMoeHybridConfig`` → the hybrid fields of ModelConfig. What the
+  decoder does not implement is refused here, by name, not served wrong."""
+  n_layers = int(hf["num_hidden_layers"])
+  layer_types = tuple(hf.get("layer_types") or ("attention",) * n_layers)
+  if len(layer_types) != n_layers or set(layer_types) - {"mamba", "attention"}:
+    raise ValueError(f"granitemoehybrid: layer_types must name {n_layers} layers, each 'mamba' or 'attention'; got {layer_types}")
+  if int(hf.get("num_local_experts") or 0):
+    raise ValueError("granitemoehybrid with routed experts (num_local_experts > 0) is not supported: only the shared MLP is")
+  if int(hf.get("mamba_n_groups", 1)) != 1:
+    raise ValueError("granitemoehybrid: mamba_n_groups != 1 is not supported")
+  if bool(hf.get("mamba_proj_bias", False)):
+    raise ValueError("granitemoehybrid: mamba_proj_bias is not supported")
+  pos = hf.get("position_embedding_type", "rope")
+  if pos not in ("nope", "rope"):
+    raise ValueError(f"granitemoehybrid: position_embedding_type {pos!r} is not supported")
+  heads, head_dim = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+  if heads * head_dim != int(hf.get("mamba_expand", 2)) * int(hf["hidden_size"]):
+    raise ValueError("granitemoehybrid: mamba_n_heads * mamba_d_head must equal mamba_expand * hidden_size")
+  return dict(
+    layer_types=layer_types,
+    ssm_heads=heads,
+    ssm_head_dim=head_dim,
+    ssm_state=int(hf["mamba_d_state"]),
+    ssm_conv=int(hf["mamba_d_conv"]),
+    ssm_chunk=int(hf.get("mamba_chunk_size", 256)),
+    embed_scale=float(hf.get("embedding_multiplier", 1.0)),
+    residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+    logits_scaling=float(hf.get("logits_scaling", 1.0)),
+    attn_multiplier=float(hf.get("attention_multiplier") or 0.0),
+    use_rope=pos == "rope",
   )
 
 

@@ -124,6 +124,10 @@ def init_kv_cache(cfg: ModelConfig, n_shard_layers: int, batch: int, max_seq: in
   it against the config's cache dims, the qdot idiom) with the same scale
   leaves.
   """
+  if cfg.recurrent_layers:
+    # One place for every slot-cache path (solo sessions, XOT_TPU_PAGED=0, draft caches): a state-space
+    # layer's state lives beside the page pool (ops/paged.py init_paged_pool) and nowhere else.
+    raise ValueError("a configuration with recurrent layers is served by the batched server over the page pool (XOT_TPU_BATCHED=1, XOT_TPU_PAGED=1); it has no slot-indexed KV cache")
   dtype = dtype or cfg.dtype
   mode = kv_quant_mode(cfg, quant)
   kd, vd = cfg.cache_k_dim, cfg.cache_v_dim
@@ -178,6 +182,18 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     final_norm [D]               (last shard only)
     lm_head    [D, V]            (last shard only; omitted when tied to a
                                   first-shard embed in the same params)
+
+  A hybrid (``cfg.layer_types``) keeps its attention layers in ``layers``
+  [La, ...] and its state-space layers in ``ssm_layers`` [Ls, ...], each in
+  model order (``_layer_runs`` interleaves them again):
+    ssm_layers/ssm_norm [Ls, D]    ssm_layers/w_z [Ls, D, di]  w_xbc [Ls, D, di + 2*N]  w_dt [Ls, D, H]
+                                   (HF's one in_proj, cut at its three outputs: its 2*di + 2*N + H columns are
+                                   no whole number of 128 lanes, and the TPU stores such a stack column-major
+                                   and copies it whole, once a dispatch, for the dot — PERF.md §6, PR 34)
+    ssm_layers/conv_w [Ls, K, di + 2*N]   conv_b [Ls, di + 2*N]
+    ssm_layers/dt_bias, A_log, D [Ls, H] f32
+    ssm_layers/gate_norm [Ls, di]  ssm_layers/w_out [Ls, di, D]
+    + mlp_norm, w_gate, w_up, w_down as in ``layers``
   """
   dtype = dtype or cfg.dtype
   L = shard.n_shard_layers
@@ -234,7 +250,32 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     return stack
 
   params: Params = {}
-  if cfg.n_experts:
+  if cfg.recurrent_layers:
+    if not (shard.is_first_layer and shard.is_last_layer):
+      raise ValueError("a configuration with recurrent layers is built whole: its two stacks do not split by a layer range")
+    Ls, H, di, C = cfg.recurrent_layers, cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+    if cfg.n_attn_layers:
+      params["layers"] = dense_stack(cfg.n_attn_layers)
+    # The Mamba-2 initialisation: decays -exp(A_log) in [-16, -1], steps softplus(dt_bias) log-uniform in [1e-3, 1e-1], skip D = 1.
+    dt = jnp.exp(jax.random.uniform(next(keys), (Ls, H), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    params["ssm_layers"] = {
+      "ssm_norm": jnp.ones((Ls, D), dtype=dtype),
+      "w_z": w(next(keys), Ls, D, di),
+      "w_xbc": w(next(keys), Ls, D, C),
+      "w_dt": w(next(keys), Ls, D, H),
+      "conv_w": w(next(keys), Ls, cfg.ssm_conv, C, scale=cfg.ssm_conv**-0.5),
+      "conv_b": jnp.zeros((Ls, C), dtype=dtype),
+      "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+      "A_log": jnp.log(jax.random.uniform(next(keys), (Ls, H), jnp.float32, 1.0, 16.0)),
+      "D": jnp.ones((Ls, H), jnp.float32),
+      "gate_norm": jnp.ones((Ls, di), dtype=dtype),
+      "w_out": w(next(keys), Ls, di, D),
+      "mlp_norm": jnp.ones((Ls, D), dtype=dtype),
+      "w_gate": w(next(keys), Ls, D, F),
+      "w_up": w(next(keys), Ls, D, F),
+      "w_down": w(next(keys), Ls, F, D),
+    }
+  elif cfg.n_experts:
     # MoE model: dense prefix (layers [0, first_k_dense) globally), MoE rest.
     n_dense = min(max(cfg.first_k_dense - shard.start_layer, 0), L)
     Lm, E, Fm, Fs = L - n_dense, cfg.n_experts, cfg.moe_hidden_dim, cfg.shared_expert_dim
@@ -382,6 +423,12 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
   if "q_norm" in p:  # qwen3: per-head RMSNorm on q/k before rope
     q = rms_norm(q, p["q_norm"], cfg.norm_eps)
     k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+  if cfg.attn_multiplier:
+    # granite's softmax scale, as a factor on q over the cores' own 1/sqrt(hd): the attention paths and the
+    # Pallas kernels keep one scale (``plain_attention`` stays true). 1/64 over 1/8 is 0.125, exact in bf16.
+    q = q * jnp.asarray(cfg.attn_multiplier * cfg.head_dim**0.5, q.dtype)
+  if not cfg.use_rope:  # "nope": no position term; causality alone orders the tokens
+    return q, k, v
   m = rope_attention_factor(cfg)
   q = apply_rope(q, positions, inv_freq, m)
   k = apply_rope(k, positions, inv_freq, m)
@@ -406,6 +453,13 @@ def _attn_opts(cfg: ModelConfig, layer_sliding=None) -> dict:
     # Traced per-layer window: huge (== no-op) on global-attention layers.
     opts["sliding_window"] = jnp.where(layer_sliding > 0, cfg.sliding_window, jnp.int32(2**30))
   return opts
+
+
+def _residual(h, out, cfg: ModelConfig):
+  """``h + out``, a block's output scaled by granite's ``residual_multiplier`` first."""
+  if cfg.residual_multiplier != 1.0:  # the multiplier itself stays float32: 0.22 rounded to bfloat16 is 0.2197, in every block
+    out = (out.astype(jnp.float32) * cfg.residual_multiplier).astype(out.dtype)
+  return h + out
 
 
 def _mlp_block(h, p, cfg: ModelConfig):
@@ -462,8 +516,184 @@ def _mlp_block(h, p, cfg: ModelConfig):
       out = _mm(gated, p, "w_down", cfg.quant_compute)
       if "post_mlp_norm" in p:  # gemma2 post-feedforward layernorm
         out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
-      h = h + out
+      h = _residual(h, out, cfg)
   return h, aux
+
+
+# ------------------------------------------------ state-space (Mamba-2) mixer
+# (granitemoehybrid's "mamba" layers; HF ``GraniteMoeHybridMambaLayer``, one
+# group.) [z | xBC | dt] = u W_in (three leaves here, w_z | w_xbc | w_dt); xBC through a causal depthwise convolution
+# of ``ssm_conv`` taps and silu; [x | B | C] = xBC; per head Δ = softplus(dt +
+# dt_bias), a = exp(-Δ exp(A_log)); S_t = a_t S_{t-1} + Δ_t x_t ⊗ B_t;
+# y_t = S_t C_t + D x_t; out = rms(y ⊙ silu(z)) W_out. What a row keeps
+# between calls is S [H, P, N] in float32 and the last ``ssm_conv - 1`` rows of
+# the pre-convolution xBC: the two per-slot leaves ``ssm`` and ``conv`` that
+# ride beside the K/V pages in the page pool (ops/paged.py init_paged_pool).
+# Prefill scans a prompt in chunks of ``ssm_chunk`` from a state and returns
+# the state after the row's last real token; decode is one recurrence step.
+# Decays, steps and the state are float32; the chunk scan's matrix products
+# take their operands in the model dtype and accumulate in float32.
+
+
+@component_scope("xot.ssm_proj")
+def _ssm_in(h, p, cfg: ModelConfig):
+  """Norm and input projection: h [B,S,D] → z [B,S,di], xBC [B,S,di+2N], dt [B,S,H]."""
+  u = rms_norm(h, p["ssm_norm"], cfg.norm_eps)
+  return tuple(_mm(u, p, name, cfg.quant_compute) for name in ("w_z", "w_xbc", "w_dt"))
+
+
+@component_scope("xot.ssm_proj")
+def _ssm_out(h, y, p, cfg: ModelConfig):
+  return _residual(h, _mm(y, p, "w_out", cfg.quant_compute), cfg)
+
+
+def _ssm_conv(xbc, conv0, p):
+  """The causal depthwise convolution as ``K`` shifted adds, then silu. xbc
+  [B,S,C]; conv0 [B,K-1,C] the rows before it (zeros at a prompt's start).
+  Returns (activated [B,S,C], the padded input [B, K-1+S, C])."""
+  K, S = p["conv_w"].shape[0], xbc.shape[1]
+  xp = jnp.concatenate([conv0.astype(xbc.dtype), xbc], axis=1)
+  acc = p["conv_b"].astype(jnp.float32)
+  for j in range(K):
+    acc = acc + xp[:, j : j + S].astype(jnp.float32) * p["conv_w"][j].astype(jnp.float32)
+  return jax.nn.silu(acc).astype(xbc.dtype), xp
+
+
+def _ssm_split(xbc, cfg: ModelConfig):
+  """Activated xBC [..., di+2N] → x [..., H, P], B [..., N], C [..., N]."""
+  di, N = cfg.ssm_inner, cfg.ssm_state
+  x = xbc[..., :di].reshape(*xbc.shape[:-1], cfg.ssm_heads, cfg.ssm_head_dim)
+  return x, xbc[..., di : di + N], xbc[..., di + N :]
+
+
+def _ssm_gate(y, x, z, p, cfg: ModelConfig):
+  """Skip, gate, then the norm over all ``di`` channels (one group): y, x [..., H, P] f32, z [..., di]."""
+  y = y + p["D"].astype(jnp.float32)[:, None] * x
+  g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+  return rms_norm(g, p["gate_norm"], cfg.norm_eps).astype(z.dtype)
+
+
+def _ssm_chunk_scan(x, dt, a_log, bm, cm, state, chunk: int):
+  """The recurrence over a sequence, a chunk at a time (the SSD form).
+
+  x [B,S,H,P]; dt [B,S,H] f32 (0 at a padded position: the state passes it
+  unchanged); a_log [H] f32 = -exp(A_log); bm, cm [B,S,N]; state [B,H,P,N]
+  f32. Returns (y [B,S,H,P] f32 without the skip, state after position S-1).
+  Inside a chunk of L positions y is a masked [L, L] product, as attention
+  is; between chunks only the state is carried, so the [B, H, L, L] decay
+  term exists for one chunk at a time."""
+  B, S, H, P = x.shape
+  L = min(chunk, S)
+  pad = -S % L
+  if pad:
+    x, dt, bm, cm = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)) for t in (x, dt, bm, cm))
+  chunks = lambda t: jnp.moveaxis(t.reshape(B, -1, L, *t.shape[2:]), 1, 0)  # noqa: E731 — [c, B, L, ...]
+  tri = jnp.tril(jnp.ones((L, L), bool))
+  mm = x.dtype
+
+  def body(state, per_chunk):
+    xc, dtc, bc, cc = per_chunk
+    cs = jnp.cumsum(dtc * a_log, axis=1)  # [B,L,H], falling: log of the decay from the chunk's start through l
+    xdt = (xc.astype(jnp.float32) * dtc[..., None]).astype(mm)
+    seg = cs[:, :, None, :] - cs[:, None, :, :]  # [B,l,s,H]: log decay over (s, l]
+    decay = jnp.where(tri[None, :, :, None], jnp.exp(jnp.where(tri[None, :, :, None], seg, 0.0)), 0.0)
+    cb = jnp.einsum("bln,bsn->bls", cc, bc, preferred_element_type=jnp.float32)
+    y = jnp.einsum("blsh,bshp->blhp", (cb[..., None] * decay).astype(mm), xdt, preferred_element_type=jnp.float32)
+    y = y + jnp.einsum("bln,bhpn->blhp", cc, state.astype(mm), preferred_element_type=jnp.float32) * jnp.exp(cs)[..., None]
+    to_end = jnp.exp(cs[:, -1:, :] - cs)  # [B,L,H]
+    grown = jnp.einsum("bln,blhp->bhpn", bc, (xdt.astype(jnp.float32) * to_end[..., None]).astype(mm), preferred_element_type=jnp.float32)
+    return jnp.exp(cs[:, -1, :])[:, :, None, None] * state + grown, y
+
+  state, y = jax.lax.scan(body, state, tuple(chunks(t) for t in (x, dt, bm, cm)))
+  return jnp.moveaxis(y, 0, 1).reshape(B, S + pad, H, P)[:, :S], state
+
+
+def _ssm_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
+  """One state-space layer over a sequence: h [B,S,D], the rows' states ssm0
+  [B,H,P,N] f32 and conv0 [B,K-1,C] → (h, ssm, conv) after each row's
+  ``seq_lens`` tokens (None: all S). Positions past a row's length are
+  padding: Δ = 0 there and the convolution's tail is cut at the length, so
+  padding moves neither leaf."""
+  B, S, _ = h.shape
+  z, xbc, dt = _ssm_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    xbc, xp = _ssm_conv(xbc, conv0, p)
+    x, bm, cm = _ssm_split(xbc, cfg)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    if seq_lens is None:
+      conv = xp[:, S:]
+    else:
+      dt = jnp.where((jnp.arange(S, dtype=jnp.int32)[None, :] < seq_lens[:, None])[..., None], dt, 0.0)
+      conv = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, xp.shape[1] - S, axis=0))(xp, seq_lens)
+    y, ssm = _ssm_chunk_scan(x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), bm, cm, ssm0, cfg.ssm_chunk)
+    y = _ssm_gate(y, x.astype(jnp.float32), z, p, cfg)
+  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, ssm, conv.astype(conv0.dtype)
+
+
+def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
+  """One recurrence step of one state-space layer for every slot row: h
+  [B,1,D], ``pool`` the carried dict whose leaves ``ssm`` [Ls,B,H,P,N] and
+  ``conv`` [Ls,B,K-1,C] are read and written in place at ``layer``. A row
+  that is not ``active`` keeps both leaves bit for bit."""
+  z, xbc, dt = _ssm_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
+    ssm0 = jax.lax.dynamic_index_in_dim(pool["ssm"], layer, 0, keepdims=False).astype(jnp.float32)
+    xbc, xp = _ssm_conv(xbc, conv0, p)
+    x, bm, cm = _ssm_split(xbc[:, 0], cfg)
+    x = x.astype(jnp.float32)
+    dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"])  # [B,H]
+    a = jnp.exp(dt * -jnp.exp(p["A_log"].astype(jnp.float32)))
+    ssm = a[:, :, None, None] * ssm0 + (dt[:, :, None] * x)[..., None] * bm.astype(jnp.float32)[:, None, None, :]
+    y = jnp.einsum("bhpn,bn->bhp", ssm, cm.astype(jnp.float32))
+    pool = {
+      **pool,
+      "ssm": jax.lax.dynamic_update_index_in_dim(pool["ssm"], jnp.where(active[:, None, None, None], ssm, ssm0).astype(pool["ssm"].dtype), layer, 0),
+      "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], jnp.where(active[:, None, None], xp[:, 1:].astype(conv0.dtype), conv0), layer, 0),
+    }
+    y = _ssm_gate(y[:, None], x[:, None], z, p, cfg)
+  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, pool
+
+
+def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params, slot_rows=None, fresh=None, seq_lens=None, adapter_ids=None):
+  """A hybrid's layers over a sequence, in the published order: the prefill
+  of the paged programs and the cache-less forward (scoring, training).
+
+  ``carry`` rides the layer loop as the page pool does in decode
+  (``_scan_layers_over_pool``). Prefill: the rows' gathered K/V windows
+  [La, K, S_tot, Hkv, hd] under the pool's page-leaf names, plus the POOL's
+  own state leaves ``ssm`` / ``conv``, read and written at (layer,
+  ``slot_rows``) — a row whose ``fresh`` flag is set starts from zeros, so a
+  slot's last tenant is never seen; a padding row names a slot past the last
+  and its write is dropped. Cache-less: ``carry`` is empty, every row starts
+  from zeros and nothing is kept. Returns (h, carry)."""
+  from ..ops.paged import STATE_LEAVES
+
+  inv_freq = rope_inv_freq(cfg)
+  B = h.shape[0]
+  pages = [name for name in carry if name not in STATE_LEAVES]
+  kv_positions = jnp.arange(carry[pages[0]].shape[2], dtype=jnp.int32) if pages else positions[0]
+
+  def step(h, carry, lp, layer):
+    if "w_xbc" not in lp:
+      kv = {name: jax.lax.dynamic_index_in_dim(carry[name], layer, 0, keepdims=False) for name in pages} or None
+      h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, bool(pages), adapter_ids=adapter_ids)
+      return h, {**carry, **{name: jax.lax.dynamic_update_index_in_dim(carry[name], kv[name], layer, 0) for name in pages}}
+    if "ssm" not in carry:
+      ssm0 = jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+      h, _, _ = _ssm_layer(h, lp, cfg, ssm0, jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), h.dtype), seq_lens)
+      return h, carry
+    with jax.named_scope("xot.ssm"):
+      ssm0 = jnp.where(fresh[:, None, None, None], 0.0, carry["ssm"].at[layer, slot_rows].get(mode="clip")).astype(jnp.float32)
+      conv0 = jnp.where(fresh[:, None, None], 0, carry["conv"].at[layer, slot_rows].get(mode="clip"))
+    h, ssm, conv = _ssm_layer(h, lp, cfg, ssm0, conv0, seq_lens)
+    with jax.named_scope("xot.ssm"):
+      carry = {**carry, "ssm": carry["ssm"].at[layer, slot_rows].set(ssm.astype(carry["ssm"].dtype), mode="drop"), "conv": carry["conv"].at[layer, slot_rows].set(conv, mode="drop")}
+    return h, carry
+
+  return _scan_layers_over_pool(step, h, _layer_runs(params, cfg), carry)
 
 
 def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: ModelConfig, use_cache: bool, attn_fn=None, adapter_ids=None):
@@ -569,7 +799,7 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
     attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
     if "post_attn_norm" in p:  # gemma2 post-attention layernorm
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-    h = h + attn_out
+    h = _residual(h, attn_out, cfg)
   h, aux = _mlp_block(h, p, cfg)
   return h, kv, aux
 
@@ -602,6 +832,8 @@ def head_logits(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray
     # Keep operands in model dtype on the MXU; accumulate fp32. (Casting the
     # [D,V] head to fp32 would double its HBM traffic on every decode step.)
     logits = jax.lax.dot_general(h, w_out.astype(h.dtype), (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+  if cfg.logits_scaling != 1.0:  # granite
+    logits = logits / cfg.logits_scaling
   if cfg.final_logit_softcap:
     logits = cfg.final_logit_softcap * jnp.tanh(logits / cfg.final_logit_softcap)
   return logits
@@ -642,7 +874,11 @@ def shard_forward(
   # lax.scan; MoE models with no dense prefix simply have no "layers" key.
   stacks = _layer_stacks(params)
 
-  if use_cache:
+  if "ssm_layers" in params:  # a hybrid, cache-less; with a cache its forward is the paged prefill (prefill_into_pages_many)
+    if use_cache:
+      raise ValueError("a hybrid has no slot-cache forward: shard_forward runs it cache-less")
+    h, new_cache = _hybrid_layers(h, params, cfg, positions, {}, adapter_ids=adapter_ids)[0], None
+  elif use_cache:
     parts = []
     off = 0
     for stack in stacks:
@@ -674,11 +910,15 @@ def shard_forward(
 
   if shard.is_last_layer:
     if head_pos is not None:
-      B = h.shape[0]
-      idx = head_pos.reshape(B, 1, 1)
-      h = jnp.take_along_axis(h, jnp.broadcast_to(idx, (B, 1, h.shape[-1])), axis=1)
+      h = _rows_at(h, head_pos)
     return head_logits(params, cfg, h), new_cache
   return h, new_cache
+
+
+def _rows_at(h: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+  """h [B,S,D] at each row's own S-axis index idx [B] → [B,1,D]."""
+  B = h.shape[0]
+  return jnp.take_along_axis(h, jnp.broadcast_to(idx.reshape(B, 1, 1), (B, 1, h.shape[-1])), axis=1)
 
 
 # Jitted entry: cfg/shard are static (hashable frozen dataclasses).
@@ -1048,8 +1288,7 @@ def prefill_into_slots(params, cfg: ModelConfig, shard: Shard, tokens, cache, ro
   return logits[:, 0, :], cache
 
 
-@partial(tracked_jit, "prefill.pages_many", static_argnames=("cfg", "shard", "page_size"))
-def prefill_into_pages_many(params, cfg: ModelConfig, shard: Shard, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, adapter_ids=None):
+def prefill_into_pages_many(params, cfg: ModelConfig, shard: Shard, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, adapter_ids=None, slot_rows=None):
   """``prefill_into_pages`` for K requests in ONE dispatch.
 
   tokens [K, S_pad] int32 — each row's prompt SUFFIX from its own
@@ -1059,16 +1298,40 @@ def prefill_into_pages_many(params, cfg: ModelConfig, shard: Shard, tokens, pool
   dynamic_update_slice clamps out-of-range starts, which would shift a
   row's writes onto wrong slots (batch_scheduler groups admissions by
   this constraint). Returns (last-token logits [K, V], pool).
+
+  A pool with per-slot state leaves (a hybrid's) is told ``slot_rows`` [K]
+  int32 too: the state-space layers start row k from the state at its slot —
+  from zeros where ``prefix_lens[k]`` is 0, which is what resets a slot for
+  its next tenant — and leave the state after its last real token there. A
+  padding row names a slot past the last; nothing is written for it.
   """
-  from ..ops.paged import gather_row_pages, scatter_row_pages, touched_page_targets
+  from ..ops.paged import gather_row_pages, page_leaves, scatter_row_pages, state_leaves, touched_page_targets
 
   K, S = tokens.shape
-  temp = {key: gather_row_pages(val, bt_rows) for key, val in pool.items()}
+  pages = page_leaves(pool)
+  temp = {key: gather_row_pages(val, bt_rows) for key, val in pages.items()}
   positions = prefix_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-  logits, temp = shard_forward(params, cfg, shard, tokens, positions, temp, head_pos=prompt_lens - prefix_lens - 1, adapter_ids=adapter_ids)
+  if len(pages) < len(pool):
+    h, carry = _hybrid_layers(
+      embed_tokens(params, cfg, tokens), params, cfg, positions, {**temp, **state_leaves(pool)},
+      slot_rows=slot_rows, fresh=prefix_lens == 0, seq_lens=prompt_lens - prefix_lens, adapter_ids=adapter_ids,
+    )
+    logits, temp, state = head_logits(params, cfg, _rows_at(h, prompt_lens - prefix_lens - 1)), page_leaves(carry), state_leaves(carry)
+  else:
+    logits, temp = shard_forward(params, cfg, shard, tokens, positions, temp, head_pos=prompt_lens - prefix_lens - 1, adapter_ids=adapter_ids)
+    state = {}
   target = touched_page_targets(bt_rows, prefix_lens, prompt_lens, page_size)
-  pool = {key: scatter_row_pages(pool[key], temp[key], target) for key in pool}
+  pool = {**{key: scatter_row_pages(pages[key], temp[key], target) for key in pages}, **state}
   return logits[:, 0, :], pool
+
+
+_PAGES_MANY_STATIC = ("cfg", "shard", "page_size")
+_pages_many = prefill_into_pages_many  # one body, one name in the trace, two jitted forms
+prefill_into_pages_many = tracked_jit("prefill.pages_many", _pages_many, static_argnames=_PAGES_MANY_STATIC)
+# The same program with the pool DONATED, for a pool that holds per-slot state: a second copy of that leaf
+# (4.9 GB for 64 slots of granite-4.0-h-micro) does not fit beside the first, so its prefill writes in place
+# and a failed one costs the pool (the scheduler then rebuilds it, as after a failed decode chunk).
+prefill_into_pages_many_inplace = tracked_jit("prefill.pages_many", _pages_many, static_argnames=_PAGES_MANY_STATIC, donate_argnums=(4,))
 
 
 @partial(tracked_jit, "sample.rows", static_argnames=("k_max",))
@@ -1110,13 +1373,17 @@ def prefill_into_slots_sampled(params, cfg: ModelConfig, shard: Shard, tokens, c
   return tok, cache
 
 
-@partial(tracked_jit, "prefill.pages_many_sampled", static_argnames=("cfg", "shard", "page_size", "k_max"))
-def prefill_into_pages_many_sampled(params, cfg: ModelConfig, shard: Shard, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, temps, top_ks, key, k_max: int, adapter_ids=None):
+def prefill_into_pages_many_sampled(params, cfg: ModelConfig, shard: Shard, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size: int, temps, top_ks, key, k_max: int, adapter_ids=None, slot_rows=None):
   """``prefill_into_pages_many`` with the sampling epilogue fused in-program
   (the paged-admission analogue of ``prefill_into_slots_sampled``)."""
-  last, pool = prefill_into_pages_many(params, cfg, shard, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size, adapter_ids)
+  last, pool = prefill_into_pages_many(params, cfg, shard, tokens, pool, bt_rows, prefix_lens, prompt_lens, page_size, adapter_ids, slot_rows)
   tok, _ = _next_token_batched(last, key, temps, top_ks, k_max)
   return tok, pool
+
+
+_pages_many_sampled = prefill_into_pages_many_sampled
+prefill_into_pages_many_sampled = tracked_jit("prefill.pages_many_sampled", _pages_many_sampled, static_argnames=(*_PAGES_MANY_STATIC, "k_max"))
+prefill_into_pages_many_sampled_inplace = tracked_jit("prefill.pages_many_sampled", _pages_many_sampled, static_argnames=(*_PAGES_MANY_STATIC, "k_max"), donate_argnums=(4,))
 
 
 @component_scope("xot.sample")
@@ -1196,7 +1463,14 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params):
   a layer's step touches the token rows it writes and the pages it reads.
   ``layer`` is the pool's own layer index and runs on across the stacks, so a
   model of two (dense prefix + experts) indexes the one pool from both, with
-  no split and no join."""
+  no split and no join.
+
+  A hybrid's entry is a run ``(stack, lo, hi)`` (``_layer_runs``): layers
+  [lo, hi) of a stack whose kind owns leaves of the pool of its own (K/V pages
+  for attention layers, per-slot state for state-space layers), so ``layer``
+  is the layer's index in its stack, and the layer's parameters are read at
+  that index inside the loop, as a scan reads its ``xs``: a slice of the
+  stack cut out beforehand would be a copy of the run's weights."""
 
   def body(carry, per_layer):
     lp, layer = per_layer
@@ -1204,6 +1478,15 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params):
 
   first = 0
   for stack in stacks:
+    if isinstance(stack, tuple):
+      stack, lo, hi = stack
+
+      def run_body(carry, layer, stack=stack):
+        lp = {name: jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False) for name, leaf in stack.items()}
+        return step(*carry, lp, layer), None
+
+      (h, pool), _ = jax.lax.scan(run_body, (h, pool), jnp.arange(lo, hi, dtype=jnp.int32))
+      continue
     n = next(iter(stack.values())).shape[0]
     (h, pool), _ = jax.lax.scan(body, (h, pool), (stack, first + jnp.arange(n, dtype=jnp.int32)))
     first += n
@@ -1215,12 +1498,29 @@ def _layer_stacks(params: Params) -> list:
   return [params[name] for name in ("layers", "moe_layers") if name in params]
 
 
+def _layer_runs(params: Params, cfg: ModelConfig) -> list:
+  """A hybrid's layers in the published order, as runs ``(stack, lo, hi)`` of
+  one kind for ``_scan_layers_over_pool``: granite-4.0-h-micro is state-space
+  runs of 5, 9, 9, 9 and 4 with an attention layer after each of the first
+  four. Any other model: its stacks, whole."""
+  if "ssm_layers" not in params:
+    return _layer_stacks(params)
+  runs, done = [], {"mamba": 0, "attention": 0}
+  for i, kind in enumerate(cfg.layer_types):
+    if i and cfg.layer_types[i - 1] == kind:
+      runs[-1][2] += 1
+    else:
+      runs.append([params["ssm_layers" if kind == "mamba" else "layers"], done[kind], done[kind] + 1])
+    done[kind] += 1
+  return [tuple(r) for r in runs]
+
+
 def _write_kv(pool: Params, k, v, layer, block_tables, pos, page_size: int, kv_quant: str, kernel: bool = False, interpret: bool = False) -> Params:
   """One token a row (k/v [B, Hkv, hd]) of one layer into the stacked pool:
   as it is for float pools, as per-(token, head) codes and scales for
   int8/int4 pages (models/quantize.py). ``kernel``: the pool is in the
   kernel's form and Mosaic writes it (ops/paged.py ``write_token_kv``)."""
-  from ..ops.paged import write_token_kv
+  from ..ops.paged import page_leaves, write_token_kv
 
   new = {"k": k, "v": v}
   if kv_quant:
@@ -1228,7 +1528,7 @@ def _write_kv(pool: Params, k, v, layer, block_tables, pos, page_size: int, kv_q
 
     quant_fn = quantize_kv_int4 if kv_quant == "int4" else quantize_kv
     (new["k"], new["k_scale"]), (new["v"], new["v_scale"]) = quant_fn(k), quant_fn(v)
-  return write_token_kv(pool, new, layer, block_tables, pos, page_size, kernel, interpret)
+  return {**pool, **write_token_kv(page_leaves(pool), new, layer, block_tables, pos, page_size, kernel, interpret)}
 
 
 def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg: ModelConfig, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None):
@@ -1274,24 +1574,30 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
     attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
     if "post_attn_norm" in p:  # gemma2
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-    h = h + attn_out
+    h = _residual(h, attn_out, cfg)
   h, _ = _mlp_block(h, p, cfg)
   return h, pool
 
 
-def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None):
+def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None, active=None):
   """One decode step for all rows against the page pool.
 
   tokens [B, 1] int32 → (logits [B, 1, V], updated pool). Full shard only
   (the batched server is single-node). The pool comes back in the form it
-  came in (``_paged_layer_step``)."""
+  came in (``_paged_layer_step``). A hybrid's state-space layers step their
+  per-slot state leaves of the pool instead (``_ssm_decode_step``), for the
+  rows ``active`` [B] names (None: all)."""
   h = embed_tokens(params, cfg, tokens)
   inv_freq = rope_inv_freq(cfg)
+  if active is None:
+    active = jnp.ones((tokens.shape[0],), jnp.bool_)
 
   def step(h, pool, lp, layer):
+    if "w_xbc" in lp:
+      return _ssm_decode_step(h, pool, lp, layer, active, cfg)
     return _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
 
-  h, pool = _scan_layers_over_pool(step, h, _layer_stacks(params), pool)
+  h, pool = _scan_layers_over_pool(step, h, _layer_runs(params, cfg), pool)
   return head_logits(params, cfg, h), pool
 
 
@@ -1313,7 +1619,7 @@ def _paged_decode_scan(params, cfg: ModelConfig, shard: Shard, token, pool, bloc
     # their table to the trash page so held-token rewrites can't land on a
     # page another row now owns.
     bt = jnp.where(active[:, None], block_tables, 0)
-    logits, pool = paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, bt, page_size, use_kernel, adapter_ids, kv_quant)
+    logits, pool = paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, bt, page_size, use_kernel, adapter_ids, kv_quant, active)
     nxt, key = _next_token_batched(logits[:, 0, :], key, temps, top_ks, k_max)
     nxt = jnp.where(active, nxt, tok[:, 0])  # inactive rows hold their token
     pos = jnp.where(active, pos + 1, pos)  # ...and their position
@@ -1830,9 +2136,11 @@ def score_last_tokens(params, cfg: ModelConfig, shard: Shard, tokens, seq_len, n
     h, _, aux = _layer_step(h, lp, None, positions, positions[0], inv_freq, cfg, False)
     return (h, _aux + aux), None
 
-  stacks = _layer_stacks(params)
-  for stack in stacks:
-    (h, _), _ = jax.lax.scan(body, (h, jnp.float32(0.0)), stack)
+  if "ssm_layers" in params:  # a hybrid: the same mixers from a zero state, in the published order
+    h, _ = _hybrid_layers(h, params, cfg, positions, {})
+  else:
+    for stack in _layer_stacks(params):
+      (h, _), _ = jax.lax.scan(body, (h, jnp.float32(0.0)), stack)
 
   # Hidden states at positions [L-n-1, L-2] predict tokens [L-n, L-1].
   # ``n_scored`` is BUCKETED by the caller (jax_engine.score_tokens) so one

@@ -34,6 +34,7 @@ _MODEL_TYPE = {
   "phi3": "phi3",  # fused qkv / gate_up re-fused on write
   "mixtral": "mixtral",  # expert stacks unstacked to per-expert names
   "qwen2-moe": "qwen2_moe",
+  "granite-hybrid": "granitemoehybrid",  # two stacks re-interleaved by layer_types
 }
 
 
@@ -60,7 +61,7 @@ def export_hf_checkpoint(out_dir: str | Path, cfg: ModelConfig, params: dict, dt
     raise NotImplementedError("HF export of vision (llava) trees is not supported — the tower/projector would be silently dropped")
   if not isinstance(params, dict) or "embed" not in params or "final_norm" not in params:
     raise ValueError("export needs a FULL model tree (first+last shard params); mesh serving modes (pp/sp) hold params elsewhere — export from a plain load")
-  for stack_key in ("layers", "moe_layers"):
+  for stack_key in ("layers", "moe_layers", "ssm_layers"):
     if any(k.endswith("_scale") for k in params.get(stack_key, {})):
       raise NotImplementedError("params are int8/int4-quantized (XOT_TPU_QUANT); export from an unquantized load — casting quantized codes to float would silently corrupt the checkpoint")
 
@@ -84,6 +85,9 @@ def export_hf_checkpoint(out_dir: str | Path, cfg: ModelConfig, params: dict, dt
   sd: dict[str, np.ndarray] = {"model.embed_tokens.weight": _np32(params["embed"])}
   # MoE stacks live under "moe_layers" (dense-prefix models) or "layers".
   stacks = [params[k] for k in ("layers", "moe_layers") if k in params]
+  if cfg.recurrent_layers:
+    stacks = []
+    _granite_hybrid_layers(sd, cfg, params)
   i = -1
   for stack in stacks:
     L = stack["attn_norm"].shape[0]
@@ -198,6 +202,15 @@ def export_hf_checkpoint(out_dir: str | Path, cfg: ModelConfig, params: dict, dt
       hidden_act="gelu_pytorch_tanh",
       hidden_activation="gelu_pytorch_tanh",
     )
+  if cfg.family == "granite-hybrid":
+    hf_cfg.update(
+      layer_types=list(cfg.layer_types), shared_intermediate_size=cfg.hidden_dim, num_local_experts=0, num_experts_per_tok=0,
+      mamba_n_heads=cfg.ssm_heads, mamba_d_head=cfg.ssm_head_dim, mamba_d_state=cfg.ssm_state, mamba_d_conv=cfg.ssm_conv, mamba_chunk_size=cfg.ssm_chunk,
+      mamba_expand=cfg.ssm_inner // cfg.dim, mamba_n_groups=1, mamba_conv_bias=True, mamba_proj_bias=False,
+      embedding_multiplier=cfg.embed_scale, residual_multiplier=cfg.residual_multiplier, logits_scaling=cfg.logits_scaling,
+      attention_multiplier=cfg.attn_multiplier or cfg.head_dim**-0.5, position_embedding_type="rope" if cfg.use_rope else "nope",
+    )
+    del hf_cfg["head_dim"]  # GraniteMoeHybridConfig derives it from hidden_size // num_attention_heads
   if cfg.n_experts:
     hf_cfg.update(num_experts_per_tok=cfg.n_active_experts, norm_topk_prob=cfg.norm_topk_prob)
     if cfg.family == "mixtral":
@@ -214,8 +227,36 @@ def export_hf_checkpoint(out_dir: str | Path, cfg: ModelConfig, params: dict, dt
   return out_dir
 
 
+def _granite_hybrid_layers(sd: dict, cfg: ModelConfig, params: dict) -> None:
+  """``GraniteMoeHybridForCausalLM``'s per-layer names from the two stacks, in ``layer_types`` order (the
+  inverse of loader.py's split): the mixer by kind, then the fused SwiGLU ``shared_mlp`` every layer has."""
+  seen = {"mamba": 0, "attention": 0}
+  for i, kind in enumerate(cfg.layer_types):
+    stack = params["ssm_layers" if kind == "mamba" else "layers"]
+    p = {k: v[seen[kind]] for k, v in stack.items()}
+    seen[kind] += 1
+    pre = f"model.layers.{i}"
+    if kind == "mamba":
+      sd[f"{pre}.input_layernorm.weight"] = _np32(p["ssm_norm"])
+      sd[f"{pre}.mamba.in_proj.weight"] = np.concatenate([_lin(p["w_z"]), _lin(p["w_xbc"]), _lin(p["w_dt"])], axis=0)
+      sd[f"{pre}.mamba.conv1d.weight"] = np.ascontiguousarray(_np32(p["conv_w"]).T[:, None, :])  # [K, C] → [C, 1, K]
+      sd[f"{pre}.mamba.conv1d.bias"] = _np32(p["conv_b"])
+      for name in ("dt_bias", "A_log", "D"):
+        sd[f"{pre}.mamba.{name}"] = _np32(p[name])
+      sd[f"{pre}.mamba.norm.weight"] = _np32(p["gate_norm"])
+      sd[f"{pre}.mamba.out_proj.weight"] = _lin(p["w_out"])
+    else:
+      sd[f"{pre}.input_layernorm.weight"] = _np32(p["attn_norm"])
+      for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj")):
+        sd[f"{pre}.self_attn.{theirs}.weight"] = _lin(p[ours])
+    sd[f"{pre}.post_attention_layernorm.weight"] = _np32(p["mlp_norm"])
+    sd[f"{pre}.shared_mlp.input_linear.weight"] = np.concatenate([_lin(p["w_gate"]), _lin(p["w_up"])], axis=0)
+    sd[f"{pre}.shared_mlp.output_linear.weight"] = _lin(p["w_down"])
+
+
 def _arch(family: str) -> str:
   return {
+    "granite-hybrid": "GraniteMoeHybridForCausalLM",
     "llama": "LlamaForCausalLM",
     "qwen2": "Qwen2ForCausalLM",
     "qwen3": "Qwen3ForCausalLM",

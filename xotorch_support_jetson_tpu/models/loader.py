@@ -74,7 +74,17 @@ _LAYER_MAP: dict[str, tuple[str, bool]] = {
   "mlp.shared_experts.up_proj.weight": ("w_shared_up", True),
   "mlp.shared_experts.down_proj.weight": ("w_shared_down", True),
   "mlp.shared_expert_gate.weight": ("w_shared_expert_gate", True),
+  # granitemoehybrid (HF GraniteMoeHybridMambaLayer / GraniteMoeHybridMLP): a "mamba" layer's input_layernorm
+  # is renamed ``ssm_norm`` below; in_proj, conv1d.weight and the fused shared_mlp.input_linear are handled by name.
+  "mamba.out_proj.weight": ("w_out", True),
+  "mamba.conv1d.bias": ("conv_b", False),
+  "mamba.dt_bias": ("dt_bias", False),
+  "mamba.A_log": ("A_log", False),
+  "mamba.D": ("D", False),
+  "mamba.norm.weight": ("gate_norm", False),
+  "shared_mlp.output_linear.weight": ("w_down", True),
 }
+_F32_KEYS = ("router_bias", "dt_bias", "A_log", "D")  # small per-layer vectors whose precision the model leans on
 
 # Per-expert projections: `{block_sparse_moe|mlp}.experts.{e}.{proj}.weight`,
 # stacked into [E, D, F] / [E, F, D] leaves (mixtral names w1/w3/w2).
@@ -228,10 +238,17 @@ def load_shard_weights(model_dir: str | Path, cfg: ModelConfig, shard: Shard) ->
             per_layer[layer_idx]["wk"] = arr[qd : qd + kd].T
             per_layer[layer_idx]["wv"] = arr[qd + kd :].T
             continue
-          if suffix == "mlp.gate_up_proj.weight":  # phi3: fused [2F, D]
+          if suffix in ("mlp.gate_up_proj.weight", "shared_mlp.input_linear.weight"):  # phi3, granite: fused [2F, D]
             arr = _to_numpy(f.get_tensor(raw_name))
             per_layer[layer_idx]["w_gate"] = arr[: cfg.hidden_dim].T
             per_layer[layer_idx]["w_up"] = arr[cfg.hidden_dim :].T
+            continue
+          if suffix == "mamba.in_proj.weight":  # [di + (di + 2N) + H, D], cut at its three outputs z | xBC | dt
+            arr, di, C = _to_numpy(f.get_tensor(raw_name)), cfg.ssm_inner, cfg.ssm_conv_dim
+            per_layer[layer_idx].update(w_z=arr[:di].T, w_xbc=arr[di : di + C].T, w_dt=arr[di + C :].T)
+            continue
+          if suffix == "mamba.conv1d.weight":  # depthwise [C, 1, K] → taps-major [K, C]
+            per_layer[layer_idx]["conv_w"] = _to_numpy(f.get_tensor(raw_name))[:, 0, :].T
             continue
           em = _EXPERT_RE.match(suffix)
           if em is not None:
@@ -254,6 +271,13 @@ def load_shard_weights(model_dir: str | Path, cfg: ModelConfig, shard: Shard) ->
   first_k = cfg.first_k_dense if cfg.n_experts else shard.n_layers
   all_idx = range(shard.start_layer, shard.end_layer + 1)
   groups = [("layers", [i for i in all_idx if i < first_k]), ("moe_layers", [i for i in all_idx if i >= first_k])]
+  if cfg.recurrent_layers:
+    # A hybrid's two stacks, each in model order (models/decoder.py _layer_runs interleaves them again).
+    if not (shard.is_first_layer and shard.is_last_layer):
+      raise ValueError("a configuration with recurrent layers loads whole: its two stacks do not split by a layer range")
+    groups = [("layers", [i for i in all_idx if cfg.layer_types[i] == "attention"]), ("ssm_layers", [i for i in all_idx if cfg.layer_types[i] == "mamba"])]
+    for i in groups[1][1]:
+      per_layer[i]["ssm_norm"] = per_layer[i].pop("attn_norm")
 
   _norm_keys = ("attn_norm", "post_attn_norm", "mlp_norm", "post_mlp_norm")
 
@@ -262,7 +286,7 @@ def load_shard_weights(model_dir: str | Path, cfg: ModelConfig, shard: Shard) ->
       if sorted(t) != list(range(len(t))):
         raise ValueError(f"{key}: missing expert tensors (have {sorted(t)})")
       t = np.stack([t[e] for e in range(len(t))])
-    dtype = jnp.float32 if key == "router_bias" else cfg.dtype
+    dtype = jnp.float32 if key in _F32_KEYS else cfg.dtype
     if cfg.post_norms and key in _norm_keys:
       # gemma stores zero-centered norm weights; HF computes x*(1+w.float())
       # in fp32, so the gain must stay fp32 — a bf16(1+w) round-trip loses
@@ -368,6 +392,14 @@ def check_shard_params(params: Params, cfg: ModelConfig, shard: Shard) -> None:
     return exp
 
   checks: dict[str, dict] = {}
+  if cfg.recurrent_layers:  # a hybrid: "layers" holds its attention layers only, "ssm_layers" the rest
+    n_dense, L, Ls = cfg.n_attn_layers, cfg.n_attn_layers, cfg.recurrent_layers
+    H, di, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+    checks["ssm_layers"] = {
+      "ssm_norm": (Ls, cfg.dim), "w_z": (Ls, cfg.dim, di), "w_xbc": (Ls, cfg.dim, C), "w_dt": (Ls, cfg.dim, H), "conv_w": (Ls, cfg.ssm_conv, C), "conv_b": (Ls, C),
+      "dt_bias": (Ls, H), "A_log": (Ls, H), "D": (Ls, H), "gate_norm": (Ls, di), "w_out": (Ls, di, cfg.dim), "mlp_norm": (Ls, cfg.dim),
+      "w_gate": (Ls, cfg.dim, cfg.hidden_dim), "w_up": (Ls, cfg.dim, cfg.hidden_dim), "w_down": (Ls, cfg.hidden_dim, cfg.dim),
+    }
   if n_dense:
     checks["layers"] = {
       **attn_expect(n_dense),

@@ -46,8 +46,22 @@ from .attention import NEG_INF, gqa_attention, mla_absorbed_attention
 
 DEFAULT_PAGE_SIZE = 64
 
+# A hybrid's per-slot leaves of the pool dict: the state of its state-space
+# layers, indexed [layer, slot row] and not by page. They are donated, carried
+# and returned with the page leaves; whatever walks the pool by page takes
+# ``page_leaves`` of it.
+STATE_LEAVES = ("ssm", "conv")
 
-def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtype=None, quant: str | None = None) -> dict:
+
+def page_leaves(pool: dict) -> dict:
+  return {name: leaf for name, leaf in pool.items() if name not in STATE_LEAVES}
+
+
+def state_leaves(pool: dict) -> dict:
+  return {name: leaf for name, leaf in pool.items() if name in STATE_LEAVES}
+
+
+def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtype=None, quant: str | None = None, n_slots: int = 0) -> dict:
   """Page pool for a shard. ``n_pages`` INCLUDES the reserved trash page 0.
 
   Geometry follows ``models/decoder.py init_kv_cache``: GQA heads for dense
@@ -60,10 +74,26 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   iff ``shape[-1] * 2 == cfg.cache_k_dim``) and the same per-(slot, head)
   scales, halving page bytes AGAIN vs int8 (~2x pages, ~2x effective pool
   read bandwidth, half the host-tier and wire bytes per page).
+
+  A configuration with recurrent layers has pages for its attention layers
+  only (the layer axis is ``cfg.n_attn_layers``) and, beside them, the state
+  of its state-space layers for each of ``n_slots`` rows: ``ssm``
+  [Ls, n_slots, H, P, N] in float32 (the recurrence multiplies it by a decay
+  near 1 for thousands of steps; what it loses in bfloat16 is read in PERF.md
+  §6, PR 34) and ``conv`` [Ls, n_slots, K-1, di+2N], the rows the convolution
+  still needs, in the model dtype. Zeros: a slot's state before its first
+  tenant, and what a prefill from position 0 starts from.
   """
   from ..models.decoder import kv_quant_mode
 
   dtype = dtype or cfg.dtype
+  state = {}
+  if cfg.recurrent_layers:
+    n_shard_layers, Ls = cfg.n_attn_layers, cfg.recurrent_layers
+    state = {
+      "ssm": jnp.zeros((Ls, n_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
+      "conv": jnp.zeros((Ls, n_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype),
+    }
   mode = kv_quant_mode(cfg, quant)
   kd, vd = cfg.cache_k_dim, cfg.cache_v_dim
   if mode == "int4":
@@ -79,8 +109,9 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
       "v": jnp.zeros(v_shape, dtype=jnp.int8),
       "k_scale": jnp.ones(scale_shape, dtype=jnp.float32),
       "v_scale": jnp.ones(scale_shape, dtype=jnp.float32),
+      **state,
     }
-  return {"k": jnp.zeros(k_shape, dtype=dtype), "v": jnp.zeros(v_shape, dtype=dtype)}
+  return {"k": jnp.zeros(k_shape, dtype=dtype), "v": jnp.zeros(v_shape, dtype=dtype), **state}
 
 
 def _stacked(leaf, layer):
@@ -547,7 +578,7 @@ def kernel_pool_form(pool: dict) -> dict:
   buffer per leaf from the first step to the last. Costs nothing for code
   leaves of whole lanes (int8/bf16 at hd 128/256); the scale leaves (3 % of
   an int8 pool) are relaid, and code leaves under 128 lanes copied, once."""
-  return {name: _kernel_leaf(leaf) for name, leaf in pool.items()}
+  return {name: leaf if name in STATE_LEAVES else _kernel_leaf(leaf) for name, leaf in pool.items()}
 
 
 @component_scope("xot.kv_write")
@@ -556,7 +587,7 @@ def stored_pool_form(pool: dict, like: dict) -> dict:
   pool as ``init_paged_pool`` lays it out), at the program's end."""
   out = {}
   for name, leaf in pool.items():
-    want = like[name].shape
+    want = like[name].shape  # (a per-slot state leaf has one form: it passes through)
     if leaf.ndim < len(want):  # lane-dense scales → [L, P, Hkv, ps, 1]
       leaf = leaf[..., : want[-2], None]
     out[name] = leaf if leaf.shape == want else leaf[..., : want[-1]]
