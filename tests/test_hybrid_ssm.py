@@ -13,6 +13,7 @@ token at a time. Logits here have a spread of ~0.2; tolerances are absolute.
 
 import asyncio
 import sys
+from functools import partial
 from pathlib import Path
 
 import jax
@@ -28,6 +29,7 @@ from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer  
 from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine  # noqa: E402
 from xotorch_support_jetson_tpu.models import decoder as dec  # noqa: E402
 from xotorch_support_jetson_tpu.models.config import config_from_hf  # noqa: E402
+from xotorch_support_jetson_tpu.ops import ssm as ssm_ops  # noqa: E402
 from xotorch_support_jetson_tpu.ops.paged import init_paged_pool  # noqa: E402
 
 HF = {
@@ -39,6 +41,11 @@ HF = {
 CFG = config_from_hf(HF)
 PARAMS, SHARD = dec.full_model_params(jax.random.PRNGKey(0), CFG)
 PARAMS["ssm_layers"]["conv_b"] = 0.1 * jax.random.normal(jax.random.PRNGKey(1), PARAMS["ssm_layers"]["conv_b"].shape)  # a bias that is not zero
+# The same architecture with a state of 128 lanes (16 heads x 8 x 128): the widths the one-pass form of the state's
+# decode step tiles (ops/ssm.py one_pass_supported), which the rehearsal's state of 16 is not.
+HF_LANES = {**HF, "mamba_n_heads": 16, "mamba_d_head": 8, "mamba_d_state": 128}
+CFG_LANES = config_from_hf(HF_LANES)
+PARAMS_LANES = dec.full_model_params(jax.random.PRNGKey(0), CFG_LANES)[0]
 PS, SLOTS, MP = 16, 4, 8
 RNG = np.random.default_rng(0)
 TOKENS = RNG.integers(3, CFG.vocab_size, size=96)
@@ -50,6 +57,24 @@ TOL = 5e-6
 def highest_precision():
   with jax.default_matmul_precision("highest"):
     yield
+
+
+@pytest.fixture(params=["reference", "one_pass"])
+def state_step(request, monkeypatch):
+  """The form a decode program steps the recurrent state in. ``one_pass``: the module's model is the 128-lane one and
+  ``ssm_state_step`` is told what a TPU's program would be — the Mosaic kernel, here in interpret mode — while the
+  attention layers stay on the gather path (their kernel has tests of its own). A new configuration is a new static
+  argument, so no program traced for the other form is met again."""
+  if request.param == "reference":
+    yield request.param
+    return
+  real, traced = ssm_ops.ssm_state_step, []
+  for name, value in (("HF", HF_LANES), ("CFG", CFG_LANES), ("PARAMS", PARAMS_LANES)):
+    monkeypatch.setattr(sys.modules[__name__], name, value)
+  monkeypatch.setattr(ssm_ops, "ssm_state_step", lambda *args, **_: traced.append(1) or real(*args[:7], use_kernel=True, interpret=True))
+  assert ssm_ops.one_pass_supported(fresh_pool()["ssm"], True)
+  yield request.param
+  assert traced, "the decode program of this case was not traced with the one-pass form"
 
 
 def reference(tokens) -> np.ndarray:
@@ -79,9 +104,9 @@ def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None 
   return dec.prefill_into_pages_many(PARAMS, CFG, SHARD, jnp.asarray(tok), pool, jnp.asarray(bts), jnp.asarray(prefix_lens), jnp.asarray(prompt_lens), PS, None, jnp.asarray(slot_rows))
 
 
-@jax.jit
-def _decode_forward(tok, pos, pool, active):
-  return dec.paged_decode_forward(PARAMS, CFG, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)
+@partial(jax.jit, static_argnums=0)
+def _decode_forward(cfg, params, tok, pos, pool, active):
+  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)
 
 
 def decode_step(pool, tokens: dict, positions: dict):
@@ -89,7 +114,7 @@ def decode_step(pool, tokens: dict, positions: dict):
   tok, pos, active = np.zeros((SLOTS, 1), np.int32), np.zeros((SLOTS,), np.int32), np.zeros((SLOTS,), bool)
   for r, t in tokens.items():
     tok[r, 0], pos[r], active[r] = t, positions[r], True
-  logits, pool = _decode_forward(jnp.asarray(tok), jnp.asarray(pos), pool, jnp.asarray(active))
+  logits, pool = _decode_forward(CFG, PARAMS, jnp.asarray(tok), jnp.asarray(pos), pool, jnp.asarray(active))
   return np.asarray(logits[:, 0]), pool
 
 
@@ -143,9 +168,10 @@ def test_the_cacheless_forward_equals_the_reference():
   np.testing.assert_allclose(np.asarray(got[0]), reference(TOKENS), atol=TOL, rtol=0)
 
 
-def test_prefill_then_decode_through_pool_and_state_equals_the_reference():
+def test_prefill_then_decode_through_pool_and_state_equals_the_reference(state_step):
   """(b) 50 prompt tokens prefilled into slot 2 (padded to 64, beside three padding rows), then 30 decode steps, one
-  token each, through pages and state: every step's logits are the reference's full forward at that position."""
+  token each, through pages and state: every step's logits are the reference's full forward at that position — in
+  either form of the state's decode step."""
   want = reference(TOKENS[:80])
   last, pool = prefill(fresh_pool(), {2: TOKENS[:50]}, pad_to=64, pad_rows=3)
   np.testing.assert_allclose(np.asarray(last[0]), want[49], atol=TOL, rtol=0)
@@ -194,9 +220,9 @@ def test_a_reused_slot_gives_its_second_tenant_the_solo_answer():
     np.testing.assert_array_equal(got, want)
 
 
-def test_a_decode_chunk_leaves_an_inactive_rows_state_bit_for_bit():
+def test_a_decode_chunk_leaves_an_inactive_rows_state_bit_for_bit(state_step):
   """(e) A chunk of 4 steps of ``decode.paged_batch`` with rows 0 and 3 active: rows 1 and 2, resident but not
-  stepped (a row mid-prefill, a starved row), keep both state leaves exactly."""
+  stepped (a row mid-prefill, a starved row), keep both state leaves exactly — in either form of the state's step."""
   _, pool = prefill(fresh_pool(), {0: TOKENS[:20], 1: TOKENS[20:50], 2: TOKENS[50:58], 3: TOKENS[30:70]}, pad_to=64)
   before = {slot: state_of(pool, slot) for slot in range(SLOTS)}
   active = np.asarray([True, False, False, True])

@@ -758,6 +758,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_node_role",  # 0=both 1=prefill 2=decode (ISSUE 10)
   "xot_tpu_kv_quant_bits",  # 16=bf16 8=int8 4=int4 (ISSUE 11)
   "xot_tpu_recurrent_state_bytes",  # per-slot state beside the page pool (ISSUE 34)
+  "xot_tpu_recurrent_state_step",  # {form}: 1 on the form the decode programs step that state in, one_pass / reference (ISSUE 35)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
   # Multi-LoRA serving (ISSUE 15; swaps labeled {direction}, requests
   # labeled {adapter} — adapter names are client-asserted, same trust note
@@ -839,6 +840,7 @@ def test_metric_name_snapshot_after_serving():
   gm.set_gauge("spec_gamma", 0, labels={"row": "0"})
   gm.set_gauge("spec_proposer", 0, labels={"row": "0"})
   gm.set_gauge("kv_draft_bytes", 0)
+  gm.set_gauge("recurrent_state_step", 0, labels={"form": "reference"})  # set when a pool with state leaves is made (ISSUE 35)
   gm.inc("recurrent_state_resets_total", 0)  # event-driven: only a configuration with recurrent layers resets a slot's state (ISSUE 34)
   gm.set_gauge("kv_draft_slots", 0)
   gm.set_gauge("kv_draft_pages_equivalent", 0)

@@ -278,14 +278,46 @@ def test_qkv_weights_are_read_where_they_lie(chip, llama_1b, weights):
   print(f"decode.paged_batch B=16 {weights}: temp={mem.temp_size_in_bytes}")
 
 
+def _takers(text: str, shape: str) -> list[tuple[str, str]]:
+  """(name, opcode) of every fusion and custom call of an optimised HLO text that takes a value of ``shape`` (a
+  regex) as an operand. Operands are printed by name, so the names' shapes are read first."""
+  line_re = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([a-z][\w\-]*)\((.*)$", re.M)
+  lines = line_re.findall(text)
+  shapes = {name: result for name, result, _, _ in lines}
+  out = []
+  for name, _, op, rest in lines:
+    if op in ("fusion", "custom-call") and any(re.fullmatch(shape + r"\S*", shapes.get(operand, "")) for operand in re.findall(r"%[\w.\-]+", rest.split("), ")[0])):
+      out.append((name, op))
+  return out
+
+
+@pytest.mark.parametrize("slots", [64, 16])
+def test_state_step_kernel_compiles_for_v5e(chip, slots):
+  """``ops/ssm.py``'s one-pass form alone at granite-4.0-h-micro's leaf (ISSUE 35): the stacked leaf aliased input →
+  output and read at a layer scalar, [32, 64, 128] tiles in and out of VMEM inside the default scoped limit, the
+  lanes→sublanes relayout of Δ·x, the lane sums and the index map of rows that stand still as Mosaic lowers them for
+  a v5e. Nothing copies the leaf."""
+  from xotorch_support_jetson_tpu.ops.ssm import one_pass_supported, ssm_state_step
+
+  leaf = _sds(chip, (36, slots, 64, 64, 128), jnp.float32)
+  assert one_pass_supported(leaf, True)
+  step = jax.jit(lambda *args: ssm_state_step(*args, use_kernel=True), donate_argnums=0)
+  per_head, per_row = _sds(chip, (slots, 64), jnp.float32), _sds(chip, (slots, 128), jnp.float32)
+  compiled = step.lower(leaf, _sds(chip, (), jnp.int32), per_head, _sds(chip, (slots, 64, 64), jnp.float32), per_row, per_row, _sds(chip, (slots,), jnp.bool_)).compile()
+  text = compiled.as_text()
+  assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and "ssm_state_step" in text
+  assert not re.search(rf"= f32\[36,{slots},64,64,128\]\S* (copy|copy-start|transpose)\(", text)
+  assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20  # y and the relaid decay: no second state
+
+
 def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   """granite-4.0-h-micro whole, as ``granite-4.0-h-micro.decode-closed-64`` serves it (ISSUE 34): 64 slots, 1537
   pages, bf16, the kernel path. ``decode.paged_batch`` is accepted by XLA:TPU beside 6.4 GB of weights, 4.9 GB of
   recurrent state and 0.8 GB of pages. The state leaf is one buffer from the donated argument to the result: no
   instruction copies it (a copy is a second 4.8 GB, and PR 29's finding over again); it is read at (layer) and
-  written back by fusions the compiler aliases to it. No stacked state-space projection is relaid or copied:
-  HF's one ``in_proj`` of 8512 columns is no whole number of lanes, the TPU kept that stack column-major and
-  copied all 1.26 GB of it once a dispatch for the dot, so it is three leaves (AOT, PR 34; PERF.md section 6)."""
+  written back by the Mosaic call ``ssm_state_step``, which aliases it (PR 35; until then by fusions the compiler
+  aliased to it). No stacked state-space projection is relaid or copied: HF's one ``in_proj`` of 8512 columns is no
+  whole number of lanes, the TPU kept that stack column-major and copied all 1.26 GB of it once a dispatch for the dot, so it is three leaves (AOT, PR 34; PERF.md section 6)."""
   import json
 
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
@@ -310,7 +342,13 @@ def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
     _sds(chip, (2,), jnp.uint32), None,
   )  # fmt: skip
   kernels = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
-  assert len(kernels) == 8, len(kernels)  # the paged kernel and the token write, once for each of the four attention layers' loops
+  # the paged kernel and the token write, once for each of the four attention layers' loops, and the state step
+  # (ops/ssm.py, the one-pass form), once for each of the five state-space runs'
+  assert len(kernels) == 13, len(kernels)
+  # Each run reads the state's tiles in ONE instruction, the kernel: no fusion takes the leaf or a layer of it (the
+  # reference expression compiles to two a run — the in-place update and the contraction's second read; PERF.md §6, PR 35).
+  takers = _takers(text, r"f32\[(36,|1,)?64,64,64,128\]")
+  assert len(takers) == 5 and all(op == "custom-call" and name.startswith("%ssm_state_step") for name, op in takers), takers
   state = r"f32\[36,64,64,64,128\]"
   copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {state}\S* (copy|copy-start|transpose)\(", line)]
   assert not copied, copied

@@ -530,9 +530,14 @@ def _mlp_block(h, p, cfg: ModelConfig):
 # the pre-convolution xBC: the two per-slot leaves ``ssm`` and ``conv`` that
 # ride beside the K/V pages in the page pool (ops/paged.py init_paged_pool).
 # Prefill scans a prompt in chunks of ``ssm_chunk`` from a state and returns
-# the state after the row's last real token; decode is one recurrence step.
-# Decays, steps and the state are float32; the chunk scan's matrix products
-# take their operands in the model dtype and accumulate in float32.
+# the state after the row's last real token; decode is one recurrence step,
+# and who steps the ``ssm`` leaf there is ``ops/ssm.py ssm_state_step``: the
+# XLA expression, or on a TPU one Mosaic kernel that passes over the leaf's
+# layer once — ``_ssm_decode_step`` keeps the rest (norm, projections, the
+# convolution and its ``conv`` leaf, softplus, exp, skip, gate, norm).
+# Decays, steps and the state are float32, in the pool and in both forms of
+# the decode step; the chunk scan's matrix products take their operands in
+# the model dtype and accumulate in float32.
 
 
 @component_scope("xot.ssm_proj")
@@ -631,27 +636,25 @@ def _ssm_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
   return h, ssm, conv.astype(conv0.dtype)
 
 
-def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
+def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bool = False):
   """One recurrence step of one state-space layer for every slot row: h
   [B,1,D], ``pool`` the carried dict whose leaves ``ssm`` [Ls,B,H,P,N] and
   ``conv`` [Ls,B,K-1,C] are read and written in place at ``layer``. A row
-  that is not ``active`` keeps both leaves bit for bit."""
+  that is not ``active`` keeps both leaves bit for bit. The state's own step
+  — decay, increment, contraction with C — is ``ops/ssm.py ssm_state_step``'s,
+  which passes over the leaf once where ``use_kernel`` and the leaf allow."""
+  from ..ops.ssm import ssm_state_step
+
   z, xbc, dt = _ssm_in(h, p, cfg)
   with jax.named_scope("xot.ssm"):
     conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
-    ssm0 = jax.lax.dynamic_index_in_dim(pool["ssm"], layer, 0, keepdims=False).astype(jnp.float32)
     xbc, xp = _ssm_conv(xbc, conv0, p)
     x, bm, cm = _ssm_split(xbc[:, 0], cfg)
     x = x.astype(jnp.float32)
     dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"])  # [B,H]
     a = jnp.exp(dt * -jnp.exp(p["A_log"].astype(jnp.float32)))
-    ssm = a[:, :, None, None] * ssm0 + (dt[:, :, None] * x)[..., None] * bm.astype(jnp.float32)[:, None, None, :]
-    y = jnp.einsum("bhpn,bn->bhp", ssm, cm.astype(jnp.float32))
-    pool = {
-      **pool,
-      "ssm": jax.lax.dynamic_update_index_in_dim(pool["ssm"], jnp.where(active[:, None, None, None], ssm, ssm0).astype(pool["ssm"].dtype), layer, 0),
-      "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], jnp.where(active[:, None, None], xp[:, 1:].astype(conv0.dtype), conv0), layer, 0),
-    }
+    ssm, y = ssm_state_step(pool["ssm"], layer, a, dt[:, :, None] * x, bm.astype(jnp.float32), cm.astype(jnp.float32), active, use_kernel)
+    pool = {**pool, "ssm": ssm, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], jnp.where(active[:, None, None], xp[:, 1:].astype(conv0.dtype), conv0), layer, 0)}
     y = _ssm_gate(y[:, None], x[:, None], z, p, cfg)
   h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
   return h, pool
@@ -1594,7 +1597,7 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
 
   def step(h, pool, lp, layer):
     if "w_xbc" in lp:
-      return _ssm_decode_step(h, pool, lp, layer, active, cfg)
+      return _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
     return _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
 
   h, pool = _scan_layers_over_pool(step, h, _layer_runs(params, cfg), pool)

@@ -82,7 +82,10 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   near 1 for thousands of steps; what it loses in bfloat16 is read in PERF.md
   §6, PR 34) and ``conv`` [Ls, n_slots, K-1, di+2N], the rows the convolution
   still needs, in the model dtype. Zeros: a slot's state before its first
-  tenant, and what a prefill from position 0 starts from.
+  tenant, and what a prefill from position 0 starts from. In decode the
+  ``ssm`` leaf is stepped in place at (layer) by ``ops/ssm.py
+  ssm_state_step`` — float32 in both of its forms, the XLA expression and
+  the one-pass Mosaic kernel — and ``conv`` by ``_ssm_decode_step`` itself.
   """
   from ..models.decoder import kv_quant_mode
 
