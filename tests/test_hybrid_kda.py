@@ -1,0 +1,418 @@
+"""A hybrid of Kimi-Delta-Attention and latent-attention layers with a share of routed experts on the served path
+(ISSUE 36): Ling-3.0-flash's architecture at the benchmark's rehearsal widths — a KDA layer with the dense FFN, a KDA
+and an MLA layer with experts, a KDA layer with experts; 8 of 32 experts held — against the benchmark's plain
+reference (``benchmark/arch_hybrid_kda_moe.py reference_forward``: float32, the delta rule token by token, the held
+experts one at a time, nothing of the program).
+
+The float32 cases run at ``highest`` matmul precision, so the program and the reference differ by the order of their
+sums alone: the chunked scan solves a chunk's 16 updates as one triangular system where the reference makes them a
+token at a time. Logits have a spread of ~1; tolerances are absolute.
+"""
+
+import asyncio
+import re
+import sys
+from dataclasses import replace
+from functools import partial
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmark"))
+
+import arch_hybrid_kda_moe as kind  # noqa: E402
+import common  # noqa: E402
+import weights  # noqa: E402
+
+from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer  # noqa: E402
+from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine  # noqa: E402
+from xotorch_support_jetson_tpu.inference.shard import Shard  # noqa: E402
+from xotorch_support_jetson_tpu.models import decoder as dec  # noqa: E402
+from xotorch_support_jetson_tpu.models.config import config_from_hf  # noqa: E402
+from xotorch_support_jetson_tpu.ops import moe as moe_ops  # noqa: E402
+from xotorch_support_jetson_tpu.ops import ssm as ssm_ops  # noqa: E402
+from xotorch_support_jetson_tpu.ops.paged import init_paged_pool  # noqa: E402
+
+FILE = common.load_config("ling-3.0-flash-ep4-d7")
+HF = {**{k: v for k, v in FILE.items() if not isinstance(v, dict)}, **kind.REHEARSE_WIDTHS, "torch_dtype": "float32", "max_position_embeddings": 256}
+CFG = config_from_hf(HF)
+SHARD = Shard("ling", 0, CFG.n_layers - 1, CFG.n_layers)
+BF16_PARAMS = weights.build_params(HF, 11)  # the benchmark's own seeded weights, bfloat16 leaves (the token-topic router among them)
+PARAMS = jax.tree.map(lambda x: x.astype(jnp.float32), BF16_PARAMS)
+PS, SLOTS, MP = 16, 4, 8
+RNG = np.random.default_rng(0)
+TOKENS = RNG.integers(3, CFG.vocab_size, size=112)
+# The program against the reference, both float32 at "highest": orders of summation only (measured 2e-6 on logits of
+# spread 1; the delta rule's triangular solve amplifies a rounding a little more than Mamba-2's plain sums do).
+TOL = 3e-5
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+  with jax.default_matmul_precision("highest"):
+    yield
+
+
+def reference(tokens, params=PARAMS) -> np.ndarray:
+  return np.asarray(kind.reference_forward(params, HF, jnp.asarray(tokens)))
+
+
+def fresh_pool(cfg=CFG):
+  return init_paged_pool(cfg, cfg.n_layers, 1 + SLOTS * MP, PS, n_slots=SLOTS)
+
+
+def tables() -> np.ndarray:
+  return np.arange(1, 1 + SLOTS * MP, dtype=np.int32).reshape(SLOTS, MP)
+
+
+def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None = None, pad_rows: int = 0, params=PARAMS, cfg=CFG):
+  """Prefill ``{slot: tokens}`` as one group (each row from ``prefix[slot]`` on) → (last logits [K, V], pool)."""
+  rows = sorted(prompts)
+  prefix = prefix or {}
+  K = len(rows) + pad_rows
+  S = pad_to or max(len(prompts[r]) - prefix.get(r, 0) for r in rows)
+  tok, bts = np.zeros((K, S), np.int32), np.zeros((K, MP), np.int32)
+  prefix_lens, prompt_lens, slot_rows = np.zeros((K,), np.int32), np.ones((K,), np.int32), np.full((K,), SLOTS, np.int32)
+  for i, r in enumerate(rows):
+    start = prefix.get(r, 0)
+    tok[i, : len(prompts[r]) - start] = prompts[r][start:]
+    bts[i], prefix_lens[i], prompt_lens[i], slot_rows[i] = tables()[r], start, len(prompts[r]), r
+  return dec.prefill_into_pages_many(params, cfg, SHARD, jnp.asarray(tok), pool, jnp.asarray(bts), jnp.asarray(prefix_lens), jnp.asarray(prompt_lens), PS, None, jnp.asarray(slot_rows))
+
+
+@partial(jax.jit, static_argnums=0)
+def _decode_forward(cfg, params, tok, pos, pool, active):
+  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)
+
+
+def decode_step(pool, tokens: dict, positions: dict, params=PARAMS, cfg=CFG):
+  """One teacher-forced decode step of the rows named → (logits [SLOTS, V], pool)."""
+  tok, pos, active = np.zeros((SLOTS, 1), np.int32), np.zeros((SLOTS,), np.int32), np.zeros((SLOTS,), bool)
+  for r, t in tokens.items():
+    tok[r, 0], pos[r], active[r] = t, positions[r], True
+  logits, pool = _decode_forward(cfg, params, jnp.asarray(tok), jnp.asarray(pos), pool, jnp.asarray(active))
+  return np.asarray(logits[:, 0]), pool
+
+
+def state_of(pool, slot: int):
+  return np.asarray(pool["ssm"][:, slot]), np.asarray(pool["conv"][:, slot])
+
+
+# ------------------------------------------------------------ (g) the configuration
+
+
+def test_config_from_hf_maps_the_catalog_rows_keys():
+  """The file's keys are the catalog row's; at the published widths: five KDA layers to one MLA layer, 32 heads of a
+  128 x 128 state, 512 routed experts of which 128 are held, top 8 inside 4 of 8 groups, chunks of 16."""
+  cfg = common.model_config(FILE)
+  assert cfg.family == "bailing-hybrid" and cfg.layer_types == ("kda",) * 5 + ("attention", "kda") and (cfg.recurrent_layers, cfg.n_attn_layers, cfg.recurrent_kind) == (6, 1, "kda")
+  assert [cfg.layer_stack(i) for i in range(7)] == ["ssm_layers"] + ["ssm_moe_layers"] * 4 + ["moe_layers", "ssm_moe_layers"]
+  assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_chunk, cfg.ssm_conv_dim, cfg.kda_lower_bound) == (32, 128, 128, 4, 16, 12288, -5.0)
+  assert (cfg.n_experts, cfg.experts_held, cfg.n_held_experts, cfg.n_active_experts, cfg.n_group, cfg.topk_group, cfg.group_mode, cfg.router_scoring) == (512, (0, 128), 128, 8, 8, 4, "top2sum", "sigmoid")
+  assert (cfg.first_k_dense, cfg.moe_hidden_dim, cfg.shared_expert_dim, cfg.hidden_dim, cfg.routed_scaling_factor, cfg.norm_topk_prob) == (1, 768, 768, 6144, 2.5, True)
+  assert cfg.is_mla and cfg.mla_q_norm and (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim) == (0, 512, 128, 64, 128)
+  assert (cfg.norm_eps, cfg.rope_theta, cfg.vocab_size, cfg.tied_embedding, cfg.max_seq_len) == (1e-6, 6e6, 39296, False, 4096)
+  assert CFG.layer_types == ("kda", "kda", "attention", "kda") and CFG.experts_held == (0, 8) and CFG.n_experts == 32
+  assert {name: next(iter(st.values())).shape[0] for name, st in PARAMS.items() if isinstance(st, dict)} == {"ssm_layers": 1, "ssm_moe_layers": 2, "moe_layers": 1}
+  assert jax.tree.map(lambda x: x.shape, dec.full_model_params(jax.random.PRNGKey(0), CFG)[0]) == jax.tree.map(lambda x: x.shape, PARAMS)  # the benchmark's maker and the program's agree leaf for leaf
+
+
+@pytest.mark.parametrize("key,value,named", [
+  ("expert_swiglu_limit_list", [0, 0, 4, 0], "expert_swiglu_limit_list"), ("share_expert_swiglu_limit_list", [5, 0, 0, 0], "share_expert_swiglu_limit_list"),
+  ("use_kda_lora", True, "use_kda_lora"), ("use_nGPT", True, "use_nGPT"), ("value_norm", True, "value_norm"), ("no_kda_lora", False, "no_kda_lora false"),
+  ("kda_safe_gate", False, "kda_safe_gate false"), ("gated_attention_proj_granularity_type", "element_wise", "head-wise"), ("experts_held_from", 30, "experts_held_from"),
+])  # fmt: skip
+def test_config_from_hf_refuses_what_is_not_implemented_by_name(key, value, named):
+  with pytest.raises(ValueError, match=named):
+    config_from_hf({**HF, key: value})
+
+
+def test_the_published_model_whole_is_refused_for_its_clamped_layers_and_a_checkpoint_for_its_names(tmp_path):
+  """All 42 layers: layers 34-41 clamp their SwiGLU, which is not implemented. And no safetensors name map exists for
+  the family: a checkpoint is refused by name, loader and exporter alike."""
+  from xotorch_support_jetson_tpu.models.hf_export import export_hf_checkpoint
+  from xotorch_support_jetson_tpu.models.loader import load_shard_weights
+
+  with pytest.raises(ValueError, match="swiglu_limit_list"):
+    config_from_hf({**{k: v for k, v in FILE.items() if not isinstance(v, dict)}, "num_hidden_layers": 42})
+  with pytest.raises(NotImplementedError, match="bailing_hybrid"):
+    load_shard_weights(tmp_path, CFG, SHARD)
+  with pytest.raises(NotImplementedError, match="bailing-hybrid"):
+    export_hf_checkpoint(tmp_path / "out", CFG, PARAMS)
+  with pytest.raises(ValueError, match="bailing_hybrid"):  # MODEL_FAMILIES' error lists the new family
+    config_from_hf({"model_type": "rwkv7"})
+
+
+# ------------------------------------------------------------ (a) the chunked delta rule
+
+
+def _random_kda_inputs(B, S, seed):
+  H, N, P = 3, 8, 16
+  ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+  unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+  q, k, v = unit(jax.random.normal(ks[0], (B, S, H, N))) / N**0.5, unit(jax.random.normal(ks[1], (B, S, H, N))), jax.random.normal(ks[2], (B, S, H, P))
+  g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (B, S, H, N)))  # all of (-5, 0): 16 positions of it reach e^-80
+  return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H))), jax.random.normal(ks[5], (B, H, P, N))
+
+
+def _token_by_token(q, k, v, g, beta, state, lens):
+  """The recurrence in float64 numpy, row by row up to its length: (o [B, S, H, P] zero past the length, final states)."""
+  q, k, v, g, beta, state = (np.asarray(t, np.float64) for t in (q, k, v, g, beta, state))
+  out = np.zeros(v.shape)
+  for b, n in enumerate(lens):
+    for t in range(n):
+      s = state[b] * np.exp(g[b, t])[:, None, :]  # [H, P, N]: the decay along the key channels
+      u = beta[b, t][:, None] * (v[b, t] - np.einsum("hpn,hn->hp", s, k[b, t]))
+      state[b] = s + u[:, :, None] * k[b, t][:, None, :]
+      out[b, t] = np.einsum("hpn,hn->hp", state[b], q[b, t])
+  return out, state
+
+
+@pytest.mark.parametrize("length,lens", [(48, (48, 48)), (45, (45, 17)), (16, (16, 1)), (7, (7, 3)), (1, (1, 1)), (70, (70, 33))])
+def test_the_chunked_delta_rule_equals_the_token_by_token_recurrence(length, lens):
+  """(a) ``_kda_chunk_scan`` in chunks of 16 from a non-zero state, rows of ragged lengths (log decay and beta 0 past a
+  row's length, as ``_kda_layer`` masks them): outputs up to each row's length and the state after it are the
+  recurrence's, and so are ``kda_state_step``'s, one token at a time."""
+  q, k, v, g, beta, s0 = _random_kda_inputs(2, length, length)
+  valid = jnp.arange(length)[None, :] < jnp.asarray(lens)[:, None]
+  o, state = dec._kda_chunk_scan(q, k, v, jnp.where(valid[..., None, None], g, 0.0), jnp.where(valid[..., None], beta, 0.0), s0, 16)
+  want_o, want_state = _token_by_token(q, k, v, g, beta, s0, lens)
+  np.testing.assert_allclose(np.where(np.asarray(valid)[..., None, None], np.asarray(o), 0.0), want_o, atol=2e-6, rtol=0)
+  np.testing.assert_allclose(np.asarray(state), want_state, atol=2e-6, rtol=0)
+  leaf = jnp.stack([jnp.zeros_like(s0), s0])  # layer 1 of a leaf of two
+  for t in range(length):
+    leaf, y = ssm_ops.kda_state_step(leaf, 1, jnp.exp(g[:, t]), beta[:, t], k[:, t], v[:, t], q[:, t], valid[:, t])
+    np.testing.assert_allclose(np.asarray(y)[np.asarray(valid[:, t])], want_o[:, t][np.asarray(valid[:, t])], atol=2e-6, rtol=0)
+  np.testing.assert_allclose(np.asarray(leaf[1]), want_state, atol=2e-6, rtol=0)
+  assert not np.asarray(leaf[0]).any()
+
+
+def test_a_longer_chunk_would_leave_float32s_range():
+  """Why ``ssm_chunk`` is 16 at a lower bound of -5: the factorised decays reach e^(5 x chunk), and float32 ends at
+  e^88.7. config_from_hf sets the chunk from the bound; at 18 positions of the fastest decay the scan is not finite."""
+  assert config_from_hf({**HF, "kda_lower_bound": -10}).ssm_chunk == 8 and config_from_hf({**HF, "kda_lower_bound": -1}).ssm_chunk == 80
+  q, k, v, _, beta, s0 = _random_kda_inputs(1, 18, 0)
+  g = jnp.full(q.shape, -4.99)
+  assert np.isfinite(np.asarray(dec._kda_chunk_scan(q, k, v, g, beta, s0, 16)[0])).all()
+  assert not np.isfinite(np.asarray(dec._kda_chunk_scan(q, k, v, g, beta, s0, 18)[0])).all()
+
+
+# ------------------------------------------------------------ (c) the share
+
+
+def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+  """(c) One expert layer of 32 routed experts, uncut, against the four chips' shares of it (8 experts each): each
+  share's routed part (``moe_ffn held=(lo, hi)`` over that chip's slice of the expert leaves, the router whole), summed,
+  plus the shared expert counted once, is the whole layer as the reference gives it with all 32 held. And one share
+  alone is what the reference gives for that share."""
+  D, E, Fm = CFG.dim, CFG.n_experts, CFG.moe_hidden_dim
+  ks = jax.random.split(jax.random.PRNGKey(7), 8)
+  w = lambda key, *shape: jax.random.normal(key, shape) * shape[-2] ** -0.5  # noqa: E731
+  router, bias = PARAMS["ssm_moe_layers"]["w_router"][0], 0.05 * jax.random.normal(ks[0], (E,))  # the token-topic router; a selection bias that is not zero
+  eg, eu, ed = w(ks[1], E, D, Fm), w(ks[2], E, D, Fm), w(ks[3], E, Fm, D)
+  sg, su, sd = w(ks[4], D, Fm), w(ks[5], D, Fm), w(ks[6], Fm, D)
+  x = PARAMS["embed"][TOKENS[:40]]
+  h = jnp.zeros_like(x)
+  moe = dict(top_k=8, n_group=CFG.n_group, topk_group=CFG.topk_group, scaling=CFG.routed_scaling_factor, eps=CFG.norm_eps)
+  whole = kind._moe_ffn(h + x, jnp.ones((D,)), router, bias, eg, eu, ed, sg, su, sd, lo=0, **moe) - x  # norm gain 1: the layer's input is rms(x)
+  xn = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + CFG.norm_eps)
+  routed = lambda lo, hi: moe_ops.moe_ffn(  # noqa: E731
+    xn, router, eg[lo:hi], eu[lo:hi], ed[lo:hi], k=8, scoring="sigmoid", norm_topk=True, selection_bias=bias, scale=2.5, n_group=CFG.n_group, topk_group=CFG.topk_group,
+    group_mode="top2sum", held=(lo, hi),
+  )
+  shares = [routed(lo, lo + 8) for lo in range(0, E, 8)]
+  shared = (jax.nn.silu(xn @ sg) * (xn @ su)) @ sd
+  np.testing.assert_allclose(np.asarray(sum(shares) + shared), np.asarray(whole), atol=2e-5, rtol=0)
+  np.testing.assert_allclose(np.asarray(sum(shares)), np.asarray(routed(0, E)), atol=2e-5, rtol=0)  # ... and to the layer that holds them all
+  for lo in (0, 8):
+    one = kind._moe_ffn(h + x, jnp.ones((D,)), router, bias, eg[lo : lo + 8], eu[lo : lo + 8], ed[lo : lo + 8], sg, su, sd, lo=lo, **moe) - x
+    np.testing.assert_allclose(np.asarray(shares[lo // 8] + shared), np.asarray(one), atol=2e-5, rtol=0)
+  # a long run of tokens in blocks of 16 is the one block's result: routing is per token
+  blocked = moe_ops.moe_ffn(xn, router, eg[:8], eu[:8], ed[:8], k=8, scoring="sigmoid", norm_topk=True, selection_bias=bias, scale=2.5, n_group=CFG.n_group, topk_group=CFG.topk_group,
+                            group_mode="top2sum", held=(0, 8), chunk=16)  # fmt: skip
+  np.testing.assert_allclose(np.asarray(blocked), np.asarray(shares[0]), atol=2e-5, rtol=0)
+  assert all(float(jnp.abs(s).max()) > 1e-3 for s in shares)  # every chip's share is a part of the sum
+  assert float(jnp.abs(shares[0] - routed(0, E)).max()) > 1e-2  # ... and no one share is the layer
+
+
+# ------------------------------------------------------------ (b) (d) pool, state and pages
+
+
+def test_the_cacheless_forward_equals_the_reference():
+  got, _ = dec.shard_forward(PARAMS, CFG, SHARD, jnp.asarray(TOKENS)[None], jnp.arange(len(TOKENS))[None])
+  np.testing.assert_allclose(np.asarray(got[0]), reference(TOKENS), atol=TOL, rtol=0)
+
+
+def test_prefill_then_decode_through_pool_state_and_latent_pages_equals_the_reference():
+  """(b) float32: 50 prompt tokens prefilled into slot 2 (padded to 64, beside three padding rows), then 40 decode
+  steps, one token each, through the latent pages of the one MLA layer, the KDA layers' state and convolution rows:
+  every step's LOGITS are the reference's full forward at that position, to the order of the sums."""
+  want = reference(TOKENS[:90])
+  last, pool = prefill(fresh_pool(), {2: TOKENS[:50]}, pad_to=64, pad_rows=3)
+  assert pool["k"].shape[0] == 1 and pool["k"].shape[-1] == CFG.kv_lora_rank and pool["ssm"].shape == (3, SLOTS, 4, 16, 16) and pool["conv"].shape == (3, SLOTS, 3, 3 * 4 * 16)
+  np.testing.assert_allclose(np.asarray(last[0]), want[49], atol=TOL, rtol=0)
+  for t in range(50, 90):
+    logits, pool = decode_step(pool, {2: TOKENS[t]}, {2: t})
+    np.testing.assert_allclose(logits[2], want[t], atol=TOL, rtol=0, err_msg=f"decode step at position {t}")
+  for other in (0, 1, 3):  # nothing was written for the padding row, nor for a slot no request held
+    assert not state_of(pool, other)[0].any() and not state_of(pool, other)[1].any()
+
+
+def test_the_bfloat16_path_stays_within_bfloat16s_rounding_of_the_reference():
+  """(b) bfloat16 weights and activations as served, the state float32: prefill and 34 decode steps against the
+  float32 reference on the same bfloat16 weights. bfloat16 keeps 8 bits: each of the 4 layers' two blocks rounds its
+  output to 2^-8 of a residual of magnitude ~2, and the logits (spread 1) carry the sum — measured 0.051 at the worst
+  entry; 0.15 is three times that and a twelfth of what a dropped layer reads (1.78)."""
+  cfg = replace(CFG, dtype=jnp.bfloat16)
+  want = reference(TOKENS[:90], params=jax.tree.map(lambda x: x.astype(jnp.float32), BF16_PARAMS))
+  dropped = np.asarray(kind.reference_forward(PARAMS, HF, jnp.asarray(TOKENS[:90]), drop_layer=3))
+  last, pool = prefill(fresh_pool(cfg), {1: TOKENS[:56]}, pad_to=64, params=BF16_PARAMS, cfg=cfg)
+  assert pool["ssm"].dtype == jnp.float32 and pool["conv"].dtype == jnp.bfloat16 and pool["k"].dtype == jnp.bfloat16
+  worst = float(np.abs(np.asarray(last[0], np.float32) - want[55]).max())
+  for t in range(56, 90):
+    logits, pool = decode_step(pool, {1: TOKENS[t]}, {1: t}, params=BF16_PARAMS, cfg=cfg)
+    worst = max(worst, float(np.abs(logits[1].astype(np.float32) - want[t]).max()))
+  assert worst < 0.15 < 0.5 * float(np.abs(dropped[55:] - want[55:]).max()), worst
+
+
+def test_a_padded_group_leaves_each_row_the_state_of_its_unpadded_run():
+  """Rows of 50, 33 and 2 tokens as one group padded to 64: padding has no decay and no update and is cut from the
+  convolution's tail, so each slot's state is what the row's own prefill leaves alone."""
+  prompts = {0: TOKENS[:50], 1: TOKENS[10:43], 3: TOKENS[60:62]}
+  _, grouped = prefill(fresh_pool(), prompts, pad_to=64, pad_rows=1)
+  for slot, toks in prompts.items():
+    _, solo = prefill(fresh_pool(), {slot: toks}, pad_to=None if slot == 1 else 64)
+    for got, want in zip(state_of(grouped, slot), state_of(solo, slot)):
+      np.testing.assert_allclose(got, want, atol=TOL, rtol=0, err_msg=f"slot {slot}")
+
+
+def test_a_prompt_prefilled_in_two_chunks_equals_one():
+  """Positions [0, 48) then [48, 83): the second call continues from the slot's own state, convolution rows and pages."""
+  toks = TOKENS[:83]
+  whole_logits, whole = prefill(fresh_pool(), {1: toks}, pad_to=96)
+  _, pool = prefill(fresh_pool(), {1: toks[:48]}, pad_to=64)
+  cut_logits, cut = prefill(pool, {1: toks}, prefix={1: 48}, pad_to=64)
+  np.testing.assert_allclose(np.asarray(cut_logits), np.asarray(whole_logits), atol=TOL, rtol=0)
+  for got, want in zip(state_of(cut, 1), state_of(whole, 1)):
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+  np.testing.assert_allclose(np.asarray(cut_logits[0]), reference(toks)[-1], atol=TOL, rtol=0)
+
+
+def test_a_reused_slot_gives_its_second_tenant_the_solo_answer():
+  """(d) Slot 2 serves one request (prefill + decode steps), then another from position 0: the second sees zeros, not
+  its predecessor's state, and its logits and state are those of a pool it has to itself, bit for bit."""
+  _, pool = prefill(fresh_pool(), {2: TOKENS[:40]}, pad_to=64)
+  for t in range(40, 46):
+    _, pool = decode_step(pool, {2: TOKENS[t]}, {2: t})
+  assert state_of(pool, 2)[0].any()
+  second = TOKENS[50:77]
+  reused_logits, reused = prefill(pool, {2: second}, pad_to=64)
+  solo_logits, solo = prefill(fresh_pool(), {2: second}, pad_to=64)
+  np.testing.assert_array_equal(np.asarray(reused_logits), np.asarray(solo_logits))
+  for got, want in zip(state_of(reused, 2), state_of(solo, 2)):
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_decode_chunk_leaves_an_inactive_rows_state_bit_for_bit():
+  """(d) A chunk of 4 steps of ``decode.paged_batch`` with rows 0 and 3 active: rows 1 and 2, resident but not stepped,
+  keep the state and the convolution rows exactly."""
+  _, pool = prefill(fresh_pool(), {0: TOKENS[:20], 1: TOKENS[20:50], 2: TOKENS[50:58], 3: TOKENS[30:70]}, pad_to=64)
+  before = {slot: state_of(pool, slot) for slot in range(SLOTS)}
+  active = np.asarray([True, False, False, True])
+  pos = np.asarray([20, 30, 8, 40], np.int32)
+  _, _, new_pos, pool = dec.fused_paged_batch_decode(
+    PARAMS, CFG, SHARD, jnp.ones((SLOTS, 1), jnp.int32), pool, tables(), jnp.asarray(pos), jnp.asarray(active), np.zeros((SLOTS,), np.float32), 4, page_size=PS, use_kernel=False,
+  )
+  assert np.asarray(new_pos).tolist() == [24, 30, 8, 44]
+  for slot in (1, 2):
+    for got, want in zip(state_of(pool, slot), before[slot]):
+      np.testing.assert_array_equal(got, want)
+  for slot in (0, 3):
+    assert not np.array_equal(state_of(pool, slot)[0], before[slot][0]) and not np.array_equal(state_of(pool, slot)[1], before[slot][1])
+
+
+def test_latent_attention_by_query_blocks_is_the_whole_softmax():
+  """What a hybrid's prefill asks of ``mla_absorbed_attention``: queries taken ``q_block`` positions at a time (so that the
+  float32 scores of a whole group never exist at once) give what the one pass gives — each block's softmax is whole —
+  at a length that is no multiple of the block, and at offsets into a longer window."""
+  from xotorch_support_jetson_tpu.ops.attention import mla_absorbed_attention
+
+  B, S, T, H, nope, rope, rank, vd = 2, 40, 64, 4, 16, 8, 32, 16
+  ks = jax.random.split(jax.random.PRNGKey(3), 5)
+  q_nope, q_pe = jax.random.normal(ks[0], (B, S, H, nope)), jax.random.normal(ks[1], (B, S, H, rope))
+  ckv, kpe, w_kv_b = jax.random.normal(ks[2], (B, T, rank)), jax.random.normal(ks[3], (B, T, rope)), jax.random.normal(ks[4], (rank, H * (nope + vd))) * rank**-0.5
+  positions = jnp.asarray([[0], [17]]) + jnp.arange(S)[None, :]
+  whole = mla_absorbed_attention(q_nope, q_pe, ckv, kpe, w_kv_b, positions, jnp.arange(T), vd)
+  for block in (16, 8, 64):
+    np.testing.assert_allclose(np.asarray(mla_absorbed_attention(q_nope, q_pe, ckv, kpe, w_kv_b, positions, jnp.arange(T), vd, block)), np.asarray(whole), atol=2e-6, rtol=0)
+  assert dec._HYBRID_MLA_Q_BLOCK == 256
+
+
+# ------------------------------------------------------------ (e) the scheduler
+
+
+def _serve(server, prompts, n_gen):
+  async def run():
+    return await asyncio.gather(*(
+      server.submit(f"r{i}-{len(p)}", np.asarray(p, np.int32), max_tokens=n_gen, temp=0.0, top_k=35, eos_ids=(), emit=lambda *_: None) for i, p in enumerate(prompts)
+    ))
+
+  return asyncio.run(run())
+
+
+def _greedy_under_the_reference(prompt, answer) -> bool:
+  logits = reference(np.asarray(list(prompt) + list(answer)))
+  return [int(np.argmax(logits[len(prompt) - 1 + i])) for i in range(len(answer))] == list(answer)
+
+
+def test_the_scheduler_serves_interleaved_requests_as_the_reference_does(monkeypatch, capsys):
+  """(e) Two requests of different lengths through ``BatchedServer`` (admission groups, decode chunks, the pool's state
+  and latent pages) answer greedy-equal to the reference; the same long prompt again reuses no page; prefix reuse,
+  the host tier, speculation and mixed ticks are off by the ONE property ``recurrent_layers``; the gauges say which
+  rule steps the state and how many experts are held of how many routed."""
+  from xotorch_support_jetson_tpu.utils.metrics import metrics
+
+  monkeypatch.setenv("XOT_TPU_BATCH_SLOTS", "2")
+  monkeypatch.setenv("XOT_TPU_PAGE_SIZE", str(PS))
+  engine = JaxShardedInferenceEngine(use_local_mesh=False)
+  engine.load_test_model(SHARD, CFG, PARAMS)
+  server = BatchedServer(engine)
+  long_prompt, other = [int(t) for t in TOKENS[:52]], [int(t) for t in TOKENS[60:75]]
+  resets = lambda: metrics.counter_value("recurrent_state_resets_total")  # noqa: E731
+  before = resets()
+  try:
+    first = _serve(server, [long_prompt, other], 6)
+    hits = metrics.counter_value("prefix_cache_hit_pages_total")
+    again = _serve(server, [long_prompt], 6)
+    assert metrics.counter_value("prefix_cache_hit_pages_total") == hits and not server.allocator.cached_keys()
+  finally:
+    server.shutdown()
+  assert again[0] == first[0] and len(first[0]) == len(first[1]) == 6
+  assert _greedy_under_the_reference(long_prompt, first[0]) and _greedy_under_the_reference(other, first[1])
+  assert CFG.recurrent_layers == 3 and server.tier is None and not server.spec and not server._mixed_active() and not server.ops.mixed_tick_supported() and server.ops.prefill_donates_pool
+  assert resets() - before == 3
+  assert metrics.gauge_value("recurrent_state_bytes") == 2 * 3 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4)
+  assert metrics.gauge_value("recurrent_state_step", labels={"form": "delta_reference"}) == 1 and metrics.gauge_value("recurrent_state_step", labels={"form": "one_pass"}) == 0
+  assert (metrics.gauge_value("moe_experts_routed"), metrics.gauge_value("moe_experts_held")) == (32, 8)
+  assert capsys.readouterr().out.count("keep a recurrent state per slot") == 1
+
+
+# ------------------------------------------------------------ (f) tracing
+
+
+def test_the_scopes_reach_the_lowered_decode_program():
+  """(f) ``xot.ssm_proj`` (norm, ``w_qkv`` / ``w_f`` / ``w_bg``, ``w_out``) and ``xot.ssm`` (convolution, gates, the state's
+  read, delta step and write, head norm, output gate), the expert layer's three and the latent attention's are in the
+  lowered ``decode.paged_batch``: the readers granite's cell has read this cell too, and ``moe_experts_roofline`` its own."""
+  args = (
+    PARAMS, CFG, SHARD, jnp.ones((SLOTS, 1), jnp.int32), fresh_pool(), jnp.asarray(tables()), jnp.asarray([3, 5, 7, 9], jnp.int32), jnp.ones((SLOTS,), bool),
+    jnp.zeros((SLOTS,), jnp.float32), jnp.full((SLOTS,), 8, jnp.int32), 4, 8, PS, False, jax.random.PRNGKey(1), None,
+  )
+  text = dec._fused_paged_batch_decode_impl.xot_jitted.lower(*args).as_text(debug_info=True)
+  scopes = set(re.findall(r"xot\.[a-z_]+", text))
+  want = {"xot.ssm", "xot.ssm_proj", "xot.moe_router", "xot.moe_experts", "xot.moe_shared", "xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.ffn", "xot.head", "xot.sample"}
+  assert want <= scopes, sorted(want - scopes)
+  assert re.search(r'"[^"]*xot\.ssm/[^"]*dynamic_update_slice', text), "no state write under xot.ssm"

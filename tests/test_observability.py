@@ -758,7 +758,9 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_node_role",  # 0=both 1=prefill 2=decode (ISSUE 10)
   "xot_tpu_kv_quant_bits",  # 16=bf16 8=int8 4=int4 (ISSUE 11)
   "xot_tpu_recurrent_state_bytes",  # per-slot state beside the page pool (ISSUE 34)
-  "xot_tpu_recurrent_state_step",  # {form}: 1 on the form the decode programs step that state in, one_pass / reference (ISSUE 35)
+  "xot_tpu_recurrent_state_step",  # {form}: 1 on the rule and form the decode programs step that state in: one_pass / reference (Mamba-2, ISSUE 35), delta_reference (KDA, ISSUE 36)
+  "xot_tpu_moe_experts_routed",  # the router's width of the loaded shard's expert layers (0: dense) (ISSUE 36)
+  "xot_tpu_moe_experts_held",  # how many of those experts' weights the shard holds: fewer for one chip's share of an expert-parallel deployment (ISSUE 36)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
   # Multi-LoRA serving (ISSUE 15; swaps labeled {direction}, requests
   # labeled {adapter} — adapter names are client-asserted, same trust note

@@ -359,6 +359,78 @@ def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+def _ling_at_the_cells_settings(chip):
+  """(hf, cfg, params, pool) of ``ling-3.0-flash.decode-closed-64`` as shapes on the described chip."""
+  import json
+  from dataclasses import replace
+
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import full_model_params
+  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+  hf = json.loads((ROOT / "benchmark" / "configs" / "ling-3.0-flash-ep4-d7.json").read_text())
+  n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"]["XOT_TPU_BATCH_PAGES"])
+  cfg = replace(config_from_hf({k: v for k, v in hf.items() if not isinstance(v, dict)}), max_seq_len=int(hf["serving_window_tokens"]))
+  on_chip = lambda tree: jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)  # noqa: E731
+  params = on_chip(jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg)[0]))
+  pool = on_chip(jax.eval_shape(lambda: init_paged_pool(cfg, cfg.n_layers, n_pages, PS, n_slots=n_slots)))
+  return hf, cfg, params, pool
+
+
+def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
+  """Ling-3.0-flash's first stage at one chip's share, as ``ling-3.0-flash.decode-closed-64`` serves it (ISSUE 36): 64
+  slots, 1537 latent pages of ONE attention layer, bf16, 128 of 512 experts held. ``decode.paged_batch`` is accepted by
+  XLA:TPU beside 10.3 GB of weights, 0.81 GB of float32 matrix state and 0.11 GB of pages. The state leaf is one buffer
+  from the donated argument to the result: no instruction copies it or a layer of it; it is read at (layer) by the
+  fusions of the delta step and written back in place. No Mosaic call: MLA takes the gather path and the delta rule
+  has the XLA expression only. No stacked expert leaf is copied or relaid (a copy of one is 3.8 GB)."""
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip)
+  n_slots = pool["ssm"].shape[1]
+  assert pool["k"].shape == (1, 1537, 1, PS, 512) and pool["v"].shape == (1, 1537, 1, PS, 64) and pool["ssm"].shape == (6, 64, 32, 128, 128) and pool["conv"].shape == (6, 64, 3, 12288)
+  assert params["ssm_moe_layers"]["w_experts_gate"].shape == (5, 128, 2560, 768) and params["moe_layers"]["w_router"].shape == (1, 2560, 512)
+  rows = _rows(chip, n_slots)
+  compiled, text = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, Shard("ling", 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (n_slots, 1), jnp.int32), pool,
+    _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, False,
+    _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+  assert "tpu_custom_call" not in text
+  state = r"f32\[(6,)?64,32,128,128\]"
+  copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {state}\S* (copy|copy-start|transpose)\(", line)]
+  assert not copied, copied
+  experts = [line.strip()[:160] for line in text.splitlines() if re.search(r"= bf16\[(5,|1,)?128,(2560,768|768,2560)\]\S* (copy|copy-start|transpose)\(", line)]
+  assert not experts, experts
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch ling B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= 6 * 64 * 32 * 128 * 128 * 4  # the pool is donated: the state is updated where it lies
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip):
+  """The largest prefill program the cell meets — a group of 8 rows padded to 1024 tokens, ``prefill.pages_many_sampled``
+  with the pool donated — fits beside the weights and the state: the chunked delta rule's float32 operands, the expert
+  layer's dispatch at 256 tokens a block and the latent attention's scores of 256 queries at a time (whole, they are
+  4 GB twice over and the compiler refuses the program) are its temporaries."""
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import prefill_into_pages_many_sampled_inplace
+
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip)
+  K, S = 8, 1024
+  rows = _rows(chip, K)
+  compiled, _text = _compile(
+    prefill_into_pages_many_sampled_inplace, params, cfg, Shard("ling", 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (K, S), jnp.int32), pool,
+    _sds(chip, (K, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
+  )  # fmt: skip
+  mem = compiled.memory_analysis()
+  print(f"prefill.pages_many_sampled ling K=8 S=1024: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
 def test_compiler_refuses_a_pool_beyond_the_chip(chip, llama_1b):
   """What makes the test above a fit check: the same step over three times
   the pool is refused at compile time, not at run time."""

@@ -911,7 +911,7 @@ class BatchedServer:
 
       metrics.set_gauge("recurrent_state_bytes", sum(leaf.size * leaf.dtype.itemsize for leaf in state_leaves(self.cache).values()))
       if recurrent:  # which form the decode programs of this pool step the state in: what they will observe, asked once
-        form = state_step_form(self.cache["ssm"], paged_kernel_supported(eng.cfg))
+        form = state_step_form(self.cache["ssm"], paged_kernel_supported(eng.cfg), eng.cfg.recurrent_kind)
         for name in STATE_STEP_FORMS:
           metrics.set_gauge("recurrent_state_step", int(name == form), labels={"form": name})
       from .kv_tier import KvTierManager, kv_tier_enabled

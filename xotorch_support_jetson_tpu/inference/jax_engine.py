@@ -305,6 +305,7 @@ class JaxShardedInferenceEngine(InferenceEngine):
 
       self.params = quantize_params(self.params, self.quant)
     self.cfg = cfg
+    self._note_experts()
     self.shard = shard
     self._effective_shard = eff
     self._vision_params = None  # set by _split_vision_params in mesh modes
@@ -698,12 +699,19 @@ class JaxShardedInferenceEngine(InferenceEngine):
     prefer_processor = self.cfg is not None and self.cfg.vision is not None
     self.tokenizer = await resolve_tokenizer(repo, local, prefer_processor=prefer_processor)
 
+  def _note_experts(self) -> None:
+    """The expert layers' two counts as the loaded shard has them: the router's width, and how many of those experts'
+    weights are here (fewer where the shard is one chip's share of an expert-parallel deployment; 0 and 0: dense)."""
+    metrics.set_gauge("moe_experts_routed", self.cfg.n_experts)
+    metrics.set_gauge("moe_experts_held", self.cfg.n_held_experts)
+
   def load_test_model(self, shard: Shard, cfg, params, tokenizer=None) -> None:
     """Directly inject a model (unit tests / local pipeline composition)."""
     self.adapter_registry = None  # stale geometry: re-enable against the new params
     self.shard = shard
     self._effective_shard = shard
     self.cfg = cfg
+    self._note_experts()
     self.params = params
     self.tokenizer = tokenizer
     self._vision_params = None

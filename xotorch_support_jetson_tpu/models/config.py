@@ -137,21 +137,38 @@ class ModelConfig:
   # the placeholder token id the HF processor expands per image patch.
   vision: Any = None  # VisionConfig | None (Any keeps this module torch/vision-free)
   image_token_id: int = -1
-  # --- hybrid state-space models (granitemoehybrid): ``layer_types`` names
-  # every layer's mixer in model order ("mamba" | "attention"; empty ⇒ all
-  # attention). A "mamba" layer is a Mamba-2 mixer (one group) over ``ssm_heads``
+  # --- hybrids of recurrent and attention layers: ``layer_types`` names every
+  # layer's mixer in model order (a kind of RECURRENT_KINDS | "attention";
+  # empty ⇒ all attention). A recurrent layer keeps a per-slot state beside
+  # the page pool instead of K/V pages; a model's recurrent layers are all of
+  # one kind. A "mamba" layer (granitemoehybrid) is a Mamba-2 mixer (one group) over ``ssm_heads``
   # heads of ``ssm_head_dim`` with a state of ``ssm_state`` a channel, a causal
   # depthwise convolution of ``ssm_conv`` taps, scanned in chunks of
-  # ``ssm_chunk`` at prefill (models/decoder.py). The stacked parameters are
-  # ``ssm_layers`` [n "mamba"] beside ``layers`` [n "attention"], and the
-  # page pool keeps K/V pages for the attention layers only, with per-slot
-  # state leaves beside them (ops/paged.py init_paged_pool).
+  # ``ssm_chunk`` at prefill (models/decoder.py). A "kda" layer (bailing_hybrid)
+  # is Kimi Delta Attention: ``ssm_heads`` heads whose state is a matrix
+  # [``ssm_head_dim`` values x ``ssm_state`` key channels], decayed per key
+  # channel and corrected by a rank-one delta rule; q, k and v pass a causal
+  # depthwise convolution of ``ssm_conv`` taps; the per-channel log decay is
+  # ``kda_lower_bound`` x a sigmoid, so ``ssm_chunk`` positions of it stay
+  # inside float32's range. The stacked parameters are named by (mixer, FFN)
+  # pairing (``layer_stack``): ``layers`` / ``moe_layers`` the attention layers
+  # with a dense / an expert FFN, ``ssm_layers`` / ``ssm_moe_layers`` the
+  # recurrent ones. The page pool keeps pages for the attention layers only,
+  # with per-slot state leaves beside them (ops/paged.py init_paged_pool).
   layer_types: tuple[str, ...] = ()
   ssm_heads: int = 0
   ssm_head_dim: int = 0
   ssm_state: int = 0
   ssm_conv: int = 0
   ssm_chunk: int = 256
+  kda_lower_bound: float = 0.0  # "kda": the log decay of a key channel lies in (kda_lower_bound, 0)
+  # bailing_hybrid's ``use_qk_norm`` as read for its MLA layers: an RMSNorm over each query head's nope+rope channels
+  # before rope, beside the latent's own norm (leaf ``q_norm``).
+  mla_q_norm: bool = False
+  # An expert layer told which experts it holds (one chip's share of an expert-parallel deployment): the router, its
+  # bias, the groups and the top-k stay ``n_experts`` wide; the expert leaves hold experts [lo, hi) and the layer
+  # computes their part of the result alone (ops/moe.py moe_ffn ``held``). () ⇒ all of them.
+  experts_held: tuple[int, int] | tuple[()] = ()
   # Granite's four multipliers: ``embed_scale`` above is its
   # embedding_multiplier; every block's output is scaled by
   # ``residual_multiplier`` before it joins the residual; logits are divided
@@ -191,7 +208,23 @@ class ModelConfig:
     The ONE property the scheduler's gates read: above 0, pages alone are not
     a request's state, so whatever reuses or moves pages without it (prefix
     reuse, the host tier, speculation, mixed ticks) is off."""
-    return sum(1 for t in self.layer_types if t == "mamba")
+    return sum(1 for t in self.layer_types if t in RECURRENT_KINDS)
+
+  @property
+  def recurrent_kind(self) -> str:
+    """The one kind of this model's recurrent layers ("" where it has none)."""
+    return next((t for t in self.layer_types if t in RECURRENT_KINDS), "")
+
+  @property
+  def n_held_experts(self) -> int:
+    """Experts whose weights this shard holds: the expert axis of the expert leaves."""
+    return self.experts_held[1] - self.experts_held[0] if self.experts_held else self.n_experts
+
+  def layer_stack(self, layer_idx: int) -> str:
+    """The stacked-parameter dict layer ``layer_idx`` lives in, by its (mixer, FFN) pairing."""
+    recurrent = bool(self.layer_types) and self.layer_types[layer_idx] in RECURRENT_KINDS
+    experts = bool(self.n_experts) and layer_idx >= self.first_k_dense
+    return ("ssm_" if recurrent else "") + ("moe_layers" if experts else "layers")
 
   @property
   def n_attn_layers(self) -> int:
@@ -204,7 +237,9 @@ class ModelConfig:
 
   @property
   def ssm_conv_dim(self) -> int:
-    """Channels the convolution runs over: x and the one group's B and C."""
+    """Channels the convolution runs over: x and the one group's B and C ("mamba"); every head's q, k and v ("kda")."""
+    if self.recurrent_kind == "kda":
+      return self.ssm_heads * (2 * self.ssm_state + self.ssm_head_dim)
     return self.ssm_inner + 2 * self.ssm_state
 
   @property
@@ -244,11 +279,13 @@ class ModelConfig:
     return replace(self, n_layers=n_layers)
 
 
+RECURRENT_KINDS = ("mamba", "kda")  # the ``layer_types`` whose layers keep a per-slot state (``ModelConfig.recurrent_layers``)
+
 # HF ``model_type`` (or, with its underscores dropped, the ``architectures`` entry) -> family; first match wins, so
 # a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
 MODEL_FAMILIES = {
   "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
-  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "llama": "llama",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "llama": "llama",
 }
 
 
@@ -357,16 +394,27 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     shared_dim = n_shared * moe_hidden
     if family == "qwen2-moe":
       shared_dim = int(hf.get("shared_expert_intermediate_size") or 0)
+    if family == "bailing-hybrid":
+      shared_dim = int(hf.get("num_shared_experts") or 0) * int(hf.get("moe_shared_expert_intermediate_size") or moe_hidden)
     # deepseek group-limited routing: v3 is always sigmoid + top-2-sum group
     # scores (HF DeepseekV3TopkRouter); v2 keys it on topk_method.
     scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family == "deepseek-v3") else "softmax"
-    if family == "deepseek-v3":
+    if family == "deepseek-v3" or hf.get("topk_method") == "noaux_tc":
       group_mode = "top2sum"
     elif hf.get("topk_method") == "group_limited_greedy":
       group_mode = "max"
     else:
       group_mode = "none"
+    # A deployment's share (one chip of an expert-parallel group): the count above is of the experts HELD, from
+    # ``experts_held_from`` on, and ``num_experts_routed`` is the router's width, the published count.
+    routed, held = int(hf.get("num_experts_routed") or n_experts), ()
+    if routed != n_experts:
+      lo = int(hf.get("experts_held_from") or 0)
+      if not 0 <= lo <= routed - n_experts:
+        raise ValueError(f"experts_held_from {lo}: {n_experts} held experts do not lie inside the {routed} routed")
+      held, n_experts = (lo, lo + n_experts), routed
     moe = dict(
+      experts_held=held,
       n_experts=n_experts,
       n_active_experts=int(hf.get("num_experts_per_tok", 2)),
       moe_hidden_dim=moe_hidden,
@@ -409,6 +457,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
   hybrid: dict[str, Any] = {}
   if family == "granite-hybrid":
     hybrid = _granite_hybrid_fields(hf)
+  if family == "bailing-hybrid":
+    hybrid = _bailing_hybrid_fields(hf)
 
   n_heads = int(hf["num_attention_heads"])
   return ModelConfig(
@@ -417,7 +467,7 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     n_layers=int(hf["num_hidden_layers"]),
     n_heads=n_heads,
     n_kv_heads=int(hf.get("num_key_value_heads", n_heads)),
-    hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if hybrid else int(hf["intermediate_size"]),
+    hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if family == "granite-hybrid" else int(hf["intermediate_size"]),
     head_dim=int(hf.get("head_dim") or 0),
     norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
     rope_theta=float(hf.get("rope_theta", 10000.0)),
@@ -472,6 +522,39 @@ def _granite_hybrid_fields(hf: dict) -> dict:
     logits_scaling=float(hf.get("logits_scaling", 1.0)),
     attn_multiplier=float(hf.get("attention_multiplier") or 0.0),
     use_rope=pos == "rope",
+  )
+
+
+def _bailing_hybrid_fields(hf: dict) -> dict:
+  """``bailing_hybrid`` (Ling-3.0) → the hybrid fields of ModelConfig: layer ``i`` is an MLA layer where
+  ``(i + 1) % layer_group_size == 0``, else a Kimi-Delta-Attention layer. What the decoder does not implement is
+  refused here, by name, not served wrong. The multi-token-prediction module (``num_nextn_predict_layers``) is a draft
+  head outside the forward pass and ``max_window_layers`` has no ``use_sliding_window`` beside it: neither is read."""
+  n_layers, group = int(hf["num_hidden_layers"]), int(hf.get("layer_group_size") or 1)
+  for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+    if any(float(v) for v in (hf.get(key) or ())[:n_layers]):
+      raise ValueError(f"bailing_hybrid: a non-zero {key} in the layers kept (a clamp on the SwiGLU) is not supported")
+  on = [key for key in ("use_kda_lora", "use_nGPT", "value_norm", "up_proj_norm", "scale_router_input", "use_mla_nope", "use_bias", "use_qkv_bias") if hf.get(key)]
+  off = [key for key in ("no_kda_lora", "kda_safe_gate", "linear_silu", "rope_interleave", "moe_router_enable_expert_bias") if not hf.get(key, True)]
+  if on or off:
+    raise ValueError(f"bailing_hybrid: {', '.join(on + [f'{key} false' for key in off])} is not supported")
+  if hf.get("gated_attention_proj_granularity_type", "head_wise") != "head_wise" or int(hf.get("group_norm_size") or 1) != 1:
+    raise ValueError("bailing_hybrid: only a head-wise output gate (gated_attention_proj_granularity_type 'head_wise', group_norm_size 1) is supported")
+  heads, head_dim = int(hf["num_attention_heads"]), int(hf.get("head_dim") or int(hf["hidden_size"]) // int(hf["num_attention_heads"]))
+  if int(hf.get("num_kv_heads_for_linear_attn") or 0) not in (0, heads):
+    raise ValueError("bailing_hybrid: num_kv_heads_for_linear_attn other than 0 or num_attention_heads is not supported")
+  lower = float(hf.get("kda_lower_bound") or 0.0)
+  if not lower < 0:
+    raise ValueError("bailing_hybrid: kda_lower_bound must be below 0 (the log decay of a key channel lies between it and 0)")
+  return dict(
+    layer_types=tuple("attention" if (i + 1) % group == 0 else "kda" for i in range(n_layers)),
+    ssm_heads=heads,
+    ssm_head_dim=head_dim,
+    ssm_state=head_dim,
+    ssm_conv=int(hf.get("short_conv_kernel_size") or 4),
+    ssm_chunk=max(int(80.0 / -lower), 1),  # exp(±lower·chunk) stays inside float32 (e^80 = 5.5e34): 16 positions at -5
+    kda_lower_bound=lower,
+    mla_q_norm=bool(hf.get("use_qk_norm", False)),
   )
 
 

@@ -183,9 +183,12 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     lm_head    [D, V]            (last shard only; omitted when tied to a
                                   first-shard embed in the same params)
 
-  A hybrid (``cfg.layer_types``) keeps its attention layers in ``layers``
-  [La, ...] and its state-space layers in ``ssm_layers`` [Ls, ...], each in
-  model order (``_layer_runs`` interleaves them again):
+  A hybrid (``cfg.layer_types``) keeps each (mixer, FFN) pairing in a stack of
+  its own (``cfg.layer_stack``): attention layers in ``layers`` (dense FFN) /
+  ``moe_layers`` (experts), recurrent layers in ``ssm_layers`` /
+  ``ssm_moe_layers``, each in model order (``_layer_runs`` interleaves them
+  again). An expert stack's expert leaves hold ``cfg.n_held_experts`` experts;
+  its router and bias stay ``cfg.n_experts`` wide. A "mamba" stack:
     ssm_layers/ssm_norm [Ls, D]    ssm_layers/w_z [Ls, D, di]  w_xbc [Ls, D, di + 2*N]  w_dt [Ls, D, H]
                                    (HF's one in_proj, cut at its three outputs: its 2*di + 2*N + H columns are
                                    no whole number of 128 lanes, and the TPU stores such a stack column-major
@@ -194,6 +197,11 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     ssm_layers/dt_bias, A_log, D [Ls, H] f32
     ssm_layers/gate_norm [Ls, di]  ssm_layers/w_out [Ls, di, D]
     + mlp_norm, w_gate, w_up, w_down as in ``layers``
+  A "kda" stack (H heads, N key and P value channels a head, K taps):
+    ssm_norm [L, D]   w_qkv [L, D, H*(2N+P)] (q | k | v)   conv_w [L, K, H*(2N+P)]
+    w_f [L, D, H*N], b_f [L, H*N] f32 (the decay gate)     w_bg [L, D, 2H] (β | output gate, one a head)
+    o_norm [L, P] (the per-head output norm)               w_out [L, H*P, D]
+    + mlp_norm and the FFN's leaves (dense or expert)
   """
   dtype = dtype or cfg.dtype
   L = shard.n_shard_layers
@@ -222,6 +230,8 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
         leaves["wq_b"] = w(next(keys), L, cfg.q_lora_rank, H * qk)
       else:
         leaves["wq"] = w(next(keys), L, D, H * qk)
+      if cfg.mla_q_norm:
+        leaves["q_norm"] = jnp.ones((L, qk), dtype=dtype)
       return leaves
     leaves = {
       "attn_norm": jnp.ones((L, D), dtype=dtype),
@@ -249,16 +259,29 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       stack["is_sliding"] = sliding_flags(cfg, range(shard.start_layer, shard.start_layer + L))
     return stack
 
-  params: Params = {}
-  if cfg.recurrent_layers:
-    if not (shard.is_first_layer and shard.is_last_layer):
-      raise ValueError("a configuration with recurrent layers is built whole: its two stacks do not split by a layer range")
-    Ls, H, di, C = cfg.recurrent_layers, cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
-    if cfg.n_attn_layers:
-      params["layers"] = dense_stack(cfg.n_attn_layers)
+  def expert_ffn(Lm):
+    E, Eh, Fm, Fs = cfg.n_experts, cfg.n_held_experts, cfg.moe_hidden_dim, cfg.shared_expert_dim
+    moe = {
+      "w_router": w(next(keys), Lm, D, E),
+      "w_experts_gate": w(next(keys), Lm, Eh, D, Fm),
+      "w_experts_up": w(next(keys), Lm, Eh, D, Fm),
+      "w_experts_down": w(next(keys), Lm, Eh, Fm, D),
+    }
+    if cfg.router_scoring == "sigmoid":
+      moe["router_bias"] = jnp.zeros((Lm, E), dtype=jnp.float32)
+    if Fs:
+      moe["w_shared_gate"] = w(next(keys), Lm, D, Fs)
+      moe["w_shared_up"] = w(next(keys), Lm, D, Fs)
+      moe["w_shared_down"] = w(next(keys), Lm, Fs, D)
+      if cfg.shared_expert_gate:
+        moe["w_shared_expert_gate"] = w(next(keys), Lm, D, 1)
+    return moe
+
+  def mamba_leaves(Ls):
+    H, di, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
     # The Mamba-2 initialisation: decays -exp(A_log) in [-16, -1], steps softplus(dt_bias) log-uniform in [1e-3, 1e-1], skip D = 1.
     dt = jnp.exp(jax.random.uniform(next(keys), (Ls, H), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
-    params["ssm_layers"] = {
+    return {
       "ssm_norm": jnp.ones((Ls, D), dtype=dtype),
       "w_z": w(next(keys), Ls, D, di),
       "w_xbc": w(next(keys), Ls, D, C),
@@ -270,34 +293,45 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       "D": jnp.ones((Ls, H), jnp.float32),
       "gate_norm": jnp.ones((Ls, di), dtype=dtype),
       "w_out": w(next(keys), Ls, di, D),
-      "mlp_norm": jnp.ones((Ls, D), dtype=dtype),
-      "w_gate": w(next(keys), Ls, D, F),
-      "w_up": w(next(keys), Ls, D, F),
-      "w_down": w(next(keys), Ls, F, D),
     }
+
+  def kda_leaves(Ls):
+    H, N, P, C = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim, cfg.ssm_conv_dim
+    return {
+      "ssm_norm": jnp.ones((Ls, D), dtype=dtype),
+      "w_qkv": w(next(keys), Ls, D, C),
+      "conv_w": w(next(keys), Ls, cfg.ssm_conv, C, scale=cfg.ssm_conv**-0.5),
+      "w_f": w(next(keys), Ls, D, H * N),
+      # the gate's bias N(-3, 2^2) spreads the channels' decays over all of (e^kda_lower_bound, 1), most of them slow
+      "b_f": 2.0 * jax.random.normal(next(keys), (Ls, H * N), jnp.float32) - 3.0,
+      "w_bg": w(next(keys), Ls, D, 2 * H),
+      "o_norm": jnp.ones((Ls, P), dtype=dtype),
+      "w_out": w(next(keys), Ls, H * P, D),
+    }
+
+  params: Params = {}
+  if cfg.recurrent_layers:
+    if not (shard.is_first_layer and shard.is_last_layer):
+      raise ValueError("a configuration with recurrent layers is built whole: its stacks do not split by a layer range")
+    keys = iter(jax.random.split(next(keys), 96))  # up to four stacks of up to 17 drawn leaves
+    names = [cfg.layer_stack(i) for i in range(cfg.n_layers)]
+    recurrent_leaves = kda_leaves if cfg.recurrent_kind == "kda" else mamba_leaves
+    for name in dict.fromkeys(names):  # in the order the model meets them
+      n = names.count(name)
+      mixer = {**recurrent_leaves(n), "mlp_norm": jnp.ones((n, D), dtype=dtype)} if name.startswith("ssm_") else attn_leaves(n)
+      params[name] = {**mixer, **(expert_ffn(n) if name.endswith("moe_layers") else {"w_gate": w(next(keys), n, D, F), "w_up": w(next(keys), n, D, F), "w_down": w(next(keys), n, F, D)})}
   elif cfg.n_experts:
     # MoE model: dense prefix (layers [0, first_k_dense) globally), MoE rest.
     n_dense = min(max(cfg.first_k_dense - shard.start_layer, 0), L)
-    Lm, E, Fm, Fs = L - n_dense, cfg.n_experts, cfg.moe_hidden_dim, cfg.shared_expert_dim
+    Lm = L - n_dense
     if n_dense:
       params["layers"] = dense_stack(n_dense)
     moe_start = shard.start_layer + n_dense
     moe = {
       **({"is_sliding": sliding_flags(cfg, range(moe_start, moe_start + Lm))} if cfg.sliding_window else {}),
       **attn_leaves(Lm),
-      "w_router": w(next(keys), Lm, D, E),
-      "w_experts_gate": w(next(keys), Lm, E, D, Fm),
-      "w_experts_up": w(next(keys), Lm, E, D, Fm),
-      "w_experts_down": w(next(keys), Lm, E, Fm, D),
+      **expert_ffn(Lm),
     }
-    if cfg.router_scoring == "sigmoid":
-      moe["router_bias"] = jnp.zeros((Lm, E), dtype=jnp.float32)
-    if Fs:
-      moe["w_shared_gate"] = w(next(keys), Lm, D, Fs)
-      moe["w_shared_up"] = w(next(keys), Lm, D, Fs)
-      moe["w_shared_down"] = w(next(keys), Lm, Fs, D)
-      if cfg.shared_expert_gate:
-        moe["w_shared_expert_gate"] = w(next(keys), Lm, D, 1)
     params["moe_layers"] = moe
   else:
     params["layers"] = dense_stack(L)
@@ -342,6 +376,8 @@ def _mla_latents(x, p, cfg: ModelConfig, positions, inv_freq):
     if "wq_lora_a" in p:
       q = q + ((x @ p["wq_lora_a"]) @ p["wq_lora_b"]) * 2.0
   q = q.reshape(B, S, H, nope + rope)
+  if "q_norm" in p:  # bailing_hybrid's use_qk_norm: each head's nope+rope channels normed before rope (cfg.mla_q_norm)
+    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
   q_nope, q_pe = q[..., :nope], q[..., nope:]
 
   kv_a = _mm(x, p, "wkv_a", cfg.quant_compute)  # [B, S, kv_lora_rank + rope]
@@ -501,6 +537,7 @@ def _mlp_block(h, p, cfg: ModelConfig):
       n_group=cfg.n_group,
       topk_group=cfg.topk_group,
       group_mode=cfg.group_mode,
+      **({"held": cfg.experts_held} if cfg.experts_held else {}),
     )
     if "w_shared_gate" in p:
       with jax.named_scope("xot.moe_shared"):
@@ -558,10 +595,24 @@ def _ssm_conv(xbc, conv0, p):
   Returns (activated [B,S,C], the padded input [B, K-1+S, C])."""
   K, S = p["conv_w"].shape[0], xbc.shape[1]
   xp = jnp.concatenate([conv0.astype(xbc.dtype), xbc], axis=1)
-  acc = p["conv_b"].astype(jnp.float32)
+  acc = p["conv_b"].astype(jnp.float32) if "conv_b" in p else 0.0  # (a "kda" layer's convolution has no bias)
   for j in range(K):
     acc = acc + xp[:, j : j + S].astype(jnp.float32) * p["conv_w"][j].astype(jnp.float32)
   return jax.nn.silu(acc).astype(xbc.dtype), xp
+
+
+def _conv_tail(xp, S: int, seq_lens):
+  """The rows the convolution still needs after each row's ``seq_lens`` tokens (None: all S) of its padded input
+  ``xp`` [B, K-1+S, C]: the K-1 before position ``seq_lens``, so padding is cut from the tail."""
+  if seq_lens is None:
+    return xp[:, S:]
+  return jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, xp.shape[1] - S, axis=0))(xp, seq_lens)
+
+
+def _step_conv(pool: Params, xp, conv0, layer, active) -> Params:
+  """``pool`` with the ``conv`` leaf of recurrent layer ``layer`` moved on by the one token of ``xp`` [B, K, C] for the
+  ``active`` rows; the others keep ``conv0`` bit for bit."""
+  return {**pool, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], jnp.where(active[:, None, None], xp[:, 1:].astype(conv0.dtype), conv0), layer, 0)}
 
 
 def _ssm_split(xbc, cfg: ModelConfig):
@@ -625,11 +676,9 @@ def _ssm_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
     xbc, xp = _ssm_conv(xbc, conv0, p)
     x, bm, cm = _ssm_split(xbc, cfg)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-    if seq_lens is None:
-      conv = xp[:, S:]
-    else:
+    if seq_lens is not None:
       dt = jnp.where((jnp.arange(S, dtype=jnp.int32)[None, :] < seq_lens[:, None])[..., None], dt, 0.0)
-      conv = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, xp.shape[1] - S, axis=0))(xp, seq_lens)
+    conv = _conv_tail(xp, S, seq_lens)
     y, ssm = _ssm_chunk_scan(x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), bm, cm, ssm0, cfg.ssm_chunk)
     y = _ssm_gate(y, x.astype(jnp.float32), z, p, cfg)
   h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
@@ -654,10 +703,144 @@ def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bo
     dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"])  # [B,H]
     a = jnp.exp(dt * -jnp.exp(p["A_log"].astype(jnp.float32)))
     ssm, y = ssm_state_step(pool["ssm"], layer, a, dt[:, :, None] * x, bm.astype(jnp.float32), cm.astype(jnp.float32), active, use_kernel)
-    pool = {**pool, "ssm": ssm, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], jnp.where(active[:, None, None], xp[:, 1:].astype(conv0.dtype), conv0), layer, 0)}
+    pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
     y = _ssm_gate(y[:, None], x[:, None], z, p, cfg)
   h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
   return h, pool
+
+
+# ------------------------------------------- Kimi Delta Attention (KDA) mixer
+# (bailing_hybrid's "kda" layers; Kimi Linear, arXiv:2510.26692.) Per head,
+# with N key and P value channels:
+#   [q~ | k~ | v~] = silu(conv(u W_qkv)) (a causal depthwise convolution of
+#   ``ssm_conv`` taps);  q = l2norm(q~)/sqrt(N), k = l2norm(k~), v = v~;
+#   g = kda_lower_bound * sigmoid(u W_f + b_f) in (kda_lower_bound, 0), the log
+#   decay of each key channel, alpha = exp(g);  beta = sigmoid(u W_beta);
+#   S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T,  o_t = S_t^T q_t;
+#   out = (sigmoid(u W_g) [one a head] * rmsnorm_head(o_t)) W_out.
+# The state rides the pool's ``ssm`` leaf as [H, P, N] (values x key channels:
+# S^T, so the decay lies along the lanes as Mamba-2's does) in float32, the last
+# ``ssm_conv - 1`` rows of the pre-convolution [q|k|v] its ``conv`` leaf. Decode
+# is one delta-rule step (``ops/ssm.py kda_state_step``); prefill scans a prompt
+# in chunks of ``ssm_chunk`` positions (``_kda_chunk_scan``). Gates, decays,
+# norms, the state and every product with it are float32.
+
+
+@component_scope("xot.ssm_proj")
+def _kda_in(h, p, cfg: ModelConfig):
+  """Norm and the input projections: h [B,S,D] → qkv [B,S,H(2N+P)], f [B,S,HN] (the decay gate), bg [B,S,2H] (β | output gate)."""
+  u = rms_norm(h, p["ssm_norm"], cfg.norm_eps)
+  return tuple(_mm(u, p, name, cfg.quant_compute) for name in ("w_qkv", "w_f", "w_bg"))
+
+
+_L2_EPS = 1e-6
+
+
+def _kda_gates(qkv, f, bg, p, cfg: ModelConfig):
+  """Activated [q|k|v] [..., H(2N+P)], gate pre-activations f [..., HN], bg [..., 2H] → float32 q, k [..., H, N]
+  (unit norm; q scaled by 1/sqrt(N)), v [..., H, P], g [..., H, N] the log decay, beta and the output gate [..., H]."""
+  H, N, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
+  lead = qkv.shape[:-1]
+  qkv = qkv.astype(jnp.float32)
+  q, k, v = qkv[..., : H * N].reshape(*lead, H, N), qkv[..., H * N : 2 * H * N].reshape(*lead, H, N), qkv[..., 2 * H * N :].reshape(*lead, H, P)
+  unit = lambda t: t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + _L2_EPS)  # noqa: E731
+  g = cfg.kda_lower_bound * jax.nn.sigmoid(f.astype(jnp.float32) + p["b_f"].astype(jnp.float32)).reshape(*lead, H, N)
+  bg = jax.nn.sigmoid(bg.astype(jnp.float32))
+  return unit(q) * N**-0.5, unit(k), v, g, bg[..., :H], bg[..., H:]
+
+
+def _kda_out(y, gate, p, cfg: ModelConfig, dtype):
+  """The per-head norm of o [..., H, P] f32, the head-wise sigmoid gate [..., H], heads joined: [..., H*P] in ``dtype``."""
+  y = rms_norm(y, p["o_norm"], cfg.norm_eps) * gate[..., None]
+  return y.reshape(*y.shape[:-2], -1).astype(dtype)
+
+
+def _kda_chunk_scan(q, k, v, g, beta, state, chunk: int):
+  """The delta rule over a sequence, ``chunk`` positions at a time.
+
+  q, k, g [B,S,H,N], v [B,S,H,P], beta [B,S,H], state [B,H,P,N], all float32;
+  g = 0 and beta = 0 at a padded position (the state passes it unchanged).
+  Returns (o [B,S,H,P], state after position S-1).
+
+  Inside a chunk, with G_t the running sum of g from the chunk's start and
+  S_0 the state there, the updates w_t = beta_t (v_t - S_{t-1} Diag(alpha_t) k_t)
+  solve the unit lower-triangular system
+    (I + Diag(beta) A) W = Diag(beta) (V - (K * e^G) S_0^T),   A[t,s] = sum_n k_t k_s e^(G_t - G_s)  (s < t),
+  and o_t = S_0 (q_t * e^(G_t)) + sum_(s<=t) W_s (q_t . k_s e^(G_t - G_s)). The pairwise
+  decays are factorised, e^(G_t) x e^(-G_s): |G| <= |kda_lower_bound| x chunk,
+  which ``cfg.ssm_chunk`` holds under 80, so e^(-G_s) stays inside float32
+  (at 17 positions of -5 it would not). The triangular inverse is the finite
+  product (I - N)(I + N^2)(I + N^4)... of the nilpotent N = Diag(beta) A: matrix
+  products only. Products are float32 at ``highest`` precision: the state a
+  prompt leaves is what hundreds of decode steps start from."""
+  B, S, H, _ = q.shape
+  L = min(chunk, S)
+  pad = -S % L
+  if pad:
+    q, k, v, g, beta = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)) for t in (q, k, v, g, beta))
+  chunks = lambda t: jnp.moveaxis(t.reshape(B, -1, L, *t.shape[2:]), 1, 0)  # noqa: E731 — [c, B, L, ...]
+  lower, eye = jnp.tril(jnp.ones((L, L), bool), -1), jnp.eye(L, dtype=jnp.float32)
+  mm = partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+  def body(state, per_chunk):
+    qc, kc, vc, gc, bc = per_chunk
+    G = jnp.cumsum(gc, axis=1)  # [B,L,H,N], falling from 0
+    grow, k_in = jnp.exp(G), kc * jnp.exp(-G)
+    k_out, q_out = kc * grow, qc * grow
+    A = jnp.where(lower, mm("blhn,bshn->bhls", k_out, k_in), 0.0) * jnp.moveaxis(bc, 1, 2)[..., None]  # N = Diag(beta) A
+    inv, power = eye - A, A
+    for _ in range(max(L - 1, 1).bit_length() - 1):
+      power = mm("bhls,bhst->bhlt", power, power)
+      inv = mm("bhls,bhst->bhlt", inv, eye + power)
+    w = mm("bhls,bshp->blhp", inv, bc[..., None] * (vc - mm("blhn,bhpn->blhp", k_out, state)))
+    o = mm("blhn,bhpn->blhp", q_out, state) + mm("bhls,bshp->blhp", jnp.where(lower | (eye > 0), mm("blhn,bshn->bhls", q_out, k_in), 0.0), w)
+    to_end = jnp.exp(G[:, -1:] - G)  # [B,L,H,N], at most 1
+    return state * grow[:, -1][:, :, None, :] + mm("bshp,bshn->bhpn", w, kc * to_end), o
+
+  state, o = jax.lax.scan(body, state, tuple(chunks(t) for t in (q, k, v, g, beta)))
+  return jnp.moveaxis(o, 0, 1).reshape(B, S + pad, H, -1)[:, :S], state
+
+
+def _kda_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
+  """One KDA layer over a sequence, as ``_ssm_layer``: h [B,S,D], the rows' states ssm0 [B,H,P,N] f32 and conv0
+  [B,K-1,C] → (h, ssm, conv) after each row's ``seq_lens`` tokens. At a padded position the log decay and beta are
+  0 and the convolution's tail is cut at the length, so padding moves neither leaf."""
+  S = h.shape[1]
+  qkv, f, bg = _kda_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    qkv, xp = _ssm_conv(qkv, conv0, p)
+    q, k, v, g, beta, gate = _kda_gates(qkv, f, bg, p, cfg)
+    if seq_lens is not None:
+      valid = jnp.arange(S, dtype=jnp.int32)[None, :] < seq_lens[:, None]
+      g, beta = jnp.where(valid[..., None, None], g, 0.0), jnp.where(valid[..., None], beta, 0.0)
+    conv = _conv_tail(xp, S, seq_lens)
+    y, ssm = _kda_chunk_scan(q, k, v, g, beta, ssm0, cfg.ssm_chunk)
+    y = _kda_out(y, gate, p, cfg, h.dtype)
+  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, ssm, conv.astype(conv0.dtype)
+
+
+def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
+  """One delta-rule step of one KDA layer for every slot row, as ``_ssm_decode_step``: the leaves ``ssm`` and ``conv``
+  of ``pool`` are read and written in place at ``layer``; a row that is not ``active`` keeps both bit for bit."""
+  from ..ops.ssm import kda_state_step
+
+  qkv, f, bg = _kda_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
+    qkv, xp = _ssm_conv(qkv, conv0, p)
+    q, k, v, g, beta, gate = _kda_gates(qkv[:, 0], f[:, 0], bg[:, 0], p, cfg)
+    ssm, y = kda_state_step(pool["ssm"], layer, jnp.exp(g), beta, k, v, q, active)
+    pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
+    y = _kda_out(y[:, None], gate[:, None], p, cfg, h.dtype)
+  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, pool
+
+
+# A hybrid's latent-attention layers take a prefill's queries this many positions at a time (ops/attention.py
+# mla_absorbed_attention ``q_block``): its pool is donated with per-slot state beside the weights, and the float32 scores
+# of a whole group against the gathered window do not fit there (AOT, tests/test_tpu_compile.py; PERF.md §6, PR 36).
+_HYBRID_MLA_Q_BLOCK = 256
 
 
 def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params, slot_rows=None, fresh=None, seq_lens=None, adapter_ids=None):
@@ -680,18 +863,19 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
   kv_positions = jnp.arange(carry[pages[0]].shape[2], dtype=jnp.int32) if pages else positions[0]
 
   def step(h, carry, lp, layer):
-    if "w_xbc" not in lp:
+    if "ssm_norm" not in lp:  # an attention layer (dense or latent): ``layer`` counts the pool's page layers
       kv = {name: jax.lax.dynamic_index_in_dim(carry[name], layer, 0, keepdims=False) for name in pages} or None
-      h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, bool(pages), adapter_ids=adapter_ids)
+      h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, bool(pages), adapter_ids=adapter_ids, mla_q_block=_HYBRID_MLA_Q_BLOCK)
       return h, {**carry, **{name: jax.lax.dynamic_update_index_in_dim(carry[name], kv[name], layer, 0) for name in pages}}
+    over_sequence = _kda_layer if "w_f" in lp else _ssm_layer
     if "ssm" not in carry:
       ssm0 = jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
-      h, _, _ = _ssm_layer(h, lp, cfg, ssm0, jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), h.dtype), seq_lens)
+      h, _, _ = over_sequence(h, lp, cfg, ssm0, jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), h.dtype), seq_lens)
       return h, carry
     with jax.named_scope("xot.ssm"):
       ssm0 = jnp.where(fresh[:, None, None, None], 0.0, carry["ssm"].at[layer, slot_rows].get(mode="clip")).astype(jnp.float32)
       conv0 = jnp.where(fresh[:, None, None], 0, carry["conv"].at[layer, slot_rows].get(mode="clip"))
-    h, ssm, conv = _ssm_layer(h, lp, cfg, ssm0, conv0, seq_lens)
+    h, ssm, conv = over_sequence(h, lp, cfg, ssm0, conv0, seq_lens)
     with jax.named_scope("xot.ssm"):
       carry = {**carry, "ssm": carry["ssm"].at[layer, slot_rows].set(ssm.astype(carry["ssm"].dtype), mode="drop"), "conv": carry["conv"].at[layer, slot_rows].set(conv, mode="drop")}
     return h, carry
@@ -699,7 +883,7 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
   return _scan_layers_over_pool(step, h, _layer_runs(params, cfg), carry)
 
 
-def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: ModelConfig, use_cache: bool, attn_fn=None, adapter_ids=None):
+def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: ModelConfig, use_cache: bool, attn_fn=None, adapter_ids=None, mla_q_block: int = 0):
   """One decoder layer. h [B,S,D] → (h, new_kv, aux).
 
   ``kv`` is this layer's cache dict ({"k", "v"} [+ "k_scale"/"v_scale" when
@@ -739,6 +923,7 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
       positions,
       kv_positions,
       cfg.v_head_dim,
+      mla_q_block,
     )
   else:
     if "wkv_a" in p:  # MLA, cache-less (training): naive per-head K/V
@@ -877,7 +1062,7 @@ def shard_forward(
   # lax.scan; MoE models with no dense prefix simply have no "layers" key.
   stacks = _layer_stacks(params)
 
-  if "ssm_layers" in params:  # a hybrid, cache-less; with a cache its forward is the paged prefill (prefill_into_pages_many)
+  if cfg.recurrent_layers:  # a hybrid, cache-less; with a cache its forward is the paged prefill (prefill_into_pages_many)
     if use_cache:
       raise ValueError("a hybrid has no slot-cache forward: shard_forward runs it cache-less")
     h, new_cache = _hybrid_layers(h, params, cfg, positions, {}, adapter_ids=adapter_ids)[0], None
@@ -1468,12 +1653,14 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params):
   model of two (dense prefix + experts) indexes the one pool from both, with
   no split and no join.
 
-  A hybrid's entry is a run ``(stack, lo, hi)`` (``_layer_runs``): layers
-  [lo, hi) of a stack whose kind owns leaves of the pool of its own (K/V pages
-  for attention layers, per-slot state for state-space layers), so ``layer``
-  is the layer's index in its stack, and the layer's parameters are read at
-  that index inside the loop, as a scan reads its ``xs``: a slice of the
-  stack cut out beforehand would be a copy of the run's weights."""
+  A hybrid's entry is a run ``(stack, lo, hi, pool_lo)`` (``_layer_runs``):
+  layers [lo, hi) of a stack, whose mixer's kind owns leaves of the pool of
+  its own (pages for attention layers, per-slot state for recurrent layers) and
+  counts its layers there from ``pool_lo`` on, so ``layer`` is the layer's
+  index among the pool's layers of its kind, and the layer's parameters are
+  read at their index in the stack inside the loop, as a scan reads its
+  ``xs``: a slice of the stack cut out beforehand would be a copy of the run's
+  weights."""
 
   def body(carry, per_layer):
     lp, layer = per_layer
@@ -1482,11 +1669,11 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params):
   first = 0
   for stack in stacks:
     if isinstance(stack, tuple):
-      stack, lo, hi = stack
+      stack, lo, hi, pool_lo = stack
 
-      def run_body(carry, layer, stack=stack):
-        lp = {name: jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False) for name, leaf in stack.items()}
-        return step(*carry, lp, layer), None
+      def run_body(carry, at, stack=stack, shift=pool_lo - lo):
+        lp = {name: jax.lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) for name, leaf in stack.items()}
+        return step(*carry, lp, at + shift if shift else at), None
 
       (h, pool), _ = jax.lax.scan(run_body, (h, pool), jnp.arange(lo, hi, dtype=jnp.int32))
       continue
@@ -1502,20 +1689,26 @@ def _layer_stacks(params: Params) -> list:
 
 
 def _layer_runs(params: Params, cfg: ModelConfig) -> list:
-  """A hybrid's layers in the published order, as runs ``(stack, lo, hi)`` of
-  one kind for ``_scan_layers_over_pool``: granite-4.0-h-micro is state-space
-  runs of 5, 9, 9, 9 and 4 with an attention layer after each of the first
-  four. Any other model: its stacks, whole."""
-  if "ssm_layers" not in params:
+  """A hybrid's layers in the published order, as runs ``(stack, lo, hi, pool_lo)``
+  for ``_scan_layers_over_pool``: consecutive layers of one (mixer, FFN) pairing
+  are layers [lo, hi) of that pairing's stack (``cfg.layer_stack``) and layers
+  ``pool_lo`` on of the pool's leaves of their mixer's kind. granite-4.0-h-micro
+  is state-space runs of 5, 9, 9, 9 and 4 with an attention layer after each of
+  the first four; Ling-3.0-flash's first stage a KDA layer with a dense FFN, four
+  with experts, a latent-attention layer with experts, a KDA layer with experts.
+  Any other model: its stacks, whole."""
+  if not cfg.recurrent_layers:
     return _layer_stacks(params)
-  runs, done = [], {"mamba": 0, "attention": 0}
-  for i, kind in enumerate(cfg.layer_types):
-    if i and cfg.layer_types[i - 1] == kind:
+  runs, in_stack, in_pool = [], {}, {True: 0, False: 0}
+  for i in range(cfg.n_layers):
+    name, recurrent = cfg.layer_stack(i), cfg.layer_types[i] != "attention"
+    at = in_stack.get(name, 0)
+    if runs and runs[-1][0] == name:
       runs[-1][2] += 1
     else:
-      runs.append([params["ssm_layers" if kind == "mamba" else "layers"], done[kind], done[kind] + 1])
-    done[kind] += 1
-  return [tuple(r) for r in runs]
+      runs.append([name, at, at + 1, in_pool[recurrent]])
+    in_stack[name], in_pool[recurrent] = at + 1, in_pool[recurrent] + 1
+  return [(params[name], lo, hi, pool_lo) for name, lo, hi, pool_lo in runs]
 
 
 def _write_kv(pool: Params, k, v, layer, block_tables, pos, page_size: int, kv_quant: str, kernel: bool = False, interpret: bool = False) -> Params:
@@ -1598,6 +1791,8 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
   def step(h, pool, lp, layer):
     if "w_xbc" in lp:
       return _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
+    if "w_f" in lp:
+      return _kda_decode_step(h, pool, lp, layer, active, cfg)
     return _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
 
   h, pool = _scan_layers_over_pool(step, h, _layer_runs(params, cfg), pool)
@@ -2139,7 +2334,7 @@ def score_last_tokens(params, cfg: ModelConfig, shard: Shard, tokens, seq_len, n
     h, _, aux = _layer_step(h, lp, None, positions, positions[0], inv_freq, cfg, False)
     return (h, _aux + aux), None
 
-  if "ssm_layers" in params:  # a hybrid: the same mixers from a zero state, in the published order
+  if cfg.recurrent_layers:  # a hybrid: the same mixers from a zero state, in the published order
     h, _ = _hybrid_layers(h, params, cfg, positions, {})
   else:
     for stack in _layer_stacks(params):

@@ -102,6 +102,7 @@ def mla_absorbed_attention(
   q_positions: jnp.ndarray,  # [B, Sq]
   kv_positions: jnp.ndarray,  # [Skv]
   v_dim: int,
+  q_block: int = 0,
 ) -> jnp.ndarray:
   """MLA attention against the *latent* cache (weight absorption).
 
@@ -116,8 +117,19 @@ def mla_absorbed_attention(
   Decode is HBM-bound on the cache read, so shrinking cached bytes is the
   long-context lever (SURVEY.md §5.7 is greenfield in the reference).
   Returns [B, Sq, H, v_dim] in q_nope.dtype.
+
+  ``q_block`` > 0 takes the queries ``q_block`` positions at a time (each
+  block's softmax is whole: it is exact), so the float32 scores that exist
+  at once are [B, H, q_block, Skv] and not [B, H, Sq, Skv] — 4 GB twice over
+  for a prefill group of 8 rows x 1024 queries x 32 heads against a window of
+  4096, which a pool with state leaves beside 10 GB of weights has no room for.
   """
   B, Sq, H, nope = q_nope.shape
+  if q_block and Sq > q_block:
+    pad = -Sq % q_block
+    blocks = lambda t: jnp.moveaxis(jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)).reshape(B, -1, q_block, *t.shape[2:]), 1, 0)  # noqa: E731
+    out = jax.lax.map(lambda t: mla_absorbed_attention(t[0], t[1], ckv, kpe, w_kv_b, t[2], kv_positions, v_dim), (blocks(q_nope), blocks(q_pe), blocks(q_positions)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, Sq + pad, H, v_dim)[:, :Sq]
   rank = ckv.shape[-1]
   rope = q_pe.shape[-1]
   W = w_kv_b.reshape(rank, H, nope + v_dim)

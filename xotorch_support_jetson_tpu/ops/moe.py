@@ -101,15 +101,22 @@ def dispatch_combine_masks(idx: jnp.ndarray, weights: jnp.ndarray, n_experts: in
   return dispatch, combine
 
 
-def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode):
+def _held_index(idx, held):
+  """Routed expert ids → ids into the expert leaves of a shard that holds experts ``held`` = [lo, hi) of the router's
+  (None: all of them, ids as they are). A choice outside the range gives an id outside the leaves': its one-hot is all
+  zeros, so it is neither dispatched nor combined, and nothing stands in for what the absent expert would have added."""
+  return idx if held is None else idx - held[0]
+
+
+def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held=None):
   """One dispatch/compute/combine block over [T, D] tokens. Returns (out, aux)."""
   T, D = x.shape
-  E = w_gate.shape[0]
+  E, E_held = w_router.shape[-1], w_gate.shape[0]
   with jax.named_scope("xot.moe_router"):
     logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
     weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
     C = expert_capacity(T, k, E, capacity_factor)
-    dispatch, combine = dispatch_combine_masks(idx, weights, E, C)
+    dispatch, combine = dispatch_combine_masks(_held_index(idx, held), weights, E_held, C)
 
   with jax.named_scope("xot.moe_experts"):
     xin = jnp.einsum("td,tec->ecd", x, dispatch.astype(x.dtype))  # [E, C, D]
@@ -136,7 +143,7 @@ from ..utils.helpers import env_flag as _env_flag
 MOE_GATHER_MAX = 32 if _env_flag("XOT_TPU_MOE_GATHER") else 0
 
 
-def _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode):
+def _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None):
   """Decode-path MoE: gather the k active experts' weights per token.
 
   [T, D] tokens with T small; reads only the routed experts' slabs (XLA
@@ -144,12 +151,15 @@ def _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, se
   [E, D, F] stream). Same routing as the einsum path, no capacity concept.
   """
   T, D = x.shape
-  E = w_gate.shape[0]
+  E = w_router.shape[-1]
   with jax.named_scope("xot.moe_router"):
     logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
     weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
   with jax.named_scope("xot.moe_experts"):
     flat = idx.reshape(-1)  # [T·k]
+    if held is not None:  # a choice this shard does not hold weighs nothing; its (clipped) gather is never combined
+      weights = jnp.where((idx >= held[0]) & (idx < held[1]), weights, 0.0)
+      flat = jnp.clip(_held_index(flat, held), 0, w_gate.shape[0] - 1)
     g = jnp.take(w_gate, flat, axis=0).reshape(T, k, D, -1)
     u = jnp.take(w_up, flat, axis=0).reshape(T, k, D, -1)
     d = jnp.take(w_down, flat, axis=0).reshape(T, k, -1, D)
@@ -179,9 +189,18 @@ def moe_ffn(
   n_group: int = 1,
   topk_group: int = 1,
   group_mode: str = "none",
+  held: tuple[int, int] | None = None,
 ):
   """Routed SwiGLU FFN over ``E`` experts; returns [T, D] in x.dtype
   (or ``(out, aux_loss)`` with ``return_aux``).
+
+  ``held`` = (lo, hi): this shard's share of an expert-parallel layer. The
+  router (``w_router`` [D, E], its bias, the groups, the top-k and the
+  weights' normalisation over all k chosen) stays ``E`` wide; ``w_gate`` /
+  ``w_up`` / ``w_down`` hold experts [lo, hi) only, and the result is their
+  part of the layer's sum: what the absent experts would have added is left
+  out (the shares of all the shards add up to the whole layer).
+
 
   Small token runs (decode steps; T ≤ MOE_GATHER_MAX with the exact
   ``capacity_factor=None``) take the weight-gather path — HBM reads scale
@@ -194,10 +213,10 @@ def moe_ffn(
   T, D = x.shape
 
   def block(xs):
-    return _moe_ffn_block(xs, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode)
+    return _moe_ffn_block(xs, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held)
 
   if T <= MOE_GATHER_MAX and capacity_factor is None:
-    out, aux = _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
+    out, aux = _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held)
   elif T <= chunk:
     out, aux = block(x)
   else:

@@ -1,4 +1,4 @@
-"""The decode step of a state-space layer's recurrent state: one owner.
+"""The decode step of a recurrent layer's per-slot state: one owner, two update rules.
 
 A hybrid's page pool carries, beside its K/V pages, the leaf ``ssm``
 [Ls, slots, H, P, N] in float32 (``ops/paged.py init_paged_pool``): each slot
@@ -30,6 +30,19 @@ stay with the caller (``models/decoder.py _ssm_decode_step``). Which form a
 program takes is read from what it can observe — the ``use_kernel`` its
 dispatch resolved (``paged_kernel_supported``: a TPU) and the leaf's shape
 and dtype (``one_pass_supported``) — and set nowhere.
+
+The second rule is the **delta rule** of a Kimi-Delta-Attention layer
+(``kda_state_step``), over the same leaf with P the value channels and N the
+key channels of a head's matrix state:
+
+  S ← S · Diag(α)       u = β (v − S k)       S ← S + u ⊗ k       y = S q
+
+with α [B, H, N] the decay of each key channel, β [B, H], k and q [B, H, N],
+v [B, H, P]. It has the reference expression only: one pass reads the state
+for the two contractions it needs of the decayed state (S·Diag(α) with k and
+with q; y = (S·Diag(α)) q + u (k·q)), a second reads it again and writes the
+update — two reads and a write where the least is one of each. A one-pass
+Mosaic form is ROADMAP A's, with the trace's number.
 """
 
 from __future__ import annotations
@@ -58,11 +71,14 @@ def one_pass_supported(ssm_leaf, use_kernel: bool) -> bool:
   return N % LANES == 0 and P % SUBLANES == 0 and _head_block(H, P, N) is not None
 
 
-STATE_STEP_FORMS = ("one_pass", "reference")
+STATE_STEP_FORMS = ("one_pass", "reference", "delta_reference")
 
 
-def state_step_form(ssm_leaf, use_kernel: bool) -> str:
-  """The name of the form ``ssm_state_step`` takes: the label of the gauge ``recurrent_state_step``."""
+def state_step_form(ssm_leaf, use_kernel: bool, kind: str = "mamba") -> str:
+  """The name of the rule and form a decode program of ``kind`` layers ("mamba" | "kda") steps this leaf in: the label
+  of the gauge ``recurrent_state_step``."""
+  if kind == "kda":
+    return "delta_reference"
   return "one_pass" if one_pass_supported(ssm_leaf, use_kernel) else "reference"
 
 
@@ -83,6 +99,24 @@ def _state_step_reference(ssm_leaf, layer, a, dtx, bm, cm, active):
   ssm = a[:, :, None, None] * ssm0 + dtx[..., None] * bm[:, None, None, :]
   y = jnp.einsum("bhpn,bn->bhp", ssm, cm)
   return jax.lax.dynamic_update_index_in_dim(ssm_leaf, jnp.where(active[:, None, None, None], ssm, ssm0).astype(ssm_leaf.dtype), layer, 0), y
+
+
+def kda_state_step(ssm_leaf, layer, alpha, beta, k, v, q, active):
+  """One delta-rule step of Kimi-Delta-Attention layer ``layer`` for every slot row.
+
+  ssm_leaf [Ls, B, H, P, N] float32, stepped in place at ``layer`` (a traced scalar); alpha [B, H, N] the decay of
+  each key channel; beta [B, H]; k, q [B, H, N]; v [B, H, P]; active [B] bool — all float32. Returns (ssm_leaf, y
+  [B, H, P] float32). A row that is not ``active`` keeps its state bit for bit."""
+  s0 = jax.lax.dynamic_index_in_dim(ssm_leaf, layer, 0, keepdims=False).astype(jnp.float32)
+  # Both contractions of the decayed state are sibling sums over one read of it (multiply-and-sum, so float32 on
+  # the vector unit whatever the matrix unit's default precision); then y = S_new q = (S·Diag(α)) q + u (k·q), so
+  # the updated state is written and never read back.
+  sk = jnp.sum(s0 * (alpha * k)[:, :, None, :], axis=-1)
+  sq = jnp.sum(s0 * (alpha * q)[:, :, None, :], axis=-1)
+  u = beta[..., None] * (v - sk)
+  y = sq + u * jnp.sum(k * q, axis=-1, keepdims=True)
+  new = s0 * alpha[:, :, None, :] + u[..., None] * k[:, :, None, :]
+  return jax.lax.dynamic_update_index_in_dim(ssm_leaf, jnp.where(active[:, None, None, None], new, s0).astype(ssm_leaf.dtype), layer, 0), y
 
 
 # ------------------------------------------------------- the one-pass kernel
