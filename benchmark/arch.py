@@ -29,7 +29,13 @@ A kind's file exports:
                                      where the kind's cache has one type whatever that key says
 
 A file that lacks a part is refused here, by the name of the part, before any
-weight is made."""
+weight is made. Parts a reader or a tool asks for only where the kind has them
+(``getattr``; no table): ``ssm_state_bytes(hf, rows)`` and ``moe_expert_bytes(hf,
+rows)`` for the two rooflines of those names; for a kind with routed experts
+``routed_experts(hf) -> (first, counted, routed, top_k)``, the experts a step's
+bytes count, and ``router_tables(hf, key)``, its topic router's tables as
+``make_params`` draws them (``tools/experts_touched.py``); ``reference_forward``
+of such a kind also takes ``routed=[]`` and fills it with the router's choices."""
 
 from __future__ import annotations
 

@@ -20,7 +20,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from flops_bytes import expected_distinct_experts
+from flops_bytes import experts_touched
 from reference import F32, causal_attention, rms_norm, rope_adjacent, rope_angles, swiglu
 from weights import ACT, normal
 
@@ -108,35 +108,49 @@ def _router(hf: dict, z: dict, key, n: int, topics):
   """[n, D, E] bfloat16: an N(0, 1/D) part plus, for each of ``router_topics`` topics, ``router_topic_gain`` / D times
   the topic's direction on the columns of the topic's own k experts of that layer: two in each of ``topk_group``
   groups drawn from the ``n_group``, so that the group limit keeps all of them and the k-th choice stands clear of
-  the (k+1)-th (the configuration file's ``assumed.router_topics``)."""
+  the (k+1)-th (the configuration file's ``assumed.router_topics``). Beside it ``owns`` [n, T, E], 1 where the topic
+  owns the expert (None without topics)."""
   D, E, k, G, Gk = z["D"], z["E"], z["k"], int(hf["n_group"]), int(hf["topk_group"])
   k_w, k_g, k_e = jax.random.split(key, 3)
   w = normal(k_w, (n, D, E), D**-0.5)
   if topics is None:
-    return w.astype(ACT)
+    return w.astype(ACT), None
   T, per = topics.shape[0], k // Gk
   in_group = jax.lax.top_k(jax.random.uniform(k_g, (n, T, G)), Gk)[1]  # [n, T, Gk] the topic's groups
   in_expert = jax.lax.top_k(jax.random.uniform(k_e, (n, T, Gk, E // G)), per)[1]  # [n, T, Gk, per] its experts inside each
   owns = jax.nn.one_hot((in_group[..., None] * (E // G) + in_expert).reshape(n, T, k), E, dtype=F32).sum(axis=2)  # [n, T, E], k ones a row
-  return (w + (float(hf["router_topic_gain"]) / D) * jnp.einsum("td,lte->lde", topics, owns)).astype(ACT)
+  return (w + (float(hf["router_topic_gain"]) / D) * jnp.einsum("td,lte->lde", topics, owns)).astype(ACT), owns
 
 
-def _ffn_leaves(hf: dict, z: dict, keys, n: int, experts: bool, topics) -> dict:
+def _ffn_leaves(hf: dict, z: dict, keys, n: int, experts: bool, topics) -> tuple[dict, object]:
+  """The stack's FFN leaves and, for an expert stack under a topic router, which experts each topic owns."""
   D = z["D"]
   if not experts:
-    return {name: _stack(next(keys), n, shape, shape[0] ** -0.5) for name, shape in (("w_gate", (D, z["F"])), ("w_up", (D, z["F"])), ("w_down", (z["F"], D)))}
-  out = {"w_router": _router(hf, z, next(keys), n, topics), "router_bias": jnp.zeros((n, z["E"]), F32)}
+    return {name: _stack(next(keys), n, shape, shape[0] ** -0.5) for name, shape in (("w_gate", (D, z["F"])), ("w_up", (D, z["F"])), ("w_down", (z["F"], D)))}, None
+  w_router, owns = _router(hf, z, next(keys), n, topics)
+  out = {"w_router": w_router, "router_bias": jnp.zeros((n, z["E"]), F32)}
   for name, shape in (("w_experts_gate", (z["Eh"], D, z["Fm"])), ("w_experts_up", (z["Eh"], D, z["Fm"])), ("w_experts_down", (z["Eh"], z["Fm"], D))):
     out[name] = _stack(next(keys), n, shape, shape[1] ** -0.5)
   for name, shape in (("w_shared_gate", (D, z["Fs"])), ("w_shared_up", (D, z["Fs"])), ("w_shared_down", (z["Fs"], D))):
     out[name] = _stack(next(keys), n, shape, shape[0] ** -0.5)
-  return out
+  return out, owns
 
 
 def make_params(hf: dict, key) -> dict:
   """bfloat16 leaves under the program's names (``models/decoder.py init_shard_params``): one stack a (mixer, FFN)
   pairing, each in model order; the expert leaves hold the ``num_experts`` experts held, the router all
   ``num_experts_routed``; the gate's bias ``b_f`` and the router's selection bias float32."""
+  return _make(hf, key)[0]
+
+
+def router_tables(hf: dict, key) -> dict | None:
+  """What the topic router reads a token by, drawn as ``make_params`` draws it from the same key (the same function;
+  under ``jit`` the compiler drops the weights): ``topic_of`` [V], each token id's topic, and ``owns``
+  [expert layers in model order, T, E routed], 1 where the topic owns the expert. None where the file states no topics."""
+  return _make(hf, key)[1]
+
+
+def _make(hf: dict, key) -> tuple[dict, dict | None]:
   z = _sizes(hf)
   keys = iter(jax.random.split(key, 64))
   topics = topic_of = None
@@ -147,17 +161,19 @@ def make_params(hf: dict, key) -> dict:
   counts: dict = {}
   for name, _ in layer_stacks(hf):
     counts[name] = counts.get(name, 0) + 1
-  params: dict = {}
+  params, owns = {}, {}
   for name, n in counts.items():
     mixer = _kda_leaves(z, keys, n) if name.startswith("ssm_") else _mla_leaves(z, keys, n)
-    params[name] = {**mixer, **_ffn_leaves(hf, z, keys, n, name.endswith("moe_layers"), topics)}
+    ffn, owns[name] = _ffn_leaves(hf, z, keys, n, name.endswith("moe_layers"), topics)
+    params[name] = {**mixer, **ffn}
   embed = normal(next(keys), (z["V"], z["D"]), 1.0)
   if topics is not None:
     embed = embed + float(hf["embed_topic_gain"]) * topics[topic_of]
   params["embed"] = embed.astype(ACT)
   params["final_norm"] = jnp.ones((z["D"],), ACT)
   params["lm_head"] = normal(next(keys), (z["D"], z["V"]), z["D"] ** -0.5).astype(ACT)
-  return params
+  tables = None if topics is None else {"topic_of": topic_of, "owns": jnp.stack([owns[name][i] for name, i in layer_stacks(hf) if owns[name] is not None])}
+  return params, tables
 
 
 # -------------------------------------------------------------- reference
@@ -276,7 +292,9 @@ def _moe_ffn(h, mlp_norm, w_router, router_bias, eg, eu, ed, sg, su, sd, *, top_
 
 def reference_forward(params: dict, hf: dict, tokens, drop_layer: int | None = None, no_decay: bool = False, no_delta: bool = False, beta_one: bool = False,
                       no_gate: bool = False, held_shift: int = 0, group_limit: bool = True, drop_expert: bool = False, theta_scale: float = 1.0,
-                      state_dtype: str | None = None, decay_dtype: str | None = None, operands: str | None = None):
+                      state_dtype: str | None = None, decay_dtype: str | None = None, operands: str | None = None, routed: list | None = None):
+  """``routed``, no probe: a list that receives, for each expert layer in model order, [S, E routed] True where the
+  router chose the expert (``tools/experts_touched.py`` holds them against the topics' tables)."""
   z = _sizes(hf)
   eps = float(hf["rms_norm_eps"])
   f32 = lambda st, i, *names: tuple(st[n][i].astype(F32) for n in names)  # noqa: E731
@@ -296,10 +314,12 @@ def reference_forward(params: dict, hf: dict, tokens, drop_layer: int | None = N
         theta=float(hf["rope_theta"]) * theta_scale, operands=operands,
       )
     if "w_router" in st:
+      route = dict(top_k=z["k"], n_group=int(hf["n_group"]), topk_group=int(hf["topk_group"]), scaling=float(hf["routed_scaling_factor"]))
+      if routed is not None:
+        routed.append(router_gates(rms_norm(h, st["mlp_norm"][i], eps), st["w_router"][i], st["router_bias"][i], group_limit=group_limit, **route) > 0)
       h = _moe_ffn(
         h, st["mlp_norm"][i], st["w_router"][i], st["router_bias"][i], st["w_experts_gate"][i], st["w_experts_up"][i], st["w_experts_down"][i],
-        *f32(st, i, "w_shared_gate", "w_shared_up", "w_shared_down"), top_k=z["k"], n_group=int(hf["n_group"]), topk_group=int(hf["topk_group"]),
-        scaling=float(hf["routed_scaling_factor"]), eps=eps, lo=z["lo"] + held_shift, group_limit=group_limit, drop_expert=drop_expert, operands=operands,
+        *f32(st, i, "w_shared_gate", "w_shared_up", "w_shared_down"), **route, eps=eps, lo=z["lo"] + held_shift, group_limit=group_limit, drop_expert=drop_expert, operands=operands,
       )
     else:
       h = _dense_ffn(h, *f32(st, i, "mlp_norm", "w_gate", "w_up", "w_down"), eps=eps, operands=operands)
@@ -382,9 +402,18 @@ def weight_bytes(hf: dict, rows: float | None = None) -> float:
 
 
 def held_experts_touched(hf: dict, rows: float) -> float:
-  """Expected distinct HELD experts of one layer that ``rows`` tokens choose, under uniform routing over all E."""
+  """Expected distinct HELD experts of one layer that ``rows`` tokens choose, under the router the file states
+  (``flops_bytes.experts_touched``): with ``router_topics`` a topic owns a held expert with probability
+  ``topk_group / n_group`` (its group is one of the topic's) x ``k / topk_group`` of the group's ``E / n_group``
+  (``_router``), which is k / E; without, uniform routing over all E."""
+  return experts_touched(hf, *routed_experts(hf)[1:], rows)
+
+
+def routed_experts(hf: dict) -> tuple[int, int, int, int]:
+  """(first, counted, routed, top_k): a step's bytes count the ``counted`` experts held, from router column ``first``
+  on, of the ``routed`` that a token chooses ``top_k`` of."""
   z = _sizes(hf)
-  return expected_distinct_experts(z["E"], z["k"], rows) * z["Eh"] / z["E"]
+  return z["lo"], z["Eh"], z["E"], z["k"]
 
 
 def moe_expert_bytes(hf: dict, rows: float) -> float:

@@ -16,7 +16,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from flops_bytes import expected_distinct_experts, q_bytes
+from flops_bytes import experts_touched, q_bytes
 from reference import F32, causal_attention, deq, head, rms_norm, rope_adjacent, rope_angles, swiglu
 from weights import ACT, head_and_embed, normal, put_q
 
@@ -41,6 +41,17 @@ def _mla_leaves(hf: dict, keys, n_layers: int) -> dict:
 def make_params(hf: dict, key) -> dict:
   """``first_k_dense_replace`` dense layers, then routed experts + shared experts; the router and its
   selection bias stay f32-precise (bf16 router weights, f32 zero bias)."""
+  return _make(hf, key)[0]
+
+
+def router_tables(hf: dict, key) -> dict | None:
+  """What the topic router reads a token by, drawn as ``make_params`` draws it from the same key (the same function;
+  under ``jit`` the compiler drops the weights): ``topic_of`` [V], each token id's topic, and ``owns``
+  [expert layers in model order, T, E], 1 where the topic owns the expert. None where the file states no topics."""
+  return _make(hf, key)[1]
+
+
+def _make(hf: dict, key) -> tuple[dict, dict | None]:
   L, D, V = hf["num_hidden_layers"], hf["hidden_size"], hf["vocab_size"]
   n_dense = _n_dense(hf)
   Lm, E, Fm, F = L - n_dense, hf["n_routed_experts"], hf["moe_intermediate_size"], hf["intermediate_size"]
@@ -54,7 +65,7 @@ def make_params(hf: dict, key) -> dict:
     params["layers"] = dense
   moe = _mla_leaves(hf, keys, Lm)
   w_router = normal(next(keys), (Lm, D, E), 1.0 / D**0.5)
-  topics = topic_of = None
+  topics = topic_of = owns = None
   n_topics = int(hf.get("router_topics") or 0)
   if n_topics:
     # A router that reads the token (see the configuration file's ``assumed``):
@@ -79,7 +90,7 @@ def make_params(hf: dict, key) -> dict:
       put_q(moe, name, next(keys), Lm, shape)
   params["moe_layers"] = moe
   head_and_embed(params, keys, V, D, topic_of, topics, float(hf.get("embed_topic_gain", 0.0)))
-  return params
+  return params, (None if topics is None else {"topic_of": topic_of, "owns": owns})
 
 
 # -------------------------------------------------------------- reference
@@ -142,7 +153,9 @@ def _moe_ffn(h, mlp_norm, w_router, router_bias, eg, eg_s, eu, eu_s, ed, ed_s, s
   return h + routed + swiglu(x, sg, su, sd)
 
 
-def reference_forward(params: dict, hf: dict, tokens, drop_layer: int | None = None, theta_scale: float = 1.0, drop_expert: bool = False, swap_experts: bool = False):
+def reference_forward(params: dict, hf: dict, tokens, drop_layer: int | None = None, theta_scale: float = 1.0, drop_expert: bool = False, swap_experts: bool = False, routed: list | None = None):
+  """``routed``, no probe: a list that receives, for each expert layer in model order, [S, E] True where the router
+  chose the expert (``tools/experts_touched.py`` holds them against the topics' tables)."""
   n_dense = _n_dense(hf)
   eps = float(hf["rms_norm_eps"])
   mla = dict(
@@ -160,6 +173,9 @@ def reference_forward(params: dict, hf: dict, tokens, drop_layer: int | None = N
     else:
       ex = [a for n in ("w_experts_gate", "w_experts_up", "w_experts_down") for a in (st[n][i], st[f"{n}_scale"][i])]
       bias = st["router_bias"][i] if "router_bias" in st else jnp.zeros((hf["n_routed_experts"],), F32)
+      if routed is not None:
+        affinity = jax.nn.sigmoid(rms_norm(h, st["mlp_norm"][i], eps) @ st["w_router"][i].astype(F32)) + bias.astype(F32)
+        routed.append(jax.nn.one_hot(jax.lax.top_k(affinity, hf["num_experts_per_tok"])[1], affinity.shape[-1], dtype=bool).any(axis=1))  # ``_moe_ffn``'s own choice
       h = _moe_ffn(
         h, st["mlp_norm"][i], st["w_router"][i], bias, *ex, *(deq(st, n, i) for n in ("w_shared_gate", "w_shared_up", "w_shared_down")),
         top_k=hf["num_experts_per_tok"], norm_topk=bool(hf.get("norm_topk_prob", False)), scaling=float(hf.get("routed_scaling_factor", 1.0)), eps=eps,
@@ -205,16 +221,23 @@ def attn_weight_bytes(hf: dict) -> int:
 
 
 def weight_bytes(hf: dict, tokens: float, all_experts: bool = False) -> float:
-  """Weights a step of ``tokens`` rows touches: of the routed experts only the expected distinct ones."""
+  """Weights a step of ``tokens`` rows touches: of the routed experts only the expected distinct ones under the
+  router the file states (``flops_bytes.experts_touched``: a topic owns each of its layer's E experts with probability k / E)."""
   D, F, Fm, L, V, E = hf["hidden_size"], hf["intermediate_size"], hf["moe_intermediate_size"], hf["num_hidden_layers"], hf["vocab_size"], hf["n_routed_experts"]
   n_dense = _n_dense(hf)
   Fs = int(hf.get("n_shared_experts") or 0) * Fm
   attn = attn_weight_bytes(hf)
   dense_ffn = 2 * q_bytes(D, F) + q_bytes(F, D)
   expert = 2 * q_bytes(D, Fm) + q_bytes(Fm, D)
-  touched = E if all_experts else expected_distinct_experts(E, hf["num_experts_per_tok"], tokens)
+  touched = E if all_experts else experts_touched(hf, *routed_experts(hf)[1:], tokens)
   moe_ffn = touched * expert + 2 * D * E + 4 * E + (2 * q_bytes(D, Fs) + q_bytes(Fs, D) if Fs else 0)
   return n_dense * (attn + dense_ffn) + (L - n_dense) * (attn + moe_ffn) + q_bytes(D, V) + 2 * D
+
+
+def routed_experts(hf: dict) -> tuple[int, int, int, int]:
+  """(first, counted, routed, top_k): a step's bytes count every one of a layer's ``routed`` experts, of which a token chooses ``top_k``."""
+  E = hf["n_routed_experts"]
+  return 0, E, E, hf["num_experts_per_tok"]
 
 
 def step_weight_bytes(hf: dict, rows: float) -> float:

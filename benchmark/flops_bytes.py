@@ -5,8 +5,13 @@ benchmark so that no later change to the program can move the yardstick.
 step at the served types: every weight that takes part (int8 codes + f32
 scales per output channel, bf16 norms and router), the embedding rows of the
 batch, and the resident cache of the rows that decode. For expert layers only
-the experts the batch routes to count, as the expected number of distinct
-experts under uniform routing, E * (1 - (1 - k/E)^B).
+the experts the batch routes to count: the expected number of distinct experts
+that the rows choose under the router the configuration file states. Where the
+file states ``router_topics`` (a token's topic fixes its experts, so two rows
+of one topic choose the same ones) that is ``topic_router_distinct_experts``;
+where it states none, uniform and independent routing, E * (1 - (1 - k/E)^B)
+(``expected_distinct_experts``). A kind with routed experts asks
+``experts_touched`` and so takes whichever its file states.
 
 Which weights take part, what a layer reads of the cache and the matrix
 operations of a step are the kind's own (``benchmark/arch_<kind>.py``:
@@ -15,6 +20,8 @@ summed from them, and the roofline, is here.
 """
 
 from __future__ import annotations
+
+import math
 
 import arch
 
@@ -26,6 +33,34 @@ def q_bytes(d_in: int, d_out: int) -> int:
 
 def expected_distinct_experts(n_experts: int, top_k: int, tokens: float) -> float:
   return n_experts * (1.0 - (1.0 - top_k / n_experts) ** max(tokens, 0.0))
+
+
+def topic_router_distinct_experts(n_experts: float, owned_p: float, topics: int, tokens: float) -> float:
+  """Of ``n_experts`` experts, the expected number that ``tokens`` tokens choose when each token draws one of
+  ``topics`` topics uniformly and chooses exactly its topic's experts, and each topic owns an expert with probability
+  ``owned_p`` independently of the other topics (how the kinds' ``make_params`` draw a layer's ownership table). The
+  expectation is over the table and over the tokens' topics: an expert that s topics own is passed over by every token
+  with probability (1 - s/T)^tokens, and s is Binomial(T, p). As tokens grow it tends to n (1 - (1 - p)^T), the
+  experts some topic owns, not to n: no token ever chooses the others."""
+  tokens, T = max(tokens, 0.0), int(topics)
+  if tokens == 0.0 or owned_p <= 0.0:
+    return 0.0
+  if owned_p >= 1.0:  # top_k = routed: every topic owns every expert
+    return float(n_experts)
+  log_binom = lambda s: math.lgamma(T + 1) - math.lgamma(s + 1) - math.lgamma(T - s + 1) + s * math.log(owned_p) + (T - s) * math.log1p(-owned_p)  # noqa: E731
+  passed_over = sum(math.exp(log_binom(s)) * (1.0 - s / T) ** tokens for s in range(T + 1))
+  return n_experts * max(1.0 - passed_over, 0.0)
+
+
+def experts_touched(hf: dict, counted: float, routed: int, top_k: int, rows: float) -> float:
+  """Of ``counted`` experts of a layer that routes ``top_k`` of ``routed`` a token (every one of them as likely to be
+  chosen as any other; a kind's ``routed_experts(hf)`` names the three), the expected distinct ones that ``rows``
+  tokens choose under the router that the configuration file ``hf`` states: its topic router where ``router_topics``
+  is set, else uniform independent routing."""
+  topics = int(hf.get("router_topics") or 0)
+  if topics:
+    return topic_router_distinct_experts(counted, top_k / routed, topics, rows)
+  return expected_distinct_experts(routed, top_k, rows) * counted / routed
 
 
 def decode_step_min_bytes(hf: dict, rows: float, resident_tokens: float, kv_quant: str) -> float:

@@ -1,6 +1,8 @@
 """The routed experts against their memory roofline: the bytes of held expert weights that the expert layers of one
 decode step must read for the rows resident in the traced interval (``moe_expert_bytes`` of the kind's file: the expected
-distinct held experts that the rows choose, each expert's three matrices, in every expert layer) over the peak HBM rate,
+distinct held experts that the rows choose under the router the configuration file states - with ``router_topics`` a
+token's topic fixes its experts, so rows of one topic share them: ``flops_bytes.experts_touched``, 60.5 of Ling's 128 at 64
+rows where independent rows would touch 81.3 (PR 39) - each expert's three matrices, in every expert layer) over the peak HBM rate,
 over the device self time of scope ``xot.moe_experts`` per step. The scope also holds the dispatch and the combine,
 which move activations; the share is that of the whole scope. A program that streams every held expert whatever the
 rows chose reads more than is counted, so the share says how far the expert layer is from touching only what it must.

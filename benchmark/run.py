@@ -79,7 +79,7 @@ async def get_json(session, url: str):
 async def counters(session, url: str) -> dict:
   """The program's own counts, through its own doors: /v1/programs and /metrics."""
   progs = await get_json(session, f"{url}/v1/programs")
-  out = {"compiles": progs["totals"]["compiles"], "families": {f: (v["compiles"], v["dispatches"]) for f, v in progs["families"].items()}}
+  out = {"compiles": progs["totals"]["compiles"], "families": {f: (v["compiles"], v["dispatches"]) for f, v in progs["families"].items()}, "signatures": {f: v.get("signatures", []) for f, v in progs["families"].items()}}
   async with session.get(f"{url}/metrics") as resp:
     for line in (await resp.text()).splitlines():
       if line.startswith("xot_tpu_") and " " in line:
@@ -95,6 +95,11 @@ async def counters(session, url: str) -> dict:
 def family_delta(before: dict, after: dict, i: int) -> dict:
   """Per program family, how far its compiles (``i`` 0) or dispatches (1) moved between two ``counters()``; the unmoved left out."""
   return {f: a[i] - b for f, a in after["families"].items() if a[i] != (b := before["families"].get(f, (0, 0))[i])}
+
+
+def compiled_between(before: dict, after: dict) -> dict:
+  """Which families compiled between two ``counters()``, how often, and the newest argument signatures the program's ledger kept of each (it keeps eight)."""
+  return {f: {"compiles": n, "signatures": after["signatures"].get(f, [])[-n:]} for f, n in family_delta(before, after, 0).items()}
 
 
 def is_failed(mode: str, r) -> bool:
@@ -155,6 +160,12 @@ def client_view(ctx: dict) -> dict:
   total = [(r.last - (r.due if mode == "open" else r.sent)) * 1e3 for r in recs if r.ok]
   stat = lambda v: {"p50": percentile(v, 50), "p90": percentile(v, 90), "mean": sum(v) / len(v)} if v else {}  # noqa: E731
   return {"ttft_ms": stat(ttft), "tpot_ms": stat(tpot), "latency_ms": stat(total), "finished": len(total)}
+
+
+def request_rows(ctx: dict) -> list:
+  """Every request of the window as the client saw it (stderr only): what a spread is made of, when a later PR has to look."""
+  since = lambda r: r.due if ctx["mode"] == "open" else r.sent  # noqa: E731
+  return [[round(since(r) - ctx["t_open"], 3), r.prompt_tokens, r.tokens, round((r.first - since(r)) * 1e3, 1) if r.first else None, round(t * 1e3, 3) if (t := r.tpot()) else None] for r in ctx["recs"]]
 
 
 async def main_async(args) -> int:
@@ -225,7 +236,9 @@ async def main_async(args) -> int:
       ctx.update(mode=plan["mode"], t_start=T_START, hf=hf, cfg=cfg, traffic=traffic, peaks=peaks, spec=spec, chunk=int(os.getenv("XOT_TPU_BATCH_CHUNK", "8")))
       window_compiles = ctx["after"]["compiles"] - ctx["before"]["compiles"]
       if window_compiles:
-        log(event="compiled_in_window", compiles=window_compiles, families=family_delta(ctx["before"], ctx["after"], 0))
+        log(event="compiled_in_window", compiles=window_compiles, families=compiled_between(ctx["before"], ctx["after"]))
+      if ctx["before"]["compiles"] != ctx["start"]["compiles"]:
+        log(event="compiled_in_ramp", families=compiled_between(ctx["start"], ctx["before"]))
       attempted = len(ctx["recs"])
       failed = sum(1 for r in ctx["recs"] if is_failed(plan["mode"], r))
       device = {**info, "count": int(cell["chips"]), "memory_peak_bytes": memory_peak(int(cell["chips"]))}
@@ -251,6 +264,7 @@ async def main_async(args) -> int:
       result.update(metrics=metrics, device=device)
       dispatched = family_delta(ctx["before"], ctx["after"], 1)
       ramp = {"ramp_compiles": ctx["before"]["compiles"] - ctx["start"]["compiles"], "first_tokens_s": ctx.get("first_tokens_s")}
+      log(event="requests", columns=["due_s", "prompt", "answer", "ttft_ms", "tpot_ms"], rows=request_rows(ctx))
       log(event="window", attempted=attempted, failed=failed, window_compiles=window_compiles, late_p95_ms=ctx.get("late_p95_ms"), dispatches=dispatched, **ramp, **client_view(ctx), **knee_view(plan["mode"], ctx, traffic))
       log(event="compared", correct=bool(correct), **correctness.compared(detail, hf["arch_kind"]), stream_equals_blocking=[detail.get("stream_equals_blocking"), True])
       print(json.dumps(result), flush=True)
@@ -293,8 +307,10 @@ async def measure(session, stack, plan: dict, args, trace_dir) -> dict:
     has returned and closed before ``stop_trace`` is asked for; ``window_s`` and
     ``busy_s`` are read inside it, whatever the profiler records before and after.
     A capture over which no program family's dispatch count moved holds no device
-    work: its trace is removed and another is taken at once, while a whole one
-    still fits the window."""
+    work, and one over which no decode family's moved (an open loop's lull that
+    held one prefill; seed 1633000008 of PR 33) gives the decode readers nothing:
+    its trace is removed and another is taken at once, while a whole one still
+    fits the window; the last that fits is kept if it holds any dispatch."""
     import jax
 
     from trace_reduce import MARK
@@ -319,13 +335,14 @@ async def measure(session, stack, plan: dict, args, trace_dir) -> dict:
       after = await counters(session, stack.url)
       await stopped
       event = {"event": "capture", "attempt": len(empty) + 1, "offset_s": [cap_start - t_open, cap_end - t_open], "dispatches": family_delta(before, after, 1)}
-      if event["dispatches"]:
+      another = len(empty) + 1 < CAPTURE_ATTEMPTS and time.perf_counter() + length <= t_open + seconds
+      if any(f.startswith("decode.") for f in event["dispatches"]) or (event["dispatches"] and not another):
         marks.update(cap_start=cap_start, cap_end=cap_end, capture=event)  # logged by reduce_trace, with what the file holds
         return
       log(**event, kept=False)
       empty.append(event["offset_s"])
     shutil.rmtree(trace_dir, ignore_errors=True)
-    raise RuntimeError(f"no capture to reduce: the program dispatched nothing during any of {len(empty)} captures of {length:g} s, at {empty} s of the {seconds:g} s window")
+    raise RuntimeError(f"no capture to reduce: the program dispatched nothing during any of {len(empty)} captures of {length:g} s (or, before the last, prefills and no decode step), at {empty} s of the {seconds:g} s window")
 
   async def open_after_first_tokens(all_recs: list, close_at: asyncio.Future) -> None:
     n = plan["clients"]
@@ -387,7 +404,8 @@ async def sweep(session, stack, gen, traffic: dict, args, vocab: int) -> int:
     before = await counters(session, stack.url)
     ctx = await measure(session, stack, plan, args, None)
     ctx["mode"] = plan["mode"]
-    log(event="sweep", attempted=len(ctx["recs"]), failed=sum(1 for r in ctx["recs"] if is_failed(plan["mode"], r)), window_compiles=ctx["after"]["compiles"] - ctx["before"]["compiles"],
+    log(event="requests", rate_rps=rate, columns=["due_s", "prompt", "answer", "ttft_ms", "tpot_ms"], rows=request_rows(ctx))
+    log(event="sweep", out_tok_s=client.tokens_between(ctx["recs"], ctx["t_open"], ctx["t_close"]) / float(args.seconds), dispatches=family_delta(ctx["before"], ctx["after"], 1), attempted=len(ctx["recs"]), failed=sum(1 for r in ctx["recs"] if is_failed(plan["mode"], r)), window_compiles=ctx["after"]["compiles"] - ctx["before"]["compiles"],
         late_p95_ms=ctx.get("late_p95_ms"), compiles_since_last=ctx["after"]["compiles"] - before["compiles"], **client_view(ctx), **knee_view(plan["mode"], ctx, mix))
   return 0
 

@@ -31,6 +31,20 @@ def test_seeds_reorder_the_same_work():
   assert len(a["window"]) == 80 and a["ramp_s"] <= a["window"][0]["due_s"] and a["window"][-1]["due_s"] < a["ramp_s"] + 20
 
 
+def test_a_seed_turns_the_file_s_one_cycle():
+  """A seed starts the traffic file's one cycle of (gap, prompt, answer) triples at another place: the same neighbours, but at the wrap."""
+  t = common.load_traffic("chat-poisson")
+  plans = [open_poisson.plan(t, seed, 51, 32768)["window"] for seed in (1, 2, 2**31 + 7)]
+  triples = [[(round(b["due_s"] - a["due_s"], 9), len(a["prompt"]), a["max_tokens"]) for a, b in zip(w, w[1:])] for w in plans]
+  for other in triples[1:]:
+    at = [(p, m) for _, p, m in other].index(triples[0][0][1:])
+    assert len(set(other) & set(triples[0])) >= len(other) - 1 and other[at] == triples[0][0]
+  assert triples[0] != triples[1] and [r["prompt"].tolist() for r in plans[0]] != [r["prompt"].tolist() for r in plans[1]]
+  other_cycle = open_poisson.plan({**t, "order_seed": 1}, 1, 51, 32768)["window"]
+  assert Counter(len(r["prompt"]) for r in other_cycle) == Counter(len(r["prompt"]) for r in plans[0]) and Counter(r["max_tokens"] for r in other_cycle) == Counter(r["max_tokens"] for r in plans[0])
+  assert [len(r["prompt"]) for r in other_cycle] != [len(r["prompt"]) for r in plans[0]]
+
+
 def test_lengths_are_the_stated_distribution_unrounded():
   t = common.load_traffic("chat-poisson")
   q = sizes.quantiles(t["prompt_tokens"], 10001)
@@ -55,6 +69,21 @@ def test_warm_up_covers_every_shape_of_the_planned_lengths():
   assert {warm.shapes_of(n, rule)[-1] for n in finals} == {s for s in want if s[0] == "final"}
   groups = common.load_config("moonlight-a3b-d14")["warm_shape_rule"]
   assert warm.shapes_of(129, groups) == [("group", 256)] and warm.shapes_of(1024, groups) == [("group", 1024)]
+
+
+def test_warm_up_covers_the_idle_server_s_shapes():
+  """With no decode row resident the scheduler slices nothing: `_chunk_ready` takes the prompt in chunks of
+  XOT_TPU_PREFILL_CHUNK padded to PREFILL_BUCKET, `_page_window` over the padded end (worked by hand)."""
+  import warm
+
+  rule = common.load_config("mistral-7b-int8")["warm_shape_rule"]
+  assert warm.idle_shapes_of(95, rule) == [("idle", 128, 2)] and warm.idle_shapes_of(2048, rule) == [("idle", 2048, 32)]
+  assert warm.idle_shapes_of(2049, rule) == [("idle", 2048, 32), ("idle", 128, 64)] and warm.idle_shapes_of(2748, rule) == [("idle", 2048, 32), ("idle", 768, 64)]
+  lengths = open_poisson.prompt_lengths(_open(3, rate=0.56, seconds=51))
+  idle = warm.cover(lengths, rule, warm.idle_shapes_of)[0]
+  assert {s for n in idle for s in warm.idle_shapes_of(n, rule)} == {s for n in lengths for s in warm.idle_shapes_of(n, rule)} and len(idle) <= len({-(-n // 128) for n in lengths})
+  groups = common.load_config("moonlight-a3b-d14")["warm_shape_rule"]  # no mixed ticks: one path, busy or idle
+  assert warm.idle_shapes_of(700, groups) == [] and warm.cover(lengths, groups, warm.idle_shapes_of) == ([], [])
 
 
 def test_closed_queue_is_stratified():

@@ -59,7 +59,8 @@ def test_the_file_holds_every_number_of_the_catalog_row_outside_reduced():
 def test_the_kda_moe_byte_model_is_the_published_sizes_reckoning():
   """ISSUE 36's arithmetic, from the file: a KDA mixer 52.6 M parameters, the MLA mixer 31.9 M, the dense FFN 47.2 M, an
   expert 5.90 M, 5.17 G in all = 10.3 GB; 12.6 MB of state and 0.44 MB of convolution rows a slot; 1152 bytes of latent
-  cache a token; at 64 rows the held experts touched (81 of 128 a layer) and the state are most of a step's least bytes."""
+  cache a token; at 64 rows the held experts touched (60 of 128 a layer under the file's topic router, ISSUE 39; 81
+  under uniform routing, which the file does not state) and the state are most of a step's least bytes."""
   hf, kind = common.load_config(CONFIG), arch.load(KIND)
   p = kind._params(hf)
   assert [round(p[k] / 1e6, 1) for k in ("kda", "mla", "dense_ffn", "expert")] == [52.6, 31.9, 47.2, 5.9]
@@ -70,11 +71,19 @@ def test_the_kda_moe_byte_model_is_the_published_sizes_reckoning():
   assert round(6 * 32 * 128 * 128 * 4 / 1e6, 1) == 12.6 and round((slot - 6 * 32 * 128 * 128 * 4) / 1e6, 2) == 0.44
   per_layer = kind.cache_read_bytes(hf, 64, 64 * 500, "")
   assert len(per_layer) == 7 and per_layer[5] == 64 * 500 * 1152 and per_layer[0] == per_layer[6] == kind.ssm_state_bytes(hf, 64) / 6
-  assert 80.5 < kind.held_experts_touched(hf, 64) < 81.5 and round(kind.moe_expert_bytes(hf, 64) / 1e9, 2) == 5.75
+  assert 60.0 < kind.held_experts_touched(hf, 64) < 61.0 and round(kind.moe_expert_bytes(hf, 64) / 1e9, 2) == 4.28
+  assert kind.held_experts_touched(hf, 61.1) == pytest.approx(59.3, abs=0.1) and kind.held_experts_touched(hf, 64) == pytest.approx(60.5, abs=0.1)
   step = fb.decode_step_min_bytes(hf, 64, 64 * 500, "")
   share = (kind.moe_expert_bytes(hf, 64) + kind.ssm_state_bytes(hf, 64)) / step
-  assert 0.85 < share < 0.90, share
-  assert kind.step_weight_bytes(hf, 1e9) == pytest.approx(kind.weight_bytes(hf) - 39296 * 2560 * 2)  # every held expert touched: all but the embedding table
+  assert 0.83 < share < 0.85, share
+  # rows without end reach the held experts that some topic owns, 128 (1 - (63/64)^64) = 81.3 a layer, not all 128: no token chooses the rest
+  owned = 128 * (1 - (63 / 64) ** 64)
+  assert kind.held_experts_touched(hf, 1e9) == pytest.approx(owned) == pytest.approx(81.28, abs=0.01)
+  assert kind.step_weight_bytes(hf, 1e9) == pytest.approx(kind.weight_bytes(hf) - 39296 * 2560 * 2 - 6 * (128 - owned) * p["expert"] * 2)
+  plain = {**hf, "router_topics": 0}  # a file that states no topics counts as before: uniform routing over all 512
+  assert 80.5 < kind.held_experts_touched(plain, 64) < 81.5 and round(kind.moe_expert_bytes(plain, 64) / 1e9, 2) == 5.75
+  assert kind.step_weight_bytes(plain, 1e9) == pytest.approx(kind.weight_bytes(plain) - 39296 * 2560 * 2)
+  assert fb.decode_step_flops(plain, 64) == fb.decode_step_flops(hf, 64)  # operations follow the assignments (rows x k), not the distinct experts
   assert fb.decode_step_flops(hf, 64) > 0 and kind.CACHE_TYPE_ENV is None
 
 
@@ -115,6 +124,36 @@ def test_the_topic_router_keeps_the_group_limit_clear_of_its_choices():
   groups = chosen.reshape(256, 8, 8).sum(axis=2)
   assert (np.sort(groups, axis=1)[:, 4:] == 2).mean() > 0.9  # two in each of four groups
   assert 0.15 < chosen[:, :16].sum() / chosen.sum() < 0.35  # the share of choices held by chip 0
+
+
+def test_the_topic_count_is_what_the_kinds_own_router_makes_rows_choose():
+  """Ties ``held_experts_touched`` to the code that makes the weights: the kind's ``_router`` drawn with topics on
+  (8 topics, 64 routed experts in 8 groups, 16 held, 8 a token: p x T = 1, as published), 64 random token ids walked
+  through the reference, and in every expert layer the held experts that the reference's own router chose for any of
+  them - the mixers' context and all - against the expectation; and against ``router_tables``' reading of the same
+  draw. Uniform routing would touch 16.0 of the 16."""
+  hf, kind = _tiny(), arch.load(KIND)
+  hf.update(num_experts=16, num_experts_routed=64, router_topics=8)
+  want = kind.held_experts_touched(hf, 64)
+  assert want == pytest.approx(10.5, abs=0.01) and kind.held_experts_touched({**hf, "router_topics": 0}, 64) > 15.99
+  touched, by_table, as_owned = [], [], []
+  for seed in range(8):
+    params = weights.build_params(hf, seed)
+    tables = jax.jit(lambda k: kind.router_tables(weights.shape_hf(hf), k))(weights.seed_key(seed))
+    owns, topic_of = np.asarray(tables["owns"]) > 0, np.asarray(tables["topic_of"])
+    assert owns.shape == (3, 8, 64) and (owns.sum(axis=2) == 8).all() and topic_of.shape == (hf["vocab_size"],)
+    tokens = np.random.default_rng(seed).integers(3, hf["vocab_size"], size=64)
+    routed: list = []
+    logits = kind.reference_forward(params, hf, tokens, routed=routed)
+    assert len(routed) == 3 and np.array_equal(np.asarray(logits), np.asarray(kind.reference_forward(params, hf, tokens)))
+    for layer, chose in enumerate(np.asarray(r) for r in routed):
+      assert chose.shape == (64, 64) and (chose.sum(axis=1) == 8).all()
+      touched.append(chose[:, :16].any(axis=0).sum())
+      by_table.append(owns[layer][topic_of[tokens]][:, :16].any(axis=0).sum())
+      as_owned.append((chose == owns[layer][topic_of[tokens]]).all(axis=1).mean())
+  assert np.mean(as_owned) > 0.9  # a token's topic fixes its eight experts, in every expert layer
+  assert np.mean(by_table) == pytest.approx(want, rel=0.1) and np.mean(touched) == pytest.approx(want, rel=0.1), (np.mean(touched), np.mean(by_table), want)
+  assert np.mean(touched) < 0.8 * 16
 
 
 def test_the_new_reader_finds_nothing_where_the_program_has_no_such_scope(monkeypatch):

@@ -4,7 +4,14 @@ anything moved (``data/pins_bb2de62.json`` and ``.npz``; XLA's CPU backend,
 jax 0.9.0): at the rehearsal widths every weight leaf from a fixed seed, to the
 byte; the reference's log-probs on a fixed prompt, plain and under every
 probe; the limits of ``correct``; and at the published configurations the
-byte and operation counts the rooflines divide by, to the last digit."""
+byte and operation counts the rooflines divide by, to the last digit.
+
+Re-recorded by design in PR 39, and nothing else: ``published["moonlight-a3b-d14"]["decode_step_min_bytes"]`` (6.754 ->
+6.463 GB at 16 rows, 2.692 -> 2.666 at 3; one row touches its six experts either way). The parent counted the experts a
+step touches under uniform, independent routing; the configuration file states a topic router (``router_topics`` 64:
+two rows of one topic choose the same six), and ``flops_bytes.experts_touched`` now counts under the router the file
+states. The parent's three values stay beside them as ``decode_step_min_bytes_uniform_routing`` and are still met to
+the last digit by the same file with ``router_topics`` 0; the operation counts did not move."""
 
 import hashlib
 import json
@@ -75,7 +82,12 @@ def test_bytes_and_operations_at_the_published_sizes_are_the_parents(config):
   kvq = layer_lib.kv_quant({"hf": hf})
   assert kvq == pin["kv_quant"]
   cases = ((16.0, 12800.0), (3.0, 1234.0), (1.0, 64.0))
-  assert [fb.decode_step_min_bytes(hf, r, t, kvq) for r, t in cases] == pin["decode_step_min_bytes"]
+  if "decode_step_min_bytes_uniform_routing" in pin:  # a file with a topic router (the docstring): the parent's count where it states none ...
+    assert [fb.decode_step_min_bytes({**hf, "router_topics": 0}, r, t, kvq) for r, t in cases] == pin["decode_step_min_bytes_uniform_routing"]
+    assert [fb.decode_step_min_bytes(hf, r, t, kvq) for r, t in cases] == pytest.approx(pin["decode_step_min_bytes"], rel=1e-12)  # ... and PR 39's as it states it (a sum of 65 rounded terms)
+    assert all(new <= old for new, old in zip(pin["decode_step_min_bytes"], pin["decode_step_min_bytes_uniform_routing"]))
+  else:
+    assert [fb.decode_step_min_bytes(hf, r, t, kvq) for r, t in cases] == pin["decode_step_min_bytes"]
   assert [fb.decode_step_flops(hf, r) for r, _ in cases] == pin["decode_step_flops"]
   if "paged_attention_min_bytes" in pin:  # the parent's was the dense kind's formula, read in Mistral's cells only
     assert [fb.paged_attention_min_bytes(hf, r, t, kvq) for r, t in cases] == pin["paged_attention_min_bytes"]

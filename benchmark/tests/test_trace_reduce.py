@@ -172,20 +172,22 @@ def test_no_device_plane_reads_no_chip():
 # ------------------------------------------------------------------ run.py capture(): an empty capture is taken again
 
 
-@pytest.mark.parametrize("dispatched, kept", [([0, 0, 0, 0, 0, 5, 5], 2), ([0, 0, 0, 7, 7, 7, 7], 1), ([0] * 9, None)], ids=["second_kept", "first_kept", "all_empty"])
+@pytest.mark.parametrize("dispatched, kept", [([0, 0, 0, 0, 0, 5, 5], 2), ([0, 0, 0, 7, 7, 7, 7], 1), ([0] * 9, None), ([0, 0, 0, (0, 2), (0, 2), (5, 2), (5, 2)], 2)], ids=["second_kept", "first_kept", "all_empty", "prefill_only_taken_again"])
 def test_a_capture_without_a_dispatch_is_taken_again(recorded, tmp_path, monkeypatch, capsys, dispatched, kept):
   """``measure`` with a stubbed profiler (each ``start_trace`` leaves the recorded file under a name of
   its own), a stubbed load generator and a scripted ``counters()``: window open, window close and two
-  readings a capture, in the order of the calls."""
+  readings a capture, in the order of the calls. A capture over which prefills moved and no decode step
+  (an open loop's lull) is taken again too: the decode readers would find nothing in it (PR 39)."""
   import jax
 
   import run
 
   calls, started = [], []
+  pair = lambda v: v if isinstance(v, tuple) else (v, 0)  # noqa: E731 — (decode, prefill) dispatches so far; a bare number is decode's
 
   async def counters(session, url):
-    calls.append(dispatched[min(len(calls), len(dispatched) - 1)])
-    return {"compiles": 0, "families": {"decode.paged_batch": (0, calls[-1]), "prefill.pages_many": (0, 0)}}
+    calls.append(pair(dispatched[min(len(calls), len(dispatched) - 1)]))
+    return {"compiles": 0, "families": {"decode.paged_batch": (0, calls[-1][0]), "prefill.pages_many": (0, calls[-1][1])}}
 
   def start_trace(log_dir, profiler_options=None):
     started.append(len(started) + 1)
@@ -220,7 +222,7 @@ def test_a_capture_without_a_dispatch_is_taken_again(recorded, tmp_path, monkeyp
   events = [json.loads(line) for line in capsys.readouterr().err.splitlines() if line.startswith('{"event": "capture"')]
   assert [(e["attempt"], e["kept"]) for e in events] == [(n, n == kept) for n in range(1, kept + 1)]
   last = events[-1]
-  assert last["dispatches"] == {"decode.paged_batch": max(dispatched)} and last["offset_s"] == [ctx["cap_start"] - ctx["t_open"], ctx["cap_end"] - ctx["t_open"]]
+  assert last["dispatches"] == {"decode.paged_batch": max(pair(v)[0] for v in dispatched)} and last["offset_s"] == [ctx["cap_start"] - ctx["t_open"], ctx["cap_end"] - ctx["t_open"]]
   assert 0.9 + 0.2 * (kept - 1) <= last["offset_s"][0] < last["offset_s"][1] <= 2.0
   assert (last["marked"], last["interval"], last["extent"], last["busy_s"], last["window_s"], last["outside_s"]) == (False, red["extent"], red["extent"], red["busy_s"], red["window_s"], 0.0)
   assert run.device_seconds(red, ctx["capture"]) == {"busy_s": pytest.approx(0.24688, rel=1e-3), "window_s": red["window_s"]}

@@ -14,12 +14,24 @@ so that the warm-up can send one prompt per shape instead of one per length:
                  whole prompt that short - go through the group dispatch: one
                  program per (group size rounded to a power of two, pages
                  covering the prompt rounded to a power of two).
+                 When NO decode row is resident (``idle_chunk_tokens`` in the
+                 rule; warmed where the traffic file's ``warm`` block says
+                 ``idle``: a server that has drained its queue, as an open loop
+                 below its knee does many times a window) nothing is sliced:
+                 the group dispatch takes the whole prompt in chunks of
+                 ``idle_chunk_tokens``, each one program per (length padded
+                 to ``bucket_tokens``, pages covering its padded end rounded
+                 to a power of two) - ``idle_shapes_of``.
+                 ``final_escorts`` in the ``warm`` block lists how many short prompts wait beside each distinct final
+                 window (1: a group of two, 3: of four; one where the file
+                 lists a group size above one and says nothing else).
   padded_groups  (no mixed ticks) A group of k admissions is one program per
                  (k rounded to a power of two, longest prompt padded to
                  ``bucket_tokens``).
 
-One long "anchor" request is held resident throughout, so the paths are the
-ones the window takes. The lengths sent are the planned requests' own (the
+The idle shapes are sent first, one prompt at a time to a server with nothing
+resident. Then one long "anchor" request is held resident, so the paths are the
+ones a busy window takes. The lengths sent are the planned requests' own (the
 seed reorders one fixed multiset, so they are the same in every run); the rule
 only says which of them repeat a shape. If the rule changes in the program,
 ``window_compiles`` reads above 0 and the run says which family compiled."""
@@ -27,6 +39,7 @@ only says which of them repeat a shape. If the rule changes in the program,
 from __future__ import annotations
 
 import asyncio
+import time
 
 import numpy as np
 
@@ -55,11 +68,27 @@ def shapes_of(length: int, rule: dict) -> list[tuple]:
   return out + [("final", pow2(-(-max(length, bucket) // page)))]
 
 
-def cover(lengths: list[int], rule: dict) -> tuple[list[int], list[int]]:
+def idle_shapes_of(length: int, rule: dict) -> list[tuple]:
+  """The compiled prefill shapes one prompt of ``length`` tokens runs through alone when no decode row is resident."""
+  chunk = int(rule.get("idle_chunk_tokens", 0))
+  if rule["kind"] != "mixed_slices" or chunk <= 0:
+    return []  # one path, busy or idle: ``shapes_of`` has it
+  bucket, page = int(rule["bucket_tokens"]), int(rule["page_tokens"])
+  out, start = [], 0
+  while start < length:
+    pad = -(-min(chunk, length - start) // bucket) * bucket
+    out.append(("idle", pad, pow2(-(-(start + pad) // page))))
+    start += chunk
+  return out
+
+
+def cover(lengths: list[int], rule: dict, shapes=shapes_of) -> tuple[list[int], list[int]]:
   """(one length per shape not yet covered, one length per distinct final shape)."""
   seen, firsts, finals = set(), [], {}
   for n in sorted(set(lengths)):
-    got = shapes_of(n, rule)
+    got = shapes(n, rule)
+    if not got:
+      continue
     if any(s not in seen for s in got):
       firsts.append(n)
       seen.update(got)
@@ -72,13 +101,17 @@ async def _send(session, stack, rng, vocab: int, length: int, max_tokens: int = 
   return await client.stream_chat(session, stack.url, stack.model_id, sizes.prompt_ids(rng, length, vocab), max_tokens, rec)
 
 
-async def _escorted(session, stack, rng, vocab: int, length: int, short: int) -> list[client.Rec]:
-  """One prompt with a short one always waiting beside it, so that its final
-  dispatch is a group of two."""
+async def _escorted(session, stack, rng, vocab: int, length: int, short: int, escorts: int = 1) -> list[client.Rec]:
+  """One prompt with ``escorts`` short ones always waiting beside it, so that
+  its final dispatch is a group of ``escorts`` + 1."""
   main = asyncio.create_task(_send(session, stack, rng, vocab, length))
   recs = []
-  while not main.done():
-    recs.append(await _send(session, stack, rng, vocab, short, 1))
+
+  async def escort() -> None:
+    while not main.done():
+      recs.append(await _send(session, stack, rng, vocab, short, 1))
+
+  await asyncio.gather(*(escort() for _ in range(escorts)))
   return [await main, *recs]
 
 
@@ -87,21 +120,24 @@ async def run(session, stack, rule: dict, warm: dict, vocab: int, seed: int, len
   ``warm`` block; ``lengths``: the prompt lengths of the planned requests (ramp and window)."""
   rng = np.random.default_rng([int(seed), 3])
   firsts, finals = cover(lengths, rule)
+  idle = cover(lengths, rule, idle_shapes_of)[0] if warm.get("idle") else []  # a closed loop's server is never idle inside its window
+  t0 = time.perf_counter()
+  recs: list[client.Rec] = [await _send(session, stack, rng, vocab, n) for n in idle]  # one at a time: each meets a server with nothing resident
+  idle_s = time.perf_counter() - t0
   together = max(int(warm.get("concurrent", 1)), 1)  # how many warm groups are in flight at once
   anchor_rec = client.Rec(0.0, min(lengths), int(warm.get("anchor_tokens", 2048)))
   anchor = asyncio.create_task(client.stream_chat(session, stack.url, stack.model_id, sizes.prompt_ids(rng, min(lengths), vocab), anchor_rec.max_tokens, anchor_rec))
   while anchor_rec.first is None and not anchor.done():
     await asyncio.sleep(0.01)
-  recs: list[client.Rec] = []
   try:
     if rule["kind"] == "mixed_slices":
       # Only one prompt is sliced per tick, so groups larger than one form at the final dispatch alone.
       for i in range(0, len(firsts), together):
         recs += await asyncio.gather(*(_send(session, stack, rng, vocab, n) for n in firsts[i : i + together]))
       short = min(min(lengths), int(rule["bucket_tokens"]))
-      if max(warm.get("group_sizes", [1])) > 1:
+      for escorts in warm.get("final_escorts", [1] if max(warm.get("group_sizes", [1])) > 1 else []):
         for n in finals:
-          recs += await _escorted(session, stack, rng, vocab, n, short)
+          recs += await _escorted(session, stack, rng, vocab, n, short, escorts)
     else:
       for k in warm.get("group_sizes", [1]):
         for i in range(0, len(firsts), together):
@@ -109,4 +145,4 @@ async def run(session, stack, rule: dict, warm: dict, vocab: int, seed: int, len
   finally:
     anchor.cancel()
     await asyncio.gather(anchor, return_exceptions=True)
-  return {"warm_requests": len(recs), "warm_failed": sum(not r.ok for r in recs), "warm_lengths": firsts, "warm_finals": finals, "anchor_tokens_seen": anchor_rec.tokens}
+  return {"warm_requests": len(recs), "warm_failed": sum(not r.ok for r in recs), "warm_idle": idle, "warm_idle_s": round(idle_s, 3), "warm_lengths": firsts, "warm_finals": finals, "anchor_tokens_seen": anchor_rec.tokens}
