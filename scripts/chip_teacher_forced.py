@@ -90,7 +90,7 @@ def main() -> int:
   last, pool = dec.prefill_into_pages_many_inplace(params, cfg, shard, jnp.asarray(tok), pool, jnp.asarray(bts), jnp.zeros((K,), jnp.int32), jnp.asarray(prompt_lens), ps, None, jnp.asarray(slot_rows))
   got = [[np.asarray(jax.nn.log_softmax(last[i].astype(jnp.float32)))] for i in range(args.rows)]
 
-  step = jax.jit(lambda params, tok, pos, pool, active: dec.paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, jnp.asarray(tables), ps, False, active=active), donate_argnums=3)
+  step = jax.jit(lambda params, tok, pos, pool, active: dec.paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, jnp.asarray(tables), ps, False, active=active)[:2], donate_argnums=3)
   active = np.zeros((slots,), bool)
   active[use_slots] = True
   for t in range(args.steps - 1):

@@ -85,7 +85,7 @@ def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None 
 
 @partial(jax.jit, static_argnums=0)
 def _decode_forward(cfg, params, tok, pos, pool, active):
-  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)
+  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)[:2]  # (the third result counts expert visits)
 
 
 def decode_step(pool, tokens: dict, positions: dict, params=PARAMS, cfg=CFG):
@@ -222,7 +222,7 @@ def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
   routed = lambda lo, hi: moe_ops.moe_ffn(  # noqa: E731
     xn, router, eg[lo:hi], eu[lo:hi], ed[lo:hi], k=8, scoring="sigmoid", norm_topk=True, selection_bias=bias, scale=2.5, n_group=CFG.n_group, topk_group=CFG.topk_group,
     group_mode="top2sum", held=(lo, hi),
-  )
+  )[0]
   shares = [routed(lo, lo + 8) for lo in range(0, E, 8)]
   shared = (jax.nn.silu(xn @ sg) * (xn @ su)) @ sd
   np.testing.assert_allclose(np.asarray(sum(shares) + shared), np.asarray(whole), atol=2e-5, rtol=0)
@@ -232,7 +232,7 @@ def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(np.asarray(shares[lo // 8] + shared), np.asarray(one), atol=2e-5, rtol=0)
   # a long run of tokens in blocks of 16 is the one block's result: routing is per token
   blocked = moe_ops.moe_ffn(xn, router, eg[:8], eu[:8], ed[:8], k=8, scoring="sigmoid", norm_topk=True, selection_bias=bias, scale=2.5, n_group=CFG.n_group, topk_group=CFG.topk_group,
-                            group_mode="top2sum", held=(0, 8), chunk=16)  # fmt: skip
+                            group_mode="top2sum", held=(0, 8), chunk=16)[0]  # fmt: skip
   np.testing.assert_allclose(np.asarray(blocked), np.asarray(shares[0]), atol=2e-5, rtol=0)
   assert all(float(jnp.abs(s).max()) > 1e-3 for s in shares)  # every chip's share is a part of the sum
   assert float(jnp.abs(shares[0] - routed(0, E)).max()) > 1e-2  # ... and no one share is the layer
@@ -416,3 +416,74 @@ def test_the_scopes_reach_the_lowered_decode_program():
   want = {"xot.ssm", "xot.ssm_proj", "xot.moe_router", "xot.moe_experts", "xot.moe_shared", "xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.ffn", "xot.head", "xot.sample"}
   assert want <= scopes, sorted(want - scopes)
   assert re.search(r'"[^"]*xot\.ssm/[^"]*dynamic_update_slice', text), "no state write under xot.ssm"
+
+
+def test_the_served_decode_counts_the_experts_its_rows_chose(monkeypatch):
+  """(ISSUE 40) ``/metrics`` says which form the experts' product takes (off the TPU: the block form) and how far the
+  grouped form would engage: ``moe_experts_visited_total`` over ``moe_expert_layer_steps_total`` is the mean number of
+  distinct held experts a decode step chose in one expert layer — here against a count by hand of the router's own
+  choices, recorded as the decode programs ran (3 rows, top 8 of 32, experts 0-7 held, 3 expert layers)."""
+  from xotorch_support_jetson_tpu.utils.metrics import metrics
+
+  seen = []
+
+  def recording(logits, k, *args, **kwargs):
+    weights, idx = router_topk(logits, k, *args, **kwargs)
+    if logits.shape[0] == 3:  # a decode step's rows (a prefill group is rows x padded tokens)
+      jax.debug.callback(lambda chosen: seen.append(np.asarray(chosen)), idx, ordered=True)
+    return weights, idx
+
+  router_topk = moe_ops.router_topk
+  monkeypatch.setattr(moe_ops, "router_topk", recording)
+  monkeypatch.setenv("XOT_TPU_BATCH_SLOTS", "3")  # no other test's shapes: the programs are traced here, with the recorder
+  monkeypatch.setenv("XOT_TPU_PAGE_SIZE", str(PS))
+  engine = JaxShardedInferenceEngine(use_local_mesh=False)
+  engine.load_test_model(SHARD, CFG, PARAMS)
+  server = BatchedServer(engine)
+  visited, steps = (lambda: metrics.counter_value("moe_experts_visited_total")), (lambda: metrics.counter_value("moe_expert_layer_steps_total"))  # noqa: E731
+  before = visited(), steps()
+  try:
+    answers = _serve(server, [[int(t) for t in TOKENS[:20]], [int(t) for t in TOKENS[30:41]], [int(t) for t in TOKENS[50:77]]], 20)
+  finally:
+    server.shutdown()
+  assert [len(a) for a in answers] == [20, 20, 20]
+  assert metrics.gauge_value("moe_ffn_form", labels={"form": "block"}) == 1 and metrics.gauge_value("moe_ffn_form", labels={"form": "grouped"}) == 0
+  exposition = metrics.render_prometheus()  # what ``/metrics`` serves
+  assert 'xot_tpu_moe_ffn_form{form="block"} 1' in exposition and 'xot_tpu_moe_ffn_form{form="grouped"} 0' in exposition
+  assert "xot_tpu_moe_experts_visited_total " in exposition and "xot_tpu_moe_expert_layer_steps_total " in exposition
+  n_steps, n_visited = int(steps() - before[1]), int(visited() - before[0])
+  lo, hi = CFG.experts_held
+  by_hand = [len({int(e) for e in chosen.ravel() if lo <= e < hi}) for chosen in seen]
+  assert server._expert_layers == 3 and n_steps >= 2 * 3 * server.chunk and n_steps % (3 * server.chunk) == 0  # whole chunks: 3 expert layers x the chunk's steps
+  assert len(by_hand) >= n_steps  # (a chunk in flight at shutdown ran and is not settled)
+  assert n_visited == sum(by_hand[:n_steps])
+  assert 1 <= n_visited / n_steps <= 8
+
+
+def test_a_lane_wide_hybrid_takes_the_grouped_form_where_told(monkeypatch):
+  """(ISSUE 40) The hybrid's prefill into the pool and its decode chunk — runs of one stack's layers, the expert
+  leaves held for part of the router's range — with the experts' kernels interpreted (``INTERPRET``): the
+  stacked expert leaves reach ``moe_ffn`` whole with the layer's index, and logits, greedy tokens and the count of
+  expert visits are the block form's."""
+  hf = {**HF, "hidden_size": 128, "moe_intermediate_size": 128, "moe_shared_expert_intermediate_size": 128}
+  cfg = config_from_hf(hf)
+  params = jax.tree.map(lambda x: x.astype(jnp.float32), weights.build_params(hf, 12))
+  assert dec._whole_expert_leaves(params, cfg) == ()
+  prompts = {0: TOKENS[:37], 2: TOKENS[40:59]}
+
+  def run(cfg):
+    last, pool = prefill(fresh_pool(cfg), prompts, params=params, cfg=cfg)
+    tok = jnp.asarray(np.argmax(np.asarray(last), axis=-1)[[0, 0, 1, 1]].astype(np.int32)[:, None])
+    pos = jnp.asarray([37, 0, 19, 0], jnp.int32)
+    toks, _, _, _, visits = dec.fused_paged_batch_decode(
+      params, cfg, SHARD, tok, pool, jnp.asarray(tables()), pos, jnp.asarray([True, False, True, False]), jnp.zeros((SLOTS,), jnp.float32), 5, page_size=PS, use_kernel=False, experts_visited=True
+    )
+    return np.asarray(last), np.asarray(toks)[[0, 2]], int(visits)
+
+  ref = run(cfg)
+  monkeypatch.setattr(moe_ops, "INTERPRET", True)
+  told = replace(cfg, max_seq_len=cfg.max_seq_len + 1)  # another static config: traced anew
+  assert set(dec._whole_expert_leaves(params, told)) == {"w_experts_gate", "w_experts_up", "w_experts_down"}
+  got = run(told)
+  np.testing.assert_allclose(got[0], ref[0], rtol=0, atol=TOL)
+  assert got[1].tolist() == ref[1].tolist() and got[2] == ref[2] > 0

@@ -106,7 +106,7 @@ def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None 
 
 @partial(jax.jit, static_argnums=0)
 def _decode_forward(cfg, params, tok, pos, pool, active):
-  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)
+  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)[:2]  # (the third result counts expert visits)
 
 
 def decode_step(pool, tokens: dict, positions: dict):

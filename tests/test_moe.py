@@ -90,7 +90,8 @@ def test_moe_ffn_matches_per_token_loop():
   w_up = jax.random.normal(ks[3], (E, D, F)) * 0.1
   w_down = jax.random.normal(ks[4], (E, F, D)) * 0.1
 
-  out = moe_ffn(x, w_router, w_gate, w_up, w_down, k=k)
+  out, _aux, visited = moe_ffn(x, w_router, w_gate, w_up, w_down, k=k)
+  assert 1 <= int(visited) <= E
 
   weights, idx = router_topk(x @ w_router, k)
   expected = np.zeros((T, D), np.float32)
@@ -205,8 +206,8 @@ def test_moe_chunked_dispatch_matches_single_block():
   w_gate = jax.random.normal(ks[2], (E, D, F)) * 0.1
   w_up = jax.random.normal(ks[3], (E, D, F)) * 0.1
   w_down = jax.random.normal(ks[4], (E, F, D)) * 0.1
-  one = moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, chunk=64)
-  chunked = moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, chunk=16)
+  one = moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, chunk=64)[0]
+  chunked = moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, chunk=16)[0]
   np.testing.assert_allclose(np.asarray(chunked), np.asarray(one), rtol=1e-5, atol=1e-6)
 
 
@@ -275,33 +276,273 @@ def test_mla_lora_adapters_are_live():
   np.testing.assert_allclose(np.asarray(folded), np.asarray(bumped), rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize(
-  "kwargs",
-  [
-    dict(scoring="softmax", norm_topk=False),  # mixtral
-    dict(scoring="softmax", norm_topk=True),  # qwen2-moe
-    dict(scoring="softmax", norm_topk=True, n_group=4, topk_group=2, group_mode="max", scale=2.0),  # deepseek-v2
-    dict(scoring="sigmoid", norm_topk=True, n_group=4, topk_group=2, group_mode="top2sum", scale=2.5),  # deepseek-v3
-  ],
-)
-def test_moe_gather_path_matches_einsum_path(kwargs):
-  """The decode-time weight-gather path (T <= MOE_GATHER_MAX) computes the
-  same outputs as the batched dispatch/combine einsums, for every routing
-  variant."""
-  from xotorch_support_jetson_tpu.ops.moe import _moe_ffn_block, _moe_ffn_gather
+ROUTINGS = [
+  dict(scoring="softmax", norm_topk=False),  # mixtral
+  dict(scoring="softmax", norm_topk=True),  # qwen2-moe
+  dict(scoring="softmax", norm_topk=True, n_group=4, topk_group=2, group_mode="max", scale=2.0),  # deepseek-v2
+  dict(scoring="sigmoid", norm_topk=True, n_group=4, topk_group=2, group_mode="top2sum", scale=2.5),  # deepseek-v3
+]
 
+
+def _experts(rng, E, E_held, D, F, dtype=jnp.float32, layers=None):
+  lead = () if layers is None else (layers,)
+  w = lambda *shape: jnp.asarray(rng.normal(size=lead + shape) * 0.1, dtype)  # noqa: E731
+  return jnp.asarray(rng.normal(size=(D, E)), jnp.float32), w(E_held, D, F), w(E_held, D, F), w(E_held, F, D)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+  """The tests' switch (``ops/moe.py INTERPRET``): this CPU takes the grouped form, its kernels interpreted."""
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(moe, "INTERPRET", True)
+
+
+def _both_forms(x, w_router, w_gate, w_up, w_down, k, held=None, scales=None, layer=None, **routing):
+  """(block form, grouped form with its kernels interpreted) of one layer: each (out, aux, visited). The grouped form
+  takes the leaves as a stack (of one layer, where ``layer`` is None) — codes with their ``scales`` —, the block form
+  that layer's leaves, dequantised."""
+  from xotorch_support_jetson_tpu.models.quantize import dequantize_leaf
+  from xotorch_support_jetson_tpu.ops.moe import _moe_ffn_block, _moe_ffn_grouped
+
+  full = dict(scoring="softmax", norm_topk=False, selection_bias=None, scale=1.0, n_group=1, topk_group=1, group_mode="none")
+  full.update(routing)
+  stack = [w_gate, w_up, w_down]
+  if layer is None:
+    stack, scales, layer = [w[None] for w in stack], scales and tuple(s[None] for s in scales), 0
+  cut = lambda w: jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)  # noqa: E731
+  one = [cut(w) for w in stack]
+  if scales:
+    one = [dequantize_leaf(w, cut(s), w.shape[-2], x.dtype) for w, s in zip(one, scales)]
+  ref = _moe_ffn_block(x, w_router, *one, k, capacity_factor=None, held=held, **full)
+  got = _moe_ffn_grouped(x, w_router, *stack, k, held=held, scales=scales, layer=layer, **full)
+  return ref, got
+
+
+def _assert_same(ref, got, rtol=1e-5, atol=1e-5):
+  np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=rtol, atol=atol)
+  np.testing.assert_allclose(float(got[1]), float(ref[1]), rtol=1e-5)
+  assert int(got[2]) == int(ref[2])
+
+
+@pytest.mark.parametrize("kwargs", ROUTINGS)
+def test_moe_grouped_form_matches_block_form(kwargs, interpreted):
+  """The grouped form (sorted assignments, two Mosaic kernels, interpreted here) computes the same outputs, auxiliary
+  loss and count of experts visited as the dispatch/combine einsums, for every routing variant."""
   rng = np.random.default_rng(17)
   E, D, F, k = 8, 16, 24, 3
-  w_router = jnp.asarray(rng.normal(size=(D, E)), jnp.float32)
-  w_gate = jnp.asarray(rng.normal(size=(E, D, F)) * 0.1, jnp.float32)
-  w_up = jnp.asarray(rng.normal(size=(E, D, F)) * 0.1, jnp.float32)
-  w_down = jnp.asarray(rng.normal(size=(E, F, D)) * 0.1, jnp.float32)
+  w_router, w_gate, w_up, w_down = _experts(rng, E, E, D, F)
   bias = jnp.asarray(rng.normal(size=(E,)) * 0.1, jnp.float32) if kwargs["scoring"] == "sigmoid" else None
-  full = dict(scoring="softmax", norm_topk=False, selection_bias=bias, scale=1.0, n_group=1, topk_group=1, group_mode="none")
-  full.update(kwargs)
   for T in (1, 2, 4):
     x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
-    ref, aux_ref = _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, capacity_factor=None, **full)
-    got, aux_got = _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, **full)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(float(aux_got), float(aux_ref), rtol=1e-5)
+    _assert_same(*_both_forms(x, w_router, w_gate, w_up, w_down, k, selection_bias=bias, **kwargs))
+
+
+@pytest.mark.parametrize(
+  "what,T,E,k,held",
+  [
+    ("one token: a row tile of 16 with 13 rows of padding behind 3 groups", 1, 8, 3, None),
+    ("a decode step of 64 rows: four row tiles, every expert's group inside or across them", 64, 16, 8, None),
+    ("T·k no multiple of the tile: 201 rows, 55 of padding", 67, 8, 3, None),
+    ("two experts: each group spans several row tiles", 200, 2, 2, None),
+    ("empty groups: 4 assignments over 32 experts", 2, 32, 2, None),
+    ("a held range with choices outside it, some rows with none inside", 40, 16, 2, (4, 9)),
+    ("a held range nobody chose: no visit at all", 3, 16, 1, (15, 16)),
+  ],
+)
+def test_moe_grouped_form_over_tiles_groups_and_held_ranges(what, T, E, k, held, interpreted):
+  rng = np.random.default_rng(T * 31 + E)
+  D, F = 16, 24
+  E_held = E if held is None else held[1] - held[0]
+  w_router, w_gate, w_up, w_down = _experts(rng, E, E_held, D, F)
+  if what.startswith("a held range nobody"):
+    w_router = w_router.at[:, 15].set(-w_router[:, :15].sum(axis=1))  # expert 15 scores under every other where they score high
+  x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+  ref, got = _both_forms(x, w_router, w_gate, w_up, w_down, k, held=held, norm_topk=True)
+  _assert_same(ref, got)
+  if what.startswith("empty groups"):
+    assert int(got[2]) <= 4
+  if held is not None:
+    whole = _both_forms(x, w_router, *_experts(rng, E, E, D, F)[1:], k, norm_topk=True)[0]
+    assert int(got[2]) <= min(E_held, int(whole[2]))
+
+
+def test_moe_grouped_form_leaves_garbage_rows_out_by_where(interpreted):
+  """Rows of an expert not held hold whatever the kernels found (here: NaN from an uninitialised output block): the
+  result has none of it."""
+  rng = np.random.default_rng(5)
+  w_router, w_gate, w_up, w_down = _experts(rng, 16, 4, 16, 24)
+  x = jnp.asarray(rng.normal(size=(9, 16)), jnp.float32)
+  ref, got = _both_forms(x, w_router, w_gate, w_up, w_down, 2, held=(0, 4))
+  assert np.all(np.isfinite(np.asarray(got[0])))
+  _assert_same(ref, got)
+
+
+@pytest.mark.parametrize("T", [1, 16, 130])
+def test_moe_grouped_form_takes_int8_codes_and_a_stacks_layer(T, interpreted):
+  """int8 leaves go into the kernels as codes, their per-output-channel scales multiply the products' rows; the leaves
+  are a stack's and the layer a traced scalar. Against the block form over the dequantised layer."""
+  from xotorch_support_jetson_tpu.models.quantize import quantize_weight
+
+  rng = np.random.default_rng(T)
+  L, E, D, F, k = 3, 8, 32, 64, 2
+  w_router, *ws = _experts(rng, E, E, D, F, layers=L)
+  (g, sg), (u, su), (d, sd) = (quantize_weight(w) for w in ws)
+  assert g.dtype == jnp.int8 and sg.shape == (L, E, F) and sd.shape == (L, E, D)
+  x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+  for layer in (0, 2):
+    ref, got = jax.jit(lambda layer: _both_forms(x, w_router, g, u, d, k, scales=(sg, su, sd), layer=layer, norm_topk=True))(jnp.int32(layer))
+    _assert_same(ref, got, rtol=2e-5, atol=2e-5)
+    alone = _both_forms(x, w_router, g[layer], u[layer], d[layer], k, scales=(sg[layer], su[layer], sd[layer]), norm_topk=True)[1]
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(alone[0]), rtol=1e-6, atol=1e-6)
+
+
+def test_moe_ffn_cuts_a_long_run_into_grouped_pieces(monkeypatch, interpreted):
+  """A run longer than ``GROUPED_MAX_TOKENS`` goes through the grouped form in pieces, by tokens: same result as the
+  block form's blocks; the count of visits is summed over the pieces. ``moe_ffn`` takes the grouped form where it is
+  handed a stack and a layer, the block form where it is handed a layer's leaves."""
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(moe, "GROUPED_MAX_TOKENS", 32)
+  rng = np.random.default_rng(3)
+  E, D, F, k, T = 8, 128, 128, 2, 80
+  w_router, w_gate, w_up, w_down = _experts(rng, E, E, D, F, layers=2)
+  x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+  assert moe.ffn_form(w_gate, w_down, None, True) == "grouped"
+  kernels = lambda layer: str(jax.make_jaxpr(lambda x: moe.moe_ffn(x, w_router, *layer, k=k))(x)).count("pallas_call")  # noqa: E731
+  assert kernels((w_gate[1], w_up[1], w_down[1])) == 0
+  assert str(jax.make_jaxpr(lambda x: moe.moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, layer=1))(x)).count("pallas_call") == 2 * 3  # three pieces
+  got = moe.moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, layer=1)
+  ref = moe.moe_ffn(x, w_router, w_gate[1], w_up[1], w_down[1], k=k, chunk=32)
+  np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
+  pieces = [moe._moe_ffn_block(x[at : at + 32], w_router, w_gate[1], w_up[1], w_down[1], k, "softmax", False, None, 1.0, None, 1, 1, "none") for at in (0, 32, 64)]
+  assert int(got[2]) == int(ref[2]) == sum(int(p[2]) for p in pieces)
+  np.testing.assert_allclose(float(got[1]), sum(float(p[1]) * n for p, n in zip(pieces, (32, 32, 16))) / T, rtol=1e-5)
+
+
+BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
+
+
+@pytest.mark.parametrize(
+  "what,gate,down,dtype,capacity_factor,mosaic_kernels,scaled,want",
+  [
+    ("Ling's held experts on a TPU", (6, 128, 2560, 768), (6, 128, 768, 2560), BF16, None, True, False, "grouped"),
+    ("Moonlight's int8 codes with scales", (13, 64, 2048, 1408), (13, 64, 1408, 2048), I8, None, True, True, "grouped"),
+    ("one layer's float32 leaves", (8, 128, 256), (8, 256, 128), F32, None, True, False, "grouped"),
+    ("mixtral's experts: a column block of 512 of the 14336", (32, 8, 4096, 14336), (32, 8, 14336, 4096), BF16, None, True, False, "grouped"),
+    ("a capacity factor: assignments may drop", (6, 128, 2560, 768), (6, 128, 768, 2560), BF16, 1.25, True, False, "block"),
+    ("a plan that leaves a mesh axis to GSPMD: no Mosaic kernel", (6, 128, 2560, 768), (6, 128, 768, 2560), BF16, None, False, False, "block"),
+    ("int8 codes without scales", (13, 64, 2048, 1408), (13, 64, 1408, 2048), I8, None, True, False, "block"),
+    ("packed int4: half the rows", (13, 64, 1024, 1408), (13, 64, 704, 2048), I8, None, True, True, "block"),
+    ("the tests' widths: no whole lane group", (4, 64, 32), (4, 32, 64), F32, None, True, False, "block"),
+    ("bfloat16 leaves with scales", (4, 128, 128), (4, 128, 128), BF16, None, True, True, "block"),
+  ],
+)
+def test_the_expert_form_is_read_from_what_the_program_sees(what, gate, down, dtype, capacity_factor, mosaic_kernels, scaled, want, monkeypatch):
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  w_gate, w_down = jax.ShapeDtypeStruct(gate, dtype), jax.ShapeDtypeStruct(down, dtype)
+  assert moe.ffn_form(w_gate, w_down, capacity_factor, mosaic_kernels, scaled) == want, what
+  assert want in moe.FFN_FORMS
+
+
+@pytest.mark.parametrize("platform,mosaic_kernels,want", [("tpu", True, "grouped"), ("tpu", False, "block"), ("cpu", True, "block"), ("gpu", True, "block")])
+def test_the_expert_form_follows_the_platform_and_the_plan(platform, mosaic_kernels, want, monkeypatch):
+  """The experts' kernels are for a TPU, and for a plan with no mesh axis left to GSPMD (the engine clears
+  ``mosaic_kernels`` for such a plan): the layer loops and the gauge ask with the config's flag."""
+  from xotorch_support_jetson_tpu.models import decoder
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(jax, "default_backend", lambda: platform)
+  cfg = _moe_cfg(dim=128, moe_hidden_dim=128, mosaic_kernels=mosaic_kernels)
+  leaf = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+  stack = {"w_experts_gate": leaf(3, 4, 128, 128), "w_experts_up": leaf(3, 4, 128, 128), "w_experts_down": leaf(3, 4, 128, 128), "w_router": leaf(3, 128, 4)}
+  assert moe.ffn_form(stack["w_experts_gate"], stack["w_experts_down"], cfg.moe_capacity_factor, cfg.mosaic_kernels) == want
+  assert decoder.served_expert_form({"moe_layers": stack}, cfg) == want
+  assert decoder._whole_expert_leaves({"moe_layers": stack}, cfg) == (("w_experts_gate", "w_experts_up", "w_experts_down") if want == "grouped" else ())
+  assert decoder.served_expert_form(None, cfg) == "block"  # a ring holds its weights itself: its loops hand no stack over
+
+
+def test_nothing_of_the_gather_path_is_left():
+  import os
+
+  from xotorch_support_jetson_tpu.ops import moe
+
+  assert not hasattr(moe, "_moe_ffn_gather") and not hasattr(moe, "MOE_GATHER_MAX")
+  assert "XOT_TPU_MOE_GATHER" not in open(moe.__file__).read() and "XOT_TPU_MOE_GATHER" not in os.environ
+
+
+def _lane_wide_moe(quant: bool):
+  """A tiny MoE decoder whose expert faces are whole lane groups (so the predicate takes the grouped form once the
+  program is told it may), its weights, and prompts."""
+  from xotorch_support_jetson_tpu.models.quantize import quantize_params
+
+  cfg = _moe_cfg(dim=128, moe_hidden_dim=128, n_experts=8, n_active_experts=2, shared_expert_dim=32, max_seq_len=64 + int(quant))
+  params, shard = full_model_params(jax.random.PRNGKey(21), cfg, "moe-lanes")
+  return cfg, (quantize_params(params) if quant else params), shard
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float32", "int8"])
+def test_moe_decoder_programs_take_the_grouped_form_where_told(quant, monkeypatch):
+  """The prefill over a cache and the paged decode chunk of a dense-prefix + experts model, once with the block form
+  (what a CPU resolves) and once with the experts' kernels interpreted (``INTERPRET``): the same logits, the same
+  greedy tokens, the same count of expert visits — and the layer loops hand the stacked expert leaves over whole."""
+  from dataclasses import replace
+
+  from xotorch_support_jetson_tpu.models import decoder
+  from xotorch_support_jetson_tpu.ops import moe
+  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+  cfg, params, shard = _lane_wide_moe(quant)
+  assert decoder._whole_expert_leaves(params, cfg) == ()
+  tokens = jnp.asarray([[5, 9, 2, 7, 1, 3, 8, 4], [11, 12, 13, 14, 15, 16, 17, 18]], dtype=jnp.int32)
+  positions = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32), (2, 8))
+  PS = 8
+  bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+
+  def run(cfg):
+    logits, _ = shard_forward(params, cfg, shard, tokens, positions, init_kv_cache(cfg, cfg.n_layers, 2, 16))
+    pool = init_paged_pool(cfg, cfg.n_layers, 5, PS)
+    toks, _, _, _, seen = decoder.fused_paged_batch_decode(
+      params, cfg, shard, tokens[:, :1], pool, bt, jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool), jnp.zeros((2,), jnp.float32), 4, page_size=PS, use_kernel=False, experts_visited=True
+    )
+    return np.asarray(logits, np.float32), np.asarray(toks), int(seen)
+
+  ref_logits, ref_toks, ref_seen = run(cfg)
+  monkeypatch.setattr(moe, "INTERPRET", True)
+  told = replace(cfg, max_seq_len=cfg.max_seq_len + 2)  # another static config: the programs are traced anew
+  whole = decoder._whole_expert_leaves(params, told)
+  assert set(whole) == {name for name in params["moe_layers"] if name.startswith("w_experts_")} and len(whole) == (6 if quant else 3)
+  got_logits, got_toks, got_seen = run(told)
+  np.testing.assert_allclose(got_logits, ref_logits, rtol=2e-4, atol=2e-4)
+  assert got_toks.tolist() == ref_toks.tolist()
+  assert got_seen == ref_seen and 3 * 4 * 2 <= got_seen <= 3 * 4 * 4  # 3 expert layers x 4 steps, 2 rows x top 2
+
+
+def test_a_differentiated_forward_keeps_the_block_form(monkeypatch):
+  """Training and the cache-less forward hand no stack over whole: where the served programs take the grouped form
+  (here: interpreted), ``shard_forward`` without a cache and ``shard_forward_aux`` under ``jax.grad`` trace no kernel
+  — a ``pallas_call`` with scalar prefetch has no derivative — and give the block form's numbers."""
+  from xotorch_support_jetson_tpu.models import decoder
+  from xotorch_support_jetson_tpu.ops import moe
+
+  cfg, params, shard = _lane_wide_moe(False)
+  tokens = jnp.asarray([[5, 9, 2, 7, 1, 3, 8, 4]], dtype=jnp.int32)
+  positions = jnp.arange(8, dtype=jnp.int32)[None]
+
+  def loss(params):
+    logits, aux = decoder.shard_forward_aux(params, cfg, shard, tokens, positions)
+    return jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)) + 0.01 * aux
+
+  ref_loss, ref_grad = jax.value_and_grad(loss)(params)
+  ref_logits = shard_forward(params, cfg, shard, tokens, positions)[0]
+  monkeypatch.setattr(moe, "INTERPRET", True)
+  assert decoder.served_expert_form(params, cfg) == "grouped"
+  assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(loss))(params))
+  assert "pallas_call" not in str(jax.make_jaxpr(lambda p: shard_forward(p, cfg, shard, tokens, positions)[0])(params))
+  got_loss, got_grad = jax.value_and_grad(loss)(params)
+  assert float(got_loss) == float(ref_loss)
+  np.testing.assert_array_equal(np.asarray(got_grad["moe_layers"]["w_experts_down"]), np.asarray(ref_grad["moe_layers"]["w_experts_down"]))
+  assert float(jnp.abs(got_grad["moe_layers"]["w_experts_down"]).max()) > 0
+  np.testing.assert_array_equal(np.asarray(shard_forward(params, cfg, shard, tokens, positions)[0]), np.asarray(ref_logits))

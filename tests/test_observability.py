@@ -761,6 +761,9 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_recurrent_state_step",  # {form}: 1 on the rule and form the decode programs step that state in: one_pass / reference (Mamba-2, ISSUE 35), delta_reference (KDA, ISSUE 36)
   "xot_tpu_moe_experts_routed",  # the router's width of the loaded shard's expert layers (0: dense) (ISSUE 36)
   "xot_tpu_moe_experts_held",  # how many of those experts' weights the shard holds: fewer for one chip's share of an expert-parallel deployment (ISSUE 36)
+  "xot_tpu_moe_ffn_form",  # {form}: 1 on the form the routed experts' product takes in the pool's programs: grouped / block (ops/moe.py ffn_form, ISSUE 40)
+  "xot_tpu_moe_experts_visited_total",  # distinct held experts the decode rows chose, summed over expert layers and steps; over the next: the mean a layer and step (ISSUE 40)
+  "xot_tpu_moe_expert_layer_steps_total",  # expert layers x decode steps of the settled chunks (ISSUE 40)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
   # Multi-LoRA serving (ISSUE 15; swaps labeled {direction}, requests
   # labeled {adapter} — adapter names are client-asserted, same trust note
@@ -844,6 +847,9 @@ def test_metric_name_snapshot_after_serving():
   gm.set_gauge("kv_draft_bytes", 0)
   gm.set_gauge("recurrent_state_step", 0, labels={"form": "reference"})  # set when a pool with state leaves is made (ISSUE 35)
   gm.inc("recurrent_state_resets_total", 0)  # event-driven: only a configuration with recurrent layers resets a slot's state (ISSUE 34)
+  gm.set_gauge("moe_ffn_form", 0, labels={"form": "block"})  # set when a pool is made for a model with routed experts (ISSUE 40)
+  gm.inc("moe_experts_visited_total", 0)  # event-driven: only a model with routed experts visits any (ISSUE 40)
+  gm.inc("moe_expert_layer_steps_total", 0)
   gm.set_gauge("kv_draft_slots", 0)
   gm.set_gauge("kv_draft_pages_equivalent", 0)
   # Mixed ticks (ISSUE 14): a short solo drive never stages a chunked

@@ -359,14 +359,83 @@ def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
-def _ling_at_the_cells_settings(chip):
-  """(hf, cfg, params, pool) of ``ling-3.0-flash.decode-closed-64`` as shapes on the described chip."""
+@pytest.mark.parametrize(
+  "what,T,L,E,held,D,F,k,dtype",
+  [
+    ("Ling's decode step: 64 rows x top 8 of 512, 128 held, whole-expert blocks", 64, 6, 512, (0, 128), 2560, 768, 8, jnp.bfloat16),
+    ("Moonlight's decode step: int8 codes cast in VMEM, a scale row an expert", 16, 13, 64, None, 2048, 1408, 6, jnp.int8),
+    ("mixtral's widths: an expert's matrix is cut into column blocks of 512", 16, 2, 8, None, 4096, 14336, 2, jnp.bfloat16),
+  ],
+)
+def test_expert_kernels_compile_for_v5e(chip, what, T, L, E, held, D, F, k, dtype, monkeypatch):
+  """``ops/moe.py``'s grouped form alone (ISSUE 40): ``moe_gate_up`` and ``moe_down`` as Mosaic lowers them for a v5e —
+  a dynamic grid of visits, whole-expert (or column) blocks of the STACKED leaves at a layer scalar inside a 64 MiB
+  VMEM limit, the int8 cast — and nothing copies or cuts an expert leaf."""
+  from xotorch_support_jetson_tpu.ops import moe
+
+  E_held = E if held is None else held[1] - held[0]
+  scaled = dtype == jnp.int8
+  leaves = [_sds(chip, (L, E_held, D, F), dtype), _sds(chip, (L, E_held, D, F), dtype), _sds(chip, (L, E_held, F, D), dtype)]
+  scales = [_sds(chip, (L, E_held, F), jnp.float32), _sds(chip, (L, E_held, F), jnp.float32), _sds(chip, (L, E_held, D), jnp.float32)] if scaled else []
+  monkeypatch.setattr(moe, "_on_tpu", lambda: True)  # (the backend here is the CPU)
+  assert moe.ffn_form(leaves[0], leaves[2], None, True, scaled) == "grouped"
+
+  def layer(x, w_router, layer, w_gate, w_up, w_down, *scales):
+    return moe.moe_ffn(x, w_router, w_gate, w_up, w_down, k=k, held=held, scales=scales or None, layer=layer)
+
+  compiled = jax.jit(layer).lower(_sds(chip, (T, D), jnp.bfloat16), _sds(chip, (D, E), jnp.float32), _sds(chip, (), jnp.int32), *leaves, *scales).compile()
+  text = compiled.as_text()
+  calls = [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line for m in [re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)] if m]
+  assert sorted(calls) == ["moe_down", "moe_gate_up"], calls
+  stack = rf"{'s8' if scaled else 'bf16'}\[{L},{E_held},({D},{F}|{F},{D})\]"
+  assert {op for _, op in _takers(text, stack)} == {"custom-call"}
+  assert compiled.memory_analysis().temp_size_in_bytes < 64e6, what
+
+
+def test_training_a_lane_wide_moe_lowers_for_v5e(chip, monkeypatch):
+  """``jax.grad`` of ``shard_forward_aux`` (train/trainer.py, parallel/train_step.py) for a MoE whose expert faces are
+  whole lane groups — the served programs of the same weights take the grouped form on this chip — lowers and compiles
+  for a v5e: the cache-less forward hands no stack over whole, so its experts are the block form's einsums, which have
+  a derivative (a ``pallas_call`` with scalar prefetch and a dynamic grid has none: ``_pallas_call_jvp_rule`` raises
+  at trace time). The cache-less ``shard_forward`` holds no kernel either."""
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models import decoder
+  from xotorch_support_jetson_tpu.models.config import tiny_test_config
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(moe, "_on_tpu", lambda: True)  # (the backend here is the CPU)
+  cfg = tiny_test_config(dim=128, moe_hidden_dim=128, n_experts=8, n_active_experts=2, first_k_dense=1, n_layers=3, shared_expert_dim=128, dtype=jnp.bfloat16)
+  shard = Shard("moe-lanes", 0, cfg.n_layers - 1, cfg.n_layers)
+  params = jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), jax.eval_shape(lambda: decoder.full_model_params(jax.random.PRNGKey(0), cfg)[0]))
+  assert decoder.served_expert_form(params, cfg) == "grouped"
+  tokens, positions = _sds(chip, (2, 64), jnp.int32), _sds(chip, (2, 64), jnp.int32)
+
+  def loss(params, tokens, positions):
+    logits, aux = decoder.shard_forward_aux(params, cfg, shard, tokens, positions)
+    return jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)) + 0.01 * aux
+
+  text = jax.jit(jax.grad(loss)).lower(params, tokens, positions).compile().as_text()
+  assert "tpu_custom_call" not in text
+  text = jax.jit(lambda p, t, pos: decoder.shard_forward(p, cfg, shard, t, pos)[0]).lower(params, tokens, positions).compile().as_text()
+  assert "tpu_custom_call" not in text
+  # ... and the same weights over a cache do take the kernels
+  cache = jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), jax.eval_shape(lambda: decoder.init_kv_cache(cfg, cfg.n_layers, 2, 128)))
+  text = jax.jit(lambda p, t, pos, c: decoder.shard_forward(p, cfg, shard, t, pos, c)[0]).lower(params, tokens, positions, cache).compile().as_text()
+  assert {m for m in re.findall(r'/(\w+)/pallas_call"', text)} >= {"moe_gate_up", "moe_down"}
+
+
+def _ling_at_the_cells_settings(chip, monkeypatch):
+  """(hf, cfg, params, pool) of ``ling-3.0-flash.decode-closed-64`` as shapes on the described chip, its programs
+  told what they see on the chip: a TPU (``ops/moe.py ffn_form`` asks the backend, which is the CPU here)."""
   import json
   from dataclasses import replace
 
   from xotorch_support_jetson_tpu.models.config import config_from_hf
   from xotorch_support_jetson_tpu.models.decoder import full_model_params
+  from xotorch_support_jetson_tpu.ops import moe
   from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+  monkeypatch.setattr(moe, "_on_tpu", lambda: True)
 
   hf = json.loads((ROOT / "benchmark" / "configs" / "ling-3.0-flash-ep4-d7.json").read_text())
   n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"]["XOT_TPU_BATCH_PAGES"])
@@ -377,18 +446,20 @@ def _ling_at_the_cells_settings(chip):
   return hf, cfg, params, pool
 
 
-def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
+def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch):
   """Ling-3.0-flash's first stage at one chip's share, as ``ling-3.0-flash.decode-closed-64`` serves it (ISSUE 36): 64
   slots, 1537 latent pages of ONE attention layer, bf16, 128 of 512 experts held. ``decode.paged_batch`` is accepted by
   XLA:TPU beside 10.3 GB of weights, 0.81 GB of float32 matrix state and 0.11 GB of pages. The state leaf is one buffer
   from the donated argument to the result: no instruction copies it or a layer of it; it is read at (layer) by the
-  fusions of the delta step and written back in place. No Mosaic call: MLA takes the gather path and the delta rule
-  has the XLA expression only. No stacked expert leaf is copied or relaid (a copy of one is 3.8 GB)."""
+  fusions of the delta step and written back in place. The only Mosaic calls are the experts' two (ISSUE 40:
+  ``moe_gate_up``, ``moe_down``, in both stacks' loops — MLA takes the gather path and the delta rule has the XLA
+  expression only), and they take the STACKED expert leaves: no stacked expert leaf, and no layer of one, is copied,
+  cut out or relaid (a copy of a stack is 3.8 GB, of a layer 0.75 GB a step)."""
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
 
-  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip)
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch)
   n_slots = pool["ssm"].shape[1]
   assert pool["k"].shape == (1, 1537, 1, PS, 512) and pool["v"].shape == (1, 1537, 1, PS, 64) and pool["ssm"].shape == (6, 64, 32, 128, 128) and pool["conv"].shape == (6, 64, 3, 12288)
   assert params["ssm_moe_layers"]["w_experts_gate"].shape == (5, 128, 2560, 768) and params["moe_layers"]["w_router"].shape == (1, 2560, 512)
@@ -398,7 +469,11 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
     _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, False,
     _sds(chip, (2,), jnp.uint32), None,
   )  # fmt: skip
-  assert "tpu_custom_call" not in text
+  calls = [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line for m in [re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)] if m]
+  assert sorted(set(calls)) == ["moe_down", "moe_gate_up"], sorted(set(calls))
+  stack = r"bf16\[(5|1),128,(2560,768|768,2560)\]"
+  takers = {op for shape in (stack,) for _, op in _takers(text, shape)}
+  assert takers == {"custom-call"}, takers  # the kernels alone take the stacks: no fusion cuts a layer out of one
   state = r"f32\[(6,)?64,32,128,128\]"
   copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {state}\S* (copy|copy-start|transpose)\(", line)]
   assert not copied, copied
@@ -410,22 +485,26 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
-def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip):
+def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip, monkeypatch):
   """The largest prefill program the cell meets — a group of 8 rows padded to 1024 tokens, ``prefill.pages_many_sampled``
   with the pool donated — fits beside the weights and the state: the chunked delta rule's float32 operands, the expert
-  layer's dispatch at 256 tokens a block and the latent attention's scores of 256 queries at a time (whole, they are
-  4 GB twice over and the compiler refuses the program) are its temporaries."""
+  layer's sorted rows and products of 4096 tokens a piece (ISSUE 40: two pieces here, 32,768 assignments each) and the
+  latent attention's scores of 256 queries at a time (whole, they are 4 GB twice over and the compiler refuses the
+  program) are its temporaries. No piece copies an expert leaf or a layer of one."""
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models.decoder import prefill_into_pages_many_sampled_inplace
 
-  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip)
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch)
   K, S = 8, 1024
   rows = _rows(chip, K)
-  compiled, _text = _compile(
+  compiled, text = _compile(
     prefill_into_pages_many_sampled_inplace, params, cfg, Shard("ling", 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (K, S), jnp.int32), pool,
     _sds(chip, (K, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
   )  # fmt: skip
+  experts = [line.strip()[:160] for line in text.splitlines() if re.search(r"= bf16\[(5,|1,)?128,(2560,768|768,2560)\]\S* (copy|copy-start|transpose|fusion|dynamic-slice)\(", line)]
+  assert not experts, experts
+  assert text.count('custom_call_target="tpu_custom_call"') >= 8  # (gate/up, down) x 2 pieces x 2 stacks' loops
   mem = compiled.memory_analysis()
   print(f"prefill.pages_many_sampled ling K=8 S=1024: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

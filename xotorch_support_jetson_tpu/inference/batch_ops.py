@@ -241,6 +241,7 @@ class DecoderBatchOps(_PageCopyMixin):
     return fused_paged_batch_decode(
       eng.params, eng.cfg, eng._effective_shard, token, pool, block_tables, positions, active, temps, n_steps,
       top_k=top_ks, k_max=k_max, page_size=page_size, key=key, adapter_ids=adapter_ids,
+      experts_visited=True,  # a fifth result: the chunk's count of expert visits, read back beside its tokens
     )
 
   # ------------------------------------------------- mixed tick (ISSUE 14)
@@ -261,6 +262,7 @@ class DecoderBatchOps(_PageCopyMixin):
       eng.params, eng.cfg, eng._effective_shard, token, pool, block_tables, positions, active, temps,
       pf_tokens, pf_bt, pf_prefix, pf_end, n_steps,
       top_k=top_ks, k_max=k_max, page_size=page_size, key=key, adapter_ids=adapter_ids, pf_adapter=pf_adapter,
+      experts_visited=True,
     )
 
 

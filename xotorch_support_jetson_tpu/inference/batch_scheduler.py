@@ -301,6 +301,9 @@ class _Chunk:
   mixed_ready: object = None  # _Ready | None
   mixed_start: int = 0
   mixed_end: int = 0
+  # Device int32 scalar: the distinct held experts the chunk's rows chose, summed over its expert layers and steps
+  # (ops/moe.py): read back with the tokens, it feeds ``moe_experts_visited_total``. None: a program that does not count.
+  experts_visited: object = None
 
 
 # A server of more than ``GROUP_SLOTS_WHOLE`` slots holds a prefill group to ``GROUP_ROWS`` rows, so that its prefill
@@ -321,6 +324,7 @@ class BatchedServer:
     self.ops = engine.batch_ops
     self.n_slots = self.ops.round_slots(n_slots or int(os.getenv("XOT_TPU_BATCH_SLOTS", "4")))
     self.chunk = chunk or int(os.getenv("XOT_TPU_BATCH_CHUNK", "8"))
+    self._expert_layers = 0  # expert layers a decode step passes: set with the pool (``_note_expert_form``)
     # Per-request top_k IS honored (traced per row, like temperature —
     # ops/sampling.py sample_logits_per_row); only the candidate-set cap
     # ``k_max`` is static in the compiled program. Requests asking for more
@@ -914,6 +918,7 @@ class BatchedServer:
         form = state_step_form(self.cache["ssm"], paged_kernel_supported(eng.cfg), eng.cfg.recurrent_kind)
         for name in STATE_STEP_FORMS:
           metrics.set_gauge("recurrent_state_step", int(name == form), labels={"form": name})
+      self._note_expert_form()
       from .kv_tier import KvTierManager, kv_tier_enabled
 
       if recurrent:
@@ -2418,6 +2423,7 @@ class BatchedServer:
       # is 0.5-1 ms of it, the engine's handling before it 1.4-2.7 ms).
       with _phase("stage", tick=tick, rows=int(active.sum())):
         counts = pos_dev = n_prop = None
+        seen = ()  # a solo engine's plain and mixed programs also return their count of expert visits (a pp / sp ring's do not)
         # The draft cache rides the dispatch only when a MODEL-drafted row is
         # in it (ISSUE 12): n-gram/plain-only chunks compile the draft-free
         # program — no draft rounds, no donated draft cache (it stays valid
@@ -2443,7 +2449,7 @@ class BatchedServer:
           # AND the staged admission's prefill by its budgeted slice (the
           # slice carries ITS OWN adapter index — pf_adapter — so a mixed
           # tick's prefill half applies the admission's adapter per-row too).
-          toks, next_tok, _pos, self.cache = self.ops.mixed_paged_batch_decode(
+          toks, next_tok, _pos, self.cache, *seen = self.ops.mixed_paged_batch_decode(
             jnp.asarray(tokens), self.cache, jnp.asarray(self.block_tables), jnp.asarray(positions),
             jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks), self.chunk,
             k_max=self.k_max, page_size=self.page_size, key=sub,
@@ -2451,7 +2457,7 @@ class BatchedServer:
             **({**lora_kw, "pf_adapter": np.asarray([getattr(mixed_r.req, "adapter_slot", 0)], np.int32)} if lora_kw else {}),
           )
         elif self.paged:
-          toks, next_tok, _pos, self.cache = self.ops.paged_batch_decode(
+          toks, next_tok, _pos, self.cache, *seen = self.ops.paged_batch_decode(
             jnp.asarray(tokens), self.cache, jnp.asarray(self.block_tables), jnp.asarray(positions),
             jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks), self.chunk,
             k_max=self.k_max, page_size=self.page_size, key=sub, **lora_kw,
@@ -2471,7 +2477,7 @@ class BatchedServer:
             n_prop.copy_to_host_async()
         except AttributeError:  # backend without async copies
           pass
-        return toks, next_tok, counts, pos_dev, n_prop
+        return toks, next_tok, counts, pos_dev, n_prop, seen[0] if seen else None
 
     if plan.starved:
       metrics.inc("scheduler_page_starved_total", len(plan.starved))
@@ -2487,14 +2493,30 @@ class BatchedServer:
       mixed_ready=mixed_r, mixed_start=m_start, mixed_end=m_end,
     )
 
+  def _note_expert_form(self) -> None:
+    """Which form the routed experts' product takes in this pool's programs (models/decoder.py ``served_expert_form``:
+    the layer loops' own decision, asked once), and how many expert layers a decode step passes. A pipelined or
+    sequence-parallel ring holds the weights itself (``engine.params`` is None): block form, and its programs do not
+    count their visits."""
+    from ..models.decoder import served_expert_form
+    from ..ops.moe import FFN_FORMS
+
+    cfg, params = self.engine.cfg, getattr(self.engine, "params", None)
+    self._expert_layers = sum(stack["w_experts_gate"].shape[0] for stack in (params or {}).values() if isinstance(stack, dict) and "w_experts_gate" in stack)
+    if not cfg.n_experts:
+      return
+    form = served_expert_form(params, cfg)
+    for name in FFN_FORMS:
+      metrics.set_gauge("moe_ffn_form", int(name == form), labels={"form": name})
+
   async def _dispatch_decode(self, plan: _Plan, inflight: _Chunk | None) -> _Chunk:
     tick = self._next_tick()
     with _phase("stage", tick=tick, rows=int(plan.active.sum())):
       run, rids, record = self._stage_decode(plan, inflight, tick)
-    toks, next_tok, counts, pos_dev, n_prop = await asyncio.get_event_loop().run_in_executor(
+    toks, next_tok, counts, pos_dev, n_prop, experts_visited = await asyncio.get_event_loop().run_in_executor(
       self.engine.executor, self._attributed(run, rids, tick)
     )
-    return _Chunk(toks=toks, next_tok=next_tok, counts=counts, pos_dev=pos_dev, n_prop=n_prop, tick=tick, **record)
+    return _Chunk(toks=toks, next_tok=next_tok, counts=counts, pos_dev=pos_dev, n_prop=n_prop, experts_visited=experts_visited, tick=tick, **record)
 
   def _note_spec_settle(self, row: int, slot: _Slot, record: _Chunk, avail: int, emitted: int, proposed: int) -> None:
     """Per-row spec-chunk bookkeeping at the settle: per-proposer acceptance
@@ -2549,16 +2571,24 @@ class BatchedServer:
     acceptance drives its EWMA → next-depth policy here, at the settle."""
     eng = self.engine
 
+    counted = record.experts_visited is not None  # a program that counts its expert visits: a solo engine's, of a model with experts
+
     def fetch():
       with _phase("readback", tick=record.tick):  # waits for the chunk's program
         return (
           np.asarray(record.toks),
           np.asarray(record.counts) if record.counts is not None else None,
           np.asarray(record.n_prop) if record.n_prop is not None else None,
+          int(record.experts_visited) if counted else 0,
         )
 
-    rows_host, counts_host, n_prop_host = await asyncio.get_event_loop().run_in_executor(eng.executor, fetch)
+    rows_host, counts_host, n_prop_host, visited = await asyncio.get_event_loop().run_in_executor(eng.executor, fetch)
     with _phase("settle", tick=record.tick):
+      if counted:
+        # Their quotient is the mean number of distinct held experts a decode step visits in one expert layer: how
+        # far the grouped form (``moe_ffn_form``) engages — it reads those and no other.
+        metrics.inc("moe_experts_visited_total", visited)
+        metrics.inc("moe_expert_layer_steps_total", self._expert_layers * self.chunk)
       self._settle_host(record, rows_host, counts_host, n_prop_host)
 
   def _settle_host(self, record: _Chunk, rows_host, counts_host, n_prop_host) -> None:

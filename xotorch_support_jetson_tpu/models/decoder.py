@@ -499,44 +499,48 @@ def _residual(h, out, cfg: ModelConfig):
 
 
 def _mlp_block(h, p, cfg: ModelConfig):
-  """Post-attention norm + FFN (dense or MoE+shared-expert). Returns (h, aux)."""
+  """Post-attention norm + FFN (dense or MoE+shared-expert). Returns (h, aux, visited): the router's auxiliary loss and
+  the number of distinct held experts the rows chose (0 and 0 for a dense FFN)."""
   B, S, D = h.shape
   with jax.named_scope("xot.ffn"):
     x = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-  aux = jnp.float32(0.0)
+  aux, visited = jnp.float32(0.0), jnp.int32(0)
   if "w_experts_gate" in p:  # routed MoE FFN (ops/moe.py) + optional shared expert
     from ..ops.moe import moe_ffn
 
-    def expert_w(name):
-      # int8/int4 expert weights: dequantize next to the einsum (XLA fuses
-      # the scale multiply into the operand read — w8a16-style).
-      w = p[name]
-      if f"{name}_scale" in p:
+    names = ("w_experts_gate", "w_experts_up", "w_experts_down")
+    xt = x.reshape(B * S, D)
+    if "expert_layer" in p:
+      # The grouped form: the layer loop asked ops/moe.py ``ffn_form`` and handed the stack's expert leaves over whole
+      # (``_whole_expert_leaves``) — codes as stored with their scale leaves, and the layer to take.
+      experts = [p[name] for name in names]
+      form = dict(layer=p["expert_layer"], scales=tuple(p[f"{name}_scale"] for name in names) if f"{names[0]}_scale" in p else None)
+    else:
+      # The block form, over this layer's leaves: int8/int4 expert weights are dequantized next to the einsum (XLA
+      # fuses the scale multiply into the operand read — w8a16-style).
+      def expert_w(name):
+        if f"{name}_scale" not in p:
+          return p[name]
         from .quantize import dequantize_leaf
 
-        in_dim = cfg.moe_hidden_dim if name == "w_experts_down" else D
-        return dequantize_leaf(w, p[f"{name}_scale"], in_dim, h.dtype)
-      return w
+        return dequantize_leaf(p[name], p[f"{name}_scale"], cfg.moe_hidden_dim if name == "w_experts_down" else D, h.dtype)
 
-    xt = x.reshape(B * S, D)
-    with jax.named_scope("xot.moe_experts"):  # the dequantised expert slabs are the experts' cost
-      w_gate, w_up, w_down = expert_w("w_experts_gate"), expert_w("w_experts_up"), expert_w("w_experts_down")
-    out, aux = moe_ffn(
+      with jax.named_scope("xot.moe_experts"):  # the dequantised expert slabs are the experts' cost
+        experts, form = [expert_w(name) for name in names], {}
+    out, aux, visited = moe_ffn(
       xt,
       p["w_router"],
-      w_gate,
-      w_up,
-      w_down,
+      *experts,
       k=cfg.n_active_experts,
       scoring=cfg.router_scoring,
       norm_topk=cfg.norm_topk_prob,
       selection_bias=p.get("router_bias"),
       scale=cfg.routed_scaling_factor,
       capacity_factor=cfg.moe_capacity_factor,
-      return_aux=True,
       n_group=cfg.n_group,
       topk_group=cfg.topk_group,
       group_mode=cfg.group_mode,
+      **form,
       **({"held": cfg.experts_held} if cfg.experts_held else {}),
     )
     if "w_shared_gate" in p:
@@ -554,7 +558,7 @@ def _mlp_block(h, p, cfg: ModelConfig):
       if "post_mlp_norm" in p:  # gemma2 post-feedforward layernorm
         out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
       h = _residual(h, out, cfg)
-  return h, aux
+  return h, aux, visited
 
 
 # ------------------------------------------------ state-space (Mamba-2) mixer
@@ -681,7 +685,7 @@ def _ssm_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
     conv = _conv_tail(xp, S, seq_lens)
     y, ssm = _ssm_chunk_scan(x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), bm, cm, ssm0, cfg.ssm_chunk)
     y = _ssm_gate(y, x.astype(jnp.float32), z, p, cfg)
-  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  h, *_ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
   return h, ssm, conv.astype(conv0.dtype)
 
 
@@ -691,7 +695,8 @@ def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bo
   ``conv`` [Ls,B,K-1,C] are read and written in place at ``layer``. A row
   that is not ``active`` keeps both leaves bit for bit. The state's own step
   — decay, increment, contraction with C — is ``ops/ssm.py ssm_state_step``'s,
-  which passes over the leaf once where ``use_kernel`` and the leaf allow."""
+  which passes over the leaf once where ``use_kernel`` and the leaf allow.
+  Returns (h, pool, the experts its FFN visited: ``_mlp_block``)."""
   from ..ops.ssm import ssm_state_step
 
   z, xbc, dt = _ssm_in(h, p, cfg)
@@ -705,8 +710,8 @@ def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bo
     ssm, y = ssm_state_step(pool["ssm"], layer, a, dt[:, :, None] * x, bm.astype(jnp.float32), cm.astype(jnp.float32), active, use_kernel)
     pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
     y = _ssm_gate(y[:, None], x[:, None], z, p, cfg)
-  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
-  return h, pool
+  h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, pool, visited
 
 
 # ------------------------------------------- Kimi Delta Attention (KDA) mixer
@@ -816,13 +821,14 @@ def _kda_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
     conv = _conv_tail(xp, S, seq_lens)
     y, ssm = _kda_chunk_scan(q, k, v, g, beta, ssm0, cfg.ssm_chunk)
     y = _kda_out(y, gate, p, cfg, h.dtype)
-  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  h, *_ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
   return h, ssm, conv.astype(conv0.dtype)
 
 
 def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
   """One delta-rule step of one KDA layer for every slot row, as ``_ssm_decode_step``: the leaves ``ssm`` and ``conv``
-  of ``pool`` are read and written in place at ``layer``; a row that is not ``active`` keeps both bit for bit."""
+  of ``pool`` are read and written in place at ``layer``; a row that is not ``active`` keeps both bit for bit. Returns
+  (h, pool, the experts its FFN visited)."""
   from ..ops.ssm import kda_state_step
 
   qkv, f, bg = _kda_in(h, p, cfg)
@@ -833,8 +839,8 @@ def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
     ssm, y = kda_state_step(pool["ssm"], layer, jnp.exp(g), beta, k, v, q, active)
     pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
     y = _kda_out(y[:, None], gate[:, None], p, cfg, h.dtype)
-  h, _ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
-  return h, pool
+  h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, pool, visited
 
 
 # A hybrid's latent-attention layers take a prefill's queries this many positions at a time (ops/attention.py
@@ -880,7 +886,9 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
       carry = {**carry, "ssm": carry["ssm"].at[layer, slot_rows].set(ssm.astype(carry["ssm"].dtype), mode="drop"), "conv": carry["conv"].at[layer, slot_rows].set(conv, mode="drop")}
     return h, carry
 
-  return _scan_layers_over_pool(step, h, _layer_runs(params, cfg), carry)
+  # (a cache-less forward may be differentiated — training — and the experts' kernels have no derivative: it hands no
+  # stack over whole, so its layers take the block form)
+  return _scan_layers_over_pool(step, h, _layer_runs(params, cfg), carry, _whole_expert_leaves(params, cfg) if carry else ())
 
 
 def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: ModelConfig, use_cache: bool, attn_fn=None, adapter_ids=None, mla_q_block: int = 0):
@@ -988,7 +996,7 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
     if "post_attn_norm" in p:  # gemma2 post-attention layernorm
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
     h = _residual(h, attn_out, cfg)
-  h, aux = _mlp_block(h, p, cfg)
+  h, aux, _ = _mlp_block(h, p, cfg)
   return h, kv, aux
 
 
@@ -1069,18 +1077,20 @@ def shard_forward(
   elif use_cache:
     parts = []
     off = 0
+    whole = _whole_expert_leaves(params, cfg)  # (the cache-less forward below may be differentiated: block form)
     for stack in stacks:
       L = next(iter(stack.values())).shape[0]
+      sliced, put = _split_whole(stack, whole)
 
-      def body(carry, per_layer):
+      def body(carry, per_layer, put=put):
         h = carry
-        lp, kv = per_layer
-        h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, True, adapter_ids=adapter_ids)
+        lp, kv, at = per_layer
+        h, kv, _ = _layer_step(h, put(lp, at), kv, positions, kv_positions, inv_freq, cfg, True, adapter_ids=adapter_ids)
         return h, kv
 
       with jax.named_scope("xot.kv_write"):  # a model of two stacks splits the cache per stack and joins it again: whole-cache copies
         sub = {key: val[off : off + L] for key, val in kv_cache.items()}
-      h, new_sub = jax.lax.scan(body, h, (stack, sub))
+      h, new_sub = jax.lax.scan(body, h, (sliced, sub, jnp.arange(L, dtype=jnp.int32)))
       parts.append(new_sub)
       off += L
     with jax.named_scope("xot.kv_write"):
@@ -1639,10 +1649,41 @@ def fused_batch_decode(params, cfg: ModelConfig, shard: Shard, token, cache, pos
 # and the attention reads its pages by (layer, page).
 
 
-def _scan_layers_over_pool(step, h, stacks, pool: Params):
+def _whole_expert_leaves(params: Params, cfg: ModelConfig) -> tuple:
+  """The names of the stacked leaves a layer loop over ``params`` must not slice: the expert leaves (and their scale
+  leaves), where the experts' product takes the grouped form (ops/moe.py ``ffn_form``, asked of the leaves as they
+  are) — its kernels index the stack by layer, and a layer cut out ahead of a custom call would be a copy of the
+  layer's experts, every step. () for the block form, whose einsums read a scan's slice in place. This is where a
+  program's form is decided: a layer that gets these leaves whole (``_split_whole``) takes the grouped form, any other
+  the block form (``_mlp_block``)."""
+  from ..ops.moe import ffn_form
+
+  for stack in (params[name] for name in ("moe_layers", "ssm_moe_layers") if name in params):
+    if ffn_form(stack["w_experts_gate"], stack["w_experts_down"], cfg.moe_capacity_factor, cfg.mosaic_kernels, "w_experts_gate_scale" in stack) == "grouped":
+      return tuple(name for name in stack if name.startswith("w_experts_"))
+  return ()
+
+
+def served_expert_form(params: Params | None, cfg: ModelConfig) -> str:
+  """The form the experts' product takes in the programs that serve ``params`` over a cache or a pool (prefill, decode
+  chunk, mixed tick): the label of the gauge ``moe_ffn_form``. Without params — a ``--pp`` / ``--sp`` ring holds its
+  stages' weights itself, and its layer loops hand no stack over whole — the block form."""
+  return "grouped" if params and _whole_expert_leaves(params, cfg) else "block"
+
+
+def _split_whole(stack: Params, whole: tuple):
+  """(the leaves a layer loop slices, ``put(lp, at)``: a layer's parameters with the ``whole`` leaves of the stack put
+  beside them unsliced and ``expert_layer`` = ``at``, the layer's index in the stack, to take them at)."""
+  kept = {name: stack[name] for name in whole if name in stack}
+  if not kept:
+    return stack, lambda lp, at: lp
+  return {name: leaf for name, leaf in stack.items() if name not in kept}, lambda lp, at: {**lp, **kept, "expert_layer": at}
+
+
+def _scan_layers_over_pool(step, h, stacks, pool: Params, whole: tuple = ()):
   """Run ``step(h, pool, layer_params, layer) → (h, pool)`` over the layers of
   ``stacks`` (stacked-parameter dicts, in model order) with the STACKED pool
-  in the loop's carry.
+  in the loop's carry (``h`` may be any pytree the step carries beside it).
 
   The pool is never a scan ``xs``/``ys``: as ``xs`` every layer's whole
   [P, Hkv, ps, hd] slice was cut out of the stacked leaf and as ``ys`` written
@@ -1660,25 +1701,31 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params):
   index among the pool's layers of its kind, and the layer's parameters are
   read at their index in the stack inside the loop, as a scan reads its
   ``xs``: a slice of the stack cut out beforehand would be a copy of the run's
-  weights."""
+  weights.
 
-  def body(carry, per_layer):
-    lp, layer = per_layer
-    return step(*carry, lp, layer), None
-
+  ``whole`` names leaves no layer is cut out of (``_whole_expert_leaves``): the
+  step gets them as the stack has them, with the layer's index in the stack as
+  ``expert_layer``."""
   first = 0
   for stack in stacks:
     if isinstance(stack, tuple):
       stack, lo, hi, pool_lo = stack
+      sliced, put = _split_whole(stack, whole)
 
-      def run_body(carry, at, stack=stack, shift=pool_lo - lo):
-        lp = {name: jax.lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) for name, leaf in stack.items()}
-        return step(*carry, lp, at + shift if shift else at), None
+      def run_body(carry, at, sliced=sliced, put=put, shift=pool_lo - lo):
+        lp = {name: jax.lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) for name, leaf in sliced.items()}
+        return step(*carry, put(lp, at), at + shift if shift else at), None
 
       (h, pool), _ = jax.lax.scan(run_body, (h, pool), jnp.arange(lo, hi, dtype=jnp.int32))
       continue
     n = next(iter(stack.values())).shape[0]
-    (h, pool), _ = jax.lax.scan(body, (h, pool), (stack, first + jnp.arange(n, dtype=jnp.int32)))
+    sliced, put = _split_whole(stack, whole)
+
+    def body(carry, per_layer, put=put, first=first):
+      lp, layer = per_layer
+      return step(*carry, put(lp, layer - first), layer), None
+
+    (h, pool), _ = jax.lax.scan(body, (h, pool), (sliced, first + jnp.arange(n, dtype=jnp.int32)))
     first += n
   return h, pool
 
@@ -1735,7 +1782,8 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
   form on the kernel path — ops/paged.py ``kernel_pool_form``), ``layer``
   this layer's index into it; positions [B, 1]. ``kv_quant`` names the
   pool's mode where its shapes cannot (the kernel's form pads the code
-  axis); None reads it off the stored shapes. Returns (h, pool).
+  axis); None reads it off the stored shapes. Returns (h, pool, the experts
+  its FFN visited: ``_mlp_block``).
   """
   B, S, D = h.shape
   with jax.named_scope("xot.attn_proj"):
@@ -1771,39 +1819,46 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
     if "post_attn_norm" in p:  # gemma2
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
     h = _residual(h, attn_out, cfg)
-  h, _ = _mlp_block(h, p, cfg)
-  return h, pool
+  h, _, visited = _mlp_block(h, p, cfg)
+  return h, pool, visited
 
 
 def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool, adapter_ids=None, kv_quant: str | None = None, active=None):
   """One decode step for all rows against the page pool.
 
-  tokens [B, 1] int32 → (logits [B, 1, V], updated pool). Full shard only
+  tokens [B, 1] int32 → (logits [B, 1, V], updated pool, experts visited). Full shard only
   (the batched server is single-node). The pool comes back in the form it
   came in (``_paged_layer_step``). A hybrid's state-space layers step their
   per-slot state leaves of the pool instead (``_ssm_decode_step``), for the
-  rows ``active`` [B] names (None: all)."""
+  rows ``active`` [B] names (None: all). The third result is how many
+  distinct held experts the rows chose, summed over the expert layers (int32;
+  what the grouped form of ops/moe.py visits; 0 without experts)."""
   h = embed_tokens(params, cfg, tokens)
   inv_freq = rope_inv_freq(cfg)
   if active is None:
     active = jnp.ones((tokens.shape[0],), jnp.bool_)
 
-  def step(h, pool, lp, layer):
+  def step(carry, pool, lp, layer):
+    h, seen = carry
     if "w_xbc" in lp:
-      return _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
-    if "w_f" in lp:
-      return _kda_decode_step(h, pool, lp, layer, active, cfg)
-    return _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
+      h, pool, visited = _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
+    elif "w_f" in lp:
+      h, pool, visited = _kda_decode_step(h, pool, lp, layer, active, cfg)
+    else:
+      h, pool, visited = _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
+    return (h, seen + visited), pool
 
-  h, pool = _scan_layers_over_pool(step, h, _layer_runs(params, cfg), pool)
-  return head_logits(params, cfg, h), pool
+  (h, seen), pool = _scan_layers_over_pool(step, (h, jnp.int32(0)), _layer_runs(params, cfg), pool, _whole_expert_leaves(params, cfg))
+  return head_logits(params, cfg, h), pool, seen
 
 
 def _paged_decode_scan(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, top_ks, n_steps: int, k_max: int, page_size: int, use_kernel: bool, key, adapter_ids=None):
   """The chunked paged decode loop shared by ``fused_paged_batch_decode``
   and the mixed-tick program below — ONE definition of the per-step math, so
   the mixed tick's decode half is the plain program's decode half by
-  construction (the token-identity contract of ISSUE 14)."""
+  construction (the token-identity contract of ISSUE 14). Returns (tokens,
+  next token, positions, pool) and, for a model with routed experts, the
+  distinct held experts visited, summed over expert layers and steps."""
 
   from ..ops.paged import kernel_attends, kernel_pool_form, stored_pool_form
 
@@ -1812,19 +1867,20 @@ def _paged_decode_scan(params, cfg: ModelConfig, shard: Shard, token, pool, bloc
     pool = kernel_pool_form(pool)  # once a dispatch, not once a layer: the steps write and read this form
 
   def body(carry, _):
-    tok, pos, pool, key = carry
+    tok, pos, pool, key, seen = carry
     # Inactive rows would write into whatever page their table names; pin
     # their table to the trash page so held-token rewrites can't land on a
     # page another row now owns.
     bt = jnp.where(active[:, None], block_tables, 0)
-    logits, pool = paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, bt, page_size, use_kernel, adapter_ids, kv_quant, active)
+    logits, pool, visited = paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, bt, page_size, use_kernel, adapter_ids, kv_quant, active)
     nxt, key = _next_token_batched(logits[:, 0, :], key, temps, top_ks, k_max)
     nxt = jnp.where(active, nxt, tok[:, 0])  # inactive rows hold their token
     pos = jnp.where(active, pos + 1, pos)  # ...and their position
-    return (nxt[:, None], pos, pool, key), nxt
+    return (nxt[:, None], pos, pool, key, seen + visited), nxt
 
-  (next_tok, pos, pool, _), toks = jax.lax.scan(body, (token, positions, pool, key), None, length=n_steps)
-  return jnp.moveaxis(toks, 0, 1), next_tok, pos, stored_pool_form(pool, stored) if kernel_form else pool
+  (next_tok, pos, pool, _, seen), toks = jax.lax.scan(body, (token, positions, pool, key, jnp.int32(0)), None, length=n_steps)
+  out = jnp.moveaxis(toks, 0, 1), next_tok, pos, stored_pool_form(pool, stored) if kernel_form else pool
+  return (*out, seen) if cfg.n_experts else out  # a model without experts: the program keeps the four results it had
 
 
 @partial(tracked_jit, "decode.paged_batch", static_argnames=("cfg", "shard", "n_steps", "k_max", "page_size", "use_kernel"), donate_argnums=(4,))
@@ -1832,7 +1888,7 @@ def _fused_paged_batch_decode_impl(params, cfg: ModelConfig, shard: Shard, token
   return _paged_decode_scan(params, cfg, shard, token, pool, block_tables, positions, active, temps, top_ks, n_steps, k_max, page_size, use_kernel, key, adapter_ids)
 
 
-def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, n_steps: int, top_k=35, k_max: int = 64, page_size: int = 64, use_kernel: bool | None = None, key=None, adapter_ids=None):
+def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, n_steps: int, top_k=35, k_max: int = 64, page_size: int = 64, use_kernel: bool | None = None, key=None, adapter_ids=None, experts_visited: bool = False):
   """``fused_batch_decode`` against the page pool.
 
   Same contract plus ``block_tables`` [B, mp] int32 — the host must have
@@ -1840,7 +1896,10 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
   dispatch (inference/batch_scheduler.py does). Returns
   (tokens [B, n_steps], next_token [B, 1], positions [B], pool) —
   ``next_token`` is the device-resident chain input for the following chunk
-  (see ``fused_batch_decode``).
+  (see ``fused_batch_decode``). With ``experts_visited``, for a model with
+  routed experts, a fifth: the number of distinct held experts the rows
+  chose, summed over the chunk's expert layers and steps (int32 scalar; a
+  model without experts keeps the four).
 
   ``use_kernel=None`` resolves to the Pallas kernel wherever it can run
   (ops/paged.py ``paged_kernel_supported``), the XLA gather elsewhere.
@@ -1855,10 +1914,11 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
     use_kernel = paged_kernel_supported(cfg)
   B = token.shape[0]
   top_ks = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
-  return _fused_paged_batch_decode_impl(
+  out = _fused_paged_batch_decode_impl(
     params, cfg, shard, token, pool, jnp.asarray(block_tables, jnp.int32), positions, active.astype(jnp.bool_),
     jnp.asarray(temps, jnp.float32), top_ks, int(n_steps), int(k_max), int(page_size), bool(use_kernel), key, adapter_ids,
   )
+  return out if experts_visited else out[:4]
 
 
 # --------------------------------------------------- mixed prefill+decode tick
@@ -1899,7 +1959,7 @@ def _fused_mixed_paged_batch_decode_impl(params, cfg: ModelConfig, shard: Shard,
   return _paged_decode_scan(params, cfg, shard, token, pool, block_tables, positions, active, temps, top_ks, n_steps, k_max, page_size, use_kernel, key, adapter_ids)
 
 
-def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, pf_tokens, pf_bt, pf_prefix, pf_end, n_steps: int, top_k=35, k_max: int = 64, page_size: int = 64, use_kernel: bool | None = None, key=None, adapter_ids=None, pf_adapter=None):
+def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, pf_tokens, pf_bt, pf_prefix, pf_end, n_steps: int, top_k=35, k_max: int = 64, page_size: int = 64, use_kernel: bool | None = None, key=None, adapter_ids=None, pf_adapter=None, experts_visited: bool = False):
   """``fused_paged_batch_decode`` with one admission's prefill slice fused in.
 
   Decode operands as in ``fused_paged_batch_decode``; the prefill slice is
@@ -1910,8 +1970,9 @@ def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token
   (``pf_prefix + S_pad <= max_seq``, the scatter-clamp constraint of
   ``prefill_into_pages_many``). Returns the plain contract
   (tokens [B, n_steps], next_token [B, 1], positions [B], pool) — the slice
-  emits nothing; its pages simply advance. ``use_kernel=None`` resolves as
-  in the plain program.
+  emits nothing; its pages simply advance —, with ``experts_visited`` the
+  decode half's count of expert visits as there. ``use_kernel=None`` resolves
+  as in the plain program.
   """
   from ..ops.paged import paged_kernel_supported
 
@@ -1925,13 +1986,14 @@ def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token
     use_kernel = paged_kernel_supported(cfg)
   B = token.shape[0]
   top_ks = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
-  return _fused_mixed_paged_batch_decode_impl(
+  out = _fused_mixed_paged_batch_decode_impl(
     params, cfg, shard, token, pool, jnp.asarray(block_tables, jnp.int32), positions, active.astype(jnp.bool_),
     jnp.asarray(temps, jnp.float32), top_ks, jnp.asarray(pf_tokens, jnp.int32), jnp.asarray(pf_bt, jnp.int32),
     jnp.asarray(pf_prefix, jnp.int32), jnp.asarray(pf_end, jnp.int32),
     int(n_steps), int(k_max), int(page_size), bool(use_kernel), key,
     adapter_ids, None if pf_adapter is None else jnp.asarray(pf_adapter, jnp.int32),
   )
+  return out if experts_visited else out[:4]
 
 
 # ------------------------------------------- batched speculative serving
@@ -2004,7 +2066,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
     if "post_attn_norm" in p:  # gemma2
       attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
     h = h + attn_out
-  h, _ = _mlp_block(h, p, cfg)
+  h, *_ = _mlp_block(h, p, cfg)
   return h, pool
 
 

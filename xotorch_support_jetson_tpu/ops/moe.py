@@ -1,32 +1,68 @@
-"""Mixture-of-Experts routed FFN — GShard-style one-hot dispatch/combine.
+"""Mixture-of-Experts routed FFN: the router, and the one owner of the form the experts' product takes.
 
 The reference *registers* MoE models (deepseek-v3/r1/coder-v2-lite,
 ``models.py:69-70``) but its dense-only layer builder cannot load them
 (SURVEY.md §2.11: "registry entries ≠ working support",
 ``general_mha.py:77-120``). This module is the TPU-native delivery of that
-promise: routing + expert compute as pure einsums so the expert axis shards
-over an ``ep`` mesh axis (parallel/mesh.py) and GSPMD places the
-dispatch/combine all-to-alls on ICI.
+promise.
 
-Design (idiomatic TPU, not a translation of any torch MoE):
+- **top-k routing** (``router_topk``) with either softmax scoring
+  (mixtral/qwen2-moe/deepseek-v2) or sigmoid scoring with a selection-only
+  correction bias (deepseek-v3), optionally group-limited (deepseek's
+  device-limited routing: v2 ``group_limited_greedy``, v3 ``noaux_tc``).
+  ``held`` tells a layer which experts [lo, hi) of the router's its leaves
+  hold: a choice outside them adds nothing.
 
-- **top-k routing** with either softmax scoring (mixtral/qwen2-moe/deepseek-v2)
-  or sigmoid scoring with a selection-only correction bias (deepseek-v3),
-  optionally group-limited (deepseek's device-limited routing: v2
-  ``group_limited_greedy``, v3 ``noaux_tc``).
-- **Capacity-based dispatch**: tokens are assigned a position inside their
-  expert's buffer via a cumulative-sum rank; position ≥ capacity ⇒ the token
-  drops that expert (its combine weight is zero). ``capacity_factor=None``
-  means exact compute (capacity = T, nothing ever drops) — the right default
-  for inference where logits must match the unrouted math.
-- **Batched expert matmuls**: every expert's FFN runs as one
-  ``[E, C, D] x [E, D, F]`` einsum — a single large MXU op instead of a
-  Python loop over experts.
+The routed experts' product has two forms, and ``ffn_form`` is the one place
+that says which a program takes, from what the program can see — whether
+anything may drop (``capacity_factor``), whether it may use Mosaic kernels
+(``cfg.mosaic_kernels``, on a TPU), the leaves' dtype and whether their faces
+tile. A layer loop over a cache or a pool (models/decoder.py) asks it of its
+stacked expert leaves; where the answer is "grouped" it hands ``moe_ffn`` the
+stack whole with the layer to take, and that is how ``moe_ffn`` knows: one
+decision, made once a program. Every other caller — the cache-less forward
+(which training differentiates: the kernels have no derivative), the ``--pp`` /
+``--sp`` rings' own layer loops — hands a layer's leaves and gets the block form:
+
+- the **grouped** form (``_moe_ffn_grouped``): the T·k assignments are sorted
+  by expert, their token rows gathered once ([T·k, D], activations only), and
+  two Mosaic kernels after the design of megablox's ``gmm`` (gate and up with
+  the SwiGLU between them in one, down in the other) walk the sorted rows a
+  row tile at a time. Group offsets, and for every visit its expert and its
+  row tile, are scalar-prefetch operands; visits are ordered by expert, so an
+  expert whose group is empty is never read, one whose group spans several
+  row tiles is read once a tile, and rows behind the last held group cost
+  nothing (the grid's length is the number of visits, a traced scalar). The
+  kernels take the STACKED leaves [L, E, D, F] and the layer as a scalar: a
+  layer cut out of the stack ahead of a custom call would be a copy of the
+  layer's experts every step. int8 leaves go in as codes, are cast in VMEM
+  and the product's rows multiplied by their expert's scale row. The rows go
+  back to their tokens by the inverse permutation and are summed with the
+  router's weights in float32; an assignment to an expert this shard does
+  not hold, and a padded row, weigh exactly 0 (by ``where``).
+- the **block** form (``_moe_ffn_block``), the reference: GShard-style dense
+  [T, E, C] dispatch/combine one-hots and three ``[E, C, D] x [E, D, F]``
+  einsums over EVERY held expert. Position-in-expert is a cumulative-sum
+  rank; position ≥ capacity ⇒ the token drops that expert (its combine weight
+  is zero); ``capacity_factor=None`` means capacity = T, nothing drops. It is
+  what capacity-factor routing, ``ep``-sharded GSPMD plans (the expert axis
+  shards over ``ep``, parallel/mesh.py, and GSPMD places the all-to-alls),
+  differentiated programs and every backend off the TPU take, in blocks of
+  ``chunk`` tokens so the one-hots stay O(chunk²·E). Its leaves are one layer's,
+  in the activations' dtype: the caller dequantises codes beside the call (XLA
+  fuses that into the einsum's read).
+
+Both take bf16 operands, accumulate in float32, apply silu in float32 and
+combine in float32. Both return, beside the result, the router's auxiliary
+loss and the number of distinct held experts the rows chose (what the grouped
+form visits): the counters ``moe_experts_visited_total`` /
+``moe_expert_layer_steps_total`` are fed from it.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -109,7 +145,7 @@ def _held_index(idx, held):
 
 
 def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held=None):
-  """One dispatch/compute/combine block over [T, D] tokens. Returns (out, aux)."""
+  """One dispatch/compute/combine block over [T, D] tokens. Returns (out, aux, visited)."""
   T, D = x.shape
   E, E_held = w_router.shape[-1], w_gate.shape[0]
   with jax.named_scope("xot.moe_router"):
@@ -117,6 +153,7 @@ def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, sel
     weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
     C = expert_capacity(T, k, E, capacity_factor)
     dispatch, combine = dispatch_combine_masks(_held_index(idx, held), weights, E_held, C)
+    visited = jnp.sum(jnp.any(dispatch > 0, axis=(0, 2)), dtype=jnp.int32)  # (an expert's first assignment has rank 0 and is never dropped)
 
   with jax.named_scope("xot.moe_experts"):
     xin = jnp.einsum("td,tec->ecd", x, dispatch.astype(x.dtype))  # [E, C, D]
@@ -126,56 +163,169 @@ def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, sel
     out = jnp.einsum("ecd,tec->td", out.astype(jnp.float32), combine).astype(x.dtype)
   with jax.named_scope("xot.moe_router"):
     aux = load_balancing_loss(logits, idx, E)
-  return out, aux
+  return out, aux, visited
 
 
-# Below this many tokens the gather path CAN replace the batched-einsum path:
-# decode steps route to k experts per token, and gathering just those experts'
-# weight slabs reads k·T/E of the expert bytes the einsum path streams (it
-# computes every expert's capacity block — ~32x extra HBM for deepseek-v3's
-# E=256, k=8 at batch 1). Exact only when nothing can drop, so it is gated on
-# capacity_factor=None (the inference default). OPT-IN (XOT_TPU_MOE_GATHER=1):
-# the one chip figure (stale — measured before PR 1, not reproduced) had the
-# einsum path at 234 tok/s against the gather's 117 on an E=64/k=6 decode,
-# despite reading 10x the bytes (ROADMAP.md A5).
-from ..utils.helpers import env_flag as _env_flag
+# ----------------------------------------------------------------- the grouped form
+LANES = 128
+ROW_TILE = 128  # sorted assignment rows one visit multiplies: the MXU's height; a decode step of 64 rows x 8 is four of them
+_BLOCK_BYTES = 4 << 20  # of one weight block [K, tn] as stored: gate and up, double-buffered, are four of them in VMEM
+_VMEM_LIMIT = 64 << 20  # v5e has 128 MiB; the default scoped limit (16 MiB) holds no whole expert
+# Tokens one grouped pass takes: the [T·k, D] gathered rows and the float32 [T·k, D] products are temporaries of the
+# program, ~0.2 MB a token at Ling's widths, beside a pool and weights that fill the chip. A longer run is cut by
+# tokens (a Python loop: a ``lax.map`` would make the layer's expert leaves operands of a loop and copy them).
+GROUPED_MAX_TOKENS = 4096
+FFN_FORMS = ("grouped", "block")  # what ``ffn_form`` answers
+INTERPRET = False  # the tests' switch: a CPU takes the grouped form too, its kernels interpreted
 
-MOE_GATHER_MAX = 32 if _env_flag("XOT_TPU_MOE_GATHER") else 0
+
+def _on_tpu() -> bool:
+  return jax.default_backend() == "tpu"
 
 
-def _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None):
-  """Decode-path MoE: gather the k active experts' weights per token.
+def _col_tile(K: int, N: int, itemsize: int) -> int | None:
+  """Columns of one weight block [K, tn]: all N if that fits ``_BLOCK_BYTES``, else the most whole lane groups that
+  divide N and fit; None where not even one lane group of K rows fits."""
+  if K * N * itemsize <= _BLOCK_BYTES:
+    return N
+  fits = [tn for tn in range(LANES, N, LANES) if N % tn == 0 and K * tn * itemsize <= _BLOCK_BYTES]
+  return max(fits, default=None)
 
-  [T, D] tokens with T small; reads only the routed experts' slabs (XLA
-  lowers ``jnp.take`` over the expert axis to a dynamic-gather — no full
-  [E, D, F] stream). Same routing as the einsum path, no capacity concept.
-  """
+
+def ffn_form(w_gate, w_down, capacity_factor, mosaic_kernels: bool, scaled: bool = False) -> str:
+  """The form the routed experts' product takes for these expert leaves ([..., E, D, F] and [..., E, F, D], arrays or
+  ShapeDtypeStructs) — the label of the gauge ``moe_ffn_form``. "grouped" where nothing may drop, the program may run
+  Mosaic kernels (``cfg.mosaic_kernels``, which the engine clears for a plan that leaves a mesh axis to GSPMD — a
+  Mosaic call cannot be partitioned automatically — and a TPU), the leaves are bfloat16 / float32, or int8 codes with
+  per-output-channel scales (``scaled``; a packed int4 leaf has half the rows and is refused), and both faces are whole
+  lane groups a block of which fits VMEM. Anything else: "block"."""
+  if not mosaic_kernels or not (_on_tpu() or INTERPRET) or capacity_factor is not None:
+    return "block"
+  (D, F), down = w_gate.shape[-2:], w_down.shape[-2:]
+  if down != (F, D) or w_gate.dtype != w_down.dtype or w_gate.dtype not in ((jnp.int8,) if scaled else (jnp.bfloat16, jnp.float32)):
+    return "block"
+  size = jnp.dtype(w_gate.dtype).itemsize
+  tiles = D % LANES == 0 and F % LANES == 0 and _col_tile(D, F, size) is not None and _col_tile(F, D, size) is not None
+  return "grouped" if tiles else "block"
+
+
+def _visits(sizes, m: int, tm: int):
+  """The walk over sorted rows: for ``sizes`` [E] rows of each expert in turn from row 0 and row tiles of ``tm``,
+  (offsets [E+1], expert of each visit [V], row tile of each visit [V], the number of visits). A visit is one
+  (expert, row tile) pair with a row in common; they are ordered by expert, so those of one row tile follow each other
+  and an expert with no row has none. V = m/tm + E - 1 is the most there can be; entries past the number of visits
+  repeat the last real one."""
+  E, tiles = sizes.shape[0], m // tm
+  ends = jnp.cumsum(sizes)
+  starts = ends - sizes
+  of_group = jnp.where(sizes > 0, (ends + tm - 1) // tm - starts // tm, 0)
+  first = jnp.cumsum(of_group) - of_group
+  n = jnp.sum(of_group)
+  at = jnp.minimum(jnp.arange(tiles + E - 1, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+  group = jnp.clip(jnp.searchsorted(first + of_group, at, side="right", method="compare_all"), 0, E - 1).astype(jnp.int32)
+  tile = jnp.clip(starts[group] // tm + at - first[group], 0, tiles - 1).astype(jnp.int32)
+  return jnp.concatenate([jnp.zeros((1,), jnp.int32), ends.astype(jnp.int32)]), group, tile, n.astype(jnp.int32)
+
+
+def _own_rows(offsets_ref, group_ref, tile_ref, tm: int):
+  """[tm, 1] mask: the rows of this visit's row tile that belong to this visit's expert."""
+  import jax.experimental.pallas as pl
+
+  v = pl.program_id(1)
+  g = group_ref[v]
+  rows = tile_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+  return (rows >= offsets_ref[g]) & (rows < offsets_ref[g + 1])
+
+
+def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, wu_ref, *rest, tm: int, scaled: bool):
+  del layer_ref  # the index maps read it
+  (sg_ref, su_ref, out_ref) = rest if scaled else (None, None, *rest)
+  x = x_ref[...]
+  gate = jnp.dot(x, wg_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)
+  up = jnp.dot(x, wu_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)
+  if scaled:
+    gate, up = gate * sg_ref[...], up * su_ref[...]
+  h = gate * jax.nn.sigmoid(gate) * up
+  out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), h, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def _down_kernel(layer_ref, offsets_ref, group_ref, tile_ref, h_ref, wd_ref, *rest, tm: int, scaled: bool):
+  del layer_ref
+  (sd_ref, out_ref) = rest if scaled else (None, *rest)
+  h = h_ref[...]
+  y = jnp.dot(h, wd_ref[...].astype(h.dtype), preferred_element_type=jnp.float32)
+  if scaled:
+    y = y * sd_ref[...]
+  out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), y, out_ref[...])
+
+
+def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: int, out_dtype):
+  """``rows`` [M, K] against each visit's expert in the stacked ``weights`` ([L, E, K, N] each; the layer's ``scales``
+  [E, 1, N] float32, or none) → [M, N]: rows no visit owns come back as the kernel found them."""
+  import jax.experimental.pallas as pl
+  from jax.experimental.pallas import tpu as pltpu
+
+  offsets, group, tile, n_visits = walk
+  (M, K), N = rows.shape, weights[0].shape[-1]
+  tn = _col_tile(K, N, weights[0].dtype.itemsize) or N
+  row_block = pl.BlockSpec((tm, K), lambda j, v, layer, offsets, group, tile: (tile[v], 0))
+  weight_block = pl.BlockSpec((None, None, K, tn), lambda j, v, layer, offsets, group, tile: (layer[0], group[v], 0, j))
+  scale_block = pl.BlockSpec((None, 1, tn), lambda j, v, layer, offsets, group, tile: (group[v], 0, j))
+  out_block = pl.BlockSpec((tm, tn), lambda j, v, layer, offsets, group, tile: (tile[v], j))
+  return pl.pallas_call(
+    partial(kernel, tm=tm, scaled=bool(scales)),
+    out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+    grid_spec=pltpu.PrefetchScalarGridSpec(
+      num_scalar_prefetch=4,
+      grid=(N // tn, n_visits),  # column blocks outside: inside one, consecutive visits of a row tile keep its output block in VMEM
+      in_specs=[row_block, *[weight_block] * len(weights), *[scale_block] * len(scales)],
+      out_specs=out_block,
+    ),
+    compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+    interpret=INTERPRET,
+    name=name,
+  )(jnp.asarray(layer, jnp.int32).reshape(1), offsets, group, tile, rows, *weights, *scales)
+
+
+def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None, scales=None, layer=0):
+  """The grouped form over [T, D] tokens (nothing can drop). Expert leaves stacked, [L, E, D, F] / [L, E, F, D], with
+  ``layer`` a (traced) scalar; ``scales`` their per-output-channel scales ([L, E, F], [L, E, F], [L, E, D]) where the
+  leaves are int8 codes. Returns (out, aux, visited)."""
   T, D = x.shape
-  E = w_router.shape[-1]
+  E, E_held, M = w_router.shape[-1], w_gate.shape[1], T * k
+  tm = ROW_TILE if M >= ROW_TILE else -(-M // 16) * 16  # (a bfloat16 tile is 16 sublanes)
+  Mp = -(-M // tm) * tm
   with jax.named_scope("xot.moe_router"):
     logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
     weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
-  with jax.named_scope("xot.moe_experts"):
-    flat = idx.reshape(-1)  # [T·k]
-    if held is not None:  # a choice this shard does not hold weighs nothing; its (clipped) gather is never combined
-      weights = jnp.where((idx >= held[0]) & (idx < held[1]), weights, 0.0)
-      flat = jnp.clip(_held_index(flat, held), 0, w_gate.shape[0] - 1)
-    g = jnp.take(w_gate, flat, axis=0).reshape(T, k, D, -1)
-    u = jnp.take(w_up, flat, axis=0).reshape(T, k, D, -1)
-    d = jnp.take(w_down, flat, axis=0).reshape(T, k, -1, D)
-    gated = jax.nn.silu(jnp.einsum("td,tjdf->tjf", x, g).astype(jnp.float32)).astype(x.dtype)
-    up = jnp.einsum("td,tjdf->tjf", x, u)
-    out_e = jnp.einsum("tjf,tjfd->tjd", gated * up, d)
-    out = jnp.einsum("tjd,tj->td", out_e.astype(jnp.float32), weights).astype(x.dtype)
+  with jax.named_scope("xot.moe_experts"):  # the dispatch (the sort, the walk, the rows' gather), the products and the combine
+    expert = _held_index(idx, held).reshape(M)
+    expert = jnp.where((expert >= 0) & (expert < E_held), expert, E_held)  # an expert this shard does not hold sorts behind every held group
+    expert = jnp.pad(expert, (0, Mp - M), constant_values=E_held)  # and so do the rows that fill the last tile
+    expert, order = jax.lax.sort_key_val(expert, jnp.arange(Mp, dtype=jnp.int32))
+    back = jnp.zeros((Mp,), jnp.int32).at[order].set(jnp.arange(Mp, dtype=jnp.int32))[:M]  # assignment (token-major) → its sorted row
+    sizes = jnp.sum(expert[:, None] == jnp.arange(E_held, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
+    walk = _visits(sizes, Mp, tm)
+    visited = jnp.sum(sizes > 0, dtype=jnp.int32)
+    rows = jnp.take(x, order // k, axis=0, mode="clip")  # [Mp, D]: each assignment's token, in sorted order
+    # (the layer's scales are cut out of their stack — kilobytes, where an expert leaf's layer is most of a GB — as
+    # [E, 1, N]: a block is one expert's row)
+    cut = tuple(jax.lax.dynamic_index_in_dim(s, layer, 0, keepdims=False).astype(jnp.float32)[:, None, :] for s in scales or ())
+    h = _grouped_product(_gate_up_kernel, "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, walk, tm, x.dtype)
+    y = _grouped_product(_down_kernel, "moe_down", h, (w_down,), cut[2:], layer, walk, tm, jnp.float32)
+    # Rows of an expert not held, and rows that pad the last tile, hold whatever the kernels found there: they are
+    # taken out by ``where``, never multiplied by a zero.
+    y = jnp.where((expert < E_held)[:, None], y, 0.0)
+    out = jnp.sum(jnp.take(y, back, axis=0).reshape(T, k, D) * weights.astype(jnp.float32)[:, :, None], axis=1).astype(x.dtype)
   with jax.named_scope("xot.moe_router"):
     aux = load_balancing_loss(logits, idx, E)
-  return out, aux
+  return out, aux, visited
 
 
 def moe_ffn(
   x: jnp.ndarray,  # [T, D] tokens (flattened batch*seq)
   w_router: jnp.ndarray,  # [D, E]
-  w_gate: jnp.ndarray,  # [E, D, F] per-expert gate proj
+  w_gate: jnp.ndarray,  # [E, D, F] per-expert gate proj ([L, E, D, F] with ``layer``)
   w_up: jnp.ndarray,  # [E, D, F]
   w_down: jnp.ndarray,  # [E, F, D]
   k: int,
@@ -185,14 +335,15 @@ def moe_ffn(
   scale: float = 1.0,
   capacity_factor: float | None = None,
   chunk: int = 256,
-  return_aux: bool = False,
   n_group: int = 1,
   topk_group: int = 1,
   group_mode: str = "none",
   held: tuple[int, int] | None = None,
+  scales: tuple | None = None,
+  layer=None,
 ):
-  """Routed SwiGLU FFN over ``E`` experts; returns [T, D] in x.dtype
-  (or ``(out, aux_loss)`` with ``return_aux``).
+  """Routed SwiGLU FFN over ``E`` experts; returns ([T, D] in x.dtype, the router's auxiliary loss, the number of
+  distinct held experts the rows chose: int32, summed over the blocks or pieces of a long run).
 
   ``held`` = (lo, hi): this shard's share of an expert-parallel layer. The
   router (``w_router`` [D, E], its bias, the groups, the top-k and the
@@ -201,31 +352,31 @@ def moe_ffn(
   part of the layer's sum: what the absent experts would have added is left
   out (the shares of all the shards add up to the whole layer).
 
+  ``layer``: the grouped form. The expert leaves are a stack's, [L, E, ...], handed over whole by a layer loop that
+  asked ``ffn_form`` of them, and this is the layer to take (a traced scalar: the kernels index the stack, so that no
+  layer is cut out of it); ``scales`` their scale leaves (gate, up, down) where they are int8 codes. A long run goes
+  in pieces of ``GROUPED_MAX_TOKENS``.
 
-  Small token runs (decode steps; T ≤ MOE_GATHER_MAX with the exact
-  ``capacity_factor=None``) take the weight-gather path — HBM reads scale
-  with the ACTIVE experts, not E. Long token runs are processed in
-  sequential chunks of ``chunk`` tokens so the dispatch/combine one-hots
-  stay O(chunk²·E) instead of O(T²·E) — routing is per-token, so chunking
-  is exact (with the default ``capacity_factor=None``, capacity per chunk =
-  chunk, nothing ever drops).
+  Without ``layer``: the block form over one layer's leaves, long runs in sequential blocks of ``chunk`` tokens so the
+  dispatch/combine one-hots stay O(chunk²·E) instead of O(T²·E) — routing is per-token, so cutting is exact (with
+  ``capacity_factor=None``, capacity per block = chunk, nothing ever drops).
   """
   T, D = x.shape
+  routing = (k, scoring, norm_topk, selection_bias, scale)
+  groups = (n_group, topk_group, group_mode, held)
 
-  def block(xs):
-    return _moe_ffn_block(xs, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held)
-
-  if T <= MOE_GATHER_MAX and capacity_factor is None:
-    out, aux = _moe_ffn_gather(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held)
-  elif T <= chunk:
-    out, aux = block(x)
-  else:
-    pad = (-T) % chunk
-    xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
-    out_c, aux_c = jax.lax.map(block, xp.reshape(-1, chunk, D))
-    out = out_c.reshape(-1, D)[:T]
-    aux = jnp.mean(aux_c)  # padding rows bias aux slightly; acceptable for a regularizer
-  return (out, aux) if return_aux else out
+  if layer is not None:
+    assert capacity_factor is None, "the grouped form drops nothing"
+    pieces = [x[at : at + GROUPED_MAX_TOKENS] for at in range(0, T, GROUPED_MAX_TOKENS)]
+    outs, auxs, visits = zip(*(_moe_ffn_grouped(piece, w_router, w_gate, w_up, w_down, *routing, *groups, scales, layer) for piece in pieces))
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    return out, sum(a * piece.shape[0] for a, piece in zip(auxs, pieces)) / T, sum(visits)
+  if T <= chunk:
+    return _moe_ffn_block(x, w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups)
+  pad = (-T) % chunk
+  xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+  out_c, aux_c, visited_c = jax.lax.map(lambda xs: _moe_ffn_block(xs, w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups), xp.reshape(-1, chunk, D))
+  return out_c.reshape(-1, D)[:T], jnp.mean(aux_c), jnp.sum(visited_c)  # padding rows bias aux slightly; acceptable for a regularizer
 
 
 def load_balancing_loss(router_logits: jnp.ndarray, idx: jnp.ndarray, n_experts: int) -> jnp.ndarray:

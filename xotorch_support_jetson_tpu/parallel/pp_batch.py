@@ -266,7 +266,7 @@ class PPBatchedServing:
             bt_eff = paged_bt(write_ok, g)
 
             def step(h, pool, lp, layer):
-              return _paged_layer_step(h, pool, lp, layer, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)
+              return _paged_layer_step(h, pool, lp, layer, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)[:2]
 
             # The prefix layers' stacked pool rides the layer loop's carry (decoder.py _scan_layers_over_pool).
             h_out, new = _scan_layers_over_pool(step, h_in, [pre_layers], {key: cache[f"{key}_pre"][0] for key in kv_keys})
@@ -288,7 +288,7 @@ class PPBatchedServing:
             bt_eff = paged_bt(write_ok, g)
 
             def step(h, pool, lp, layer):
-              return _paged_layer_step(h, pool, lp, layer, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)
+              return _paged_layer_step(h, pool, lp, layer, bt_eff, cur_pos[:, None], inv_freq, cfg, page_size, False)[:2]
 
             h_out, new = _scan_layers_over_pool(step, h_in, [stage_layers], {key: cache[key] for key in kv_keys})
             return h_out, {**cache, **{key: new[key] for key in kv_keys}}

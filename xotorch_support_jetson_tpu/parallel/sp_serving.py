@@ -205,7 +205,7 @@ def _sp_layer_step(h, p, kv, positions, rank_offset, inv_freq, cfg: ModelConfig,
   if "post_attn_norm" in p:  # gemma2
     attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
   h = h + attn_out
-  h, _ = _mlp_block(h, p, cfg)
+  h, *_ = _mlp_block(h, p, cfg)
   return h, kv
 
 
