@@ -327,7 +327,7 @@ def test_scheduler_gauges_counters_and_histograms(monkeypatch):
     "qwait": gm.hist_count("queue_wait_seconds"),
     "itl": gm.hist_count("itl_seconds"),
     "chunk_t": gm.hist_count("decode_chunk_seconds"),
-    "gap": gm.hist_count("sched_host_gap_seconds"),
+    "host": gm.counter_value("sched_wall_seconds_total", labels={"kind": "host"}),
   }
   seen_occupancy = []
 
@@ -351,9 +351,10 @@ def test_scheduler_gauges_counters_and_histograms(monkeypatch):
   assert gm.hist_count("queue_wait_seconds") - before["qwait"] == 3
   assert gm.hist_count("itl_seconds") > before["itl"]
   assert gm.hist_count("decode_chunk_seconds") > before["chunk_t"]
-  # Dispatch-boundary host gap: chained lookahead dispatches record 0 by
-  # construction; sync-boundary dispatches record the real idle window.
-  assert gm.hist_count("sched_host_gap_seconds") > before["gap"]
+  # Dispatch-boundary host gap (the loop's wall clock, kind "host"): a chained
+  # lookahead dispatch adds nothing to it; a sync-boundary dispatch adds the
+  # real window from the last readback to itself.
+  assert gm.counter_value("sched_wall_seconds_total", labels={"kind": "host"}) > before["host"]
   assert max(seen_occupancy) >= 1  # rows were visibly resident mid-run
   # Idle again: gauges settle back to an empty pool.
   assert gm.gauges["scheduler_batch_occupancy"] == 0
@@ -706,6 +707,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_sched_tick_prefill_tokens_total",
   "xot_tpu_sched_ticks_total",  # one per program dispatch of the scheduler loop (ISSUE 24)
   "xot_tpu_sched_phase_seconds_total",  # {phase}: host seconds of a tick by phase (ISSUE 24)
+  "xot_tpu_sched_wall_seconds_total",  # {kind}: the loop's wall time by what it waits for (ISSUE 41)
   # Disaggregated prefill/decode (ISSUE 10)
   "xot_tpu_kv_stream_pages_total",
   "xot_tpu_kv_stream_bytes_total",
@@ -785,7 +787,6 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_prefill_chunk_seconds",
   "xot_tpu_decode_chunk_seconds",
   "xot_tpu_mixed_tick_seconds",  # one fused mixed prefill+decode dispatch (ISSUE 14)
-  "xot_tpu_sched_host_gap_seconds",
   "xot_tpu_spec_acceptance_ewma",
   "xot_tpu_kv_tier_spill_seconds",
   "xot_tpu_kv_tier_restore_seconds",

@@ -161,6 +161,7 @@ from ..utils.programs import dispatch_context, ledger
 from .engine import PromptTooLongError, RequestMigratedError, ServerOverloadedError
 from .qos import DeadlineUnmeetableError
 from .sched_admission import AdmissionControl, _Request
+from .sched_clock import SchedClock
 
 __all__ = ["BatchedServer", "_Request"]
 
@@ -174,24 +175,6 @@ PROPOSER_CODE = {"plain": 0, "ngram": 1, "model": 2}
 
 def _round_up(n: int, multiple: int) -> int:
   return ((n + multiple - 1) // multiple) * multiple
-
-
-@contextmanager
-def _phase(name: str, **args):
-  """One scheduler phase (admit | plan | stage | readback | settle) on two
-  clocks (ISSUE 24): a ``xot.sched.<name>`` span in the profiler's trace, where
-  it shares the device ops' clock and names the idle gap it overlaps, and the
-  always-on ``sched_phase_seconds_total{phase}`` (host ``perf_counter``; free of
-  the profiler, what an operator scrapes). A ``TraceAnnotation`` belongs to one
-  thread and must nest there: wrap synchronous sections only, never an ``await``.
-  ``stage`` on the executor thread runs to the end of the dispatch, so when a
-  dispatch compiles its seconds hold the compile (``program_compile_seconds``)."""
-  t0 = time.perf_counter()
-  try:
-    with jax.profiler.TraceAnnotation(f"xot.sched.{name}", **args):
-      yield
-  finally:
-    metrics.inc("sched_phase_seconds_total", time.perf_counter() - t0, labels={"phase": name})
 
 
 @dataclass
@@ -274,8 +257,6 @@ class _Chunk:
   rows: list  # [(row, _Slot)] resident at dispatch
   active: np.ndarray  # [B] bool — rows that stepped in this chunk
   starved: frozenset
-  t_dispatch: float
-  chained: bool  # dispatched on top of an in-flight chunk (device never idled)
   tick: int = 0  # the scheduler tick that issued it (its ``xot.*`` spans and the ``decode_chunk`` stage carry the same number)
   # Batched speculation (ISSUE 7): variable-advance chunks. ``worst`` is the
   # chunk's worst-case per-row advance (== chunk for plain chunks) — what
@@ -457,12 +438,12 @@ class BatchedServer:
     # Page availability as of the last admission pass: the lookahead drain
     # gate retries parked requests only when this moves (_parked_admissible).
     self._parked_avail_seen: int = -1
-    # Dispatch-boundary timing: when the last chunk's host readback landed
-    # (None until the first settle / after idle). Feeds decode_chunk_seconds
-    # (device time, ready-to-ready while the pipeline is full) and
-    # sched_host_gap_seconds (device-idle window a dispatch had to wait for
-    # host work — 0 by construction for chained lookahead dispatches).
-    self._t_last_ready: float | None = None
+    # The loop's wall clock by what it waits for (sched_clock.py): the one
+    # subtraction per readback that feeds prefill_chunk_seconds /
+    # decode_chunk_seconds / mixed_tick_seconds (device time, ready-to-ready
+    # while the pipeline is full) and sched_wall_seconds_total{kind}; its
+    # snapshots ride every request's ``decode`` and ``released`` stages.
+    self.clock = SchedClock()
     # Ticks issued: one per program dispatch of the loop (plain, mixed, spec
     # or prefill group) — ``sched_ticks_total``, and the number its spans carry.
     self._tick = 0
@@ -596,6 +577,7 @@ class BatchedServer:
     references the row."""
     s = self.slots[row]
     req = s.req
+    self._note_released(s)  # a resumed row has one residency per incarnation
     self._release_pages(s, extend=keep_kv)
     self.slots[row] = None
     self._clear_row(row)
@@ -970,7 +952,32 @@ class BatchedServer:
   def _next_tick(self) -> int:
     self._tick += 1
     metrics.inc("sched_ticks_total")
+    self.clock.tick()
     return self._tick
+
+  @contextmanager
+  def _phase(self, name: str, **args):
+    """One scheduler phase (admit | plan | stage | readback | settle) on two
+    clocks (ISSUE 24): a ``xot.sched.<name>`` span in the profiler's trace, where
+    it shares the device ops' clock and names the idle gap it overlaps, and the
+    always-on ``sched_phase_seconds_total{phase}`` (host ``perf_counter``; free of
+    the profiler, what an operator scrapes; the clock's snapshots carry the same
+    sums). A ``TraceAnnotation`` belongs to one thread and must nest there: wrap
+    synchronous sections only, never an ``await``. The one exception is
+    ``xot.sched.idle`` around the loop's wait on its queue (``_run``): this task
+    alone opens a span across an await, it resumes on the thread it left, and
+    whatever else runs on that thread meanwhile is a synchronous section that
+    begins after the span opened and ends before it closes, so the events still
+    nest. ``stage`` on the executor thread runs to the end of the dispatch, so when
+    a dispatch compiles its seconds hold the compile (``program_compile_seconds``)."""
+    t0 = time.perf_counter()
+    try:
+      with jax.profiler.TraceAnnotation(f"xot.sched.{name}", **args):
+        yield
+    finally:
+      dt = time.perf_counter() - t0
+      metrics.inc("sched_phase_seconds_total", dt, labels={"phase": name})
+      self.clock.phase(name, dt)
 
   @staticmethod
   def _attributed(run, request_ids, tick: int):
@@ -1328,7 +1335,7 @@ class BatchedServer:
     tracer.stage(req.request_id, "admitted", attrs)
 
   async def _admit_pending(self, woken: _Request | None = None) -> None:
-    with _phase("admit"):
+    with self._phase("admit"):
       ready = self._collect_admissions(woken)
     if ready:
       await self._dispatch(ready)
@@ -1587,7 +1594,7 @@ class BatchedServer:
         # sampling in ONE device dispatch — same _next_token_batched math
         # on the same key, so the unfused path below is token-identical
         # (A/B-pinned; XOT_TPU_FUSED_SAMPLING=0 restores it).
-        with _phase("stage", tick=tick, rows=K):
+        with self._phase("stage", tick=tick, rows=K):
           if self.fused_sampling:
             firsts, self.cache = self.ops.prefill_into_pages_many_sampled(
               jnp.asarray(tok), self.cache, bts, prefix_lens, prompt_lens, self.page_size,
@@ -1604,7 +1611,7 @@ class BatchedServer:
             if draft_job is not None:
               draft_job()
             firsts = sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max)
-        with _phase("readback", tick=tick):  # the first tokens: waits for the prefill program
+        with self._phase("readback", tick=tick):  # the first tokens: waits for the prefill program
           return np.asarray(firsts)
 
     else:
@@ -1616,7 +1623,7 @@ class BatchedServer:
       def run():
         # Prefill AND first-token sampling stay on the engine executor — the
         # single thread that serializes all device work.
-        with _phase("stage", tick=tick, rows=K):
+        with self._phase("stage", tick=tick, rows=K):
           if self.fused_sampling:
             firsts, self.cache = self.ops.prefill_into_slots_sampled(
               jnp.asarray(tok), self.cache, rows, prompt_lens, temps, top_ks, self.k_max, sub, **lora_kw,
@@ -1630,7 +1637,7 @@ class BatchedServer:
             if draft_job is not None:
               draft_job()
             firsts = sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max)
-        with _phase("readback", tick=tick):  # the first tokens: waits for the prefill program
+        with self._phase("readback", tick=tick):  # the first tokens: waits for the prefill program
           return np.asarray(firsts)
 
     # Stage marks go down BEFORE the dispatch so the timeline's
@@ -1643,9 +1650,9 @@ class BatchedServer:
   async def _dispatch_group(self, group: list[_Ready], all_rows: set[int]) -> None:
     eng = self.engine
     tick = self._next_tick()
-    with _phase("stage", tick=tick, rows=len(group)):
+    with self._phase("stage", tick=tick, rows=len(group)):
       run = self._stage_group(group, all_rows, tick)
-    t_dispatch = time.perf_counter()
+    self.clock.dispatched("prefill")
     try:
       firsts = await asyncio.get_event_loop().run_in_executor(
         eng.executor, self._attributed(run, [r.req.request_id for r in group], tick)
@@ -1660,14 +1667,13 @@ class BatchedServer:
         raise  # the pool went into the failed call: the loop drops it and fails the rest, as after a failed decode chunk
       return
     finally:
-      # Device idle from here until the next dispatch — refreshed on the
-      # failure path too, or a failed prefill's whole device time would leak
-      # into the next dispatch's sched_host_gap_seconds observation.
-      self._t_last_ready = time.perf_counter()
+      # Device idle from here until the next dispatch — closed on the failure
+      # path too, or a failed prefill's interval would run on into the host's.
+      prefill_dt = self.clock.ready()
       for r in group:
         self._admitting.discard(r.req.request_id)
-    with _phase("settle", tick=tick):
-      metrics.observe_hist("prefill_chunk_seconds", self._t_last_ready - t_dispatch)
+    with self._phase("settle", tick=tick):
+      metrics.observe_hist("prefill_chunk_seconds", prefill_dt)
       metrics.inc("prefill_chunks_total")
       for i, r in enumerate(group):
         if r.chunk_end:  # intermediate chunk: advance and re-queue; no sample
@@ -1733,14 +1739,14 @@ class BatchedServer:
     cancelled = req.request_id in self._cancelled_ids  # raced during prefill
     finished = cancelled or first in req.eos_ids or slot.generated >= req.max_tokens
     slot.finished = finished
-    tracer.stage(req.request_id, "decode", {"first_token": int(first)})
+    tracer.stage(req.request_id, "decode", {"first_token": int(first), "clock": self.clock.snapshot()})
     req.emit(req.request_id, [] if cancelled else [first], finished)
     if not cancelled:
       slo.note_tokens(self._slo_class(req), self._slo_tenant(req), 1)
     if finished:
       self._cancelled_ids.discard(req.request_id)
       self._release_pages(slot)
-      self._slo_note_complete(slot)
+      self._note_complete(slot)
       if not req.future.done():
         req.future.set_result(slot.out_tokens)
       return
@@ -1895,6 +1901,18 @@ class BatchedServer:
   @staticmethod
   def _slo_tenant(req: _Request) -> str:
     return req.qos.tenant if req.qos is not None else "default"
+
+  def _note_released(self, slot: _Slot) -> None:
+    """The row leaves its slot — finished, cancelled, preempted or drained. The
+    timeline's ``released`` stage carries the loop's clock as the row's
+    ``decode`` stage did at its first token: their difference is where this
+    residency's wall time went (``resident_ms`` on the timeline)."""
+    tracer.stage(slot.req.request_id, "released", {"generated": slot.generated, "clock": self.clock.snapshot()})
+
+  def _note_complete(self, slot: _Slot) -> None:
+    """The completion choke point: every finish passes here."""
+    self._note_released(slot)
+    self._slo_note_complete(slot)
 
   def _slo_note_complete(self, slot: _Slot) -> None:
     """Goodput accounting at the completion choke points (ISSUE 9): a
@@ -2315,6 +2333,7 @@ class BatchedServer:
     s = self.slots[victim]
     metrics.inc("scheduler_preemptions_total")
     tracer.stage(s.req.request_id, "preempted", {"generated": s.generated})
+    self._note_released(s)
     self._release_pages(s)
     self.slots[victim] = None
     self._clear_row(victim)
@@ -2409,19 +2428,13 @@ class BatchedServer:
       })
     sub = eng.split_key()
     lora_kw = {"adapter_ids": jnp.asarray(self._h_adapters)} if self._lora_active() else {}
-    now = time.perf_counter()
-    if self._t_last_ready is not None:
-      # Device-idle window this dispatch had to wait for host work — 0 by
-      # construction when chained (the device already has this chunk's
-      # predecessor running and this one queues behind it).
-      metrics.observe_hist("sched_host_gap_seconds", 0.0 if inflight is not None else now - self._t_last_ready)
 
     def run():
       # ``stage`` on this thread runs to the end of the dispatch: the operands'
       # transfers, the engine's own argument handling and the jitted call, which
       # the nested ``xot.program:<family>`` span marks (measured, PR 24: the call
       # is 0.5-1 ms of it, the engine's handling before it 1.4-2.7 ms).
-      with _phase("stage", tick=tick, rows=int(active.sum())):
+      with self._phase("stage", tick=tick, rows=int(active.sum())):
         counts = pos_dev = n_prop = None
         seen = ()  # a solo engine's plain and mixed programs also return their count of expert visits (a pp / sp ring's do not)
         # The draft cache rides the dispatch only when a MODEL-drafted row is
@@ -2481,13 +2494,15 @@ class BatchedServer:
 
     if plan.starved:
       metrics.inc("scheduler_page_starved_total", len(plan.starved))
-    t_dispatch = time.perf_counter()
+    # The host's interval ends here unless this chunk is chained: the device
+    # then already runs its predecessor and this one queues behind it.
+    self.clock.dispatched("spec" if spec else "mixed" if mixed_r is not None else "decode")
     rids = [s.req.request_id for i, s in plan.rows if plan.active[i]]
     if mixed_r is not None:
       rids.append(mixed_r.req.request_id)
     return run, rids, dict(
       rows=plan.rows, active=plan.active,
-      starved=frozenset(plan.starved), t_dispatch=t_dispatch, chained=inflight is not None,
+      starved=frozenset(plan.starved),
       spec=spec, worst=worst, rounds=self.chunk if spec else 0, gammas=gammas,
       proposers=proposers,
       mixed_ready=mixed_r, mixed_start=m_start, mixed_end=m_end,
@@ -2511,7 +2526,7 @@ class BatchedServer:
 
   async def _dispatch_decode(self, plan: _Plan, inflight: _Chunk | None) -> _Chunk:
     tick = self._next_tick()
-    with _phase("stage", tick=tick, rows=int(plan.active.sum())):
+    with self._phase("stage", tick=tick, rows=int(plan.active.sum())):
       run, rids, record = self._stage_decode(plan, inflight, tick)
     toks, next_tok, counts, pos_dev, n_prop, experts_visited = await asyncio.get_event_loop().run_in_executor(
       self.engine.executor, self._attributed(run, rids, tick)
@@ -2574,7 +2589,7 @@ class BatchedServer:
     counted = record.experts_visited is not None  # a program that counts its expert visits: a solo engine's, of a model with experts
 
     def fetch():
-      with _phase("readback", tick=record.tick):  # waits for the chunk's program
+      with self._phase("readback", tick=record.tick):  # waits for the chunk's program
         return (
           np.asarray(record.toks),
           np.asarray(record.counts) if record.counts is not None else None,
@@ -2583,7 +2598,7 @@ class BatchedServer:
         )
 
     rows_host, counts_host, n_prop_host, visited = await asyncio.get_event_loop().run_in_executor(eng.executor, fetch)
-    with _phase("settle", tick=record.tick):
+    with self._phase("settle", tick=record.tick):
       if counted:
         # Their quotient is the mean number of distinct held experts a decode step visits in one expert layer: how
         # far the grouped form (``moe_ffn_form``) engages — it reads those and no other.
@@ -2594,15 +2609,13 @@ class BatchedServer:
   def _settle_host(self, record: _Chunk, rows_host, counts_host, n_prop_host) -> None:
     """The settle's host half, once the chunk's tokens are on the host: timing
     attribution, the emit walk, finishes, page release."""
-    t_ready = time.perf_counter()
-    # Device-time attribution: while the pipeline is full the device runs
-    # chunks back-to-back, so per-chunk device time is READY-TO-READY (==
-    # dispatch-to-dispatch in steady state); the first chunk after a
-    # boundary times dispatch-to-ready, exactly like the synchronous loop.
-    # Either way the host bookkeeping below is NOT serially attributed.
-    base = self._t_last_ready if (record.chained and self._t_last_ready is not None) else record.t_dispatch
-    chunk_dt = max(t_ready - base, 1e-9)
-    self._t_last_ready = t_ready
+    # Device-time attribution (sched_clock.py): while the pipeline is full
+    # the device runs chunks back-to-back, so per-chunk device time is
+    # READY-TO-READY (== dispatch-to-dispatch in steady state); the first
+    # chunk after a boundary times dispatch-to-ready, exactly like the
+    # synchronous loop. Either way the host bookkeeping below is NOT
+    # serially attributed.
+    chunk_dt = max(self.clock.ready(steps=self.chunk), 1e-9)
     if record.mixed_ready is not None:
       # Mixed-tick settle (ISSUE 14): the fused dispatch's prefill slice is
       # confirmed — advance the admission's prefix (max-guarded: a settle
@@ -2635,7 +2648,7 @@ class BatchedServer:
         slot.finished = True
         self._cancelled_ids.discard(req.request_id)
         self._release_pages(slot)
-        self._slo_note_complete(slot)
+        self._note_complete(slot)
         req.emit(req.request_id, [], True)
         if not req.future.done():
           req.future.set_result(slot.out_tokens)
@@ -2682,7 +2695,7 @@ class BatchedServer:
         slot.finished = True
         self._cancelled_ids.discard(req.request_id)
         self._release_pages(slot)
-        self._slo_note_complete(slot)
+        self._note_complete(slot)
         if not req.future.done():
           req.future.set_result(slot.out_tokens)
         self.slots[i] = None
@@ -2690,6 +2703,7 @@ class BatchedServer:
     self._update_gauges()
 
   async def _run(self) -> None:
+    self.clock.idle_end()  # a request is pending, or this loop would not have been started
     self._ensure_cache()
     inflight: _Chunk | None = None
     try:
@@ -2697,7 +2711,7 @@ class BatchedServer:
         # One mixed-budget verdict per loop iteration: the boundary gate,
         # the tick planner, and the admission sweep must agree within a
         # tick (and the policy read — gauge/histogram walk — runs once).
-        with _phase("plan"):
+        with self._phase("plan"):
           mixed_budget = self._mixed_budget() if (self._prefilling and self._mixed_active()) else None
         if inflight is not None:
           # Membership changes happen only at dispatch boundaries: DRAIN the
@@ -2763,12 +2777,16 @@ class BatchedServer:
             # Idle: block on the queue (the task persists — no exit/restart
             # race). The woken request and anything else that queued while
             # idle admit together in one batched dispatch.
-            self._t_last_ready = None  # idle-by-design is not a host gap
-            req = await self.queue.get()
+            self.clock.idle_begin()  # idle by design, not a host gap
+            try:
+              with jax.profiler.TraceAnnotation("xot.sched.idle"):  # the one span across an await: _phase's docstring says why it nests
+                req = await self.queue.get()
+            finally:
+              self.clock.idle_end()
             await self._admit_pending(woken=req)
             continue
 
-        with _phase("plan"):
+        with self._phase("plan"):
           if mixed_budget is None and self._prefilling and self._mixed_active():
             # The admission pass above just staged a prefill: pick up the
             # verdict for this iteration's planner.
@@ -2795,7 +2813,7 @@ class BatchedServer:
           await self._settle(inflight)
           inflight = None
           continue
-        with _phase("plan"):
+        with self._phase("plan"):
           plan = self._plan_chunk(inflight, gmax)
         plan.mixed = mixed
         if inflight is not None and (not plan.rows or not plan.active.any()):
@@ -2837,7 +2855,7 @@ class BatchedServer:
           slot.req.future.set_exception(exc)
       self.slots[i] = None
       self._clear_row(i)  # the single release hook resets every dispatch array
-    self._t_last_ready = None
+    self.clock.reset()
     while self._prefilling:
       r = self._prefilling.pop()
       self._lora_unpin(r.req)

@@ -1,0 +1,109 @@
+"""The scheduler loop's wall clock, split by what the loop waits for (ISSUE 41).
+
+At every instant the loop of one ``BatchedServer`` is in exactly one *kind*:
+
+- ``decode`` | ``mixed`` | ``spec`` | ``prefill``: a dispatch of that kind is
+  the oldest one not yet read back. A chained chunk (dispatched behind one
+  still in flight) owns the clock from its predecessor's readback to its own
+  (ready-to-ready); an unchained one from its dispatch to its readback.
+- ``host``: the loop has nothing dispatched and work is pending (last readback
+  to the loop's next hand-over). It is NOT the chip's idle share: a dispatch
+  takes the clock when the loop hands it to the executor, so the executor's
+  half of ``stage`` (transfers, the jitted call), during which the chip still
+  waits, is inside the dispatch's kind (measured, PR 41: a third to a half of
+  a capture's idle share).
+- ``idle``: nothing is in flight and nothing is pending (the loop's one
+  unbounded wait, on its queue), and the time before the loop's first request
+  and after it has failed.
+
+The kinds' seconds therefore sum to ``t - t_started`` whatever the order of
+calls. ``/metrics`` carries them as ``sched_wall_seconds_total{kind}``;
+``snapshot()`` is what a request's timeline carries at its first token and at
+its release, so two snapshots give an exact delta over any stretch of a run
+(``Tracer.timeline``'s ``resident_ms``; the benchmark's ``clock_lib``).
+
+One writer at a time: the loop and the engine's executor thread take turns (a
+phase on one waits for the other), so nothing here locks.
+"""
+
+from __future__ import annotations
+
+import time
+from collections import deque
+
+from ..utils.metrics import metrics
+
+DEVICE_KINDS = ("decode", "mixed", "spec", "prefill")
+KINDS = (*DEVICE_KINDS, "host", "idle")
+
+
+class SchedClock:
+  def __init__(self, now=time.perf_counter):
+    self._now = now
+    self.t_started = self._t = now()  # _t: up to where the seconds are booked
+    self._kind = "idle"
+    self._inflight: deque[str] = deque()  # kinds dispatched and not yet read back, oldest first
+    self._since = self._t  # when the oldest in-flight dispatch took the clock
+    self.seconds = dict.fromkeys(KINDS, 0.0)
+    self.intervals = dict.fromkeys(KINDS, 0)  # closed stretches of each kind: a snapshot's group counts (on /metrics the chunk histograms count the device kinds)
+    self.phases: dict[str, float] = {}
+    self.ticks = 0
+    self.steps = 0  # decode steps read back (a chunk's worth per decode / mixed / spec interval)
+
+  def _book(self) -> float:
+    now = self._now()
+    dt = now - self._t
+    self.seconds[self._kind] += dt
+    metrics.inc("sched_wall_seconds_total", dt, labels={"kind": self._kind})
+    self._t = now
+    return now
+
+  def _enter(self, kind: str) -> None:
+    """Close the current interval (booked up to now by the caller) and open one of ``kind``."""
+    self.intervals[self._kind] += 1
+    self._kind = kind
+
+  def dispatched(self, kind: str) -> None:
+    self._inflight.append(kind)
+    if len(self._inflight) == 1:  # unchained: the host's (or the idle wait's) interval ends here
+      self._since = self._book()
+      self._enter(kind)
+
+  def ready(self, steps: int = 0) -> float:
+    """The oldest dispatch has been read back (or has failed): close its interval and return its length."""
+    now = self._book()
+    if not self._inflight:
+      return 0.0
+    self._inflight.popleft()
+    dt, self._since = now - self._since, now
+    self.steps += steps
+    self._enter(self._inflight[0] if self._inflight else "host")
+    return dt
+
+  def idle_begin(self) -> None:
+    if self._kind == "host":
+      self._book()
+      self._enter("idle")
+
+  def idle_end(self) -> None:
+    if self._kind == "idle":
+      self._book()
+      self._enter("host")
+
+  def reset(self) -> None:
+    """The loop failed or was shut down: whatever was in flight is gone, and until a new loop starts nothing is pending."""
+    self._book()
+    self._inflight.clear()
+    if self._kind != "idle":
+      self._enter("idle")
+
+  def phase(self, name: str, dt: float) -> None:
+    self.phases[name] = self.phases.get(name, 0.0) + dt
+
+  def tick(self) -> None:
+    self.ticks += 1
+
+  def snapshot(self) -> dict:
+    """Everything cumulative, booked up to ``t`` (``perf_counter`` seconds): ``sum(seconds.values()) == t - self.t_started``."""
+    now = self._book()
+    return {"t": now, "ticks": self.ticks, "steps": self.steps, "seconds": dict(self.seconds), "intervals": dict(self.intervals), "phases": dict(self.phases)}

@@ -230,6 +230,37 @@ def parked_wait_ms(events: list[dict], end_ns: int) -> float:
   return round(total / 1e6, 3)
 
 
+def resident_ms(events: list[dict]) -> dict:
+  """Where the scheduler loop's wall time went while the request held a slot:
+  the batched scheduler writes its clock (``inference/sched_clock.py``) on the
+  request's ``decode`` stage (first token) and on ``released`` (finish,
+  preemption, drain), and the differences of the two, summed over the
+  request's residencies, say how many milliseconds of each interval between
+  two of its tokens a decode / mixed / speculative chunk was the oldest
+  dispatch in flight, how many a prefill (someone else's prompt: every
+  resident row waits), how many the loop had nothing dispatched — every kind
+  the clock keeps but ``idle``, which a loop with a resident row never is;
+  ``steps`` is the decode steps read back meanwhile. A residency still open
+  counts nothing; a path that writes no clock (solo, ``--pp`` / ``--sp``
+  rings) has ``steps`` 0 and no kinds."""
+  total: dict[str, float] = {}
+  steps = 0
+  first = None
+  for ev in events:
+    clock = ev["attributes"].get("clock")
+    if not clock:
+      continue
+    if ev["stage"] == "decode":
+      first = clock
+    elif ev["stage"] == "released" and first is not None:
+      for kind, s in clock["seconds"].items():
+        if kind != "idle":
+          total[kind] = total.get(kind, 0.0) + s - first["seconds"][kind]
+      steps += clock["steps"] - first["steps"]
+      first = None
+  return {**{kind: round(s * 1e3, 3) for kind, s in total.items()}, "steps": steps}
+
+
 def _active_program_families(window_ms: float) -> list[str]:
   """Program-ledger families dispatched within the last ``window_ms`` — the
   slow-request window, converted from the timeline's monotonic span to a
@@ -322,6 +353,7 @@ class Tracer:
             "threshold_ms": threshold_ms,
             "tokens": tl.get("tokens", 0),
             "stages": stage_summary(tl["events"], tl["start_ns"], tl["end_ns"] or now),
+            "resident_ms": resident_ms(tl["events"]),
             # Per-link hop attribution (exact aggregates, not the capped
             # detail): which peer link ate the time is answerable from the
             # log line alone.
@@ -529,6 +561,9 @@ class Tracer:
         # answerable without walking the event list. A request still parked
         # at query time accrues to "now".
         "parked_ms": parked_wait_ms(tl["events"], end_ns),
+        # The same question about a slow TPOT (ISSUE 41): what the scheduler
+        # loop waited for between this request's first token and its release.
+        "resident_ms": resident_ms(tl["events"]),
         "stages": stage_summary(tl["events"], tl["start_ns"], end_ns),
         "events": [
           {
