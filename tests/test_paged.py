@@ -136,6 +136,77 @@ def test_paged_kernel_ragged_rows_match_reference(quant, row):
   assert not ker[~live].any()
 
 
+_FOLD_PS = 8
+_FOLD_ROWS = {  # length of the middle row of three, from the tile width G: one softmax update folds a whole tile (PR 43)
+  "page-1": lambda g, ps: ps - 1,
+  "page": lambda g, ps: ps,
+  "page+1": lambda g, ps: ps + 1,  # a fold of one page and one token: the other columns hold pages never fetched
+  "tile-1": lambda g, ps: g * ps - 1,
+  "tile": lambda g, ps: g * ps,  # the fold is full: no masked column
+  "tile+1": lambda g, ps: g * ps + 1,  # the second fold holds one token beside the first tile's stale pages
+  "past_two_tiles": lambda g, ps: 2 * g * ps + ps + 3,
+  "empty_between_long": lambda g, ps: 0,  # the slots keep the longer row's pages; the row after fetches its own first tile
+}
+
+
+def check_fold_boundary_case(quant: str, hd: int, g: int, row: str):
+  """Three rows around the fold's boundaries, the kernel against the gather
+  reference (``test_paged_int4.py`` runs the packed cases). The kernel reads a POISONED pool: every page no row
+  holds (the trash page 0, which the table's entries past a row's length
+  name, among them) and every slot of a row's last page past its length
+  carry NaN scales and ±127 codes (NaN keys and, in the free pages, NaN
+  values in a bf16 pool) — so the tile slot that a long row leaves behind
+  holds NaN scale lanes where the next row's fold is masked; the reference
+  reads the same pool with all of that zeroed."""
+  rng = np.random.default_rng(43)
+  B, Hq, Hkv, ps = 3, 4, 2, _FOLD_PS
+  mp = 2 * g + 2
+  P = 1 + B * mp
+  q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
+  kp, vp, scales = _kernel_case_pools(rng, quant, P, Hkv, ps, hd)
+  lens = np.asarray([2 * g * ps + 5, _FOLD_ROWS[row](g, ps), g * ps + ps + 2], np.int32)
+  bt = 1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp)
+  held, tail = np.zeros(P, bool), np.zeros((P, 1, ps, 1), bool)
+  for r in range(B):
+    n = -(-int(lens[r]) // ps)
+    held[bt[r, :n]] = True
+    if lens[r] % ps:
+      tail[bt[r, n - 1], 0, lens[r] % ps :] = True  # the slots of a row's last page that it has not written yet
+    bt[r, n:] = 0
+  free = jnp.asarray(~held)[:, None, None, None]
+  unwritten = free | jnp.asarray(tail)
+  sign = jnp.asarray(rng.choice([-127, 127], size=kp.shape))
+
+  def poisoned(x, where, bad):
+    return jnp.where(where, jnp.asarray(bad, x.dtype), x), jnp.where(where, jnp.zeros((), x.dtype), x)
+
+  kp_bad, kp_ok = poisoned(kp, unwritten, sign if quant else jnp.nan)
+  # (float values a row has not written are probabilities 0 × whatever lies there, in the kernel as in the reference: those stay finite)
+  vp_bad, vp_ok = poisoned(vp, unwritten if quant else free, sign if quant else jnp.nan)
+  bad, ok = {}, {}
+  for name, x in scales.items():
+    bad[name], ok[name] = poisoned(x, unwritten, jnp.nan)
+  ker = np.asarray(paged_decode_attention(q, kp_bad, vp_bad, jnp.asarray(bt), jnp.asarray(lens), ps, pages_per_step=g, interpret=True, **bad))
+  ref = np.asarray(paged_gqa_attention_ref(q[:, None], kp_ok, vp_ok, jnp.asarray(bt), jnp.asarray(np.maximum(lens, 1)), ps, **ok)[:, 0])
+  live = lens > 0
+  assert np.isfinite(ker).all()
+  assert np.allclose(ker[live], ref[live], atol=2e-5), np.abs(ker[live] - ref[live]).max()
+  assert not ker[~live].any()
+
+
+@pytest.mark.parametrize("row", list(_FOLD_ROWS))
+@pytest.mark.parametrize("g", [4, 8])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("quant", ["", "int8"])
+def test_paged_kernel_fold_boundaries_match_reference(quant, hd, g, row):
+  """One softmax update takes a tile of pages: lengths that end one token
+  before, on and one token after a page and a tile, a length past two tiles
+  and an empty row between two long ones equal the gather reference, and
+  nothing a row does not hold — a page never fetched, a stale scale lane, a
+  slot the row before left — reaches its result (no NaN, no ±127 code)."""
+  check_fold_boundary_case(quant, hd, g, row)
+
+
 @pytest.mark.parametrize("hq,hkv", [(2, 2), (6, 2), (8, 1)])
 def test_paged_kernel_head_groupings_match_reference(hq, hkv):
   """The kernel stacks every query head's scores for one softmax update and

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_paged import _FOLD_ROWS, check_fold_boundary_case
 
 from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine
 from xotorch_support_jetson_tpu.models.config import tiny_test_config
@@ -103,6 +104,15 @@ def test_paged_kernel_wide_tiles_match_reference(pages_per_step):
   ref = paged_gqa_attention_ref(q[:, None], kp, vp, jnp.asarray(bt), lengths, ps, k_scale_pool=ks, v_scale_pool=vs)[:, 0]
   ker = paged_decode_attention(q, kp, vp, jnp.asarray(bt), lengths, ps, k_scale_pool=ks, v_scale_pool=vs, pages_per_step=pages_per_step, interpret=True)
   assert jnp.allclose(ref, ker, atol=1e-5), f"tile {pages_per_step} diverges"
+
+
+@pytest.mark.parametrize("row", list(_FOLD_ROWS))
+@pytest.mark.parametrize("g", [4, 8])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_kernel_int4_fold_boundaries_match_reference(hd, g, row):
+  """``test_paged.py``'s fold-boundary cases for packed int4 pages: both
+  nibble halves of a fold, the poisoned pool's NaN scales and ±127 bytes."""
+  check_fold_boundary_case("int4", hd, g, row)
 
 
 def test_paged_int4kv_decode_matches_dense_int4kv():

@@ -81,6 +81,11 @@ def _compile(tracked, *args, **kwargs):
   return compiled, compiled.as_text()
 
 
+def _mosaic_calls(text: str) -> list[str]:
+  """The kernel names of a compiled program's Mosaic calls, one per call."""
+  return [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line for m in [re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)] if m]
+
+
 def _compile_paged_kernel(chip, quant: str, batch: int, tile: int, hd: int, mp: int, n_pages: int, hq: int = HQ) -> str:
   from xotorch_support_jetson_tpu.ops.paged import _paged_decode_attention_impl
 
@@ -99,7 +104,7 @@ def _compile_paged_kernel(chip, quant: str, batch: int, tile: int, hd: int, mp: 
 
 # Every KV mode at the served tile (ops/paged.py PAGE_TILE) and 16/48/96 rows.
 # A tile is two VMEM slots of G whole pages (all kv heads).
-@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("hd", [64, 128, 256])
 @pytest.mark.parametrize("batch", [16, 48, 96])
 @pytest.mark.parametrize("quant", ["", "int8", "int4"])
 def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, hd):
@@ -385,7 +390,7 @@ def test_expert_kernels_compile_for_v5e(chip, what, T, L, E, held, D, F, k, dtyp
 
   compiled = jax.jit(layer).lower(_sds(chip, (T, D), jnp.bfloat16), _sds(chip, (D, E), jnp.float32), _sds(chip, (), jnp.int32), *leaves, *scales).compile()
   text = compiled.as_text()
-  calls = [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line for m in [re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)] if m]
+  calls = _mosaic_calls(text)
   assert sorted(calls) == ["moe_down", "moe_gate_up"], calls
   stack = rf"{'s8' if scaled else 'bf16'}\[{L},{E_held},({D},{F}|{F},{D})\]"
   assert {op for _, op in _takers(text, stack)} == {"custom-call"}
@@ -424,9 +429,10 @@ def test_training_a_lane_wide_moe_lowers_for_v5e(chip, monkeypatch):
   assert {m for m in re.findall(r'/(\w+)/pallas_call"', text)} >= {"moe_gate_up", "moe_down"}
 
 
-def _ling_at_the_cells_settings(chip, monkeypatch):
-  """(hf, cfg, params, pool) of ``ling-3.0-flash.decode-closed-64`` as shapes on the described chip, its programs
-  told what they see on the chip: a TPU (``ops/moe.py ffn_form`` asks the backend, which is the CPU here)."""
+def _ling_at_the_cells_settings(chip, monkeypatch, config: str = "ling-3.0-flash-ep4-d7", **cut):
+  """(hf, cfg, params, pool) of ``ling-3.0-flash.decode-closed-64`` (or of another expert configuration of the
+  benchmark, ``cut`` replacing keys of its file) as shapes on the described chip, its programs told what they see on
+  the chip: a TPU (``ops/moe.py ffn_form`` asks the backend, which is the CPU here)."""
   import json
   from dataclasses import replace
 
@@ -437,8 +443,8 @@ def _ling_at_the_cells_settings(chip, monkeypatch):
 
   monkeypatch.setattr(moe, "_on_tpu", lambda: True)
 
-  hf = json.loads((ROOT / "benchmark" / "configs" / "ling-3.0-flash-ep4-d7.json").read_text())
-  n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"]["XOT_TPU_BATCH_PAGES"])
+  hf = {**json.loads((ROOT / "benchmark" / "configs" / f"{config}.json").read_text()), **cut}
+  n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"].get("XOT_TPU_BATCH_PAGES", 257))
   cfg = replace(config_from_hf({k: v for k, v in hf.items() if not isinstance(v, dict)}), max_seq_len=int(hf["serving_window_tokens"]))
   on_chip = lambda tree: jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)  # noqa: E731
   params = on_chip(jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg)[0]))
@@ -469,7 +475,7 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch
     _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, False,
     _sds(chip, (2,), jnp.uint32), None,
   )  # fmt: skip
-  calls = [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line for m in [re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)] if m]
+  calls = _mosaic_calls(text)
   assert sorted(set(calls)) == ["moe_down", "moe_gate_up"], sorted(set(calls))
   stack = r"bf16\[(5|1),128,(2560,768|768,2560)\]"
   takers = {op for shape in (stack,) for _, op in _takers(text, shape)}
@@ -483,6 +489,29 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch
   print(f"decode.paged_batch ling B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.alias_size_in_bytes >= 6 * 64 * 32 * 128 * 128 * 4  # the pool is donated: the state is updated where it lies
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("config", ["moonlight-a3b-d14", "ling-3.0-flash-ep4-d7"])
+def test_latent_attention_decode_step_holds_no_paged_decode_call(chip, monkeypatch, config):
+  """The two latent-attention configurations of the benchmark, ASKED for the kernel (``use_kernel`` True): their
+  ``decode.paged_batch`` holds no Mosaic call named ``paged_decode`` — ``kernel_attends`` sends MLA to the XLA gather
+  whatever it was told —, so a change to the paged-decode kernel cannot move their cells (ISSUE 43). Moonlight is
+  cut to its dense layer and two expert layers, in bf16: which attention core a program takes does not depend on depth."""
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+
+  hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, config, **({} if config.startswith("ling") else {"num_hidden_layers": 3}))
+  n_slots = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"])
+  assert cfg.is_mla
+  rows = _rows(chip, n_slots)
+  _, text = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, Shard(config, 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (n_slots, 1), jnp.int32), pool,
+    _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
+    _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+  calls = _mosaic_calls(text)
+  assert calls and not [name for name in calls if "paged_decode" in name], calls  # (the experts' two kernels are there)
 
 
 def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip, monkeypatch):

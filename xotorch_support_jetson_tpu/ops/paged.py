@@ -376,9 +376,14 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths,
 # computed, and a row's last iteration already fetches the next row's first
 # tile (the slot parity crosses grid steps in SMEM), so only the call's very
 # first fetch is exposed. All Hq query heads of the row are computed per
-# fetched page, in three phases over the kv heads (score dots, one softmax
-# update over the stacked scores, value dots), carrying the online-softmax
-# state (f32 running max, sum, accumulator per query head) in VMEM scratch.
+# fetched TILE: one online-softmax update folds the whole tile of pages — or
+# the half or quarter of it that covers what a row's last tile holds — in
+# three phases over the kv heads (a score dot over the fold's tokens, one
+# softmax update over the stacked scores, a value dot), carrying the state
+# (f32 running max, sum, accumulator per query head) in VMEM scratch. Folded a
+# page at a time (until PR 43) a 64-token page filled half of every 128-deep
+# MXU tile and of every lane register and was one link of the chain through
+# that state: 0.49 µs a page where its bytes take 0.17; a tile a fold, 0.18.
 #
 # int8-KV pools ride through IN-KERNEL: k/v hold int8 codes, and the
 # per-(token, head) scales arrive lane-dense as [L, P, Hkv, lanes] (tokens
@@ -405,7 +410,13 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths,
 # the kernel. Scales are per (token, head) over the whole hd vector, so
 # one [1, ps] scale row serves both halves.
 
-PAGE_TILE = 8  # no width from 4 up wins (4-32 within 3 % at 16, 48 and 96 rows; PERF.md §6, PR 25); it sets the VMEM held: two slots of G pages
+# The tile is the DMA's granularity, the VMEM held (two slots of G pages) and, since PR 43, the widest softmax fold.
+# µs a call, Mistral's shapes (16 rows, int8, hd 128) at 1 / 4 / 7 / 15 pages a row and the closed cell's mix of 4-11:
+# G 4: 19.0 / 24.9 / 38.5 / 69.5 / 40.6; G 8: 19.9 / 25.1 / 32.9 / 55.6 / 39.4; G 16: 21.5 / 25.2 / 32.7 / 53.8 / 36.3
+# (a page at a time, any G: 19.9 / 43.8 / 68.1 / 132.0 / 69.5). 16 gains 2-8 % where rows hold 9-16 pages, loses 8 % at
+# one page, folds packed int4 12 % slower (its codes widen to int32) and doubles the VMEM and the kernel's code:
+# 8 stays (PERF.md §6, PR 43; with a per-page fold no width from 4 up won, PR 25).
+PAGE_TILE = 8
 
 
 def _page_tile(mp: int) -> int:
@@ -462,6 +473,10 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   @pl.when(b == 0)
   def _first_row():
     slot_ref[0] = 0
+    if not quantized:
+      # A fold multiplies probabilities 0 into the slot's pages that no tile
+      # has fetched yet: float codes there must be finite (integer codes are).
+      v_buf[...] = jnp.zeros_like(v_buf)
 
   first_slot = slot_ref[0]
   # The row before, if it held anything, started this row's first tile.
@@ -500,26 +515,45 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   qs = [q[h * group : (h + 1) * group] for h in range(n_heads)]
   qs = [(x[:, :kd], x[:, kd:]) if packed else (x,) for x in qs]
 
-  def attend_page(tile, slot, j):
-    """Fold page j of the slot's tile into the online softmax, in three
-    phases over the kv heads — every score dot, one softmax update over the
-    stacked [Hq, ps] scores, every value dot — so that the heads' matmuls
-    stand side by side: head by head, each dot waited for the softmax before
-    it and a page cost twice as much (PERF.md §6, PR 25)."""
-    valid = (tile * G + j) * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1) < length
+  def fold_codes(buf, slot, h, F):
+    """Kv head h's codes of the slot's first F pages, their tokens merged along sublanes: [F·ps, kd]."""
+    return jnp.concatenate([buf[slot, j, h, :, :kd] for j in range(F)], axis=0)
+
+  def fold_scales(buf, slot, F):
+    """Every kv head's scales of the slot's first F pages, their tokens merged along lanes: [Hkv, F·ps]."""
+    return jnp.concatenate([buf[slot, j, :, :ps] for j in range(F)], axis=1)
+
+  def attend_fold(tile, slot, F):
+    """Fold the first F pages of the slot's tile (F static, at least the
+    pages the tile holds) into the online softmax in ONE update: per kv head
+    one score product over the F pages' codes, [group, hd] × [F·ps, hd]ᵀ, one
+    max / exp / sum over the stacked [Hq, F·ps] scores, per kv head one value
+    product [group, F·ps] × [F·ps, hd] — whole 128-token MXU tiles and whole
+    lane registers where a page alone (64 tokens) filled half of each, and
+    one link of the m / l / acc chain where a page at a time made F (PERF.md
+    §6, PR 43: a page cost 0.49 µs folded alone, 0.18 in a fold of 8). The
+    heads' products stand side by side, in three phases, as before: head by
+    head each dot waited for the softmax before it (PR 25). Pages of the fold
+    that the row does not hold were never fetched — the slot holds what an
+    earlier tile left there — and are masked by position with SELECTS: a
+    stale scale lane may be anything, NaN too, and must not reach a product."""
+    valid = tile * G * ps + jax.lax.broadcasted_iota(jnp.int32, (1, F * ps), 1) < length
+    if quantized:
+      ks = fold_scales(ks_buf, slot, F)  # (its stale lanes end in the scores' select)
+      vs = jnp.where(valid, fold_scales(vs_buf, slot, F), 0.0)
     scores = []
     for h in range(n_heads):
       s = sum(
         jax.lax.dot_general(qx, kx.astype(dot_dtype), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        for qx, kx in zip(qs[h], code_halves(k_buf[slot, j, h, :, :kd]))
-      ) * scale  # [group, ps]
+        for qx, kx in zip(qs[h], code_halves(fold_codes(k_buf, slot, h, F)))
+      ) * scale  # [group, F·ps]
       if quantized:
         # codes·scale = true k: the per-token scale multiplies each score
         # COLUMN. One scale covers the whole hd vector, so it applies after
         # both int4 halves.
-        s = s * ks_buf[slot, j, pl.ds(h, 1), :ps]
-      scores.append(jnp.where(valid, s, NEG_INF))  # a fetched page holds at least one valid slot: the max stays finite
-    s = jnp.concatenate(scores, axis=0)  # [Hq, ps]
+        s = s * ks[h : h + 1]
+      scores.append(s)
+    s = jnp.where(valid, jnp.concatenate(scores, axis=0), NEG_INF)  # [Hq, F·ps]; a fetched tile holds at least one valid slot: the max stays finite
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -530,10 +564,16 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
     for h in range(n_heads):
       ph = p[h * group : (h + 1) * group]
       if quantized:
-        ph = ph * vs_buf[slot, j, pl.ds(h, 1), :ps]  # v's scale folds into probs (after the l update)
+        ph = ph * vs[h : h + 1]  # v's scale folds into probs (after the l update)
       # packed: even half, then odd half
-      upd.append(jnp.concatenate([jax.lax.dot_general(ph, vx.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) for vx in code_halves(v_buf[slot, j, h, :, :kd])], axis=-1))
+      upd.append(jnp.concatenate([jax.lax.dot_general(ph, vx.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) for vx in code_halves(fold_codes(v_buf, slot, h, F))], axis=-1))
     acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(upd, axis=0)
+
+  # The widths a tile's fold may take: the whole tile, or the half or the
+  # quarter of it that still covers the pages a row's last tile holds — a
+  # fold costs what its width costs, masked pages too (one page folded 8 wide
+  # 1.5 µs a row, 2 wide 1.2, as the page alone did).
+  folds = [G >> k for k in range(3) if G >> k]
 
   def tile_body(i, carry):
     slot = (first_slot + i) % 2
@@ -547,12 +587,9 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
       start(jnp.minimum(b + 1, n_rows - 1), 0, 1 - slot)
 
     tile_dmas(b, i, slot, lambda dma: dma.wait())
-
-    def page(j, c):
-      attend_page(i, slot, j)
-      return c
-
-    jax.lax.fori_loop(0, tile_pages(b, i), page, 0)
+    n = tile_pages(b, i)  # ≥ 1: the loop runs over resident tiles
+    for F, narrower in zip(folds, folds[1:] + [0]):
+      pl.when(jnp.logical_and(n <= F, n > narrower))(functools.partial(attend_fold, i, slot, F))
     return carry
 
   jax.lax.fori_loop(0, n_tiles, tile_body, 0)
