@@ -539,6 +539,56 @@ def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip, monkeypatc
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5e(chip, monkeypatch):
+  """Olmo-Hybrid's first twelve layers as ``olmo-hybrid-7b.decode-closed-64`` serves them (ISSUE 44): 64 slots of a
+  [30, 192, 96] float32 state in 9 layers, 1537 pages of 30 KV heads x 64 x 128 bf16 in 3. ``decode.paged_batch`` told
+  ``use_kernel`` is accepted by XLA:TPU beside 6.54 GB of weights: its Mosaic calls are the paged kernel's and the
+  token write's (the delta step has the XLA expression only, and a 96-wide face is no whole lane group), the paged
+  kernel takes Mistral's tile of 8 pages (two slots of 8 pages of K and of V are 15.7 MB of VMEM, inside the limit the
+  call asks for), and no instruction copies the state leaf or a layer of it. And the largest prefill the cell meets, a
+  group of 8 rows padded to 1024 tokens with the pool donated, fits beside them: the chunked delta rule's float32
+  operands at 64 positions a chunk are its temporaries."""
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, prefill_into_pages_many_sampled_inplace
+  from xotorch_support_jetson_tpu.ops.paged import PAGE_TILE, _page_tile
+
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, "olmo-hybrid-7b-d12")
+  n_slots, n_pages = pool["ssm"].shape[1], pool["k"].shape[1]
+  assert pool["k"].shape == pool["v"].shape == (3, n_pages, 30, PS, 128) and n_pages >= 1217 and pool["ssm"].shape == (9, 64, 30, 192, 96) and pool["conv"].shape == (9, 64, 3, 11520)
+  shard, mp = Shard("olmo", 0, cfg.n_layers - 1, cfg.n_layers), pages_to_cover(cfg.max_seq_len, PS)
+  rows = _rows(chip, n_slots)
+  compiled, text = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), pool,
+    _sds(chip, (n_slots, mp), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
+    _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+  kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+  # the paged kernel and the token write, once for each of the three attention layers' loops: the delta step is XLA's
+  assert len(kernels) == 6 and sum("paged_decode" in line for line in kernels) == 3 and sum("kv_token_write" in line for line in kernels) == 3, [line.strip()[:120] for line in kernels]
+  assert _page_tile(mp) == PAGE_TILE == 8  # Mistral's and granite's tile, at a page seven times theirs
+  state = r"f32\[(9,)?64,30,192,96\]"
+  copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {state}\S* (copy|copy-start|transpose)\(", line)]
+  assert not copied, copied
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch olmo B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4  # the pool is donated: the state is updated where it lies
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+  K, S = 8, 1024
+  rows = _rows(chip, K)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend: head size 128 takes the kernel on the chip
+  compiled, text = _compile(
+    prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool,
+    _sds(chip, (K, mp), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
+  )  # fmt: skip
+  mem = compiled.memory_analysis()
+  assert text.count('custom_call_target="tpu_custom_call"') >= 3  # the flash kernel in each of the three attention layers' loops
+  print(f"prefill.pages_many_sampled olmo K=8 S=1024: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  # That it compiled is the fit: XLA:TPU refuses this program from 1700 pages on ("Used 16.98G of 15.75G"; PERF.md §6, PR 44).
+  # Its temporaries are not a sum to hold against the chip: they overlap the donated pool's buffers (arguments + temp is 17.4 GB here).
+  assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4 and mem.argument_size_in_bytes < 13.0e9
+
+
 def test_compiler_refuses_a_pool_beyond_the_chip(chip, llama_1b):
   """What makes the test above a fit check: the same step over three times
   the pool is refused at compile time, not at run time."""

@@ -77,6 +77,7 @@ class ModelConfig:
   max_seq_len: int = 8192
   qkv_bias: bool = False  # qwen2 uses attention biases
   qk_norm: bool = False  # qwen3: per-head RMSNorm on q and k before rope
+  qk_norm_whole: bool = False  # OLMo 2 / olmo_hybrid: that RMSNorm over the WHOLE q and k projections, before the split into heads
   attn_out_bias: bool = False
   partial_rotary_factor: float = 1.0  # phi3/phi-4: rope only the leading channels
   tied_embedding: bool = False
@@ -127,6 +128,8 @@ class ModelConfig:
   # scaling, attention scale from query_pre_attn_scalar, and alternating
   # sliding-window attention (even layers sliding in HF's Gemma2).
   post_norms: bool = False
+  # False: no norm ahead of a sublayer. With ``post_norms`` that is OLMo 2's reordered block, h += norm(f(h)).
+  pre_norms: bool = True
   mlp_act: str = "silu"  # "silu" | "gelu_tanh"
   attn_logit_softcap: float = 0.0  # 0 ⇒ off
   final_logit_softcap: float = 0.0
@@ -141,16 +144,24 @@ class ModelConfig:
   # layer's mixer in model order (a kind of RECURRENT_KINDS | "attention";
   # empty ⇒ all attention). A recurrent layer keeps a per-slot state beside
   # the page pool instead of K/V pages; a model's recurrent layers are all of
-  # one kind. A "mamba" layer (granitemoehybrid) is a Mamba-2 mixer (one group) over ``ssm_heads``
-  # heads of ``ssm_head_dim`` with a state of ``ssm_state`` a channel, a causal
-  # depthwise convolution of ``ssm_conv`` taps, scanned in chunks of
-  # ``ssm_chunk`` at prefill (models/decoder.py). A "kda" layer (bailing_hybrid)
-  # is Kimi Delta Attention: ``ssm_heads`` heads whose state is a matrix
-  # [``ssm_head_dim`` values x ``ssm_state`` key channels], decayed per key
-  # channel and corrected by a rank-one delta rule; q, k and v pass a causal
-  # depthwise convolution of ``ssm_conv`` taps; the per-channel log decay is
-  # ``kda_lower_bound`` x a sigmoid, so ``ssm_chunk`` positions of it stay
-  # inside float32's range. The stacked parameters are named by (mixer, FFN)
+  # one kind, each with ``ssm_heads`` heads, a causal depthwise convolution of
+  # ``ssm_conv`` taps and a chunked prefill of ``ssm_chunk`` positions
+  # (models/decoder.py). Three kinds, two update rules (ops/ssm.py):
+  # - "mamba" (granitemoehybrid): a Mamba-2 mixer (one group); a head's state
+  #   is [``ssm_head_dim`` channels x ``ssm_state``], decayed by one scalar.
+  # - "kda" (bailing_hybrid): Kimi Delta Attention; a head's state is a matrix
+  #   [``ssm_head_dim`` values x ``ssm_state`` key channels], square there,
+  #   decayed per key channel and corrected by a rank-one delta rule with beta
+  #   in (0, 1). The log decay is ``kda_lower_bound`` x a sigmoid, and
+  #   ``ssm_chunk`` is held to the positions of it that stay inside float32's
+  #   range: that kind's chunked prefill factorises the pairwise decays.
+  # - "gdn" (olmo_hybrid): Gated DeltaNet; the same delta rule on a
+  #   rectangular matrix (192 values x 96 key channels as published), decayed
+  #   by ONE scalar a head whose log, -exp(A_log) softplus(a + dt_bias), has no
+  #   lower bound, beta in (0, ``gdn_beta_scale``). Its chunked prefill takes
+  #   the pairwise decays exp(G_t - G_s) as they are, never above 1, so its
+  #   ``ssm_chunk`` (64, the published chunk) needs no rule.
+  # The stacked parameters are named by (mixer, FFN)
   # pairing (``layer_stack``): ``layers`` / ``moe_layers`` the attention layers
   # with a dense / an expert FFN, ``ssm_layers`` / ``ssm_moe_layers`` the
   # recurrent ones. The page pool keeps pages for the attention layers only,
@@ -162,6 +173,7 @@ class ModelConfig:
   ssm_conv: int = 0
   ssm_chunk: int = 256
   kda_lower_bound: float = 0.0  # "kda": the log decay of a key channel lies in (kda_lower_bound, 0)
+  gdn_beta_scale: float = 1.0  # "gdn": beta = gdn_beta_scale x sigmoid; 2 where the transition may have negative eigenvalues
   # bailing_hybrid's ``use_qk_norm`` as read for its MLA layers: an RMSNorm over each query head's nope+rope channels
   # before rope, beside the latent's own norm (leaf ``q_norm``).
   mla_q_norm: bool = False
@@ -237,8 +249,8 @@ class ModelConfig:
 
   @property
   def ssm_conv_dim(self) -> int:
-    """Channels the convolution runs over: x and the one group's B and C ("mamba"); every head's q, k and v ("kda")."""
-    if self.recurrent_kind == "kda":
+    """Channels the convolution runs over: x and the one group's B and C ("mamba"); every head's q, k and v ("kda", "gdn")."""
+    if self.recurrent_kind in ("kda", "gdn"):
       return self.ssm_heads * (2 * self.ssm_state + self.ssm_head_dim)
     return self.ssm_inner + 2 * self.ssm_state
 
@@ -279,13 +291,13 @@ class ModelConfig:
     return replace(self, n_layers=n_layers)
 
 
-RECURRENT_KINDS = ("mamba", "kda")  # the ``layer_types`` whose layers keep a per-slot state (``ModelConfig.recurrent_layers``)
+RECURRENT_KINDS = ("mamba", "kda", "gdn")  # the ``layer_types`` whose layers keep a per-slot state (``ModelConfig.recurrent_layers``)
 
 # HF ``model_type`` (or, with its underscores dropped, the ``architectures`` entry) -> family; first match wins, so
 # a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
 MODEL_FAMILIES = {
   "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
-  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "llama": "llama",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "llama": "llama",
 }
 
 
@@ -459,6 +471,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     hybrid = _granite_hybrid_fields(hf)
   if family == "bailing-hybrid":
     hybrid = _bailing_hybrid_fields(hf)
+  if family == "olmo-hybrid":
+    hybrid = _olmo_hybrid_fields(hf)
 
   n_heads = int(hf["num_attention_heads"])
   return ModelConfig(
@@ -470,11 +484,11 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if family == "granite-hybrid" else int(hf["intermediate_size"]),
     head_dim=int(hf.get("head_dim") or 0),
     norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
-    rope_theta=float(hf.get("rope_theta", 10000.0)),
+    rope_theta=float(hf.get("rope_theta") or 10000.0),
     rope_scaling=rope_scaling,
     max_seq_len=int(hf.get("max_position_embeddings", 8192)),
     qkv_bias=family in ("qwen2", "qwen2-moe") or bool(hf.get("attention_bias", False)),
-    qk_norm=family in ("qwen3", "qwen3-moe"),
+    qk_norm=family in ("qwen3", "qwen3-moe", "olmo-hybrid"),
     partial_rotary_factor=float(hf.get("partial_rotary_factor", 1.0)),
     tied_embedding=bool(hf.get("tie_word_embeddings", family in ("gemma2", "granite-hybrid") or (family == "qwen2" and int(hf["hidden_size"]) < 2048))),
     family=family,
@@ -552,9 +566,43 @@ def _bailing_hybrid_fields(hf: dict) -> dict:
     ssm_head_dim=head_dim,
     ssm_state=head_dim,
     ssm_conv=int(hf.get("short_conv_kernel_size") or 4),
-    ssm_chunk=max(int(80.0 / -lower), 1),  # exp(±lower·chunk) stays inside float32 (e^80 = 5.5e34): 16 positions at -5
+    # "kda" alone: its scan factorises the pairwise decays, and exp(±lower·chunk) has to stay inside float32
+    # (e^80 = 5.5e34): 16 positions at -5. ("gdn" exponentiates the differences themselves, at most 0: no rule.)
+    ssm_chunk=max(int(80.0 / -lower), 1),
     kda_lower_bound=lower,
     mla_q_norm=bool(hf.get("use_qk_norm", False)),
+  )
+
+
+def _olmo_hybrid_fields(hf: dict) -> dict:
+  """``olmo_hybrid`` (Olmo-Hybrid) → the hybrid fields of ModelConfig: ``layer_types`` names each layer
+  "linear_attention" (a Gated-DeltaNet layer, kind "gdn") or "full_attention"; OLMo 2's reordered block (a norm on each
+  sublayer's output, none ahead of it) and its RMSNorm over the whole q and k projections; no position term in the
+  attention layers (``rope_parameters.rope_theta`` null). What the decoder does not implement is refused here, by name."""
+  n_layers = int(hf["num_hidden_layers"])
+  names = {"linear_attention": "gdn", "full_attention": "attention"}
+  layer_types = tuple(hf.get("layer_types") or ())
+  if len(layer_types) != n_layers or set(layer_types) - set(names):
+    raise ValueError(f"olmo_hybrid: layer_types must name {n_layers} layers, each 'linear_attention' or 'full_attention'; got {layer_types}")
+  if hf.get("attention_bias"):
+    raise ValueError("olmo_hybrid: attention_bias true is not supported")
+  if hf.get("rope_theta") is not None or (hf.get("rope_parameters") or {}).get("rope_theta") is not None or hf.get("rope_scaling"):
+    raise ValueError("olmo_hybrid: a rope_theta that is not null (rotary attention layers beside the recurrent ones) is not supported")
+  heads = int(hf["linear_num_value_heads"])
+  if int(hf.get("linear_num_key_heads") or heads) != heads:
+    raise ValueError("olmo_hybrid: linear_num_key_heads other than linear_num_value_heads (grouped keys) is not supported")
+  return dict(
+    layer_types=tuple(names[t] for t in layer_types),
+    ssm_heads=heads,
+    ssm_head_dim=int(hf["linear_value_head_dim"]),
+    ssm_state=int(hf["linear_key_head_dim"]),
+    ssm_conv=int(hf.get("linear_conv_kernel_dim") or 4),
+    ssm_chunk=64,  # Gated DeltaNet's published chunk
+    gdn_beta_scale=2.0 if hf.get("linear_allow_neg_eigval") else 1.0,
+    qk_norm_whole=True,
+    pre_norms=False,
+    post_norms=True,
+    use_rope=False,
   )
 
 

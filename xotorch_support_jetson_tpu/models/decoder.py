@@ -202,6 +202,11 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     w_f [L, D, H*N], b_f [L, H*N] f32 (the decay gate)     w_bg [L, D, 2H] (β | output gate, one a head)
     o_norm [L, P] (the per-head output norm)               w_out [L, H*P, D]
     + mlp_norm and the FFN's leaves (dense or expert)
+  A "gdn" stack (Gated DeltaNet: H heads, N key and P value channels a head, N != P as published):
+    w_qkv [L, D, H*(2N+P)]   conv_w [L, K, H*(2N+P)]   w_ab [L, D, 2H] (the decay's step a | β's b, one a head)
+    A_log, dt_bias [L, H] f32   w_z [L, D, H*P] (the output gate)   o_norm [L, P]   w_out [L, H*P, D]
+  Without ``cfg.pre_norms`` no stack has ``attn_norm`` / ``ssm_norm`` / ``mlp_norm``; with ``cfg.post_norms`` each has
+  ``post_attn_norm`` (``post_ssm_norm`` in a recurrent stack) and ``post_mlp_norm`` [L, D].
   """
   dtype = dtype or cfg.dtype
   L = shard.n_shard_layers
@@ -245,9 +250,9 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       leaves["bq"] = jnp.zeros((L, Qd), dtype=dtype)
       leaves["bk"] = jnp.zeros((L, Kd), dtype=dtype)
       leaves["bv"] = jnp.zeros((L, Kd), dtype=dtype)
-    if cfg.qk_norm:  # qwen3 per-head q/k RMSNorm weights [hd]
-      leaves["q_norm"] = jnp.ones((L, cfg.head_dim), dtype=dtype)
-      leaves["k_norm"] = jnp.ones((L, cfg.head_dim), dtype=dtype)
+    if cfg.qk_norm:  # qwen3 per-head q/k RMSNorm weights [hd]; OLMo 2's over the whole projections [Qd], [Kd]
+      leaves["q_norm"] = jnp.ones((L, Qd if cfg.qk_norm_whole else cfg.head_dim), dtype=dtype)
+      leaves["k_norm"] = jnp.ones((L, Kd if cfg.qk_norm_whole else cfg.head_dim), dtype=dtype)
     return leaves
 
   def dense_stack(L):
@@ -309,16 +314,40 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       "w_out": w(next(keys), Ls, H * P, D),
     }
 
+  def gdn_leaves(Ls):
+    H, P, C = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_conv_dim
+    dt = jnp.exp(jax.random.uniform(next(keys), (Ls, H), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))  # Mamba-2's gate and its initialisation
+    return {
+      "w_qkv": w(next(keys), Ls, D, C),
+      "conv_w": w(next(keys), Ls, cfg.ssm_conv, C, scale=cfg.ssm_conv**-0.5),
+      "w_ab": w(next(keys), Ls, D, 2 * H),
+      "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+      "A_log": jnp.log(jax.random.uniform(next(keys), (Ls, H), jnp.float32, 1.0, 16.0)),
+      "w_z": w(next(keys), Ls, D, H * P),
+      "o_norm": jnp.ones((Ls, P), dtype=dtype),
+      "w_out": w(next(keys), Ls, H * P, D),
+    }
+
+  def block_norms(stack: Params, n: int, mixer: str) -> Params:
+    """A hybrid stack's mixer leaves with the block's norms: those ahead of its two sublayers dropped without
+    ``cfg.pre_norms``, those after them added with ``cfg.post_norms``."""
+    stack = {"mlp_norm": jnp.ones((n, D), dtype=dtype), **stack}
+    if not cfg.pre_norms:
+      stack = {name: leaf for name, leaf in stack.items() if name not in (f"{mixer}_norm", "mlp_norm")}
+    if cfg.post_norms:
+      stack |= {name: jnp.ones((n, D), dtype=dtype) for name in (f"post_{mixer}_norm", "post_mlp_norm")}
+    return stack
+
   params: Params = {}
   if cfg.recurrent_layers:
     if not (shard.is_first_layer and shard.is_last_layer):
       raise ValueError("a configuration with recurrent layers is built whole: its stacks do not split by a layer range")
     keys = iter(jax.random.split(next(keys), 96))  # up to four stacks of up to 17 drawn leaves
     names = [cfg.layer_stack(i) for i in range(cfg.n_layers)]
-    recurrent_leaves = kda_leaves if cfg.recurrent_kind == "kda" else mamba_leaves
+    recurrent_leaves = {"mamba": mamba_leaves, "kda": kda_leaves, "gdn": gdn_leaves}[cfg.recurrent_kind]
     for name in dict.fromkeys(names):  # in the order the model meets them
-      n = names.count(name)
-      mixer = {**recurrent_leaves(n), "mlp_norm": jnp.ones((n, D), dtype=dtype)} if name.startswith("ssm_") else attn_leaves(n)
+      n, recurrent = names.count(name), name.startswith("ssm_")
+      mixer = block_norms(recurrent_leaves(n), n, "ssm") if recurrent else block_norms(attn_leaves(n), n, "attn")
       params[name] = {**mixer, **(expert_ffn(n) if name.endswith("moe_layers") else {"w_gate": w(next(keys), n, D, F), "w_up": w(next(keys), n, D, F), "w_down": w(next(keys), n, F, D)})}
   elif cfg.n_experts:
     # MoE model: dense prefix (layers [0, first_k_dense) globally), MoE rest.
@@ -453,10 +482,13 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
     q = q + p["bq"]
     k = k + p["bk"]
     v = v + p["bv"]
+  if cfg.qk_norm_whole:  # OLMo 2: RMSNorm over the whole q and k projections, before the split into heads
+    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+    k = rms_norm(k, p["k_norm"], cfg.norm_eps)
   q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
   k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
   v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-  if "q_norm" in p:  # qwen3: per-head RMSNorm on q/k before rope
+  if "q_norm" in p and not cfg.qk_norm_whole:  # qwen3: per-head RMSNorm on q/k before rope
     q = rms_norm(q, p["q_norm"], cfg.norm_eps)
     k = rms_norm(k, p["k_norm"], cfg.norm_eps)
   if cfg.attn_multiplier:
@@ -503,7 +535,7 @@ def _mlp_block(h, p, cfg: ModelConfig):
   the number of distinct held experts the rows chose (0 and 0 for a dense FFN)."""
   B, S, D = h.shape
   with jax.named_scope("xot.ffn"):
-    x = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
+    x = rms_norm(h, p["mlp_norm"], cfg.norm_eps) if "mlp_norm" in p else h  # (absent: a block whose norms follow its sublayers)
   aux, visited = jnp.float32(0.0), jnp.int32(0)
   if "w_experts_gate" in p:  # routed MoE FFN (ops/moe.py) + optional shared expert
     from ..ops.moe import moe_ffn
@@ -555,7 +587,7 @@ def _mlp_block(h, p, cfg: ModelConfig):
     with jax.named_scope("xot.ffn"):
       gated = _mlp_act(_mm(x, p, "w_gate", cfg.quant_compute), cfg).astype(h.dtype) * _mm(x, p, "w_up", cfg.quant_compute)
       out = _mm(gated, p, "w_down", cfg.quant_compute)
-      if "post_mlp_norm" in p:  # gemma2 post-feedforward layernorm
+      if "post_mlp_norm" in p:  # gemma2's post-feedforward layernorm; OLMo 2's only one
         out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
       h = _residual(h, out, cfg)
   return h, aux, visited
@@ -590,7 +622,10 @@ def _ssm_in(h, p, cfg: ModelConfig):
 
 @component_scope("xot.ssm_proj")
 def _ssm_out(h, y, p, cfg: ModelConfig):
-  return _residual(h, _mm(y, p, "w_out", cfg.quant_compute), cfg)
+  out = _mm(y, p, "w_out", cfg.quant_compute)
+  if "post_ssm_norm" in p:  # a block whose norm follows the mixer (cfg.post_norms)
+    out = rms_norm(out, p["post_ssm_norm"], cfg.norm_eps)
+  return _residual(h, out, cfg)
 
 
 def _ssm_conv(xbc, conv0, p):
@@ -843,6 +878,141 @@ def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
   return h, pool, visited
 
 
+# --------------------------------------------------- Gated DeltaNet (GDN) mixer
+# (olmo_hybrid's "gdn" layers; Gated Delta Networks, arXiv:2412.06464, with the negative-eigenvalue β of
+# arXiv:2411.12537.) Per head, with N key and P value channels (96 and 192 as published: the state is not square):
+#   [q~ | k~ | v~] = silu(conv(x W_qkv));  q = l2norm(q~)/sqrt(N), k = l2norm(k~), v = v~;
+#   g = -exp(A_log) * softplus(x W_a + dt_bias), ONE log decay a head with no lower bound, alpha = exp(g);
+#   beta = gdn_beta_scale * sigmoid(x W_b);
+#   S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T  (S [P, N]),  o_t = S_t q_t;
+#   out = (rmsnorm_head(o_t) * silu(x W_z)) W_out — the norm first, the gate after.
+# No norm ahead of the mixer: the block's one norm follows ``W_out`` (``_ssm_out``). The state rides the pool's ``ssm``
+# leaf as [H, P, N] and is stepped by the delta rule's one owner, ``ops/ssm.py kda_state_step``, with alpha spread over
+# the key channels; prefill scans ``ssm_chunk`` positions at a time (``_gdn_chunk_scan``).
+
+
+@component_scope("xot.ssm_proj")
+def _gdn_in(h, p, cfg: ModelConfig):
+  """The input projections: h [B,S,D] → qkv [B,S,H(2N+P)], z [B,S,HP] (the output gate), ab [B,S,2H] (the decay's step | β)."""
+  return tuple(_mm(h, p, name, cfg.quant_compute) for name in ("w_qkv", "w_z", "w_ab"))
+
+
+def _gdn_gates(qkv, ab, p, cfg: ModelConfig):
+  """Activated [q|k|v] [..., H(2N+P)] and the gates' pre-activations ab [..., 2H] → float32 q, k [..., H, N] (unit norm;
+  q scaled by 1/sqrt(N)), v [..., H, P], g [..., H] the log decay (at most 0, unbounded below), beta [..., H]. (The
+  split and the unit norms are ``_kda_gates``'s own lines: shared, they reorder that kind's lowered programs.)"""
+  H, N, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
+  lead = qkv.shape[:-1]
+  qkv, ab = qkv.astype(jnp.float32), ab.astype(jnp.float32)
+  q, k, v = qkv[..., : H * N].reshape(*lead, H, N), qkv[..., H * N : 2 * H * N].reshape(*lead, H, N), qkv[..., 2 * H * N :].reshape(*lead, H, P)
+  unit = lambda t: t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + _L2_EPS)  # noqa: E731
+  g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(ab[..., :H] + p["dt_bias"].astype(jnp.float32))
+  return unit(q) * N**-0.5, unit(k), v, g, cfg.gdn_beta_scale * jax.nn.sigmoid(ab[..., H:])
+
+
+def _gdn_out(y, z, p, cfg: ModelConfig, dtype):
+  """The per-head norm of o [..., H, P] f32, then the gate silu(z) [..., H*P], heads joined: [..., H*P] in ``dtype``."""
+  y = rms_norm(y, p["o_norm"], cfg.norm_eps) * jax.nn.silu(z.astype(jnp.float32)).reshape(y.shape)
+  return y.reshape(z.shape).astype(dtype)
+
+
+def _unit_lower_inverse(T, mm):
+  """The inverse of unit lower-triangular T [..., L, L], L = 8 x a power of two, by matrix products (``mm``) alone: the
+  8 x 8 diagonal blocks I + A by the finite product (I - A)(I + A^2)(I + A^4) of their nilpotent part, then pairs of
+  inverted blocks a, d around T's block c merged as [[a, 0], [-d c a, d]]. (The product formula on a whole chunk of 64
+  would carry powers up to A^32, whose entries reach 1e5 where the inverse's are of order 1: float32 cancels there.)"""
+  L = T.shape[-1]
+  blocks = lambda s: jnp.stack([T[..., i : i + s, i : i + s] for i in range(0, L, s)], axis=-3)  # noqa: E731 — [..., L/s, s, s]
+  eye = jnp.eye(8, dtype=T.dtype)
+  power = blocks(8) - eye
+  inv = eye - power
+  for _ in range(2):
+    power = mm("...ij,...jk->...ik", power, power)
+    inv = mm("...ij,...jk->...ik", inv, eye + power)
+  s = 8
+  while s < L:
+    a, d = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+    c = -mm("...ij,...jk->...ik", mm("...ij,...jk->...ik", d, blocks(2 * s)[..., s:, :s]), a)
+    inv = jnp.concatenate([jnp.concatenate([a, jnp.zeros_like(a)], axis=-1), jnp.concatenate([c, d], axis=-1)], axis=-2)
+    s *= 2
+  return inv[..., 0, :, :]
+
+
+def _gdn_chunk_scan(q, k, v, g, beta, state, chunk: int):
+  """The delta rule under a scalar decay over a sequence, ``chunk`` positions (8 x a power of two) at a time.
+
+  q, k [B,S,H,N], v [B,S,H,P], g, beta [B,S,H], state [B,H,P,N], all float32; g = 0 and beta = 0 at a padded position
+  (the state passes it unchanged). Returns (o [B,S,H,P], state after position S-1).
+
+  As ``_kda_chunk_scan``, with one G_t = sum of g a head: the updates W solve the unit lower-triangular system
+    (I + Diag(beta) (K K^T * D)) W = Diag(beta) (V - e^G * (K S_0^T)),   D[t,s] = e^(G_t - G_s)  (s <= t),
+  and o_t = e^(G_t) S_0 q_t + sum_(s<=t) W_s (q_t . k_s) D[t,s]. The pairwise decays are taken AS differences on the
+  [L, L] lower triangle — a scalar a head makes that one small matrix — so nothing exceeds 1 whatever the gate says:
+  this kind's log decay has no lower bound, and the factorised form e^(G_t) x e^(-G_s) would leave float32 at the
+  third position of -30. Products are float32 at ``highest`` precision, as the KDA scan's."""
+  B, S, H, _ = q.shape
+  L = min(chunk, max(8, 1 << (S - 1).bit_length()))
+  assert L % 8 == 0 and L & (L - 1) == 0, f"a gdn chunk is 8 x a power of two positions; got {chunk}"
+  pad = -S % L
+  if pad:
+    q, k, v, g, beta = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)) for t in (q, k, v, g, beta))
+  chunks = lambda t: jnp.moveaxis(t.reshape(B, -1, L, *t.shape[2:]), 1, 0)  # noqa: E731 — [c, B, L, ...]
+  through, lower, eye = jnp.tril(jnp.ones((L, L), bool)), jnp.tril(jnp.ones((L, L), bool), -1), jnp.eye(L, dtype=jnp.float32)
+  mm = partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+  def body(state, per_chunk):
+    qc, kc, vc, gc, bc = per_chunk
+    G = jnp.cumsum(gc, axis=1)  # [B,L,H], falling from 0
+    # log D[t,s] = g_(s+1) + ... + g_t summed AS ITSELF (a cumulative sum down each column of the strict lower triangle),
+    # not G_t - G_s: a difference of two sums of hundreds carries their rounding into a decay of hundredths.
+    seg = jnp.cumsum(jnp.where(lower, jnp.moveaxis(gc, 1, 2)[..., :, None], 0.0), axis=-2)  # [B,H,l,s]
+    D = jnp.where(through, jnp.exp(seg), 0.0)  # at most 1
+    grow = jnp.exp(G)[..., None]  # [B,L,H,1], at most 1
+    T = eye + jnp.where(lower, mm("blhn,bshn->bhls", kc, kc) * D, 0.0) * jnp.moveaxis(bc, 1, 2)[..., None]
+    w = mm("bhls,bshp->blhp", _unit_lower_inverse(T, mm), bc[..., None] * (vc - grow * mm("blhn,bhpn->blhp", kc, state)))
+    o = grow * mm("blhn,bhpn->blhp", qc, state) + mm("bhls,bshp->blhp", mm("blhn,bshn->bhls", qc, kc) * D, w)
+    to_end = jnp.moveaxis(D[:, :, -1, :], 1, 2)[..., None]  # [B,L,H,1]: from s to the chunk's end
+    return state * jnp.exp(G[:, -1])[:, :, None, None] + mm("bshp,bshn->bhpn", w * to_end, kc), o
+
+  state, o = jax.lax.scan(body, state, tuple(chunks(t) for t in (q, k, v, g, beta)))
+  return jnp.moveaxis(o, 0, 1).reshape(B, S + pad, H, -1)[:, :S], state
+
+
+def _gdn_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
+  """One Gated-DeltaNet layer over a sequence, as ``_kda_layer``: h [B,S,D], the rows' states ssm0 [B,H,P,N] f32 and
+  conv0 [B,K-1,C] → (h, ssm, conv) after each row's ``seq_lens`` tokens; padding moves neither leaf."""
+  S = h.shape[1]
+  qkv, z, ab = _gdn_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    qkv, xp = _ssm_conv(qkv, conv0, p)
+    q, k, v, g, beta = _gdn_gates(qkv, ab, p, cfg)
+    if seq_lens is not None:
+      valid = (jnp.arange(S, dtype=jnp.int32)[None, :] < seq_lens[:, None])[..., None]
+      g, beta = jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+    conv = _conv_tail(xp, S, seq_lens)
+    y, ssm = _gdn_chunk_scan(q, k, v, g, beta, ssm0, cfg.ssm_chunk)
+    y = _gdn_out(y, z, p, cfg, h.dtype)
+  h, *_ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, ssm, conv.astype(conv0.dtype)
+
+
+def _gdn_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
+  """One delta-rule step of one Gated-DeltaNet layer for every slot row, as ``_kda_decode_step``: the head's one decay
+  is spread over the key channels and the step is ``ops/ssm.py kda_state_step``'s. Returns (h, pool, 0 experts visited)."""
+  from ..ops.ssm import kda_state_step
+
+  qkv, z, ab = _gdn_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
+    qkv, xp = _ssm_conv(qkv, conv0, p)
+    q, k, v, g, beta = _gdn_gates(qkv[:, 0], ab[:, 0], p, cfg)
+    ssm, y = kda_state_step(pool["ssm"], layer, jnp.broadcast_to(jnp.exp(g)[..., None], k.shape), beta, k, v, q, active)
+    pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
+    y = _gdn_out(y[:, None], z, p, cfg, h.dtype)
+  h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, pool, visited
+
+
 # A hybrid's latent-attention layers take a prefill's queries this many positions at a time (ops/attention.py
 # mla_absorbed_attention ``q_block``): its pool is donated with per-slot state beside the weights, and the float32 scores
 # of a whole group against the gathered window do not fit there (AOT, tests/test_tpu_compile.py; PERF.md §6, PR 36).
@@ -869,11 +1039,11 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
   kv_positions = jnp.arange(carry[pages[0]].shape[2], dtype=jnp.int32) if pages else positions[0]
 
   def step(h, carry, lp, layer):
-    if "ssm_norm" not in lp:  # an attention layer (dense or latent): ``layer`` counts the pool's page layers
+    if "w_out" not in lp:  # an attention layer (dense or latent): ``layer`` counts the pool's page layers
       kv = {name: jax.lax.dynamic_index_in_dim(carry[name], layer, 0, keepdims=False) for name in pages} or None
       h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, bool(pages), adapter_ids=adapter_ids, mla_q_block=_HYBRID_MLA_Q_BLOCK)
       return h, {**carry, **{name: jax.lax.dynamic_update_index_in_dim(carry[name], kv[name], layer, 0) for name in pages}}
-    over_sequence = _kda_layer if "w_f" in lp else _ssm_layer
+    over_sequence = _kda_layer if "w_f" in lp else _gdn_layer if "w_ab" in lp else _ssm_layer
     if "ssm" not in carry:
       ssm0 = jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
       h, _, _ = over_sequence(h, lp, cfg, ssm0, jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), h.dtype), seq_lens)
@@ -906,7 +1076,7 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
   p = layer_params
 
   with jax.named_scope("xot.attn_proj"):
-    x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+    x = rms_norm(h, p["attn_norm"], cfg.norm_eps) if "attn_norm" in p else h
   if "wkv_a" in p and use_cache:
     # MLA with cache: write only the latent (+rope channel) and attend via
     # weight absorption (ops/attention.py mla_absorbed_attention) — the cache
@@ -1787,7 +1957,7 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
   """
   B, S, D = h.shape
   with jax.named_scope("xot.attn_proj"):
-    x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+    x = rms_norm(h, p["attn_norm"], cfg.norm_eps) if "attn_norm" in p else h
   pos = positions[:, 0]
   lengths = pos + 1  # valid KV slots incl. the token written below
   from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref
@@ -1844,6 +2014,8 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
       h, pool, visited = _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
     elif "w_f" in lp:
       h, pool, visited = _kda_decode_step(h, pool, lp, layer, active, cfg)
+    elif "w_ab" in lp:
+      h, pool, visited = _gdn_decode_step(h, pool, lp, layer, active, cfg)
     else:
       h, pool, visited = _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
     return (h, seen + visited), pool
@@ -2038,7 +2210,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
   plain chunk program in paged mode)."""
   B, W, D = h.shape
   with jax.named_scope("xot.attn_proj"):
-    x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+    x = rms_norm(h, p["attn_norm"], cfg.norm_eps) if "attn_norm" in p else h
   from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref
 
   if kv_quant is None:

@@ -54,7 +54,7 @@ def export_hf_checkpoint(out_dir: str | Path, cfg: ModelConfig, params: dict, dt
   lm_head]) in the decoder layout (stacked [L, ...] leaves).
   """
   if cfg.family not in _MODEL_TYPE:
-    raise NotImplementedError(f"HF export supports {sorted(_MODEL_TYPE)}; {cfg.family!r} (MLA layouts) is not exportable")
+    raise NotImplementedError(f"HF export supports {sorted(_MODEL_TYPE)}; {cfg.family!r} (MLA layouts, and the hybrids with no safetensors name map) is not exportable")
   if cfg.is_mla:
     raise NotImplementedError("HF export of MLA (deepseek) trees is not supported")
   if cfg.vision is not None:

@@ -180,14 +180,17 @@ def _weight_files_for_shard(model_dir: Path, shard: Shard) -> list[Path]:
   return [model_dir / f for f in sorted(needed)]
 
 
+_NO_NAME_MAP = {"bailing-hybrid": "bailing_hybrid (Ling-3.0)", "olmo-hybrid": "olmo_hybrid (Olmo-Hybrid)"}  # family -> the model_type refused by name
+
+
 def load_shard_weights(model_dir: str | Path, cfg: ModelConfig, shard: Shard) -> Params:
   """Load a shard's params from HF safetensors into the decoder layout."""
   from safetensors import safe_open
 
-  if cfg.family == "bailing-hybrid":
-    # No HF modelling code for ``bailing_hybrid`` was at hand to take the tensor names from: the decoder serves the
+  if cfg.family in _NO_NAME_MAP:
+    # No safetensors index of these families was at hand to take the tensor names from: the decoder serves the
     # architecture from a parameter tree (tests, the benchmark's seeded weights), not from a checkpoint.
-    raise NotImplementedError("bailing_hybrid (Ling-3.0) checkpoints cannot be loaded: this loader has no safetensors name map for the family")
+    raise NotImplementedError(f"{_NO_NAME_MAP[cfg.family]} checkpoints cannot be loaded: this loader has no safetensors name map for the family")
   model_dir = Path(model_dir)
   per_layer: dict[int, dict[str, np.ndarray]] = {i: {} for i in range(shard.start_layer, shard.end_layer + 1)}
   top: dict[str, np.ndarray] = {}

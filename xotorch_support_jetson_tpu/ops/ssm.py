@@ -1,4 +1,4 @@
-"""The decode step of a recurrent layer's per-slot state: one owner, two update rules.
+"""The decode step of a recurrent layer's per-slot state: one owner, two update rules, three kinds of layer.
 
 A hybrid's page pool carries, beside its K/V pages, the leaf ``ssm``
 [Ls, slots, H, P, N] in float32 (``ops/paged.py init_paged_pool``): each slot
@@ -31,14 +31,21 @@ program takes is read from what it can observe — the ``use_kernel`` its
 dispatch resolved (``paged_kernel_supported``: a TPU) and the leaf's shape
 and dtype (``one_pass_supported``) — and set nowhere.
 
-The second rule is the **delta rule** of a Kimi-Delta-Attention layer
-(``kda_state_step``), over the same leaf with P the value channels and N the
-key channels of a head's matrix state:
+The second rule is the **delta rule** (``kda_state_step``) of a
+Kimi-Delta-Attention layer ("kda") and of a Gated-DeltaNet layer ("gdn"), over
+the same leaf with P the value channels and N the key channels of a head's
+matrix state — square for "kda" (128 x 128 as published), rectangular for
+"gdn" (192 x 96):
 
   S ← S · Diag(α)       u = β (v − S k)       S ← S + u ⊗ k       y = S q
 
 with α [B, H, N] the decay of each key channel, β [B, H], k and q [B, H, N],
-v [B, H, P]. It has the reference expression only: one pass reads the state
+v [B, H, P]. "kda" decays every key channel by its own α and keeps β in (0, 1);
+"gdn" has ONE α a head, which its caller spreads over N (``models/decoder.py
+_gdn_decode_step``), and β in (0, 2): the same step, which asks nothing of
+either. A face whose N is not whole lanes (gdn's 96) is stored lane-padded by
+the TPU, a third more bytes than the state holds (PERF.md §6, PR 44). The rule
+has the reference expression only: one pass reads the state
 for the two contractions it needs of the decayed state (S·Diag(α) with k and
 with q; y = (S·Diag(α)) q + u (k·q)), a second reads it again and writes the
 update — two reads and a write where the least is one of each. A one-pass
@@ -75,9 +82,9 @@ STATE_STEP_FORMS = ("one_pass", "reference", "delta_reference")
 
 
 def state_step_form(ssm_leaf, use_kernel: bool, kind: str = "mamba") -> str:
-  """The name of the rule and form a decode program of ``kind`` layers ("mamba" | "kda") steps this leaf in: the label
-  of the gauge ``recurrent_state_step``."""
-  if kind == "kda":
+  """The name of the rule and form a decode program of ``kind`` layers ("mamba" | "kda" | "gdn") steps this leaf in: the
+  label of the gauge ``recurrent_state_step``."""
+  if kind in ("kda", "gdn"):  # the delta rule, whatever the decay's and the face's shape
     return "delta_reference"
   return "one_pass" if one_pass_supported(ssm_leaf, use_kernel) else "reference"
 
@@ -102,7 +109,7 @@ def _state_step_reference(ssm_leaf, layer, a, dtx, bm, cm, active):
 
 
 def kda_state_step(ssm_leaf, layer, alpha, beta, k, v, q, active):
-  """One delta-rule step of Kimi-Delta-Attention layer ``layer`` for every slot row.
+  """One delta-rule step of Kimi-Delta-Attention or Gated-DeltaNet layer ``layer`` for every slot row.
 
   ssm_leaf [Ls, B, H, P, N] float32, stepped in place at ``layer`` (a traced scalar); alpha [B, H, N] the decay of
   each key channel; beta [B, H]; k, q [B, H, N]; v [B, H, P]; active [B] bool — all float32. Returns (ssm_leaf, y

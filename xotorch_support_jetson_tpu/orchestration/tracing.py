@@ -46,7 +46,10 @@ from collections import OrderedDict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-MAX_TIMELINES = 256
+# As many finished timelines as live contexts (below). At 256 a 64-slot server that finishes ~300 requests in a 51 s
+# window had dropped the window's first fifth by its end, and what reads a window from its requests' timelines (the
+# benchmark's wall-clock readers) found the stretch before its capture covered by a half, give or take (PERF.md §6, PR 44).
+MAX_TIMELINES = 1024
 # Live TraceContexts are bounded the same way (satellite of ISSUE 4): a
 # request cancelled or failed before end_request used to leave its context in
 # the dict forever. LRU-evicting at this cap loses only token-group cadence
