@@ -379,9 +379,10 @@ def test_the_scheduler_serves_interleaved_requests_as_the_reference_does(monkeyp
   assert CFG.recurrent_layers == 5 and server.tier is None and not server.spec and not server._mixed_active() and not server.ops.mixed_tick_supported() and server.ops.prefill_donates_pool
   assert resets() - before == 3
   assert metrics.gauge_value("recurrent_state_bytes") == 2 * 5 * (6 * 16 * 8 * 4 + 3 * 6 * 32 * 4)
-  assert metrics.gauge_value("recurrent_state_step", labels={"form": "delta_reference"}) == 1 and metrics.gauge_value("recurrent_state_step", labels={"form": "one_pass"}) == 0
-  leaf = jax.ShapeDtypeStruct((9, 64, 30, 192, 96), jnp.float32)  # the published leaf: a 96-wide face is no whole lane group, told ``use_kernel`` or not
-  assert ssm_ops.state_step_form(leaf, True, "gdn") == "delta_reference" and not ssm_ops.one_pass_supported(leaf, True)
+  forms = {form: metrics.gauge_value("recurrent_state_step", labels={"form": form}) for form in ssm_ops.STATE_STEP_FORMS}
+  assert forms == {"one_pass": 0, "reference": 0, "delta_one_pass": 0, "delta_reference": 1}  # a CPU: the XLA expression
+  leaf = jax.ShapeDtypeStruct((9, 64, 30, 192, 96), jnp.float32)  # the published leaf: a 96-wide face is no whole lane group, which shuts out the Mamba kernel and not the delta rule's (ISSUE 45)
+  assert ssm_ops.state_step_form(leaf, True, "gdn") == "delta_one_pass" and ssm_ops.state_step_form(leaf, False, "gdn") == "delta_reference" and not ssm_ops.one_pass_supported(leaf, True)
   assert capsys.readouterr().out.count("keep a recurrent state per slot") == 1
 
 

@@ -395,7 +395,10 @@ def test_the_scheduler_serves_interleaved_requests_as_the_reference_does(monkeyp
   assert CFG.recurrent_layers == 3 and server.tier is None and not server.spec and not server._mixed_active() and not server.ops.mixed_tick_supported() and server.ops.prefill_donates_pool
   assert resets() - before == 3
   assert metrics.gauge_value("recurrent_state_bytes") == 2 * 3 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4)
-  assert metrics.gauge_value("recurrent_state_step", labels={"form": "delta_reference"}) == 1 and metrics.gauge_value("recurrent_state_step", labels={"form": "one_pass"}) == 0
+  forms = {form: metrics.gauge_value("recurrent_state_step", labels={"form": form}) for form in ssm_ops.STATE_STEP_FORMS}
+  assert forms == {"one_pass": 0, "reference": 0, "delta_one_pass": 0, "delta_reference": 1}  # a CPU: the XLA expression
+  leaf = jax.ShapeDtypeStruct((6, 64, 32, 128, 128), jnp.float32)  # the published leaf, as the cell's pool holds it
+  assert ssm_ops.state_step_form(leaf, True, "kda") == "delta_one_pass" and ssm_ops.state_step_form(leaf, False, "kda") == "delta_reference"
   assert (metrics.gauge_value("moe_experts_routed"), metrics.gauge_value("moe_experts_held")) == (32, 8)
   assert capsys.readouterr().out.count("keep a recurrent state per slot") == 1
 

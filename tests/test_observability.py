@@ -760,7 +760,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_node_role",  # 0=both 1=prefill 2=decode (ISSUE 10)
   "xot_tpu_kv_quant_bits",  # 16=bf16 8=int8 4=int4 (ISSUE 11)
   "xot_tpu_recurrent_state_bytes",  # per-slot state beside the page pool (ISSUE 34)
-  "xot_tpu_recurrent_state_step",  # {form}: 1 on the rule and form the decode programs step that state in: one_pass / reference (Mamba-2, ISSUE 35), delta_reference (KDA, ISSUE 36)
+  "xot_tpu_recurrent_state_step",  # {form}: 1 on the rule and form the decode programs step that state in: one_pass / reference (Mamba-2, ISSUE 35), delta_one_pass / delta_reference (KDA and Gated DeltaNet, ISSUE 36, 44, 45)
   "xot_tpu_moe_experts_routed",  # the router's width of the loaded shard's expert layers (0: dense) (ISSUE 36)
   "xot_tpu_moe_experts_held",  # how many of those experts' weights the shard holds: fewer for one chip's share of an expert-parallel deployment (ISSUE 36)
   "xot_tpu_moe_ffn_form",  # {form}: 1 on the form the routed experts' product takes in the pool's programs: grouped / block (ops/moe.py ffn_form, ISSUE 40)

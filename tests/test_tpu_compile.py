@@ -456,27 +456,35 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch
   """Ling-3.0-flash's first stage at one chip's share, as ``ling-3.0-flash.decode-closed-64`` serves it (ISSUE 36): 64
   slots, 1537 latent pages of ONE attention layer, bf16, 128 of 512 experts held. ``decode.paged_batch`` is accepted by
   XLA:TPU beside 10.3 GB of weights, 0.81 GB of float32 matrix state and 0.11 GB of pages. The state leaf is one buffer
-  from the donated argument to the result: no instruction copies it or a layer of it; it is read at (layer) by the
-  fusions of the delta step and written back in place. The only Mosaic calls are the experts' two (ISSUE 40:
-  ``moe_gate_up``, ``moe_down``, in both stacks' loops — MLA takes the gather path and the delta rule has the XLA
-  expression only), and they take the STACKED expert leaves: no stacked expert leaf, and no layer of one, is copied,
-  cut out or relaid (a copy of a stack is 3.8 GB, of a layer 0.75 GB a step)."""
+  from the donated argument to the result: no instruction copies it or a layer of it; it is read at (layer) and written
+  back by the Mosaic call ``delta_state_step`` (ISSUE 45: one call in each of the three runs of KDA layers, the leaf
+  aliased through it; until then by two fusions of the XLA expression), which no fusion shares it with. The other Mosaic calls
+  are the experts' two (ISSUE 40: ``moe_gate_up``, ``moe_down``, in both stacks' loops — MLA takes the gather path
+  though the program is told ``use_kernel``, which is what ``decode_kernels_supported`` resolves for it on a TPU), and
+  they take the STACKED expert leaves: no stacked expert leaf, and no layer of one, is copied, cut out or relaid (a copy
+  of a stack is 3.8 GB, of a layer 0.75 GB a step)."""
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+  from xotorch_support_jetson_tpu.ops.paged import decode_kernels_supported, paged_kernel_supported
 
   _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch)
+  assert decode_kernels_supported(cfg, "tpu") and not paged_kernel_supported(cfg, "tpu") and not decode_kernels_supported(cfg, "cpu")
   n_slots = pool["ssm"].shape[1]
   assert pool["k"].shape == (1, 1537, 1, PS, 512) and pool["v"].shape == (1, 1537, 1, PS, 64) and pool["ssm"].shape == (6, 64, 32, 128, 128) and pool["conv"].shape == (6, 64, 3, 12288)
   assert params["ssm_moe_layers"]["w_experts_gate"].shape == (5, 128, 2560, 768) and params["moe_layers"]["w_router"].shape == (1, 2560, 512)
   rows = _rows(chip, n_slots)
   compiled, text = _compile(
     _fused_paged_batch_decode_impl, params, cfg, Shard("ling", 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (n_slots, 1), jnp.int32), pool,
-    _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, False,
+    _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
     _sds(chip, (2,), jnp.uint32), None,
   )  # fmt: skip
   calls = _mosaic_calls(text)
-  assert sorted(set(calls)) == ["moe_down", "moe_gate_up"], sorted(set(calls))
+  # (the six KDA layers are three runs: the dense first layer, and the expert layers on either side of the latent layer)
+  assert sorted(set(calls)) == ["delta_state_step", "moe_down", "moe_gate_up"] and calls.count("delta_state_step") == 3, calls
+  state_takers = _takers(text, r"f32\[(6,|1,)?64,32,128,128\]")
+  assert len(state_takers) == 3 and all(op == "custom-call" and name.startswith("%delta_state_step") for name, op in state_takers), state_takers
+  assert not re.search(r"= f32\[6,64,32,128,128\]\S* dynamic-update-slice\(", text)
   stack = r"bf16\[(5|1),128,(2560,768|768,2560)\]"
   takers = {op for shape in (stack,) for _, op in _takers(text, shape)}
   assert takers == {"custom-call"}, takers  # the kernels alone take the stacks: no fusion cuts a layer out of one
@@ -542,10 +550,13 @@ def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip, monkeypatc
 def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5e(chip, monkeypatch):
   """Olmo-Hybrid's first twelve layers as ``olmo-hybrid-7b.decode-closed-64`` serves them (ISSUE 44): 64 slots of a
   [30, 192, 96] float32 state in 9 layers, 1537 pages of 30 KV heads x 64 x 128 bf16 in 3. ``decode.paged_batch`` told
-  ``use_kernel`` is accepted by XLA:TPU beside 6.54 GB of weights: its Mosaic calls are the paged kernel's and the
-  token write's (the delta step has the XLA expression only, and a 96-wide face is no whole lane group), the paged
-  kernel takes Mistral's tile of 8 pages (two slots of 8 pages of K and of V are 15.7 MB of VMEM, inside the limit the
-  call asks for), and no instruction copies the state leaf or a layer of it. And the largest prefill the cell meets, a
+  ``use_kernel`` is accepted by XLA:TPU beside 6.54 GB of weights: its Mosaic calls are the paged kernel's, the token
+  write's and the delta step's (ISSUE 45: ``delta_state_step``, one call in the loop of each of the three runs of
+  Gated-DeltaNet layers, tiles of 10 heads whose 96-wide face lies in 128 lanes of VMEM; the leaf is aliased through
+  it and no fusion takes the leaf or a layer of it — the XLA expression compiled to two a run), the paged kernel takes
+  Mistral's tile of 8 pages (two slots of 8 pages of K and of V are 15.7 MB of VMEM, inside the limit the call asks
+  for), no instruction copies the state leaf or a layer of it, and the compiler's argument bytes are what they were
+  before the step had a kernel (12.81 GB). And the largest prefill the cell meets, a
   group of 8 rows padded to 1024 tokens with the pool donated, fits beside them: the chunked delta rule's float32
   operands at 64 positions a chunk are its temporaries."""
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
@@ -564,8 +575,11 @@ def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5
     _sds(chip, (2,), jnp.uint32), None,
   )  # fmt: skip
   kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-  # the paged kernel and the token write, once for each of the three attention layers' loops: the delta step is XLA's
-  assert len(kernels) == 6 and sum("paged_decode" in line for line in kernels) == 3 and sum("kv_token_write" in line for line in kernels) == 3, [line.strip()[:120] for line in kernels]
+  # the paged kernel and the token write, once for each of the three attention layers' loops, and the delta step, once for each of the three runs of Gated-DeltaNet layers between them
+  assert len(kernels) == 9 and [sum(name in line for line in kernels) for name in ("paged_decode", "kv_token_write", "delta_state_step")] == [3, 3, 3], [line.strip()[:120] for line in kernels]
+  state_takers = _takers(text, r"f32\[(9,|1,)?64,30,192,96\]")
+  assert len(state_takers) == 3 and all(op == "custom-call" and name.startswith("%delta_state_step") for name, op in state_takers), state_takers
+  assert not re.search(r"= f32\[9,64,30,192,96\]\S* dynamic-update-slice\(", text)
   assert _page_tile(mp) == PAGE_TILE == 8  # Mistral's and granite's tile, at a page seven times theirs
   state = r"f32\[(9,)?64,30,192,96\]"
   copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {state}\S* (copy|copy-start|transpose)\(", line)]
@@ -573,7 +587,7 @@ def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5
   mem = compiled.memory_analysis()
   print(f"decode.paged_batch olmo B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4  # the pool is donated: the state is updated where it lies
-  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9 and abs(mem.argument_size_in_bytes - 12.81e9) < 0.01e9
   K, S = 8, 1024
   rows = _rows(chip, K)
   monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend: head size 128 takes the kernel on the chip

@@ -860,10 +860,10 @@ def _kda_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
   return h, ssm, conv.astype(conv0.dtype)
 
 
-def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
+def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bool = False):
   """One delta-rule step of one KDA layer for every slot row, as ``_ssm_decode_step``: the leaves ``ssm`` and ``conv``
-  of ``pool`` are read and written in place at ``layer``; a row that is not ``active`` keeps both bit for bit. Returns
-  (h, pool, the experts its FFN visited)."""
+  of ``pool`` are read and written in place at ``layer``; a row that is not ``active`` keeps both bit for bit; the
+  step passes over the leaf once where ``use_kernel`` and the leaf allow. Returns (h, pool, the experts its FFN visited)."""
   from ..ops.ssm import kda_state_step
 
   qkv, f, bg = _kda_in(h, p, cfg)
@@ -871,7 +871,7 @@ def _kda_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
     conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
     qkv, xp = _ssm_conv(qkv, conv0, p)
     q, k, v, g, beta, gate = _kda_gates(qkv[:, 0], f[:, 0], bg[:, 0], p, cfg)
-    ssm, y = kda_state_step(pool["ssm"], layer, jnp.exp(g), beta, k, v, q, active)
+    ssm, y = kda_state_step(pool["ssm"], layer, jnp.exp(g), beta, k, v, q, active, use_kernel)
     pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
     y = _kda_out(y[:, None], gate[:, None], p, cfg, h.dtype)
   h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
@@ -996,7 +996,7 @@ def _gdn_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
   return h, ssm, conv.astype(conv0.dtype)
 
 
-def _gdn_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
+def _gdn_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bool = False):
   """One delta-rule step of one Gated-DeltaNet layer for every slot row, as ``_kda_decode_step``: the head's one decay
   is spread over the key channels and the step is ``ops/ssm.py kda_state_step``'s. Returns (h, pool, 0 experts visited)."""
   from ..ops.ssm import kda_state_step
@@ -1006,7 +1006,7 @@ def _gdn_decode_step(h, pool, p, layer, active, cfg: ModelConfig):
     conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
     qkv, xp = _ssm_conv(qkv, conv0, p)
     q, k, v, g, beta = _gdn_gates(qkv[:, 0], ab[:, 0], p, cfg)
-    ssm, y = kda_state_step(pool["ssm"], layer, jnp.broadcast_to(jnp.exp(g)[..., None], k.shape), beta, k, v, q, active)
+    ssm, y = kda_state_step(pool["ssm"], layer, jnp.broadcast_to(jnp.exp(g)[..., None], k.shape), beta, k, v, q, active, use_kernel)
     pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
     y = _gdn_out(y[:, None], z, p, cfg, h.dtype)
   h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
@@ -2013,9 +2013,9 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
     if "w_xbc" in lp:
       h, pool, visited = _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
     elif "w_f" in lp:
-      h, pool, visited = _kda_decode_step(h, pool, lp, layer, active, cfg)
+      h, pool, visited = _kda_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
     elif "w_ab" in lp:
-      h, pool, visited = _gdn_decode_step(h, pool, lp, layer, active, cfg)
+      h, pool, visited = _gdn_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
     else:
       h, pool, visited = _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
     return (h, seen + visited), pool
@@ -2073,17 +2073,18 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
   chose, summed over the chunk's expert layers and steps (int32 scalar; a
   model without experts keeps the four).
 
-  ``use_kernel=None`` resolves to the Pallas kernel wherever it can run
-  (ops/paged.py ``paged_kernel_supported``), the XLA gather elsewhere.
+  ``use_kernel=None`` resolves to the Pallas kernels wherever they can run
+  (ops/paged.py ``decode_kernels_supported``: the paged kernel's answer, or
+  a latent-attention hybrid's delta step's), the XLA forms elsewhere.
   """
-  from ..ops.paged import paged_kernel_supported
+  from ..ops.paged import decode_kernels_supported
 
   if not (shard.is_first_layer and shard.is_last_layer):
     raise ValueError("fused_paged_batch_decode requires a full-model shard")
   if key is None:
     key = jax.random.PRNGKey(0)
   if use_kernel is None:
-    use_kernel = paged_kernel_supported(cfg)
+    use_kernel = decode_kernels_supported(cfg)
   B = token.shape[0]
   top_ks = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
   out = _fused_paged_batch_decode_impl(

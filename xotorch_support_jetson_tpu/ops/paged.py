@@ -729,12 +729,23 @@ def paged_kernel_supported(cfg, platform: str | None = None) -> bool:
   head width the kernel tiles — and ``XOT_TPU_NO_FLASH`` is unset. It is what
   the decode programs resolve ``use_kernel=None`` to and what the scheduler
   labels its chunks by; everything else takes the XLA gather."""
+  return _mosaic_platform(cfg, platform) and cfg.plain_attention and not cfg.is_mla and cfg.head_dim in (64, 128, 256)
+
+
+def _mosaic_platform(cfg, platform: str | None = None) -> bool:
+  """A TPU whose plan leaves the Mosaic kernels in (``cfg.mosaic_kernels``), and ``XOT_TPU_NO_FLASH`` unset."""
   import os
 
-  if os.getenv("XOT_TPU_NO_FLASH"):
-    return False
-  platform = platform or jax.default_backend()
-  return platform == "tpu" and cfg.plain_attention and not cfg.is_mla and cfg.head_dim in (64, 128, 256)
+  return not os.getenv("XOT_TPU_NO_FLASH") and (platform or jax.default_backend()) == "tpu" and cfg.mosaic_kernels
+
+
+def decode_kernels_supported(cfg, platform: str | None = None) -> bool:
+  """What ``fused_paged_batch_decode`` tells its program where its caller did not say (``use_kernel=None``): the paged
+  kernel's answer, and for a hybrid of latent attention and delta-rule layers the platform's alone. Such a model's
+  attention takes the gather whatever the program is told (``kernel_attends``), but its recurrent layers' state step
+  has a Mosaic form (``ops/ssm.py delta_state_step``) that asks nothing of the attention: told False, a TPU would step
+  Ling's state by the two-read expression."""
+  return paged_kernel_supported(cfg, platform) or (cfg.is_mla and cfg.recurrent_kind in ("kda", "gdn") and _mosaic_platform(cfg, platform))
 
 
 def kernel_attends(cfg, use_kernel) -> bool:
