@@ -1,16 +1,21 @@
 #!/usr/bin/env python3
-"""A long teacher-forced decode of a benchmark configuration with per-slot recurrent state, on the chip, against the
-kind's plain reference (builder's tool; beyond ``benchmark/correctness.py``'s 8 tokens after 160).
+"""A long teacher-forced decode of a benchmark configuration on the chip, against the kind's plain reference (builder's
+tool; beyond ``benchmark/correctness.py``'s 8 tokens after 160): for a configuration with per-slot recurrent state,
+whose state 168 positions hardly fill, and for one with attention windows, which 168 positions never reach.
 
   python scripts/chip_teacher_forced.py --config ling-3.0-flash-ep4-d7 --seed 7 [--rows 4] [--steps 160] [--probes a,b]
 
 At the published widths and the cell's pool (slots and pages of the file's ``serving_env``): ``--rows`` prompts of
-500-1000 seeded tokens are prefilled as one group through ``prefill.pages_many`` (pool donated), then ``--steps`` decode
+500-1000 seeded tokens (600-1200 for a kind with ``long_probes``: past a window of 512 from the first decoded token on)
+are prefilled as one group through ``prefill.pages_many`` (pool donated), then ``--steps`` decode
 steps run through ``paged_decode_forward`` over pool, state and pages, each fed the seeded next token (teacher-forced),
-the other slots inactive. Every step's log-softmax is compared with the float32 reference's full forward over prompt +
+the other slots inactive. A configuration without recurrent layers attends through whatever the served decode program
+does (``decode_kernels_supported``: on a TPU the Pallas paged kernel, with the layer's window); one with them keeps the
+gather it was measured with. Every step's log-softmax is compared with the float32 reference's full forward over prompt +
 continuation (``jax.default_matmul_precision("highest")``, one row at a time): the largest and the mean |difference| over
 the reference's 64 likeliest tokens a position, and the reference's best log-prob minus its log-prob of the program's
-greedy token. ``--probes`` adds the same numbers against deliberately wrong references of the kind's ``probes``. One
+greedy token. ``--probes`` adds the same numbers against deliberately wrong references of the kind's ``probes`` and
+``long_probes`` (``all``: every one of them). One
 JSON line; exit 1 where JAX sees no TPU (``--cpu`` rehearses at the kind's tiny widths)."""
 
 from __future__ import annotations
@@ -58,7 +63,7 @@ def main() -> int:
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models import decoder as dec
-  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+  from xotorch_support_jetson_tpu.ops.paged import decode_kernels_supported, init_paged_pool
 
   dev = jax.devices()[0]
   if dev.platform != "tpu" and not args.cpu:
@@ -67,15 +72,15 @@ def main() -> int:
   t0 = time.perf_counter()
   params = weights.build_params(hf, args.seed)
   cfg = common.model_config(hf)
-  if not cfg.recurrent_layers:
-    raise SystemExit("this tool is for configurations with per-slot recurrent state")
+  all_probes = {**kind.probes(hf), **getattr(kind, "long_probes", lambda _hf: {})(hf)}
+  use_kernel = not cfg.recurrent_layers and decode_kernels_supported(cfg)
   shard = Shard("m", 0, cfg.n_layers - 1, cfg.n_layers)
   slots, ps = (int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), 64) if not args.cpu else (8, 16)
   mp = pages_to_cover(cfg.max_seq_len, ps)
   n_pages = 1 + args.rows * mp
   pool = init_paged_pool(cfg, cfg.n_layers, n_pages, ps, n_slots=slots)
   rng = np.random.default_rng([args.seed, 11])
-  lo, hi = (500, 1000) if not args.cpu else (40, 90)
+  lo, hi = (40, 90) if args.cpu else (600, 1200) if hasattr(kind, "long_probes") else (500, 1000)
   lens = [int(n) for n in rng.integers(lo, hi + 1, size=args.rows)]
   seqs = [rng.integers(3, cfg.vocab_size, size=n + args.steps) for n in lens]
   use_slots = [int(s) for s in rng.choice(slots, size=args.rows, replace=False)]
@@ -90,7 +95,7 @@ def main() -> int:
   last, pool = dec.prefill_into_pages_many_inplace(params, cfg, shard, jnp.asarray(tok), pool, jnp.asarray(bts), jnp.zeros((K,), jnp.int32), jnp.asarray(prompt_lens), ps, None, jnp.asarray(slot_rows))
   got = [[np.asarray(jax.nn.log_softmax(last[i].astype(jnp.float32)))] for i in range(args.rows)]
 
-  step = jax.jit(lambda params, tok, pos, pool, active: dec.paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, jnp.asarray(tables), ps, False, active=active)[:2], donate_argnums=3)
+  step = jax.jit(lambda params, tok, pos, pool, active: dec.paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, jnp.asarray(tables), ps, use_kernel, active=active)[:2], donate_argnums=3)
   active = np.zeros((slots,), bool)
   active[use_slots] = True
   for t in range(args.steps - 1):
@@ -117,9 +122,8 @@ def main() -> int:
         worst, total, count, margin = max(worst, float(d.max())), total + float(d.sum()), count + d.size, max(margin, float(m.max()))
     return {"max_abs": worst, "mean_abs": total / count, "greedy_margin": margin, "rows": per_row}
 
-  out = {"ok": True, "device": {"platform": dev.platform, "kind": dev.device_kind}, "config": args.config, "seed": args.seed, "prompts": lens, "steps": args.steps, "slots": slots, "served_s": round(served_s, 1), "sound": against()}
-  wanted = [p for p in args.probes.split(",") if p]
-  all_probes = kind.probes(hf)
+  out = {"ok": True, "device": {"platform": dev.platform, "kind": dev.device_kind}, "config": args.config, "seed": args.seed, "prompts": lens, "steps": args.steps, "slots": slots, "kernel": bool(use_kernel), "served_s": round(served_s, 1), "sound": against()}
+  wanted = list(all_probes) if args.probes == "all" else [p for p in args.probes.split(",") if p]
   out["probes"] = {name: {k: v for k, v in against(**all_probes[name]).items() if k != "rows"} for name in wanted}
   out["total_s"] = round(time.perf_counter() - t0, 1)
   print(json.dumps(out), flush=True)

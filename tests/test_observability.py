@@ -766,6 +766,10 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_moe_ffn_form",  # {form}: 1 on the form the routed experts' product takes in the pool's programs: grouped / block (ops/moe.py ffn_form, ISSUE 40)
   "xot_tpu_moe_experts_visited_total",  # distinct held experts the decode rows chose, summed over expert layers and steps; over the next: the mean a layer and step (ISSUE 40)
   "xot_tpu_moe_expert_layer_steps_total",  # expert layers x decode steps of the settled chunks (ISSUE 40)
+  "xot_tpu_attention_layers",  # {kind}: the page pool's layers whose attention sees every position (full) / its last window (window) (ISSUE 46)
+  "xot_tpu_attention_window_tokens",  # that window, in tokens (0: no layer has one) (ISSUE 46)
+  "xot_tpu_kv_pages_resident_total",  # pages the decode rows hold in every layer that owns pages, summed a decode dispatch (ISSUE 46)
+  "xot_tpu_kv_pages_read_total",  # of those, the pages the layers' windows let their attention read (ISSUE 46)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
   # Multi-LoRA serving (ISSUE 15; swaps labeled {direction}, requests
   # labeled {adapter} — adapter names are client-asserted, same trust note
@@ -851,6 +855,10 @@ def test_metric_name_snapshot_after_serving():
   gm.set_gauge("moe_ffn_form", 0, labels={"form": "block"})  # set when a pool is made for a model with routed experts (ISSUE 40)
   gm.inc("moe_experts_visited_total", 0)  # event-driven: only a model with routed experts visits any (ISSUE 40)
   gm.inc("moe_expert_layer_steps_total", 0)
+  gm.set_gauge("attention_layers", 0, labels={"kind": "full"})  # set when a page pool is made (ISSUE 46)
+  gm.set_gauge("attention_window_tokens", 0)
+  gm.inc("kv_pages_resident_total", 0)  # counted a paged decode dispatch
+  gm.inc("kv_pages_read_total", 0)
   gm.set_gauge("kv_draft_slots", 0)
   gm.set_gauge("kv_draft_pages_equivalent", 0)
   # Mixed ticks (ISSUE 14): a short solo drive never stages a chunked

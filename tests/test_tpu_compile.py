@@ -603,6 +603,71 @@ def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5
   assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4 and mem.argument_size_in_bytes < 13.0e9
 
 
+def test_swa_gqa_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_settings_fit_v5e(chip, monkeypatch):
+  """Laguna-XS.2's first stage as ``laguna-xs.2.agent-closed-64`` serves it (ISSUE 46): 4097 pages of 8 KV heads x 64 x
+  128 bf16 in 5 layers (5.37 GB) beside 7.74 GB of weights with every expert held. ``decode.paged_batch`` told
+  ``use_kernel`` is accepted by XLA:TPU (arguments 13.11 GB): its three runs of layers — a full layer, three window
+  layers, a full layer — each hold one call of the paged kernel, the window layers' under its own name
+  ``paged_decode_window`` (groups of 8 query heads a KV head, the full layers' 6: both new to the kernel, at Mistral's
+  tile of 8 pages) inside ``xot.attn``, one token write each, and the two expert runs the grouped expert products
+  inside ``xot.moe_experts``. The mixed tick with a slice padded to 2048 (the file's budget) fits beside them, and so does the largest
+  prefill the ramp meets — a group of 8 rows of 2048 tokens over a 64-page window, the pool donated (a pool this large
+  is written in place: inference/batch_ops.py ``init_pool``) — through the flash kernel in all three runs, the window
+  layers' with their window."""
+  import json
+
+  sys.path.insert(0, str(ROOT / "benchmark"))
+  import common
+
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_mixed_paged_batch_decode_impl, _fused_paged_batch_decode_impl, full_model_params, prefill_into_pages_many_sampled_inplace
+  from xotorch_support_jetson_tpu.ops import moe
+  from xotorch_support_jetson_tpu.ops.paged import PAGE_TILE, _page_tile, init_paged_pool
+
+  monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend
+  hf = json.loads((ROOT / "benchmark" / "configs" / "laguna-xs.2-d5.json").read_text())
+  cfg = common.model_config(hf)
+  n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"]["XOT_TPU_BATCH_PAGES"])
+  on_chip = lambda tree: jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)  # noqa: E731
+  params = on_chip(jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg)[0]))
+  pool = on_chip(jax.eval_shape(lambda: init_paged_pool(cfg, cfg.n_layers, n_pages, PS)))
+  assert pool["k"].shape == pool["v"].shape == (5, n_pages, 8, PS, 128) and set(pool) == {"k", "v"} and n_pages >= 3073
+  assert {name: st["wq"].shape for name, st in params.items() if isinstance(st, dict)} == {"layers": (1, 2048, 6144), "window_moe_layers": (3, 2048, 8192), "moe_layers": (1, 2048, 6144)}
+  shard, mp = Shard("laguna", 0, cfg.n_layers - 1, cfg.n_layers), pages_to_cover(cfg.max_seq_len, PS)
+  rows, key = _rows(chip, n_slots), _sds(chip, (2,), jnp.uint32)
+  decode_args = (params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), pool, _sds(chip, (n_slots, mp), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32))
+  compiled, text = _compile(_fused_paged_batch_decode_impl, *decode_args, 8, 64, PS, True, key, None)
+  kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+  count = lambda call: sum(f"{call}/pallas_call" in line for line in kernels)  # noqa: E731  (the call's own path, not its operands' names)
+  calls = ("jit(_paged_decode_attention_impl)", "jit(_paged_decode_attention_impl)/paged_decode_window", "xot.kv_write/kv_token_write", "xot.moe_experts/moe_gate_up", "xot.moe_experts/moe_down")
+  assert len(kernels) == 10 and [count(call) for call in calls] == [2, 1, 3, 2, 2], [line.strip()[-300:] for line in kernels]
+  assert all("/xot.attn/jit(_paged_decode_attention_impl)" in line for line in kernels if "_paged_decode_attention_impl" in line)
+  assert _page_tile(mp) == PAGE_TILE == 8
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch laguna B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= 2 * 5 * n_pages * 8 * PS * 128 * 2 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9 and abs(mem.argument_size_in_bytes - (7.74e9 + 2 * 5 * n_pages * 8 * PS * 128 * 2)) < 0.01e9
+  one = lambda dtype: _sds(chip, (1,), dtype)  # noqa: E731
+  compiled, text = _compile(_fused_mixed_paged_batch_decode_impl, *decode_args, _sds(chip, (1, 2048), jnp.int32), _sds(chip, (1, 64), jnp.int32), one(jnp.int32), one(jnp.int32), 8, 64, PS, True, key, None, None)
+  mem = compiled.memory_analysis()
+  print(f"decode.mixed_paged_batch laguna pad=2048: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert text.count("paged_decode_window") >= 1 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+  K, S, window = 8, 2048, 64
+  rows = _rows(chip, K)
+  compiled, text = _compile(
+    prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool,
+    _sds(chip, (K, window), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), key, 64, None,
+  )  # fmt: skip
+  mem = compiled.memory_analysis()
+  kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+  flash = [line for line in kernels if "xot.moe_experts/moe_" not in line]
+  assert len(flash) == 3 and all("flash_attention_prefill" in line for line in flash), [line.strip()[-300:] for line in flash]  # the flash kernel in each of the three runs
+  print(f"prefill.pages_many_sampled laguna K=8 S=2048: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  # That it compiled is the fit (its temporaries overlap the donated pool's buffers: PERF.md section 6, PR 44).
+  assert mem.alias_size_in_bytes >= 2 * 5 * n_pages * 8 * PS * 128 * 2 and mem.argument_size_in_bytes < 13.2e9
+
+
 def test_compiler_refuses_a_pool_beyond_the_chip(chip, llama_1b):
   """What makes the test above a fit check: the same step over three times
   the pool is refused at compile time, not at run time."""

@@ -28,6 +28,7 @@ generic over the pool's dict-of-leaves layout (inference/kv_tier.py
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -81,16 +82,20 @@ class DecoderBatchOps(_PageCopyMixin):
   integration yet — and the scheduler falls back to plain chunks there."""
 
   def __init__(self, engine):
-    from ..models import decoder
-
     self.engine = engine
     # A pool with per-slot recurrent state is written in place by its prefill too: a second copy of the state
     # (what a program that leaves its argument intact returns) does not fit beside the first. A failed prefill
-    # then costs the pool, as a failed decode chunk does. Chosen once: the ops are rebuilt with every load.
+    # then costs the pool, as a failed decode chunk does. Chosen with every load (the ops are rebuilt), and once more
+    # where the pool is made (``init_pool``: a pool too large to hold twice).
     cfg = getattr(engine, "cfg", None)
-    self.prefill_donates_pool = bool(cfg is not None and cfg.recurrent_layers)
-    self._pages_many = decoder.prefill_into_pages_many_inplace if self.prefill_donates_pool else decoder.prefill_into_pages_many
-    self._pages_many_sampled = decoder.prefill_into_pages_many_sampled_inplace if self.prefill_donates_pool else decoder.prefill_into_pages_many_sampled
+    self._prefill_in_place(bool(cfg is not None and cfg.recurrent_layers))
+
+  def _prefill_in_place(self, donates: bool) -> None:
+    from ..models import decoder
+
+    self.prefill_donates_pool = donates
+    self._pages_many = decoder.prefill_into_pages_many_inplace if donates else decoder.prefill_into_pages_many
+    self._pages_many_sampled = decoder.prefill_into_pages_many_sampled_inplace if donates else decoder.prefill_into_pages_many_sampled
 
   def round_slots(self, n: int) -> int:
     return n
@@ -179,7 +184,15 @@ class DecoderBatchOps(_PageCopyMixin):
     from ..ops.paged import init_paged_pool
 
     eng = self.engine
-    return init_paged_pool(eng.cfg, eng._effective_shard.n_shard_layers, n_pages, page_size, n_slots=n_slots)
+    pool = init_paged_pool(eng.cfg, eng._effective_shard.n_shard_layers, n_pages, page_size, n_slots=n_slots)
+    # A pool of more than a quarter of the device's memory is written in place by its prefill too, whatever it holds:
+    # the program that leaves its argument intact returns a second copy, and beside the weights and a prefill group's
+    # temporaries that copy has no room (64 contexts of Laguna-XS.2's first stage: 5.4 GB of pages beside 7.7 GB of
+    # weights). Asked of the device once a pool; a CPU states no limit and keeps the copying program.
+    limit = 0 if self.prefill_donates_pool else (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit") or 0
+    if limit and 4 * sum(leaf.size * leaf.dtype.itemsize for leaf in pool.values()) > limit:
+      self._prefill_in_place(True)
+    return pool
 
   def prefill_into_slots(self, tokens, cache, rows, prompt_lens, adapter_ids=None):
     from ..models.decoder import prefill_into_slots

@@ -901,6 +901,12 @@ class BatchedServer:
         for name in STATE_STEP_FORMS:
           metrics.set_gauge("recurrent_state_step", int(name == form), labels={"form": name})
       self._note_expert_form()
+      # The layers that own pages, by what their attention reads of them (``cfg.attn_windows``): a window layer's pages
+      # stay resident for the whole context and the kernel reads the window's only (``_count_pages``).
+      self._windows = eng.cfg.attn_windows
+      for kind, n in (("full", sum(1 for w in self._windows if not w)), ("window", sum(1 for w in self._windows if w))):
+        metrics.set_gauge("attention_layers", n, labels={"kind": kind})
+      metrics.set_gauge("attention_window_tokens", max(self._windows, default=0))
       from .kv_tier import KvTierManager, kv_tier_enabled
 
       if recurrent:
@@ -2400,6 +2406,8 @@ class BatchedServer:
     elif self.spec:
       self._spec_plain_chunks += 1
     worst = spec_worst_advance(self.chunk, gmax) if spec else self.chunk
+    if self.paged and not spec:  # (a chained speculative chunk's positions are on the device)
+      self._count_pages(positions, active)
     # Mixed tick (ISSUE 14): stage the prefill slice's host operands. The
     # slice pads to a power of two (one compiled program per pad bucket —
     # the traced prefix/end mean slice-length changes within a bucket never
@@ -2523,6 +2531,17 @@ class BatchedServer:
     form = served_expert_form(params, cfg)
     for name in FFN_FORMS:
       metrics.set_gauge("moe_ffn_form", int(name == form), labels={"form": name})
+
+  def _count_pages(self, positions, active) -> None:
+    """One decode dispatch's pages, from the rows' lengths on the host: ``kv_pages_resident_total`` — what the active
+    rows hold, in every layer that owns pages — and ``kv_pages_read_total`` — what those layers' attention reads of
+    them at the chunk's first step: every page in a layer without a window, the pages from the one that holds
+    position ``length - window`` on in a layer with one. Their quotient is what the window returns in bandwidth, and
+    what a pool whose window layers held a window only would return in bytes."""
+    lengths = np.asarray(positions)[np.asarray(active, bool)].astype(np.int64) + 1
+    held = -(-lengths // self.page_size)
+    metrics.inc("kv_pages_resident_total", int(held.sum()) * len(self._windows))
+    metrics.inc("kv_pages_read_total", int(sum((held - np.maximum(lengths - w, 0) // self.page_size if w else held).sum() for w in self._windows)))
 
   async def _dispatch_decode(self, plan: _Plan, inflight: _Chunk | None) -> _Chunk:
     tick = self._next_tick()

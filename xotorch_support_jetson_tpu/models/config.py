@@ -63,6 +63,28 @@ class LongRopeScaling:
 
 
 @dataclass(frozen=True)
+class AttnKind:
+  """One attention layer's description, where a model's attention layers are not all alike: the ONE owner of which
+  layers have a window, how many query heads, which rope and a gate or none (``ModelConfig.layer_attn`` holds one a
+  layer; the KV heads and the head size are the model's). ``name`` is "full" or "window" and prefixes the layer's
+  stack (``ModelConfig.layer_stack``); ``window`` 0 is none, else a query at t sees the keys in (t - window, t];
+  ``out_gate``: one scalar a head, softplus(x W_og) in float32, multiplies the head's output ahead of ``wo``."""
+
+  name: str
+  n_heads: int
+  window: int = 0
+  rope_theta: float = 10000.0
+  rope_scaling: RopeScaling | YarnScaling | LongRopeScaling | None = None
+  partial_rotary_factor: float = 1.0
+  out_gate: bool = False
+
+  @property
+  def shape(self) -> tuple:
+    """What two kinds must share to share a stack of parameters: everything but the window."""
+    return (self.n_heads, self.rope_theta, self.rope_scaling, self.partial_rotary_factor, self.out_gate)
+
+
+@dataclass(frozen=True)
 class ModelConfig:
   vocab_size: int
   dim: int  # embedding/residual width
@@ -134,7 +156,16 @@ class ModelConfig:
   attn_logit_softcap: float = 0.0  # 0 ⇒ off
   final_logit_softcap: float = 0.0
   query_pre_attn_scalar: float = 0.0  # 0 ⇒ scale by 1/sqrt(qk head dim)
-  sliding_window: int = 0  # 0 ⇒ global attention everywhere
+  # gemma2's window size (0: none). WHICH layers have it is ``layer_attn``'s to say (filled in from this key by gemma2's
+  # rule where nothing else describes the layers: ``__post_init__``); in a model whose kinds differ in nothing else
+  # (gemma2) they share one stack and the window rides a traced per-layer flag (``is_sliding``).
+  sliding_window: int = 0
+  # One ``AttnKind`` a layer (None at a recurrent layer) where a model's attention layers are not all alike; () ⇒ every
+  # attention layer is the model-level fields' (``attn_kind``). Kinds that differ in more than their window (laguna:
+  # 48 query heads, YaRN over half a head and no window / 64 heads, plain rope and a window of 512) keep a stack of
+  # parameters each (``layer_stack``), run in the published order (``mixed_layers``), and their window is a static
+  # operand of the attention kernels.
+  layer_attn: tuple = ()
   embed_scale: float = 1.0  # gemma multiplies embeddings by sqrt(dim)
   # --- vision (llava): CLIP tower + projector config (models/vision.py) and
   # the placeholder token id the HF processor expands per image patch.
@@ -164,8 +195,12 @@ class ModelConfig:
   # The stacked parameters are named by (mixer, FFN)
   # pairing (``layer_stack``): ``layers`` / ``moe_layers`` the attention layers
   # with a dense / an expert FFN, ``ssm_layers`` / ``ssm_moe_layers`` the
-  # recurrent ones. The page pool keeps pages for the attention layers only,
-  # with per-slot state leaves beside them (ops/paged.py init_paged_pool).
+  # recurrent ones; attention layers of a second shape (``layer_attn``: other
+  # query heads, another rope, a gate) take stacks under their kind's name
+  # (``window_moe_layers``). The page pool keeps pages for the attention layers
+  # only — one K/V leaf for every attention kind: the KV heads and the head size
+  # are the model's —, with per-slot state leaves beside them (ops/paged.py
+  # init_paged_pool).
   layer_types: tuple[str, ...] = ()
   ssm_heads: int = 0
   ssm_head_dim: int = 0
@@ -198,17 +233,36 @@ class ModelConfig:
   # of the config, so the choice keys the compiled programs.
   mosaic_kernels: bool = True
 
-  def layer_is_sliding(self, layer_idx: int) -> bool:
-    """HF Gemma2: even-indexed layers use the sliding window."""
-    return self.sliding_window > 0 and layer_idx % 2 == 0
+  def attn_kind(self, layer_idx: int) -> AttnKind:
+    """Layer ``layer_idx``'s attention description: its ``layer_attn`` entry, else the model-level fields'."""
+    if self.layer_attn:
+      return self.layer_attn[layer_idx]
+    return AttnKind("full", self.n_heads, 0, self.rope_theta, self.rope_scaling, self.partial_rotary_factor)
+
+  @property
+  def attn_shapes(self) -> tuple:
+    """The distinct ``AttnKind.shape`` of the attention layers, in the order the model meets them: one stack of
+    parameters each (the first keeps the plain names ``layers`` / ``moe_layers``)."""
+    return tuple(dict.fromkeys(k.shape for k in self.layer_attn if k is not None))
+
+  @property
+  def traced_window(self) -> bool:
+    """Whether some stack holds layers with a window beside layers without (gemma2: all its layers are one stack):
+    the window then rides a traced per-layer flag, which no Pallas kernel takes."""
+    return len({(k.shape, k.window) for k in self.layer_attn if k is not None}) > len(self.attn_shapes)
+
+  @property
+  def mixed_layers(self) -> bool:
+    """Whether the layers live in more stacks than the two plain ones, to be run in the published order
+    (models/decoder.py ``_layer_runs``): a hybrid with recurrent layers, or attention kinds of different shapes."""
+    return self.recurrent_layers > 0 or len(self.attn_shapes) > 1
 
   @property
   def plain_attention(self) -> bool:
-    """No per-config attention variations (softcap/window/scale override)
-    and no automatically partitioned mesh axis — the single gate for Pallas
-    kernels, which implement none of the former and cannot be lowered under
-    the latter."""
-    return self.mosaic_kernels and not self.attn_logit_softcap and not self.sliding_window and not self.query_pre_attn_scalar
+    """No softcap, no scale override, no window that rides a traced flag (a window that is static per stack IS the
+    kernels' operand) and no automatically partitioned mesh axis — the single gate for the Pallas kernels, which
+    implement none of the former and cannot be lowered under the latter."""
+    return self.mosaic_kernels and not self.attn_logit_softcap and not self.traced_window and not self.query_pre_attn_scalar
 
   @property
   def is_mla(self) -> bool:
@@ -233,10 +287,18 @@ class ModelConfig:
     return self.experts_held[1] - self.experts_held[0] if self.experts_held else self.n_experts
 
   def layer_stack(self, layer_idx: int) -> str:
-    """The stacked-parameter dict layer ``layer_idx`` lives in, by its (mixer, FFN) pairing."""
+    """The stacked-parameter dict layer ``layer_idx`` lives in, by its (mixer, FFN) pairing; an attention kind whose
+    shape is not the model's first (``attn_shapes``) prefixes its stacks with its name (``window_moe_layers``)."""
     recurrent = bool(self.layer_types) and self.layer_types[layer_idx] in RECURRENT_KINDS
     experts = bool(self.n_experts) and layer_idx >= self.first_k_dense
-    return ("ssm_" if recurrent else "") + ("moe_layers" if experts else "layers")
+    kind = None if recurrent or not self.layer_attn else self.layer_attn[layer_idx]
+    prefix = "ssm_" if recurrent else f"{kind.name}_" if kind is not None and kind.shape != self.attn_shapes[0] else ""
+    return prefix + ("moe_layers" if experts else "layers")
+
+  @property
+  def attn_windows(self) -> tuple:
+    """The window (0: none) of every layer that owns K/V pages, in the order of the page pool's layer axis."""
+    return tuple(self.attn_kind(i).window for i in range(self.n_layers) if not (self.layer_types and self.layer_types[i] in RECURRENT_KINDS))
 
   @property
   def n_attn_layers(self) -> int:
@@ -278,6 +340,11 @@ class ModelConfig:
   def __post_init__(self):
     if self.head_dim == 0:
       object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+    if self.sliding_window and not self.layer_attn:
+      # A model-level window with no per-layer description is HF Gemma2's: the even-indexed layers have it. Said
+      # here once, as a value of the per-layer field, which is what everything else reads.
+      rope = (self.rope_theta, self.rope_scaling, self.partial_rotary_factor)
+      object.__setattr__(self, "layer_attn", tuple(AttnKind("full" if i % 2 else "window", self.n_heads, 0 if i % 2 else self.sliding_window, *rope) for i in range(self.n_layers)))
 
   @property
   def q_dim(self) -> int:
@@ -297,46 +364,14 @@ RECURRENT_KINDS = ("mamba", "kda", "gdn")  # the ``layer_types`` whose layers ke
 # a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
 MODEL_FAMILIES = {
   "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
-  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "llama": "llama",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "llama": "llama",
 }
 
 
-def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
-  """Map an HF ``config.json`` dict to ModelConfig.
-
-  Handles the same key space the reference maps
-  (``llm_utils.py:30-77``): llama/qwen2/mistral/phi3 config.json layouts,
-  including llama3 rope_scaling blocks and explicit ``head_dim`` overrides
-  (needed e.g. for Llama-3.2 where head_dim * n_heads != hidden_size is
-  false but qwen3-style configs carry it explicitly).
-  """
-  vision_cfg = None
-  image_token_id = -1
-  if "text_config" in hf and isinstance(hf["text_config"], dict):
-    # Vision-language checkpoints (llava) nest the decoder config; the text
-    # path runs on the nested config, and the vision tower/projector configs
-    # are carried alongside (models/vision.py — a real tower, beyond the
-    # reference's registry entry + API image remapping, chatgpt_api.py:97-128).
-    top = hf
-    merged = dict(hf["text_config"])
-    merged.setdefault("vocab_size", top.get("vocab_size", merged.get("vocab_size")))
-    hf = merged
-    image_token_id = int(top.get("image_token_index", -1))
-    if isinstance(top.get("vision_config"), dict):
-      from .vision import vision_config_from_hf
-
-      vision_cfg = vision_config_from_hf(top["vision_config"], int(hf["hidden_size"]), top)
-  arch = (hf.get("architectures") or [""])[0].lower()
-  model_type = hf.get("model_type", "").lower()
-  family = next((fam for key, fam in MODEL_FAMILIES.items() if key in model_type or key.replace("_", "") in arch), None)
-  if family is None:
-    if model_type:
-      # An unknown architecture is not a llama: serving it as one answers with noise and no error.
-      raise ValueError(f"config_from_hf: unknown model_type {hf.get('model_type')!r} (known: {', '.join(MODEL_FAMILIES)})")
-    family = "llama"  # an absent model_type stays llama (bare test configs)
-
+def _rope_scaling_from(rs, hf: dict):
+  """An HF ``rope_scaling`` / ``rope_parameters`` block → RopeScaling | YarnScaling | LongRopeScaling | None (a block
+  of another ``rope_type``, "default" among them: None)."""
   rope_scaling = None
-  rs = hf.get("rope_scaling")
   if isinstance(rs, dict):
     rope_type = rs.get("rope_type", rs.get("type", ""))
     if rope_type == "llama3":
@@ -385,6 +420,44 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
         original_max_position_embeddings=orig,
         attention_factor=float(attention_factor),
       )
+  return rope_scaling
+
+
+def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
+  """Map an HF ``config.json`` dict to ModelConfig.
+
+  Handles the same key space the reference maps
+  (``llm_utils.py:30-77``): llama/qwen2/mistral/phi3 config.json layouts,
+  including llama3 rope_scaling blocks and explicit ``head_dim`` overrides
+  (needed e.g. for Llama-3.2 where head_dim * n_heads != hidden_size is
+  false but qwen3-style configs carry it explicitly).
+  """
+  vision_cfg = None
+  image_token_id = -1
+  if "text_config" in hf and isinstance(hf["text_config"], dict):
+    # Vision-language checkpoints (llava) nest the decoder config; the text
+    # path runs on the nested config, and the vision tower/projector configs
+    # are carried alongside (models/vision.py — a real tower, beyond the
+    # reference's registry entry + API image remapping, chatgpt_api.py:97-128).
+    top = hf
+    merged = dict(hf["text_config"])
+    merged.setdefault("vocab_size", top.get("vocab_size", merged.get("vocab_size")))
+    hf = merged
+    image_token_id = int(top.get("image_token_index", -1))
+    if isinstance(top.get("vision_config"), dict):
+      from .vision import vision_config_from_hf
+
+      vision_cfg = vision_config_from_hf(top["vision_config"], int(hf["hidden_size"]), top)
+  arch = (hf.get("architectures") or [""])[0].lower()
+  model_type = hf.get("model_type", "").lower()
+  family = next((fam for key, fam in MODEL_FAMILIES.items() if key in model_type or key.replace("_", "") in arch), None)
+  if family is None:
+    if model_type:
+      # An unknown architecture is not a llama: serving it as one answers with noise and no error.
+      raise ValueError(f"config_from_hf: unknown model_type {hf.get('model_type')!r} (known: {', '.join(MODEL_FAMILIES)})")
+    family = "llama"  # an absent model_type stays llama (bare test configs)
+
+  rope_scaling = _rope_scaling_from(hf.get("rope_scaling"), hf)
 
   eos = hf.get("eos_token_id", [])
   if isinstance(eos, int):
@@ -404,13 +477,14 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     moe_hidden = int(hf.get("moe_intermediate_size") or hf["intermediate_size"])
     n_shared = int(hf.get("n_shared_experts") or 0)
     shared_dim = n_shared * moe_hidden
-    if family == "qwen2-moe":
+    if family in ("qwen2-moe", "laguna"):
       shared_dim = int(hf.get("shared_expert_intermediate_size") or 0)
     if family == "bailing-hybrid":
       shared_dim = int(hf.get("num_shared_experts") or 0) * int(hf.get("moe_shared_expert_intermediate_size") or moe_hidden)
     # deepseek group-limited routing: v3 is always sigmoid + top-2-sum group
     # scores (HF DeepseekV3TopkRouter); v2 keys it on topk_method.
-    scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family == "deepseek-v3") else "softmax"
+    # (laguna's row names no score function: deepseek-v3's router, whose 256 / top-8 / 2.5 its keys repeat, is assumed)
+    scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family in ("deepseek-v3", "laguna")) else "softmax"
     if family == "deepseek-v3" or hf.get("topk_method") == "noaux_tc":
       group_mode = "top2sum"
     elif hf.get("topk_method") == "group_limited_greedy":
@@ -432,10 +506,10 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
       moe_hidden_dim=moe_hidden,
       shared_expert_dim=shared_dim,
       shared_expert_gate=family == "qwen2-moe",
-      first_k_dense=int(hf.get("first_k_dense_replace", 0)),
+      first_k_dense=_leading_dense(hf) if "mlp_layer_types" in hf else int(hf.get("first_k_dense_replace", 0)),
       router_scoring=scoring,
-      norm_topk_prob=bool(hf.get("norm_topk_prob", family == "mixtral")),
-      routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+      norm_topk_prob=bool(hf.get("norm_topk_prob", family in ("mixtral", "laguna"))),
+      routed_scaling_factor=float(hf.get("routed_scaling_factor", hf.get("moe_routed_scaling_factor", 1.0))),
       moe_aux_loss_coef=float(hf.get("router_aux_loss_coef", hf.get("aux_loss_alpha", 0.001))),
       n_group=int(hf.get("n_group") or 1),
       topk_group=int(hf.get("topk_group") or 1),
@@ -473,6 +547,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     hybrid = _bailing_hybrid_fields(hf)
   if family == "olmo-hybrid":
     hybrid = _olmo_hybrid_fields(hf)
+  if family == "laguna":
+    hybrid = _laguna_fields(hf)
 
   n_heads = int(hf["num_attention_heads"])
   return ModelConfig(
@@ -488,7 +564,7 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     rope_scaling=rope_scaling,
     max_seq_len=int(hf.get("max_position_embeddings", 8192)),
     qkv_bias=family in ("qwen2", "qwen2-moe") or bool(hf.get("attention_bias", False)),
-    qk_norm=family in ("qwen3", "qwen3-moe", "olmo-hybrid"),
+    qk_norm=family in ("qwen3", "qwen3-moe", "olmo-hybrid", "laguna"),  # (laguna: assumed; its row has no key for or against)
     partial_rotary_factor=float(hf.get("partial_rotary_factor", 1.0)),
     tied_embedding=bool(hf.get("tie_word_embeddings", family in ("gemma2", "granite-hybrid") or (family == "qwen2" and int(hf["hidden_size"]) < 2048))),
     family=family,
@@ -604,6 +680,64 @@ def _olmo_hybrid_fields(hf: dict) -> dict:
     post_norms=True,
     use_rope=False,
   )
+
+
+def _leading_dense(hf: dict) -> int:
+  """``mlp_layer_types`` ("dense" | "sparse" a layer) → how many leading layers have a dense FFN; a dense layer after a
+  sparse one has no place in the two FFN stacks and is refused."""
+  types = list(hf["mlp_layer_types"])
+  n_dense = next((i for i, t in enumerate(types) if t != "dense"), len(types))
+  if len(types) != int(hf["num_hidden_layers"]) or set(types) - {"dense", "sparse"} or "dense" in types[n_dense:]:
+    raise ValueError(f"mlp_layer_types must name {hf['num_hidden_layers']} layers, 'dense' ones first and then 'sparse' ones; got {types}")
+  return n_dense
+
+
+def _laguna_fields(hf: dict) -> dict:
+  """``laguna`` (Laguna-XS.2) → ``layer_attn``: ``layer_types`` names each layer "full_attention" or
+  "sliding_attention" (window ``sliding_window``), ``num_attention_heads_per_layer`` its query heads (over the model's
+  one KV head count and head size), ``rope_parameters`` a rope for each of the two kinds (or its flat spelling, below), ``gating`` a head-wise gate
+  on the attention output (softplus: models/decoder.py ``_attn_out``). What the decoder does not implement is refused
+  here, by name."""
+  n_layers = int(hf["num_hidden_layers"])
+  names = {"full_attention": "full", "sliding_attention": "window"}
+  layer_types = list(hf.get("layer_types") or ())
+  heads = list(hf.get("num_attention_heads_per_layer") or [int(hf["num_attention_heads"])] * n_layers)
+  if len(layer_types) != n_layers or set(layer_types) - set(names):
+    raise ValueError(f"laguna: layer_types must name {n_layers} layers, each 'full_attention' or 'sliding_attention'; got {layer_types}")
+  if len(heads) != n_layers:
+    raise ValueError(f"laguna: num_attention_heads_per_layer must have {n_layers} entries; got {len(heads)}")
+  if hf.get("attention_bias"):
+    raise ValueError("laguna: attention_bias true is not supported")
+  if hf.get("moe_apply_router_weight_on_input"):
+    raise ValueError("laguna: moe_apply_router_weight_on_input true (the router's weight on an expert's input) is not supported")
+  if isinstance(hf.get("num_key_value_heads_per_layer"), (list, tuple)) and len(set(hf["num_key_value_heads_per_layer"])) > 1:
+    raise ValueError("laguna: a KV head count that differs by layer (num_key_value_heads_per_layer) is not supported: the page pool has one K/V leaf")
+  if hf.get("gating") not in (None, False, True):
+    raise ValueError(f"laguna: gating {hf['gating']!r} is not supported (true: one softplus scalar a head on the attention output; false: none)")
+  window = int(hf.get("sliding_window") or 0)
+  if "sliding_attention" in layer_types and window <= 0:
+    raise ValueError("laguna: sliding_attention layers need a sliding_window above 0")
+  ropes, by_kind = {}, hf.get("rope_parameters")
+  if not isinstance(by_kind, dict):
+    # The flat spelling, under gemma3's keys, for a reader that has dropped the file's nested groups (the benchmark's
+    # ``common.model_config`` keeps no dict but ``rope_scaling``): the full layers' rope as ``rope_theta`` +
+    # ``rope_scaling`` + the top-level ``partial_rotary_factor``, the window layers' as ``rope_local_base_freq``,
+    # plain and over the whole head.
+    by_kind = {
+      "full_attention": {"rope_type": "default", **(hf.get("rope_scaling") or {}), "rope_theta": hf.get("rope_theta"), "partial_rotary_factor": hf.get("partial_rotary_factor", 1.0)},
+      "sliding_attention": {"rope_type": "default", "rope_theta": hf.get("rope_local_base_freq"), "partial_rotary_factor": 1.0},
+    }
+  for t in dict.fromkeys(layer_types):
+    rp = by_kind.get(t)
+    if not isinstance(rp, dict) or rp.get("rope_type", "default") not in ("default", "yarn") or rp.get("rope_theta") is None:
+      raise ValueError(f"laguna: rope_parameters.{t} must be a block of rope_type 'default' or 'yarn' with a rope_theta; got {rp!r}")
+    ropes[t] = (float(rp["rope_theta"]), _rope_scaling_from(rp, hf), float(rp.get("partial_rotary_factor", 1.0)))
+  per_kind = {}
+  for t, h in zip(layer_types, heads):
+    if per_kind.setdefault(t, int(h)) != int(h) or int(h) % int(hf["num_key_value_heads"]):
+      raise ValueError(f"laguna: num_attention_heads_per_layer must give every {t} layer one head count, a multiple of num_key_value_heads; got {heads}")
+  kinds = {t: AttnKind(names[t], per_kind[t], window if t == "sliding_attention" else 0, *ropes[t], out_gate=bool(hf.get("gating"))) for t in per_kind}
+  return dict(layer_attn=tuple(kinds[t] for t in layer_types))
 
 
 def load_model_config(model_dir: str | Path, dtype=None) -> ModelConfig:

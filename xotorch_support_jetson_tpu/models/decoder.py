@@ -162,9 +162,10 @@ def _write_cache(cache: jnp.ndarray, new: jnp.ndarray, start: jnp.ndarray) -> jn
 
 
 def sliding_flags(cfg: ModelConfig, global_indices) -> jnp.ndarray:
-  """Per-layer sliding-window flags [L] f32 from GLOBAL layer indices — the
-  one encoding shared by init (below) and the checkpoint loader."""
-  return jnp.asarray([1.0 if cfg.layer_is_sliding(i) else 0.0 for i in global_indices], jnp.float32)
+  """Per-layer sliding-window flags [L] f32 from GLOBAL layer indices, read off the per-layer attention description
+  (``cfg.attn_kind``) — the one encoding shared by init (below) and the checkpoint loader, for a model whose window
+  rides a traced flag (``cfg.traced_window``: gemma2)."""
+  return jnp.asarray([1.0 if cfg.attn_kind(i).window else 0.0 for i in global_indices], jnp.float32)
 
 
 def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None) -> Params:
@@ -211,14 +212,14 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
   dtype = dtype or cfg.dtype
   L = shard.n_shard_layers
   D, F, V = cfg.dim, cfg.hidden_dim, cfg.vocab_size
-  Qd, Kd = cfg.q_dim, cfg.kv_dim
+  Kd = cfg.kv_dim
   keys = iter(jax.random.split(key, 32))
 
   def w(k, *shape, scale=None):
     scale = scale if scale is not None else 1.0 / jnp.sqrt(shape[-2] if len(shape) > 1 else shape[-1])
     return (jax.random.normal(k, shape, dtype=jnp.float32) * scale).astype(dtype)
 
-  def attn_leaves(L):
+  def attn_leaves(L, kind=None):
     if cfg.is_mla:
       H, qk, vh = cfg.n_heads, cfg.qk_head_dim, cfg.v_head_dim
       leaves = {
@@ -238,6 +239,7 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       if cfg.mla_q_norm:
         leaves["q_norm"] = jnp.ones((L, qk), dtype=dtype)
       return leaves
+    Qd = (kind.n_heads if kind is not None else cfg.n_heads) * cfg.head_dim  # a layer kind's own query heads (``cfg.layer_attn``)
     leaves = {
       "attn_norm": jnp.ones((L, D), dtype=dtype),
       "wq": w(next(keys), L, D, Qd),
@@ -246,6 +248,8 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       "wo": w(next(keys), L, Qd, D),
       "mlp_norm": jnp.ones((L, D), dtype=dtype),
     }
+    if kind is not None and kind.out_gate:  # the head-wise gate on the attention output: one column a query head
+      leaves["w_og"] = w(next(keys), L, D, kind.n_heads)
     if cfg.qkv_bias:
       leaves["bq"] = jnp.zeros((L, Qd), dtype=dtype)
       leaves["bk"] = jnp.zeros((L, Kd), dtype=dtype)
@@ -339,15 +343,16 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     return stack
 
   params: Params = {}
-  if cfg.recurrent_layers:
+  if cfg.mixed_layers:
     if not (shard.is_first_layer and shard.is_last_layer):
-      raise ValueError("a configuration with recurrent layers is built whole: its stacks do not split by a layer range")
+      raise ValueError("a configuration whose layers differ in kind (recurrent layers, attention kinds of different shapes) is built whole: its stacks do not split by a layer range")
     keys = iter(jax.random.split(next(keys), 96))  # up to four stacks of up to 17 drawn leaves
     names = [cfg.layer_stack(i) for i in range(cfg.n_layers)]
-    recurrent_leaves = {"mamba": mamba_leaves, "kda": kda_leaves, "gdn": gdn_leaves}[cfg.recurrent_kind]
+    recurrent_leaves = {"mamba": mamba_leaves, "kda": kda_leaves, "gdn": gdn_leaves}.get(cfg.recurrent_kind)
     for name in dict.fromkeys(names):  # in the order the model meets them
       n, recurrent = names.count(name), name.startswith("ssm_")
-      mixer = block_norms(recurrent_leaves(n), n, "ssm") if recurrent else block_norms(attn_leaves(n), n, "attn")
+      kind = cfg.layer_attn[names.index(name)] if cfg.layer_attn else None  # (None at a recurrent layer too)
+      mixer = block_norms(recurrent_leaves(n), n, "ssm") if recurrent else block_norms(attn_leaves(n, kind), n, "attn")
       params[name] = {**mixer, **(expert_ffn(n) if name.endswith("moe_layers") else {"w_gate": w(next(keys), n, D, F), "w_up": w(next(keys), n, D, F), "w_down": w(next(keys), n, F, D)})}
   elif cfg.n_experts:
     # MoE model: dense prefix (layers [0, first_k_dense) globally), MoE rest.
@@ -457,6 +462,12 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
   None skips the hook entirely — base serving never pays the gather.
   """
   B, S, _ = x.shape
+  # The layer's own attention description, where the layer loop handed one over (``_scan_layers_over_pool``: a model
+  # whose attention kinds differ in shape): its query heads, and its rope's table of the program's ``{kind: table}``.
+  kind = p.get("attn_kind")
+  n_heads, rope = (kind.n_heads, kind) if kind is not None else (cfg.n_heads, cfg)
+  if isinstance(inv_freq, dict):
+    inv_freq = inv_freq[kind]
   q = _mm(x, p, "wq", cfg.quant_compute)
   k = _mm(x, p, "wk", cfg.quant_compute)
   v = _mm(x, p, "wv", cfg.quant_compute)
@@ -485,7 +496,7 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
   if cfg.qk_norm_whole:  # OLMo 2: RMSNorm over the whole q and k projections, before the split into heads
     q = rms_norm(q, p["q_norm"], cfg.norm_eps)
     k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-  q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+  q = q.reshape(B, S, n_heads, cfg.head_dim)
   k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
   v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
   if "q_norm" in p and not cfg.qk_norm_whole:  # qwen3: per-head RMSNorm on q/k before rope
@@ -497,7 +508,7 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
     q = q * jnp.asarray(cfg.attn_multiplier * cfg.head_dim**0.5, q.dtype)
   if not cfg.use_rope:  # "nope": no position term; causality alone orders the tokens
     return q, k, v
-  m = rope_attention_factor(cfg)
+  m = rope_attention_factor(rope)
   q = apply_rope(q, positions, inv_freq, m)
   k = apply_rope(k, positions, inv_freq, m)
   return q, k, v
@@ -509,10 +520,20 @@ def _mlp_act(x, cfg: ModelConfig):
   return jax.nn.silu(x.astype(jnp.float32))
 
 
-def _attn_opts(cfg: ModelConfig, layer_sliding=None) -> dict:
-  """Attention kwargs a config implies (gemma2's scale override, logit
-  softcap, sliding window — the window rides a per-layer traced flag)."""
+def _layer_window(p) -> int:
+  """The static window of the layer whose parameters ``p`` are (0: none): its ``AttnKind``'s, where the layer loop
+  handed one over — the Pallas kernels' operand."""
+  kind = p.get("attn_kind")
+  return kind.window if kind is not None else 0
+
+
+def _attn_opts(cfg: ModelConfig, layer_sliding=None, kind=None) -> dict:
+  """Attention kwargs of the XLA cores that a config implies: gemma2's scale override and logit softcap, and the
+  layer's window — static where the layer loop handed the layer's ``AttnKind`` over (``kind``), else gemma2's, which
+  rides a per-layer traced flag (``layer_sliding``: the stack's ``is_sliding`` leaf)."""
   opts: dict = {}
+  if kind is not None and kind.window:
+    opts["sliding_window"] = kind.window
   if cfg.query_pre_attn_scalar:
     opts["scale"] = 1.0 / cfg.query_pre_attn_scalar**0.5
   if cfg.attn_logit_softcap:
@@ -528,6 +549,26 @@ def _residual(h, out, cfg: ModelConfig):
   if cfg.residual_multiplier != 1.0:  # the multiplier itself stays float32: 0.22 rounded to bfloat16 is 0.2197, in every block
     out = (out.astype(jnp.float32) * cfg.residual_multiplier).astype(out.dtype)
   return h + out
+
+
+# The nonlinearity of a head-wise gate on a softmax-attention layer's output (``AttnKind.out_gate``, leaf ``w_og``): its
+# one owner. (The row that brought the gate states ``gating: true`` and no function; softplus is assumed.)
+_out_gate = jax.nn.softplus
+
+
+@component_scope("xot.attn_proj")
+def _attn_out(h, x, attn, p, cfg: ModelConfig):
+  """An attention layer's tail, shared by every layer step: attn [B, S, Hq, hd] of the normed input x → the head-wise
+  output gate where the layer has one (one scalar a head from x, in float32), ``wo``, the post-norm where the block has
+  one, the residual."""
+  B, S = h.shape[:2]
+  if "w_og" in p:
+    gate = _out_gate(jax.lax.dot_general(x, p["w_og"], (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32))  # [B, S, Hq]
+    attn = (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+  attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
+  if "post_attn_norm" in p:  # gemma2's post-attention layernorm; OLMo 2's only one
+    attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
+  return _residual(h, attn_out, cfg)
 
 
 def _mlp_block(h, p, cfg: ModelConfig):
@@ -1020,8 +1061,9 @@ _HYBRID_MLA_Q_BLOCK = 256
 
 
 def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params, slot_rows=None, fresh=None, seq_lens=None, adapter_ids=None):
-  """A hybrid's layers over a sequence, in the published order: the prefill
-  of the paged programs and the cache-less forward (scoring, training).
+  """The layers of a model whose layers differ in kind (``cfg.mixed_layers``) over a sequence, in the published
+  order: the prefill of the paged programs, the slot-cache forward of a model without recurrent layers, and the
+  cache-less forward (scoring, training).
 
   ``carry`` rides the layer loop as the page pool does in decode
   (``_scan_layers_over_pool``). Prefill: the rows' gathered K/V windows
@@ -1126,12 +1168,13 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
           "v": _write_cache(kv["v"], vq, start),
           "v_scale": _write_cache(kv["v_scale"], vs, start),
         }
+        window = _layer_window(p)
         if cfg.plain_attention and S > 1 and not packed and flash_supported(q.shape, kv["k"].shape[1]):
           # Prefill: int8 codes + scales stream straight through the flash
           # kernel (per-block in-register dequant) — no materialized bf16
           # cache copy, 1 byte/element HBM traffic. (int4 takes the einsum
           # path below — the flash kernel has no nibble unpack.)
-          attn = flash_attention_prefill(q, kv["k"], kv["v"], q_offset=positions[:, 0], k_scale=kv["k_scale"], v_scale=kv["v_scale"])
+          attn = flash_attention_prefill(q, kv["k"], kv["v"], q_offset=positions[:, 0], k_scale=kv["k_scale"], v_scale=kv["v_scale"], window=window)
         else:
           # Decode reads the cache as quantized CODES — the convert (and the
           # int4 nibble unpack) fuses into the einsum, so the HBM-bound cache
@@ -1139,33 +1182,31 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
           k_codes = unpack_int4_kv(kv["k"]) if packed else kv["k"]
           v_codes = unpack_int4_kv(kv["v"]) if packed else kv["v"]
           attn = gqa_attention(
-            q, k_codes, v_codes, positions, kv_positions, k_scale=kv["k_scale"], v_scale=kv["v_scale"], **_attn_opts(cfg, p.get("is_sliding"))
+            q, k_codes, v_codes, positions, kv_positions, k_scale=kv["k_scale"], v_scale=kv["v_scale"], **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind"))
           )
       else:
         kv = {"k": _write_cache(kv["k"], k, start), "v": _write_cache(kv["v"], v, start)}
         k_cache, v_cache = kv["k"], kv["v"]
-        # The Pallas kernels don't implement gemma2's softcap/sliding window.
+        # The Pallas kernels don't implement gemma2's softcap or a window that rides a traced flag
+        # (``cfg.plain_attention``); a layer kind's static window is the flash kernel's operand.
+        window = _layer_window(p)
         if cfg.plain_attention and S > 1 and not cfg.is_mla and flash_supported(q.shape, k_cache.shape[1]):
           # Prefill on TPU: flash kernel against the full cache (stale slots
           # beyond the prompt are positionally masked — slot index > position).
-          attn = flash_attention_prefill(q, k_cache.astype(h.dtype), v_cache.astype(h.dtype), q_offset=positions[:, 0])
-        elif cfg.plain_attention and S == 1 and not cfg.is_mla and flash_decode_supported(q.shape, k_cache.shape[1]):
+          attn = flash_attention_prefill(q, k_cache.astype(h.dtype), v_cache.astype(h.dtype), q_offset=positions[:, 0], window=window)
+        elif cfg.plain_attention and S == 1 and not window and not cfg.is_mla and flash_decode_supported(q.shape, k_cache.shape[1]):
           # Long-cache decode step via the split-K flash-decode kernel —
           # opt-in; see flash_decode_supported for the measured rationale.
           attn = flash_decode_attention(q, k_cache.astype(h.dtype), v_cache.astype(h.dtype), positions)
         else:
-          attn = gqa_attention(q, k_cache.astype(h.dtype), v_cache.astype(h.dtype), positions, kv_positions, **_attn_opts(cfg, p.get("is_sliding")))
+          attn = gqa_attention(q, k_cache.astype(h.dtype), v_cache.astype(h.dtype), positions, kv_positions, **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
     else:
       # The override (ring sp — parallel/ring_attention.py) takes the same
       # attention options as gqa_attention, so gemma2's scale/softcap/window
       # ride through either path.
-      attn = (attn_fn or gqa_attention)(q, k, v, positions, positions[0], **_attn_opts(cfg, p.get("is_sliding")))
+      attn = (attn_fn or gqa_attention)(q, k, v, positions, positions[0], **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
 
-  with jax.named_scope("xot.attn_proj"):
-    attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
-    if "post_attn_norm" in p:  # gemma2 post-attention layernorm
-      attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-    h = _residual(h, attn_out, cfg)
+  h = _attn_out(h, x, attn, p, cfg)
   h, aux, _ = _mlp_block(h, p, cfg)
   return h, kv, aux
 
@@ -1240,10 +1281,11 @@ def shard_forward(
   # lax.scan; MoE models with no dense prefix simply have no "layers" key.
   stacks = _layer_stacks(params)
 
-  if cfg.recurrent_layers:  # a hybrid, cache-less; with a cache its forward is the paged prefill (prefill_into_pages_many)
-    if use_cache:
+  if cfg.mixed_layers:  # layers of several kinds, in the published order
+    if use_cache and cfg.recurrent_layers:  # with a cache a hybrid's forward is the paged prefill (prefill_into_pages_many)
       raise ValueError("a hybrid has no slot-cache forward: shard_forward runs it cache-less")
-    h, new_cache = _hybrid_layers(h, params, cfg, positions, {}, adapter_ids=adapter_ids)[0], None
+    h, new_cache = _hybrid_layers(h, params, cfg, positions, kv_cache or {}, adapter_ids=adapter_ids)
+    new_cache = new_cache or None
   elif use_cache:
     parts = []
     off = 0
@@ -1313,6 +1355,8 @@ def shard_forward_aux(
   exactly equivalent to the single-node step, which optimizes
   ``CE + moe_aux_loss_coef · Σ aux`` (parallel/train_step.py).
   """
+  if len(cfg.attn_shapes) > 1:
+    raise ValueError("shard_forward_aux walks the two plain stacks: a model whose attention kinds have stacks of their own is not trained over the ring")
   h = embed_tokens(params, cfg, x) if x.ndim == 2 else x.astype(cfg.dtype)
   inv_freq = rope_inv_freq(cfg)
   kv_positions = positions[0]
@@ -1828,7 +1872,7 @@ def _whole_expert_leaves(params: Params, cfg: ModelConfig) -> tuple:
   the block form (``_mlp_block``)."""
   from ..ops.moe import ffn_form
 
-  for stack in (params[name] for name in ("moe_layers", "ssm_moe_layers") if name in params):
+  for stack in (stack for name, stack in params.items() if name.endswith("moe_layers")):
     if ffn_form(stack["w_experts_gate"], stack["w_experts_down"], cfg.moe_capacity_factor, cfg.mosaic_kernels, "w_experts_gate_scale" in stack) == "grouped":
       return tuple(name for name in stack if name.startswith("w_experts_"))
   return ()
@@ -1864,9 +1908,12 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params, whole: tuple = ()):
   model of two (dense prefix + experts) indexes the one pool from both, with
   no split and no join.
 
-  A hybrid's entry is a run ``(stack, lo, hi, pool_lo)`` (``_layer_runs``):
-  layers [lo, hi) of a stack, whose mixer's kind owns leaves of the pool of
-  its own (pages for attention layers, per-slot state for recurrent layers) and
+  An entry of a model whose layers differ in kind (``cfg.mixed_layers``) is a
+  run ``(stack, lo, hi, pool_lo, kind)`` (``_layer_runs``): layers [lo, hi) of
+  a stack — ``kind`` their ``AttnKind`` where the model describes its
+  attention layers one by one (None otherwise), which the step finds beside
+  the layer's leaves as ``attn_kind``, static —, whose mixer's kind owns leaves
+  of the pool of its own (pages for attention layers, per-slot state for recurrent layers) and
   counts its layers there from ``pool_lo`` on, so ``layer`` is the layer's
   index among the pool's layers of its kind, and the layer's parameters are
   read at their index in the stack inside the loop, as a scan reads its
@@ -1879,12 +1926,13 @@ def _scan_layers_over_pool(step, h, stacks, pool: Params, whole: tuple = ()):
   first = 0
   for stack in stacks:
     if isinstance(stack, tuple):
-      stack, lo, hi, pool_lo = stack
+      stack, lo, hi, pool_lo, kind = stack
       sliced, put = _split_whole(stack, whole)
+      described = {} if kind is None else {"attn_kind": kind}  # static: the run's attention description beside its leaves
 
-      def run_body(carry, at, sliced=sliced, put=put, shift=pool_lo - lo):
+      def run_body(carry, at, sliced=sliced, put=put, shift=pool_lo - lo, described=described):
         lp = {name: jax.lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) for name, leaf in sliced.items()}
-        return step(*carry, put(lp, at), at + shift if shift else at), None
+        return step(*carry, {**put(lp, at), **described}, at + shift if shift else at), None
 
       (h, pool), _ = jax.lax.scan(run_body, (h, pool), jnp.arange(lo, hi, dtype=jnp.int32))
       continue
@@ -1906,26 +1954,29 @@ def _layer_stacks(params: Params) -> list:
 
 
 def _layer_runs(params: Params, cfg: ModelConfig) -> list:
-  """A hybrid's layers in the published order, as runs ``(stack, lo, hi, pool_lo)``
+  """The layers of a model whose layers differ in kind (``cfg.mixed_layers``: recurrent layers beside attention
+  layers, or attention kinds of different shapes) in the published order, as runs ``(stack, lo, hi, pool_lo, kind)``
   for ``_scan_layers_over_pool``: consecutive layers of one (mixer, FFN) pairing
   are layers [lo, hi) of that pairing's stack (``cfg.layer_stack``) and layers
-  ``pool_lo`` on of the pool's leaves of their mixer's kind. granite-4.0-h-micro
+  ``pool_lo`` on of the pool's leaves of their mixer's kind; ``kind`` is their ``AttnKind`` (``cfg.layer_attn``), or
+  None. Laguna-XS.2's first stage is a full-attention layer with a dense FFN, three window layers with experts, a
+  full-attention layer with experts: three stacks, three runs, one pool of five page layers. granite-4.0-h-micro
   is state-space runs of 5, 9, 9, 9 and 4 with an attention layer after each of
   the first four; Ling-3.0-flash's first stage a KDA layer with a dense FFN, four
   with experts, a latent-attention layer with experts, a KDA layer with experts.
   Any other model: its stacks, whole."""
-  if not cfg.recurrent_layers:
+  if not cfg.mixed_layers:
     return _layer_stacks(params)
   runs, in_stack, in_pool = [], {}, {True: 0, False: 0}
   for i in range(cfg.n_layers):
-    name, recurrent = cfg.layer_stack(i), cfg.layer_types[i] != "attention"
+    name, recurrent = cfg.layer_stack(i), bool(cfg.layer_types) and cfg.layer_types[i] != "attention"
     at = in_stack.get(name, 0)
     if runs and runs[-1][0] == name:
       runs[-1][2] += 1
     else:
-      runs.append([name, at, at + 1, in_pool[recurrent]])
+      runs.append([name, at, at + 1, in_pool[recurrent], cfg.layer_attn[i] if cfg.layer_attn else None])
     in_stack[name], in_pool[recurrent] = at + 1, in_pool[recurrent] + 1
-  return [(params[name], lo, hi, pool_lo) for name, lo, hi, pool_lo in runs]
+  return [(params[name], *run) for name, *run in runs]
 
 
 def _write_kv(pool: Params, k, v, layer, block_tables, pos, page_size: int, kv_quant: str, kernel: bool = False, interpret: bool = False) -> Params:
@@ -1981,14 +2032,11 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
       # stream per page tile with in-register dequant — the pool read stays
       # 1 byte/element (0.5 for packed int4; the gather fallback below moves
       # the same quantized bytes but materializes the gathered window).
-      attn = paged_decode_attention(q[:, 0], pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, kv_quant=kv_quant, **scales)[:, None]
+      window = _layer_window(p)
+      attn = paged_decode_attention(q[:, 0], pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, kv_quant=kv_quant, window=window, **scales)[:, None]
     else:
-      attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding")))
-  with jax.named_scope("xot.attn_proj"):
-    attn_out = _mm(attn.reshape(B, S, -1), p, "wo", cfg.quant_compute)
-    if "post_attn_norm" in p:  # gemma2
-      attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-    h = _residual(h, attn_out, cfg)
+      attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
+  h = _attn_out(h, x, attn, p, cfg)
   h, _, visited = _mlp_block(h, p, cfg)
   return h, pool, visited
 
@@ -2194,7 +2242,9 @@ def fused_mixed_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token
 
 
 def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg: ModelConfig, page_size: int, use_kernel: bool = False, interpret: bool = False, adapter_ids=None, kv_quant: str | None = None):
-  """One decoder layer for a multi-token VERIFY window against the page pool.
+  """One decoder layer for a multi-token VERIFY window against the page pool. ("Window" here and in
+  ``paged_window_forward`` is speculation's: the W tokens a round verifies — not a layer's attention window, which is
+  ``AttnKind.window`` and rides along as the kernel's and the reference's operand.)
 
   ``pool`` is the stacked page dict and ``layer`` this layer's index into
   it, as in ``_paged_layer_step``. positions [B, W] are each row's own
@@ -2219,6 +2269,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
   q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
   lengths = positions[:, -1] + 1  # valid KV slots incl. the window's writes
   kernel = kernel_attends(cfg, use_kernel)
+  window = _layer_window(p)  # the LAYER's window (``AttnKind.window``), not this step's verify window W
   for j in range(W):  # W is small (gamma_max+1) and static; per-token scales, the values a one-token-at-a-time write produces
     pool = _write_kv(pool, k[:, j], v[:, j], layer, block_tables, positions[:, j], page_size, kv_quant, kernel, interpret)
   scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if kv_quant else {}
@@ -2227,18 +2278,14 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
     # by its own query's length. Token-exact against the gather route (A/B-pinned).
     attn = jnp.stack(
       [
-        paged_decode_attention(q[:, j], pool["k"], pool["v"], block_tables, positions[:, j] + 1, page_size, interpret=interpret, layer=layer, kv_quant=kv_quant, **scales)
+        paged_decode_attention(q[:, j], pool["k"], pool["v"], block_tables, positions[:, j] + 1, page_size, interpret=interpret, layer=layer, kv_quant=kv_quant, window=window, **scales)
         for j in range(W)
       ],
       axis=1,
     )  # [B, W, Hq, hd]
   else:  # gather route: one multi-query reference call
-    attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, q_positions=positions, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding")))
-  with jax.named_scope("xot.attn_proj"):
-    attn_out = _mm(attn.reshape(B, W, -1), p, "wo", cfg.quant_compute)
-    if "post_attn_norm" in p:  # gemma2
-      attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
-    h = h + attn_out
+    attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, q_positions=positions, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
+  h = _attn_out(h, x, attn, p, cfg)
   h, *_ = _mlp_block(h, p, cfg)
   return h, pool
 
@@ -2246,7 +2293,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
 def paged_window_forward(params, cfg: ModelConfig, shard: Shard, tokens, positions, pool, block_tables, page_size: int, use_kernel: bool = False, interpret: bool = False, adapter_ids=None, kv_quant: str | None = None):
   """W-token forward for every row against the page pool — the batched
   speculative VERIFY pass. tokens/positions [B, W] → (logits [B, W, V],
-  updated pool, in the form it came in). Full shard only. ``use_kernel``
+  updated pool, in the form it came in; the "window" is the verify's W tokens, not an attention window). Full shard only. ``use_kernel``
   routes each window position through the tuned Pallas kernel instead of
   the gather reference (``_paged_window_layer_step``; A/B-pinned token-exact)."""
   if cfg.is_mla:
@@ -2257,7 +2304,7 @@ def paged_window_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
   def step(h, pool, lp, layer):
     return _paged_window_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, interpret, adapter_ids, kv_quant)
 
-  h, pool = _scan_layers_over_pool(step, h, _layer_stacks(params), pool)
+  h, pool = _scan_layers_over_pool(step, h, _layer_runs(params, cfg), pool)
   return head_logits(params, cfg, h), pool
 
 
@@ -2569,7 +2616,7 @@ def score_last_tokens(params, cfg: ModelConfig, shard: Shard, tokens, seq_len, n
     h, _, aux = _layer_step(h, lp, None, positions, positions[0], inv_freq, cfg, False)
     return (h, _aux + aux), None
 
-  if cfg.recurrent_layers:  # a hybrid: the same mixers from a zero state, in the published order
+  if cfg.mixed_layers:  # the same mixers (a recurrent one from a zero state), in the published order
     h, _ = _hybrid_layers(h, params, cfg, positions, {})
   else:
     for stack in _layer_stacks(params):

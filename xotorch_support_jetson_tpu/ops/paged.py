@@ -365,7 +365,12 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths,
 # the row's own block-table entries (scalar-prefetched into SMEM). No axis
 # is sized by the table's width mp: a row of length 0 does nothing, and
 # entries past a row's length are never read — not the table entry, not the
-# page behind it.
+# page behind it. A layer with a window (``window``, static, since PR 46)
+# walks from the tile that holds position ``length - window`` on, fetches from
+# the page that holds it and masks the positions before it: its pages stay
+# resident for the whole context and what the call reads is the window's.
+# Such a call is named ``paged_decode_window``; ``window`` 0 traces the kernel
+# without any of it.
 #
 # The pool operands are the STACKED leaves and stay in HBM (``pl.ANY``); the
 # layer rides scalar prefetch beside the block table and the lengths, and the
@@ -429,11 +434,11 @@ def _page_tile(mp: int) -> int:
   return g
 
 
-def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str):
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str, window: int = 0):
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
-  G, ps = pages_per_step, page_size
+  G, ps, W = pages_per_step, page_size, window
   quantized = bool(kv_quant)
   packed = kv_quant == "int4"
   n_pools = 4 if quantized else 2  # k, v (+ their scales): HBM operands first, then the output, then their VMEM tiles
@@ -450,6 +455,27 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
     beyond the table inside it)."""
     return jnp.clip(jnp.minimum(pl.cdiv(len_ref[row], ps), mp) - tile * G, 0, G)
 
+  # A layer with a window (``window`` > 0, static): the row's one query, at
+  # position length - 1, sees the keys from ``seen_from`` on, so the walk
+  # starts at the tile that holds that position, fetches from the page that
+  # holds it and masks the positions before it. THE owner of the window's
+  # tile arithmetic; every ``if W`` below is Python's, and ``window`` 0 traces
+  # the kernel as it was.
+
+  def seen_from(row):
+    return jnp.maximum(len_ref[row] - W, 0)
+
+  def first_tile(row):
+    """The first tile a row's walk takes (held under the row's last: a length beyond the table)."""
+    if not W:
+      return 0
+    last = jnp.maximum(pl.cdiv(jnp.minimum(pl.cdiv(len_ref[row], ps), mp), G) - 1, 0)
+    return jnp.minimum(seen_from(row) // (G * ps), last)
+
+  def first_page(row, tile):
+    """The first page of a tile the window lets the query see: no DMA below it."""
+    return jnp.clip(seen_from(row) // ps - tile * G, 0, G) if W else 0
+
   def tile_dmas(row, tile, slot, act):
     """Start or wait for (``act``) the DMAs of the resident pages of one
     tile: per page one copy of its [Hkv, ps, hd] codes for k and for v, and
@@ -462,7 +488,7 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
         act(pltpu.make_async_copy(hbm.at[layer, p], buf.at[slot, j], sem.at[slot]))
       return carry
 
-    jax.lax.fori_loop(0, tile_pages(row, tile), page, 0)
+    jax.lax.fori_loop(first_page(row, tile), tile_pages(row, tile), page, 0)
 
   def start(row, tile, slot):
     tile_dmas(row, tile, slot, lambda dma: dma.start())
@@ -482,9 +508,11 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   # The row before, if it held anything, started this row's first tile.
   prefetched = jnp.logical_and(b > 0, len_ref[jnp.maximum(b - 1, 0)] > 0)
 
+  t0 = first_tile(b)
+
   @pl.when(jnp.logical_not(prefetched))
   def _fetch_first_tile():
-    start(b, 0, first_slot)
+    start(b, t0, first_slot)
 
   m_ref[...] = jnp.full_like(m_ref, NEG_INF)
   l_ref[...] = jnp.zeros_like(l_ref)
@@ -537,7 +565,8 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
     that the row does not hold were never fetched — the slot holds what an
     earlier tile left there — and are masked by position with SELECTS: a
     stale scale lane may be anything, NaN too, and must not reach a product."""
-    valid = tile * G * ps + jax.lax.broadcasted_iota(jnp.int32, (1, F * ps), 1) < length
+    pos = tile * G * ps + jax.lax.broadcasted_iota(jnp.int32, (1, F * ps), 1)
+    valid = jnp.logical_and(pos < length, pos >= length - W) if W else pos < length
     if quantized:
       ks = fold_scales(ks_buf, slot, F)  # (its stale lanes end in the scores' select)
       vs = jnp.where(valid, fold_scales(vs_buf, slot, F), 0.0)
@@ -576,7 +605,7 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   folds = [G >> k for k in range(3) if G >> k]
 
   def tile_body(i, carry):
-    slot = (first_slot + i) % 2
+    slot = (first_slot + i - t0) % 2 if W else (first_slot + i) % 2
 
     @pl.when(i + 1 < n_tiles)
     def _fetch_next_tile():
@@ -584,7 +613,8 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
 
     @pl.when(jnp.logical_and(i + 1 == n_tiles, b + 1 < n_rows))
     def _fetch_next_rows_first_tile():
-      start(jnp.minimum(b + 1, n_rows - 1), 0, 1 - slot)
+      nxt = jnp.minimum(b + 1, n_rows - 1)
+      start(nxt, first_tile(nxt), 1 - slot)
 
     tile_dmas(b, i, slot, lambda dma: dma.wait())
     n = tile_pages(b, i)  # ≥ 1: the loop runs over resident tiles
@@ -592,8 +622,8 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
       pl.when(jnp.logical_and(n <= F, n > narrower))(functools.partial(attend_fold, i, slot, F))
     return carry
 
-  jax.lax.fori_loop(0, n_tiles, tile_body, 0)
-  slot_ref[0] = (first_slot + n_tiles) % 2
+  jax.lax.fori_loop(t0, n_tiles, tile_body, 0)
+  slot_ref[0] = (first_slot + n_tiles - t0) % 2 if W else (first_slot + n_tiles) % 2
   l = l_ref[...]
   o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
@@ -637,7 +667,7 @@ def stored_pool_form(pool: dict, like: dict) -> dict:
 @component_scope("xot.attn")
 def paged_decode_attention(
   q, k_pool, v_pool, block_tables, lengths, page_size: int,
-  k_scale_pool=None, v_scale_pool=None, pages_per_step: int | None = None, interpret: bool = False, layer=None, kv_quant: str | None = None,
+  k_scale_pool=None, v_scale_pool=None, pages_per_step: int | None = None, interpret: bool = False, layer=None, kv_quant: str | None = None, window: int = 0,
 ):
   """Decode attention off the page pool (dense GQA models).
 
@@ -657,7 +687,11 @@ def paged_decode_attention(
   shapes); stored leaves that need it are converted per call — a copy of the
   leaf, which a program with a layer loop must make outside it.
   ``pages_per_step`` (static) overrides the tile (``PAGE_TILE`` clamped to
-  the table's width). Returns [B, Hq, hd].
+  the table's width: the same rule for a layer with a window and one
+  without). ``window`` (static; 0: none): the layer's window — the row's
+  query sees its last ``window`` positions only, and the pages wholly before
+  them are neither fetched nor folded; such a call is named
+  ``paged_decode_window`` in the trace. Returns [B, Hq, hd].
   """
   if (k_scale_pool is None) != (v_scale_pool is None):
     raise ValueError("paged_decode_attention: k_scale_pool and v_scale_pool must be passed together")
@@ -668,12 +702,12 @@ def paged_decode_attention(
   G = pages_per_step or _page_tile(jnp.shape(block_tables)[1])
   return _paged_decode_attention_impl(
     q, block_tables, lengths, layer, *(x for x, _ in pools),
-    page_size=page_size, pages_per_step=G, kv_quant=kv_quant, interpret=interpret,
+    page_size=page_size, pages_per_step=G, kv_quant=kv_quant, interpret=interpret, window=int(window),
   )
 
 
-@functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret"))
-def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool):
+@functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret", "window"))
+def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool, window: int = 0):
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
@@ -710,12 +744,13 @@ def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_s
     scratch_shapes=scratch,
   )
   out = pl.pallas_call(
-    functools.partial(_paged_decode_kernel, page_size=page_size, scale=scale, pages_per_step=G, kv_quant=kv_quant),
+    functools.partial(_paged_decode_kernel, page_size=page_size, scale=scale, pages_per_step=G, kv_quant=kv_quant, **({"window": window} if window else {})),
     out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
     grid_spec=grid_spec,
     # Rows in order: the prefetch chain crosses them.
     compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=tile_bytes + (16 << 20)),
     interpret=interpret,
+    **({"name": "paged_decode_window"} if window else {}),  # read apart in a trace; the call without a window keeps the name it had
   )(block_tables, lengths, layer, q, *pools)
   if packed:
     # Undo the deinterleave: channel 2i from the even half, 2i+1 from the odd half.
@@ -725,10 +760,12 @@ def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_s
 
 def paged_kernel_supported(cfg, platform: str | None = None) -> bool:
   """Whether a paged program's attention core is the Pallas kernel: wherever
-  it can run — a TPU, plain attention (no softcap, no window), not MLA, a
-  head width the kernel tiles — and ``XOT_TPU_NO_FLASH`` is unset. It is what
-  the decode programs resolve ``use_kernel=None`` to and what the scheduler
-  labels its chunks by; everything else takes the XLA gather."""
+  it can run — a TPU, plain attention (``cfg.plain_attention``: no softcap,
+  no scale override, no window that rides a traced flag; a window that is
+  static per layer kind is the kernel's operand), not MLA, a head width the
+  kernel tiles — and ``XOT_TPU_NO_FLASH`` is unset. It is what the decode
+  programs resolve ``use_kernel=None`` to and what the scheduler labels its
+  chunks by; everything else takes the XLA gather."""
   return _mosaic_platform(cfg, platform) and cfg.plain_attention and not cfg.is_mla and cfg.head_dim in (64, 128, 256)
 
 
@@ -751,6 +788,7 @@ def decode_kernels_supported(cfg, platform: str | None = None) -> bool:
 def kernel_attends(cfg, use_kernel) -> bool:
   """Whether a paged program told ``use_kernel`` attends through the Pallas
   kernel: the one test of the layer steps, the token write and the pool-form
-  conversion. The kernel has no softcap or window and MLA has its own core,
-  so such a model takes the gather whatever it was told."""
+  conversion. The kernel has no softcap and takes a window only as a static
+  operand (``cfg.plain_attention``), and MLA has its own core, so a model
+  outside that takes the gather whatever it was told."""
   return bool(use_kernel) and cfg.plain_attention and not cfg.is_mla

@@ -29,7 +29,7 @@ BLOCK_Q = 128
 BLOCK_K = 128
 
 
-def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: int, scale: float, quantized: bool):
+def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: int, scale: float, quantized: bool, window: int = 0):
   """Grid: (B, Hq, Sq/BQ, Skv/BK) — the KV axis is GRID-tiled (innermost,
   sequential) with the online-softmax state carried in VMEM scratch, so
   VMEM holds one [BK, hd] K/V tile at a time regardless of Skv. (The first
@@ -41,7 +41,13 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
   refs precede the outputs — dequantization is per-(token, head) scales
   applied to scores/probs in-register (cf. ops/attention.py gqa_attention),
   so the HBM stream stays 1 byte/element and the quantized prefill never
-  materializes a dequantized cache."""
+  materializes a dequantized cache.
+
+  ``window`` (static; 0: none): a query at t sees the keys in (t - window, t]
+  (ops/attention.py ``cap_and_mask_scores``'s rule): blocks wholly before the
+  tile's first query's window are skipped like those past its causal horizon,
+  and the edge blocks masked. Every ``if window`` is Python's: 0 traces the
+  kernel as it was."""
   import jax.experimental.pallas as pl
 
   if quantized:
@@ -68,7 +74,11 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
   # NEG_INF columns: skip their COMPUTE. Their DMA still streams: there is
   # no index-map clamp here, so the kernel needs no scalar-prefetch grid. The
   # compute skip alone keeps the MXU work O(context).
-  @pl.when(start <= off_ref[b] + (qi + 1) * bq - 1)
+  needed = start <= off_ref[b] + (qi + 1) * bq - 1
+  if window:  # ... and the block's last key is inside the window of the tile's first query
+    needed = jnp.logical_and(needed, start + block_k - 1 > off_ref[b] + qi * bq - window)
+
+  @pl.when(needed)
   def _block():
     k_blk = k_ref[0, 0].astype(jnp.float32)  # [BK, hd]
     v_blk = v_ref[0, 0].astype(jnp.float32)
@@ -79,6 +89,8 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
       scores = scores * jnp.transpose(ks_ref[0, 0], (1, 0))
     kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)  # [1,BK]
     mask = kv_pos <= q_pos
+    if window:
+      mask = jnp.logical_and(mask, kv_pos > q_pos - window)
     scores = jnp.where(mask, scores, NEG_INF)
     m = m_ref[...]
     blk_m = jnp.max(scores, axis=1, keepdims=True)  # [BQ,1]
@@ -99,9 +111,14 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
     o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(tracked_jit, "ops.flash_prefill", static_argnames=("interpret",))
+# A layer with a window computes the K blocks its window touches and skips the rest, so its block is kept near the
+# window's size: at the 2048 of a layer without one, a window of 512 would compute what full causal attention does.
+WINDOW_BLOCK_K = 512
+
+
+@functools.partial(tracked_jit, "ops.flash_prefill", static_argnames=("interpret", "window"))
 @component_scope("xot.attn")
-def flash_attention_prefill(q, k, v, q_offset=0, k_scale=None, v_scale=None, interpret: bool = False):
+def flash_attention_prefill(q, k, v, q_offset=0, k_scale=None, v_scale=None, interpret: bool = False, window: int = 0):
   """q [B,Sq,Hq,hd], k/v [B,Skv,Hkv,hd] → [B,Sq,Hq,hd].
 
   ``q_offset`` — int or [B] int32 (TRACED): absolute position of each row's
@@ -109,7 +126,9 @@ def flash_attention_prefill(q, k, v, q_offset=0, k_scale=None, v_scale=None, int
   pad; the positional mask keeps padded KV slots (slot index > pos) inert as
   long as they hold finite values). With ``k_scale``/``v_scale``
   [B,Skv,Hkv,1] (int8 KV — models/quantize.py quantize_kv), k/v are int8
-  codes dequantized in-register per block.
+  codes dequantized in-register per block. ``window`` (static; 0: none)
+  restricts each query to its last ``window`` positions, whole-prompt and
+  chunked (``q_offset``) alike.
   """
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
@@ -134,9 +153,9 @@ def flash_attention_prefill(q, k, v, q_offset=0, k_scale=None, v_scale=None, int
   # on this platform is ~25 µs; at BLOCK_K=128 a 32K cache is 512K steps
   # (~13 s per 512-token chunk, measured) — at 2048 it is 32× fewer. VMEM
   # per step stays ≤ ~1 MB ([2048, hd] K+V tiles + the [BQ, 2048] scores).
-  block_k = next((bk for bk in (2048, 1024, 512, 256, 128) if Skv % bk == 0), BLOCK_K)
+  block_k = next((bk for bk in (2048, 1024, 512, 256, 128) if Skv % bk == 0 and (not window or bk <= max(WINDOW_BLOCK_K, BLOCK_K))), BLOCK_K)
   grid = (B, Hq, Sq // BLOCK_Q, Skv // block_k)
-  kernel = functools.partial(_flash_kernel, block_k=block_k, scale=scale, quantized=quantized)
+  kernel = functools.partial(_flash_kernel, block_k=block_k, scale=scale, quantized=quantized, **({"window": int(window)} if window else {}))
   in_specs = [
     pl.BlockSpec(memory_space=pltpu.SMEM),
     pl.BlockSpec((1, 1, BLOCK_Q, hd), lambda b, h, i, kb: (b, h, i, 0)),

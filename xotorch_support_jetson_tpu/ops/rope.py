@@ -20,30 +20,39 @@ import jax.numpy as jnp
 from ..models.config import LongRopeScaling, ModelConfig, RopeScaling, YarnScaling
 
 
-def rope_inv_freq(cfg: ModelConfig) -> jnp.ndarray:
+def rope_inv_freq(cfg: ModelConfig):
   """[rot_dim/2] inverse frequencies, with optional llama3/yarn scaling.
 
   For MLA models (deepseek) only the ``qk_rope_head_dim`` channel carries
-  position; dense models rotate the whole head_dim.
+  position; dense models rotate the whole head_dim. A model whose attention
+  kinds differ in shape (``cfg.attn_shapes``) gets one table a kind,
+  ``{AttnKind: table}``, made once a program; the layer step takes its own
+  (models/decoder.py ``_dense_qkv``).
   """
+  if len(cfg.attn_shapes) > 1:
+    return {k: _inv_freq(int(cfg.head_dim * k.partial_rotary_factor), k.rope_theta, k.rope_scaling, cfg.max_seq_len) for k in dict.fromkeys(cfg.layer_attn) if k is not None}
   rot_dim = cfg.qk_rope_head_dim if cfg.is_mla else int(cfg.head_dim * cfg.partial_rotary_factor)
+  return _inv_freq(rot_dim, cfg.rope_theta, cfg.rope_scaling, cfg.max_seq_len)
+
+
+def _inv_freq(rot_dim: int, theta: float, scaling, max_seq_len: int) -> jnp.ndarray:
   half = rot_dim // 2
-  inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-  if isinstance(cfg.rope_scaling, YarnScaling):
-    return _yarn_inv_freq(rot_dim, cfg.rope_theta, cfg.rope_scaling)
-  if isinstance(cfg.rope_scaling, LongRopeScaling):
-    s = cfg.rope_scaling
+  inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+  if isinstance(scaling, YarnScaling):
+    return _yarn_inv_freq(rot_dim, theta, scaling)
+  if isinstance(scaling, LongRopeScaling):
     # Static short/long selection keyed to the effective max sequence (the
     # engine clamps cfg.max_seq_len to its serving cap) — see LongRopeScaling.
-    ext = s.short_factor if cfg.max_seq_len <= s.original_max_position_embeddings else s.long_factor
+    ext = scaling.short_factor if max_seq_len <= scaling.original_max_position_embeddings else scaling.long_factor
     return inv_freq / jnp.asarray(ext, dtype=jnp.float32)
-  if isinstance(cfg.rope_scaling, RopeScaling):
-    inv_freq = _llama3_scale(inv_freq, cfg.rope_scaling)
+  if isinstance(scaling, RopeScaling):
+    inv_freq = _llama3_scale(inv_freq, scaling)
   return inv_freq
 
 
-def rope_attention_factor(cfg: ModelConfig) -> float:
-  """Yarn/longrope post-scaling of cos/sin (HF multiplies them by it); 1.0 otherwise."""
+def rope_attention_factor(cfg) -> float:
+  """Yarn/longrope post-scaling of cos/sin (HF multiplies them by it); 1.0 otherwise. ``cfg``: a ModelConfig or one
+  layer's AttnKind."""
   return cfg.rope_scaling.attention_factor if isinstance(cfg.rope_scaling, (YarnScaling, LongRopeScaling)) else 1.0
 
 
