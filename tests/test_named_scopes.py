@@ -64,14 +64,16 @@ _NUMBERED = re.compile(r"[A-Za-z_][\w-]*\.\d+")
 
 def _program(text: str) -> str:
   """Optimised HLO text without what only describes its source: per-instruction
-  metadata, the stack-frame tables, and the serial numbers of instruction names
-  (renumbered in order of appearance: the scopes change how many instructions
-  the unoptimised module holds, hence every later serial, and nothing else)."""
+  metadata, the stack-frame tables, and the instruction names, which become
+  serials in order of appearance. The scopes change how many instructions the
+  unoptimised module holds, hence every later serial; and a ``conditional``'s
+  branch computation (``_next_token_batched``'s draw, ISSUE 47) names its
+  parameter after the innermost scope — ``xot_sample`` with, the function's
+  own name without. What an instruction does is in its text, not in its name."""
   seen: dict[str, str] = {}
 
   def renumber(m):
-    stem = m.group(0).rsplit(".", 1)[0]
-    return seen.setdefault(m.group(0), f"{stem}.#{len(seen)}")
+    return seen.setdefault(m.group(0), f"#{len(seen)}")
 
   return _NUMBERED.sub(renumber, _STACK_TABLES.sub("", _METADATA.sub("", text)))
 

@@ -666,6 +666,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_scheduler_preemptions_total",
   "xot_tpu_scheduler_page_starved_total",
   "xot_tpu_decode_chunks_total",
+  "xot_tpu_decode_draw_skipped_chunks_total",
   "xot_tpu_decode_tokens_total",
   "xot_tpu_prefill_chunks_total",
   "xot_tpu_recurrent_state_resets_total",  # slots a prefill from position 0 started from zeros (ISSUE 34)

@@ -285,6 +285,9 @@ class _Chunk:
   # Device int32 scalar: the distinct held experts the chunk's rows chose, summed over its expert layers and steps
   # (ops/moe.py): read back with the tokens, it feeds ``moe_experts_visited_total``. None: a program that does not count.
   experts_visited: object = None
+  # No row of the dispatch's ``temps`` operand was positive: the program's own predicate (models/decoder.py
+  # ``_next_token_batched``) was false at every step and no draw was taken (``decode_draw_skipped_chunks_total``).
+  draw_skipped: bool = False
 
 
 # A server of more than ``GROUP_SLOTS_WHOLE`` slots holds a prefill group to ``GROUP_ROWS`` rows, so that its prefill
@@ -2514,6 +2517,7 @@ class BatchedServer:
       spec=spec, worst=worst, rounds=self.chunk if spec else 0, gammas=gammas,
       proposers=proposers,
       mixed_ready=mixed_r, mixed_start=m_start, mixed_end=m_end,
+      draw_skipped=not (temps > 0).any(),
     )
 
   def _note_expert_form(self) -> None:
@@ -2650,12 +2654,16 @@ class BatchedServer:
         # Disagg overlap rides mixed ticks too: ship the slice's completed
         # full pages while the remaining prefill advances.
         self._disagg_stream_chunk(r)
+    path = {"path": "spec" if record.spec else self.decode_path}
     if record.active.any():
       # Per-chunk decode-path attribution: the dispatch table's real-world
       # mix, observable at /metrics instead of only in offline bench JSON.
       if record.mixed_ready is None:
         metrics.observe_hist("decode_chunk_seconds", chunk_dt)
-      metrics.inc("decode_chunks_total", labels={"path": "spec" if record.spec else self.decode_path})
+      metrics.inc("decode_chunks_total", labels=path)
+      if record.draw_skipped:
+        # The hit share of the program's draw predicate: skipped ÷ decode_chunks_total, path by path.
+        metrics.inc("decode_draw_skipped_chunks_total", labels=path)
 
     for i, slot in record.rows:
       if slot.finished or self.slots[i] is not slot:
@@ -2699,7 +2707,7 @@ class BatchedServer:
       if emit:
         # Same path label as this chunk's decode_chunks_total increment, so
         # the two per-path series stay ratio-able (tokens per chunk).
-        metrics.inc("decode_tokens_total", len(emit), labels={"path": "spec" if record.spec else self.decode_path})
+        metrics.inc("decode_tokens_total", len(emit), labels=path)
         # Inter-token latency: the chunk's wall-clock amortized over its
         # tokens — ONE weighted observation (utils/metrics.py observe_hist
         # n=k) instead of k lock round trips.

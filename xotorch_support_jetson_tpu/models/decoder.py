@@ -1758,7 +1758,10 @@ def sample_rows(logits, key, temps, top_ks, k_max: int):
   ``_next_token_batched`` on the in-program logits with the same key, so
   the sampled tokens match token-for-token — kept as the
   ``XOT_TPU_FUSED_SAMPLING=0`` A/B reference and for backends without the
-  fused programs (pp/sp)."""
+  fused programs (pp/sp). As there, the draw is taken only when some row's
+  temperature is positive (an all-greedy group pays the argmax alone) and the
+  key is split either way, so the caller's key schedule does not depend on
+  who samples."""
   tok, _ = _next_token_batched(logits, key, temps, top_ks, k_max)
   return tok
 
@@ -1801,14 +1804,26 @@ prefill_into_pages_many_sampled_inplace = tracked_jit("prefill.pages_many_sample
 @component_scope("xot.sample")
 def _next_token_batched(rows, key, temps, top_ks, k_max: int):
   """Per-row sampling: temp ≤ 0 rows greedy, others top-k at their own
-  (traced) temperature and top_k (ops/sampling.py sample_logits_per_row)."""
+  (traced) temperature and top_k (ops/sampling.py sample_logits_per_row).
+
+  The draw (divide, ``top_k`` over the vocabulary, categorical) is taken only
+  when some row's temperature is positive: a ``lax.cond`` on
+  ``any(temps > 0)``, a predicate of the operand the program already has, so
+  an all-greedy batch pays the argmax alone (ISSUE 47). The key advances
+  either way — the split is outside the ``cond`` — so a sampling row draws
+  the subkey it would have drawn had every earlier step sampled, and the
+  speculative rounds keep the plain program's split-per-step schedule."""
   from ..ops.sampling import sample_logits_per_row
 
   greedy_rows = jnp.argmax(rows, axis=-1).astype(jnp.int32)
   key, sub = jax.random.split(key)
-  safe_temp = jnp.where(temps > 0, temps, 1.0)
-  sampled = sample_logits_per_row(rows, sub, safe_temp, top_ks, k_max=k_max)
-  return jnp.where(temps > 0, sampled, greedy_rows), key
+
+  def draw():
+    safe_temp = jnp.where(temps > 0, temps, 1.0)
+    sampled = sample_logits_per_row(rows, sub, safe_temp, top_ks, k_max=k_max)
+    return jnp.where(temps > 0, sampled, greedy_rows)
+
+  return jax.lax.cond(jnp.any(temps > 0), draw, lambda: greedy_rows), key
 
 
 @partial(tracked_jit, "decode.batch", static_argnames=("cfg", "shard", "n_steps", "k_max"), donate_argnums=(4,))

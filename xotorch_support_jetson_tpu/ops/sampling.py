@@ -68,7 +68,13 @@ def sample_logits_per_row(
   pool of heterogeneous requests (inference/batch_scheduler.py). The static
   ``k_max`` caps the candidate set; each row's traced ``top_ks`` masks ranks
   beyond its own k, so per-request values neither recompile nor leak into
-  other rows."""
+  other rows.
+
+  This is the draw itself, for every row it is given: its one caller on the
+  served path (models/decoder.py ``_next_token_batched``) takes it only when
+  some row's temperature is positive — an all-greedy batch never ranks the
+  vocabulary — and splits its key before deciding, so the key advances
+  whether or not the draw is taken."""
   x = logits.astype(jnp.float32) / jnp.maximum(temps, 1e-6)[:, None]
   k_cap = min(k_max, x.shape[-1])
   vals, idxs = jax.lax.top_k(x, k_cap)  # [B, k_cap] descending
