@@ -69,8 +69,8 @@ def tables() -> np.ndarray:
 
 
 def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None = None, pad_rows: int = 0, params=PARAMS, cfg=CFG):
-  """Prefill ``{slot: tokens}`` as one group (each row from ``prefix[slot]`` on) → (last logits [K, V], pool)."""
-  rows = sorted(prompts)
+  """Prefill ``{slot: tokens}`` as one group, its rows in the dict's order (each row from ``prefix[slot]`` on) → (last logits [K, V], pool)."""
+  rows = list(prompts)
   prefix = prefix or {}
   K = len(rows) + pad_rows
   S = pad_to or max(len(prompts[r]) - prefix.get(r, 0) for r in rows)
@@ -299,6 +299,25 @@ def test_a_prompt_prefilled_in_two_chunks_equals_one():
   for got, want in zip(state_of(cut, 1), state_of(whole, 1)):
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
   np.testing.assert_allclose(np.asarray(cut_logits[0]), reference(toks)[-1], atol=TOL, rtol=0)
+
+
+def test_a_second_chunk_in_a_group_of_unsorted_slots_beside_a_fresh_and_a_padding_row_equals_one_chunk():
+  """The one path that USES the state a prefill group reads (``fresh`` false; ``models/decoder.py _state_rows``, ISSUE
+  48), and no cell of the benchmark sends it: two prompts prefilled to positions 48 and 32 as a group of slots 3, 0 and
+  a padding row, then continued in ONE group whose rows name slots 3, 2, 0 — neither sorted nor adjacent; slot 2's row
+  starts at position 0 — and a padding row, which names the slot past the last (its read is clamped onto slot 3's, its
+  write dropped). Every row ends in the logits and the state of its one-chunk prefill and in the token-by-token
+  reference's logits; slot 1, which no row names, stays zero."""
+  a, b, c = TOKENS[:83], TOKENS[10:80], TOKENS[60:90]
+  _, pool = prefill(fresh_pool(), {3: a[:48], 0: b[:32]}, pad_to=64, pad_rows=1)
+  logits, pool = prefill(pool, {3: a, 2: c, 0: b}, prefix={3: 48, 0: 32}, pad_to=64, pad_rows=1)
+  for i, (slot, toks) in enumerate({3: a, 2: c, 0: b}.items()):
+    whole_logits, whole = prefill(fresh_pool(), {slot: toks}, pad_to=96)
+    np.testing.assert_allclose(np.asarray(logits[i]), np.asarray(whole_logits[0]), atol=TOL, rtol=0, err_msg=f"slot {slot}")
+    np.testing.assert_allclose(np.asarray(logits[i]), reference(toks)[-1], atol=TOL, rtol=0, err_msg=f"slot {slot}")
+    for got, want in zip(state_of(pool, slot), state_of(whole, slot)):
+      np.testing.assert_allclose(got, want, atol=TOL, rtol=0, err_msg=f"slot {slot}")
+  assert not any(leaf.any() for leaf in state_of(pool, 1))
 
 
 def test_a_reused_slot_gives_its_second_tenant_the_solo_answer():

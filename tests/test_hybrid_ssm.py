@@ -90,8 +90,8 @@ def tables() -> np.ndarray:
 
 
 def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None = None, pad_rows: int = 0):
-  """Prefill ``{slot: tokens}`` as one group (each row from ``prefix[slot]`` on) → (last logits [K, V], pool)."""
-  rows = sorted(prompts)
+  """Prefill ``{slot: tokens}`` as one group, its rows in the dict's order (each row from ``prefix[slot]`` on) → (last logits [K, V], pool)."""
+  rows = list(prompts)
   prefix = prefix or {}
   K = len(rows) + pad_rows
   S = pad_to or max(len(prompts[r]) - prefix.get(r, 0) for r in rows)
@@ -206,6 +206,25 @@ def test_a_prompt_prefilled_in_two_chunks_equals_one():
   np.testing.assert_allclose(np.asarray(cut_logits[0]), reference(toks)[-1], atol=TOL, rtol=0)
 
 
+def test_a_second_chunk_in_a_group_of_unsorted_slots_beside_a_fresh_and_a_padding_row_equals_one_chunk():
+  """The one path that USES the state a prefill group reads (``fresh`` false; ``models/decoder.py _state_rows``, ISSUE
+  48), and no cell of the benchmark sends it: two prompts prefilled to positions 48 and 32 as a group of slots 3, 0 and
+  a padding row, then continued in ONE group whose rows name slots 3, 2, 0 — neither sorted nor adjacent; slot 2's row
+  starts at position 0 — and a padding row, which names the slot past the last (its read is clamped onto slot 3's, its
+  write dropped). Every row ends in the logits and the state of its one-chunk prefill and in the token-by-token
+  reference's logits; slot 1, which no row names, stays zero."""
+  a, b, c = TOKENS[:83], TOKENS[10:80], TOKENS[60:90]
+  _, pool = prefill(fresh_pool(), {3: a[:48], 0: b[:32]}, pad_to=64, pad_rows=1)
+  logits, pool = prefill(pool, {3: a, 2: c, 0: b}, prefix={3: 48, 0: 32}, pad_to=64, pad_rows=1)
+  for i, (slot, toks) in enumerate({3: a, 2: c, 0: b}.items()):
+    whole_logits, whole = prefill(fresh_pool(), {slot: toks}, pad_to=96)
+    np.testing.assert_allclose(np.asarray(logits[i]), np.asarray(whole_logits[0]), atol=TOL, rtol=0, err_msg=f"slot {slot}")
+    np.testing.assert_allclose(np.asarray(logits[i]), reference(toks)[-1], atol=TOL, rtol=0, err_msg=f"slot {slot}")
+    for got, want in zip(state_of(pool, slot), state_of(whole, slot)):
+      np.testing.assert_allclose(got, want, atol=TOL, rtol=0, err_msg=f"slot {slot}")
+  assert not any(leaf.any() for leaf in state_of(pool, 1))
+
+
 def test_a_reused_slot_gives_its_second_tenant_the_solo_answer():
   """(e) Slot 2 serves one request (prefill + decode steps), then another from position 0: the second sees zeros, not
   its predecessor's state, and its logits and state are those of a pool it has to itself."""
@@ -301,7 +320,7 @@ def test_a_prefill_group_holds_at_most_eight_rows_on_a_server_of_many_slots(monk
 
   monkeypatch.setenv("XOT_TPU_BATCH_SLOTS", "12")
   server = BatchedServer(_engine())
-  server.max_seq = 256
+  server.max_seq, server.pages_per_row = 256, 4
   ready = [bs._Ready(req=None, row=i, pad_to=pad) for i, pad in enumerate([32, 128, 64, 64, 128, 32, 64, 32, 32, 64, 32])]
   assert [len(g) for g in server._dispatch_groups(ready)] == [11]  # 12 slots: whole
   monkeypatch.setattr(bs, "GROUP_SLOTS_WHOLE", 4)  # (a server of more slots than that, without building 17 of them)
@@ -309,6 +328,39 @@ def test_a_prefill_group_holds_at_most_eight_rows_on_a_server_of_many_slots(monk
   assert [len(g) for g in groups] == [8, 3] and [g[0].pad_to for g in groups] == [128, 32]
   assert sorted(r.row for g in groups for r in g) == list(range(11))
   assert [len(g) for g in server._dispatch_groups(ready[:8])] == [8]
+
+
+@pytest.mark.parametrize(
+  "max_seq,chunk,rows,sizes",
+  [
+    (4096, 2048, [(0, 2048)] * 8, [8]),  # eight first chunks: the widest group there is, a window of 32 pages
+    (4096, 2048, [(2048, 1024)] * 8, [4, 4]),  # eight second chunks end past 2048 tokens: a window of 64 pages, four rows
+    (4096, 2048, [(0, 1024)] * 5 + [(2048, 1024)] * 3, [5, 3]),  # a later chunk would widen the window of all five
+    (4096, 2048, [(0, 1024)] * 3 + [(2048, 1024)] * 3, [4, 2]),  # ... and joins three, whose window it doubles
+    (8192, 2048, [(4096, 256)] * 5, [2, 2, 1]),  # a final bucket after 4096 tokens: a window of 128 pages, two rows
+    (4096, 0, [(2048, 1024)] * 8, [8]),  # no chunking: a first group is as wide as a row, nothing to hold a later one to
+  ],
+  ids=["first_chunks", "second_chunks", "second_beside_five_first", "second_beside_three_first", "window_of_128_pages", "unchunked"],
+)
+def test_a_prefill_group_of_a_wider_page_window_holds_fewer_rows(monkeypatch, max_seq, chunk, rows, sizes):
+  """A group's program gathers each row's page window of every attention layer, so on a server of more than 16 slots
+  no group's windows together are wider than those of eight first chunks (8 x ``XOT_TPU_PREFILL_CHUNK``): a group
+  that ends further on — later chunks of long prompts — holds 4, 2 or 1 rows (``_group_rows``; ISSUE 48: eight rows x
+  4096 tokens is the group XLA:TPU refuses beside Olmo-Hybrid's pool). Counted on the padded rows, a power of two."""
+  from xotorch_support_jetson_tpu.inference import batch_scheduler as bs
+
+  monkeypatch.setenv("XOT_TPU_BATCH_SLOTS", "12")
+  monkeypatch.setenv("XOT_TPU_PREFILL_CHUNK", str(chunk))
+  monkeypatch.setattr(bs, "GROUP_SLOTS_WHOLE", 4)
+  server = BatchedServer(_engine())
+  server.max_seq, server.pages_per_row = max_seq, max_seq // server.page_size
+  groups = server._dispatch_groups([bs._Ready(req=None, row=i, pad_to=pad, prefix_len=prefix) for i, (prefix, pad) in enumerate(rows)])
+  assert [len(g) for g in groups] == sizes and sorted(r.row for g in groups for r in g) == list(range(len(rows)))
+  for g in groups:
+    window = server._page_window(max(r.prefix_len for r in g) + g[0].pad_to)
+    assert not chunk or server._row_bucket(len(g)) * window <= bs.GROUP_ROWS * server._page_window(chunk)
+  monkeypatch.setattr(bs, "GROUP_SLOTS_WHOLE", 16)  # a server of up to 16 slots is never asked
+  assert [len(g) for g in server._dispatch_groups([bs._Ready(req=None, row=i, pad_to=pad, prefix_len=prefix) for i, (prefix, pad) in enumerate(rows)])] == [len(rows)]
 
 
 def test_the_slot_cache_paths_refuse_a_recurrent_configuration():

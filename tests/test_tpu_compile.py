@@ -15,6 +15,7 @@ Code that asks ``jax.default_backend()`` sees the CPU here, so the tests
 hand the jitted kernels and steps their shapes (and ``use_kernel``) directly.
 """
 
+import math
 import os
 import pathlib
 import re
@@ -283,16 +284,36 @@ def test_qkv_weights_are_read_where_they_lie(chip, llama_1b, weights):
   print(f"decode.paged_batch B=16 {weights}: temp={mem.temp_size_in_bytes}")
 
 
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([a-z][\w\-]*)\((.*)$", re.M)  # name = result type opcode(operands...
+
+
 def _takers(text: str, shape: str) -> list[tuple[str, str]]:
   """(name, opcode) of every fusion and custom call of an optimised HLO text that takes a value of ``shape`` (a
   regex) as an operand. Operands are printed by name, so the names' shapes are read first."""
-  line_re = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([a-z][\w\-]*)\((.*)$", re.M)
-  lines = line_re.findall(text)
+  lines = _HLO_LINE.findall(text)
   shapes = {name: result for name, result, _, _ in lines}
   out = []
   for name, _, op, rest in lines:
     if op in ("fusion", "custom-call") and any(re.fullmatch(shape + r"\S*", shapes.get(operand, "")) for operand in re.findall(r"%[\w.\-]+", rest.split("), ")[0])):
       out.append((name, op))
+  return out
+
+
+def _materialised(text: str) -> list[tuple[str, str, str, list[str], str]]:
+  """(name, result type, opcode, operand names, op_name) of every instruction of an optimised HLO text that stands in
+  the entry computation, a loop's body or a called one — not inside a fusion's or a reducer's own computation, whose
+  instructions produce no buffer."""
+  inner = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", text)) | set(re.findall(r"to_apply=(%[\w.\-]+)", text))
+  out, computation = [], None
+  for line in text.splitlines():
+    head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+    if head:
+      computation = head.group(1)
+    m = None if computation in inner else _HLO_LINE.match(line)
+    if m:
+      name, result, op, rest = m.groups()
+      scope = re.search(r'op_name="([^"]*)"', rest)
+      out.append((name, result, op, re.findall(r"%[\w.\-]+", rest.split("), ")[0]), scope.group(1) if scope else ""))
   return out
 
 
@@ -556,9 +577,21 @@ def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5
   it and no fusion takes the leaf or a layer of it — the XLA expression compiled to two a run), the paged kernel takes
   Mistral's tile of 8 pages (two slots of 8 pages of K and of V are 15.7 MB of VMEM, inside the limit the call asks
   for), no instruction copies the state leaf or a layer of it, and the compiler's argument bytes are what they were
-  before the step had a kernel (12.81 GB). And the largest prefill the cell meets, a
-  group of 8 rows padded to 1024 tokens with the pool donated, fits beside them: the chunked delta rule's float32
-  operands at 64 positions a chunk are its temporaries."""
+  before the step had a kernel (12.81 GB). And the largest prefill groups a server of this pool dispatches fit beside
+  them with the pool donated — larger than any the cell meets (8 rows padded to 1024 tokens from position 0, a page
+  table of 16): eight first chunks (8 x ``XOT_TPU_PREFILL_CHUNK`` = 2048 tokens, a table of 32 pages) and, at the
+  widest table a row can have (64 pages: a group that ends past 2048 tokens, the later chunks of long prompts), the
+  four rows the scheduler holds such a group to (``BatchedServer._group_rows``). The chunked delta rule's float32
+  operands at 64 positions a chunk and the rows' gathered K/V windows — every attention layer's, 240 MB a layer each
+  of K and of V at 8 x 4096 tokens — are the temporaries: 3.48 and 2.55 GB. Eight rows at a table of 64 are what
+  ``_group_rows`` exists for: XLA:TPU refuses that program by 52 MB at 8 x 1024 ("Used 15.80G of 15.75G", 1.17 GB of
+  it the heap's fragmentation), where until ISSUE 48 it fitted as a rematerialised program (79 instructions named
+  ``remat``, 4.62 GB of temporaries, 1.2 GB of them the two cuts of the WHOLE state leaf that the rows' state read was
+  lowered to once a Gated-DeltaNet layer — ``test_hybrid_prefill_group_reads_the_admitted_rows_state_where_it_lies``;
+  PERF.md §7)."""
+  from types import SimpleNamespace
+
+  from xotorch_support_jetson_tpu.inference.batch_scheduler import GROUP_ROWS, BatchedServer
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, prefill_into_pages_many_sampled_inplace
@@ -588,19 +621,66 @@ def test_gdn_hybrid_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5
   print(f"decode.paged_batch olmo B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4  # the pool is donated: the state is updated where it lies
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9 and abs(mem.argument_size_in_bytes - 12.81e9) < 0.01e9
-  K, S = 8, 1024
-  rows = _rows(chip, K)
+  sched = SimpleNamespace(paged=True, prefill_chunk=2048, page_size=PS, pages_per_row=mp)  # what the rule reads of a server (XOT_TPU_PREFILL_CHUNK's default: the cell sets none)
+  sched._page_window = lambda end_pos: BatchedServer._page_window(sched, end_pos)
   monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend: head size 128 takes the kernel on the chip
+  S = sched.prefill_chunk
+  for table in (sched._page_window(S), mp):
+    K = BatchedServer._group_rows(sched, table * PS)
+    assert (K, table) in ((GROUP_ROWS, 32), (GROUP_ROWS // 2, 64))
+    rows = _rows(chip, K)
+    compiled, text = _compile(
+      prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool,
+      _sds(chip, (K, table), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
+    )  # fmt: skip
+    mem = compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3  # the flash kernel in each of the three attention layers' loops
+    print(f"prefill.pages_many_sampled olmo K={K} S={S} table={table}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+    # That it compiled is the fit: XLA:TPU refuses 8 x 1024 at a table of 64 from 1700 pages on at the parent ("Used 16.98G of 15.75G"; PERF.md §6, PR 44) and at this pool now.
+    # Its temporaries are not a sum to hold against the chip: they overlap the donated pool's buffers (arguments + temp is 16.3 GB for the eight first chunks).
+    assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4 and mem.argument_size_in_bytes < 13.0e9
+
+
+@pytest.mark.parametrize("K,S", [(2, 640), (8, 1024)], ids=["typical_group_2x640", "largest_group_8x1024"])
+@pytest.mark.parametrize("config", ["olmo-hybrid-7b-d12", "granite-4.0-h-micro-bf16", "ling-3.0-flash-ep4-d7"])
+def test_hybrid_prefill_group_reads_the_admitted_rows_state_where_it_lies(chip, monkeypatch, config, K, S):
+  """The three recurrent kinds' prefill program at their cells' pools (64 slots; ISSUE 48), for a typical group and the
+  largest one, with the page window the scheduler hands such a group (``_page_window``: 16 pages): the only instructions
+  that produce a tensor of an eighth of the ``ssm`` leaf or more out of the leaf are the ``xot.ssm/scatter`` fusions,
+  which write the rows' states back into the donated leaf in place — no ``slice``, ``copy``, ``gather`` of it. The
+  read of the K admitted rows is K ``dynamic-slice``s of one [H, P, N] row each (``models/decoder.py _state_rows``).
+  As a gather (``.at[layer, slot_rows].get``) XLA:TPU cut Olmo's WHOLE leaf in two first, once a Gated-DeltaNet layer —
+  ``mini-gather-slice`` f32[9,64,30,128,96] + [9,64,30,64,96], 1.27 GB read and written, 12.8 M of the layer body's
+  19.3 M estimated cycles, 1.2 GB of the program's 1.49 GB of temporaries at 2 x 640 (0.29 GB now) — because a face
+  192 x 96 is no whole number of lanes; granite's [64, 128] and Ling's [128, 128] already compiled to a row loop."""
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import prefill_into_pages_many_sampled_inplace
+
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, config)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend
+  assert pool["ssm"].shape[1] == 64 and pool["k"].shape[1] == 1537
+  rows, window = _rows(chip, K), 1 << (-(-S // PS) - 1).bit_length()
   compiled, text = _compile(
-    prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool,
-    _sds(chip, (K, mp), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
+    prefill_into_pages_many_sampled_inplace, params, cfg, Shard(config, 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (K, S), jnp.int32), pool,
+    _sds(chip, (K, window), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
   )  # fmt: skip
+  leaf = pool["ssm"]
+  of_leaf = f"f32[{','.join(map(str, leaf.shape))}]"
+  produced = _materialised(text)
+  types = {name: result for name, result, *_ in produced}
+  large = []
+  for name, result, op, operands, scope in produced:
+    dims = re.fullmatch(r"\w+\[([\d,]*)\]\S*", result)  # (a tuple — a loop, an asynchronous start — is what its done or its elements are)
+    if op in ("parameter", "get-tuple-element", "bitcast") or not dims or 8 * math.prod(int(d) for d in dims.group(1).split(",") if d) < leaf.size:
+      continue
+    if result.startswith(of_leaf) or any(of_leaf in types.get(operand, "") for operand in operands):
+      large.append((name, result.split("{")[0], op, scope[-40:]))
+  assert large and all(op == "fusion" and result == of_leaf and scope.endswith("/xot.ssm/scatter") for _, result, op, scope in large), large
   mem = compiled.memory_analysis()
-  assert text.count('custom_call_target="tpu_custom_call"') >= 3  # the flash kernel in each of the three attention layers' loops
-  print(f"prefill.pages_many_sampled olmo K=8 S=1024: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
-  # That it compiled is the fit: XLA:TPU refuses this program from 1700 pages on ("Used 16.98G of 15.75G"; PERF.md §6, PR 44).
-  # Its temporaries are not a sum to hold against the chip: they overlap the donated pool's buffers (arguments + temp is 17.4 GB here).
-  assert mem.alias_size_in_bytes >= 9 * 64 * 30 * 192 * 96 * 4 and mem.argument_size_in_bytes < 13.0e9
+  print(f"prefill.pages_many_sampled {config} K={K} S={S}: temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes} leaf-sized={[name for name, *_ in large]}")
+  assert mem.alias_size_in_bytes >= leaf.size * 4  # the pool is donated: the state is written where it lies
+  if config.startswith("olmo") and K == 2:
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
 
 
 def test_swa_gqa_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_settings_fit_v5e(chip, monkeypatch):

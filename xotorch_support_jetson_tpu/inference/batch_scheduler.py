@@ -1167,6 +1167,24 @@ class BatchedServer:
       mp_used *= 2
     return min(mp_used, self.pages_per_row)
 
+  def _group_rows(self, end_pos: int) -> int:
+    """Most rows of a prefill group that covers ``[0, end_pos)`` on a server
+    of more than ``GROUP_SLOTS_WHOLE`` slots: ``GROUP_ROWS`` while its page
+    window is no wider than that of one chunk from position 0, and half as
+    many for each doubling past it (a later chunk of long prompts). The
+    program gathers every row's window of every attention layer before the
+    first layer runs, so the group of ``GROUP_ROWS`` first chunks is the most
+    K/V any group holds beside its activations. (8 rows x 4096 tokens of
+    Olmo-Hybrid's 30 KV heads in three layers are 1.4 GB, twice what 8 first
+    chunks gather, and XLA:TPU refuses that program beside the cell's pool
+    by 52 MB; ISSUE 48.) A power of two, so the groups' programs stay those
+    of 1, 2, 4 and 8 rows."""
+    rows = GROUP_ROWS
+    if self.paged and self.prefill_chunk > 0:
+      while rows > 1 and rows * self._page_window(end_pos) > GROUP_ROWS * self._page_window(self.prefill_chunk):
+        rows //= 2
+    return rows
+
   def _free_slot(self, taken: frozenset | set = frozenset()) -> int | None:
     # Mid-chunked-prefill rows are protected by ``taken``: _admit_pending
     # swaps _prefilling out and seeds taken with those rows before any
@@ -1475,12 +1493,13 @@ class BatchedServer:
     a power of two, longest member), and a program first met in service
     stops every row for its compile (~10 s each at 64 slots): more rows are
     met only when a lump of callers ends together, once in minutes (PR 34).
-    It also bounds the activations (8 rows x ``XOT_TPU_PREFILL_CHUNK``).
+    It also bounds the activations (8 rows x ``XOT_TPU_PREFILL_CHUNK``) and,
+    by ``_group_rows``, the K/V windows the program gathers beside them.
     A server of up to 16 slots is never asked, so it groups as before."""
     groups: list[list[_Ready]] = []
     for r in sorted(ready, key=lambda x: x.pad_to, reverse=True):
       for g in groups:
-        whole = self.n_slots <= GROUP_SLOTS_WHOLE or len(g) < GROUP_ROWS
+        whole = self.n_slots <= GROUP_SLOTS_WHOLE or len(g) < self._group_rows(max(r.prefix_len, *(m.prefix_len for m in g)) + g[0].pad_to)
         if r.prefix_len + g[0].pad_to <= self.max_seq and whole:
           g.append(r)
           break

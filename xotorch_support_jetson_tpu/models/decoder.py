@@ -1060,6 +1060,19 @@ def _gdn_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bo
 _HYBRID_MLA_Q_BLOCK = 256
 
 
+def _state_rows(leaf, layer, slot_rows):
+  """``leaf[layer, slot_rows]`` of the pool's stacked ``ssm`` leaf [L, n_slots, H, P, N] → [K, H, P, N], one
+  ``dynamic_slice`` a row (K is static and small): each read touches its row's bytes where they lie. A slot past the
+  last is clamped to the last, as a gather's ``mode="clip"`` does. (The gather itself, at a face that is no whole number
+  of lanes — Olmo's [192, 96] —, XLA:TPU lowers by cutting the WHOLE leaf in two first: 1.7 GB copied a layer to read K
+  rows of 2.2 MB. The ``conv`` leaf's rows [K-1, C] are whole lanes in every kind and its gather compiles to a gather of
+  the rows; read by slices its rows reach the convolution in a layout that costs a copy of the padded sequence a layer.
+  AOT for a described v5e; PERF.md §6, PR 48.)"""
+  at = (0,) * (leaf.ndim - 2)
+  rows = [jax.lax.dynamic_slice(leaf, (layer, slot_rows[i], *at), (1, 1, *leaf.shape[2:]))[0] for i in range(slot_rows.shape[0])]
+  return jnp.concatenate(rows)
+
+
 def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params, slot_rows=None, fresh=None, seq_lens=None, adapter_ids=None):
   """The layers of a model whose layers differ in kind (``cfg.mixed_layers``) over a sequence, in the published
   order: the prefill of the paged programs, the slot-cache forward of a model without recurrent layers, and the
@@ -1091,7 +1104,7 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
       h, _, _ = over_sequence(h, lp, cfg, ssm0, jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), h.dtype), seq_lens)
       return h, carry
     with jax.named_scope("xot.ssm"):
-      ssm0 = jnp.where(fresh[:, None, None, None], 0.0, carry["ssm"].at[layer, slot_rows].get(mode="clip")).astype(jnp.float32)
+      ssm0 = jnp.where(fresh[:, None, None, None], 0.0, _state_rows(carry["ssm"], layer, slot_rows)).astype(jnp.float32)
       conv0 = jnp.where(fresh[:, None, None], 0, carry["conv"].at[layer, slot_rows].get(mode="clip"))
     h, ssm, conv = over_sequence(h, lp, cfg, ssm0, conv0, seq_lens)
     with jax.named_scope("xot.ssm"):
