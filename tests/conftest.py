@@ -11,6 +11,7 @@ by ``chip_smoke.py``, never by a test.
 import asyncio
 import inspect
 import os
+import tempfile
 
 import pytest
 
@@ -33,14 +34,63 @@ os.environ.setdefault("XOT_TPU_SPEC_NGRAM", "0")
 
 import jax  # noqa: E402
 
-# The persistent compile cache stays off in the test process: entries written
-# by tests/test_tpu_compile.py for a described (absent) chip cannot be read
-# back. Daemons that tests start get a cache directory of their own.
-jax.config.update("jax_enable_compilation_cache", False)
+# What a test costs is the programs it compiles (two thirds of a model kind's
+# module is XLA:CPU compiling), and modules build many of the same ones: the
+# suite's workers share one persistent compile cache under the system's
+# temporary directory (the driver gives a run a TMPDIR of its own, so its runs
+# start with the directory empty). A key is the program's own bytes, its compile
+# options and the compiler's version, so an entry is never stale; op metadata is
+# not in the key, so a module that compiles one program under two sets of scope
+# names, or for a described (absent) chip whose entries cannot be read back,
+# asks for ``no_persistent_compile_cache``. Daemons and subprocesses that tests
+# start get a cache directory of their own.
+jax.config.update("jax_compilation_cache_dir", os.path.join(tempfile.gettempdir(), "xot-test-compile-cache"))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)  # most of the suite's programs compile in under JAX's one second
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+pytest.register_assert_rewrite("served_kind")  # the served-kind battery's cases live there; its ``assert``s keep their messages
+
+
+# The order the suite's modules are handed to the driver's six workers in (``-n 6 --dist loadfile``: a module a worker
+# at a time, the next as a worker runs dry). Left alone, xdist hands out the modules of most tests first, so a module of
+# thirteen compiles of a cell's whole program (530 CPU-seconds) and the benchmark's rehearsals (a server and its callers
+# in processes of their own, 100-180 s each) start last, all at once, on cores they then fight over — a run ends when
+# they do, and a rehearsal that meets a busy machine finds no decode step in its capture. So: the long first (a run can
+# end no sooner than its longest chain, started last), and of the modules that start servers or compile for the TPU
+# with many threads at most two at a time. Made from a whole run's junit file (CPU-seconds a module, longest first, a
+# loud module passed over while two are running); a module not named keeps its place after these, none over 60 s.
+LONG_FIRST = (
+  "test_tpu_compile_cells", "test_moe", "test_hybrid_kda_moe_kind", "test_swa_gqa_moe", "test_hybrid_kda", "test_paged",
+  "test_hybrid_gdn_kind", "test_tpu_compile_smoke", "test_diffusion", "test_pp_batch", "test_hybrid_ssm", "test_spec_decode",
+  "test_paged_int4", "test_hybrid_gdn", "test_pp_lifecycle", "test_tpu_compile", "test_hybrid_ssm_kind", "test_mixed_tick",
+  "test_qkv_barrier", "test_spec_ngram", "test_pp_serving", "test_spec_batch", "test_named_scopes", "test_image_api",
+  "test_batched", "test_add_cell", "test_paged_pool_inplace", "test_ring_training", "test_sp_paged", "test_sp_serving",
+  "test_experts_touched", "test_parallel", "test_hf_golden", "test_ssm_state_step", "test_kv_tier", "test_kv_quant",
+)  # fmt: skip
 
 
 def pytest_configure(config):
   config.addinivalue_line("markers", "asyncio: run test in an asyncio event loop")
+  config.option.loadscopereorder = False  # xdist's own order is by count of tests; ``LONG_FIRST`` is by what they cost
+
+
+def pytest_collection_modifyitems(items):
+  rank = {name: i for i, name in enumerate(LONG_FIRST)}
+  items.sort(key=lambda item: rank.get(item.path.stem, len(rank)))  # stable: the rest as collected
+
+
+@pytest.fixture(scope="module")
+def no_persistent_compile_cache():
+  """The persistent compile cache off for one module, and as it was after it."""
+  from jax.experimental.compilation_cache import compilation_cache
+
+  was = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  yield
+  jax.config.update("jax_enable_compilation_cache", was)
+  compilation_cache.reset_cache()
 
 
 @pytest.fixture(autouse=True)

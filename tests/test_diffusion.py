@@ -48,6 +48,10 @@ from xotorch_support_jetson_tpu.inference.diffusion_pipeline import DiffusionPip
 
 
 CFG = tiny_diffusion_config()
+# One program a call where the functions alone dispatch (and compile) an op at a time: what these cases cost is the
+# programs they compile. Same keys, same values.
+init_diffusion_params = jax.jit(init_diffusion_params, static_argnums=1)
+unet_apply = jax.jit(unet_apply, static_argnums=1)
 
 
 @pytest.fixture(scope="module")

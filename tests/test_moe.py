@@ -104,6 +104,11 @@ def test_moe_ffn_matches_per_token_loop():
   np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-4, atol=1e-5)
 
 
+# ``shard_forward`` as one program a call: eager, it dispatches (and compiles) an op at a time. For the decoder cases
+# below, none of which patches what a trace reads; the grouped form's cases further down call the function itself.
+forward = jax.jit(shard_forward, static_argnums=(1, 2))
+
+
 def test_moe_decoder_forward_and_decode():
   """Dense-prefix + MoE stacks: prefill-with-cache then one decode step."""
   cfg = _moe_cfg(shared_expert_dim=32, shared_expert_gate=True)
@@ -115,11 +120,11 @@ def test_moe_decoder_forward_and_decode():
   tokens = jnp.arange(B * S, dtype=jnp.int32).reshape(B, S) % cfg.vocab_size
   positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
   cache = init_kv_cache(cfg, shard.n_shard_layers, B, 16)
-  logits, cache = shard_forward(params, cfg, shard, tokens, positions, cache)
+  logits, cache = forward(params, cfg, shard, tokens, positions, cache)
   assert logits.shape == (B, S, cfg.vocab_size)
 
   nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
-  logits2, _ = shard_forward(params, cfg, shard, nxt, jnp.full((B, 1), S, jnp.int32), cache)
+  logits2, _ = forward(params, cfg, shard, nxt, jnp.full((B, 1), S, jnp.int32), cache)
   assert logits2.shape == (B, 1, cfg.vocab_size)
   assert np.all(np.isfinite(np.asarray(logits2, dtype=np.float32)))
 
@@ -134,15 +139,15 @@ def test_moe_sharding_equivalence_across_boundary():
   tokens = jnp.arange(S, dtype=jnp.int32)[None, :]
   positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-  full_logits, _ = shard_forward(params, cfg, full, tokens, positions, None)
+  full_logits, _ = forward(params, cfg, full, tokens, positions, None)
 
   for split in (1, 2):  # layer boundary: at the dense/MoE edge and mid-MoE
     a = Shard("moe-test", 0, split - 1, cfg.n_layers)
     b = Shard("moe-test", split, cfg.n_layers - 1, cfg.n_layers)
     pa = slice_shard_params(params, cfg, full, a)
     pb = slice_shard_params(params, cfg, full, b)
-    hidden, _ = shard_forward(pa, cfg, a, tokens, positions, None)
-    logits, _ = shard_forward(pb, cfg, b, hidden, positions, None)
+    hidden, _ = forward(pa, cfg, a, tokens, positions, None)
+    logits, _ = forward(pb, cfg, b, hidden, positions, None)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(full_logits), rtol=2e-5, atol=2e-5)
 
 
@@ -153,7 +158,7 @@ def test_moe_sigmoid_router_decoder():
   assert "layers" not in params and "router_bias" in params["moe_layers"]
   tokens = jnp.asarray([[1, 2, 3]], dtype=jnp.int32)
   positions = jnp.asarray([[0, 1, 2]], dtype=jnp.int32)
-  logits, _ = shard_forward(params, cfg, shard, tokens, positions, None)
+  logits, _ = forward(params, cfg, shard, tokens, positions, None)
   assert np.all(np.isfinite(np.asarray(logits, dtype=np.float32)))
 
 
@@ -170,8 +175,8 @@ def test_moe_quantized_forward_close_to_fp():
 
   tokens = jnp.asarray([[1, 2, 3, 4]], dtype=jnp.int32)
   positions = jnp.asarray([[0, 1, 2, 3]], dtype=jnp.int32)
-  ref, _ = shard_forward(params, cfg, shard, tokens, positions, None)
-  out, _ = shard_forward(qp, cfg, shard, tokens, positions, None)
+  ref, _ = forward(params, cfg, shard, tokens, positions, None)
+  out, _ = forward(qp, cfg, shard, tokens, positions, None)
   # int8 weight error is small at tiny scale; just require close correlation
   ref, out = np.asarray(ref, np.float32).ravel(), np.asarray(out, np.float32).ravel()
   corr = np.corrcoef(ref, out)[0, 1]
@@ -237,11 +242,11 @@ def test_mla_decode_cache_matches_full_forward():
   S = 6
   tokens = jnp.arange(1, S + 2, dtype=jnp.int32)[None, :]  # S+1 tokens
   positions = jnp.broadcast_to(jnp.arange(S + 1, dtype=jnp.int32), (1, S + 1))
-  full_logits, _ = shard_forward(params, cfg, shard, tokens, positions, None)
+  full_logits, _ = forward(params, cfg, shard, tokens, positions, None)
 
   cache = init_kv_cache(cfg, shard.n_shard_layers, 1, 16)
-  _, cache = shard_forward(params, cfg, shard, tokens[:, :S], positions[:, :S], cache)
-  step_logits, _ = shard_forward(params, cfg, shard, tokens[:, S:], positions[:, S:], cache)
+  _, cache = forward(params, cfg, shard, tokens[:, :S], positions[:, :S], cache)
+  step_logits, _ = forward(params, cfg, shard, tokens[:, S:], positions[:, S:], cache)
   np.testing.assert_allclose(np.asarray(step_logits[:, 0]), np.asarray(full_logits[:, S]), rtol=2e-4, atol=2e-4)
 
 
@@ -259,20 +264,20 @@ def test_mla_lora_adapters_are_live():
 
   tokens = jnp.asarray([[1, 2, 3, 4]], dtype=jnp.int32)
   positions = jnp.asarray([[0, 1, 2, 3]], dtype=jnp.int32)
-  base, _ = shard_forward(params, cfg, shard, tokens, positions, None)
-  zeroed, _ = shard_forward(lp, cfg, shard, tokens, positions, None)
+  base, _ = forward(params, cfg, shard, tokens, positions, None)
+  zeroed, _ = forward(lp, cfg, shard, tokens, positions, None)
   np.testing.assert_allclose(np.asarray(zeroed), np.asarray(base), rtol=1e-6)  # B=0 ⇒ no-op
 
   # Non-zero B must change the output — proves the decoder actually applies
   # the adapters on the MLA path (a silent no-op would pass the line above).
   lp["layers"]["wq_b_lora_b"] = jnp.ones_like(lp["layers"]["wq_b_lora_b"]) * 0.05
-  bumped, _ = shard_forward(lp, cfg, shard, tokens, positions, None)
+  bumped, _ = forward(lp, cfg, shard, tokens, positions, None)
   assert not np.allclose(np.asarray(bumped), np.asarray(base))
 
   # merge_lora folds the delta and drops the adapter leaves.
   merged = merge_lora(lp, rank=4)
   assert "wq_b_lora_a" not in merged["layers"]
-  folded, _ = shard_forward(merged, cfg, shard, tokens, positions, None)
+  folded, _ = forward(merged, cfg, shard, tokens, positions, None)
   np.testing.assert_allclose(np.asarray(folded), np.asarray(bumped), rtol=2e-4, atol=2e-5)
 
 

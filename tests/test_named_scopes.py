@@ -8,6 +8,7 @@ compiled program, and the optimised program is the same with and without them.
 """
 
 import contextlib
+import functools
 import re
 
 import jax
@@ -19,6 +20,10 @@ from xotorch_support_jetson_tpu.models.config import tiny_test_config
 from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, full_model_params
 from xotorch_support_jetson_tpu.models.quantize import quantize_params
 from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+# One program is compiled here under two sets of scope names, and op metadata is no part of the suite's
+# persistent-cache key (tests/conftest.py): a hit would hand the second compile the first one's names.
+pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
 
 PS = 16
 COMMON = {"xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.dequant", "xot.head", "xot.sample"}
@@ -51,6 +56,12 @@ def _lowered(flavor: str):
   return _fused_paged_batch_decode_impl.xot_jitted.lower(*args)
 
 
+@functools.cache
+def _compiled(flavor: str) -> str:
+  """The optimised text of ``_lowered(flavor)`` with its scopes: both tests read it, one compile."""
+  return _lowered(flavor).compile().as_text()
+
+
 def _scopes_in(text: str) -> set[str]:
   return set(re.findall(r"xot\.[a-z_]+", " ".join(re.findall(r'op_name="([^"]*)"', text))))
 
@@ -80,7 +91,7 @@ def _program(text: str) -> str:
 
 @pytest.mark.parametrize("flavor", sorted(CONFIGS))
 def test_every_scope_reaches_the_compiled_decode_program(flavor):
-  text = _lowered(flavor).compile().as_text()
+  text = _compiled(flavor)
   assert _scopes_in(text) >= CONFIGS[flavor][2], sorted(CONFIGS[flavor][2] - _scopes_in(text))
   # nested: a dequantisation names its component first, so a reader can split it from the einsum beside it
   assert re.search(r'op_name="[^"]*xot\.(attn_proj|ffn|moe_experts|moe_shared|head|attn)/[^"]*xot\.dequant', text)
@@ -88,7 +99,7 @@ def test_every_scope_reaches_the_compiled_decode_program(flavor):
 
 @pytest.mark.parametrize("flavor", sorted(CONFIGS))
 def test_scopes_do_not_change_the_optimised_program(flavor, monkeypatch):
-  with_scopes = _lowered(flavor).compile().as_text()
+  with_scopes = _compiled(flavor)
   monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
   jax.clear_caches()  # the traced jaxpr carries the name stack
   try:

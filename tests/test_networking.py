@@ -170,9 +170,13 @@ async def test_two_node_cluster_scope_timeline_with_skew():
     # pre-skew samples through the periodic clock-sync pass).
     clock_sync.forget("node0")
     clock_sync.forget("node1")
-    await nodes[0]._clock_sync_pass()
+    # One echo's offset is off by up to rtt / 2, and the marks ordered below are 1-2 ms apart: an echo taken while
+    # the suite's other workers hold the cores flipped them (ROADMAP D18). The estimate is an EWMA (alpha 0.2), so it
+    # is given the echoes an EWMA needs: after 16 the first one's share is 0.8^15, 3.5 %, and the rest average out.
+    for _ in range(16):
+      await asyncio.gather(*(peer.health_check() for peer in nodes[0].peers))
     est = clock_sync.estimate("node1")
-    assert est is not None
+    assert est is not None and est.samples >= 16
     assert SKEW_MS - 10 < est.offset_ns / 1e6 < SKEW_MS + 10  # correctly signed: node1 AHEAD
 
     shard = build_base_shard("dummy", "DummyInferenceEngine")

@@ -8,101 +8,87 @@ computed densely, nothing of the program, its rope tables included).
 
 The float32 cases run at ``highest`` matmul precision, so the program and the reference differ by the order of their
 sums alone. Logits have a spread of ~1; tolerances are absolute.
+
+The cases every served kind has — prefill, decode, padding, chunking, slot reuse, bfloat16, the scheduler, the scopes —
+are ``tests/served_kind.py``'s battery, taken in below under the names they have always had here; this kind runs its
+prefill-then-decode and its two-chunk cases at prompts and cuts on both sides of the window.
 """
 
-import asyncio
 import json
-import re
-import sys
 from dataclasses import replace
-from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_kind import SLOTS, Kind, battery, rehearsal_of
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmark"))
-
-import arch_swa_gqa_moe as kind  # noqa: E402
+import arch_swa_gqa_moe  # noqa: E402 — served_kind puts benchmark/ on the path
 import common  # noqa: E402
 import weights  # noqa: E402
 
-from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer  # noqa: E402
-from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine  # noqa: E402
-from xotorch_support_jetson_tpu.inference.shard import Shard  # noqa: E402
 from xotorch_support_jetson_tpu.models import decoder as dec  # noqa: E402
 from xotorch_support_jetson_tpu.models.config import AttnKind, YarnScaling, config_from_hf  # noqa: E402
 from xotorch_support_jetson_tpu.ops.attention import gqa_attention  # noqa: E402
-from xotorch_support_jetson_tpu.ops.paged import init_paged_pool, paged_decode_attention  # noqa: E402
+from xotorch_support_jetson_tpu.ops.paged import paged_decode_attention  # noqa: E402
 from xotorch_support_jetson_tpu.ops.pallas_attention import flash_attention_prefill  # noqa: E402
 from xotorch_support_jetson_tpu.ops.rope import rope_attention_factor, rope_inv_freq  # noqa: E402
+from xotorch_support_jetson_tpu.utils.metrics import metrics  # noqa: E402
 
-FILE = common.load_config("laguna-xs.2-d5")
-HF = {**FILE, **kind.REHEARSE_WIDTHS, "torch_dtype": "float32", "max_position_embeddings": 256}
-CFG = config_from_hf(HF)
-SHARD = Shard("laguna", 0, CFG.n_layers - 1, CFG.n_layers)
+FILE, HF = rehearsal_of("laguna-xs.2-d5", arch_swa_gqa_moe, nested=True)
 BF16_PARAMS = weights.build_params(HF, 11)  # the benchmark's own seeded weights, bfloat16 leaves
-PARAMS = jax.tree.map(lambda x: x.astype(jnp.float32), BF16_PARAMS)
 W = 8  # the rehearsal's window
-PS, SLOTS, MP = 4, 4, 16  # pages of 4: a window of 8 spans two or three of them, so its first page is crossed inside it
-RNG = np.random.default_rng(0)
-TOKENS = RNG.integers(3, CFG.vocab_size, size=64)
-# The program against the reference, both float32 at "highest": orders of summation only. Measured 6e-6 at the worst
-# entry of logits of spread 1 (prefill, 16 decode steps and the cache-less forward alike).
-TOL = 5e-5
-
-
-@pytest.fixture(autouse=True)
-def highest_precision():
-  with jax.default_matmul_precision("highest"):
-    yield
-
-
-def reference(tokens, params=PARAMS, **probe) -> np.ndarray:
-  return np.asarray(kind.reference_forward(params, HF, jnp.asarray(tokens), **probe))
-
-
-def fresh_pool(cfg=CFG):
-  return init_paged_pool(cfg, cfg.n_layers, 1 + SLOTS * MP, PS)
-
-
-def tables() -> np.ndarray:
-  return np.arange(1, 1 + SLOTS * MP, dtype=np.int32).reshape(SLOTS, MP)
-
-
-def prefill(pool, prompts: dict, prefix: dict | None = None, pad_to: int | None = None, pad_rows: int = 0, params=PARAMS, cfg=CFG):
-  """Prefill ``{slot: tokens}`` as one group (each row from ``prefix[slot]`` on) → (last logits [K, V], pool)."""
-  rows = sorted(prompts)
-  prefix = prefix or {}
-  K = len(rows) + pad_rows
-  S = pad_to or max(len(prompts[r]) - prefix.get(r, 0) for r in rows)
-  tok, bts = np.zeros((K, S), np.int32), np.zeros((K, MP), np.int32)
-  prefix_lens, prompt_lens = np.zeros((K,), np.int32), np.ones((K,), np.int32)
-  for i, r in enumerate(rows):
-    start = prefix.get(r, 0)
-    tok[i, : len(prompts[r]) - start] = prompts[r][start:]
-    bts[i], prefix_lens[i], prompt_lens[i] = tables()[r], start, len(prompts[r])
-  return dec.prefill_into_pages_many(params, cfg, SHARD, jnp.asarray(tok), pool, jnp.asarray(bts), jnp.asarray(prefix_lens), jnp.asarray(prompt_lens), PS)
-
-
-@partial(jax.jit, static_argnums=0)
-def _decode_forward(cfg, params, tok, pos, pool, active):
-  return dec.paged_decode_forward(params, cfg, SHARD, tok, pos[:, None], pool, jnp.asarray(tables()), PS, False, active=active)[:2]
-
-
-def decode_step(pool, tokens: dict, positions: dict, params=PARAMS, cfg=CFG):
-  """One teacher-forced decode step of the rows named → (logits [SLOTS, V], pool)."""
-  tok, pos, active = np.zeros((SLOTS, 1), np.int32), np.zeros((SLOTS,), np.int32), np.zeros((SLOTS,), bool)
-  for r, t in tokens.items():
-    tok[r, 0], pos[r], active[r] = t, positions[r], True
-  logits, pool = _decode_forward(cfg, params, jnp.asarray(tok), jnp.asarray(pos), pool, jnp.asarray(active))
-  return np.asarray(logits[:, 0]), pool
-
-
-def pages_of(pool, slot: int):
-  return np.asarray(pool["k"][:, tables()[slot]]), np.asarray(pool["v"][:, tables()[slot]])
+KIND = Kind(
+  name="laguna", arch=arch_swa_gqa_moe, hf=HF, params=jax.tree.map(lambda x: x.astype(jnp.float32), BF16_PARAMS), bf16_params=BF16_PARAMS,
+  # The program against the reference, both float32 at "highest": orders of summation only. Measured 6e-6 at the worst
+  # entry of logits of spread 1 (prefill, 16 decode steps and the cache-less forward alike).
+  tol=5e-5,
+  # bfloat16 weights, activations and pages as served, gate and router float32, a prompt of 20 tokens (past the window):
+  # each of the 4 layers' two blocks rounds its increment and the stream — measured 0.025 in the mean and 0.74 at the
+  # worst entry (one position's, thirty times the mean: 16 experts of width 32 leave a token's fourth and fifth router
+  # scores closer than the published widths' topic router does). The mean is held to three times its reading, the worst
+  # entry under half of the weakest wrong architecture's (a dropped layer: 0.34 / 2.1).
+  bf16=(0.075, 1.0),
+  families=("laguna", "laguna"),
+  pool={"k": (4, 1 + SLOTS * 16, 2, 4, 16), "v": (4, 1 + SLOTS * 16, 2, 4, 16)},  # one page leaf for both kinds of layer, in model order
+  # the gate's projection and product and the q/k norms under ``xot.attn_proj``, both kinds' cores under ``xot.attn``,
+  # router, experts and shared expert under the ``xot.moe_*`` scopes there are; the gate's softplus, once a run of
+  # layers, lies under ``xot.attn_proj``. (That the windowed call is named ``paged_decode_window`` in a TPU's program
+  # is ``tests/test_tpu_compile_cells.py``'s to show: a CPU lowers no Mosaic call.)
+  scopes=frozenset({"xot.moe_router", "xot.moe_experts", "xot.moe_shared"}),
+  ops_under=((r"call @softplus", 3, 3, r"xot\.attn_proj"),),
+  # each kind given the other's rope, a rope over the whole head or without YaRN in the full layers, 6 heads everywhere,
+  # no gate or a sigmoid's, no q/k norm, a softmax router, gates not normalised or not scaled, the shared or a routed
+  # expert lost, a layer dropped, float8 operands; and of ``long_probes`` (64 positions under a window of 8 are past it)
+  # no window, a window on every layer, a window twice as wide: a thousand tolerances or more
+  probe_floor=lambda name: 1000,
+  cases={
+    # fewer tokens than the window (8), one short of it, the window exactly, one past it, past it and a page boundary inside it (pages of 4), far past it
+    "prompt": [3, 7, 8, 9, 13, 30],
+    "cut": [5, 12, 16],  # the cut under the window, past it inside a page, on a page's edge
+    "key,value,named": [
+      ("attention_bias", True, "attention_bias"), ("moe_apply_router_weight_on_input", True, "moe_apply_router_weight_on_input"), ("gating", "elementwise", "gating"),
+      ("rope_parameters", {**FILE["rope_parameters"], "sliding_attention": {"rope_type": "longrope", "rope_theta": 1e4}}, "rope_parameters.sliding_attention"),
+      ("rope_parameters", {"full_attention": FILE["rope_parameters"]["full_attention"]}, "rope_parameters.sliding_attention"),
+      ("layer_types", ["full_attention", "chunked_attention", "sliding_attention", "full_attention"], "layer_types"), ("layer_types", ["full_attention"] * 3, "layer_types"),
+      ("num_attention_heads_per_layer", [6, 8, 8], "num_attention_heads_per_layer"), ("num_attention_heads_per_layer", [6, 8, 4, 6], "num_attention_heads_per_layer"),
+      ("num_attention_heads_per_layer", [6, 7, 7, 6], "num_attention_heads_per_layer"), ("num_key_value_heads_per_layer", [2, 2, 1, 2], "KV head count"),
+      ("mlp_layer_types", ["dense", "sparse", "dense", "sparse"], "mlp_layer_types"), ("mlp_layer_types", ["dense", "sparse"], "mlp_layer_types"), ("sliding_window", 0, "sliding_window"),
+    ],
+  },  # fmt: skip
+  names={
+    "test_prefill_then_decode_through_the_pool_equals_the_reference": "test_prefill_then_decode_through_the_pages_equals_the_reference_on_both_sides_of_the_window",
+    "test_a_padded_group_leaves_each_row_what_its_unpadded_run_does": "test_a_padded_group_leaves_each_row_the_pages_of_its_unpadded_run",
+    "test_a_decode_chunk_leaves_an_inactive_rows_cache_bit_for_bit": "test_a_decode_chunk_leaves_an_inactive_rows_pages_bit_for_bit",
+  },
+  # pages of 4: a window of 8 spans two or three of them, so its first page is crossed inside it; 16 of them a row
+  page_size=4, pages_per_row=16, n_tokens=64, pad=32, prompt=30, decode_steps=20, cut=16, chunked=27, tenants=(20, 6), bf16_prompt=20,
+  scheduler_prompts=((0, 29), (30, 45), (50, 55)),  # two requests past the window and one under it
+)  # fmt: skip
+CFG, PARAMS, SHARD, TOKENS, TOL, PS = KIND.cfg, KIND.params, KIND.shard, KIND.tokens, KIND.tol, KIND.page_size
+prefill, fresh_pool, reference, tables = KIND.prefill, KIND.fresh_pool, KIND.reference, lambda: KIND.tables
+globals().update(battery(KIND))
 
 
 # ------------------------------------------------------------ the configuration
@@ -146,36 +132,8 @@ def test_the_flat_spelling_of_the_ropes_is_the_nested_one():
   assert nested.layer_attn == flat.layer_attn == common.model_config(FILE).layer_attn and len(set(nested.layer_attn)) == 2
   assert CFG.layer_attn[0].n_heads == 6 and CFG.layer_attn[1] == AttnKind("window", 8, W, 10000.0, None, 1.0, True) and CFG.attn_windows == (0, W, W, 0)
   assert {name: next(iter(st.values())).shape[0] for name, st in PARAMS.items() if isinstance(st, dict)} == {"layers": 1, "window_moe_layers": 2, "moe_layers": 1}
-  assert jax.tree.map(lambda x: x.shape, dec.full_model_params(jax.random.PRNGKey(0), CFG)[0]) == jax.tree.map(lambda x: x.shape, PARAMS)  # the benchmark's maker and the program's agree leaf for leaf
+  assert jax.tree.map(lambda x: x.shape, jax.eval_shape(lambda: dec.full_model_params(jax.random.PRNGKey(0), CFG)[0])) == jax.tree.map(lambda x: x.shape, PARAMS)  # the benchmark's maker and the program's agree leaf for leaf (shapes alone: nothing is drawn)
   assert PARAMS["layers"]["wq"].shape == (1, 64, 6 * 16) and PARAMS["window_moe_layers"]["wq"].shape == (2, 64, 8 * 16) and PARAMS["window_moe_layers"]["w_og"].shape == (2, 64, 8) and PARAMS["moe_layers"]["q_norm"].shape == (1, 16)
-
-
-@pytest.mark.parametrize("key,value,named", [
-  ("attention_bias", True, "attention_bias"), ("moe_apply_router_weight_on_input", True, "moe_apply_router_weight_on_input"), ("gating", "elementwise", "gating"),
-  ("rope_parameters", {**FILE["rope_parameters"], "sliding_attention": {"rope_type": "longrope", "rope_theta": 1e4}}, "rope_parameters.sliding_attention"),
-  ("rope_parameters", {"full_attention": FILE["rope_parameters"]["full_attention"]}, "rope_parameters.sliding_attention"),
-  ("layer_types", ["full_attention", "chunked_attention", "sliding_attention", "full_attention"], "layer_types"), ("layer_types", ["full_attention"] * 3, "layer_types"),
-  ("num_attention_heads_per_layer", [6, 8, 8], "num_attention_heads_per_layer"), ("num_attention_heads_per_layer", [6, 8, 4, 6], "num_attention_heads_per_layer"),
-  ("num_attention_heads_per_layer", [6, 7, 7, 6], "num_attention_heads_per_layer"), ("num_key_value_heads_per_layer", [2, 2, 1, 2], "KV head count"),
-  ("mlp_layer_types", ["dense", "sparse", "dense", "sparse"], "mlp_layer_types"), ("mlp_layer_types", ["dense", "sparse"], "mlp_layer_types"), ("sliding_window", 0, "sliding_window"),
-])  # fmt: skip
-def test_config_from_hf_refuses_what_is_not_implemented_by_name(key, value, named):
-  with pytest.raises(ValueError, match=re.escape(named)):
-    config_from_hf({**HF, key: value})
-
-
-def test_a_checkpoint_of_the_family_is_refused_by_name(tmp_path):
-  """No safetensors name map exists for the family: a checkpoint is refused by name, loader and exporter alike."""
-  from xotorch_support_jetson_tpu.models.hf_export import export_hf_checkpoint
-  from xotorch_support_jetson_tpu.models.loader import load_shard_weights
-
-  with pytest.raises(NotImplementedError, match="laguna"):
-    load_shard_weights(tmp_path, CFG, SHARD)
-  with pytest.raises(NotImplementedError, match="laguna"):
-    export_hf_checkpoint(tmp_path / "out", CFG, PARAMS)
-  with pytest.raises(ValueError, match="laguna"):  # MODEL_FAMILIES' error lists the new family
-    config_from_hf({"model_type": "rwkv7"})
-
 
 def test_gemma2s_even_layers_are_a_value_of_the_per_layer_field():
   """gemma2's rule — even layers have the window — is read off ``layer_attn`` like any other; its kinds differ in the
@@ -201,7 +159,7 @@ def test_each_kinds_rope_table_is_the_references(hf):
   tables_ = rope_inv_freq(cfg)
   assert set(tables_) == set(cfg.layer_attn) and len(tables_) == 2
   for k, table in tables_.items():
-    inv, factor, rot = kind.rope_table(hf, k.name)
+    inv, factor, rot = arch_swa_gqa_moe.rope_table(hf, k.name)
     assert rot == int(cfg.head_dim * k.partial_rotary_factor) == 2 * table.shape[0] and factor == rope_attention_factor(k)
     np.testing.assert_allclose(np.asarray(table), np.asarray(inv, np.float32), rtol=2e-6, atol=0)
   full, window = (rope_inv_freq(cfg)[cfg.layer_attn[i]] for i in (0, 1))
@@ -278,87 +236,8 @@ def test_the_flash_kernel_with_a_window_equals_the_masked_softmax(hq, window):
 # ------------------------------------------------------------ pages on both sides of the window
 
 
-def test_the_cacheless_forward_equals_the_reference():
-  got, _ = dec.shard_forward(PARAMS, CFG, SHARD, jnp.asarray(TOKENS)[None], jnp.arange(len(TOKENS))[None])
-  np.testing.assert_allclose(np.asarray(got[0]), reference(TOKENS), atol=TOL, rtol=0)
-
-
-def test_every_named_probe_moves_the_reference_past_the_tolerance():
-  """Each wrong reference of ``probes`` and of ``long_probes`` (64 positions under a window of 8 are past it) lies a
-  thousand tolerances or more from the sound one: each kind given the other's rope, a rope over the whole head or
-  without YaRN in the full layers, 6 heads everywhere, no gate or a sigmoid's, no q/k norm, a softmax router, gates
-  not normalised or not scaled, the shared or a routed expert lost, a layer dropped, float8 operands; no window, a
-  window on every layer, a window twice as wide."""
-  sound = reference(TOKENS)
-  for name, probe in {**kind.probes(HF), **kind.long_probes(HF)}.items():
-    moved = float(np.abs(reference(TOKENS, **probe) - sound).max())
-    assert moved > 1000 * TOL, (name, moved)
-  assert set(kind.long_probes(HF)) == {"window_off", "window_on_full_layers", "window_1024"} and not set(kind.long_probes(HF)) & set(kind.probes(HF))
-
-
-@pytest.mark.parametrize("prompt", [3, 7, 8, 9, 13, 30])
-def test_prefill_then_decode_through_the_pages_equals_the_reference_on_both_sides_of_the_window(prompt):
-  """float32: ``prompt`` tokens prefilled into slot 2 beside three padding rows — fewer than the window (8), one short
-  of it, the window exactly, one past it, past it and a page boundary inside it (pages of 4), far past it — then 20
-  decode steps, one token each, through the two window layers' and the two full layers' pages: every step's LOGITS are
-  the reference's full forward at that position, to the order of the sums, as each row crosses the window."""
-  want = reference(TOKENS[: prompt + 20])
-  last, pool = prefill(fresh_pool(), {2: TOKENS[:prompt]}, pad_to=32, pad_rows=3)
-  assert pool["k"].shape == (4, 1 + SLOTS * MP, 2, PS, 16) and set(pool) == {"k", "v"}  # one page leaf for both kinds, in model order
-  np.testing.assert_allclose(np.asarray(last[0]), want[prompt - 1], atol=TOL, rtol=0)
-  for other in (0, 1, 3):  # nothing was written for a padding row, nor for a slot no request held
-    assert not pages_of(pool, other)[0].any()
-  for t in range(prompt, prompt + 20):
-    logits, pool = decode_step(pool, {2: TOKENS[t]}, {2: t})
-    np.testing.assert_allclose(logits[2], want[t], atol=TOL, rtol=0, err_msg=f"decode step at position {t}")
-
-
-def test_the_bfloat16_path_stays_within_bfloat16s_rounding_of_the_reference():
-  """bfloat16 weights, activations and pages as served, gate and router float32: prefill (20 tokens, past the window) and
-  34 decode steps against the float32 reference on the same bfloat16 weights, the logits (spread 1) of all 35
-  positions. bfloat16 keeps 7 bits of mantissa: each of the 4 layers' two blocks rounds its increment and the stream —
-  measured 0.025 in the mean and 0.74 at the worst entry (one position's, thirty times the mean: 16 experts of width 32
-  leave a token's fourth and fifth router scores closer than the published widths' topic router does). The mean is
-  held to three times its reading, the worst entry under half of the weakest wrong architecture's (a dropped layer:
-  0.34 / 2.1)."""
-  cfg = replace(CFG, dtype=jnp.bfloat16)
-  want = reference(TOKENS[:54], params=jax.tree.map(lambda x: x.astype(jnp.float32), BF16_PARAMS))
-  dropped = np.abs(reference(TOKENS[:54], drop_layer=3) - want)[19:]
-  last, pool = prefill(fresh_pool(cfg), {1: TOKENS[:20]}, pad_to=32, params=BF16_PARAMS, cfg=cfg)
-  assert pool["k"].dtype == jnp.bfloat16
-  off = [np.abs(np.asarray(last[0], np.float32) - want[19])]
-  for t in range(20, 54):
-    logits, pool = decode_step(pool, {1: TOKENS[t]}, {1: t}, params=BF16_PARAMS, cfg=cfg)
-    off.append(np.abs(logits[1].astype(np.float32) - want[t]))
-  mean, worst = float(np.mean(off)), float(np.max(off))
-  assert mean < 0.075 < 0.5 * float(dropped.mean()) and worst < 1.0 < 0.5 * float(dropped.max()), (mean, worst, float(dropped.mean()), float(dropped.max()))
-
-
-def test_a_padded_group_leaves_each_row_the_pages_of_its_unpadded_run():
-  """Rows of 30, 13 and 2 tokens as one group padded to 32: each row's pages and last logits are what the row's own
-  prefill gives alone (its experts see its own tokens, its window its own positions)."""
-  prompts = {0: TOKENS[:30], 1: TOKENS[10:23], 3: TOKENS[40:42]}
-  logits, grouped = prefill(fresh_pool(), prompts, pad_to=32, pad_rows=1)
-  for i, (slot, toks) in enumerate(prompts.items()):
-    solo_logits, solo = prefill(fresh_pool(), {slot: toks}, pad_to=None if slot == 1 else 32)
-    np.testing.assert_allclose(np.asarray(logits[i]), np.asarray(solo_logits[0]), atol=TOL, rtol=0)
-    n = -(-len(toks) // PS)
-    for got, want in zip(pages_of(grouped, slot), pages_of(solo, slot)):
-      np.testing.assert_allclose(got[:, : n - 1], want[:, : n - 1], atol=TOL, rtol=0, err_msg=f"slot {slot}")
-
-
-@pytest.mark.parametrize("cut", [5, 12, 16])
-def test_a_prompt_prefilled_in_two_chunks_equals_one(cut):
-  """Positions [0, cut) then [cut, 27): the second call's window layers look back over the first call's pages — the
-  cut under the window, past it inside a page, on a page's edge."""
-  toks = TOKENS[:27]
-  whole_logits, whole = prefill(fresh_pool(), {1: toks}, pad_to=32)
-  _, pool = prefill(fresh_pool(), {1: toks[:cut]}, pad_to=16)
-  cut_logits, chunked = prefill(pool, {1: toks}, prefix={1: cut}, pad_to=32)
-  np.testing.assert_allclose(np.asarray(cut_logits), np.asarray(whole_logits), atol=TOL, rtol=0)
-  np.testing.assert_allclose(np.asarray(cut_logits[0]), reference(toks)[-1], atol=TOL, rtol=0)
-  for got, want in zip(pages_of(chunked, 1), pages_of(whole, 1)):
-    np.testing.assert_allclose(got[:, :6], want[:, :6], atol=TOL, rtol=0)
+def test_the_long_probes_are_the_windows_three_and_none_of_the_others():
+  assert set(arch_swa_gqa_moe.long_probes(HF)) == {"window_off", "window_on_full_layers", "window_1024"} and not set(arch_swa_gqa_moe.long_probes(HF)) & set(arch_swa_gqa_moe.probes(HF))
 
 
 def test_a_mixed_ticks_slice_and_its_decode_half_equal_the_two_programs_apart():
@@ -385,36 +264,6 @@ def test_a_mixed_ticks_slice_and_its_decode_half_equal_the_two_programs_apart():
   np.testing.assert_allclose(np.asarray(last[0]), reference(toks)[-1], atol=TOL, rtol=0)
 
 
-def test_a_reused_slot_gives_its_second_tenant_the_solo_answer():
-  """Slot 2 serves one request past the window (prefill + decode steps), then another, shorter than the window, from
-  position 0: the second's window layers never see its predecessor's pages, and its logits are those of a pool it has to
-  itself, bit for bit."""
-  _, pool = prefill(fresh_pool(), {2: TOKENS[:20]}, pad_to=32)
-  for t in range(20, 26):
-    _, pool = decode_step(pool, {2: TOKENS[t]}, {2: t})
-  second = TOKENS[40:46]
-  reused_logits, reused = prefill(pool, {2: second}, pad_to=32)
-  solo_logits, solo = prefill(fresh_pool(), {2: second}, pad_to=32)
-  np.testing.assert_array_equal(np.asarray(reused_logits), np.asarray(solo_logits))
-  a, b = decode_step(reused, {2: TOKENS[46]}, {2: 6})[0], decode_step(solo, {2: TOKENS[46]}, {2: 6})[0]
-  np.testing.assert_array_equal(a[2], b[2])
-
-
-def test_a_decode_chunk_leaves_an_inactive_rows_pages_bit_for_bit():
-  """A chunk of 4 steps of ``decode.paged_batch`` with rows 0 and 3 active: rows 1 and 2, resident but not stepped, keep
-  their pages exactly, and the active rows' tokens are the reference's greedy ones."""
-  _, pool = prefill(fresh_pool(), {0: TOKENS[:20], 1: TOKENS[20:50], 2: TOKENS[50:58], 3: TOKENS[30:41]}, pad_to=32)
-  before = {slot: pages_of(pool, slot) for slot in range(SLOTS)}
-  active, pos = np.asarray([True, False, False, True]), np.asarray([20, 30, 8, 11], np.int32)
-  first = jnp.asarray([[TOKENS[20]], [1], [1], [TOKENS[41]]], jnp.int32)
-  toks, _, new_pos, pool = dec.fused_paged_batch_decode(PARAMS, CFG, SHARD, first, pool, tables(), jnp.asarray(pos), jnp.asarray(active), np.zeros((SLOTS,), np.float32), 4, page_size=PS, use_kernel=False)
-  assert np.asarray(new_pos).tolist() == [24, 30, 8, 15]
-  for slot in (1, 2):
-    for got, want in zip(pages_of(pool, slot), before[slot]):
-      np.testing.assert_array_equal(got, want)
-  row0 = list(TOKENS[:21]) + [int(t) for t in np.asarray(toks)[0, :3]]
-  assert [int(np.argmax(reference(np.asarray(row0))[20 + i])) for i in range(3)] == [int(t) for t in np.asarray(toks)[0, :3]]
-
 
 def test_the_slot_cache_and_the_speculative_verify_window_equal_the_reference():
   """The paths beside the page pool's two programs: ``shard_forward`` over a slot-indexed cache (solo sessions,
@@ -423,10 +272,10 @@ def test_the_slot_cache_and_the_speculative_verify_window_equal_the_reference():
   through the pages: all are the reference's logits."""
   want = reference(TOKENS[:40])
   cache = dec.init_kv_cache(CFG, CFG.n_layers, 1, 64)
-  logits, cache = dec.shard_forward(PARAMS, CFG, SHARD, jnp.asarray(TOKENS[:20])[None], jnp.arange(20)[None], cache)
+  logits, cache = dec.jit_shard_forward(PARAMS, CFG, SHARD, jnp.asarray(TOKENS[:20])[None], jnp.arange(20)[None], cache)
   np.testing.assert_allclose(np.asarray(logits[0]), want[:20], atol=TOL, rtol=0)
   for t in range(20, 40):
-    logits, cache = dec.shard_forward(PARAMS, CFG, SHARD, jnp.asarray([[TOKENS[t]]]), jnp.asarray([[t]]), cache)
+    logits, cache = dec.jit_shard_forward(PARAMS, CFG, SHARD, jnp.asarray([[TOKENS[t]]]), jnp.asarray([[t]]), cache)
     np.testing.assert_allclose(np.asarray(logits[0, 0]), want[t], atol=TOL, rtol=0, err_msg=f"slot-cache decode at position {t}")
   _, pool = prefill(fresh_pool(), {1: TOKENS[:20]}, pad_to=32)
   toks, pos = np.zeros((SLOTS, 3), np.int32), np.zeros((SLOTS, 3), np.int32)
@@ -435,72 +284,19 @@ def test_the_slot_cache_and_the_speculative_verify_window_equal_the_reference():
   np.testing.assert_allclose(np.asarray(logits[1]), want[20:23], atol=TOL, rtol=0)
 
 
+
 # ------------------------------------------------------------ the scheduler
 
 
-def _serve(server, prompts, n_gen):
-  async def run():
-    return await asyncio.gather(*(
-      server.submit(f"r{i}-{len(p)}", np.asarray(p, np.int32), max_tokens=n_gen, temp=0.0, top_k=35, eos_ids=(), emit=lambda *_: None) for i, p in enumerate(prompts)
-    ))
-
-  return asyncio.run(run())
-
-
-def _greedy_under_the_reference(prompt, answer) -> bool:
-  logits = reference(np.asarray(list(prompt) + list(answer)))
-  return [int(np.argmax(logits[len(prompt) - 1 + i])) for i in range(len(answer))] == list(answer)
-
-
-def test_the_scheduler_serves_interleaved_requests_as_the_reference_does(monkeypatch):
-  """Two requests past the window and one under it through ``BatchedServer`` (admission groups, decode chunks, mixed
-  ticks on, the pool's pages) answer greedy-equal to the reference; the gauges say how many layers have a window and
-  how wide, and the two page counters what the rows held against what their layers' windows let the kernel read."""
-  from xotorch_support_jetson_tpu.utils.metrics import metrics
-
-  monkeypatch.setenv("XOT_TPU_BATCH_SLOTS", "2")
-  monkeypatch.setenv("XOT_TPU_PAGE_SIZE", str(PS))
-  engine = JaxShardedInferenceEngine(use_local_mesh=False)
-  engine.load_test_model(SHARD, CFG, PARAMS)
-  server = BatchedServer(engine)
-  prompts = [[int(t) for t in TOKENS[:29]], [int(t) for t in TOKENS[30:45]], [int(t) for t in TOKENS[50:55]]]
-  held, read = (metrics.counter_value(f"kv_pages_{name}_total") for name in ("resident", "read"))
-  try:
-    answers = _serve(server, prompts, 6)
-  finally:
-    server.shutdown()
-  assert all(len(a) == 6 and _greedy_under_the_reference(p, a) for p, a in zip(prompts, answers))
-  assert not CFG.recurrent_layers and server.ops.mixed_tick_supported() and not server.ops.prefill_donates_pool  # (a CPU states no memory limit: the copying prefill)
-  assert metrics.gauge_value("attention_layers", labels={"kind": "full"}) == 2 and metrics.gauge_value("attention_layers", labels={"kind": "window"}) == 2 and metrics.gauge_value("attention_window_tokens") == W
-  held, read = metrics.counter_value("kv_pages_resident_total") - held, metrics.counter_value("kv_pages_read_total") - read
+def test_the_served_gauges_and_page_counters_say_what_the_windows_let_the_kernel_read(served):
+  """After the battery's interleaved requests (two past the window, one under it; mixed ticks on): the gauges say how many
+  layers have a window and how wide, and the two page counters what the rows held against what their layers' windows let
+  the kernel read."""
+  server, after = served.server, served.after
+  assert not CFG.recurrent_layers and after.gauge_value("attention_layers", labels={"kind": "full"}) == 2 and after.gauge_value("attention_layers", labels={"kind": "window"}) == 2 and after.gauge_value("attention_window_tokens") == W
+  held, read = (after.counter_value(f"kv_pages_{name}_total") - served.before.counter_value(f"kv_pages_{name}_total") for name in ("resident", "read"))
   assert 0 < read < held and held % 4 == 0  # rows past the window: the window layers read 2-3 pages of the 4-9 a row holds
   server._windows, server.page_size = (0, W, W, 0), PS
   before = [metrics.counter_value(f"kv_pages_{name}_total") for name in ("resident", "read")]
   server._count_pages(np.asarray([29, 4, 0]), np.asarray([True, True, False]))  # lengths 30 and 5: 8 + 2 pages held a layer; a window of 8 from position 22 reads pages 5-7
   assert [metrics.counter_value(f"kv_pages_{name}_total") - b for name, b in zip(("resident", "read"), before)] == [4 * 10, 2 * 10 + 2 * (3 + 2)]
-
-
-# ------------------------------------------------------------ tracing
-
-
-def test_the_scopes_reach_the_lowered_decode_program():
-  """The gate's projection and product and the q/k norms under ``xot.attn_proj``, both kinds' cores under ``xot.attn``,
-  router, experts and shared expert under the ``xot.moe_*`` scopes there are: the scopes are in the lowered
-  ``decode.paged_batch``, and the gate's softplus of every run of layers lies under ``xot.attn_proj``. (That the windowed call is named ``paged_decode_window`` in a TPU's program is
-  ``tests/test_tpu_compile.py``'s to show: a CPU lowers no Mosaic call.)"""
-  args = (
-    PARAMS, CFG, SHARD, jnp.ones((SLOTS, 1), jnp.int32), fresh_pool(), jnp.asarray(tables()), jnp.asarray([3, 5, 7, 9], jnp.int32), jnp.ones((SLOTS,), bool),
-    jnp.zeros((SLOTS,), jnp.float32), jnp.full((SLOTS,), 8, jnp.int32), 4, 8, PS, False, jax.random.PRNGKey(1), None,
-  )
-  text = dec._fused_paged_batch_decode_impl.xot_jitted.lower(*args).as_text(debug_info=True)
-  scopes = set(re.findall(r"xot\.[a-z_]+", text))
-  want = {"xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.ffn", "xot.moe_router", "xot.moe_experts", "xot.moe_shared", "xot.head", "xot.sample"}
-  assert want <= scopes, sorted(want - scopes)
-  locs = dict(re.findall(r"(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
-
-  def named(ref: str, depth: int = 0) -> str:  # a location's whole chain of names
-    body = locs.get(ref, "")
-    return body + "".join(named(r, depth + 1) for r in re.findall(r"#loc\d+", body)) if depth < 8 else body
-
-  gates = [m for m in re.finditer(r"call @softplus.*loc\((#loc\d+)\)", text)]
-  assert len(gates) == 3 and all("xot.attn_proj" in named(m.group(1)) for m in gates)  # the gate's softplus, once a run of layers
