@@ -6,7 +6,8 @@ whose state 168 positions hardly fill, and for one with attention windows, which
   python scripts/chip_teacher_forced.py --config ling-3.0-flash-ep4-d7 --seed 7 [--rows 4] [--steps 160] [--probes a,b]
 
 At the published widths and the cell's pool (slots and pages of the file's ``serving_env``): ``--rows`` prompts of
-500-1000 seeded tokens (600-1200 for a kind with ``long_probes``: past a window of 512 from the first decoded token on)
+500-1000 seeded tokens (600-1200 for a kind with ``long_probes``: past a window of 512 from the first decoded token on;
+what the kind's ``long_prompt_tokens`` says where it says: 4160-4608 past a window of 4096)
 are prefilled as one group through ``prefill.pages_many`` (pool donated), then ``--steps`` decode
 steps run through ``paged_decode_forward`` over pool, state and pages, each fed the seeded next token (teacher-forced),
 the other slots inactive. A configuration without recurrent layers attends through whatever the served decode program
@@ -15,7 +16,7 @@ gather it was measured with. Every step's log-softmax is compared with the float
 continuation (``jax.default_matmul_precision("highest")``, one row at a time): the largest and the mean |difference| over
 the reference's 64 likeliest tokens a position, and the reference's best log-prob minus its log-prob of the program's
 greedy token. ``--probes`` adds the same numbers against deliberately wrong references of the kind's ``probes`` and
-``long_probes`` (``all``: every one of them). One
+``long_probes`` (``all``: every one of them; an ``exact_probes`` entry by its name, also beside ``all``). One
 JSON line; exit 1 where JAX sees no TPU (``--cpu`` rehearses at the kind's tiny widths)."""
 
 from __future__ import annotations
@@ -80,7 +81,7 @@ def main() -> int:
   n_pages = 1 + args.rows * mp
   pool = init_paged_pool(cfg, cfg.n_layers, n_pages, ps, n_slots=slots)
   rng = np.random.default_rng([args.seed, 11])
-  lo, hi = (40, 90) if args.cpu else (600, 1200) if hasattr(kind, "long_probes") else (500, 1000)
+  lo, hi = (40, 90) if args.cpu else kind.long_prompt_tokens(hf) if hasattr(kind, "long_prompt_tokens") else (600, 1200) if hasattr(kind, "long_probes") else (500, 1000)
   lens = [int(n) for n in rng.integers(lo, hi + 1, size=args.rows)]
   seqs = [rng.integers(3, cfg.vocab_size, size=n + args.steps) for n in lens]
   use_slots = [int(s) for s in rng.choice(slots, size=args.rows, replace=False)]
@@ -123,8 +124,10 @@ def main() -> int:
     return {"max_abs": worst, "mean_abs": total / count, "greedy_margin": margin, "rows": per_row}
 
   out = {"ok": True, "device": {"platform": dev.platform, "kind": dev.device_kind}, "config": args.config, "seed": args.seed, "prompts": lens, "steps": args.steps, "slots": slots, "kernel": bool(use_kernel), "served_s": round(served_s, 1), "sound": against()}
-  wanted = list(all_probes) if args.probes == "all" else [p for p in args.probes.split(",") if p]
+  wanted = [name for p in args.probes.split(",") if p for name in (all_probes if p == "all" else [p])]
+  all_probes |= getattr(kind, "exact_probes", lambda _hf: {})(hf)  # by name only: what bfloat16 serving cannot tell is no part of "all"
   out["probes"] = {name: {k: v for k, v in against(**all_probes[name]).items() if k != "rows"} for name in wanted}
+  out["memory_peak_bytes"] = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
   out["total_s"] = round(time.perf_counter() - t0, 1)
   print(json.dumps(out), flush=True)
   return 0

@@ -135,3 +135,29 @@ def test_kernel_path_scopes_the_token_write_and_the_attention_kernel():
   assert writes[0].startswith('"xot.kv_write/kv_token_write') and "paged_decode" not in writes[0]
   attention = [locs[ref] for ref in re.findall(r"call @_paged_decode_attention_impl.*?loc\((#loc\d+)\)", text)]
   assert len(attention) == 1 and "xot.attn/" in attention[0], attention
+
+
+@pytest.mark.parametrize("router_input", ["ffn", "attn"])
+def test_the_routing_is_drawn_under_its_scope_on_the_side_of_the_attention_the_model_says(router_input):
+  """``xot.moe_router`` is emitted where the routing is DRAWN (ops/moe.py ``route``): after the attention's scopes for a
+  router that reads its experts' input, ahead of them for one that reads the attention's (``cfg.router_input`` "attn",
+  ISSUE 50) — in the paged decode layer step as traced, equation by equation. Either way the one top-k of the layer lies
+  under the scope, and the experts' product follows the attention."""
+  from xotorch_support_jetson_tpu.models.decoder import _paged_layer_step
+  from xotorch_support_jetson_tpu.ops.rope import rope_inv_freq
+
+  cfg = tiny_test_config(n_layers=1, max_seq_len=128, n_experts=4, n_active_experts=2, moe_hidden_dim=32, router_input=router_input)
+  params, shard = full_model_params(jax.random.PRNGKey(0), cfg)
+  lp = {name: leaf[0] for name, leaf in params["moe_layers"].items()}
+  B, mp = 2, 128 // PS
+  pool = init_paged_pool(cfg, 1, 1 + B * mp, PS)
+  bt = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
+  step = lambda h, pool: _paged_layer_step(h, pool, lp, 0, bt, jnp.asarray([[3], [5]], jnp.int32), rope_inv_freq(cfg), cfg, PS, False)  # noqa: E731
+  eqns = jax.make_jaxpr(step)(jnp.ones((B, 1, cfg.dim), jnp.float32), pool).jaxpr.eqns
+  stacks = [str(e.source_info.name_stack) for e in eqns]
+  at = lambda scope: [i for i, s in enumerate(stacks) if scope in s.split("/")]  # noqa: E731
+  router, attn, experts = at("xot.moe_router"), at("xot.attn"), at("xot.moe_experts")
+  assert router and attn and experts and min(experts) > max(attn)
+  top_k = [i for i, e in enumerate(eqns) if e.primitive.name == "top_k"]
+  assert len(top_k) == 1 and top_k[0] in router
+  assert (top_k[0] < min(attn)) == (router_input == "attn") and (min(router) < min(attn)) == (router_input == "attn")

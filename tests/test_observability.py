@@ -769,6 +769,9 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_moe_expert_layer_steps_total",  # expert layers x decode steps of the settled chunks (ISSUE 40)
   "xot_tpu_attention_layers",  # {kind}: the page pool's layers whose attention sees every position (full) / its last window (window) (ISSUE 46)
   "xot_tpu_attention_window_tokens",  # that window, in tokens (0: no layer has one) (ISSUE 46)
+  "xot_tpu_attention_rope_layers",  # {rope}: the page pool's layers whose q and k carry a position term (rope) / none (none) (ISSUE 50)
+  "xot_tpu_moe_router_input",  # {at}: the expert layers whose router reads its experts' own input (ffn) / the attention's, drawn ahead of it (attn) (ISSUE 50)
+  "xot_tpu_moe_expert_gate",  # {act}: the expert layers by their experts' gate nonlinearity: silu / relu (ops/moe.py EXPERT_ACTS, ISSUE 50)
   "xot_tpu_kv_pages_resident_total",  # pages the decode rows hold in every layer that owns pages, summed a decode dispatch (ISSUE 46)
   "xot_tpu_kv_pages_read_total",  # of those, the pages the layers' windows let their attention read (ISSUE 46)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
@@ -858,6 +861,9 @@ def test_metric_name_snapshot_after_serving():
   gm.inc("moe_expert_layer_steps_total", 0)
   gm.set_gauge("attention_layers", 0, labels={"kind": "full"})  # set when a page pool is made (ISSUE 46)
   gm.set_gauge("attention_window_tokens", 0)
+  gm.set_gauge("attention_rope_layers", 0, labels={"rope": "rope"})  # set when a page pool is made (ISSUE 50)
+  gm.set_gauge("moe_router_input", 0, labels={"at": "ffn"})  # set when a pool is made for a model with routed experts (ISSUE 50)
+  gm.set_gauge("moe_expert_gate", 0, labels={"act": "silu"})
   gm.inc("kv_pages_resident_total", 0)  # counted a paged decode dispatch
   gm.inc("kv_pages_read_total", 0)
   gm.set_gauge("kv_draft_slots", 0)

@@ -337,3 +337,76 @@ def test_swa_gqa_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_set
   print(f"prefill.pages_many_sampled laguna K=8 S=2048: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   # That it compiled is the fit (its temporaries overlap the donated pool's buffers: PERF.md section 6, PR 44).
   assert mem.alias_size_in_bytes >= 2 * 5 * n_pages * 8 * PS * 128 * 2 and mem.argument_size_in_bytes < 13.2e9
+
+
+def test_swa_nope_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_settings_fit_v5e(chip, monkeypatch):
+  """SmallThinker-21BA3B's first stage as ``smallthinker-21ba3b.longdoc-closed-32`` serves it (ISSUE 50): the file's
+  pages of 4 KV heads x 64 x 128 bf16 in 8 layers beside 7.93 GB of weights with every expert held, block tables of 256
+  pages a row (the model's whole context, 16384). ``decode.paged_batch`` told ``use_kernel`` is accepted by XLA:TPU: its
+  four runs of layers — a global layer, three window layers, a global layer, three window layers — each hold one call
+  of the paged kernel at groups of SEVEN query heads a KV head (which no other configuration has), the window layers'
+  under its own name ``paged_decode_window`` (a window of 64 pages), one token write each, and the grouped expert
+  products, ReLU-gated, inside ``xot.moe_experts``; the router's top-k lies AHEAD of the run's attention call in the
+  program's text as it does in the model. The mixed tick with a slice padded to 2048 (the file's budget) over a 256-page
+  window fits beside them, and so do the largest prefill groups the ramp meets (below), the pool donated."""
+  import json
+
+  sys.path.insert(0, str(ROOT / "benchmark"))
+  import common
+
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_mixed_paged_batch_decode_impl, _fused_paged_batch_decode_impl, full_model_params, prefill_into_pages_many_sampled_inplace
+  from xotorch_support_jetson_tpu.ops import moe
+  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
+
+  monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend
+  hf = json.loads((ROOT / "benchmark" / "configs" / "smallthinker-21ba3b-d8.json").read_text())
+  cfg = common.model_config(hf)
+  n_slots, n_pages = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), int(hf["serving_env"]["XOT_TPU_BATCH_PAGES"])
+  on_chip = lambda tree: jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)  # noqa: E731
+  params = on_chip(jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg)[0]))
+  pool = on_chip(jax.eval_shape(lambda: init_paged_pool(cfg, cfg.n_layers, n_pages, PS)))
+  pool_bytes = 2 * 8 * n_pages * 4 * PS * 128 * 2
+  assert pool["k"].shape == pool["v"].shape == (8, n_pages, 4, PS, 128) and set(pool) == {"k", "v"} and 4609 <= n_pages <= 5121 and n_slots == 32
+  assert {name: (st["wq"].shape, st["w_experts_gate"].shape) for name, st in params.items() if isinstance(st, dict)} == {
+    "moe_layers": ((2, 2560, 3584), (2, 64, 2560, 768)), "window_moe_layers": ((6, 2560, 3584), (6, 64, 2560, 768)),
+  }
+  shard, mp = Shard("smallthinker", 0, cfg.n_layers - 1, cfg.n_layers), pages_to_cover(cfg.max_seq_len, PS)
+  assert mp == 256
+  rows, key = _rows(chip, n_slots), _sds(chip, (2,), jnp.uint32)
+  decode_args = (params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), pool, _sds(chip, (n_slots, mp), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32))
+  compiled, text = _compile(_fused_paged_batch_decode_impl, *decode_args, 8, 64, PS, True, key, None)
+  kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+  count = lambda call: sum(f"{call}/pallas_call" in line for line in kernels)  # noqa: E731  (the call's own path, not its operands' names)
+  calls = ("jit(_paged_decode_attention_impl)", "jit(_paged_decode_attention_impl)/paged_decode_window", "xot.kv_write/kv_token_write", "xot.moe_experts/moe_gate_up", "xot.moe_experts/moe_down")
+  assert len(kernels) == 16 and [count(call) for call in calls] == [2, 2, 4, 4, 4], [line.strip()[-300:] for line in kernels]
+  assert all("/xot.attn/jit(_paged_decode_attention_impl)" in line for line in kernels if "_paged_decode_attention_impl" in line)
+  assert "xot.moe_router" in text
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch smallthinker B=32 pages={n_pages}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= pool_bytes and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9 and abs(mem.argument_size_in_bytes - (7.93e9 + pool_bytes)) < 0.01e9
+  one = lambda dtype: _sds(chip, (1,), dtype)  # noqa: E731
+  compiled, text = _compile(_fused_mixed_paged_batch_decode_impl, *decode_args, _sds(chip, (1, 2048), jnp.int32), _sds(chip, (1, mp), jnp.int32), one(jnp.int32), one(jnp.int32), 8, 64, PS, True, key, None, None)
+  mem = compiled.memory_analysis()
+  print(f"decode.mixed_paged_batch smallthinker pad=2048 window={mp}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert text.count("paged_decode_window") >= 1 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+  # The prefill groups the ramp meets, the pool donated: ``_group_rows`` holds rows x page window to 8 first chunks' (256
+  # pages in all), so 8 rows of a first chunk over 32 pages and ONE row of a 12 k-token prompt's last chunk over the
+  # whole 256 are the most K/V a group gathers beside its activations (8 rows over 256 pages would be 4.2 GB of
+  # temporaries, which XLA:TPU refuses beside this pool) — through the flash kernel in all four runs of layers. (With
+  # the chunk at 4096, measured and set aside, 8 rows x 4096 over 64 pages take 2.89 GB of temporaries and still fit.)
+  for K, S, window in ((8, 2048, 32), (1, 2048, mp)):
+    rows = _rows(chip, K)
+    compiled, text = _compile(
+      prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool,
+      _sds(chip, (K, window), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS, rows(jnp.float32), rows(jnp.int32), key, 64, None,
+    )  # fmt: skip
+    mem = compiled.memory_analysis()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in kernels if "xot.moe_experts/moe_" not in line]
+    assert len(flash) == 4 and all("flash_attention_prefill" in line for line in flash), [line.strip()[-300:] for line in flash]
+    print(f"prefill.pages_many_sampled smallthinker K={K} S={S} window={window}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+    # That it compiled is the fit (its temporaries overlap the donated pool's buffers: PERF.md section 6, PR 44).
+    assert mem.alias_size_in_bytes >= pool_bytes and mem.argument_size_in_bytes < 13.4e9

@@ -910,6 +910,10 @@ class BatchedServer:
       for kind, n in (("full", sum(1 for w in self._windows if not w)), ("window", sum(1 for w in self._windows if w))):
         metrics.set_gauge("attention_layers", n, labels={"kind": kind})
       metrics.set_gauge("attention_window_tokens", max(self._windows, default=0))
+      # ... and by whether their q and k carry a position term (``AttnKind.rope``; a model-level "nope" is every layer's)
+      ropes = eng.cfg.attn_ropes
+      for rope, n in (("rope", sum(ropes)), ("none", len(ropes) - sum(ropes))):
+        metrics.set_gauge("attention_rope_layers", n, labels={"rope": rope})
       from .kv_tier import KvTierManager, kv_tier_enabled
 
       if recurrent:
@@ -2545,7 +2549,7 @@ class BatchedServer:
     sequence-parallel ring holds the weights itself (``engine.params`` is None): block form, and its programs do not
     count their visits."""
     from ..models.decoder import served_expert_form
-    from ..ops.moe import FFN_FORMS
+    from ..ops.moe import EXPERT_ACTS, FFN_FORMS
 
     cfg, params = self.engine.cfg, getattr(self.engine, "params", None)
     self._expert_layers = sum(stack["w_experts_gate"].shape[0] for stack in (params or {}).values() if isinstance(stack, dict) and "w_experts_gate" in stack)
@@ -2554,6 +2558,14 @@ class BatchedServer:
     form = served_expert_form(params, cfg)
     for name in FFN_FORMS:
       metrics.set_gauge("moe_ffn_form", int(name == form), labels={"form": name})
+    # The expert layers by where their router reads (``cfg.router_input``: its experts' own input, or the attention's,
+    # drawn ahead of it) and by their experts' gate (``cfg.expert_act``): one value a model today, a count so that a
+    # model of two says so.
+    n_expert_layers = self._expert_layers or cfg.n_layers - cfg.first_k_dense
+    for at in ("ffn", "attn"):
+      metrics.set_gauge("moe_router_input", n_expert_layers * int(at == cfg.router_input), labels={"at": at})
+    for act in EXPERT_ACTS:
+      metrics.set_gauge("moe_expert_gate", n_expert_layers * int(act == cfg.expert_act), labels={"act": act})
 
   def _count_pages(self, positions, active) -> None:
     """One decode dispatch's pages, from the rows' lengths on the host: ``kv_pages_resident_total`` — what the active

@@ -65,10 +65,12 @@ class LongRopeScaling:
 @dataclass(frozen=True)
 class AttnKind:
   """One attention layer's description, where a model's attention layers are not all alike: the ONE owner of which
-  layers have a window, how many query heads, which rope and a gate or none (``ModelConfig.layer_attn`` holds one a
-  layer; the KV heads and the head size are the model's). ``name`` is "full" or "window" and prefixes the layer's
-  stack (``ModelConfig.layer_stack``); ``window`` 0 is none, else a query at t sees the keys in (t - window, t];
-  ``out_gate``: one scalar a head, softplus(x W_og) in float32, multiplies the head's output ahead of ``wo``."""
+  layers have a window, how many query heads, which rope — or none — and a gate or none (``ModelConfig.layer_attn``
+  holds one a layer; the KV heads and the head size are the model's). ``name`` is "full" or "window" and prefixes the
+  layer's stack (``ModelConfig.layer_stack``); ``window`` 0 is none, else a query at t sees the keys in (t - window, t];
+  ``out_gate``: one scalar a head, softplus(x W_og) in float32, multiplies the head's output ahead of ``wo``;
+  ``rope`` False: the kind's q and k go to the attention as projected, with no position term (smallthinker's global
+  layers beside its roped window layers; a model none of whose layers has one says so once, ``ModelConfig.use_rope``)."""
 
   name: str
   n_heads: int
@@ -77,11 +79,12 @@ class AttnKind:
   rope_scaling: RopeScaling | YarnScaling | LongRopeScaling | None = None
   partial_rotary_factor: float = 1.0
   out_gate: bool = False
+  rope: bool = True
 
   @property
   def shape(self) -> tuple:
     """What two kinds must share to share a stack of parameters: everything but the window."""
-    return (self.n_heads, self.rope_theta, self.rope_scaling, self.partial_rotary_factor, self.out_gate)
+    return (self.n_heads, self.rope_theta, self.rope_scaling, self.partial_rotary_factor, self.out_gate, self.rope)
 
 
 @dataclass(frozen=True)
@@ -129,6 +132,11 @@ class ModelConfig:
   routed_scaling_factor: float = 1.0
   moe_capacity_factor: float | None = None  # None ⇒ exact compute (no token drops)
   moe_aux_loss_coef: float = 0.0  # load-balancing loss weight in training
+  # Where an expert layer's router reads: "ffn", the experts' own normed input, or "attn" (smallthinker), the normed
+  # input of the layer's ATTENTION — the choice is drawn ahead of the attention and carried across it to the experts,
+  # which read the stream after the attention's residual (models/decoder.py ``_route_ahead``).
+  router_input: str = "ffn"
+  expert_act: str = "silu"  # the routed experts' gate nonlinearity, one of ops/moe.py ``EXPERT_ACTS``: "silu" (SwiGLU) | "relu" (ReGLU)
   # Group-limited routing (deepseek): experts are grouped; only experts in the
   # top ``topk_group`` groups are eligible. Group score = max expert score
   # (v2 "group_limited_greedy") or sum of top-2 (v3 "noaux_tc").
@@ -162,9 +170,10 @@ class ModelConfig:
   sliding_window: int = 0
   # One ``AttnKind`` a layer (None at a recurrent layer) where a model's attention layers are not all alike; () ⇒ every
   # attention layer is the model-level fields' (``attn_kind``). Kinds that differ in more than their window (laguna:
-  # 48 query heads, YaRN over half a head and no window / 64 heads, plain rope and a window of 512) keep a stack of
-  # parameters each (``layer_stack``), run in the published order (``mixed_layers``), and their window is a static
-  # operand of the attention kernels.
+  # 48 query heads, YaRN over half a head and no window / 64 heads, plain rope and a window of 512; smallthinker: no
+  # position term and no window / plain rope and a window of 4096, 28 heads both) keep a stack of parameters each
+  # (``layer_stack``), run in the published order (``mixed_layers``), and their window is a static operand of the
+  # attention kernels.
   layer_attn: tuple = ()
   embed_scale: float = 1.0  # gemma multiplies embeddings by sqrt(dim)
   # --- vision (llava): CLIP tower + projector config (models/vision.py) and
@@ -225,7 +234,7 @@ class ModelConfig:
   residual_multiplier: float = 1.0
   logits_scaling: float = 1.0
   attn_multiplier: float = 0.0
-  use_rope: bool = True  # False: no position term at all ("nope")
+  use_rope: bool = True  # False: no position term in ANY attention layer ("nope"); some layers only: ``AttnKind.rope``
   # Cleared by the engine (never by a user) when the serving plan leaves a
   # mesh axis of more than one device to GSPMD: a Mosaic kernel cannot be
   # partitioned automatically ("wrap the call in a shard_map"), so programs
@@ -237,7 +246,7 @@ class ModelConfig:
     """Layer ``layer_idx``'s attention description: its ``layer_attn`` entry, else the model-level fields'."""
     if self.layer_attn:
       return self.layer_attn[layer_idx]
-    return AttnKind("full", self.n_heads, 0, self.rope_theta, self.rope_scaling, self.partial_rotary_factor)
+    return AttnKind("full", self.n_heads, 0, self.rope_theta, self.rope_scaling, self.partial_rotary_factor, rope=self.use_rope)
 
   @property
   def attn_shapes(self) -> tuple:
@@ -299,6 +308,11 @@ class ModelConfig:
   def attn_windows(self) -> tuple:
     """The window (0: none) of every layer that owns K/V pages, in the order of the page pool's layer axis."""
     return tuple(self.attn_kind(i).window for i in range(self.n_layers) if not (self.layer_types and self.layer_types[i] in RECURRENT_KINDS))
+
+  @property
+  def attn_ropes(self) -> tuple:
+    """Whether q and k carry a position term, for every layer that owns K/V pages, in the order of ``attn_windows``."""
+    return tuple(self.attn_kind(i).rope for i in range(self.n_layers) if not (self.layer_types and self.layer_types[i] in RECURRENT_KINDS))
 
   @property
   def n_attn_layers(self) -> int:
@@ -364,7 +378,7 @@ RECURRENT_KINDS = ("mamba", "kda", "gdn")  # the ``layer_types`` whose layers ke
 # a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
 MODEL_FAMILIES = {
   "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
-  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "llama": "llama",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "smallthinker": "smallthinker", "llama": "llama",
 }
 
 
@@ -472,9 +486,9 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
   # deepseek-v2/v3 (n_routed_experts, n_shared_experts, first_k_dense_replace,
   # sigmoid scoring + routed_scaling_factor on v3).
   moe: dict[str, Any] = {}
-  n_experts = int(hf.get("num_local_experts") or hf.get("num_experts") or hf.get("n_routed_experts") or 0)
+  n_experts = int(hf.get("num_local_experts") or hf.get("num_experts") or hf.get("n_routed_experts") or hf.get("moe_num_primary_experts") or 0)
   if n_experts:
-    moe_hidden = int(hf.get("moe_intermediate_size") or hf["intermediate_size"])
+    moe_hidden = int(hf.get("moe_intermediate_size") or hf.get("moe_ffn_hidden_size") or hf["intermediate_size"])
     n_shared = int(hf.get("n_shared_experts") or 0)
     shared_dim = n_shared * moe_hidden
     if family in ("qwen2-moe", "laguna"):
@@ -502,13 +516,13 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     moe = dict(
       experts_held=held,
       n_experts=n_experts,
-      n_active_experts=int(hf.get("num_experts_per_tok", 2)),
+      n_active_experts=int(hf.get("num_experts_per_tok") or hf.get("moe_num_active_primary_experts") or 2),
       moe_hidden_dim=moe_hidden,
       shared_expert_dim=shared_dim,
       shared_expert_gate=family == "qwen2-moe",
       first_k_dense=_leading_dense(hf) if "mlp_layer_types" in hf else int(hf.get("first_k_dense_replace", 0)),
       router_scoring=scoring,
-      norm_topk_prob=bool(hf.get("norm_topk_prob", family in ("mixtral", "laguna"))),
+      norm_topk_prob=bool(hf.get("norm_topk_prob", family in ("mixtral", "laguna", "smallthinker"))),
       routed_scaling_factor=float(hf.get("routed_scaling_factor", hf.get("moe_routed_scaling_factor", 1.0))),
       moe_aux_loss_coef=float(hf.get("router_aux_loss_coef", hf.get("aux_loss_alpha", 0.001))),
       n_group=int(hf.get("n_group") or 1),
@@ -549,6 +563,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     hybrid = _olmo_hybrid_fields(hf)
   if family == "laguna":
     hybrid = _laguna_fields(hf)
+  if family == "smallthinker":
+    hybrid = _smallthinker_fields(hf)
 
   n_heads = int(hf["num_attention_heads"])
   return ModelConfig(
@@ -557,7 +573,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     n_layers=int(hf["num_hidden_layers"]),
     n_heads=n_heads,
     n_kv_heads=int(hf.get("num_key_value_heads", n_heads)),
-    hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if family == "granite-hybrid" else int(hf["intermediate_size"]),
+    # (smallthinker: every layer an expert layer, no dense FFN width at all)
+    hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if family == "granite-hybrid" else int(hf.get("intermediate_size") or 0) if family == "smallthinker" else int(hf["intermediate_size"]),
     head_dim=int(hf.get("head_dim") or 0),
     norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
     rope_theta=float(hf.get("rope_theta") or 10000.0),
@@ -738,6 +755,45 @@ def _laguna_fields(hf: dict) -> dict:
       raise ValueError(f"laguna: num_attention_heads_per_layer must give every {t} layer one head count, a multiple of num_key_value_heads; got {heads}")
   kinds = {t: AttnKind(names[t], per_kind[t], window if t == "sliding_attention" else 0, *ropes[t], out_gate=bool(hf.get("gating"))) for t in per_kind}
   return dict(layer_attn=tuple(kinds[t] for t in layer_types))
+
+
+def _smallthinker_fields(hf: dict) -> dict:
+  """``smallthinker`` (SmallThinker-21BA3B) → ``layer_attn`` and where the router reads: ``sliding_window_layout`` names
+  each layer 1 (a window of ``sliding_window_size``) or 0 (none), ``rope_layout`` 1 (plain rope at ``rope_theta`` over
+  the whole head) or 0 (no position term) — two fields of the kind, so the lists need not agree; every layer routes
+  ``moe_num_active_primary_experts`` of ``moe_num_primary_experts`` ReLU-gated experts of ``moe_ffn_hidden_size`` by a
+  softmax over the chosen, from a router that reads the attention's normed input, with no shared expert and no dense
+  FFN. What the decoder does not implement is refused here, by name."""
+  n_layers = int(hf["num_hidden_layers"])
+  layouts = {key: list(hf.get(key) or [0 if key == "sliding_window_layout" else 1] * n_layers) for key in ("rope_layout", "sliding_window_layout")}
+  for key, layout in layouts.items():
+    if len(layout) != n_layers or set(layout) - {0, 1}:
+      raise ValueError(f"smallthinker: {key} must name {n_layers} layers, each 0 or 1; got {layout}")
+  if hf.get("rope_scaling"):
+    raise ValueError("smallthinker: a rope_scaling that is not null is not supported")
+  if not hf.get("moe_primary_router_apply_softmax", False):
+    raise ValueError("smallthinker: moe_primary_router_apply_softmax false (sigmoid scores on the chosen experts) is not supported")
+  if not hf.get("norm_topk_prob", True):
+    raise ValueError("smallthinker: norm_topk_prob false is not supported: the softmax is taken over the chosen experts")
+  if not int(hf.get("moe_num_primary_experts") or 0):
+    raise ValueError("smallthinker: moe_num_primary_experts must be above 0: every layer is an expert layer, the model has no dense FFN")
+  secondary = sorted(key for key in hf if "secondary" in key and hf[key])
+  if secondary:
+    raise ValueError(f"smallthinker: a secondary expert tier ({', '.join(secondary)}) is not supported")
+  if hf.get("attention_bias"):
+    raise ValueError("smallthinker: attention_bias true is not supported")
+  window = int(hf.get("sliding_window_size") or 0)
+  if 1 in layouts["sliding_window_layout"] and window <= 0:
+    raise ValueError("smallthinker: window layers (sliding_window_layout 1) need a sliding_window_size above 0")
+  heads, theta = int(hf["num_attention_heads"]), float(hf.get("rope_theta") or 10000.0)
+  kind = lambda roped, windowed: AttnKind("window" if windowed else "full", heads, window if windowed else 0, theta, None, 1.0, rope=bool(roped))  # noqa: E731
+  layer_attn = tuple(kind(r, w) for r, w in zip(layouts["rope_layout"], layouts["sliding_window_layout"]))
+  # Each kind of layer needs a stack of its own, named by its window: kinds that differ in the window alone would share
+  # one and the window ride a traced flag (gemma2's way, which no Pallas kernel takes and this family has no leaf for).
+  kinds = list(dict.fromkeys(layer_attn))
+  if len({k.shape for k in kinds}) < len(kinds) or len({k.name for k in kinds[1:]}) < len(kinds[1:]):
+    raise ValueError(f"smallthinker: rope_layout {layouts['rope_layout']} and sliding_window_layout {layouts['sliding_window_layout']} give layers that differ in the window alone, or two kinds of one name: not supported")
+  return dict(layer_attn=layer_attn, router_input="attn", expert_act="relu")
 
 
 def load_model_config(model_dir: str | Path, dtype=None) -> ModelConfig:

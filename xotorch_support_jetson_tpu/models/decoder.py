@@ -467,7 +467,7 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
   kind = p.get("attn_kind")
   n_heads, rope = (kind.n_heads, kind) if kind is not None else (cfg.n_heads, cfg)
   if isinstance(inv_freq, dict):
-    inv_freq = inv_freq[kind]
+    inv_freq = inv_freq.get(kind)  # (a kind without rope has no table)
   q = _mm(x, p, "wq", cfg.quant_compute)
   k = _mm(x, p, "wk", cfg.quant_compute)
   v = _mm(x, p, "wv", cfg.quant_compute)
@@ -506,7 +506,7 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
     # granite's softmax scale, as a factor on q over the cores' own 1/sqrt(hd): the attention paths and the
     # Pallas kernels keep one scale (``plain_attention`` stays true). 1/64 over 1/8 is 0.125, exact in bf16.
     q = q * jnp.asarray(cfg.attn_multiplier * cfg.head_dim**0.5, q.dtype)
-  if not cfg.use_rope:  # "nope": no position term; causality alone orders the tokens
+  if not (cfg.use_rope and (kind is None or kind.rope)):  # "nope", the model's or this kind of layer's: no position term; causality alone orders the tokens
     return q, k, v
   m = rope_attention_factor(rope)
   q = apply_rope(q, positions, inv_freq, m)
@@ -571,9 +571,30 @@ def _attn_out(h, x, attn, p, cfg: ModelConfig):
   return _residual(h, attn_out, cfg)
 
 
-def _mlp_block(h, p, cfg: ModelConfig):
+def _routing_args(p, cfg: ModelConfig) -> dict:
+  """How an expert layer's router draws its choice (ops/moe.py ``route`` / ``moe_ffn``), from the configuration."""
+  return dict(
+    k=cfg.n_active_experts, scoring=cfg.router_scoring, norm_topk=cfg.norm_topk_prob, selection_bias=p.get("router_bias"), scale=cfg.routed_scaling_factor,
+    n_group=cfg.n_group, topk_group=cfg.topk_group, group_mode=cfg.group_mode,
+  )
+
+
+def _route_ahead(x, p, cfg: ModelConfig):
+  """The layer's routing drawn from ``x`` [B, S, D], the ATTENTION's normed input, where the model's router reads it
+  (``cfg.router_input`` "attn": smallthinker) — ahead of the attention, under ``xot.moe_router``; the layer step hands
+  it across the attention to ``_mlp_block``, whose experts read the stream after the attention's residual. None for
+  every other model and for a layer without experts: ``_mlp_block`` then routes from its own input."""
+  if cfg.router_input != "attn" or "w_router" not in p:
+    return None
+  from ..ops.moe import route
+
+  return route(x.reshape(-1, x.shape[-1]), p["w_router"], **_routing_args(p, cfg))
+
+
+def _mlp_block(h, p, cfg: ModelConfig, routed=None):
   """Post-attention norm + FFN (dense or MoE+shared-expert). Returns (h, aux, visited): the router's auxiliary loss and
-  the number of distinct held experts the rows chose (0 and 0 for a dense FFN)."""
+  the number of distinct held experts the rows chose (0 and 0 for a dense FFN). ``routed``: the layer's routing where
+  it was drawn ahead of the attention (``_route_ahead``)."""
   B, S, D = h.shape
   with jax.named_scope("xot.ffn"):
     x = rms_norm(h, p["mlp_norm"], cfg.norm_eps) if "mlp_norm" in p else h  # (absent: a block whose norms follow its sublayers)
@@ -604,17 +625,12 @@ def _mlp_block(h, p, cfg: ModelConfig):
       xt,
       p["w_router"],
       *experts,
-      k=cfg.n_active_experts,
-      scoring=cfg.router_scoring,
-      norm_topk=cfg.norm_topk_prob,
-      selection_bias=p.get("router_bias"),
-      scale=cfg.routed_scaling_factor,
+      **_routing_args(p, cfg),
       capacity_factor=cfg.moe_capacity_factor,
-      n_group=cfg.n_group,
-      topk_group=cfg.topk_group,
-      group_mode=cfg.group_mode,
       **form,
       **({"held": cfg.experts_held} if cfg.experts_held else {}),
+      act=cfg.expert_act,
+      routed=routed,
     )
     if "w_shared_gate" in p:
       with jax.named_scope("xot.moe_shared"):
@@ -1132,6 +1148,7 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
 
   with jax.named_scope("xot.attn_proj"):
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps) if "attn_norm" in p else h
+  routed = _route_ahead(x, p, cfg)
   if "wkv_a" in p and use_cache:
     # MLA with cache: write only the latent (+rope channel) and attend via
     # weight absorption (ops/attention.py mla_absorbed_attention) — the cache
@@ -1220,7 +1237,7 @@ def _layer_step(h, layer_params, kv, positions, kv_positions, inv_freq, cfg: Mod
       attn = (attn_fn or gqa_attention)(q, k, v, positions, positions[0], **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
 
   h = _attn_out(h, x, attn, p, cfg)
-  h, aux, _ = _mlp_block(h, p, cfg)
+  h, aux, _ = _mlp_block(h, p, cfg, routed)
   return h, kv, aux
 
 
@@ -2037,6 +2054,7 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
   B, S, D = h.shape
   with jax.named_scope("xot.attn_proj"):
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps) if "attn_norm" in p else h
+  routed = _route_ahead(x, p, cfg)
   pos = positions[:, 0]
   lengths = pos + 1  # valid KV slots incl. the token written below
   from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref
@@ -2065,7 +2083,7 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
     else:
       attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
   h = _attn_out(h, x, attn, p, cfg)
-  h, _, visited = _mlp_block(h, p, cfg)
+  h, _, visited = _mlp_block(h, p, cfg, routed)
   return h, pool, visited
 
 
@@ -2290,6 +2308,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
   B, W, D = h.shape
   with jax.named_scope("xot.attn_proj"):
     x = rms_norm(h, p["attn_norm"], cfg.norm_eps) if "attn_norm" in p else h
+  routed = _route_ahead(x, p, cfg)
   from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref
 
   if kv_quant is None:
@@ -2314,7 +2333,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
   else:  # gather route: one multi-query reference call
     attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, q_positions=positions, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
   h = _attn_out(h, x, attn, p, cfg)
-  h, *_ = _mlp_block(h, p, cfg)
+  h, *_ = _mlp_block(h, p, cfg, routed)
   return h, pool
 
 

@@ -52,8 +52,17 @@ decision, made once a program. Every other caller — the cache-less forward
   in the activations' dtype: the caller dequantises codes beside the call (XLA
   fuses that into the einsum's read).
 
-Both take bf16 operands, accumulate in float32, apply silu in float32 and
-combine in float32. Both return, beside the result, the router's auxiliary
+Both take bf16 operands, accumulate in float32, apply the gate's nonlinearity
+(``EXPERT_ACTS``: silu, or relu for ReGLU experts — a static choice, from the
+configuration) in float32 and combine in float32.
+
+**The routing is an operand of its own** (``route`` → ``Routed``): by default
+``moe_ffn`` draws it from the tokens it computes from, inside ``_moe_ffn_*``;
+a model whose router reads another tensor (smallthinker's reads the normed
+input of the layer's ATTENTION) has its layer step call ``route`` there, ahead
+of the attention, and hand ``moe_ffn`` the result (``routed``) with the
+experts' own input. ``router_topk`` stays the one place a choice is drawn, under
+``xot.moe_router`` wherever ``route`` is called from. Both return, beside the result, the router's auxiliary
 loss and the number of distinct held experts the rows chose (what the grouped
 form visits): the counters ``moe_experts_visited_total`` /
 ``moe_expert_layer_steps_total`` are fed from it.
@@ -63,6 +72,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -144,20 +154,42 @@ def _held_index(idx, held):
   return idx if held is None else idx - held[0]
 
 
-def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held=None):
+class Routed(NamedTuple):
+  """A layer's routing of T tokens, drawn by ``route``: the router's float32 logits [T, E] (the auxiliary loss reads
+  them), the k combine weights [T, k] float32 and the chosen experts [T, k] int32."""
+
+  logits: jnp.ndarray
+  weights: jnp.ndarray
+  idx: jnp.ndarray
+
+
+def route(x, w_router, k, scoring="softmax", norm_topk=False, selection_bias=None, scale=1.0, n_group=1, topk_group=1, group_mode="none") -> Routed:
+  """The routing of tokens ``x`` [T, D] by ``w_router`` [D, E], under ``xot.moe_router`` wherever it is called from:
+  inside the two forms (the router reads the experts' input), or by a layer step ahead of its attention, whose normed
+  input ``x`` then is (``moe_ffn``'s ``routed``)."""
+  with jax.named_scope("xot.moe_router"):
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    return Routed(logits, *router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode))
+
+
+# The routed experts' gate nonlinearity, W_down(act(W_gate y) * W_up y): the one owner of the choice, for both forms
+# (the Mosaic body of ``moe_gate_up`` spells the same two in ``_gate_up_kernel``). Applied in float32.
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held=None, act="silu", routed=None):
   """One dispatch/compute/combine block over [T, D] tokens. Returns (out, aux, visited)."""
   T, D = x.shape
   E, E_held = w_router.shape[-1], w_gate.shape[0]
+  logits, weights, idx = routed or route(x, w_router, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
   with jax.named_scope("xot.moe_router"):
-    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
-    weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
     C = expert_capacity(T, k, E, capacity_factor)
     dispatch, combine = dispatch_combine_masks(_held_index(idx, held), weights, E_held, C)
     visited = jnp.sum(jnp.any(dispatch > 0, axis=(0, 2)), dtype=jnp.int32)  # (an expert's first assignment has rank 0 and is never dropped)
 
   with jax.named_scope("xot.moe_experts"):
     xin = jnp.einsum("td,tec->ecd", x, dispatch.astype(x.dtype))  # [E, C, D]
-    gated = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xin, w_gate).astype(jnp.float32)).astype(x.dtype)
+    gated = EXPERT_ACTS[act](jnp.einsum("ecd,edf->ecf", xin, w_gate).astype(jnp.float32)).astype(x.dtype)
     up = jnp.einsum("ecd,edf->ecf", xin, w_up)
     out = jnp.einsum("ecf,efd->ecd", gated * up, w_down)  # [E, C, D]
     out = jnp.einsum("ecd,tec->td", out.astype(jnp.float32), combine).astype(x.dtype)
@@ -237,7 +269,7 @@ def _own_rows(offsets_ref, group_ref, tile_ref, tm: int):
   return (rows >= offsets_ref[g]) & (rows < offsets_ref[g + 1])
 
 
-def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, wu_ref, *rest, tm: int, scaled: bool):
+def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, wu_ref, *rest, tm: int, scaled: bool, act: str = "silu"):
   del layer_ref  # the index maps read it
   (sg_ref, su_ref, out_ref) = rest if scaled else (None, None, *rest)
   x = x_ref[...]
@@ -245,7 +277,7 @@ def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, 
   up = jnp.dot(x, wu_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)
   if scaled:
     gate, up = gate * sg_ref[...], up * su_ref[...]
-  h = gate * jax.nn.sigmoid(gate) * up
+  h = (jnp.maximum(gate, 0.0) if act == "relu" else gate * jax.nn.sigmoid(gate)) * up  # ``EXPERT_ACTS``, in float32
   out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), h, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
@@ -287,7 +319,7 @@ def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: 
   )(jnp.asarray(layer, jnp.int32).reshape(1), offsets, group, tile, rows, *weights, *scales)
 
 
-def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None, scales=None, layer=0):
+def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None, scales=None, layer=0, act="silu", routed=None):
   """The grouped form over [T, D] tokens (nothing can drop). Expert leaves stacked, [L, E, D, F] / [L, E, F, D], with
   ``layer`` a (traced) scalar; ``scales`` their per-output-channel scales ([L, E, F], [L, E, F], [L, E, D]) where the
   leaves are int8 codes. Returns (out, aux, visited)."""
@@ -295,9 +327,7 @@ def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, s
   E, E_held, M = w_router.shape[-1], w_gate.shape[1], T * k
   tm = ROW_TILE if M >= ROW_TILE else -(-M // 16) * 16  # (a bfloat16 tile is 16 sublanes)
   Mp = -(-M // tm) * tm
-  with jax.named_scope("xot.moe_router"):
-    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
-    weights, idx = router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
+  logits, weights, idx = routed or route(x, w_router, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
   with jax.named_scope("xot.moe_experts"):  # the dispatch (the sort, the walk, the rows' gather), the products and the combine
     expert = _held_index(idx, held).reshape(M)
     expert = jnp.where((expert >= 0) & (expert < E_held), expert, E_held)  # an expert this shard does not hold sorts behind every held group
@@ -311,7 +341,7 @@ def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, s
     # (the layer's scales are cut out of their stack — kilobytes, where an expert leaf's layer is most of a GB — as
     # [E, 1, N]: a block is one expert's row)
     cut = tuple(jax.lax.dynamic_index_in_dim(s, layer, 0, keepdims=False).astype(jnp.float32)[:, None, :] for s in scales or ())
-    h = _grouped_product(_gate_up_kernel, "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, walk, tm, x.dtype)
+    h = _grouped_product(partial(_gate_up_kernel, act=act), "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, walk, tm, x.dtype)
     y = _grouped_product(_down_kernel, "moe_down", h, (w_down,), cut[2:], layer, walk, tm, jnp.float32)
     # Rows of an expert not held, and rows that pad the last tile, hold whatever the kernels found there: they are
     # taken out by ``where``, never multiplied by a zero.
@@ -341,8 +371,10 @@ def moe_ffn(
   held: tuple[int, int] | None = None,
   scales: tuple | None = None,
   layer=None,
+  act: str = "silu",
+  routed: Routed | None = None,
 ):
-  """Routed SwiGLU FFN over ``E`` experts; returns ([T, D] in x.dtype, the router's auxiliary loss, the number of
+  """Routed gated FFN (``act``: one of ``EXPERT_ACTS``; silu is SwiGLU) over ``E`` experts; returns ([T, D] in x.dtype, the router's auxiliary loss, the number of
   distinct held experts the rows chose: int32, summed over the blocks or pieces of a long run).
 
   ``held`` = (lo, hi): this shard's share of an expert-parallel layer. The
@@ -357,6 +389,9 @@ def moe_ffn(
   layer is cut out of it); ``scales`` their scale leaves (gate, up, down) where they are int8 codes. A long run goes
   in pieces of ``GROUPED_MAX_TOKENS``.
 
+  ``routed``: the tokens' routing where the layer step drew it elsewhere (``route``, from another tensor than ``x``:
+  the model's router reads its attention's input); None: drawn here from ``x``. A long run cuts it as it cuts ``x``.
+
   Without ``layer``: the block form over one layer's leaves, long runs in sequential blocks of ``chunk`` tokens so the
   dispatch/combine one-hots stay O(chunk²·E) instead of O(T²·E) — routing is per-token, so cutting is exact (with
   ``capacity_factor=None``, capacity per block = chunk, nothing ever drops).
@@ -367,15 +402,21 @@ def moe_ffn(
 
   if layer is not None:
     assert capacity_factor is None, "the grouped form drops nothing"
-    pieces = [x[at : at + GROUPED_MAX_TOKENS] for at in range(0, T, GROUPED_MAX_TOKENS)]
-    outs, auxs, visits = zip(*(_moe_ffn_grouped(piece, w_router, w_gate, w_up, w_down, *routing, *groups, scales, layer) for piece in pieces))
+    cuts = range(0, T, GROUPED_MAX_TOKENS)
+    pieces = [x[at : at + GROUPED_MAX_TOKENS] for at in cuts]
+    drawn = [routed and Routed(*(t[at : at + GROUPED_MAX_TOKENS] for t in routed)) for at in cuts]
+    outs, auxs, visits = zip(*(_moe_ffn_grouped(piece, w_router, w_gate, w_up, w_down, *routing, *groups, scales, layer, act, r) for piece, r in zip(pieces, drawn)))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     return out, sum(a * piece.shape[0] for a, piece in zip(auxs, pieces)) / T, sum(visits)
   if T <= chunk:
-    return _moe_ffn_block(x, w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups)
+    return _moe_ffn_block(x, w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups, act, routed)
   pad = (-T) % chunk
-  xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
-  out_c, aux_c, visited_c = jax.lax.map(lambda xs: _moe_ffn_block(xs, w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups), xp.reshape(-1, chunk, D))
+  blocks = lambda t, fill=0: (jnp.pad(t, ((0, pad), (0, 0)), constant_values=fill) if pad else t).reshape(-1, chunk, t.shape[-1])  # noqa: E731
+  if routed is None:
+    out_c, aux_c, visited_c = jax.lax.map(lambda xs: _moe_ffn_block(xs, w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups, act), blocks(x))
+  else:  # (a padding row chooses no expert at all — an id past the last: it must not take a real token's place in an expert's capacity)
+    cut = Routed(blocks(routed.logits), blocks(routed.weights), blocks(routed.idx, w_router.shape[-1]))
+    out_c, aux_c, visited_c = jax.lax.map(lambda xr: _moe_ffn_block(xr[0], w_router, w_gate, w_up, w_down, *routing, capacity_factor, *groups, act, xr[1]), (blocks(x), cut))
   return out_c.reshape(-1, D)[:T], jnp.mean(aux_c), jnp.sum(visited_c)  # padding rows bias aux slightly; acceptable for a regularizer
 
 

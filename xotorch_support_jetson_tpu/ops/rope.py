@@ -25,12 +25,12 @@ def rope_inv_freq(cfg: ModelConfig):
 
   For MLA models (deepseek) only the ``qk_rope_head_dim`` channel carries
   position; dense models rotate the whole head_dim. A model whose attention
-  kinds differ in shape (``cfg.attn_shapes``) gets one table a kind,
-  ``{AttnKind: table}``, made once a program; the layer step takes its own
-  (models/decoder.py ``_dense_qkv``).
+  kinds differ in shape (``cfg.attn_shapes``) gets one table a kind that has
+  a rope (``AttnKind.rope``), ``{AttnKind: table}``, made once a program; the
+  layer step takes its own (models/decoder.py ``_dense_qkv``).
   """
   if len(cfg.attn_shapes) > 1:
-    return {k: _inv_freq(int(cfg.head_dim * k.partial_rotary_factor), k.rope_theta, k.rope_scaling, cfg.max_seq_len) for k in dict.fromkeys(cfg.layer_attn) if k is not None}
+    return {k: _inv_freq(int(cfg.head_dim * k.partial_rotary_factor), k.rope_theta, k.rope_scaling, cfg.max_seq_len) for k in dict.fromkeys(cfg.layer_attn) if k is not None and k.rope}
   rot_dim = cfg.qk_rope_head_dim if cfg.is_mla else int(cfg.head_dim * cfg.partial_rotary_factor)
   return _inv_freq(rot_dim, cfg.rope_theta, cfg.rope_scaling, cfg.max_seq_len)
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
-from ..models.decoder import _dense_qkv, _mla_latents, _mla_w_kv_b, _mlp_block, _next_token, embed_tokens, head_logits
+from ..models.decoder import _dense_qkv, _mla_latents, _mla_w_kv_b, _mlp_block, _next_token, _route_ahead, embed_tokens, head_logits
 from ..ops.attention import NEG_INF, cap_and_mask_scores
 from ..ops.norm import rms_norm
 from ..ops.rope import rope_inv_freq
@@ -165,6 +165,7 @@ def _sp_layer_step(h, p, kv, positions, rank_offset, inv_freq, cfg: ModelConfig,
   if read_one is None:
     read_one = lambda leaf: leaf  # noqa: E731
   x = rms_norm(h, p["attn_norm"], cfg.norm_eps)
+  routed = _route_ahead(x, p, cfg)
   start = positions[:, 0]
   if "wkv_a" in p:
     q_nope, q_pe, c_kv, k_pe = _mla_latents(x, p, cfg, positions, inv_freq)
@@ -205,7 +206,7 @@ def _sp_layer_step(h, p, kv, positions, rank_offset, inv_freq, cfg: ModelConfig,
   if "post_attn_norm" in p:  # gemma2
     attn_out = rms_norm(attn_out, p["post_attn_norm"], cfg.norm_eps)
   h = h + attn_out
-  h, *_ = _mlp_block(h, p, cfg)
+  h, *_ = _mlp_block(h, p, cfg, routed)
   return h, kv
 
 
