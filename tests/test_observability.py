@@ -707,6 +707,7 @@ EXPECTED_METRIC_NAMES = {
   # Mixed prefill+decode ticks (ISSUE 14)
   "xot_tpu_sched_tick_prefill_tokens_total",
   "xot_tpu_sched_ticks_total",  # one per program dispatch of the scheduler loop (ISSUE 24)
+  "xot_tpu_sched_dispatches_total",  # {queue}: a dispatch enqueued behind one not yet read back, or onto an empty queue (ISSUE 51)
   "xot_tpu_sched_phase_seconds_total",  # {phase}: host seconds of a tick by phase (ISSUE 24)
   "xot_tpu_sched_wall_seconds_total",  # {kind}: the loop's wall time by what it waits for (ISSUE 41)
   # Disaggregated prefill/decode (ISSUE 10)

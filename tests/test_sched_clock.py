@@ -47,6 +47,18 @@ SEQUENCES = {
     [1.0, 2.0, 0.75],
     {"decode": 1, "mixed": 1, "spec": 1},
   ),
+  "group-behind-a-chunk": (  # ISSUE 51: a prefill group enqueued behind chunk N, N settled under it, chunk N+1 enqueued behind the group, the group settled under N+1 — the host never holds the clock
+    [("idle_end", None, 0.0), ("dispatched", "decode", 0.25), ("dispatched", "prefill", 0.5), ("ready", None, 0.75), ("dispatched", "decode", 0.5), ("ready", None, 1.5), ("dispatched", "decode", 0.25), ("ready", None, 1.0), ("ready", None, 2.0)],
+    {"host": 0.25, "decode": 4.5, "prefill": 2.0},
+    [1.25, 2.0, 1.25, 2.0],
+    {"decode": 3, "prefill": 1},
+  ),
+  "withdrawn-group": (  # a group whose enqueue raised leaves the queue: behind a chunk the chunk keeps the clock, alone the host takes it back
+    [("idle_end", None, 0.0), ("dispatched", "decode", 0.5), ("dispatched", "prefill", 0.25), ("withdrawn", None, 0.25), ("ready", None, 1.0), ("dispatched", "prefill", 0.5), ("withdrawn", None, 0.25), ("dispatched", "decode", 0.25), ("ready", None, 1.0)],
+    {"host": 1.25, "decode": 2.5, "prefill": 0.25},
+    [1.5, 1.0],
+    {"decode": 2, "prefill": 1},
+  ),
   "failed-prefill": (  # the failure path closes the interval as a readback does; a reset drops what was in flight
     [("idle_end", None, 1.0), ("dispatched", "prefill", 0.5), ("ready", None, 3.0), ("dispatched", "decode", 0.5), ("dispatched", "decode", 0.5), ("reset", None, 0.25), ("idle_end", None, 4.0), ("dispatched", "prefill", 0.125), ("ready", None, 1.0)],
     {"idle": 5.0, "host": 1.125, "prefill": 4.0, "decode": 0.75},
@@ -85,6 +97,26 @@ def test_kinds_partition_the_wall_time(name):
   assert {k: n for k, n in snap["intervals"].items() if k in DEVICE_KINDS and n} == want_intervals
   for k in KINDS:  # /metrics carries the same sums
     assert metrics.counter_value("sched_wall_seconds_total", labels={"kind": k}) - before[k] == pytest.approx(snap["seconds"][k])
+
+
+def test_expected_is_what_the_kind_last_took_from_when_the_oldest_dispatch_got_the_clock():
+  now = _Time()
+  clock = SchedClock(now=now)
+  clock.idle_end()
+  assert clock.expected() is None  # nothing in flight
+  clock.dispatched("decode")
+  assert clock.expected() is None  # no decode chunk read back yet: no estimate
+  now.t += 2.0
+  clock.dispatched("prefill")  # behind the chunk
+  assert clock.ready() == 2.0 and clock.last == {"decode": 2.0}
+  assert clock.expected() is None  # the group has the clock now, and no group has been read back
+  now.t += 0.5
+  clock.dispatched("decode")
+  assert clock.ready() == 0.5
+  now.t += 0.5
+  assert clock.expected() == pytest.approx((1.5, 2.0))  # the chunk got the clock at the group's readback; its kind last took 2.0
+  now.t += 2.5
+  assert clock.expected() == pytest.approx((-1.0, 2.0))  # overdue: the estimate is an estimate
 
 
 def test_snapshot_carries_phases_ticks_and_steps():

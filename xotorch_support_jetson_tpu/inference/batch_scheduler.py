@@ -36,15 +36,43 @@ shapes:
   against dispatch-time positions, so a row always holds one extra chunk of
   page headroom and the speculative chunk can never overflow a block table.
   Membership changes (admission prefills, slot frees, preemption) happen
-  only at dispatch boundaries, and the pipeline DRAINS whenever a waiting
-  request could actually admit (a slot is free, or a chunked prefill is
-  mid-flight) so admissions (and TTFT) never wait behind a speculative
-  chunk — while a backlog with zero free slots keeps the pipeline chaining
-  at saturation. Greedy traffic is token-identical to the synchronous loop
+  only at dispatch boundaries, and an ADMISSION DOES NOT DRAIN the pipeline
+  (ISSUE 51): at a boundary with chunk N in flight the admission pass runs
+  while N computes — late in N's time (``_wait_late_into``), so that it
+  sees what arrived during N as a pass at N's end did; everything it reads
+  is host state that is exact while N flies (free slots, the free list,
+  the queue) — and its prefill group G is staged and enqueued BEHIND N. The device executes one stream in order:
+  G's pool operand is N's result, a device future, and its pages come off
+  the free list, which holds no page whose garbage writes by N anyone can
+  read (positions mask them, and G runs after N). N is then settled UNDER
+  G; chunk N+1 is planned with G's rows already in their slots and
+  enqueued behind G, their first tokens merged into its chain token ON THE
+  DEVICE (``models/decoder.py merge_first_tokens``, enqueued with the
+  group), so it waits for no readback either; and G is settled (first-token
+  emit, EOS / ``max_tokens`` 1 / raced cancel) UNDER N+1. A row that ends
+  at its first token is in N+1 all the same and is dropped on read like any
+  overrun; a ``max_tokens`` of 1 is known at the plan and left inactive.
+  Whatever N's settle frees is offered in a second pass before N+1 is
+  planned, so no row joins a chunk later than under a drain at every
+  boundary. A waiter whose prompt will be sliced into mixed ticks only
+  claims its slot and pages in the pass and forces nothing. N is settled
+  FIRST only where what comes next needs its settled state
+  (``_needs_settled_state``, ``_group_settles_first`` and the checks after
+  the plan in ``_run``): a QoS preemption, a graceful drain or migration
+  and a disaggregation hand-off (they extract rows a chunk in flight
+  holds), a parked waiter whose demand only N's finishing rows can cover
+  (the pass behind N parks it again; N is settled and the pass repeated), a
+  cancel on a prompt mid-prefill, a spec ↔ plain switch and n-gram rows
+  (host proposals key on settled history), and
+  ``XOT_TPU_SCHED_LOOKAHEAD=0``, the strictly synchronous tick that stays
+  the reference schedule. A backlog with zero free slots chains chunk to
+  chunk as before. ``sched_dispatches_total{queue="behind"|"empty"}`` says
+  how often a dispatch rode behind one not yet read back.
+  Greedy traffic is token-identical to the synchronous loop
   by construction (same compiled programs, same sampling; only the
   host/device schedule changes), and each SAMPLED request's stream is
-  identical too — the key-split order is one split per dispatched chunk on
-  the event-loop thread, and a speculative chunk's extra split happens only
+  identical too — the key-split order is one split per dispatch on
+  the event-loop thread, in the order N, G, N+1, and a speculative chunk's extra split happens only
   AFTER every emitted token of the finishing request. The one honest caveat:
   that extra split shifts the engine's key chain, so sampled requests
   arriving AFTER an EOS-triggered speculative chunk draw different (equally
@@ -223,6 +251,9 @@ class _Slot:
   # it yields the request's realized mean inter-token latency for goodput's
   # within-SLO check.
   t_first: float = 0.0
+  # The row has its place and its prefill group is enqueued, but its first token is still on the device (ISSUE 51):
+  # the group's settle reads it, emits it and resolves what it ends (``_confirm_admission``).
+  first_pending: bool = False
 
 
 @dataclass
@@ -276,9 +307,10 @@ class _Chunk:
   proposers: list | None = None  # [n_slots] "model"|"ngram"|"plain"
   n_prop: object = None  # device [B] int32 — tokens drafted per row
   # Mixed tick (ISSUE 14): the admission whose prefill slice rode this
-  # dispatch (its ``prefix_len`` advances to ``mixed_end`` at the settle —
-  # never before, so a cancel/teardown while the chunk flies releases the
-  # pages against the CONFIRMED prefix).
+  # dispatch. Its ``prefix_len`` advances to ``mixed_end`` at the settle, or
+  # at the boundary before it when the loop looks for what to enqueue
+  # behind this chunk (``_run``; ISSUE 51): the end is the host's own
+  # number, and whatever reads the slice's pages runs after this chunk.
   mixed_ready: object = None  # _Ready | None
   mixed_start: int = 0
   mixed_end: int = 0
@@ -288,6 +320,21 @@ class _Chunk:
   # No row of the dispatch's ``temps`` operand was positive: the program's own predicate (models/decoder.py
   # ``_next_token_batched``) was false at every step and no draw was taken (``decode_draw_skipped_chunks_total``).
   draw_skipped: bool = False
+
+
+@dataclass
+class _Group:
+  """One dispatched prefill group, possibly still executing on the device: what its settle needs. The rows that
+  sample were given their slots at the enqueue (``installed``), so that the next decode chunk could be planned and
+  enqueued behind the group; their first tokens are read back here, after it."""
+
+  members: list  # [_Ready]
+  firsts: object  # device [K] int32: the sampled first tokens (garbage for a row of an intermediate chunk)
+  tick: int
+  installed: dict  # row -> _Slot, first token pending
+  # Settled before the next chunk is planned, not under it: a member is handed to a decode node at its first token
+  # (the hand-off extracts the row, which no chunk in flight may hold) or an n-gram index must see that token first.
+  sync: bool = False
 
 
 # A server of more than ``GROUP_SLOTS_WHOLE`` slots holds a prefill group to ``GROUP_ROWS`` rows, so that its prefill
@@ -450,6 +497,11 @@ class BatchedServer:
     # Ticks issued: one per program dispatch of the loop (plain, mixed, spec
     # or prefill group) — ``sched_ticks_total``, and the number its spans carry.
     self._tick = 0
+    # Prefill groups enqueued and not yet settled, oldest first (ISSUE 51); empty at the top of every loop pass.
+    self._pending: list[_Group] = []
+    # The next decode chunk's input token with the pending groups' first tokens already merged in, on the device
+    # (``merge_first_tokens``); None: the chunk takes the in-flight chunk's handle or the host's tokens as it always did.
+    self._chain = None
     # Graceful drain (ISSUE 8): once draining, submit() refuses new work
     # (typed "draining" 429) and the loop's next dispatch boundary offers
     # every resident row to the migration callback exactly once; rows the
@@ -554,7 +606,7 @@ class BatchedServer:
       return None
     best = None
     for i, s in enumerate(self.slots):
-      if s is None or s.finished or s.cancelled:
+      if s is None or s.finished or s.cancelled or s.first_pending:  # (a row whose first token is still on the device cannot be extracted)
         continue
       if s.pos + 1 >= self.max_seq:
         # The row is at the context window: it finishes imminently (freeing
@@ -755,8 +807,10 @@ class BatchedServer:
 
   def _tier_write(self, pages: list[int], data: dict) -> None:
     """Restore-side device write: scatter host page data into freshly
-    allocated pages. Donates the pool leaves — runs only at admission
-    boundaries with the pipeline drained, exactly like prefill."""
+    allocated pages. Donates the pool leaves — runs in the admission pass,
+    on the loop thread while the executor is idle; with a chunk in flight
+    the pool is that chunk's result and the write queues behind it, exactly
+    like the prefill group that follows."""
     if self.cache is None:
       raise RuntimeError("page pool torn down under a restore")
     self.cache = self.ops.write_pages(self.cache, pages, data)
@@ -1190,11 +1244,13 @@ class BatchedServer:
     return rows
 
   def _free_slot(self, taken: frozenset | set = frozenset()) -> int | None:
-    # Mid-chunked-prefill rows are protected by ``taken``: _admit_pending
-    # swaps _prefilling out and seeds taken with those rows before any
-    # _free_slot call.
+    """The first row no request holds. A prompt mid-chunked-prefill holds its row without a slot: while it waits
+    in ``_prefilling``, and while its chunk rides a pending group (``_collect_admissions`` swaps ``_prefilling``
+    out and names those rows in ``taken``)."""
+    held = {r.row for r in self._prefilling}  # (both lists are empty at most boundaries)
+    held.update(r.row for g in self._pending for r in g.members if r.chunk_end)
     for i, s in enumerate(self.slots):
-      if s is None and i not in taken:
+      if s is None and i not in taken and i not in held:
         return i
     return None
 
@@ -1365,13 +1421,15 @@ class BatchedServer:
       attrs["tenant"] = req.qos.tenant
     tracer.stage(req.request_id, "admitted", attrs)
 
-  async def _admit_pending(self, woken: _Request | None = None) -> None:
+  async def _admit_pending(self, woken: _Request | None = None, behind: _Chunk | None = None) -> None:
+    """One admission pass: what it admits is staged and ENQUEUED (``_pending``), not awaited. ``behind`` is the decode
+    chunk in flight, if any: the groups then queue behind it on the device."""
     with self._phase("admit"):
-      ready = self._collect_admissions(woken)
+      ready = self._collect_admissions(woken, inflight=behind is not None)
     if ready:
-      await self._dispatch(ready)
+      await self._dispatch(ready, behind)
 
-  def _collect_admissions(self, woken: _Request | None) -> list[_Ready]:
+  def _collect_admissions(self, woken: _Request | None, inflight: bool = False) -> list[_Ready]:
     """Collect every admissible request — parked (page-starved) first, in
     arrival order, then the queue — for ``_admit_pending`` to prefill in ONE batched dispatch
     (more only when the scatter-clamp grouping splits; see ``_dispatch``).
@@ -1379,7 +1437,13 @@ class BatchedServer:
     admits first. Every still-unmet parked request's page demand accumulates
     into ``reserve``: younger requests may only admit out of the surplus
     beyond it, so freed pages accumulate toward the parked requests instead
-    of being consumed by later small prompts."""
+    of being consumed by later small prompts.
+
+    ``inflight``: a decode chunk is on the device (ISSUE 51). Everything read here is host state that is exact all
+    the same — free slots, the free list (the chunk's own growth was allocated at its dispatch), the queue — and
+    whatever this pass sends to the device (a tier restore, the group) queues behind the chunk. The one thing it
+    may not do is preempt: a victim's row is in that chunk. The loop sees the waiter that wants one
+    (``_preempt_possible``), settles the chunk and passes again."""
     self._admit_pass += 1  # one boundary pass = one drain-cadence observation
     ready: list[_Ready] = []
     taken: set[int] = set()
@@ -1419,7 +1483,7 @@ class BatchedServer:
       if r is not None:
         ready.append(r)
         taken.add(row)
-    if self.qos is not None and not self.queue.empty() and self._free_slot(taken) is None:
+    if not inflight and self.qos is not None and not self.queue.empty() and self._free_slot(taken) is None:
       # Overload policy: a waiting request that outranks a resident row
       # preempts it (the row re-enqueues and resumes token-identically)
       # instead of queueing behind it — batch rows yield before interactive
@@ -1511,17 +1575,16 @@ class BatchedServer:
         groups.append([r])
     return groups
 
-  async def _dispatch(self, ready: list[_Ready]) -> None:
-    """Prefill K prepared admissions in one device dispatch per group and
-    emit their first tokens. All-or-nothing per group: a device failure
-    fails every request in the group, releases their pages, and the pool
-    keeps serving."""
+  async def _dispatch(self, ready: list[_Ready], behind: _Chunk | None = None) -> None:
+    """Stage K prepared admissions and ENQUEUE their prefill, one device dispatch per group; the first tokens are
+    read back, emitted and resolved at each group's settle (``_settle_groups``). All-or-nothing per group: a
+    device failure fails every request in the group, releases their pages, and the pool keeps serving."""
     for r in ready:
       self._chunk_ready(r)  # cap long prompts to one prefill chunk per tick
       self._admitting.add(r.req.request_id)
     try:
       for group in self._dispatch_groups(ready):
-        await self._dispatch_group(group, all_rows={r.row for r in ready})
+        await self._enqueue_group(group, {r.row for r in ready}, behind)
     except BaseException as e:  # loop teardown mid-dispatch (CancelledError):
       # device errors are handled per group — only make sure no admitted
       # request's future leaks unresolved before the task dies. Their
@@ -1554,10 +1617,14 @@ class BatchedServer:
       kpad *= 2
     return max(min(kpad, self.n_slots), K)
 
-  def _stage_group(self, group: list[_Ready], all_rows: set[int], tick: int):
+  def _stage_group(self, group: list[_Ready], all_rows: set[int], tick: int, chain_base=None):
     """Host operands of one prefill group and the ``run()`` closure that
-    stages them on the executor thread, dispatches the program and reads the
-    first tokens back."""
+    stages them on the executor thread and ENQUEUES the program: it returns
+    the device handle of the first tokens without waiting for them
+    (``_settle_group`` reads them back). ``chain_base`` not None: the closure
+    also writes those tokens into the next decode chunk's chain token, on the
+    device (``self._chain``; ``chain_base`` is what the chain starts from when
+    no earlier group of this boundary has started it)."""
     eng = self.engine
     K = len(group)
     S_pad = max(r.pad_to for r in group)
@@ -1582,6 +1649,17 @@ class BatchedServer:
       prompt_lens[i] = end
       temps[i] = r.req.temp
       top_ks[i] = min(r.req.top_k, self.k_max)
+    # Each sampling row's slot, for the merge of its first token into the chain token; the slot past the last
+    # (dropped) for padding and for a row of an intermediate chunk, which samples nothing.
+    merge_rows = np.full((n_rows,), self.n_slots, dtype=np.int32)
+    merge_rows[:K] = [self.n_slots if r.chunk_end else r.row for r in group]
+
+    def merge(firsts):
+      if chain_base is not None:
+        from ..models.decoder import merge_first_tokens
+
+        self._chain = merge_first_tokens(jnp.asarray(self._chain if self._chain is not None else chain_base), firsts, merge_rows)
+      return firsts
 
     if self.paged:
       # Truncate the gathered page window to this dispatch's span: the
@@ -1643,8 +1721,7 @@ class BatchedServer:
             if draft_job is not None:
               draft_job()
             firsts = sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max)
-        with self._phase("readback", tick=tick):  # the first tokens: waits for the prefill program
-          return np.asarray(firsts)
+          return merge(firsts)
 
     else:
       rows = np.asarray([r.row for r in group] + spare[: n_rows - K], dtype=np.int32)
@@ -1669,8 +1746,7 @@ class BatchedServer:
             if draft_job is not None:
               draft_job()
             firsts = sample_rows(last, sub, jnp.asarray(temps), jnp.asarray(top_ks), self.k_max)
-        with self._phase("readback", tick=tick):  # the first tokens: waits for the prefill program
-          return np.asarray(firsts)
+          return merge(firsts)
 
     # Stage marks go down BEFORE the dispatch so the timeline's
     # prefill_chunk duration covers the device work, not the gap after it.
@@ -1679,35 +1755,102 @@ class BatchedServer:
       tracer.stage(r.req.request_id, "prefill_chunk", {"tokens": end - r.prefix_len, "batched_with": K - 1})
     return run
 
-  async def _dispatch_group(self, group: list[_Ready], all_rows: set[int]) -> None:
+  def _note_dispatch(self, kind: str) -> None:
+    """One program handed to the device: onto an empty queue, or BEHIND one the loop has not read back yet
+    (``sched_dispatches_total{queue}``; the ``behind`` share is how often the host's work rode under the device's).
+    The clock's interval of the host ends here unless it is chained."""
+    metrics.inc("sched_dispatches_total", labels={"queue": "behind" if self.clock.queued else "empty"})
+    self.clock.dispatched(kind)
+
+  def _group_settles_first(self, group: list[_Ready]) -> bool:
+    """Must this group be settled BEFORE the next chunk is planned (and not under it)? Without the lookahead
+    always: the synchronous tick is the reference schedule. With it, when a member's first token is needed on the
+    host at once: a request placed for remote decode is extracted at its first token (no chunk may hold the row), and
+    a server that proposes from n-gram indexes builds the row's index over that token before the plan asks it."""
+    if not self.lookahead or "ngram" in self.spec_proposers:
+      return True
+    return self.kv_handoff is not None and self.paged and any(r.req.disagg_target for r in group)
+
+  async def _enqueue_group(self, group: list[_Ready], all_rows: set[int], behind: _Chunk | None) -> None:
+    """Stage one prefill group and enqueue it — behind the decode chunk in flight, if there is one: its pool operand
+    is that chunk's result, a device future, so the runtime orders them. Nothing is awaited but the enqueue. The rows
+    that sample take their slots NOW (``_install_admission``), so the next chunk's plan holds them."""
     eng = self.engine
     tick = self._next_tick()
+    sync = self._group_settles_first(group)
+    chain_base = None if sync else behind.next_tok if behind is not None else self._h_tokens
     with self._phase("stage", tick=tick, rows=len(group)):
-      run = self._stage_group(group, all_rows, tick)
-    self.clock.dispatched("prefill")
+      run = self._stage_group(group, all_rows, tick, chain_base)
+    self._note_dispatch("prefill")
     try:
       firsts = await asyncio.get_event_loop().run_in_executor(
         eng.executor, self._attributed(run, [r.req.request_id for r in group], tick)
       )
     except Exception as e:  # noqa: BLE001
-      for r in group:
-        self._release_ready_pages(r)
-        if not r.req.future.done():
-          r.req.future.set_exception(e)
-        self._cancelled_ids.discard(r.req.request_id)
+      self.clock.withdrawn()  # it never reached the device: what is in flight keeps the clock
+      self._fail_group(group, {}, e)
       if getattr(self.ops, "prefill_donates_pool", False):
         raise  # the pool went into the failed call: the loop drops it and fails the rest, as after a failed decode chunk
       return
+    installed = {r.row: self._install_admission(r) for r in group if not r.chunk_end}
+    self._pending.append(_Group(members=group, firsts=firsts, tick=tick, installed=installed, sync=sync))
+
+  def _fail_group(self, members: list[_Ready], installed: dict, e: Exception) -> None:
+    """Fail every request of a prefill group and give its pages back (``installed``: row -> slot of the members
+    that had taken their slots)."""
+    for r in members:
+      self._admitting.discard(r.req.request_id)
+      slot = installed.get(r.row)
+      if slot is None:
+        self._release_ready_pages(r)
+      else:  # the slot owns the pages since the install (the next chunk's plan may have grown them): all freed, none donated
+        self._lora_unpin(r.req)
+        for p in slot.shared_pages:
+          self.allocator.release(p)
+        if slot.pages:
+          self.allocator.free(slot.pages)
+        slot.shared_pages, slot.pages, slot.finished = [], [], True
+        if self.slots[r.row] is slot:
+          self.slots[r.row] = None
+          self._clear_row(r.row)
+      if not r.req.future.done():
+        r.req.future.set_exception(e)
+      self._cancelled_ids.discard(r.req.request_id)
+
+  async def _settle_groups(self) -> None:
+    """Read back and settle every pending group, oldest first (the device's order, and the clock's)."""
+    self._chain = None  # whoever needed it has taken it (``_stage_decode``); from here the host's tokens hold the firsts
+    while self._pending:
+      await self._settle_group(self._pending[0])
+      self._pending.pop(0)  # only now: a teardown inside the await still finds the group (``_fail_all``)
+
+  async def _settle_group(self, g: _Group) -> None:
+    """One prefill group's first tokens read back, and the host half of its admission: the emit of each first
+    token, what that token ends, the next chunk of a long prompt. Under the lookahead this runs while the decode chunk
+    enqueued behind the group computes; a row that ended at its first token is in that chunk all the same, and is
+    dropped on read at the chunk's settle like any overrun."""
+
+    def fetch():
+      with self._phase("readback", tick=g.tick):  # the first tokens: waits for the prefill program
+        return np.asarray(g.firsts)
+
+    try:
+      firsts = await asyncio.get_event_loop().run_in_executor(self.engine.executor, fetch)
+    except Exception as e:  # noqa: BLE001
+      self._fail_group(g.members, g.installed, e)
+      if getattr(self.ops, "prefill_donates_pool", False):
+        raise
+      return
     finally:
-      # Device idle from here until the next dispatch — closed on the failure
+      # Device idle from here until the next dispatch (unless one is queued behind) — closed on the failure
       # path too, or a failed prefill's interval would run on into the host's.
       prefill_dt = self.clock.ready()
-      for r in group:
+      for r in g.members:
         self._admitting.discard(r.req.request_id)
-    with self._phase("settle", tick=tick):
+    with self._phase("settle", tick=g.tick):
       metrics.observe_hist("prefill_chunk_seconds", prefill_dt)
       metrics.inc("prefill_chunks_total")
-      for i, r in enumerate(group):
+      for i, r in enumerate(g.members):
         if r.chunk_end:  # intermediate chunk: advance and re-queue; no sample
           r.prefix_len = r.chunk_end
           if r.req.disagg_target and self.kv_stream is not None and self.paged:
@@ -1718,7 +1861,7 @@ class BatchedServer:
             self._disagg_stream_chunk(r)
           self._prefilling.append(r)
           continue
-        self._finish_admission(r, int(firsts[i]))
+        self._confirm_admission(r.row, g.installed[r.row], int(firsts[i]))
 
   def _draft_prefill_job(self, group: list[_Ready]):
     """Host-side prep of the draft prefill that rides the SAME executor
@@ -1748,40 +1891,19 @@ class BatchedServer:
 
     return job
 
-  def _finish_admission(self, r: _Ready, first: int) -> None:
+  def _install_admission(self, r: _Ready) -> _Slot:
+    """Give an admission whose prefill group is enqueued its slot: everything the next chunk's plan and dispatch
+    read of a row but its first token, which is still on the device and reaches that chunk there
+    (``merge_first_tokens``). From here the slot owns the pages."""
     req = r.req
     slot = _Slot(
-      req=req, pos=int(req.tokens.shape[0]), generated=1, last_token=first,
+      req=req, pos=int(req.tokens.shape[0]), generated=1, first_pending=True,
       shared_pages=r.shared_pages, pages=list(r.new_pages), chain_keys=r.chain_keys,
     )
     if req.carry_tokens:
       # Resumed after a QoS preemption: the finish paths report carry + new
       # (``generated``/``max_tokens`` already net out the carried span).
       slot.out_tokens.extend(req.carry_tokens)
-    slot.out_tokens.append(first)
-    slot.t_first = time.perf_counter()
-    if req.t_submit:
-      ttft = slot.t_first - req.t_submit
-      metrics.observe_hist("ttft_seconds", ttft)
-      req.slo_ttft_s = ttft
-      # Per-class TTFT (ISSUE 9): the SLO engine's burn-rate windows need
-      # the class dimension the unlabeled histogram can't carry; a separate
-      # family keeps the existing exposition and bench deltas untouched.
-      slo.observe_ttft(self._slo_class(req), ttft)
-    cancelled = req.request_id in self._cancelled_ids  # raced during prefill
-    finished = cancelled or first in req.eos_ids or slot.generated >= req.max_tokens
-    slot.finished = finished
-    tracer.stage(req.request_id, "decode", {"first_token": int(first), "clock": self.clock.snapshot()})
-    req.emit(req.request_id, [] if cancelled else [first], finished)
-    if not cancelled:
-      slo.note_tokens(self._slo_class(req), self._slo_tenant(req), 1)
-    if finished:
-      self._cancelled_ids.discard(req.request_id)
-      self._release_pages(slot)
-      self._note_complete(slot)
-      if not req.future.done():
-        req.future.set_result(slot.out_tokens)
-      return
     if self.spec and req.temp <= 0.0:
       # Starting depth by QoS class (module docstring): interactive and
       # standard rows open at full depth — an accepted run directly cuts
@@ -1800,15 +1922,9 @@ class BatchedServer:
       else:
         slot.spec_proposer = "ngram"
         slot.spec_gamma = max(self.spec_ngram_max // 2, 1) if cls == "batch" else self.spec_ngram_max
-      if "ngram" in self.spec_proposers:
-        from .ngram import NgramIndex
-
-        slot.ngram = NgramIndex(self.spec_ngram_n)
-        slot.ngram.extend(req.tokens)
-        slot.ngram.extend([first])
     self.slots[r.row] = slot
     self._h_occupied[r.row] = True
-    self._h_tokens[r.row, 0] = first
+    self._h_tokens[r.row, 0] = 0  # until the settle: the chunk behind the group takes the token from the device
     self._h_positions[r.row] = slot.pos
     self._h_temps[r.row] = req.temp
     self._h_top_ks[r.row] = min(req.top_k, self.k_max)
@@ -1819,12 +1935,55 @@ class BatchedServer:
       self.block_tables[r.row, :] = 0
       n = len(slot.shared_pages) + len(slot.pages)
       self.block_tables[r.row, :n] = slot.shared_pages + slot.pages
+    return slot
+
+  def _confirm_admission(self, row: int, slot: _Slot, first: int) -> None:
+    """The first token is on the host: emit it and resolve what it ends (EOS, a ``max_tokens`` of 1, a raced
+    cancel). A row that ends here may already be in the chunk enqueued behind its group; that chunk's settle drops it
+    on read, and its pages go back only to dispatches the device runs after that chunk."""
+    req = slot.req
+    slot.first_pending = False
+    slot.last_token = first
+    slot.out_tokens.append(first)
+    slot.t_first = time.perf_counter()
+    if req.t_submit:
+      ttft = slot.t_first - req.t_submit
+      metrics.observe_hist("ttft_seconds", ttft)
+      req.slo_ttft_s = ttft
+      # Per-class TTFT (ISSUE 9): the SLO engine's burn-rate windows need
+      # the class dimension the unlabeled histogram can't carry; a separate
+      # family keeps the existing exposition and bench deltas untouched.
+      slo.observe_ttft(self._slo_class(req), ttft)
+    cancelled = slot.cancelled or req.request_id in self._cancelled_ids  # raced during prefill
+    finished = cancelled or first in req.eos_ids or slot.generated >= req.max_tokens
+    slot.finished = finished
+    tracer.stage(req.request_id, "decode", {"first_token": int(first), "clock": self.clock.snapshot()})
+    req.emit(req.request_id, [] if cancelled else [first], finished)
+    if not cancelled:
+      slo.note_tokens(self._slo_class(req), self._slo_tenant(req), 1)
+    if finished:
+      self._cancelled_ids.discard(req.request_id)
+      self._release_pages(slot)
+      self._note_complete(slot)
+      if not req.future.done():
+        req.future.set_result(slot.out_tokens)
+      if self.slots[row] is slot:
+        self.slots[row] = None
+        self._clear_row(row)
+      return
+    self._h_tokens[row, 0] = first
+    if self.spec and req.temp <= 0.0 and "ngram" in self.spec_proposers:
+      from .ngram import NgramIndex
+
+      slot.ngram = NgramIndex(self.spec_ngram_n)
+      slot.ngram.extend(req.tokens)
+      slot.ngram.extend([first])
     if req.disagg_target and self.kv_handoff is not None and self.paged:
       # Disaggregated decode (ISSUE 10): prefill is done and the first
       # token is sampled — hand the row to its decode node instead of
-      # decoding here. Runs at an admission boundary (pipeline drained), so
-      # extraction is exactly the drain-migration contract.
-      self._disagg_handoff(r.row)
+      # decoding here. Runs with nothing in flight (``_group_settles_first``),
+      # so extraction is exactly the drain-migration contract.
+      self._disagg_handoff(row)
 
   # ------------------------------------------------- disaggregation (ISSUE 10)
 
@@ -2257,6 +2416,9 @@ class BatchedServer:
       rows.append((i, s))
       if spec is not None and not inflight.spec and spec[i] and generated[i] >= self._h_max_tokens[i]:
         active[i] = False  # finishes at the in-flight settle; drop-on-read covers the rest
+      elif s.first_pending and generated[i] >= self._h_max_tokens[i]:
+        active[i] = False  # a ``max_tokens`` of 1: its first token, still on the device, is its last; its group's settle ends it
+        finishing += 1
       elif s.cancelled or int(positions[i]) + self.chunk >= self.max_seq:
         active[i] = False
         finishing += 1
@@ -2397,7 +2559,11 @@ class BatchedServer:
     # dispatch (pipeline empty) uses the persistent host arrays. The key
     # split happens HERE on the event-loop thread — the executor thread
     # never touches the engine's PRNG chain.
+    # Behind a prefill group still in flight it is the chain token that group's first tokens were merged
+    # into, on the device (``_stage_group``): the chunk waits for no readback of theirs either.
     tokens = inflight.next_tok if inflight is not None else self._h_tokens
+    if self._chain is not None:
+      tokens, self._chain = self._chain, None
     positions, active = plan.positions, plan.active
     if spec and inflight is not None:
       positions = inflight.pos_dev  # true device positions; plan's copy is worst-case
@@ -2530,7 +2696,7 @@ class BatchedServer:
       metrics.inc("scheduler_page_starved_total", len(plan.starved))
     # The host's interval ends here unless this chunk is chained: the device
     # then already runs its predecessor and this one queues behind it.
-    self.clock.dispatched("spec" if spec else "mixed" if mixed_r is not None else "decode")
+    self._note_dispatch("spec" if spec else "mixed" if mixed_r is not None else "decode")
     rids = [s.req.request_id for i, s in plan.rows if plan.active[i]]
     if mixed_r is not None:
       rids.append(mixed_r.req.request_id)
@@ -2760,6 +2926,45 @@ class BatchedServer:
         self._clear_row(i)
     self._update_gauges()
 
+  def _preempt_possible(self) -> bool:
+    """A waiting request outranks a resident row and no slot is free: the next admission pass would preempt."""
+    return self.qos is not None and self._free_slot() is None and not self.queue.empty() and self._preempt_victim_for(self.queue.peek()) is not None
+
+  def _admission_waiting(self, mixed_budget: int | None = None) -> bool:
+    """Would an admission pass at this boundary have anything to do? A waiter and a free slot (a parked one only
+    when page availability has grown since the last pass looked: ``_parked_admissible``), a waiter that may
+    preempt, or a chunked prefill whose next dispatch goes through the admission path (``_prefill_boundary_needed``)."""
+    if self._free_slot() is not None and (not self.queue.empty() or self._parked_admissible()):
+      return True
+    return self._preempt_possible() or self._prefill_boundary_needed(mixed_budget)
+
+  async def _wait_late_into(self, chunk: _Chunk) -> None:
+    """Hold the admission pass that runs behind ``chunk`` until LATE in the chunk's device time: a pass at the
+    boundary's start would see only what arrived while the last group was settled, and a burst of arrivals still
+    landing (k callers at once, each a handler on this loop's thread) would be cut in two — two small groups where a
+    pass at the chunk's end made one, and prefill shapes the warm-up never reached. So the loop sleeps — the
+    thread serves the arriving requests meanwhile — until a quarter of the chunk's expected time is left
+    (``SchedClock.expected``: what its kind last took, from when it got the device), which is several times what the
+    pass and its staging cost the host, and no longer than the chunk runs: it wakes every 5 ms at most and stops
+    when the chunk's tokens are ready. No estimate yet (the first chunk of a kind): no wait."""
+    ready = getattr(chunk.toks, "is_ready", None)
+    while (est := self.clock.expected()) is not None and est[0] > 0.25 * est[1] and not (ready is not None and ready()):
+      await asyncio.sleep(min(est[0] - 0.25 * est[1], 0.005))
+
+  def _needs_settled_state(self) -> bool:
+    """With a chunk in flight: does what comes next need that chunk SETTLED first? (All of it is host state the loop
+    can read at the boundary.) The strictly synchronous tick (``XOT_TPU_SCHED_LOOKAHEAD=0``, the reference
+    schedule); a graceful drain and a QoS preemption, which extract resident rows the chunk still holds; a cancel
+    that landed on a prompt mid-prefill, whose pages a mixed chunk in flight may be writing; and a chunked prefill
+    left with no decode row resident, whose next chunk starts from a prefix the chunk in flight may still advance."""
+    if not self.lookahead or self._drain_pending() or self._preempt_possible():
+      return True
+    if not self._prefilling:
+      return False
+    if any(r.req.request_id in self._cancelled_ids for r in self._prefilling):
+      return True
+    return self._mixed_active() and not any(s is not None for s in self.slots)
+
   async def _run(self) -> None:
     self.clock.idle_end()  # a request is pending, or this loop would not have been started
     self._ensure_cache()
@@ -2772,41 +2977,48 @@ class BatchedServer:
         with self._phase("plan"):
           mixed_budget = self._mixed_budget() if (self._prefilling and self._mixed_active()) else None
         if inflight is not None:
-          # Membership changes happen only at dispatch boundaries: DRAIN the
-          # pipeline whenever a waiting request could actually ADMIT —
-          # admissions must never queue behind a speculative chunk (the
-          # TTFT contract) — or when lookahead is off (the strictly
-          # synchronous tick: dispatch, settle, admit). A backlog with NO
-          # free slot cannot admit no matter how often we drain, so the
-          # pipeline keeps chaining at saturation (the regime the overlap
-          # targets); the settle after every dispatch still discovers
-          # finishes, so the first freed slot flips this gate at the very
-          # next boundary and the waiter admits one chunk later at most.
-          # Mid-chunked-prefill continuations always drain: their next
-          # prefill chunk must dispatch at the boundary regardless of slots.
-          # A PARKED (page-starved) waiter additionally needs its page
-          # demand to be coverable under the head-of-line reserve
-          # (_parked_admissible mirrors the admission pass exactly) — in
-          # the page-bound saturated regime the allocator stays below every
-          # admissible demand and the pipeline keeps chaining; the settle
-          # after each dispatch still releases finishing rows' pages, so
-          # the boundary where coverage first becomes possible flips this
-          # gate and the waiter admits then.
-          admissible = self._free_slot() is not None and (not self.queue.empty() or self._parked_admissible())
-          if not admissible and self.qos is not None and self._free_slot() is None and not self.queue.empty() and self._preempt_victim_for(self.queue.peek()) is not None:
-            # A waiting request outranks a resident row: drain so the next
-            # boundary's admission pass can preempt-and-admit — interactive
-            # work must not chain behind a saturated batch pipeline.
-            admissible = True
-          # Mid-chunked-prefill continuations force a boundary only when the
-          # ALTERNATING schedule needs one (ISSUE 14): under mixed ticks an
-          # intermediate slice rides the decode dispatch and chains, so only
-          # final-slice-ready entries (their dispatch samples), cancels, and
-          # no-decode-resident states drain the pipeline.
-          if not self.lookahead or self._prefill_boundary_needed(mixed_budget) or admissible or self._drain_pending():
+          # A boundary with chunk N on the device (ISSUE 51). The device executes one stream in order, so what
+          # the loop enqueues now runs after N whatever the host does meanwhile, and the host's work rides under
+          # the device's instead of in front of it:
+          #   - nothing waits and nothing needs N settled: chunk N+1 is chained from N's device-resident token
+          #     and N is settled under it (below, as ever; a backlog with no free slot chains the same way);
+          #   - someone can ADMIT: the pass runs late in N's device time (``_wait_late_into``: so that it sees what
+          #     arrived during N, as a pass at N's end did), against host state that is exact while N flies, and its
+          #     prefill group G is enqueued BEHIND N (its pool operand is N's result, a device future; its pages
+          #     come off the free list, which holds no page a write of N can still be read from). N is then
+          #     settled under G, N+1 is planned with G's rows in their slots and enqueued behind G with their
+          #     first tokens merged into its chain token on the device, and G is settled under N+1. A waiter
+          #     whose prompt will be sliced into mixed ticks only claims its slot and pages in that pass and
+          #     forces nothing;
+          #   - what comes next needs N's settled state (``_needs_settled_state``): N is settled first, and the
+          #     pass after it is the synchronous one.
+          # No row joins a chunk later than under a drain at every boundary: whatever N's settle frees (slots,
+          # pages a parked waiter was short of) is offered in a second pass before N+1 is planned.
+          if inflight.mixed_ready is not None:
+            # The slice that rides N ends where the host said it would, and whatever reads those pages next runs
+            # after N: the prompt's prefix is taken as advanced NOW (N's settle would do it, too late for this
+            # boundary), so a prompt whose last intermediate slice is in N sends its final slice's group behind N
+            # and the next waiter's first slice rides N+1 — the tick a drain at every boundary gave them.
+            inflight.mixed_ready.prefix_len = max(inflight.mixed_ready.prefix_len, inflight.mixed_end)
+          if self._needs_settled_state():
             await self._settle(inflight)
             inflight = None
             continue
+          if self._admission_waiting(mixed_budget):
+            await self._wait_late_into(inflight)
+            if self._needs_settled_state():
+              continue  # what the wait let in (a cancel, a drain, a waiter that may preempt) asks for N settled: the top of the loop does it
+            await self._admit_pending(behind=inflight)
+            if self._pending or self._preempt_possible() or (self._parked and self._free_slot() is not None):
+              # Groups ride behind N, or the pass left someone only N's settle can help (a parked waiter short
+              # of pages that N's finishing rows hold, a waiter that may preempt): settle N, then pass again.
+              await self._settle(inflight)
+              inflight = None
+              if self._admission_waiting():
+                await self._admit_pending()
+              self._update_gauges()
+              if not self._pending:
+                continue  # nothing enqueued after all: the synchronous pass below owns the idle wait
         else:
           if self._drain_pending():
             # Graceful drain: the pipeline is drained (no in-flight chunk),
@@ -2818,7 +3030,7 @@ class BatchedServer:
           # batched dispatch between decode chunks.
           await self._admit_pending()
           self._update_gauges()
-          if all(s is None for s in self.slots):
+          if not self._pending and all(s is None for s in self.slots):
             if self._prefilling:
               # A chunked prefill is mid-flight with no resident decoders:
               # loop straight back to dispatch its next chunk.
@@ -2842,6 +3054,12 @@ class BatchedServer:
             finally:
               self.clock.idle_end()
             await self._admit_pending(woken=req)
+            if not self._pending:
+              continue
+        if any(g.sync for g in self._pending):
+          # The first tokens are needed on the host before the plan (``_group_settles_first``): the old order.
+          await self._settle_groups()
+          if all(s is None for s in self.slots):
             continue
 
         with self._phase("plan"):
@@ -2881,6 +3099,11 @@ class BatchedServer:
           await self._settle(inflight)
           inflight = None
           continue
+        if self._pending and not plan.active.any():
+          # Nothing would step behind the groups either (an intermediate chunk with no decode row resident, rows
+          # of one token each): settle them and look again.
+          await self._settle_groups()
+          continue
         if plan.deadlocked:
           self._preempt_starved(plan)
           continue
@@ -2890,6 +3113,8 @@ class BatchedServer:
           # (already streaming via copy_to_host_async) plus all bookkeeping
           # overlaps device work instead of serializing in front of it.
           await self._settle(prev)
+        # ... and the groups this chunk was enqueued behind (``prev`` is then None: N was settled under them).
+        await self._settle_groups()
     except asyncio.CancelledError:
       self._fail_all(RuntimeError("batched server shut down"))
       raise
@@ -2914,6 +3139,12 @@ class BatchedServer:
       self.slots[i] = None
       self._clear_row(i)  # the single release hook resets every dispatch array
     self.clock.reset()
+    self._chain = None
+    while self._pending:  # groups enqueued and not settled: a sampling member is in ``slots`` (failed above), an intermediate one nowhere else
+      for r in self._pending.pop().members:
+        self._lora_unpin(r.req)
+        if not r.req.future.done():
+          r.req.future.set_exception(exc)
     while self._prefilling:
       r = self._prefilling.pop()
       self._lora_unpin(r.req)

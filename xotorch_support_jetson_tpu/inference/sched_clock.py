@@ -47,6 +47,7 @@ class SchedClock:
     self.seconds = dict.fromkeys(KINDS, 0.0)
     self.intervals = dict.fromkeys(KINDS, 0)  # closed stretches of each kind: a snapshot's group counts (on /metrics the chunk histograms count the device kinds)
     self.phases: dict[str, float] = {}
+    self.last: dict[str, float] = {}  # the newest closed interval of each device kind: what the next one of that kind is expected to take
     self.ticks = 0
     self.steps = 0  # decode steps read back (a chunk's worth per decode / mixed / spec interval)
 
@@ -69,13 +70,34 @@ class SchedClock:
       self._since = self._book()
       self._enter(kind)
 
+  @property
+  def queued(self) -> int:
+    """Dispatches handed over and not yet read back: above 0, the next one is enqueued behind them."""
+    return len(self._inflight)
+
+  def expected(self) -> tuple[float, float] | None:
+    """(seconds the oldest dispatch in flight is expected to run on, what its kind last took), or None where nothing
+    is in flight or none of its kind has been read back yet. An estimate: a chunk's time hardly moves from one to
+    the next, a mixed tick's follows its slice."""
+    took = self.last.get(self._inflight[0]) if self._inflight else None
+    return None if took is None else (self._since + took - self._now(), took)
+
+  def withdrawn(self) -> None:
+    """The newest dispatch failed before it reached the device (a prefill group whose enqueue raised): it leaves the
+    queue, and if it had the clock the host takes it back. What is older keeps its place."""
+    self._book()
+    if self._inflight:
+      self._inflight.pop()
+      if not self._inflight:
+        self._enter("host")
+
   def ready(self, steps: int = 0) -> float:
     """The oldest dispatch has been read back (or has failed): close its interval and return its length."""
     now = self._book()
     if not self._inflight:
       return 0.0
-    self._inflight.popleft()
     dt, self._since = now - self._since, now
+    self.last[self._inflight.popleft()] = dt
     self.steps += steps
     self._enter(self._inflight[0] if self._inflight else "host")
     return dt

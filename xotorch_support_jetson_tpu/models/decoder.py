@@ -1796,6 +1796,17 @@ def sample_rows(logits, key, temps, top_ks, k_max: int):
   return tok
 
 
+@tracked_jit("sample.merge_firsts")
+def merge_first_tokens(chain, firsts, rows):
+  """A prefill group's first tokens written into the decode chunk's chain token ON THE DEVICE (ISSUE 51): ``chain``
+  [B, 1] is the next chunk's input token (the chunk in flight's ``next_token`` handle, or the host's tokens), ``firsts``
+  [K] the group's sampled tokens, ``rows`` [K] each one's slot — the slot past the last for a row that takes no place
+  in the next chunk (padding, an intermediate prefill chunk, a ``max_tokens`` of 1), which is dropped. The scheduler
+  enqueues this behind the group and the next chunk behind it, so that chunk never waits for the group's readback.
+  One program per group size, compiled in the dispatch that first compiles that group's prefill."""
+  return chain.at[rows, 0].set(firsts.astype(chain.dtype), mode="drop")
+
+
 # ------------------------------------------------ fused sampling epilogue
 # (ISSUE 11): the batched admission path historically ran TWO device
 # dispatches per prefill group — the prefill program, then ``sample_rows``
