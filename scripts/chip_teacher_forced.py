@@ -11,7 +11,7 @@ what the kind's ``long_prompt_tokens`` says where it says: 4160-4608 past a wind
 are prefilled as one group through ``prefill.pages_many`` (pool donated), then ``--steps`` decode
 steps run through ``paged_decode_forward`` over pool, state and pages, each fed the seeded next token (teacher-forced),
 the other slots inactive. A configuration without recurrent layers attends through whatever the served decode program
-does (``decode_kernels_supported``: on a TPU the Pallas paged kernel, with the layer's window); one with them keeps the
+does (``paged_kernel_supported``: on a TPU the Pallas paged kernel, with the layer's window); one with them keeps the
 gather it was measured with. Every step's log-softmax is compared with the float32 reference's full forward over prompt +
 continuation (``jax.default_matmul_precision("highest")``, one row at a time): the largest and the mean |difference| over
 the reference's 64 likeliest tokens a position, and the reference's best log-prob minus its log-prob of the program's
@@ -64,7 +64,7 @@ def main() -> int:
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models import decoder as dec
-  from xotorch_support_jetson_tpu.ops.paged import decode_kernels_supported, init_paged_pool
+  from xotorch_support_jetson_tpu.ops.paged import init_paged_pool, paged_kernel_supported
 
   dev = jax.devices()[0]
   if dev.platform != "tpu" and not args.cpu:
@@ -74,7 +74,7 @@ def main() -> int:
   params = weights.build_params(hf, args.seed)
   cfg = common.model_config(hf)
   all_probes = {**kind.probes(hf), **getattr(kind, "long_probes", lambda _hf: {})(hf)}
-  use_kernel = not cfg.recurrent_layers and decode_kernels_supported(cfg)
+  use_kernel = not cfg.recurrent_layers and paged_kernel_supported(cfg)
   shard = Shard("m", 0, cfg.n_layers - 1, cfg.n_layers)
   slots, ps = (int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), 64) if not args.cpu else (8, 16)
   mp = pages_to_cover(cfg.max_seq_len, ps)
@@ -96,6 +96,10 @@ def main() -> int:
   last, pool = dec.prefill_into_pages_many_inplace(params, cfg, shard, jnp.asarray(tok), pool, jnp.asarray(bts), jnp.zeros((K,), jnp.int32), jnp.asarray(prompt_lens), ps, None, jnp.asarray(slot_rows))
   got = [[np.asarray(jax.nn.log_softmax(last[i].astype(jnp.float32)))] for i in range(args.rows)]
 
+  if use_kernel:  # as a decode dispatch does, once: a latent model's rope leaf (64 lanes) is otherwise padded and cut back a layer, a step
+    from xotorch_support_jetson_tpu.ops.paged import kernel_pool_form
+
+    pool = jax.jit(kernel_pool_form, donate_argnums=0)(pool)
   step = jax.jit(lambda params, tok, pos, pool, active: dec.paged_decode_forward(params, cfg, shard, tok, pos[:, None], pool, jnp.asarray(tables), ps, use_kernel, active=active)[:2], donate_argnums=3)
   active = np.zeros((slots,), bool)
   active[use_slots] = True

@@ -106,6 +106,18 @@ def _fp32_matmuls():
     yield
 
 
+@pytest.fixture
+def interpreted_paged_kernels(monkeypatch):
+  """A decode program told ``use_kernel`` on this CPU: the paged kernels it traces — the latent body, the Mosaic token
+  write — run interpreted (a decode program has no such argument). Yields the list the latent body's traces append to."""
+  from xotorch_support_jetson_tpu.ops import paged
+
+  attend, write, traced = paged.paged_latent_decode_attention, paged.write_token_kv, []
+  monkeypatch.setattr(paged, "paged_latent_decode_attention", lambda *a, **kw: traced.append(1) or attend(*a, **kw, interpret=True))
+  monkeypatch.setattr(paged, "write_token_kv", lambda *a: write(*a[:7], interpret=True))
+  return traced
+
+
 @pytest.hookimpl(tryfirst=True)
 def pytest_pyfunc_call(pyfuncitem):
   """Minimal pytest-asyncio replacement (the plugin isn't in the image)."""

@@ -251,6 +251,32 @@ def test_the_served_decode_counts_the_experts_its_rows_chose(monkeypatch):
   assert 1 <= n_visited / n_steps <= 8
 
 
+def test_a_lane_wide_latent_decodes_the_same_tokens_through_the_kernels_and_the_gather(monkeypatch, interpreted_paged_kernels):
+  """(ISSUE 52) The hybrid with a latent the paged kernel's latent body tiles (rank 128): its decode chunk told
+  ``use_kernel`` — the latent layer through ``paged_decode_latent`` and the Mosaic token write on the pool in the
+  kernel's form, the KDA layers' delta step in whichever form the leaf allows, all interpreted — and told not (the
+  gather reference, the XLA expression) emit the same greedy tokens, an inactive row beside them; ``mla_q_norm`` acts
+  before either core."""
+  from xotorch_support_jetson_tpu.ops import paged
+
+  hf = {**HF, "kv_lora_rank": 128}
+  cfg = config_from_hf(hf)
+  assert cfg.mla_q_norm and paged.kernel_attends(cfg, True) and not paged.kernel_attends(CFG, True)
+  params = jax.tree.map(lambda x: x.astype(jnp.float32), weights.build_params(hf, 13))
+  prompts = {0: TOKENS[:37], 2: TOKENS[40:59]}
+  last, pool = KIND.prefill(KIND.fresh_pool(cfg), prompts, pad_to=37, params=params, cfg=cfg)
+  tok = jnp.asarray(np.argmax(np.asarray(last), axis=-1)[[0, 0, 1, 1]].astype(np.int32)[:, None])
+  pos, active = jnp.asarray([37, 0, 19, 0], jnp.int32), jnp.asarray([True, False, True, False])
+
+  def run(use_kernel):  # (the pool is donated: each run takes a copy)
+    toks, *_ = dec.fused_paged_batch_decode(params, cfg, SHARD, tok, jax.tree.map(jnp.copy, pool), jnp.asarray(KIND.tables), pos, active, jnp.zeros((SLOTS,), jnp.float32), 2 * PS, page_size=PS, use_kernel=use_kernel)
+    return np.asarray(toks)[[0, 2]]
+
+  step = ssm_ops.kda_state_step
+  monkeypatch.setattr(ssm_ops, "kda_state_step", lambda *a: step(*a, interpret=True))
+  assert run(True).tolist() == run(False).tolist() and interpreted_paged_kernels
+
+
 def test_a_lane_wide_hybrid_takes_the_grouped_form_where_told(monkeypatch):
   """(ISSUE 40) The hybrid's prefill into the pool and its decode chunk — runs of one stack's layers, the expert
   leaves held for part of the router's range — with the experts' kernels interpreted (``INTERPRET``): the

@@ -32,8 +32,11 @@ from xotorch_support_jetson_tpu.models.decoder import (
 )
 from xotorch_support_jetson_tpu.ops.paged import (
   init_paged_pool,
+  kernel_pool_form,
   paged_decode_attention,
   paged_gqa_attention_ref,
+  paged_latent_decode_attention,
+  paged_mla_attention_ref,
 )
 
 CFG = tiny_test_config(n_layers=2, max_seq_len=128)
@@ -244,6 +247,115 @@ def test_paged_kernel_never_reads_past_a_rows_length(quant):
   assert np.array_equal(got, run(clean))
 
 
+# ------------------------------------------------- the kernel's latent body (absorbed MLA)
+
+
+def _latent_case(rng, H: int, lens, ps: int, mp: int, rank: int = 512, rope: int = 64, nope: int = 32, v_dim: int = 32, layers: int = 3, dtype=jnp.bfloat16):
+  """Operands of one call at ``layer`` 2 of stacked leaves: rows of ``lens`` tokens whose table entries past their
+  length name a page that is NaN in every layer (it must never be read), and beside them what the gather reference
+  may read — the same table with those entries on the trash page, the same pool with that page zeroed."""
+  B, lens = len(lens), np.asarray(lens, np.int32)
+  held = [-(-int(n) // ps) for n in lens]
+  poison = 1 + sum(held)
+  bt, nxt = np.full((B, mp), poison, np.int32), 1
+  for r, n in enumerate(held):
+    bt[r, :n] = range(nxt, nxt + n)
+    nxt += n
+  k = jnp.asarray(rng.normal(size=(layers, poison + 1, 1, ps, rank)), dtype)
+  v = jnp.asarray(rng.normal(size=(layers, poison + 1, 1, ps, rope)), dtype)
+  q_nope, q_pe = jnp.asarray(rng.normal(size=(B, 1, H, nope)), jnp.float32), jnp.asarray(rng.normal(size=(B, 1, H, rope)), jnp.float32)
+  w_kv_b = jnp.asarray(rng.normal(size=(rank, H * (nope + v_dim))) / rank**0.5, jnp.float32)
+  rest = (w_kv_b, v_dim, ps)
+  seen = (q_nope, q_pe, k.at[:, poison].set(jnp.nan), v.at[:, poison].set(jnp.nan), jnp.asarray(bt), jnp.asarray(lens), *rest)
+  clean = (q_nope, q_pe, k.at[:, poison].set(0), v.at[:, poison].set(0), jnp.asarray(np.where(bt == poison, 0, bt)), jnp.asarray(np.maximum(lens, 1)), *rest)
+  return seen, clean, lens > 0
+
+
+# Both products take float32 operands over pages of either dtype (q_abs is a float32 product; the probabilities stay
+# float32): the body and the reference differ by the order of their sums. A page or a mask wrong reads 0.1-1.
+_LATENT_ATOL = 2e-5
+
+
+@pytest.mark.parametrize("H", [16, 32], ids=["moonlight-16-heads", "ling-32-heads"])
+def test_latent_body_matches_the_gather_reference_at_the_cells_widths(H):
+  """The latent body against ``paged_mla_attention_ref`` at the two cells' head counts, rank 512 / rope 64, pages of
+  64 and a table of 64: rows of 1, 7, 9 and 64 pages and a row of length 0 in one call, ``layer`` 2 of stacked
+  leaves, every table entry past a row's length naming a NaN page."""
+  ps = mp = 64
+  seen, clean, live = _latent_case(np.random.default_rng(51), H, [ps - 3, 7 * ps, 0, 9 * ps + 5, 64 * ps], ps, mp)
+  got = np.asarray(paged_latent_decode_attention(*seen, layer=jnp.int32(2), interpret=True))
+  ref = np.asarray(paged_mla_attention_ref(*clean, layer=jnp.int32(2)))
+  assert got.shape == ref.shape == (5, 1, H, 32) and np.isfinite(got).all()
+  assert np.allclose(got[live], ref[live], atol=_LATENT_ATOL), np.abs(got[live] - ref[live]).max()
+  assert not got[~live].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("tile", [1, 2, 4, 8])
+def test_latent_body_tile_widths_and_fold_boundaries_match_reference(tile, dtype):
+  """Every tile width over lengths that end one token before, on and one after a page and a tile, past two tiles,
+  and an empty row between two long ones (its slots keep the longer row's pages; the row after fetches its own first
+  tile) — small pages of a 128-wide latent and a 16-wide rope channel, which the kernel's form pads to a lane group."""
+  ps, mp, g = 8, 20, tile
+  lens = [2 * g * ps + 5, 0, ps - 1, ps, ps + 1, g * ps - 1, g * ps, g * ps + 1, 2 * g * ps + ps + 3, 1]
+  seen, clean, live = _latent_case(np.random.default_rng(53), 6, lens, ps, mp, rank=128, rope=16, nope=8, v_dim=8, dtype=dtype)
+  got = np.asarray(paged_latent_decode_attention(*seen, layer=jnp.int32(2), pages_per_step=g, interpret=True))
+  ref = np.asarray(paged_mla_attention_ref(*clean, layer=jnp.int32(2)))
+  assert np.isfinite(got).all() and np.allclose(got[live], ref[live], atol=_LATENT_ATOL), np.abs(got[live] - ref[live]).max()
+  assert not got[~live].any()
+
+
+def test_latent_body_takes_the_leaves_stored_or_in_the_kernels_form():
+  """A decode program hands the kernel the pool in the kernel's form (the rope leaf padded to a lane group once a
+  dispatch); a direct caller the stored leaves, padded per call: the same bits. A single layer's leaves are a stack of one."""
+  ps, mp = 8, 6
+  seen, _, _ = _latent_case(np.random.default_rng(55), 4, [3 * ps + 2, ps], ps, mp, rank=128, rope=16, nope=8, v_dim=8)
+  q_nope, q_pe, k, v, *rest = seen
+  form = kernel_pool_form({"k": k, "v": v})
+  assert form["k"].shape == k.shape and form["v"].shape == (*v.shape[:-1], 128)
+  stored = np.asarray(paged_latent_decode_attention(*seen, layer=jnp.int32(1), interpret=True))
+  assert np.array_equal(stored, np.asarray(paged_latent_decode_attention(q_nope, q_pe, form["k"], form["v"], *rest, layer=jnp.int32(1), interpret=True)))
+  assert np.array_equal(stored, np.asarray(paged_latent_decode_attention(q_nope, q_pe, k[1], v[1], *rest, interpret=True)))
+
+
+def test_latent_body_scales_by_the_models_head_width_not_the_operands():
+  """The scores' scale is (nope + rope)^-1/2 — the kernel's operand is rank + 128 lanes wide, which says nothing of it:
+  the same latents under a wider nope give other probabilities, and the reference agrees at both."""
+  ps, mp = 8, 4
+  for nope in (8, 64):
+    seen, clean, _ = _latent_case(np.random.default_rng(57), 4, [2 * ps + 3, ps], ps, mp, rank=128, rope=16, nope=nope, v_dim=8, dtype=jnp.float32)
+    got, ref = paged_latent_decode_attention(*seen, interpret=True, layer=jnp.int32(0)), paged_mla_attention_ref(*clean, layer=jnp.int32(0))
+    assert np.allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+
+
+def test_latent_model_decodes_the_same_tokens_through_the_kernel_and_the_gather(interpreted_paged_kernels):
+  """A small MLA model whose latent the kernel tiles (rank 128): ``fused_paged_batch_decode`` told ``use_kernel``
+  (the latent body and the Mosaic token write, interpreted, on the pool in the kernel's form) and told not (the
+  gather reference) emit the same greedy tokens and leave the same pool, an inactive row and a page boundary included."""
+  from xotorch_support_jetson_tpu.ops.paged import kernel_attends
+
+  cfg = tiny_test_config(n_layers=2, max_seq_len=128, n_heads=4, n_kv_heads=4, kv_lora_rank=128, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
+  assert kernel_attends(cfg, True)
+  params, shard = full_model_params(KEY, cfg)
+  mp, n_slots = 128 // PS, 3
+  prompts = [[3, 25, 9], list(range(5, 5 + PS - 2)), [100]]  # the second row's decode crosses into a fresh page
+  bt = np.arange(1, 1 + n_slots * mp, dtype=np.int32).reshape(n_slots, mp)
+  firsts = []
+  pool = init_paged_pool(cfg, shard.n_shard_layers, 1 + n_slots * mp, PS)
+  for r, p in enumerate(prompts):
+    pad = np.zeros((1, 16), np.int32)
+    pad[0, : len(p)] = p
+    last, pool = prefill_into_pages(params, cfg, shard, jnp.asarray(pad), pool, jnp.asarray(bt[r]), jnp.int32(0), jnp.int32(len(p)), PS)
+    firsts.append(int(np.argmax(np.asarray(last)[0])))
+  tok = jnp.asarray([[f] for f in firsts], jnp.int32)
+  positions, active = jnp.asarray([len(p) for p in prompts], jnp.int32), jnp.asarray([True, True, False])
+  run = lambda use_kernel: fused_paged_batch_decode(params, cfg, shard, tok, jax.tree.map(jnp.copy, pool), jnp.asarray(bt), positions, active, jnp.zeros((n_slots,), jnp.float32), 10, page_size=PS, use_kernel=use_kernel)  # noqa: E731  (the pool is donated)
+  ref, got = run(False), run(True)
+  assert interpreted_paged_kernels and np.array_equal(np.asarray(got[0])[:2], np.asarray(ref[0])[:2]) and np.array_equal(np.asarray(got[2]), np.asarray(ref[2]))
+  for name in ("k", "v"):  # the same tokens written to the same slots, in the stored form
+    assert got[3][name].shape == ref[3][name].shape and np.allclose(np.asarray(got[3][name])[:, 1:], np.asarray(ref[3][name])[:, 1:], atol=1e-5)
+
+
 # The one owner of "which attention core does a paged program run" (ops/paged.py):
 # name -> (config overrides, the kernel can run for it on a TPU, it attends through the kernel when told to).
 _MLA = dict(kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, family="deepseek-v2")
@@ -254,7 +366,9 @@ _OWNER_CONFIGS = {
   "gqa-hd96": (dict(head_dim=96), False, True),  # no tiling for that width on the chip; interpret-mode tests may still ask for it
   "softcap": (dict(head_dim=64, attn_logit_softcap=30.0), False, False),
   "window": (dict(head_dim=64, sliding_window=32), False, False),
-  "mla": (_MLA, False, False),
+  "mla": (_MLA, False, False),  # a latent of 16: no whole lane group for the latent body to tile
+  "mla-rank128": (dict(_MLA, kv_lora_rank=128), True, True),  # the latent body (absorbed MLA)
+  "mla-rope256": (dict(_MLA, kv_lora_rank=128, qk_rope_head_dim=256), False, False),  # a rope channel past one lane group
 }
 
 
@@ -263,8 +377,8 @@ _OWNER_CONFIGS = {
 @pytest.mark.parametrize("name", list(_OWNER_CONFIGS))
 def test_paged_kernel_supported_is_the_resolver(monkeypatch, name, platform, no_flash):
   """The kernel wherever it can run — a TPU, plain GQA attention at a head
-  width it tiles, ``XOT_TPU_NO_FLASH`` unset — and the gather elsewhere;
-  nothing else (batch, context, KV mode) has a say."""
+  width it tiles or latent attention its latent body tiles, ``XOT_TPU_NO_FLASH``
+  unset — and the gather elsewhere; nothing else (batch, context, KV mode) has a say."""
   from xotorch_support_jetson_tpu.ops.paged import paged_kernel_supported
 
   overrides, can_run, _ = _OWNER_CONFIGS[name]

@@ -272,33 +272,36 @@ def test_the_cells_tiles():
   assert ssm._head_block(64, 64, 128) == 32
 
 
+_LING = dict(kv_lora_rank=512, qk_rope_head_dim=64, layer_types=("kda", "attention"))
+
+
 @pytest.mark.parametrize(
   "what,overrides,platform,no_flash,want",
   [
-    ("a latent-attention hybrid of delta-rule layers on a TPU (Ling)", dict(kv_lora_rank=8, layer_types=("kda", "attention")), "tpu", False, True),
-    ("the same on a CPU", dict(kv_lora_rank=8, layer_types=("kda", "attention")), "cpu", False, False),
-    ("the same with the kernels switched off", dict(kv_lora_rank=8, layer_types=("kda", "attention")), "tpu", True, False),
-    ("the same under a plan that leaves an axis to GSPMD", dict(kv_lora_rank=8, layer_types=("kda", "attention"), mosaic_kernels=False), "tpu", False, False),
-    ("latent attention without recurrent layers (Moonlight)", dict(kv_lora_rank=8), "tpu", False, False),
+    ("a latent-attention hybrid of delta-rule layers on a TPU (Ling)", _LING, "tpu", False, True),
+    ("the same on a CPU", _LING, "cpu", False, False),
+    ("the same with the kernels switched off", _LING, "tpu", True, False),
+    ("the same under a plan that leaves an axis to GSPMD", dict(_LING, mosaic_kernels=False), "tpu", False, False),
+    ("latent attention without recurrent layers (Moonlight)", dict(kv_lora_rank=512, qk_rope_head_dim=64), "tpu", False, True),
+    ("a latent the kernel's latent body does not tile, beside delta-rule layers", dict(_LING, kv_lora_rank=8), "tpu", False, False),
     ("plain attention beside Gated-DeltaNet layers (Olmo): the paged kernel's answer", dict(layer_types=("gdn", "attention")), "tpu", False, True),
     ("plain attention of a head width the paged kernel does not tile", dict(layer_types=("gdn", "attention"), head_dim=96), "tpu", False, False),
   ],
 )
 def test_what_a_decode_program_is_told_where_its_caller_did_not_say(what, overrides, platform, no_flash, want, monkeypatch):
-  """``fused_paged_batch_decode`` resolves ``use_kernel=None`` through ``decode_kernels_supported``: what the paged
-  kernel answers, and for latent attention beside delta-rule layers — whose attention takes the gather whatever the
-  program is told — the platform alone, so that Ling's state step takes its kernel on a TPU as Olmo's does."""
+  """``fused_paged_batch_decode`` resolves ``use_kernel=None`` through ``paged_kernel_supported``, and the recurrent
+  layers' Mosaic state steps ride that one answer. Until ISSUE 52 latent attention took the gather whatever a program
+  was told and a second resolver answered the platform alone for Ling, so that its delta step took its kernel; the
+  kernel's latent body made the paged kernel's own answer true there, and the special case went."""
   from dataclasses import replace
 
   from xotorch_support_jetson_tpu.models.config import ModelConfig
-  from xotorch_support_jetson_tpu.ops.paged import decode_kernels_supported, kernel_attends, paged_kernel_supported
+  from xotorch_support_jetson_tpu.ops.paged import kernel_attends, paged_kernel_supported
 
   monkeypatch.delenv("XOT_TPU_NO_FLASH", raising=False)
   if no_flash:
     monkeypatch.setenv("XOT_TPU_NO_FLASH", "1")
   cfg = replace(ModelConfig(dim=64, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=64, hidden_dim=128, vocab_size=128), **overrides)
-  assert decode_kernels_supported(cfg, platform) is want, what
-  if cfg.is_mla:
-    assert not paged_kernel_supported(cfg, platform) and not kernel_attends(cfg, True)
-  else:
-    assert decode_kernels_supported(cfg, platform) is paged_kernel_supported(cfg, platform)
+  assert paged_kernel_supported(cfg, platform) is want, what
+  if cfg.is_mla:  # told the kernel, the layer steps attend through it exactly where the latent body tiles the model
+    assert kernel_attends(cfg, True) is (cfg.kv_lora_rank % 128 == 0 and cfg.mosaic_kernels)

@@ -64,6 +64,28 @@ def test_paged_decode_kernel_compiles_at_served_shapes(chip, quant, batch, tile,
   assert "tpu_custom_call" in _compile_paged_kernel(chip, quant, batch, tile, hd, mp, n_pages, hq)
 
 
+@pytest.mark.parametrize(
+  "batch,heads,layers,n_pages,rope_lanes",
+  [
+    pytest.param(16, 16, 14, 1025, 128, id="moonlight-a3b-served"),  # the benchmark's cells: rank 512, a table of 64 pages; the rope leaf in the kernel's form
+    pytest.param(64, 32, 1, 1537, 128, id="ling-3.0-flash-served"),
+    pytest.param(16, 16, 14, 1025, 64, id="moonlight-a3b-stored-leaves"),  # a direct caller's stored leaf: padded per call
+  ],
+)
+def test_paged_decode_latent_body_compiles_at_served_shapes(chip, batch, heads, layers, n_pages, rope_lanes):
+  """The kernel's latent body (absorbed MLA, ISSUE 52) at both latent cells' shapes and the served tile: q_abs ‖ q_pe
+  [B, H, 512 + 128] float32, the stacked latent and rope leaves in HBM, read at a layer scalar."""
+  from xotorch_support_jetson_tpu.ops.paged import PAGE_TILE, _paged_decode_attention_impl
+
+  _, text = _compile(
+    _paged_decode_attention_impl,
+    _sds(chip, (batch, heads, 640), jnp.float32), _sds(chip, (batch, 64), jnp.int32), _sds(chip, (batch,), jnp.int32), _sds(chip, (1,), jnp.int32),
+    _sds(chip, (layers, n_pages, 1, PS, 512), jnp.bfloat16), _sds(chip, (layers, n_pages, 1, PS, rope_lanes), jnp.bfloat16),
+    page_size=PS, pages_per_step=PAGE_TILE, kv_quant="", interpret=False, latent_scale=float(192**-0.5),
+  )  # fmt: skip
+  assert _mosaic_calls(text) == ["paged_decode_latent"]
+
+
 @pytest.mark.parametrize("hd", [64, 128])
 @pytest.mark.parametrize("quant", ["", "int8", "int4"])
 def test_token_write_kernel_compiles_for_v5e(chip, quant, hd):

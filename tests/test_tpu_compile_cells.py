@@ -71,17 +71,17 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch
   from the donated argument to the result: no instruction copies it or a layer of it; it is read at (layer) and written
   back by the Mosaic call ``delta_state_step`` (ISSUE 45: one call in each of the three runs of KDA layers, the leaf
   aliased through it; until then by two fusions of the XLA expression), which no fusion shares it with. The other Mosaic calls
-  are the experts' two (ISSUE 40: ``moe_gate_up``, ``moe_down``, in both stacks' loops — MLA takes the gather path
-  though the program is told ``use_kernel``, which is what ``decode_kernels_supported`` resolves for it on a TPU), and
-  they take the STACKED expert leaves: no stacked expert leaf, and no layer of one, is copied, cut out or relaid (a copy
+  are the experts' two (ISSUE 40: ``moe_gate_up``, ``moe_down``, in both stacks' loops) and, since ISSUE 52, the latent
+  layer's two — ``paged_decode_latent`` and ``kv_token_write``, which ``paged_kernel_supported`` resolves for it on a
+  TPU (``test_latent_attention_decode_step_walks_its_rows_pages_in_the_kernel``); the experts' take the STACKED expert leaves: no stacked expert leaf, and no layer of one, is copied, cut out or relaid (a copy
   of a stack is 3.8 GB, of a layer 0.75 GB a step)."""
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
-  from xotorch_support_jetson_tpu.ops.paged import decode_kernels_supported, paged_kernel_supported
+  from xotorch_support_jetson_tpu.ops.paged import paged_kernel_supported
 
   _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch)
-  assert decode_kernels_supported(cfg, "tpu") and not paged_kernel_supported(cfg, "tpu") and not decode_kernels_supported(cfg, "cpu")
+  assert paged_kernel_supported(cfg, "tpu") and not paged_kernel_supported(cfg, "cpu")
   n_slots = pool["ssm"].shape[1]
   assert pool["k"].shape == (1, 1537, 1, PS, 512) and pool["v"].shape == (1, 1537, 1, PS, 64) and pool["ssm"].shape == (6, 64, 32, 128, 128) and pool["conv"].shape == (6, 64, 3, 12288)
   assert params["ssm_moe_layers"]["w_experts_gate"].shape == (5, 128, 2560, 768) and params["moe_layers"]["w_router"].shape == (1, 2560, 512)
@@ -93,7 +93,7 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch
   )  # fmt: skip
   calls = _mosaic_calls(text)
   # (the six KDA layers are three runs: the dense first layer, and the expert layers on either side of the latent layer)
-  assert sorted(set(calls)) == ["delta_state_step", "moe_down", "moe_gate_up"] and calls.count("delta_state_step") == 3, calls
+  assert sorted(set(calls)) == ["delta_state_step", "kv_token_write", "moe_down", "moe_gate_up", "paged_decode_latent"] and calls.count("delta_state_step") == 3, calls
   state_takers = _takers(text, r"f32\[(6,|1,)?64,32,128,128\]")
   assert len(state_takers) == 3 and all(op == "custom-call" and name.startswith("%delta_state_step") for name, op in state_takers), state_takers
   assert not re.search(r"= f32\[6,64,32,128,128\]\S* dynamic-update-slice\(", text)
@@ -112,26 +112,57 @@ def test_kda_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip, monkeypatch
 
 
 @pytest.mark.parametrize("config", ["moonlight-a3b-d14", "ling-3.0-flash-ep4-d7"])
-def test_latent_attention_decode_step_holds_no_paged_decode_call(chip, monkeypatch, config):
-  """The two latent-attention configurations of the benchmark, ASKED for the kernel (``use_kernel`` True): their
-  ``decode.paged_batch`` holds no Mosaic call named ``paged_decode`` — ``kernel_attends`` sends MLA to the XLA gather
-  whatever it was told —, so a change to the paged-decode kernel cannot move their cells (ISSUE 43). Moonlight is
-  cut to its dense layer and two expert layers, in bf16: which attention core a program takes does not depend on depth."""
+def test_latent_attention_decode_step_walks_its_rows_pages_in_the_kernel(chip, monkeypatch, config):
+  """The two latent-attention configurations of the benchmark at their cells' settings, told the kernel (``use_kernel``
+  True, what ``paged_kernel_supported`` resolves for them on a TPU since ISSUE 52; until then this test held the
+  opposite — ``kernel_attends`` sent MLA to the XLA gather whatever it was told, which read every row's whole
+  4096-token table twice a layer, in float32). Their ``decode.paged_batch`` holds the kernel's latent body
+  ``paged_decode_latent`` and the Mosaic token write once a loop of latent layers, inside ``xot.attn`` and
+  ``xot.kv_write``; nothing anywhere in the optimised text has a gathered window's shape ([slots, 4096, ·] or
+  [slots, 64 pages, ·]) or is a float32 latent; the latent leaf is one buffer from the donated argument to the result,
+  taken by the two Mosaic calls alone; the rope leaf (64 lanes stored, and stored by the TPU with the pages along the
+  lanes) is brought to the kernel's form once a dispatch — a copy and a pad before the step loop, a copy and a slice
+  after it, none inside — and the program fits 15.75 GB. So a change to the paged-decode kernel's walk DOES move these
+  two cells now. Moonlight is cut to its dense layer and two expert layers, in bf16: which attention core a program
+  takes does not depend on depth (the whole cell's program, 14 layers in int8, compiled by hand: PERF.md §6, PR 52)."""
   from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
   from xotorch_support_jetson_tpu.inference.shard import Shard
   from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl
+  from xotorch_support_jetson_tpu.ops.paged import kernel_attends, paged_kernel_supported
 
-  hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, config, **({} if config.startswith("ling") else {"num_hidden_layers": 3}))
+  # Moonlight's file names no pool: the scheduler's default for it is a table a slot, 16 x 64 pages and the trash page
+  # (a rope leaf of a few MB the compiler would keep in VMEM, in pieces: not the cell's program).
+  cut = {} if config.startswith("ling") else {"num_hidden_layers": 3, "serving_env": {"XOT_TPU_BATCH_SLOTS": "16", "XOT_TPU_BATCH_PAGES": "1025"}}
+  hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, config, **cut)
   n_slots = int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"])
-  assert cfg.is_mla
+  assert cfg.is_mla and paged_kernel_supported(cfg, "tpu") and kernel_attends(cfg, True) and not paged_kernel_supported(cfg, "cpu")
+  L, P = pool["k"].shape[:2]
+  assert pool["k"].shape == (L, P, 1, PS, 512) and pool["v"].shape == (L, P, 1, PS, 64) and cfg.max_seq_len == 4096
   rows = _rows(chip, n_slots)
-  _, text = _compile(
+  compiled, text = _compile(
     _fused_paged_batch_decode_impl, params, cfg, Shard(config, 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (n_slots, 1), jnp.int32), pool,
     _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
     _sds(chip, (2,), jnp.uint32), None,
   )  # fmt: skip
   calls = _mosaic_calls(text)
-  assert calls and not [name for name in calls if "paged_decode" in name], calls  # (the experts' two kernels are there)
+  loops = 1 if config.startswith("ling") else 2  # Ling's one latent layer; Moonlight's dense layer and its expert layers
+  assert calls.count("paged_decode_latent") == calls.count("kv_token_write") == loops and not [name for name in calls if name.startswith("paged_decode") and name != "paged_decode_latent"], calls
+  kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+  assert all("/xot.attn/jit(_paged_decode_attention_impl)/paged_decode_latent/pallas_call" in line for line in kernels if "paged_decode_latent" in line)
+  gathered = re.findall(rf"(?:bf16|f32)\[{n_slots},(?:4096|64,1,64|64,64,1|64,64),(?:512|64|128)\]", text)
+  assert not gathered, gathered[:4]
+  assert not re.search(r"f32\[[\d,]+,(?:64|4096),512\]", text)  # no latent page, and no window of them, in float32
+  produced = _materialised(text)
+  latent, rope = f"bf16[{L},{P},1,{PS},512]", (f"bf16[{L},{P},1,{PS},64]", f"bf16[{L},{P},1,{PS},128]")
+  assert {op for _, result, op, *_ in produced if result.startswith(latent)} <= {"parameter", "get-tuple-element"}
+  takers = _takers(text, re.escape(latent))
+  assert len(takers) == 2 * loops and all(op == "custom-call" for _, op in takers), takers
+  moved = [(op, scope) for _, result, op, _, scope in produced if result.startswith(rope) and op not in ("parameter", "get-tuple-element", "copy-start", "copy-done")]
+  assert sorted(op for op, _ in moved) == ["copy", "copy", "pad", "slice"] and not [scope for _, scope in moved if "/while/" in scope], moved
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch {config} B={n_slots}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= L * P * PS * 576 * 2  # the pool is donated: the pages are written where they lie
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip, monkeypatch):

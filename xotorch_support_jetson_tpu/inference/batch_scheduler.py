@@ -949,12 +949,12 @@ class BatchedServer:
       self.block_tables = np.zeros((self.n_slots, self.pages_per_row), dtype=np.int32)
       self.cache = self.ops.init_pool(n_pages, ps, **({"n_slots": self.n_slots} if recurrent else {}))
       metrics.set_gauge("page_pool_pages_total", n_pages - 1)  # page 0 = trash page
-      from ..ops.paged import decode_kernels_supported, state_leaves
+      from ..ops.paged import paged_kernel_supported, state_leaves
       from ..ops.ssm import STATE_STEP_FORMS, state_step_form
 
       metrics.set_gauge("recurrent_state_bytes", sum(leaf.size * leaf.dtype.itemsize for leaf in state_leaves(self.cache).values()))
       if recurrent:  # which form the decode programs of this pool step the state in: what they will observe, asked once
-        form = state_step_form(self.cache["ssm"], decode_kernels_supported(eng.cfg), eng.cfg.recurrent_kind)
+        form = state_step_form(self.cache["ssm"], paged_kernel_supported(eng.cfg), eng.cfg.recurrent_kind)
         for name in STATE_STEP_FORMS:
           metrics.set_gauge("recurrent_state_step", int(name == form), labels={"form": name})
       self._note_expert_form()

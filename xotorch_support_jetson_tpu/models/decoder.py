@@ -2068,20 +2068,22 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
   routed = _route_ahead(x, p, cfg)
   pos = positions[:, 0]
   lengths = pos + 1  # valid KV slots incl. the token written below
-  from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref, paged_mla_attention_ref
+  from ..ops.paged import kernel_attends, paged_decode_attention, paged_gqa_attention_ref, paged_latent_decode_attention, paged_mla_attention_ref
 
   if kv_quant is None:
     kv_quant = pool_kv_quant(pool, cfg)
+  kernel = kernel_attends(cfg, use_kernel)
   if "wkv_a" in p:
-    # MLA: pages hold the latent ("k") and rope channel ("v"), one head entry.
+    # MLA: pages hold the latent ("k") and rope channel ("v"), one head entry. The kernel's latent body walks the
+    # pages each row holds; the reference gathers the table's whole width.
     q_nope, q_pe, c_kv, k_pe = _mla_latents(x, p, cfg, positions, inv_freq)
-    pool = _write_kv(pool, c_kv[:, 0][:, None, :], k_pe[:, 0][:, None, :], layer, block_tables, pos, page_size, "")
+    pool = _write_kv(pool, c_kv[:, 0][:, None, :], k_pe[:, 0][:, None, :], layer, block_tables, pos, page_size, "", kernel)
     with jax.named_scope("xot.attn"):  # the absorbed up-projection is the core's operand
       w_kv_b = _mla_w_kv_b(p, h.dtype)
-    attn = paged_mla_attention_ref(q_nope, q_pe, pool["k"], pool["v"], block_tables, lengths, w_kv_b, cfg.v_head_dim, page_size, layer=layer)
+    attend = paged_latent_decode_attention if kernel else paged_mla_attention_ref
+    attn = attend(q_nope, q_pe, pool["k"], pool["v"], block_tables, lengths, w_kv_b, cfg.v_head_dim, page_size, layer=layer)
   else:
     q, k, v = _dense_qkv(x, p, cfg, positions, inv_freq, adapter_ids)
-    kernel = kernel_attends(cfg, use_kernel)
     pool = _write_kv(pool, k[:, 0], v[:, 0], layer, block_tables, pos, page_size, kv_quant, kernel)
     scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if kv_quant else {}
     if kernel:
@@ -2179,17 +2181,16 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
   model without experts keeps the four).
 
   ``use_kernel=None`` resolves to the Pallas kernels wherever they can run
-  (ops/paged.py ``decode_kernels_supported``: the paged kernel's answer, or
-  a latent-attention hybrid's delta step's), the XLA forms elsewhere.
+  (ops/paged.py ``paged_kernel_supported``), the XLA forms elsewhere.
   """
-  from ..ops.paged import decode_kernels_supported
+  from ..ops.paged import paged_kernel_supported
 
   if not (shard.is_first_layer and shard.is_last_layer):
     raise ValueError("fused_paged_batch_decode requires a full-model shard")
   if key is None:
     key = jax.random.PRNGKey(0)
   if use_kernel is None:
-    use_kernel = decode_kernels_supported(cfg)
+    use_kernel = paged_kernel_supported(cfg)
   B = token.shape[0]
   top_ks = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
   out = _fused_paged_batch_decode_impl(

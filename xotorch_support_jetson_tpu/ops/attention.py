@@ -92,6 +92,16 @@ def cap_and_mask_scores(scores, q_positions, kv_positions, logit_softcap: float 
   return jnp.where(mask, scores, NEG_INF)
 
 
+def mla_absorb(q_nope: jnp.ndarray, w_kv_b: jnp.ndarray, v_dim: int):
+  """The kv_b up-projection [rank, H*(nope+v)] folded into the query side: (q_abs = q_nope · W_k [B, Sq, H, rank], W_v
+  [rank, H, v]), both float32 — the two XLA ends of absorbed MLA, whichever core attends between them (the gathered
+  window below, or the latent body of the paged decode kernel: ops/paged.py ``paged_latent_decode_attention``)."""
+  H, nope = q_nope.shape[-2:]
+  W = w_kv_b.reshape(-1, H, nope + v_dim)
+  w_k, w_v = W[..., :nope].astype(jnp.float32), W[..., nope:].astype(jnp.float32)  # [rank, H, nope], [rank, H, v]
+  return jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32), w_k), w_v
+
+
 @component_scope("xot.attn")
 def mla_absorbed_attention(
   q_nope: jnp.ndarray,  # [B, Sq, H, nope]
@@ -130,14 +140,10 @@ def mla_absorbed_attention(
     blocks = lambda t: jnp.moveaxis(jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)).reshape(B, -1, q_block, *t.shape[2:]), 1, 0)  # noqa: E731
     out = jax.lax.map(lambda t: mla_absorbed_attention(t[0], t[1], ckv, kpe, w_kv_b, t[2], kv_positions, v_dim), (blocks(q_nope), blocks(q_pe), blocks(q_positions)))
     return jnp.moveaxis(out, 0, 1).reshape(B, Sq + pad, H, v_dim)[:, :Sq]
-  rank = ckv.shape[-1]
   rope = q_pe.shape[-1]
-  W = w_kv_b.reshape(rank, H, nope + v_dim)
-  w_k = W[..., :nope].astype(jnp.float32)  # [rank, H, nope]
-  w_v = W[..., nope:].astype(jnp.float32)  # [rank, H, v]
+  q_abs, w_v = mla_absorb(q_nope, w_kv_b, v_dim)
   scale = 1.0 / jnp.sqrt(jnp.asarray(nope + rope, dtype=jnp.float32))
 
-  q_abs = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32), w_k)  # [B,Sq,H,rank]
   scores = jnp.einsum("bshr,btr->bhst", q_abs, ckv.astype(jnp.float32))
   scores = scores + jnp.einsum("bshp,btp->bhst", q_pe.astype(jnp.float32), kpe.astype(jnp.float32))
   scores = scores * scale

@@ -18,6 +18,9 @@ resident pages: the block table and the lengths ride scalar prefetch, a
 loop bounded by the row's own length fetches its pages from HBM by DMA,
 double-buffered, and nothing past a row's length is read. What a call costs
 is set by the tokens it attends, not by the table's width or the pool's size.
+Latent attention (MLA) decodes through the same kernel's latent body since
+PR 52 (``paged_latent_decode_attention``); until then it took the gather
+reference, which reads the table's whole width a row whatever the row holds.
 
 Pool layout: ``[L, P, Hkv, ps, hd]`` — one logical page id addresses the same
 page index in every layer, and a page's ``[Hkv, ps, hd]`` block is contiguous:
@@ -268,9 +271,10 @@ def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray, layer=None) -> jn
   """[L, P, Hkv, ps, hd] at ``layer`` × [B, mp] → position-ordered KV
   [B, mp·ps, Hkv, hd].
 
-  The XLA fallback path (CPU tests, MLA models): one gather by
-  ``(layer, page)`` reads the rows' pages only and materializes the gathered
-  window. The Pallas kernel below avoids this copy on TPU.
+  The XLA fallback path (every backend off the TPU, ``use_kernel=False``
+  callers): one gather by ``(layer, page)`` reads every page the TABLE names —
+  its whole width a row, whatever the row holds — and materializes the gathered
+  window. The Pallas kernel below walks the pages a row holds instead.
   """
   pool, layer = _stacked(pool, layer)
   g = pool[layer, block_tables]  # [B, mp, Hkv, ps, hd]
@@ -349,7 +353,9 @@ def paged_gqa_attention_ref(q, k_pool, v_pool, block_tables, lengths, page_size:
 @component_scope("xot.attn")
 def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths, w_kv_b, v_dim: int, page_size: int, layer=None) -> jnp.ndarray:
   """Paged MLA decode attention: gather the latent pages of ``layer`` out of
-  the stacked leaves, then the absorbed op."""
+  the stacked leaves, then the absorbed op. The reference of the kernel's
+  latent body (``paged_latent_decode_attention``) and what ``use_kernel=False``
+  callers take: it reads the table's whole width a row, twice, in float32."""
   ckv = gather_pages(k_pool, block_tables, layer)[:, :, 0, :].astype(q_nope.dtype)  # [B, mp·ps, rank]
   kpe = gather_pages(v_pool, block_tables, layer)[:, :, 0, :].astype(q_nope.dtype)
   kv_positions = jnp.arange(ckv.shape[1], dtype=jnp.int32)
@@ -371,6 +377,15 @@ def paged_mla_attention_ref(q_nope, q_pe, k_pool, v_pool, block_tables, lengths,
 # resident for the whole context and what the call reads is the window's.
 # Such a call is named ``paged_decode_window``; ``window`` 0 traces the kernel
 # without any of it.
+#
+# Absorbed MLA is one more body of the same walk (``latent``, static, since
+# PR 52; the call is named ``paged_decode_latent``): multi-query attention over
+# the ONE cached "head" whose key is latent(rank) ‖ rope and whose value is the
+# latent again. q arrives as q_abs ‖ q_pe [H, rank + lanes] (q_abs = q_nope · W_k
+# and the result's · W_v stay in XLA), the pools are the latent leaf "k" and the
+# rope leaf "v", a fold's scores are two dots summed and its value product
+# takes the latent tile already in VMEM: a page is fetched once and serves
+# both. ``latent`` False traces the kernel as it was.
 #
 # The pool operands are the STACKED leaves and stay in HBM (``pl.ANY``); the
 # layer rides scalar prefetch beside the block table and the lengths, and the
@@ -434,7 +449,7 @@ def _page_tile(mp: int) -> int:
   return g
 
 
-def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str, window: int = 0):
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: int, scale: float, pages_per_step: int, kv_quant: str, window: int = 0, latent: bool = False):
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
@@ -445,6 +460,7 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   pools_hbm, o_ref, pools_buf = refs[:n_pools], refs[n_pools], refs[n_pools + 1 : 2 * n_pools + 1]
   sem, slot_ref, m_ref, l_ref, acc_ref = refs[2 * n_pools + 1 :]
   k_buf, v_buf, ks_buf, vs_buf = (*pools_buf, None, None)[:4]
+  value_buf = k_buf if latent else v_buf  # the latent body's value is the latent tile its scores read ("k"; "v" is the rope channel)
   n_rows, mp = bt_ref.shape
   n_heads = k_buf.shape[2]
   b = pl.program_id(0)
@@ -502,7 +518,7 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
     if not quantized:
       # A fold multiplies probabilities 0 into the slot's pages that no tile
       # has fetched yet: float codes there must be finite (integer codes are).
-      v_buf[...] = jnp.zeros_like(v_buf)
+      value_buf[...] = jnp.zeros_like(value_buf)
 
   first_slot = slot_ref[0]
   # The row before, if it held anything, started this row's first tile.
@@ -518,10 +534,13 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   l_ref[...] = jnp.zeros_like(l_ref)
   acc_ref[...] = jnp.zeros_like(acc_ref)
   hd = q_ref.shape[-1]
-  kd = hd // 2 if packed else hd  # the lanes of a code tile that are codes, not padding
+  kd = None if latent else hd // 2 if packed else hd  # the lanes of a code tile that are codes, not padding (a latent's tiles are whole)
   # The score dot runs in q's dtype over quantized pages: int8 codes are
   # exact in bf16 and in f32, and products of bf16 pairs are exact in the
-  # f32 accumulator.
+  # f32 accumulator. (The latent body's q is float32 — q_abs is a float32
+  # product — so both of its dots run in float32 over the bfloat16 pages, as
+  # the value dot of every body does: on the chip that costs what bfloat16
+  # operands cost, 42.2 against 42.3 µs a call of 16 rows: PERF.md §5, PR 52.)
   dot_dtype = q_ref.dtype if quantized else jnp.promote_types(q_ref.dtype, k_buf.dtype)
 
   def code_halves(x):
@@ -538,14 +557,28 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   # operands. Packed int4: q arrives DEINTERLEAVED (even channels in the
   # first half, odd in the second — paged_decode_attention reorders outside
   # the kernel), and acc/o stay in that layout until the caller re-interleaves.
+  # The latent body: the one cached head's group is every query head, q_abs
+  # [H, rank] beside q_pe [H, lanes] — one left operand a tile of the key.
   group = q_ref.shape[1] // n_heads
   q = q_ref[0].astype(dot_dtype)
   qs = [q[h * group : (h + 1) * group] for h in range(n_heads)]
-  qs = [(x[:, :kd], x[:, kd:]) if packed else (x,) for x in qs]
+  rank = k_buf.shape[-1]  # (the latent body's; a GQA body's is hd)
+  qs = [(x[:, :rank], x[:, rank:]) if latent else (x[:, :kd], x[:, kd:]) if packed else (x,) for x in qs]
 
   def fold_codes(buf, slot, h, F):
     """Kv head h's codes of the slot's first F pages, their tokens merged along sublanes: [F·ps, kd]."""
     return jnp.concatenate([buf[slot, j, h, :, :kd] for j in range(F)], axis=0)
+
+  def key_operands(slot, h, F):
+    """The score dots' right operands of kv head h over the slot's first F pages, one a left operand of ``qs[h]``:
+    the latent body's key is the latent tile ‖ the rope tile."""
+    if latent:
+      return fold_codes(k_buf, slot, h, F), fold_codes(v_buf, slot, h, F)
+    return code_halves(fold_codes(k_buf, slot, h, F))
+
+  def value_operands(slot, h, F):
+    """The value dot's: the latent body's value is the latent tile again, already in VMEM."""
+    return (fold_codes(k_buf, slot, h, F),) if latent else code_halves(fold_codes(v_buf, slot, h, F))
 
   def fold_scales(buf, slot, F):
     """Every kv head's scales of the slot's first F pages, their tokens merged along lanes: [Hkv, F·ps]."""
@@ -574,7 +607,7 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
     for h in range(n_heads):
       s = sum(
         jax.lax.dot_general(qx, kx.astype(dot_dtype), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        for qx, kx in zip(qs[h], code_halves(fold_codes(k_buf, slot, h, F)))
+        for qx, kx in zip(qs[h], key_operands(slot, h, F))
       ) * scale  # [group, F·ps]
       if quantized:
         # codes·scale = true k: the per-token scale multiplies each score
@@ -595,7 +628,7 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
       if quantized:
         ph = ph * vs[h : h + 1]  # v's scale folds into probs (after the l update)
       # packed: even half, then odd half
-      upd.append(jnp.concatenate([jax.lax.dot_general(ph, vx.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) for vx in code_halves(fold_codes(v_buf, slot, h, F))], axis=-1))
+      upd.append(jnp.concatenate([jax.lax.dot_general(ph, vx.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) for vx in value_operands(slot, h, F)], axis=-1))
     acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(upd, axis=0)
 
   # The widths a tile's fold may take: the whole tile, or the half or the
@@ -706,24 +739,49 @@ def paged_decode_attention(
   )
 
 
-@functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret", "window"))
-def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool, window: int = 0):
+@component_scope("xot.attn")
+def paged_latent_decode_attention(q_nope, q_pe, k_pool, v_pool, block_tables, lengths, w_kv_b, v_dim: int, page_size: int, layer=None, pages_per_step: int | None = None, interpret: bool = False) -> jnp.ndarray:
+  """Absorbed-MLA decode attention off the page pool: ``paged_mla_attention_ref``'s operands and result (q_nope
+  [B, 1, H, nope], q_pe [B, 1, H, rope] roped; "k" the latent leaf [L, P, 1, ps, rank], "v" the rope leaf
+  [L, P, 1, ps, rope], stored or in the kernel's form; → [B, 1, H, v_dim]), read by the kernel's latent body — the
+  pages each row holds, once, where the reference gathers the table's whole width. The two absorbed products stay in
+  XLA on either side of the call (``ops/attention.py mla_absorb``): q_abs = q_nope · W_k goes in beside q_pe (padded to
+  the rope leaf's lanes, whose padding is zeros), and out = (Σ p·latent) · W_v comes after it."""
+  from .attention import mla_absorb
+
+  nope, rope = q_nope.shape[-1], q_pe.shape[-1]
+  q_abs, w_v = mla_absorb(q_nope, w_kv_b, v_dim)
+  q = jnp.concatenate([q_abs[:, 0], jnp.pad(q_pe[:, 0].astype(jnp.float32), [(0, 0), (0, 0), (0, -rope % 128)])], axis=-1)  # [B, H, rank + lanes]
+  (k_pool, layer), (v_pool, _) = _stacked(k_pool, layer), _stacked(v_pool, layer)
+  ctx = _paged_decode_attention_impl(
+    q, block_tables, lengths, jnp.asarray(layer, jnp.int32).reshape(1), k_pool, v_pool,
+    page_size=page_size, pages_per_step=pages_per_step or _page_tile(jnp.shape(block_tables)[1]), kv_quant="", interpret=interpret, latent_scale=float((nope + rope) ** -0.5),
+  )  # [B, H, rank] float32
+  return jnp.einsum("bhr,rhv->bhv", ctx, w_v)[:, None].astype(q_nope.dtype)
+
+
+@functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret", "window", "latent_scale"))
+def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool, window: int = 0, latent_scale: float = 0.0):
+  """``latent_scale`` > 0 (static): the latent body — q is q_abs ‖ q_pe [B, H, rank + lanes], the pools the latent and
+  the rope leaf, the result Σ p·latent [B, H, rank], and the scores' scale this, the model's (nope + rope)^-1/2, which
+  the operand's width says nothing of."""
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
   packed = kv_quant == "int4"
   B, Hq, hd = q.shape
   G = pages_per_step
-  scale = float(1.0 / (hd**0.5))
+  scale = latent_scale or float(1.0 / (hd**0.5))
   if packed:
     # Deinterleave q once outside the kernel (even channels first, odd
     # second) so the in-kernel two-dot uses contiguous halves; the output
     # comes back in the same layout and is re-interleaved below.
     q = jnp.concatenate([q[..., 0::2], q[..., 1::2]], axis=-1)
 
-  row_block = pl.BlockSpec((1, Hq, hd), lambda b, bt, ln, ly: (b, 0, 0))
   in_hbm = pl.BlockSpec(memory_space=pl.ANY)
   pools = [_kernel_leaf(x) for x in pools]  # k, v (+ their scales), stacked: [L, P, Hkv, ps, lanes] / [L, P, Hkv, lanes]
+  out_dim = pools[0].shape[-1] if latent_scale else hd
+  row_block = lambda width: pl.BlockSpec((1, Hq, width), lambda b, bt, ln, ly: (b, 0, 0))  # noqa: E731
   if not interpret:  # (the interpreter cannot slice an array that carries a memory space)
     # In HBM by constraint: left to XLA, a scale leaf (34 MB of Mistral's pool) is copied into VMEM before every call, once a layer.
     pools = [pltpu.with_memory_space_constraint(x, pltpu.HBM) for x in pools]
@@ -734,23 +792,23 @@ def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_s
     pltpu.SMEM((1,), jnp.int32),  # the slot the next tile goes to, across rows
     pltpu.VMEM((Hq, 1), jnp.float32),  # running max, sum and accumulator of every query head
     pltpu.VMEM((Hq, 1), jnp.float32),
-    pltpu.VMEM((Hq, hd), jnp.float32),
+    pltpu.VMEM((Hq, out_dim), jnp.float32),
   ]
   grid_spec = pltpu.PrefetchScalarGridSpec(
     num_scalar_prefetch=3,
     grid=(B,),
-    in_specs=[row_block] + [in_hbm] * len(pools),
-    out_specs=row_block,
+    in_specs=[row_block(hd)] + [in_hbm] * len(pools),
+    out_specs=row_block(out_dim),
     scratch_shapes=scratch,
   )
   out = pl.pallas_call(
-    functools.partial(_paged_decode_kernel, page_size=page_size, scale=scale, pages_per_step=G, kv_quant=kv_quant, **({"window": window} if window else {})),
-    out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
+    functools.partial(_paged_decode_kernel, page_size=page_size, scale=scale, pages_per_step=G, kv_quant=kv_quant, **({"window": window} if window else {}), **({"latent": True} if latent_scale else {})),
+    out_shape=jax.ShapeDtypeStruct((B, Hq, out_dim), q.dtype),
     grid_spec=grid_spec,
     # Rows in order: the prefetch chain crosses them.
     compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=tile_bytes + (16 << 20)),
     interpret=interpret,
-    **({"name": "paged_decode_window"} if window else {}),  # read apart in a trace; the call without a window keeps the name it had
+    **({"name": "paged_decode_window"} if window else {"name": "paged_decode_latent"} if latent_scale else {}),  # read apart in a trace; the GQA call without a window keeps the name it had
   )(block_tables, lengths, layer, q, *pools)
   if packed:
     # Undo the deinterleave: channel 2i from the even half, 2i+1 from the odd half.
@@ -762,11 +820,19 @@ def paged_kernel_supported(cfg, platform: str | None = None) -> bool:
   """Whether a paged program's attention core is the Pallas kernel: wherever
   it can run — a TPU, plain attention (``cfg.plain_attention``: no softcap,
   no scale override, no window that rides a traced flag; a window that is
-  static per layer kind is the kernel's operand), not MLA, a head width the
-  kernel tiles — and ``XOT_TPU_NO_FLASH`` is unset. It is what the decode
-  programs resolve ``use_kernel=None`` to and what the scheduler labels its
-  chunks by; everything else takes the XLA gather."""
-  return _mosaic_platform(cfg, platform) and cfg.plain_attention and not cfg.is_mla and cfg.head_dim in (64, 128, 256)
+  static per layer kind is the kernel's operand), a head width the kernel
+  tiles (MLA: a latent its latent body tiles) — and ``XOT_TPU_NO_FLASH`` is
+  unset. It is what the decode programs resolve ``use_kernel=None`` to — the
+  recurrent layers' Mosaic state steps (ops/ssm.py) ride the same answer —
+  and what the scheduler labels its chunks by; everything else takes the XLA
+  gather."""
+  return _mosaic_platform(cfg, platform) and cfg.plain_attention and (_latent_body_tiles(cfg) if cfg.is_mla else cfg.head_dim in (64, 128, 256))
+
+
+def _latent_body_tiles(cfg) -> bool:
+  """Absorbed MLA is the kernel's latent body where the cached "head" tiles: a latent of whole lane groups (it is a
+  DMA's minor axis and the result's) and a rope channel of at most one (``kernel_pool_form`` pads it to 128 lanes)."""
+  return cfg.is_mla and cfg.kv_lora_rank % 128 == 0 and cfg.qk_rope_head_dim <= 128
 
 
 def _mosaic_platform(cfg, platform: str | None = None) -> bool:
@@ -776,19 +842,11 @@ def _mosaic_platform(cfg, platform: str | None = None) -> bool:
   return not os.getenv("XOT_TPU_NO_FLASH") and (platform or jax.default_backend()) == "tpu" and cfg.mosaic_kernels
 
 
-def decode_kernels_supported(cfg, platform: str | None = None) -> bool:
-  """What ``fused_paged_batch_decode`` tells its program where its caller did not say (``use_kernel=None``): the paged
-  kernel's answer, and for a hybrid of latent attention and delta-rule layers the platform's alone. Such a model's
-  attention takes the gather whatever the program is told (``kernel_attends``), but its recurrent layers' state step
-  has a Mosaic form (``ops/ssm.py delta_state_step``) that asks nothing of the attention: told False, a TPU would step
-  Ling's state by the two-read expression."""
-  return paged_kernel_supported(cfg, platform) or (cfg.is_mla and cfg.recurrent_kind in ("kda", "gdn") and _mosaic_platform(cfg, platform))
-
-
 def kernel_attends(cfg, use_kernel) -> bool:
   """Whether a paged program told ``use_kernel`` attends through the Pallas
   kernel: the one test of the layer steps, the token write and the pool-form
   conversion. The kernel has no softcap and takes a window only as a static
-  operand (``cfg.plain_attention``), and MLA has its own core, so a model
-  outside that takes the gather whatever it was told."""
-  return bool(use_kernel) and cfg.plain_attention and not cfg.is_mla
+  operand (``cfg.plain_attention``), and absorbed MLA is its latent body
+  where that tiles the model, so a model outside that takes the gather
+  whatever it was told."""
+  return bool(use_kernel) and cfg.plain_attention and (not cfg.is_mla or _latent_body_tiles(cfg))

@@ -28,7 +28,7 @@ Both keep the state, the decay, the increment, the sum and the contraction in
 float32; they differ by the order of one sum over N. ``exp`` and ``softplus``
 stay with the caller (``models/decoder.py _ssm_decode_step``). Which form a
 program takes is read from what it can observe — the ``use_kernel`` its
-dispatch resolved (``ops/paged.py decode_kernels_supported``: a TPU) and the
+dispatch resolved (``ops/paged.py paged_kernel_supported``: a TPU) and the
 leaf's shape and dtype (``one_pass_supported``) — and set nowhere.
 
 The second rule is the **delta rule** (``kda_state_step``) of a
