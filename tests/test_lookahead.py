@@ -896,3 +896,141 @@ def test_the_pass_behind_a_chunk_waits_until_late_in_that_chunk():
 
   asyncio.run(run())
   server.shutdown()
+
+
+@contextmanager
+def _scripted_time(monkeypatch):
+  """``batch_scheduler``'s clock and ``asyncio.sleep`` by script: a sleep moves the clock on by what it was asked and
+  lets the loop's other tasks run. No real time passes, so a busy machine changes nothing. Yields (clock, sleeps asked)."""
+  from types import SimpleNamespace
+
+  from xotorch_support_jetson_tpu.inference import batch_scheduler as bs
+
+  clock, slept, real_sleep = [100.0], [], asyncio.sleep
+
+  async def sleep(dt):
+    slept.append(dt)
+    clock[0] += dt
+    await real_sleep(0)
+
+  with monkeypatch.context() as m:
+    m.setattr(bs, "time", SimpleNamespace(perf_counter=lambda: clock[0]))
+    m.setattr(asyncio, "sleep", sleep)
+    yield clock, slept
+
+
+class _NoEstimate:
+  toks = None  # ``_wait_late_into``'s first wait is over at once: no estimate, no readiness
+
+
+@pytest.mark.parametrize("lumps", [1, 2, 3])
+def test_the_pass_behind_a_chunk_lets_a_burst_finish_landing(lumps, monkeypatch):
+  """``_wait_late_into``'s second wait: k callers at once reach the queue in lumps a few milliseconds apart, and a pass
+  between two lumps would make two groups of k/2 (a k-row prefill program the benchmark's warm-up then never reaches).
+  The pass runs only once the newest arrival is ``BURST_QUIET_S`` old — after the LAST lump, however many there are."""
+  from xotorch_support_jetson_tpu.inference import batch_scheduler as bs
+
+  params, shard = full_model_params(KEY, CFG)
+  server = BatchedServer(_engine(params, shard), n_slots=2, chunk=2, lookahead=True)
+  server.clock.expected = lambda: None
+
+  async def run():
+    with _scripted_time(monkeypatch) as (clock, slept):
+      due = [clock[0] + i * 0.6 * bs.BURST_QUIET_S for i in range(lumps)]  # the first lump is in the queue as the boundary looks
+      landed = []
+
+      async def land():  # a handler on the loop's thread: it runs whenever the scheduler sleeps
+        while len(landed) < lumps:
+          if clock[0] >= due[len(landed)]:
+            landed.append(clock[0])
+            server._last_arrival = clock[0]
+          await asyncio.sleep(0)
+
+      server._last_arrival = clock[0]
+      landed.append(clock[0])
+      lander = asyncio.create_task(land())
+      await server._wait_late_into(_NoEstimate())
+      passed = clock[0]
+      await lander
+    assert len(landed) == lumps and landed[-1] + bs.BURST_QUIET_S <= passed <= landed[-1] + bs.BURST_QUIET_S + 0.002  # every lump is in, and the pass is held no longer than that
+    assert max(slept) <= 0.001 + 1e-9  # it looks again every millisecond: a lump is never slept through
+
+  asyncio.run(run())
+  server.shutdown()
+
+
+def test_the_pass_behind_a_chunk_is_not_held_by_a_steady_stream_of_arrivals(monkeypatch):
+  """Arrivals a millisecond apart without end: the pass gives up waiting for quiet after ``BURST_WAIT_MAX_S``; and with
+  nobody queued in the last ``BURST_QUIET_S`` (a closed loop's every boundary) it does not sleep at all."""
+  from xotorch_support_jetson_tpu.inference import batch_scheduler as bs
+
+  params, shard = full_model_params(KEY, CFG)
+  server = BatchedServer(_engine(params, shard), n_slots=2, chunk=2, lookahead=True)
+  server.clock.expected = lambda: None
+
+  async def run():
+    with _scripted_time(monkeypatch) as (clock, slept):
+      stop = False
+
+      async def stream():
+        while not stop:
+          server._last_arrival = clock[0]
+          await asyncio.sleep(0)
+
+      streamer = asyncio.create_task(stream())
+      t0 = server._last_arrival = clock[0]
+      await server._wait_late_into(_NoEstimate())
+      held, stop = clock[0] - t0, True
+      await streamer
+      assert bs.BURST_WAIT_MAX_S <= held <= bs.BURST_WAIT_MAX_S + 0.002, held
+
+      server._last_arrival = clock[0] - 2 * bs.BURST_QUIET_S
+      del slept[:]
+      await server._wait_late_into(_NoEstimate())
+      assert slept == []
+
+  asyncio.run(run())
+  server.shutdown()
+
+
+def test_a_group_whose_shape_was_never_staged_takes_a_staged_program_of_more_rows(monkeypatch):
+  """``_covered_rows``: a paged prefill group is staged at its own rows, padded to a power of two — unless that shape
+  has never been staged on this server and one of more rows at the same padded length and page window has (a warm-up's
+  group of four that the admission pass cut into two and two leaves [4, L] unmet and [8, L] met): then it takes that
+  program, its extra rows padding rows like any, and what it emits is what the exact shape would. Only on a server that
+  was declared warm (``POST /v1/warmup`` marks the ledger steady): one nobody warmed compiles the exact shape, once."""
+  from xotorch_support_jetson_tpu.utils.programs import ledger
+
+  monkeypatch.setenv("XOT_TPU_PAGED", "1")
+  monkeypatch.setenv("XOT_TPU_PAGE_SIZE", "16")
+  params, shard = full_model_params(KEY, CFG)
+  from xotorch_support_jetson_tpu.inference import batch_scheduler as bs
+
+  monkeypatch.setattr(bs, "GROUP_SLOTS_WHOLE", 4)  # a server of more slots than that has programs of 1, 2, 4 and 8 rows and no others
+  server = BatchedServer(_engine(params, shard), n_slots=8, chunk=2, lookahead=True)
+  staged, real = [], server.ops.prefill_into_pages_many_sampled
+
+  def recording(tok, *rest, **kw):
+    staged.append(tuple(tok.shape))
+    return real(tok, *rest, **kw)
+
+  server.ops.prefill_into_pages_many_sampled = recording
+  n_gen = 5
+  expected = [_single_row_reference(params, shard, p, n_gen - 1) for p in PROMPTS]
+
+  assert server._covered_rows(2, 32, 2) == 2 and _serve(server, PROMPTS[:1], n_gen)[0] == expected[:1]  # nothing staged yet: its own shape
+  (rows, padded), window = staged[-1], next(iter(server._group_shapes))[2]
+  assert rows == 1 and server._group_shapes == {(1, padded, window)}
+
+  server._group_shapes = {(4, padded, window), (8, padded, window), (4, 2 * padded, window)}  # as after a warm-up that missed [1, L] and [2, L]
+  assert not ledger.steady and server._covered_rows(2, padded, window) == 2  # nobody declared the server warm: the exact shape, compiled once
+  monkeypatch.setattr(ledger, "_steady", True)
+  assert (server._covered_rows(1, padded, window), server._covered_rows(2, padded, window), server._covered_rows(4, padded, window)) == (4, 4, 4)  # the fewest rows that cover
+  assert server._covered_rows(8, 2 * padded, window) == 8 and server._covered_rows(2, padded, 2 * window) == 2  # nothing of more rows at that length and window: its own
+  assert _serve(server, PROMPTS[:1], n_gen)[0] == expected[:1] and staged[-1] == (4, padded)  # one request, staged as the program of four; the same tokens
+
+  server._group_shapes.add((1, padded, window))
+  assert _serve(server, PROMPTS[1:2], n_gen)[0] == expected[1:2] and staged[-1] == (1, padded)  # a shape that was staged is taken as it is
+  monkeypatch.setattr(bs, "GROUP_SLOTS_WHOLE", 16)
+  assert server._covered_rows(2, padded, window) == 2  # a server of up to 16 slots is never asked
+  server.shutdown()

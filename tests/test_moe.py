@@ -551,3 +551,81 @@ def test_a_differentiated_forward_keeps_the_block_form(monkeypatch):
   np.testing.assert_array_equal(np.asarray(got_grad["moe_layers"]["w_experts_down"]), np.asarray(ref_grad["moe_layers"]["w_experts_down"]))
   assert float(jnp.abs(got_grad["moe_layers"]["w_experts_down"]).max()) > 0
   np.testing.assert_array_equal(np.asarray(shard_forward(params, cfg, shard, tokens, positions)[0]), np.asarray(ref_logits))
+
+
+# ------------------------------------------------------------ an expert of two matrices (ISSUE 53)
+
+
+def _ungated(rng, E, D, F, dtype=jnp.float32):
+  """(router [D, E], up stored [E, F, D] as the program keeps an ungated expert's first matrix, down [E, F, D])."""
+  w = lambda *shape: jnp.asarray(rng.normal(size=shape) * 0.1, dtype)  # noqa: E731
+  return jnp.asarray(rng.normal(size=(D, E)), jnp.float32), w(E, F, D), w(E, F, D)
+
+
+def _ungated_forms(x, w_router, w_up_t, w_down, k, act="relu2", routed=None, **routing):
+  from xotorch_support_jetson_tpu.ops.moe import _moe_ffn_block, _moe_ffn_grouped
+
+  full = dict(scoring="sigmoid", norm_topk=True, selection_bias=None, scale=2.5, n_group=1, topk_group=1, group_mode="none")
+  full.update(routing)
+  ref = _moe_ffn_block(x, w_router, None, w_up_t, w_down, k, capacity_factor=None, act=act, routed=routed, **full)
+  got = _moe_ffn_grouped(x, w_router, None, w_up_t[None], w_down[None], k, layer=0, act=act, routed=routed, **full)
+  return ref, got
+
+
+@pytest.mark.parametrize("act", ["relu2", "relu", "silu"])
+@pytest.mark.parametrize("T", [1, 5, 40])
+def test_an_ungated_experts_two_forms_equal_a_per_token_loop(act, T, interpreted):
+  """``w_gate`` None: W_down act(W_up x) with both matrices stored [F, D]. The block form's two einsums, the grouped
+  form's ``moe_up`` (a transposed contraction against the whole [F, D] block, the nonlinearity in the kernel) and
+  ``moe_down``, and a loop over each token's chosen experts in numpy agree; relu² squares in float32. F = 24 is no
+  whole number of lanes, as the published 1856 is none."""
+  from xotorch_support_jetson_tpu.ops.moe import EXPERT_ACTS, router_topk
+
+  rng = np.random.default_rng(53)
+  E, D, F, k = 8, 16, 24, 3
+  w_router, w_up_t, w_down = _ungated(rng, E, D, F)
+  bias = jnp.asarray(rng.normal(size=(E,)) * 0.1, jnp.float32)
+  x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+  ref, got = _ungated_forms(x, w_router, w_up_t, w_down, k, act=act, selection_bias=bias)
+  _assert_same(ref, got)
+  weights, idx = router_topk(x @ w_router, k, "sigmoid", True, bias, 2.5)
+  want = np.zeros((T, D), np.float32)
+  for t in range(T):
+    for w, e in zip(np.asarray(weights[t]), np.asarray(idx[t])):
+      want[t] += w * np.asarray(EXPERT_ACTS[act](x[t] @ w_up_t[e].T) @ w_down[e])
+  np.testing.assert_allclose(np.asarray(ref[0]), want, rtol=1e-5, atol=1e-5)
+  assert act != "relu2" or np.allclose(np.asarray(EXPERT_ACTS["relu2"](jnp.asarray([-2.0, 3.0]))), [0.0, 9.0])
+
+
+def test_an_ungated_expert_layers_padded_row_chooses_no_expert(interpreted):
+  """A routing drawn elsewhere whose last row chose no expert at all (an id past the last: the padding of a long run's
+  last block) adds nothing for that row and visits no expert for it, in both forms."""
+  from xotorch_support_jetson_tpu.ops.moe import Routed, route
+
+  rng = np.random.default_rng(54)
+  E, D, F, k = 8, 16, 24, 2
+  w_router, w_up_t, w_down = _ungated(rng, E, D, F)
+  x = jnp.asarray(rng.normal(size=(3, D)), jnp.float32)
+  drawn = route(x, w_router, k, "sigmoid", True, None, 2.5)
+  padded = Routed(drawn.logits, drawn.weights, drawn.idx.at[2].set(E))
+  ref, got = _ungated_forms(x, w_router, w_up_t, w_down, k, routed=padded)
+  _assert_same(ref, got)
+  assert not np.asarray(ref[0][2]).any() and not np.asarray(got[0][2]).any() and np.asarray(ref[0][:2]).any()
+  assert int(got[2]) == len(set(np.asarray(drawn.idx[:2]).ravel().tolist()))
+
+
+@pytest.mark.parametrize(
+  "what,first,down,dtype,scaled,want",
+  [
+    ("nemotron_h's experts: both matrices [1856, 2688], the first ONE 9.98 MB block a visit", (3, 128, 1856, 2688), (3, 128, 1856, 2688), BF16, False, "grouped"),
+    ("an inner width that is no whole number of packed sublanes", (3, 8, 1848, 2688), (3, 8, 1848, 2688), BF16, False, "block"),
+    ("a first matrix that does not fit VMEM twice over", (3, 8, 4096, 4096), (3, 8, 4096, 4096), BF16, False, "block"),
+    ("int8 codes: the up-only kernel takes none", (3, 8, 1856, 2688), (3, 8, 1856, 2688), I8, True, "block"),
+    ("the tests' widths: D no whole lane group", (4, 24, 64), (4, 24, 64), F32, False, "block"),
+  ],
+)
+def test_an_ungated_experts_form_is_read_from_its_two_f_by_d_leaves(what, first, down, dtype, scaled, want, monkeypatch):
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  assert moe.ffn_form(jax.ShapeDtypeStruct(first, dtype), jax.ShapeDtypeStruct(down, dtype), None, True, scaled, gated=False) == want, what

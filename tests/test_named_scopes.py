@@ -161,3 +161,54 @@ def test_the_routing_is_drawn_under_its_scope_on_the_side_of_the_attention_the_m
   top_k = [i for i, e in enumerate(eqns) if e.primitive.name == "top_k"]
   assert len(top_k) == 1 and top_k[0] in router
   assert (top_k[0] < min(attn)) == (router_input == "attn") and (min(router) < min(attn)) == (router_input == "attn")
+
+
+def _unscoped_primitives(jaxpr, prefix: str = "") -> set:
+  """The primitives of a traced program's equations — those of its loops', branches' and calls' bodies too — that no
+  ``xot.<component>`` scope names: what a device trace files under ``decode_unscoped_device_ms``."""
+  out = set()
+  for eqn in jaxpr.eqns:
+    stack = f"{prefix}/{eqn.source_info.name_stack}"
+    bodies = [b for v in eqn.params.values() for b in (v if isinstance(v, (tuple, list)) else (v,)) if hasattr(b, "eqns") or hasattr(getattr(b, "jaxpr", None), "eqns")]
+    for body in bodies:
+      out |= _unscoped_primitives(getattr(body, "jaxpr", body), stack)
+    if not bodies and "xot." not in stack:
+      out.add(eqn.primitive.name)
+  return out
+
+
+def test_blocks_of_one_sublayer_leave_no_op_unscoped_that_granites_programs_do_not():
+  """nemotron_h's decode step and prefill group (ISSUE 53) — a step with no FFN, B and C by group and the grouped gated
+  norm (``xot.ssm``), ungated experts (``xot.moe_experts``), an ungated shared expert (``xot.moe_shared``) — traced
+  equation by equation beside granite's: every primitive that stands outside the ``xot.`` scopes in the new kind's
+  programs stands outside them in granite's too (the layer loops' own index arithmetic) or in a gated-expert model's
+  (the reshape of the tokens to [B·S, D] around ``moe_ffn``, which moves nothing), so ``decode_unscoped_device_ms.closed``
+  reads for it what it reads for granite and Ling: the loops' plumbing, no part of a block."""
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import paged_decode_forward, prefill_into_pages_many
+
+  mamba = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, vocab_size=256, intermediate_size=96, max_position_embeddings=128, torch_dtype="float32")
+  granite = config_from_hf(dict(
+    mamba, model_type="granitemoehybrid", num_hidden_layers=3, layer_types=["mamba", "attention", "mamba"], mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4,
+    mamba_chunk_size=16, shared_intermediate_size=96, position_embedding_type="nope",
+  ))  # fmt: skip
+  nemotron = config_from_hf(dict(
+    mamba, model_type="nemotron_h", num_hidden_layers=5, hybrid_override_pattern="MEM*E", mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=16, n_groups=2, conv_kernel=4, chunk_size=16,
+    head_dim=16, n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=32, n_shared_experts=1, moe_shared_expert_intermediate_size=48, norm_topk_prob=True, routed_scaling_factor=2.5,
+  ))  # fmt: skip
+  assert nemotron.layer_ffn == ("experts", "none", "experts") and nemotron.ssm_groups == 2 and not nemotron.ffn_gated
+
+  def programs(cfg):
+    params, shard = full_model_params(jax.random.PRNGKey(0), cfg)
+    B, mp = 2, 128 // PS
+    pool = init_paged_pool(cfg, cfg.n_layers, 1 + B * mp, PS, n_slots=B)
+    bt = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
+    decode = jax.make_jaxpr(lambda pool: paged_decode_forward(params, cfg, shard, jnp.ones((B, 1), jnp.int32), jnp.asarray([[3], [5]], jnp.int32), pool, bt, PS, False))(pool)
+    lens = jnp.asarray([20, 32], jnp.int32)
+    prefill = jax.make_jaxpr(lambda pool: prefill_into_pages_many.xot_jitted.__wrapped__(params, cfg, shard, jnp.ones((B, 32), jnp.int32), pool, bt, jnp.zeros((B,), jnp.int32), lens, PS, None, jnp.arange(B, dtype=jnp.int32)))(pool)
+    return _unscoped_primitives(decode.jaxpr), _unscoped_primitives(prefill.jaxpr)
+
+  gated = tiny_test_config(n_layers=2, max_seq_len=128, n_experts=4, n_active_experts=2, moe_hidden_dim=32, shared_expert_dim=32)
+  (decode, prefill), (granite_decode, granite_prefill), (gated_decode, _) = programs(nemotron), programs(granite), programs(gated)
+  assert decode <= granite_decode | gated_decode, sorted(decode - granite_decode - gated_decode)
+  assert prefill <= granite_prefill | gated_decode, sorted(prefill - granite_prefill - gated_decode)

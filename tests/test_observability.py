@@ -1148,3 +1148,30 @@ async def test_profile_endpoint(tmp_path, monkeypatch):
   finally:
     await client.close()
     await node.stop()
+
+
+def test_an_ungated_expert_stack_and_a_step_without_an_ffn_are_counted_as_they_are():
+  """``_note_expert_form`` (ISSUE 53): the expert layers of a decode step are the stacks' layers that hold an expert's
+  DOWN matrix — an ungated expert has no gate leaf, and a layer step with no FFN at all is no expert-layer step —, so
+  ``moe_expert_layer_steps_total`` grows by them alone; the gauge ``moe_expert_gate`` names relu² experts."""
+  from types import SimpleNamespace
+
+  import jax
+
+  from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import full_model_params
+  from xotorch_support_jetson_tpu.utils.metrics import metrics
+
+  cfg = config_from_hf(dict(
+    model_type="nemotron_h", hidden_size=32, num_hidden_layers=7, hybrid_override_pattern="MEM*EME", num_attention_heads=4, num_key_value_heads=2, head_dim=8, vocab_size=64, intermediate_size=32,
+    mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=8, n_groups=2, conv_kernel=4, chunk_size=16, n_routed_experts=4, num_experts_per_tok=2, moe_intermediate_size=16, n_shared_experts=1,
+    moe_shared_expert_intermediate_size=16, norm_topk_prob=True, routed_scaling_factor=2.5, torch_dtype="float32", max_position_embeddings=64,
+  ))  # fmt: skip
+  params, _ = full_model_params(jax.random.PRNGKey(0), cfg)
+  assert cfg.n_layers == 4 and cfg.expert_layers == 3 and "w_experts_gate" not in params["ssm_moe_layers"]
+  stub = SimpleNamespace(engine=SimpleNamespace(cfg=cfg, params=params), _expert_layers=0)
+  BatchedServer._note_expert_form(stub)
+  assert stub._expert_layers == 3
+  snap = Metrics.merged([metrics.snapshot()])
+  assert snap.gauge_value("moe_expert_gate", labels={"act": "relu2"}) == 3 and snap.gauge_value("moe_expert_gate", labels={"act": "silu"}) == 0 and snap.gauge_value("moe_router_input", labels={"at": "ffn"}) == 3

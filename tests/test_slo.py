@@ -360,15 +360,21 @@ def test_terminal_invariant_refusal_paths(path, monkeypatch):
 
   async def run():
     streams = {}
+    resident = asyncio.Event()
 
     def emit(r, toks, fin):
       streams.setdefault(r, []).extend(toks)
+      if r == "bg-" + path:
+        resident.set()
 
     # A long-running resident occupies the single slot; a queued waiter
-    # fills the queue for the overload paths.
-    bg = asyncio.create_task(server.submit("bg-" + path, np.asarray([3, 25, 9], np.int32), max_tokens=30, temp=0.0, top_k=35, eos_ids=(), emit=emit, priority="standard", tenant="bulk"))
-    while not any(streams.get("bg-" + path) or []):
-      await asyncio.sleep(0.01)
+    # fills the queue for the overload paths. The resident's first token WAKES
+    # this task (no poll on a timer) and it has 99 tokens to go in chunks of
+    # two: with a warm compile cache 30 tokens were over inside one 10 ms poll,
+    # the waiter was admitted, and the loop below never ended (the suite hung
+    # at 96-99 % until its clock cut it: PR 53). A lost race now fails.
+    bg = asyncio.create_task(server.submit("bg-" + path, np.asarray([3, 25, 9], np.int32), max_tokens=100, temp=0.0, top_k=35, eos_ids=(), emit=emit, priority="standard", tenant="bulk"))
+    await asyncio.wait_for(resident.wait(), timeout=120)
     waiter = None
     if path in ("rejected", "shed_overload"):
       # Fill the 1-deep queue. For the shed path the waiter is strictly
@@ -377,7 +383,8 @@ def test_terminal_invariant_refusal_paths(path, monkeypatch):
       # the new arrival itself is rejected.
       waiter = asyncio.create_task(server.submit("w-" + path, np.asarray([4, 4, 4], np.int32), max_tokens=4, temp=0.0, top_k=35, eos_ids=(), emit=emit, priority="batch" if path == "shed_overload" else "interactive", tenant="bulk"))
       while server.queue.qsize() == 0:
-        await asyncio.sleep(0.01)
+        assert not waiter.done() and not bg.done(), "the resident left its slot before the waiter was queued"
+        await asyncio.sleep(0)
     if path == "shed_deadline":
       monkeypatch.setattr(server.qos, "estimate_completion_ms", lambda **kw: 1e9)
     if path == "rate_limited":

@@ -305,3 +305,38 @@ def test_what_a_decode_program_is_told_where_its_caller_did_not_say(what, overri
   assert paged_kernel_supported(cfg, platform) is want, what
   if cfg.is_mla:  # told the kernel, the layer steps attend through it exactly where the latent body tiles the model
     assert kernel_attends(cfg, True) is (cfg.kv_lora_rank % 128 == 0 and cfg.mosaic_kernels)
+
+
+# ------------------------------------------------------------ B and C a head (several B/C groups, ISSUE 53)
+
+
+def _per_head(t, H: int, groups: int):
+  """[B, N] drawn for one group → [B, H, N] with ``groups`` different groups, each spread over its H / groups heads."""
+  grouped = jnp.stack([jnp.roll(t, g, axis=-1) * (1.0 + 0.25 * g) for g in range(groups)], axis=1)  # [B, G, N]
+  return jnp.repeat(grouped, H // groups, axis=1)
+
+
+@SHAPES
+@pytest.mark.parametrize("groups", [1, 4])
+def test_b_and_c_a_head_step_both_forms_as_the_einsum_does(shape, groups):
+  """``bm``, ``cm`` [B, H, N] — a model of several B/C groups, each head its group's (``models/decoder.py
+  _ssm_decode_step`` spreads them) — in the reference expression and in the one-pass kernel (interpreted; its blocks of
+  B and C are the decay's, [Hb, N]) against the recurrence written as einsums; an inactive row keeps its tile bit for
+  bit. With one group spread over every head both forms give what the [B, N] operand gives."""
+  leaf, a, dtx, bm, cm = inputs(7, shape)
+  H = shape[2]
+  bh, ch = _per_head(bm, H, groups), _per_head(cm, H, groups)
+  active = jnp.arange(shape[1]) != 1
+  (state, y), (want_state, want_y) = both(leaf, 1, a, dtx, bh, ch, active)
+  new = np.asarray(a)[:, :, None, None] * np.asarray(leaf[1]) + np.einsum("bhp,bhn->bhpn", np.asarray(dtx), np.asarray(bh))
+  np.testing.assert_allclose(want_state[1][np.asarray(active)], new[np.asarray(active)], rtol=1e-6, atol=1e-6)
+  np.testing.assert_allclose(want_y[np.asarray(active)], np.einsum("bhpn,bhn->bhp", new, np.asarray(ch))[np.asarray(active)], rtol=1e-5, atol=1e-5 * np.abs(want_y).max())
+  np.testing.assert_allclose(state, want_state, rtol=1e-6, atol=1e-6)
+  np.testing.assert_allclose(y[np.asarray(active)], want_y[np.asarray(active)], rtol=1e-5, atol=1e-5 * np.abs(want_y).max())
+  np.testing.assert_array_equal(state[1, 1], np.asarray(leaf[1, 1]))
+  np.testing.assert_array_equal(state[0], np.asarray(leaf[0]))
+  if groups == 1:
+    (one_state, one_y), _ = both(leaf, 1, a, dtx, bm, cm, active)
+    np.testing.assert_array_equal(state, one_state)
+    np.testing.assert_allclose(y, one_y, rtol=1e-6, atol=1e-6)
+  assert ssm.state_step_form(leaf, True) == "one_pass"

@@ -441,3 +441,60 @@ def test_swa_nope_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_se
     print(f"prefill.pages_many_sampled smallthinker K={K} S={S} window={window}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
     # That it compiled is the fit (its temporaries overlap the donated pool's buffers: PERF.md section 6, PR 44).
     assert mem.alias_size_in_bytes >= pool_bytes and mem.argument_size_in_bytes < 13.4e9
+
+
+def test_hybrid_ssm_moe_decode_step_and_longest_prefill_at_the_cells_settings_fit_v5e(chip, monkeypatch):
+  """Nemotron-3-Nano's first stage as ``nemotron-3-nano.reason-closed-64`` serves it (ISSUE 53): 64 slots, 5121 pages of
+  ONE attention layer (2 KV heads of 128, 16 queries a KV head — the widest group in the benchmark; the paged kernel,
+  the token write and the flash prefill needed no tile rule), bf16, all 128 ungated experts of 4 expert blocks held:
+  12.15 GB of weights, 0.55 GB of state, 0.34 GB of pages. ``decode.paged_batch``'s Mosaic calls are the grouped state
+  step (``ssm_state_step`` with B and C a head, one call in each of the three runs of Mamba steps, the leaf aliased
+  through it), the ungated experts' two (``moe_up``, whose block is an expert's whole [1856, 2688] matrix as stored, and
+  ``moe_down``) and the attention layer's two. No stacked expert leaf, and no layer of one, is copied, cut out or relaid
+  — an up matrix stored [2688, 1856] is: XLA:TPU keeps a stack whose minor axis is no whole number of lanes
+  column-major and copies all 5.3 GB of it for the Mosaic call (AOT, PR 53), which is why both matrices of an ungated
+  expert are stored [F, D]. The largest prefill program the cell meets, a group of 8 rows padded to 2048 tokens with the
+  pool donated, fits beside them."""
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, prefill_into_pages_many_sampled_inplace, served_expert_form
+  from xotorch_support_jetson_tpu.ops.paged import paged_kernel_supported
+  from xotorch_support_jetson_tpu.ops.ssm import state_step_form
+
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, "nemotron-3-nano-30b-a3b-d9")
+  assert paged_kernel_supported(cfg, "tpu") and served_expert_form(params, cfg) == "grouped" and state_step_form(pool["ssm"], True) == "one_pass"
+  n_slots = pool["ssm"].shape[1]
+  assert pool["k"].shape == (1, 5121, 2, PS, 128) and pool["ssm"].shape == (4, 64, 64, 64, 128) and pool["conv"].shape == (4, 64, 3, 6144)
+  assert params["ssm_moe_layers"]["w_experts_up_t"].shape == (3, 128, 1856, 2688) and params["moe_layers"]["w_experts_down"].shape == (1, 128, 1856, 2688) and "w_router" not in params["ssm_mixer_layers"]
+  shard, rows = Shard("nemotron", 0, cfg.n_layers - 1, cfg.n_layers), _rows(chip, n_slots)
+  window = _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32)
+  compiled, text = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), pool, window, rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
+    _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+  calls = _mosaic_calls(text)
+  # (the paged kernel's call carries no name of its own: counted with the rest — three state steps, three runs of
+  # expert steps with two calls each, the attention step's kernel and token write)
+  assert sorted(set(calls)) == ["kv_token_write", "moe_down", "moe_up", "ssm_state_step"] and calls.count("ssm_state_step") == 3 and text.count('custom_call_target="tpu_custom_call"') == 3 + 3 * 2 + 2, calls
+  experts = r"bf16\[(3,|1,)?128,1856,2688\]"
+  assert {op for _, op in _takers(text, experts)} == {"custom-call"}  # the kernels alone take the stacks: no fusion cuts a layer out of one
+  state = r"f32\[(4,)?64,64,64,128\]"
+  copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= ({state}|{experts})\S* (copy|copy-start|transpose)\(", line)]
+  assert not copied, copied
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch nemotron B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= 4 * 64 * 64 * 64 * 128 * 4 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+  K, S = 8, 2048
+  rows = _rows(chip, K)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend (through XLA's attention the group's scores alone are 4 GB)
+  compiled, text = _compile(
+    prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool, _sds(chip, (K, 32), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS,
+    rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
+  )  # fmt: skip
+  moved = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {experts}\S* (copy|copy-start|transpose|fusion|dynamic-slice)\(", line)]
+  assert not moved, moved
+  assert {"moe_up", "moe_down"} <= set(_mosaic_calls(text)) and "flash_attention_prefill" in text, _mosaic_calls(text)
+  mem = compiled.memory_analysis()
+  print(f"prefill.pages_many_sampled nemotron K=8 S=2048: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

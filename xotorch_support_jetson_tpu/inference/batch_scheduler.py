@@ -342,6 +342,12 @@ class _Group:
 # size, and groups as it always did (``_dispatch_groups``).
 GROUP_SLOTS_WHOLE = 16
 GROUP_ROWS = 8
+# The admission pass behind a chunk lets a burst of arrivals finish landing (``_wait_late_into``): it runs once the
+# newest arrival is ``BURST_QUIET_S`` old, and no later than ``BURST_WAIT_MAX_S`` after it could first have run. On the
+# chip's host eight callers at once reach the queue over 8-13 ms with up to 6.7 ms between two of them, four over 2.5-5
+# ms (PERF.md §6, PR 53's review round): the quiet is twice the widest gap seen.
+BURST_QUIET_S = 0.012
+BURST_WAIT_MAX_S = 0.04
 
 
 class BatchedServer:
@@ -356,6 +362,8 @@ class BatchedServer:
     self.n_slots = self.ops.round_slots(n_slots or int(os.getenv("XOT_TPU_BATCH_SLOTS", "4")))
     self.chunk = chunk or int(os.getenv("XOT_TPU_BATCH_CHUNK", "8"))
     self._expert_layers = 0  # expert layers a decode step passes: set with the pool (``_note_expert_form``)
+    self._last_arrival = 0.0  # when the newest request was queued (``submit``): what ``_wait_late_into`` lets settle
+    self._group_shapes: set[tuple[int, int, int]] = set()  # (rows, padded length, pages a row) of the paged prefill groups staged here: their programs exist (``_covered_rows``)
     # Per-request top_k IS honored (traced per row, like temperature —
     # ops/sampling.py sample_logits_per_row); only the candidate-set cap
     # ``k_max`` is static in the compiled program. Requests asking for more
@@ -589,6 +597,7 @@ class BatchedServer:
     if carry:
       req.carry_tokens = list(carry)
     await self.admission.enqueue(req)
+    self._last_arrival = time.perf_counter()
     self._update_gauges()
     if self._loop_task is None or self._loop_task.done():
       self._loop_task = asyncio.create_task(self._run())
@@ -1617,6 +1626,21 @@ class BatchedServer:
       kpad *= 2
     return max(min(kpad, self.n_slots), K)
 
+  def _covered_rows(self, kpad: int, S_pad: int, pages: int) -> int:
+    """The rows a paged prefill group of ``kpad`` rows is staged at. ``kpad`` itself — unless the server is one whose
+    prefill programs are those of 1, 2, 4 and 8 rows and no others (more than ``GROUP_SLOTS_WHOLE`` slots) and has been
+    warmed (``POST /v1/warmup`` marked the ledger steady: from then on a compile in service is a fault, the recompile
+    sentinel's), no group of that shape has been staged on it, and one of MORE rows at the same padded length and
+    page window has: then that one's, the fewest there are. A program first met in service stops every row for its
+    compile (~10 s at 64 slots), and whatever was meant to meet it first — a warm-up's group of four that the admission
+    pass cut into two and two (PR 53: [4, 640], [4, 896], [4, 1024] over two rounds) — may not have; the program of
+    eight rows that stands ready costs such a group some tens of milliseconds of padding rows instead, every time that
+    shape comes (which is why a server nobody declared warm compiles the exact shape, once, as it always did). Shapes
+    are met smallest first wherever they are met in order, so a warm-up that missed nothing never takes this turn."""
+    if self.n_slots <= GROUP_SLOTS_WHOLE or not ledger.steady or (kpad, S_pad, pages) in self._group_shapes:
+      return kpad
+    return min((k for k, s, p in self._group_shapes if k > kpad and (s, p) == (S_pad, pages)), default=kpad)
+
   def _stage_group(self, group: list[_Ready], all_rows: set[int], tick: int, chain_base=None):
     """Host operands of one prefill group and the ``run()`` closure that
     stages them on the executor thread and ENQUEUES the program: it returns
@@ -1629,7 +1653,14 @@ class BatchedServer:
     K = len(group)
     S_pad = max(r.pad_to for r in group)
     kpad = self._row_bucket(K)
-    if not self.paged:
+    if self.paged:
+      # The window must cover each row's PADDED write reach (the program
+      # writes S_pad slots from prefix_len; pad garbage scatters to trash),
+      # which the scatter-clamp grouping already bounds to max_seq.
+      mp_used = self._page_window(max(int(r.prefix_len) for r in group) + S_pad)
+      kpad = self._covered_rows(kpad, S_pad, mp_used)
+      self._group_shapes.add((kpad, S_pad, mp_used))
+    else:
       # Dense padding rows scatter garbage into a real slot, so each needs a
       # DISTINCT spare free slot (never a slot another admission owns —
       # scatter order between duplicate rows is undefined). Without enough
@@ -1668,11 +1699,6 @@ class BatchedServer:
       # copy traffic — by the chunk count for chunked prefills, and by
       # window/prompt for ordinary short-prompt admissions. Power-of-two
       # bucketing bounds the compiled-shape count at log2(pages_per_row).
-      ps = self.page_size
-      # The window must cover each row's PADDED write reach (the program
-      # writes S_pad slots from prefix_len; pad garbage scatters to trash),
-      # which the scatter-clamp grouping already bounds to max_seq.
-      mp_used = self._page_window(max(int(r.prefix_len) for r in group) + S_pad)
       bts = np.zeros((n_rows, mp_used), dtype=np.int32)
       prefix_lens = np.zeros((n_rows,), dtype=np.int32)
       for i, r in enumerate(group):
@@ -2718,7 +2744,7 @@ class BatchedServer:
     from ..ops.moe import EXPERT_ACTS, FFN_FORMS
 
     cfg, params = self.engine.cfg, getattr(self.engine, "params", None)
-    self._expert_layers = sum(stack["w_experts_gate"].shape[0] for stack in (params or {}).values() if isinstance(stack, dict) and "w_experts_gate" in stack)
+    self._expert_layers = sum(stack["w_experts_down"].shape[0] for stack in (params or {}).values() if isinstance(stack, dict) and "w_experts_down" in stack)  # the stacks THIS engine holds (a shard of the layers counts its own; gated or not, an expert has a down matrix)
     if not cfg.n_experts:
       return
     form = served_expert_form(params, cfg)
@@ -2727,7 +2753,7 @@ class BatchedServer:
     # The expert layers by where their router reads (``cfg.router_input``: its experts' own input, or the attention's,
     # drawn ahead of it) and by their experts' gate (``cfg.expert_act``): one value a model today, a count so that a
     # model of two says so.
-    n_expert_layers = self._expert_layers or cfg.n_layers - cfg.first_k_dense
+    n_expert_layers = self._expert_layers or cfg.expert_layers
     for at in ("ffn", "attn"):
       metrics.set_gauge("moe_router_input", n_expert_layers * int(at == cfg.router_input), labels={"at": at})
     for act in EXPERT_ACTS:
@@ -2946,10 +2972,21 @@ class BatchedServer:
     thread serves the arriving requests meanwhile — until a quarter of the chunk's expected time is left
     (``SchedClock.expected``: what its kind last took, from when it got the device), which is several times what the
     pass and its staging cost the host, and no longer than the chunk runs: it wakes every 5 ms at most and stops
-    when the chunk's tokens are ready. No estimate yet (the first chunk of a kind): no wait."""
+    when the chunk's tokens are ready. No estimate yet (the first chunk of a kind): no wait.
+
+    Then, whatever the chunk: a burst that began to land just before that point is let finish. k callers at once
+    reach the queue in lumps some milliseconds apart (seen as 4 + 4 and 2 + 2 in a rehearsal of the benchmark's warm-up,
+    gaps of up to 6.7 ms on the chip's host, PR 53), and a pass between the lumps made two
+    groups of k/2 — the one cut that leaves the k-row program uncompiled. The pass therefore waits until the newest
+    arrival is ``BURST_QUIET_S`` old, at most ``BURST_WAIT_MAX_S`` in all (a steady stream cannot hold it), a
+    millisecond a sleep; where nobody arrived in the last ``BURST_QUIET_S`` — every boundary of a closed loop in its
+    steady state, whose callers come back early in a chunk — it costs nothing."""
     ready = getattr(chunk.toks, "is_ready", None)
     while (est := self.clock.expected()) is not None and est[0] > 0.25 * est[1] and not (ready is not None and ready()):
       await asyncio.sleep(min(est[0] - 0.25 * est[1], 0.005))
+    give_up = time.perf_counter() + BURST_WAIT_MAX_S
+    while (now := time.perf_counter()) < min(self._last_arrival + BURST_QUIET_S, give_up):
+      await asyncio.sleep(min(self._last_arrival + BURST_QUIET_S - now, 0.001))
 
   def _needs_settled_state(self) -> bool:
     """With a chunk in flight: does what comes next need that chunk SETTLED first? (All of it is host state the loop

@@ -136,7 +136,14 @@ class ModelConfig:
   # input of the layer's ATTENTION — the choice is drawn ahead of the attention and carried across it to the experts,
   # which read the stream after the attention's residual (models/decoder.py ``_route_ahead``).
   router_input: str = "ffn"
-  expert_act: str = "silu"  # the routed experts' gate nonlinearity, one of ops/moe.py ``EXPERT_ACTS``: "silu" (SwiGLU) | "relu" (ReGLU)
+  expert_act: str = "silu"  # the experts' nonlinearity (routed and shared), one of ops/moe.py ``EXPERT_ACTS``: "silu" (SwiGLU) | "relu" (ReGLU) | "relu2" (relu(x)²)
+  # The FFN's form, dense, routed and shared alike: True, W_down(act(W_gate x) * W_up x); False (nemotron_h), two matrices
+  # and no gate, W_down act(W_up x) — the stacks then hold no ``w_gate`` / ``w_experts_gate`` / ``w_shared_gate`` leaf.
+  ffn_gated: bool = True
+  # Which FFN each layer step has, "dense" | "experts" | "none", one a layer; () ⇒ the ``first_k_dense`` rule (experts
+  # from that layer on in a model with experts, else dense). "none": the step ends with its mixer's residual — a
+  # published block of ONE sublayer whose successor is another mixer (nemotron_h's ``M`` ahead of a ``*``).
+  layer_ffn: tuple = ()
   # Group-limited routing (deepseek): experts are grouped; only experts in the
   # top ``topk_group`` groups are eligible. Group score = max expert score
   # (v2 "group_limited_greedy") or sum of top-2 (v3 "noaux_tc").
@@ -160,7 +167,7 @@ class ModelConfig:
   post_norms: bool = False
   # False: no norm ahead of a sublayer. With ``post_norms`` that is OLMo 2's reordered block, h += norm(f(h)).
   pre_norms: bool = True
-  mlp_act: str = "silu"  # "silu" | "gelu_tanh"
+  mlp_act: str = "silu"  # the dense FFN's: "silu" | "gelu_tanh" | "relu2"
   attn_logit_softcap: float = 0.0  # 0 ⇒ off
   final_logit_softcap: float = 0.0
   query_pre_attn_scalar: float = 0.0  # 0 ⇒ scale by 1/sqrt(qk head dim)
@@ -187,8 +194,11 @@ class ModelConfig:
   # one kind, each with ``ssm_heads`` heads, a causal depthwise convolution of
   # ``ssm_conv`` taps and a chunked prefill of ``ssm_chunk`` positions
   # (models/decoder.py). Three kinds, two update rules (ops/ssm.py):
-  # - "mamba" (granitemoehybrid): a Mamba-2 mixer (one group); a head's state
-  #   is [``ssm_head_dim`` channels x ``ssm_state``], decayed by one scalar.
+  # - "mamba" (granitemoehybrid, nemotron_h): a Mamba-2 mixer; a head's state
+  #   is [``ssm_head_dim`` channels x ``ssm_state``], decayed by one scalar;
+  #   B and C come in ``ssm_groups`` groups (head h reads group
+  #   h // (heads / groups)) and the gated norm runs over each group's
+  #   channels by itself (1, granite: one B, one C, one norm over all).
   # - "kda" (bailing_hybrid): Kimi Delta Attention; a head's state is a matrix
   #   [``ssm_head_dim`` values x ``ssm_state`` key channels], square there,
   #   decayed per key channel and corrected by a rank-one delta rule with beta
@@ -204,7 +214,7 @@ class ModelConfig:
   # The stacked parameters are named by (mixer, FFN)
   # pairing (``layer_stack``): ``layers`` / ``moe_layers`` the attention layers
   # with a dense / an expert FFN, ``ssm_layers`` / ``ssm_moe_layers`` the
-  # recurrent ones; attention layers of a second shape (``layer_attn``: other
+  # recurrent ones, ``ssm_mixer_layers`` those with no FFN at all; attention layers of a second shape (``layer_attn``: other
   # query heads, another rope, a gate) take stacks under their kind's name
   # (``window_moe_layers``). The page pool keeps pages for the attention layers
   # only — one K/V leaf for every attention kind: the KV heads and the head size
@@ -216,6 +226,7 @@ class ModelConfig:
   ssm_state: int = 0
   ssm_conv: int = 0
   ssm_chunk: int = 256
+  ssm_groups: int = 1  # "mamba": groups of B and C (and of the gated norm)
   kda_lower_bound: float = 0.0  # "kda": the log decay of a key channel lies in (kda_lower_bound, 0)
   gdn_beta_scale: float = 1.0  # "gdn": beta = gdn_beta_scale x sigmoid; 2 where the transition may have negative eigenvalues
   # bailing_hybrid's ``use_qk_norm`` as read for its MLA layers: an RMSNorm over each query head's nope+rope channels
@@ -295,14 +306,25 @@ class ModelConfig:
     """Experts whose weights this shard holds: the expert axis of the expert leaves."""
     return self.experts_held[1] - self.experts_held[0] if self.experts_held else self.n_experts
 
+  def ffn_kind(self, layer_idx: int) -> str:
+    """Layer ``layer_idx``'s FFN, "dense" | "experts" | "none": its ``layer_ffn`` entry, else the ``first_k_dense`` rule."""
+    if self.layer_ffn:
+      return self.layer_ffn[layer_idx]
+    return "experts" if self.n_experts and layer_idx >= self.first_k_dense else "dense"
+
+  @property
+  def expert_layers(self) -> int:
+    """How many layer steps route experts."""
+    return sum(1 for i in range(self.n_layers) if self.ffn_kind(i) == "experts")
+
   def layer_stack(self, layer_idx: int) -> str:
     """The stacked-parameter dict layer ``layer_idx`` lives in, by its (mixer, FFN) pairing; an attention kind whose
-    shape is not the model's first (``attn_shapes``) prefixes its stacks with its name (``window_moe_layers``)."""
+    shape is not the model's first (``attn_shapes``) prefixes its stacks with its name (``window_moe_layers``); a layer
+    step with no FFN lives in ``mixer_layers``."""
     recurrent = bool(self.layer_types) and self.layer_types[layer_idx] in RECURRENT_KINDS
-    experts = bool(self.n_experts) and layer_idx >= self.first_k_dense
     kind = None if recurrent or not self.layer_attn else self.layer_attn[layer_idx]
     prefix = "ssm_" if recurrent else f"{kind.name}_" if kind is not None and kind.shape != self.attn_shapes[0] else ""
-    return prefix + ("moe_layers" if experts else "layers")
+    return prefix + {"experts": "moe_layers", "dense": "layers", "none": "mixer_layers"}[self.ffn_kind(layer_idx)]
 
   @property
   def attn_windows(self) -> tuple:
@@ -325,10 +347,10 @@ class ModelConfig:
 
   @property
   def ssm_conv_dim(self) -> int:
-    """Channels the convolution runs over: x and the one group's B and C ("mamba"); every head's q, k and v ("kda", "gdn")."""
+    """Channels the convolution runs over: x and every group's B and C ("mamba"); every head's q, k and v ("kda", "gdn")."""
     if self.recurrent_kind in ("kda", "gdn"):
       return self.ssm_heads * (2 * self.ssm_state + self.ssm_head_dim)
-    return self.ssm_inner + 2 * self.ssm_state
+    return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
   @property
   def qk_head_dim(self) -> int:
@@ -378,7 +400,7 @@ RECURRENT_KINDS = ("mamba", "kda", "gdn")  # the ``layer_types`` whose layers ke
 # a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
 MODEL_FAMILIES = {
   "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
-  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "smallthinker": "smallthinker", "llama": "llama",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "smallthinker": "smallthinker", "nemotron_h": "nemotron-h", "llama": "llama",
 }
 
 
@@ -495,10 +517,12 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
       shared_dim = int(hf.get("shared_expert_intermediate_size") or 0)
     if family == "bailing-hybrid":
       shared_dim = int(hf.get("num_shared_experts") or 0) * int(hf.get("moe_shared_expert_intermediate_size") or moe_hidden)
+    if family == "nemotron-h":
+      shared_dim = n_shared * int(hf.get("moe_shared_expert_intermediate_size") or moe_hidden)
     # deepseek group-limited routing: v3 is always sigmoid + top-2-sum group
     # scores (HF DeepseekV3TopkRouter); v2 keys it on topk_method.
     # (laguna's row names no score function: deepseek-v3's router, whose 256 / top-8 / 2.5 its keys repeat, is assumed)
-    scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family in ("deepseek-v3", "laguna")) else "softmax"
+    scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family in ("deepseek-v3", "laguna", "nemotron-h")) else "softmax"
     if family == "deepseek-v3" or hf.get("topk_method") == "noaux_tc":
       group_mode = "top2sum"
     elif hf.get("topk_method") == "group_limited_greedy":
@@ -565,18 +589,20 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     hybrid = _laguna_fields(hf)
   if family == "smallthinker":
     hybrid = _smallthinker_fields(hf)
+  if family == "nemotron-h":
+    hybrid = _nemotron_h_fields(hf)
 
   n_heads = int(hf["num_attention_heads"])
+  hybrid.setdefault("n_layers", int(hf["num_hidden_layers"]))  # (nemotron_h counts its layer STEPS: two published blocks can be one)
   return ModelConfig(
     vocab_size=int(hf["vocab_size"]),
     dim=int(hf["hidden_size"]),
-    n_layers=int(hf["num_hidden_layers"]),
     n_heads=n_heads,
     n_kv_heads=int(hf.get("num_key_value_heads", n_heads)),
     # (smallthinker: every layer an expert layer, no dense FFN width at all)
     hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if family == "granite-hybrid" else int(hf.get("intermediate_size") or 0) if family == "smallthinker" else int(hf["intermediate_size"]),
     head_dim=int(hf.get("head_dim") or 0),
-    norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+    norm_eps=float(hf.get("layer_norm_epsilon", hf.get("norm_eps", 1e-5)) if family == "nemotron-h" else hf.get("rms_norm_eps", 1e-5)),
     rope_theta=float(hf.get("rope_theta") or 10000.0),
     rope_scaling=rope_scaling,
     max_seq_len=int(hf.get("max_position_embeddings", 8192)),
@@ -794,6 +820,61 @@ def _smallthinker_fields(hf: dict) -> dict:
   if len({k.shape for k in kinds}) < len(kinds) or len({k.name for k in kinds[1:]}) < len(kinds[1:]):
     raise ValueError(f"smallthinker: rope_layout {layouts['rope_layout']} and sliding_window_layout {layouts['sliding_window_layout']} give layers that differ in the window alone, or two kinds of one name: not supported")
   return dict(layer_attn=layer_attn, router_input="attn", expert_act="relu")
+
+
+def _nemotron_h_fields(hf: dict) -> dict:
+  """``nemotron_h`` (Nemotron-H, arXiv:2504.03624; Nemotron-3-Nano) → the hybrid fields of ModelConfig. A published block
+  is ``h + f(rmsnorm(h))`` with ONE sublayer ``f``, named by its letter in ``hybrid_override_pattern``: ``M`` a Mamba-2
+  mixer (``mamba_num_heads`` heads of ``mamba_head_dim``, ``n_groups`` groups of B and C of ``ssm_state_size``, the gate
+  ahead of a norm over each group's channels), ``*`` grouped-query attention with no position term, ``E`` the routed
+  experts (sigmoid scores, a selection bias, the chosen scores over their sum times ``routed_scaling_factor``; two
+  matrices and no gate an expert, relu² between them; one shared expert of the same form). Two consecutive blocks — a
+  mixer, then ``E`` — are exactly the (mixer, FFN) step the decoder runs, so the pattern is read as layer STEPS:
+  a mixer letter opens one and an ``E`` right behind it is its FFN, else it has none (``layer_ffn`` "none");
+  ``n_layers`` counts the steps (29 for the published 52 letters). What cannot be read so, and what the decoder does
+  not implement, is refused here, by name."""
+  pattern = str(hf.get("hybrid_override_pattern") or "")
+  if len(pattern) != int(hf["num_hidden_layers"]) or set(pattern) - set("ME*-"):
+    raise ValueError(f"nemotron_h: hybrid_override_pattern must name num_hidden_layers = {hf['num_hidden_layers']} blocks, each 'M', 'E', '*' or '-'; got {pattern!r}")
+  if "-" in pattern:
+    raise ValueError("nemotron_h: the letter '-' in hybrid_override_pattern (a dense MLP block) is not supported")
+  mixers, ffns = [], []
+  for at, letter in enumerate(pattern):
+    if letter in "M*":
+      mixers.append("mamba" if letter == "M" else "attention")
+      ffns.append("none")
+    elif not ffns or ffns[-1] != "none":
+      raise ValueError(f"nemotron_h: hybrid_override_pattern {pattern!r} cannot be read as (mixer, FFN) steps: the 'E' at block {at} has no mixer block right ahead of it")
+    else:
+      ffns[-1] = "experts"
+  on = [key for key in ("mamba_proj_bias", "use_bias", "attention_bias", "mlp_bias", "sliding_window", "rope_scaling", "residual_in_fp32") if hf.get(key)]
+  if on:
+    raise ValueError(f"nemotron_h: {', '.join(on)} is not supported")
+  if int(hf.get("n_group") or 1) > 1 and int(hf.get("topk_group") or 1) < int(hf["n_group"]):
+    raise ValueError("nemotron_h: n_group > 1 with topk_group < n_group (group-limited routing) is not supported")
+  for key, only in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu")):
+    if hf.get(key, only) != only:
+      raise ValueError(f"nemotron_h: {key} {hf[key]!r} is not supported (only {only!r})")
+  if "experts" in ffns and not int(hf.get("n_routed_experts") or 0):
+    raise ValueError("nemotron_h: an 'E' block needs n_routed_experts above 0")
+  heads, groups = int(hf["mamba_num_heads"]), int(hf.get("n_groups") or 1)
+  if heads % groups:
+    raise ValueError(f"nemotron_h: mamba_num_heads {heads} is no multiple of n_groups {groups}")
+  return dict(
+    n_layers=len(mixers),
+    layer_types=tuple(mixers),
+    layer_ffn=tuple(ffns),
+    ssm_heads=heads,
+    ssm_head_dim=int(hf["mamba_head_dim"]),  # d_inner = heads x head_dim; ``expand`` is not read
+    ssm_state=int(hf["ssm_state_size"]),
+    ssm_conv=int(hf.get("conv_kernel") or 4),
+    ssm_chunk=int(hf.get("chunk_size") or 128),
+    ssm_groups=groups,
+    ffn_gated=False,
+    expert_act="relu2",
+    mlp_act="relu2",
+    use_rope=False,  # the family's attention applies no position term: the Mamba layers carry position (``rope_theta`` stands unread)
+  )
 
 
 def load_model_config(model_dir: str | Path, dtype=None) -> ModelConfig:

@@ -259,8 +259,13 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       leaves["k_norm"] = jnp.ones((L, Kd if cfg.qk_norm_whole else cfg.head_dim), dtype=dtype)
     return leaves
 
+  def dense_ffn(L):
+    """The dense FFN's leaves; an ungated one (``cfg.ffn_gated`` false) has no ``w_gate``."""
+    gate = {"w_gate": w(next(keys), L, D, F)} if cfg.ffn_gated else {}
+    return {**gate, "w_up": w(next(keys), L, D, F), "w_down": w(next(keys), L, F, D)}
+
   def dense_stack(L):
-    stack = {**attn_leaves(L), "w_gate": w(next(keys), L, D, F), "w_up": w(next(keys), L, D, F), "w_down": w(next(keys), L, F, D)}
+    stack = {**attn_leaves(L), **dense_ffn(L)}
     if cfg.post_norms:  # gemma2's post-attention / post-feedforward norms
       stack["post_attn_norm"] = jnp.ones((L, D), dtype=dtype)
       stack["post_mlp_norm"] = jnp.ones((L, D), dtype=dtype)
@@ -270,16 +275,18 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
 
   def expert_ffn(Lm):
     E, Eh, Fm, Fs = cfg.n_experts, cfg.n_held_experts, cfg.moe_hidden_dim, cfg.shared_expert_dim
-    moe = {
-      "w_router": w(next(keys), Lm, D, E),
-      "w_experts_gate": w(next(keys), Lm, Eh, D, Fm),
-      "w_experts_up": w(next(keys), Lm, Eh, D, Fm),
-      "w_experts_down": w(next(keys), Lm, Eh, Fm, D),
-    }
+    moe = {"w_router": w(next(keys), Lm, D, E)}
+    if cfg.ffn_gated:
+      moe["w_experts_gate"] = w(next(keys), Lm, Eh, D, Fm)
+      moe["w_experts_up"] = w(next(keys), Lm, Eh, D, Fm)
+    else:  # an ungated expert is two matrices, both stored [Fm, D] (ops/moe.py: the inner width never along the lanes); no ``w_shared_gate`` either
+      moe["w_experts_up_t"] = w(next(keys), Lm, Eh, Fm, D, scale=D**-0.5)
+    moe["w_experts_down"] = w(next(keys), Lm, Eh, Fm, D)
     if cfg.router_scoring == "sigmoid":
       moe["router_bias"] = jnp.zeros((Lm, E), dtype=jnp.float32)
     if Fs:
-      moe["w_shared_gate"] = w(next(keys), Lm, D, Fs)
+      if cfg.ffn_gated:
+        moe["w_shared_gate"] = w(next(keys), Lm, D, Fs)
       moe["w_shared_up"] = w(next(keys), Lm, D, Fs)
       moe["w_shared_down"] = w(next(keys), Lm, Fs, D)
       if cfg.shared_expert_gate:
@@ -300,7 +307,7 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
       "A_log": jnp.log(jax.random.uniform(next(keys), (Ls, H), jnp.float32, 1.0, 16.0)),
       "D": jnp.ones((Ls, H), jnp.float32),
-      "gate_norm": jnp.ones((Ls, di), dtype=dtype),
+      "gate_norm": jnp.ones((Ls, di), dtype=dtype),  # (with ``cfg.ssm_groups`` groups: each group's channels normed by themselves, under their slice of this gain)
       "w_out": w(next(keys), Ls, di, D),
     }
 
@@ -332,15 +339,16 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       "w_out": w(next(keys), Ls, H * P, D),
     }
 
-  def block_norms(stack: Params, n: int, mixer: str) -> Params:
+  def block_norms(stack: Params, n: int, mixer: str, ffn: bool = True) -> Params:
     """A hybrid stack's mixer leaves with the block's norms: those ahead of its two sublayers dropped without
-    ``cfg.pre_norms``, those after them added with ``cfg.post_norms``."""
+    ``cfg.pre_norms``, those after them added with ``cfg.post_norms``; a step with no FFN (``ffn`` false) has the
+    mixer's alone."""
     stack = {"mlp_norm": jnp.ones((n, D), dtype=dtype), **stack}
     if not cfg.pre_norms:
       stack = {name: leaf for name, leaf in stack.items() if name not in (f"{mixer}_norm", "mlp_norm")}
     if cfg.post_norms:
       stack |= {name: jnp.ones((n, D), dtype=dtype) for name in (f"post_{mixer}_norm", "post_mlp_norm")}
-    return stack
+    return stack if ffn else {name: leaf for name, leaf in stack.items() if name not in ("mlp_norm", "post_mlp_norm")}
 
   params: Params = {}
   if cfg.mixed_layers:
@@ -350,10 +358,10 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     names = [cfg.layer_stack(i) for i in range(cfg.n_layers)]
     recurrent_leaves = {"mamba": mamba_leaves, "kda": kda_leaves, "gdn": gdn_leaves}.get(cfg.recurrent_kind)
     for name in dict.fromkeys(names):  # in the order the model meets them
-      n, recurrent = names.count(name), name.startswith("ssm_")
+      n, recurrent, ffn = names.count(name), name.startswith("ssm_"), cfg.ffn_kind(names.index(name))
       kind = cfg.layer_attn[names.index(name)] if cfg.layer_attn else None  # (None at a recurrent layer too)
-      mixer = block_norms(recurrent_leaves(n), n, "ssm") if recurrent else block_norms(attn_leaves(n, kind), n, "attn")
-      params[name] = {**mixer, **(expert_ffn(n) if name.endswith("moe_layers") else {"w_gate": w(next(keys), n, D, F), "w_up": w(next(keys), n, D, F), "w_down": w(next(keys), n, F, D)})}
+      mixer = block_norms(recurrent_leaves(n), n, "ssm", ffn != "none") if recurrent else block_norms(attn_leaves(n, kind), n, "attn", ffn != "none")
+      params[name] = {**mixer, **{"experts": expert_ffn, "dense": dense_ffn, "none": lambda n: {}}[ffn](n)}
   elif cfg.n_experts:
     # MoE model: dense prefix (layers [0, first_k_dense) globally), MoE rest.
     n_dense = min(max(cfg.first_k_dense - shard.start_layer, 0), L)
@@ -517,6 +525,8 @@ def _dense_qkv(x, p, cfg: ModelConfig, positions, inv_freq, adapter_ids=None):
 def _mlp_act(x, cfg: ModelConfig):
   if cfg.mlp_act == "gelu_tanh":  # gemma2's gelu_pytorch_tanh
     return jax.nn.gelu(x.astype(jnp.float32), approximate=True)
+  if cfg.mlp_act == "relu2":  # nemotron_h's: relu(x)², in float32
+    return jnp.square(jax.nn.relu(x.astype(jnp.float32)))
   return jax.nn.silu(x.astype(jnp.float32))
 
 
@@ -592,17 +602,21 @@ def _route_ahead(x, p, cfg: ModelConfig):
 
 
 def _mlp_block(h, p, cfg: ModelConfig, routed=None):
-  """Post-attention norm + FFN (dense or MoE+shared-expert). Returns (h, aux, visited): the router's auxiliary loss and
-  the number of distinct held experts the rows chose (0 and 0 for a dense FFN). ``routed``: the layer's routing where
-  it was drawn ahead of the attention (``_route_ahead``)."""
+  """Post-attention norm + FFN (dense or MoE+shared-expert), gated or — ``cfg.ffn_gated`` false — two matrices with the
+  nonlinearity between them. Returns (h, aux, visited): the router's auxiliary loss and the number of distinct held
+  experts the rows chose (0 and 0 for a dense FFN). ``routed``: the layer's routing where it was drawn ahead of the
+  attention (``_route_ahead``). A layer step with no FFN at all (``cfg.layer_ffn`` "none": its stack holds no FFN leaf)
+  is over with its mixer's residual: ``h`` comes back as it came, and no expert is visited."""
   B, S, D = h.shape
+  aux, visited = jnp.float32(0.0), jnp.int32(0)
+  if "w_down" not in p and "w_experts_down" not in p:
+    return h, aux, visited
   with jax.named_scope("xot.ffn"):
     x = rms_norm(h, p["mlp_norm"], cfg.norm_eps) if "mlp_norm" in p else h  # (absent: a block whose norms follow its sublayers)
-  aux, visited = jnp.float32(0.0), jnp.int32(0)
-  if "w_experts_gate" in p:  # routed MoE FFN (ops/moe.py) + optional shared expert
-    from ..ops.moe import moe_ffn
+  if "w_experts_down" in p:  # routed MoE FFN (ops/moe.py) + optional shared expert
+    from ..ops.moe import EXPERT_ACTS, moe_ffn
 
-    names = ("w_experts_gate", "w_experts_up", "w_experts_down")
+    names = ("w_experts_gate", "w_experts_up", "w_experts_down") if cfg.ffn_gated else ("w_experts_up_t", "w_experts_down")
     xt = x.reshape(B * S, D)
     if "expert_layer" in p:
       # The grouped form: the layer loop asked ops/moe.py ``ffn_form`` and handed the stack's expert leaves over whole
@@ -624,7 +638,7 @@ def _mlp_block(h, p, cfg: ModelConfig, routed=None):
     out, aux, visited = moe_ffn(
       xt,
       p["w_router"],
-      *experts,
+      *(experts if cfg.ffn_gated else (None, *experts)),  # an ungated expert has no gate matrix
       **_routing_args(p, cfg),
       capacity_factor=cfg.moe_capacity_factor,
       **form,
@@ -632,9 +646,11 @@ def _mlp_block(h, p, cfg: ModelConfig, routed=None):
       act=cfg.expert_act,
       routed=routed,
     )
-    if "w_shared_gate" in p:
+    if "w_shared_down" in p:
       with jax.named_scope("xot.moe_shared"):
-        shared = jax.nn.silu(_mm(xt, p, "w_shared_gate", cfg.quant_compute).astype(jnp.float32)).astype(h.dtype) * _mm(xt, p, "w_shared_up", cfg.quant_compute)
+        shared = EXPERT_ACTS[cfg.expert_act](_mm(xt, p, "w_shared_gate" if cfg.ffn_gated else "w_shared_up", cfg.quant_compute).astype(jnp.float32)).astype(h.dtype)
+        if cfg.ffn_gated:
+          shared = shared * _mm(xt, p, "w_shared_up", cfg.quant_compute)
         shared = _mm(shared, p, "w_shared_down", cfg.quant_compute)
         if "w_shared_expert_gate" in p:  # qwen2-moe sigmoid-gated shared expert
           shared = shared * jax.nn.sigmoid((xt @ p["w_shared_expert_gate"]).astype(jnp.float32)).astype(h.dtype)
@@ -642,7 +658,9 @@ def _mlp_block(h, p, cfg: ModelConfig, routed=None):
     h = h + out.reshape(B, S, D)
   else:
     with jax.named_scope("xot.ffn"):
-      gated = _mlp_act(_mm(x, p, "w_gate", cfg.quant_compute), cfg).astype(h.dtype) * _mm(x, p, "w_up", cfg.quant_compute)
+      gated = _mlp_act(_mm(x, p, "w_gate" if cfg.ffn_gated else "w_up", cfg.quant_compute), cfg).astype(h.dtype)
+      if cfg.ffn_gated:
+        gated = gated * _mm(x, p, "w_up", cfg.quant_compute)
       out = _mm(gated, p, "w_down", cfg.quant_compute)
       if "post_mlp_norm" in p:  # gemma2's post-feedforward layernorm; OLMo 2's only one
         out = rms_norm(out, p["post_mlp_norm"], cfg.norm_eps)
@@ -651,11 +669,13 @@ def _mlp_block(h, p, cfg: ModelConfig, routed=None):
 
 
 # ------------------------------------------------ state-space (Mamba-2) mixer
-# (granitemoehybrid's "mamba" layers; HF ``GraniteMoeHybridMambaLayer``, one
-# group.) [z | xBC | dt] = u W_in (three leaves here, w_z | w_xbc | w_dt); xBC through a causal depthwise convolution
-# of ``ssm_conv`` taps and silu; [x | B | C] = xBC; per head Δ = softplus(dt +
+# (granitemoehybrid's "mamba" layers, HF ``GraniteMoeHybridMambaLayer``, one
+# group; nemotron_h's ``M`` blocks, ``cfg.ssm_groups`` groups.) [z | xBC | dt] = u W_in (three leaves here, w_z | w_xbc | w_dt); xBC through a causal depthwise convolution
+# of ``ssm_conv`` taps and silu; [x | B | C] = xBC, B and C in G groups of N
+# (head h reads group h // (H / G)); per head Δ = softplus(dt +
 # dt_bias), a = exp(-Δ exp(A_log)); S_t = a_t S_{t-1} + Δ_t x_t ⊗ B_t;
-# y_t = S_t C_t + D x_t; out = rms(y ⊙ silu(z)) W_out. What a row keeps
+# y_t = S_t C_t + D x_t; out = rms_group(y ⊙ silu(z)) W_out, the norm over
+# each group's di / G channels by itself (all of di with one group). What a row keeps
 # between calls is S [H, P, N] in float32 and the last ``ssm_conv - 1`` rows of
 # the pre-convolution xBC: the two per-slot leaves ``ssm`` and ``conv`` that
 # ride beside the K/V pages in the page pool (ops/paged.py init_paged_pool).
@@ -672,7 +692,7 @@ def _mlp_block(h, p, cfg: ModelConfig, routed=None):
 
 @component_scope("xot.ssm_proj")
 def _ssm_in(h, p, cfg: ModelConfig):
-  """Norm and input projection: h [B,S,D] → z [B,S,di], xBC [B,S,di+2N], dt [B,S,H]."""
+  """Norm and input projection: h [B,S,D] → z [B,S,di], xBC [B,S,di+2GN], dt [B,S,H]."""
   u = rms_norm(h, p["ssm_norm"], cfg.norm_eps)
   return tuple(_mm(u, p, name, cfg.quant_compute) for name in ("w_z", "w_xbc", "w_dt"))
 
@@ -712,31 +732,43 @@ def _step_conv(pool: Params, xp, conv0, layer, active) -> Params:
 
 
 def _ssm_split(xbc, cfg: ModelConfig):
-  """Activated xBC [..., di+2N] → x [..., H, P], B [..., N], C [..., N]."""
-  di, N = cfg.ssm_inner, cfg.ssm_state
+  """Activated xBC [..., di+2GN] → x [..., H, P], B [..., G, N], C [..., G, N]."""
+  di, G, N = cfg.ssm_inner, cfg.ssm_groups, cfg.ssm_state
   x = xbc[..., :di].reshape(*xbc.shape[:-1], cfg.ssm_heads, cfg.ssm_head_dim)
-  return x, xbc[..., di : di + N], xbc[..., di + N :]
+  return x, xbc[..., di : di + G * N].reshape(*xbc.shape[:-1], G, N), xbc[..., di + G * N :].reshape(*xbc.shape[:-1], G, N)
 
 
 def _ssm_gate(y, x, z, p, cfg: ModelConfig):
-  """Skip, gate, then the norm over all ``di`` channels (one group): y, x [..., H, P] f32, z [..., di]."""
+  """Skip, gate, then the norm over each group's ``di / G`` channels by itself (one group: over all ``di``; the gain
+  is one [di] leaf either way): y, x [..., H, P] f32, z [..., di]."""
   y = y + p["D"].astype(jnp.float32)[:, None] * x
   g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
-  return rms_norm(g, p["gate_norm"], cfg.norm_eps).astype(z.dtype)
+  grouped = lambda t: t.reshape(*t.shape[:-1], cfg.ssm_groups, -1)  # noqa: E731
+  return rms_norm(grouped(g), grouped(p["gate_norm"]), cfg.norm_eps).reshape(z.shape).astype(z.dtype)
 
 
 def _ssm_chunk_scan(x, dt, a_log, bm, cm, state, chunk: int):
   """The recurrence over a sequence, a chunk at a time (the SSD form).
 
   x [B,S,H,P]; dt [B,S,H] f32 (0 at a padded position: the state passes it
-  unchanged); a_log [H] f32 = -exp(A_log); bm, cm [B,S,N]; state [B,H,P,N]
-  f32. Returns (y [B,S,H,P] f32 without the skip, state after position S-1).
+  unchanged); a_log [H] f32 = -exp(A_log); bm, cm [B,S,G,N], G groups of which
+  head h reads group h // (H/G); state [B,H,P,N] f32. Returns (y [B,S,H,P] f32
+  without the skip, state after position S-1).
   Inside a chunk of L positions y is a masked [L, L] product, as attention
   is; between chunks only the state is carried, so the [B, H, L, L] decay
-  term exists for one chunk at a time."""
+  term exists for one chunk at a time. Several groups carry the group axis
+  through every product, the heads as [G, H/G]; one group (granite) keeps
+  the expressions without it, because the grouped ones at G = 1 are ANOTHER
+  program to XLA:TPU (granite's 8 x 1024 prefill, AOT for a described v5e:
+  11,590 lines of optimised text for 11,736, 1,202,173,440 B of temporaries for
+  1,206,850,560, 43.83 GB accessed for 44.04, the same flops — PERF.md §6, PR 53)
+  and no chip run has said which of the two is the faster."""
   B, S, H, P = x.shape
+  G = bm.shape[2]
   L = min(chunk, S)
   pad = -S % L
+  if G == 1:
+    bm, cm = bm[:, :, 0], cm[:, :, 0]
   if pad:
     x, dt, bm, cm = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)) for t in (x, dt, bm, cm))
   chunks = lambda t: jnp.moveaxis(t.reshape(B, -1, L, *t.shape[2:]), 1, 0)  # noqa: E731 — [c, B, L, ...]
@@ -749,11 +781,19 @@ def _ssm_chunk_scan(x, dt, a_log, bm, cm, state, chunk: int):
     xdt = (xc.astype(jnp.float32) * dtc[..., None]).astype(mm)
     seg = cs[:, :, None, :] - cs[:, None, :, :]  # [B,l,s,H]: log decay over (s, l]
     decay = jnp.where(tri[None, :, :, None], jnp.exp(jnp.where(tri[None, :, :, None], seg, 0.0)), 0.0)
-    cb = jnp.einsum("bln,bsn->bls", cc, bc, preferred_element_type=jnp.float32)
-    y = jnp.einsum("blsh,bshp->blhp", (cb[..., None] * decay).astype(mm), xdt, preferred_element_type=jnp.float32)
-    y = y + jnp.einsum("bln,bhpn->blhp", cc, state.astype(mm), preferred_element_type=jnp.float32) * jnp.exp(cs)[..., None]
     to_end = jnp.exp(cs[:, -1:, :] - cs)  # [B,L,H]
-    grown = jnp.einsum("bln,blhp->bhpn", bc, (xdt.astype(jnp.float32) * to_end[..., None]).astype(mm), preferred_element_type=jnp.float32)
+    if G == 1:
+      cb = jnp.einsum("bln,bsn->bls", cc, bc, preferred_element_type=jnp.float32)
+      y = jnp.einsum("blsh,bshp->blhp", (cb[..., None] * decay).astype(mm), xdt, preferred_element_type=jnp.float32)
+      y = y + jnp.einsum("bln,bhpn->blhp", cc, state.astype(mm), preferred_element_type=jnp.float32) * jnp.exp(cs)[..., None]
+      grown = jnp.einsum("bln,blhp->bhpn", bc, (xdt.astype(jnp.float32) * to_end[..., None]).astype(mm), preferred_element_type=jnp.float32)
+    else:
+      heads = lambda t, axis: t.reshape(*t.shape[:axis], G, H // G, *t.shape[axis + 1 :])  # noqa: E731 — the head axis as [G, H/G]
+      cb = jnp.einsum("blgn,bsgn->blsg", cc, bc, preferred_element_type=jnp.float32)
+      y = jnp.einsum("blsgh,bsghp->blghp", (cb[..., None] * heads(decay, 3)).astype(mm), heads(xdt, 2), preferred_element_type=jnp.float32)
+      y = y + jnp.einsum("blgn,bghpn->blghp", cc, heads(state.astype(mm), 1), preferred_element_type=jnp.float32) * heads(jnp.exp(cs), 2)[..., None]
+      y = y.reshape(B, L, H, P)
+      grown = jnp.einsum("blgn,blghp->bghpn", bc, heads((xdt.astype(jnp.float32) * to_end[..., None]).astype(mm), 2), preferred_element_type=jnp.float32).reshape(state.shape)
     return jnp.exp(cs[:, -1, :])[:, :, None, None] * state + grown, y
 
   state, y = jax.lax.scan(body, state, tuple(chunks(t) for t in (x, dt, bm, cm)))
@@ -761,7 +801,8 @@ def _ssm_chunk_scan(x, dt, a_log, bm, cm, state, chunk: int):
 
 
 def _ssm_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
-  """One state-space layer over a sequence: h [B,S,D], the rows' states ssm0
+  """One state-space layer step over a sequence — the mixer and the FFN its
+  stack pairs it with, if any (``_mlp_block``): h [B,S,D], the rows' states ssm0
   [B,H,P,N] f32 and conv0 [B,K-1,C] → (h, ssm, conv) after each row's
   ``seq_lens`` tokens (None: all S). Positions past a row's length are
   padding: Δ = 0 there and the convolution's tail is cut at the length, so
@@ -799,7 +840,9 @@ def _ssm_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bo
     x = x.astype(jnp.float32)
     dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"])  # [B,H]
     a = jnp.exp(dt * -jnp.exp(p["A_log"].astype(jnp.float32)))
-    ssm, y = ssm_state_step(pool["ssm"], layer, a, dt[:, :, None] * x, bm.astype(jnp.float32), cm.astype(jnp.float32), active, use_kernel)
+    # One group: B and C [B, N], every head's; several: [B, H, N], each head its group's (32 KB a row beside a 2.1 MB state).
+    per_head = (lambda t: t[:, 0]) if cfg.ssm_groups == 1 else (lambda t: jnp.repeat(t, cfg.ssm_heads // cfg.ssm_groups, axis=1))
+    ssm, y = ssm_state_step(pool["ssm"], layer, a, dt[:, :, None] * x, per_head(bm.astype(jnp.float32)), per_head(cm.astype(jnp.float32)), active, use_kernel)
     pool = _step_conv({**pool, "ssm": ssm}, xp, conv0, layer, active)
     y = _ssm_gate(y[:, None], x[:, None], z, p, cfg)
   h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
@@ -1929,7 +1972,8 @@ def _whole_expert_leaves(params: Params, cfg: ModelConfig) -> tuple:
   from ..ops.moe import ffn_form
 
   for stack in (stack for name, stack in params.items() if name.endswith("moe_layers")):
-    if ffn_form(stack["w_experts_gate"], stack["w_experts_down"], cfg.moe_capacity_factor, cfg.mosaic_kernels, "w_experts_gate_scale" in stack) == "grouped":
+    first = "w_experts_gate" if cfg.ffn_gated else "w_experts_up_t"
+    if ffn_form(stack[first], stack["w_experts_down"], cfg.moe_capacity_factor, cfg.mosaic_kernels, f"{first}_scale" in stack, gated=cfg.ffn_gated) == "grouped":
       return tuple(name for name in stack if name.startswith("w_experts_"))
   return ()
 

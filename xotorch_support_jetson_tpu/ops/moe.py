@@ -53,8 +53,19 @@ decision, made once a program. Every other caller — the cache-less forward
   fuses that into the einsum's read).
 
 Both take bf16 operands, accumulate in float32, apply the gate's nonlinearity
-(``EXPERT_ACTS``: silu, or relu for ReGLU experts — a static choice, from the
-configuration) in float32 and combine in float32.
+(``EXPERT_ACTS``: silu, relu for ReGLU experts, relu² — a static choice, from
+the configuration) in float32 and combine in float32.
+
+**An expert may have no gate** (``w_gate`` None; nemotron_h): two matrices,
+``W_down act(W_up x)``, and BOTH stored [.., F, D] — the first as HF stores it,
+out-major (leaf ``w_experts_up_t``): an inner width that is no whole number of
+lanes (nemotron_h's 1856 = 29 x 64) then never lies along the lanes, where
+XLA:TPU would store the stack column-major and copy it whole for a Mosaic call
+(5.3 GB at those widths; AOT, PERF.md §6, PR 53). The block form then has two
+einsums, and the grouped form's first kernel is up-only (``moe_up``: one whole
+[F, D] block a visit against the rows' transposed contraction, the
+nonlinearity in it), then the same ``moe_down``: a third fewer expert bytes a
+visit.
 
 **The routing is an operand of its own** (``route`` → ``Routed``): by default
 ``moe_ffn`` draws it from the tokens it computes from, inside ``_moe_ffn_*``;
@@ -172,15 +183,15 @@ def route(x, w_router, k, scoring="softmax", norm_topk=False, selection_bias=Non
     return Routed(logits, *router_topk(logits, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode))
 
 
-# The routed experts' gate nonlinearity, W_down(act(W_gate y) * W_up y): the one owner of the choice, for both forms
-# (the Mosaic body of ``moe_gate_up`` spells the same two in ``_gate_up_kernel``). Applied in float32.
-EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# The experts' nonlinearity, W_down(act(W_gate y) * W_up y) or, without a gate, W_down act(W_up y): the one owner of the
+# choice, for both forms (the Mosaic bodies spell the same in ``_act_in_kernel``). Applied in float32.
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu, "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, capacity_factor, n_group, topk_group, group_mode, held=None, act="silu", routed=None):
   """One dispatch/compute/combine block over [T, D] tokens. Returns (out, aux, visited)."""
   T, D = x.shape
-  E, E_held = w_router.shape[-1], w_gate.shape[0]
+  E, E_held = w_router.shape[-1], w_down.shape[0]
   logits, weights, idx = routed or route(x, w_router, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
   with jax.named_scope("xot.moe_router"):
     C = expert_capacity(T, k, E, capacity_factor)
@@ -189,9 +200,13 @@ def _moe_ffn_block(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, sel
 
   with jax.named_scope("xot.moe_experts"):
     xin = jnp.einsum("td,tec->ecd", x, dispatch.astype(x.dtype))  # [E, C, D]
-    gated = EXPERT_ACTS[act](jnp.einsum("ecd,edf->ecf", xin, w_gate).astype(jnp.float32)).astype(x.dtype)
-    up = jnp.einsum("ecd,edf->ecf", xin, w_up)
-    out = jnp.einsum("ecf,efd->ecd", gated * up, w_down)  # [E, C, D]
+    if w_gate is None:  # an expert of two matrices, W_down act(W_up x), the first stored [E, F, D] as the second is
+      hidden = EXPERT_ACTS[act](jnp.einsum("ecd,efd->ecf", xin, w_up).astype(jnp.float32)).astype(x.dtype)
+    else:
+      gated = EXPERT_ACTS[act](jnp.einsum("ecd,edf->ecf", xin, w_gate).astype(jnp.float32)).astype(x.dtype)
+      up = jnp.einsum("ecd,edf->ecf", xin, w_up)
+      hidden = gated * up
+    out = jnp.einsum("ecf,efd->ecd", hidden, w_down)  # [E, C, D]
     out = jnp.einsum("ecd,tec->td", out.astype(jnp.float32), combine).astype(x.dtype)
   with jax.named_scope("xot.moe_router"):
     aux = load_balancing_loss(logits, idx, E)
@@ -207,6 +222,7 @@ _VMEM_LIMIT = 64 << 20  # v5e has 128 MiB; the default scoped limit (16 MiB) hol
 # program, ~0.2 MB a token at Ling's widths, beside a pool and weights that fill the chip. A longer run is cut by
 # tokens (a Python loop: a ``lax.map`` would make the layer's expert leaves operands of a loop and copy them).
 GROUPED_MAX_TOKENS = 4096
+_INNER_GROUP = 16  # what an ungated expert's inner width F is a whole number of: the sublanes of a packed bfloat16 tile (F lies along them in both of its matrices)
 FFN_FORMS = ("grouped", "block")  # what ``ffn_form`` answers
 INTERPRET = False  # the tests' switch: a CPU takes the grouped form too, its kernels interpreted
 
@@ -224,20 +240,27 @@ def _col_tile(K: int, N: int, itemsize: int) -> int | None:
   return max(fits, default=None)
 
 
-def ffn_form(w_gate, w_down, capacity_factor, mosaic_kernels: bool, scaled: bool = False) -> str:
-  """The form the routed experts' product takes for these expert leaves ([..., E, D, F] and [..., E, F, D], arrays or
-  ShapeDtypeStructs) — the label of the gauge ``moe_ffn_form``. "grouped" where nothing may drop, the program may run
+def ffn_form(w_gate, w_down, capacity_factor, mosaic_kernels: bool, scaled: bool = False, gated: bool = True) -> str:
+  """The form the routed experts' product takes for these expert leaves ([..., E, D, F] the gate's and [..., E, F, D],
+  arrays or ShapeDtypeStructs; ``gated`` false: an expert without a gate, whose first matrix is stored [..., E, F, D]
+  as its second is) — the label of the gauge ``moe_ffn_form``. "grouped" where nothing may drop, the program may run
   Mosaic kernels (``cfg.mosaic_kernels``, which the engine clears for a plan that leaves a mesh axis to GSPMD — a
   Mosaic call cannot be partitioned automatically — and a TPU), the leaves are bfloat16 / float32, or int8 codes with
   per-output-channel scales (``scaled``; a packed int4 leaf has half the rows and is refused), and both faces are whole
   lane groups a block of which fits VMEM. Anything else: "block"."""
   if not mosaic_kernels or not (_on_tpu() or INTERPRET) or capacity_factor is not None:
     return "block"
-  (D, F), down = w_gate.shape[-2:], w_down.shape[-2:]
+  (D, F), down = w_gate.shape[-2:] if gated else w_gate.shape[-2:][::-1], w_down.shape[-2:]
   if down != (F, D) or w_gate.dtype != w_down.dtype or w_gate.dtype not in ((jnp.int8,) if scaled else (jnp.bfloat16, jnp.float32)):
     return "block"
   size = jnp.dtype(w_gate.dtype).itemsize
-  tiles = D % LANES == 0 and F % LANES == 0 and _col_tile(D, F, size) is not None and _col_tile(F, D, size) is not None
+  if gated:
+    tiles = D % LANES == 0 and F % LANES == 0 and _col_tile(D, F, size) is not None and _col_tile(F, D, size) is not None
+  else:
+    # The first product takes an expert's [F, D] matrix as ONE block, double-buffered, in half the VMEM limit (an output
+    # block [rows, part of F] would have to be whole lanes, and F need not be: 1856 = 29 x 64): 9.98 MB x 2 of 64 at
+    # nemotron_h's widths. F is the second product's contraction axis and lies along sublanes in both matrices.
+    tiles = not scaled and D % LANES == 0 and F % _INNER_GROUP == 0 and 2 * F * D * size <= _VMEM_LIMIT // 2 and _col_tile(F, D, size) is not None
   return "grouped" if tiles else "block"
 
 
@@ -269,6 +292,15 @@ def _own_rows(offsets_ref, group_ref, tile_ref, tm: int):
   return (rows >= offsets_ref[g]) & (rows < offsets_ref[g + 1])
 
 
+def _act_in_kernel(x, act: str):
+  """``EXPERT_ACTS`` as a Mosaic body spells them, on float32."""
+  if act == "relu":
+    return jnp.maximum(x, 0.0)
+  if act == "relu2":
+    return jnp.square(jnp.maximum(x, 0.0))
+  return x * jax.nn.sigmoid(x)
+
+
 def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, wu_ref, *rest, tm: int, scaled: bool, act: str = "silu"):
   del layer_ref  # the index maps read it
   (sg_ref, su_ref, out_ref) = rest if scaled else (None, None, *rest)
@@ -277,8 +309,16 @@ def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, 
   up = jnp.dot(x, wu_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)
   if scaled:
     gate, up = gate * sg_ref[...], up * su_ref[...]
-  h = (jnp.maximum(gate, 0.0) if act == "relu" else gate * jax.nn.sigmoid(gate)) * up  # ``EXPERT_ACTS``, in float32
+  h = _act_in_kernel(gate, act) * up
   out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), h, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def _up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wu_ref, out_ref, *, tm: int, scaled: bool, act: str):
+  """The ungated expert's first product with its nonlinearity: act(x W_upᵀ), the matrix [F, D] as stored."""
+  del layer_ref, scaled  # (``ffn_form`` admits no codes here)
+  x = x_ref[...]
+  up = jax.lax.dot_general(x, wu_ref[...].astype(x.dtype), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+  out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), _act_in_kernel(up, act), out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
 def _down_kernel(layer_ref, offsets_ref, group_ref, tile_ref, h_ref, wd_ref, *rest, tm: int, scaled: bool):
@@ -291,17 +331,20 @@ def _down_kernel(layer_ref, offsets_ref, group_ref, tile_ref, h_ref, wd_ref, *re
   out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), y, out_ref[...])
 
 
-def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: int, out_dtype):
+def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: int, out_dtype, out_major: bool = False):
   """``rows`` [M, K] against each visit's expert in the stacked ``weights`` ([L, E, K, N] each; the layer's ``scales``
-  [E, 1, N] float32, or none) → [M, N]: rows no visit owns come back as the kernel found them."""
+  [E, 1, N] float32, or none) → [M, N]: rows no visit owns come back as the kernel found them. ``out_major``: the
+  weights are stored [L, E, N, K] and a block is an expert's whole matrix."""
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
   offsets, group, tile, n_visits = walk
-  (M, K), N = rows.shape, weights[0].shape[-1]
-  tn = _col_tile(K, N, weights[0].dtype.itemsize) or N
+  (M, K), N = rows.shape, weights[0].shape[-2 if out_major else -1]
+  tn = N if out_major else _col_tile(K, N, weights[0].dtype.itemsize) or N
   row_block = pl.BlockSpec((tm, K), lambda j, v, layer, offsets, group, tile: (tile[v], 0))
   weight_block = pl.BlockSpec((None, None, K, tn), lambda j, v, layer, offsets, group, tile: (layer[0], group[v], 0, j))
+  if out_major:
+    weight_block = pl.BlockSpec((None, None, N, K), lambda j, v, layer, offsets, group, tile: (layer[0], group[v], 0, 0))
   scale_block = pl.BlockSpec((None, 1, tn), lambda j, v, layer, offsets, group, tile: (group[v], 0, j))
   out_block = pl.BlockSpec((tm, tn), lambda j, v, layer, offsets, group, tile: (tile[v], j))
   return pl.pallas_call(
@@ -320,11 +363,12 @@ def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: 
 
 
 def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None, scales=None, layer=0, act="silu", routed=None):
-  """The grouped form over [T, D] tokens (nothing can drop). Expert leaves stacked, [L, E, D, F] / [L, E, F, D], with
-  ``layer`` a (traced) scalar; ``scales`` their per-output-channel scales ([L, E, F], [L, E, F], [L, E, D]) where the
-  leaves are int8 codes. Returns (out, aux, visited)."""
+  """The grouped form over [T, D] tokens (nothing can drop). Expert leaves stacked, [L, E, D, F] / [L, E, F, D]
+  (``w_gate`` None: an expert without a gate, ``w_up`` stored [L, E, F, D] as ``w_down`` is), with ``layer`` a (traced)
+  scalar; ``scales`` their per-output-channel scales ([L, E, F], [L, E, F], [L, E, D]) where the leaves are int8
+  codes. Returns (out, aux, visited)."""
   T, D = x.shape
-  E, E_held, M = w_router.shape[-1], w_gate.shape[1], T * k
+  E, E_held, M = w_router.shape[-1], w_down.shape[1], T * k
   tm = ROW_TILE if M >= ROW_TILE else -(-M // 16) * 16  # (a bfloat16 tile is 16 sublanes)
   Mp = -(-M // tm) * tm
   logits, weights, idx = routed or route(x, w_router, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
@@ -341,8 +385,11 @@ def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, s
     # (the layer's scales are cut out of their stack — kilobytes, where an expert leaf's layer is most of a GB — as
     # [E, 1, N]: a block is one expert's row)
     cut = tuple(jax.lax.dynamic_index_in_dim(s, layer, 0, keepdims=False).astype(jnp.float32)[:, None, :] for s in scales or ())
-    h = _grouped_product(partial(_gate_up_kernel, act=act), "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, walk, tm, x.dtype)
-    y = _grouped_product(_down_kernel, "moe_down", h, (w_down,), cut[2:], layer, walk, tm, jnp.float32)
+    if w_gate is None:  # an expert of two matrices, both stored [F, D]
+      h = _grouped_product(partial(_up_kernel, act=act), "moe_up", rows, (w_up,), (), layer, walk, tm, x.dtype, out_major=True)
+    else:
+      h = _grouped_product(partial(_gate_up_kernel, act=act), "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, walk, tm, x.dtype)
+    y = _grouped_product(_down_kernel, "moe_down", h, (w_down,), cut[-1:], layer, walk, tm, jnp.float32)
     # Rows of an expert not held, and rows that pad the last tile, hold whatever the kernels found there: they are
     # taken out by ``where``, never multiplied by a zero.
     y = jnp.where((expert < E_held)[:, None], y, 0.0)
@@ -355,8 +402,8 @@ def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, s
 def moe_ffn(
   x: jnp.ndarray,  # [T, D] tokens (flattened batch*seq)
   w_router: jnp.ndarray,  # [D, E]
-  w_gate: jnp.ndarray,  # [E, D, F] per-expert gate proj ([L, E, D, F] with ``layer``)
-  w_up: jnp.ndarray,  # [E, D, F]
+  w_gate: jnp.ndarray | None,  # [E, D, F] per-expert gate proj ([L, E, D, F] with ``layer``); None: an expert of two matrices, W_down act(W_up x)
+  w_up: jnp.ndarray,  # [E, D, F]; [E, F, D] where ``w_gate`` is None
   w_down: jnp.ndarray,  # [E, F, D]
   k: int,
   scoring: str = "softmax",
@@ -374,7 +421,7 @@ def moe_ffn(
   act: str = "silu",
   routed: Routed | None = None,
 ):
-  """Routed gated FFN (``act``: one of ``EXPERT_ACTS``; silu is SwiGLU) over ``E`` experts; returns ([T, D] in x.dtype, the router's auxiliary loss, the number of
+  """Routed FFN — gated (``act``: one of ``EXPERT_ACTS``; silu is SwiGLU), or two matrices an expert where ``w_gate`` is None — over ``E`` experts; returns ([T, D] in x.dtype, the router's auxiliary loss, the number of
   distinct held experts the rows chose: int32, summed over the blocks or pieces of a long run).
 
   ``held`` = (lo, hi): this shard's share of an expert-parallel layer. The

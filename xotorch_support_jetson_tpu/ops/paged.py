@@ -83,7 +83,7 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   of its state-space layers for each of ``n_slots`` rows: ``ssm``
   [Ls, n_slots, H, P, N] in float32 (the recurrence multiplies it by a decay
   near 1 for thousands of steps; what it loses in bfloat16 is read in PERF.md
-  §6, PR 34) and ``conv`` [Ls, n_slots, K-1, di+2N], the rows the convolution
+  §6, PR 34) and ``conv`` [Ls, n_slots, K-1, di+2GN] (G groups of B and C: ``cfg.ssm_conv_dim``), the rows the convolution
   still needs, in the model dtype. Zeros: a slot's state before its first
   tenant, and what a prefill from position 0 starts from. In decode the
   ``ssm`` leaf is stepped in place at (layer) by ``ops/ssm.py

@@ -6,7 +6,9 @@ row's state S of every state-space layer. One decode step of one layer is
 
   S ← a · S + (Δ·x) ⊗ B        y = S · C
 
-per row and head, with a [B, H] the decay, Δ·x [B, H, P], B and C [B, N], all
+per row and head, with a [B, H] the decay, Δ·x [B, H, P], B and C [B, N] — one
+group, every head's — or [B, H, N], each head its group's (a model of several
+B/C groups: the caller spreads a group over its heads, kilobytes a row), all
 float32. The state is nearly all the bytes of the layer's step (268 MB read
 and written at 64 rows of granite-4.0-h-micro; everything else is kilobytes a
 row), so what matters is how often it crosses HBM. This module holds the two
@@ -63,6 +65,8 @@ has the same two forms, chosen the same way (``delta_one_pass_supported``):
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -123,8 +127,8 @@ def ssm_state_step(ssm_leaf, layer, a, dtx, bm, cm, active, use_kernel: bool = F
   """One recurrence step of state-space layer ``layer`` for every slot row.
 
   ssm_leaf [Ls, B, H, P, N] float32, the pool's carried leaf, stepped in place at ``layer`` (a traced scalar); a
-  [B, H] the decay; dtx [B, H, P] = Δ·x; bm, cm [B, N]; active [B] bool — all float32. Returns (ssm_leaf, y
-  [B, H, P] float32). A row that is not ``active`` keeps its state bit for bit; its ``y`` is of no use to anyone (the
+  [B, H] the decay; dtx [B, H, P] = Δ·x; bm, cm [B, N], every head's, or [B, H, N], a head's own (several B/C groups);
+  active [B] bool — all float32. Returns (ssm_leaf, y [B, H, P] float32). A row that is not ``active`` keeps its state bit for bit; its ``y`` is of no use to anyone (the
   one-pass form writes zeros there)."""
   if one_pass_supported(ssm_leaf, use_kernel):
     return _state_step_one_pass(ssm_leaf, layer, a, dtx, bm, cm, active, interpret)
@@ -133,8 +137,9 @@ def ssm_state_step(ssm_leaf, layer, a, dtx, bm, cm, active, use_kernel: bool = F
 
 def _state_step_reference(ssm_leaf, layer, a, dtx, bm, cm, active):
   ssm0 = jax.lax.dynamic_index_in_dim(ssm_leaf, layer, 0, keepdims=False).astype(jnp.float32)
-  ssm = a[:, :, None, None] * ssm0 + dtx[..., None] * bm[:, None, None, :]
-  y = jnp.einsum("bhpn,bn->bhp", ssm, cm)
+  heads = "h" if bm.ndim == 3 else ""  # B and C a head's own, or every head's
+  ssm = a[:, :, None, None] * ssm0 + dtx[..., None] * bm.reshape(bm.shape[0], -1, 1, bm.shape[-1])
+  y = jnp.einsum(f"bhpn,b{heads}n->bhp", ssm, cm)
   return jax.lax.dynamic_update_index_in_dim(ssm_leaf, jnp.where(active[:, None, None, None], ssm, ssm0).astype(ssm_leaf.dtype), layer, 0), y
 
 
@@ -221,13 +226,14 @@ def _standing_step(grid_rank: int, active_ref, s_ref, out_ref, y_ref, step):
     out_ref[0, 0] = s_ref[0, 0]
 
 
-def _state_step_kernel(layer_ref, active_ref, stand_ref, a_ref, dtx_ref, b_ref, c_ref, s_ref, out_ref, y_ref):
+def _state_step_kernel(layer_ref, active_ref, stand_ref, a_ref, dtx_ref, b_ref, c_ref, s_ref, out_ref, y_ref, per_head: bool = False):
   del layer_ref, stand_ref  # the index maps read them
+  spread = (lambda ref: ref[0][:, None, :]) if per_head else (lambda ref: ref[0][None])  # B, C [Hb, N] a head, as the decay lies, or [1, N] every head's
 
   def step():
     # (the decay lies along the lanes and spreads over sublanes; Δ·x [Hb, P] goes lanes → sublanes, then along the lanes)
-    new = a_ref[0][:, None, :] * s_ref[0, 0] + dtx_ref[0][:, :, None] * b_ref[0][None]
-    y_ref[0] = jnp.sum(new * c_ref[0][None], axis=-1)
+    new = a_ref[0][:, None, :] * s_ref[0, 0] + dtx_ref[0][:, :, None] * spread(b_ref)
+    y_ref[0] = jnp.sum(new * spread(c_ref), axis=-1)
     out_ref[0, 0] = new
 
   _standing_step(2, active_ref, s_ref, out_ref, y_ref, step)
@@ -247,17 +253,19 @@ def _state_step_one_pass(ssm_leaf, layer, a, dtx, bm, cm, active, interpret: boo
   # copy of its own before every call (12 µs a layer); as [B, H, P] it needs a second lanes → sublanes relayout in
   # the body, which no longer hides under the tile's round trip (437 µs a layer for 423; PERF.md §6, PR 35).
   a = jnp.broadcast_to(a[:, :, None], (B, H, N))
+  grouped = bm.ndim == 3  # B and C a head, [B, H, N]: blocked as the decay is; else [B, 1, N], one block a row
+  bc_block, (bm, cm) = (per_head(N), (bm, cm)) if grouped else (per_row, (bm[:, None, :], cm[:, None, :]))
   return pl.pallas_call(
-    _state_step_kernel,
+    partial(_state_step_kernel, per_head=True) if grouped else _state_step_kernel,
     out_shape=[jax.ShapeDtypeStruct(ssm_leaf.shape, ssm_leaf.dtype), jax.ShapeDtypeStruct((B, H, P), jnp.float32)],
     grid_spec=pltpu.PrefetchScalarGridSpec(
-      num_scalar_prefetch=3, grid=(B, H // hb), in_specs=[per_head(N), per_head(P), per_row, per_row, tile], out_specs=[tile, per_head(P)]
+      num_scalar_prefetch=3, grid=(B, H // hb), in_specs=[per_head(N), per_head(P), bc_block, bc_block, tile], out_specs=[tile, per_head(P)]
     ),
     input_output_aliases={7: 0},  # the leaf, after the three scalar-prefetch operands and a, dtx, bm, cm
     compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),  # in order: a standing step counts on the step before it
     interpret=interpret,
     name="ssm_state_step",  # neither the attention kernel's name nor the flash kernel's: the roofline readers count calls by those
-  )(jnp.asarray(layer, jnp.int32).reshape(1), active.astype(jnp.int32), stand, a, dtx, bm[:, None, :], cm[:, None, :], ssm_leaf)
+  )(jnp.asarray(layer, jnp.int32).reshape(1), active.astype(jnp.int32), stand, a, dtx, bm, cm, ssm_leaf)
 
 
 def _onto_sublanes(v):

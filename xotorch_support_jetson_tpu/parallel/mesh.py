@@ -177,6 +177,7 @@ def decoder_param_specs(fsdp: bool = False) -> dict:
     "w_experts_gate": P(None, "ep", d, "tp"),
     "w_experts_up": P(None, "ep", d, "tp"),
     "w_experts_down": P(None, "ep", "tp", d),
+    "w_experts_up_t": P(None, "ep", "tp", d),  # an ungated expert's first matrix, stored [F, D] as its second is
     "w_shared_gate": P(None, d, "tp"),
     "w_shared_up": P(None, d, "tp"),
     "w_shared_down": P(None, "tp", d),
