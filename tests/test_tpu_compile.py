@@ -110,16 +110,36 @@ def test_token_write_kernel_compiles_for_v5e(chip, quant, hd):
   assert "tpu_custom_call" in text and "kv_token_write" in text
 
 
-@pytest.mark.parametrize("quant", ["", "int8"])
-def test_flash_prefill_compiles_for_v5e(chip, quant):
-  """One PREFILL_BUCKET of queries against the default 4096-slot cache."""
+# The flash prefill kernel at every benchmark cell's head shapes (hq, hkv, hd, int8 codes, windows): one PREFILL_BUCKET of
+# queries and a mixed tick's 2048-token slice, against the longest page window the cell's prompts reach (a power of two).
+_FLASH_CELLS = [
+  pytest.param(32, 8, 64, "", (0,), 4096, id="llama-1b-default-cache"),
+  pytest.param(32, 8, 64, "int8", (0,), 4096, id="llama-1b-int8"),
+  pytest.param(28, 4, 128, "", (0, 4096), 16384, id="smallthinker"),
+  pytest.param(48, 8, 128, "", (0,), 4096, id="laguna-full"),
+  pytest.param(64, 8, 128, "", (512,), 4096, id="laguna-window"),
+  pytest.param(32, 8, 128, "int8", (0,), 4096, id="mistral-int8"),
+  pytest.param(30, 30, 128, "", (0,), 2048, id="olmo-mha"),
+  pytest.param(32, 8, 64, "", (0,), 2048, id="granite-hd64"),
+  pytest.param(32, 2, 128, "", (0,), 2048, id="nemotron-group16"),
+  pytest.param(8, 1, 256, "", (4096,), 8192, id="hd256-mqa-window"),  # no cell: the widest head ``flash_supported`` admits, whose tiles the rule halves
+]
+
+
+@pytest.mark.parametrize("sq", [128, 2048])
+@pytest.mark.parametrize("hq,hkv,hd,quant,windows,skv", _FLASH_CELLS)
+def test_flash_prefill_compiles_for_v5e(chip, hq, hkv, hd, quant, windows, skv, sq):
+  """Mosaic's word on the tile rule's VMEM and tiling (``ops/pallas_attention.py _tile``), with no chip: the group's
+  heads folded into one product's rows, the stored-dtype operands, the scalar-prefetched offsets in the index maps.
+  The call carries its own name, which is what a trace lists it under."""
   from xotorch_support_jetson_tpu.ops.pallas_attention import flash_attention_prefill
 
-  sq, skv, hd = 128, 4096, 64
-  kv = _sds(chip, (1, skv, HKV, hd), jnp.int8 if quant else jnp.bfloat16)
-  scale = _sds(chip, (1, skv, HKV, 1), jnp.float32) if quant else None
-  _, text = _compile(flash_attention_prefill, _sds(chip, (1, sq, HQ, hd), jnp.bfloat16), kv, kv, _sds(chip, (1,), jnp.int32), scale, scale, interpret=False)
-  assert "tpu_custom_call" in text
+  kv = _sds(chip, (1, skv, hkv, hd), jnp.int8 if quant else jnp.bfloat16)
+  scale = _sds(chip, (1, skv, hkv, 1), jnp.float32) if quant else None
+  for window in windows:
+    _, text = _compile(flash_attention_prefill, _sds(chip, (1, sq, hq, hd), jnp.bfloat16), kv, kv, _sds(chip, (1,), jnp.int32), scale, scale, interpret=False, window=window)
+    assert _mosaic_calls(text) == ["flash_prefill"], window
+    assert re.search(rf"%flash_prefill[.\d]* = bf16\[1,{hq},{sq},{hd}\]\S* custom-call", text), window
 
 
 def test_flash_decode_32k_compiles_for_v5e(chip):

@@ -1,13 +1,15 @@
 """Pallas flash-attention (prefill) kernel for TPU.
 
-Blockwise online-softmax attention: K/V stream through VMEM in BLOCK_K
-chunks while each grid step owns one (batch, q-head, q-block) tile — O(S)
-memory instead of materializing [Sq, Skv] scores in HBM, and the QK^T /
-PV matmuls stay on the MXU back-to-back.
+Blockwise online-softmax attention: K/V stream through VMEM in ``block_k``
+chunks while each grid step owns one (batch, KV head, q-block) tile — the
+group's query heads over a stretch of queries, folded into the rows of ONE
+product against the K/V tile — O(S) memory instead of materializing
+[Sq, Skv] scores in HBM, and the QK^T / PV matmuls stay on the MXU
+back-to-back.
 
 Causality is positional, consistent with ops/attention.py: query row i at
 absolute position ``q_offset + i`` attends KV slot j iff ``j <= pos``. GQA is
-handled in the index map (q head h reads kv head ``h // group``).
+handled in the block shapes (a query block is its KV head's group of heads).
 
 Used by the decoder for prefill when shapes allow (models/decoder.py);
 ``ops.attention.gqa_attention`` is the XLA fallback everywhere else
@@ -28,26 +30,74 @@ NEG_INF = -1e30
 BLOCK_Q = 128
 BLOCK_K = 128
 
+# The tile rule's numbers (``_tile``; chosen from Mosaic's final bundles for a described v5e and a sweep on the chip, PERF.md §6, PR 54).
+# Rows of one product (14 × 128): the grid's ~0.35 µs a step and the K/V latch are paid once a tile, so the more the faster — 2048
+# fit VMEM and read 3-7 % faster where a group reaches them, but Mosaic's compile time grows faster than the tile (2.4-3.5 s a
+# kernel at 2048 rows, 1.1-1.4 at 1024, 0.4-1.8 before PR 54) and a cell with many prefill shapes pays it in setup_s.
+TILE_MAX_ROWS = 1792
+TILE_MAX_Q = 512  # queries a tile: every tile computes its diagonal block whole, so a longer stretch wastes more of it
+TILE_SCORES = 1024 * 1024  # elements of the float32 scores tile [rows, block_k]: with its temporaries it stays under the 16 MiB of scoped VMEM
+# A K/V block is 512 keys at most, fewer under a narrower window: a tile computes its edge blocks whole (the diagonal; the
+# window's first), so a wider block computes more of what the mask throws away — at 2048 a window of 512 would compute what
+# full causal attention does — and at 256 the accumulator's rescale and the step's fixed cost weigh as much as the products.
+MAX_BLOCK_K = 512
+P_TERMS = 2  # bfloat16 terms p enters the value product as (bfloat16 inputs): 2 carry 16 bits of p's mantissa
 
-def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: int, scale: float, quantized: bool, window: int = 0):
-  """Grid: (B, Hq, Sq/BQ, Skv/BK) — the KV axis is GRID-tiled (innermost,
+
+def _tile(group: int, hd: int, window: int, sq: int, skv: int) -> tuple[int, int, int]:
+  """(heads, block_q, block_k) of a grid step: ``heads`` query heads of one KV head × ``block_q`` queries are the
+  rows of one product against a ``[block_k, hd]`` K/V tile. One algorithm that wants different sizes, read from
+  the shapes alone: the whole group where its rows fit (else the largest set of heads that divides it; 1 is the
+  tile of before PR 54), then the longest stretch of queries up to ``TILE_MAX_Q`` that keeps the rows inside
+  ``TILE_MAX_ROWS`` at head size 128 (group 7: 256 queries, 1792 rows; group 8: 128; group 16: two sets of 8 heads; MHA: 512) and half of that
+  at 256, whose query, output and accumulator tiles are twice as wide, then the largest power of two that divides
+  ``Skv`` and keeps the scores tile inside ``TILE_SCORES``, ``MAX_BLOCK_K`` keys at most and no more than a window."""
+  max_rows = TILE_MAX_ROWS * 128 // max(hd, 128)
+  heads = max(h for h in range(1, group + 1) if group % h == 0 and (h == 1 or h * BLOCK_Q <= max_rows))
+  block_q = max(bq for bq in range(BLOCK_Q, sq + 1, BLOCK_Q) if sq % bq == 0 and (bq == BLOCK_Q or (bq <= TILE_MAX_Q and heads * bq <= max_rows)))
+  cap = min(TILE_SCORES // (heads * block_q), MAX_BLOCK_K, window or MAX_BLOCK_K)
+  block_k = next((bk for bk in (512, 256) if skv % bk == 0 and bk <= cap), BLOCK_K)
+  return heads, block_q, block_k
+
+
+def _kv_blocks(q0, block_q: int, block_k: int, n_blocks: int, window: int):
+  """(first, last) K/V block a tile whose first query stands at ``q0`` needs: up to its last query's causal
+  horizon, from its first query's window. The index maps and the kernel body read the same two numbers."""
+  last = jnp.minimum((q0 + block_q - 1) // block_k, n_blocks - 1)
+  first = jnp.minimum(jnp.maximum(q0 - window + 1, 0) // block_k, last) if window else 0
+  return first, last
+
+
+def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: int, n_blocks: int, scale: float, quantized: bool, window: int, p_terms: int):
+  """Grid: (B, Hq/heads, Sq/BQ, KV steps) — the KV axis is GRID-tiled (innermost,
   sequential) with the online-softmax state carried in VMEM scratch, so
   VMEM holds one [BK, hd] K/V tile at a time regardless of Skv. (The first
   design kept the whole [Skv, hd] row resident and fori_loop'ed over it —
   at a 32K cache that is ~16.2 MB of operand stack, over the 16 MB scoped
   VMEM limit: long-context chunked prefill crashed at COMPILE time.)
 
-  ``quantized``: k/v refs hold int8 codes and two extra [BK, 1] f32 scale
+  The query block is ``[heads, BQ, hd]`` — query heads of ONE KV head — folded to ``[heads·BQ, hd]`` rows, so a
+  K/V tile is fetched once a group and not once a query head; a row's query is ``row % BQ``. Step ``kb`` of the
+  KV axis is the tile's block ``first + kb`` (``_kv_blocks``; the index maps clamp to ``last``, and a repeated
+  block is not fetched again): blocks past the causal horizon or before the window cost neither a DMA nor a
+  product, only what is left of the grid's steps.
+
+  Products take their operands as stored (ops/paged.py ``dot_dtype``'s rule): with bfloat16 queries the score
+  product is bfloat16 × bfloat16 (int8 codes are exact in bfloat16) summed in float32 — every product exact, so
+  only the order of the sum differs from a float32 product — and ``p`` enters the value product as ``p_terms``
+  bfloat16 terms (``p_hi + p_lo`` carries 16 bits of its mantissa), each one MXU pass against the stored ``v``.
+  float32 queries keep float32 products. The running max, denominator and accumulator are float32 either way.
+  (On the chip Mosaic multiplies float32 operands at default precision in ONE bfloat16 pass, so the float32 casts of
+  before PR 54 bought no precision there: that kernel's ``p`` was a single bfloat16 term on a v5e — PERF.md §6, PR 54.)
+
+  ``quantized``: k/v refs hold int8 codes and two extra [1, BK] f32 scale
   refs precede the outputs — dequantization is per-(token, head) scales
   applied to scores/probs in-register (cf. ops/attention.py gqa_attention),
   so the HBM stream stays 1 byte/element and the quantized prefill never
   materializes a dequantized cache.
 
   ``window`` (static; 0: none): a query at t sees the keys in (t - window, t]
-  (ops/attention.py ``cap_and_mask_scores``'s rule): blocks wholly before the
-  tile's first query's window are skipped like those past its causal horizon,
-  and the edge blocks masked. Every ``if window`` is Python's: 0 traces the
-  kernel as it was."""
+  (ops/attention.py ``cap_and_mask_scores``'s rule). Every ``if window`` is Python's."""
   import jax.experimental.pallas as pl
 
   if quantized:
@@ -55,6 +105,11 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
   else:
     o_ref, m_ref, l_ref, acc_ref = scale_refs_and_out
   b, qi, kb = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+  heads, bq, hd = q_ref.shape[1:]
+  rows = heads * bq
+  mxu = jnp.bfloat16 if q_ref.dtype == jnp.bfloat16 else jnp.float32
+  terms = p_terms if mxu == jnp.bfloat16 else 1
+  unit = scale * 1.4426950408889634  # exp(scale·x) = 2^(unit·x): the softmax scale rides in the exponent's one multiply, and the max is taken over unscaled scores
 
   @pl.when(kb == 0)
   def _init():
@@ -62,58 +117,52 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, *scale_refs_and_out, block_k: in
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-  q = q_ref[0, 0].astype(jnp.float32)  # [BQ, hd]
-  bq = q.shape[0]
-  # Per-row dynamic offset (scalar-prefetched): query row i is at absolute
-  # position off[b] + i. Prefix-cached prefills start mid-sequence
+  # Per-row dynamic offset (scalar-prefetched): query i of the tile is at absolute
+  # position off[b] + qi·BQ + i. Prefix-cached prefills start mid-sequence
   # (models/decoder.py prefill_into_pages), so the offset cannot be static 0.
-  q_pos = off_ref[b] + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)  # [BQ,1]
-  start = kb * block_k
+  q0 = off_ref[b] + qi * bq
+  first, last = _kv_blocks(q0, bq, block_k, n_blocks, window)
+  blk = first + kb
 
-  # Blocks entirely past this query tile's causal horizon contribute only
-  # NEG_INF columns: skip their COMPUTE. Their DMA still streams: there is
-  # no index-map clamp here, so the kernel needs no scalar-prefetch grid. The
-  # compute skip alone keeps the MXU work O(context).
-  needed = start <= off_ref[b] + (qi + 1) * bq - 1
-  if window:  # ... and the block's last key is inside the window of the tile's first query
-    needed = jnp.logical_and(needed, start + block_k - 1 > off_ref[b] + qi * bq - window)
-
-  @pl.when(needed)
+  @pl.when(blk <= last)
   def _block():
-    k_blk = k_ref[0, 0].astype(jnp.float32)  # [BK, hd]
-    v_blk = v_ref[0, 0].astype(jnp.float32)
-    scores = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale  # [BQ, BK]
+    q = q_ref[0].reshape(rows, hd).astype(mxu)
+    k_blk = k_ref[0, 0].astype(mxu)  # [BK, hd]
+    v_blk = v_ref[0, 0].astype(mxu)
+    scores = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)  # [rows, BK], unscaled
     if quantized:
-      # codes·scale = true k: the per-token scale multiplies each score
-      # COLUMN ([BK,1] transposed to a [1,BK] row broadcast).
-      scores = scores * jnp.transpose(ks_ref[0, 0], (1, 0))
-    kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)  # [1,BK]
+      scores = scores * ks_ref[0, 0]  # codes·scale = true k: the per-token scale multiplies each score COLUMN ([1, BK])
+    q_pos = q0 + jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), bq)  # [rows,1]: a row's query is row % BQ
+    kv_pos = blk * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)  # [1,BK]
     mask = kv_pos <= q_pos
     if window:
       mask = jnp.logical_and(mask, kv_pos > q_pos - window)
+    # Every block is masked, the interior ones for nothing: a second body without the mask was 4 % fewer bundles a step
+    # and half as much again to compile (2.4-3.5 s a kernel against 1.6-2.7; PERF.md §6, PR 54), which a cell pays in setup_s.
     scores = jnp.where(mask, scores, NEG_INF)
     m = m_ref[...]
-    blk_m = jnp.max(scores, axis=1, keepdims=True)  # [BQ,1]
-    new_m = jnp.maximum(m, blk_m)
-    p = jnp.exp(scores - new_m)
-    p = jnp.where(new_m <= NEG_INF / 2, 0.0, p)
-    alpha = jnp.exp(m - new_m)
+    new_m = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))  # [rows,1]
+    # A row with no key yet (its window starts after this block) has new_m == NEG_INF: 2^(NEG_INF - 0) is the 0 its p must be.
+    p = jnp.exp2((scores - jnp.where(new_m <= NEG_INF / 2, 0.0, new_m)) * unit)
+    alpha = jnp.exp2((m - new_m) * unit)
     m_ref[...] = new_m
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     if quantized:
-      p = p * jnp.transpose(vs_ref[0, 0], (1, 0))  # v's scale folds into probs (after the l update)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+      p = p * vs_ref[0, 0]  # v's scale folds into probs (after the l update)
+    pv = None
+    for term in range(terms):
+      p_term = p.astype(mxu)
+      if term + 1 < terms:
+        p = p - p_term.astype(jnp.float32)
+      part = jax.lax.dot_general(p_term, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+      pv = part if pv is None else pv + part
+    acc_ref[...] = acc_ref[...] * alpha + pv
 
   @pl.when(kb == pl.num_programs(3) - 1)
   def _finish():
     l = l_ref[...]
     l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-# A layer with a window computes the K blocks its window touches and skips the rest, so its block is kept near the
-# window's size: at the 2048 of a layer without one, a window of 512 would compute what full causal attention does.
-WINDOW_BLOCK_K = 512
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype).reshape(heads, bq, hd)
 
 
 @functools.partial(tracked_jit, "ops.flash_prefill", static_argnames=("interpret", "window"))
@@ -143,41 +192,52 @@ def flash_attention_prefill(q, k, v, q_offset=0, k_scale=None, v_scale=None, int
   scale = float(1.0 / (hd**0.5))
   offsets = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))
   quantized = k_scale is not None
+  window = int(window)
 
   # Layout: [B, H, S, hd] so the S×hd tile is contiguous per (b, h).
   qt = jnp.moveaxis(q, 2, 1)  # [B, Hq, Sq, hd]
   kt = jnp.moveaxis(k, 2, 1)
   vt = jnp.moveaxis(v, 2, 1)
 
-  # KV grid-block size: as LARGE as divides Skv (≤2048). Grid-step overhead
-  # on this platform is ~25 µs; at BLOCK_K=128 a 32K cache is 512K steps
-  # (~13 s per 512-token chunk, measured) — at 2048 it is 32× fewer. VMEM
-  # per step stays ≤ ~1 MB ([2048, hd] K+V tiles + the [BQ, 2048] scores).
-  block_k = next((bk for bk in (2048, 1024, 512, 256, 128) if Skv % bk == 0 and (not window or bk <= max(WINDOW_BLOCK_K, BLOCK_K))), BLOCK_K)
-  grid = (B, Hq, Sq // BLOCK_Q, Skv // block_k)
-  kernel = functools.partial(_flash_kernel, block_k=block_k, scale=scale, quantized=quantized, **({"window": int(window)} if window else {}))
-  in_specs = [
-    pl.BlockSpec(memory_space=pltpu.SMEM),
-    pl.BlockSpec((1, 1, BLOCK_Q, hd), lambda b, h, i, kb: (b, h, i, 0)),
-    pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, kb: (b, h // group, kb, 0)),
-    pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, kb: (b, h // group, kb, 0)),
-  ]
+  heads, block_q, block_k = _tile(group, hd, window, Sq, Skv)
+  n_blocks = Skv // block_k
+  # The KV axis is as long as a tile can need where that is static: under a window the blocks that
+  # block_q + window - 1 keys can touch, walked from the tile's first; else every block (the offsets are traced).
+  kv_steps = min(n_blocks, pl.cdiv(block_q + window - 1, block_k) + 1) if window else n_blocks
+
+  def kv_block(b, i, kb, off_ref):  # step kb of a tile's walk, clamped into what it needs: a repeated index is no new DMA
+    first, last = _kv_blocks(off_ref[b] + i * block_q, block_q, block_k, n_blocks, window)
+    return jnp.minimum(first + kb, last)
+
+  def kv_head(h):  # grid head h is a set of ``heads`` query heads of one KV head
+    return h * heads // group
+
+  kv_spec = pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, kb, off_ref: (b, kv_head(h), kv_block(b, i, kb, off_ref), 0))
+  q_spec = pl.BlockSpec((1, heads, block_q, hd), lambda b, h, i, kb, off_ref: (b, h, i, 0))
+  in_specs = [q_spec, kv_spec, kv_spec]
   operands = [offsets, qt, kt, vt]
   if quantized:
-    in_specs += [pl.BlockSpec((1, 1, block_k, 1), lambda b, h, i, kb: (b, h // group, kb, 0))] * 2
-    operands += [jnp.moveaxis(k_scale, 2, 1), jnp.moveaxis(v_scale, 2, 1)]
+    # [B, Skv, Hkv, 1] → [B, Hkv, 1, Skv]: a block is a lane-dense [1, BK] row, the form both products want.
+    in_specs += [pl.BlockSpec((1, 1, 1, block_k), lambda b, h, i, kb, off_ref: (b, kv_head(h), 0, kv_block(b, i, kb, off_ref)))] * 2
+    operands += [jnp.moveaxis(s, 2, 1).reshape(B, Hkv, 1, Skv) for s in (k_scale, v_scale)]
+  rows = heads * block_q
   out = pl.pallas_call(
-    kernel,
+    functools.partial(_flash_kernel, block_k=block_k, n_blocks=n_blocks, scale=scale, quantized=quantized, window=window, p_terms=P_TERMS),
     out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
-    grid=grid,
-    in_specs=in_specs,
-    out_specs=pl.BlockSpec((1, 1, BLOCK_Q, hd), lambda b, h, i, kb: (b, h, i, 0)),
-    scratch_shapes=[
-      pltpu.VMEM((BLOCK_Q, 1), jnp.float32),  # running max
-      pltpu.VMEM((BLOCK_Q, 1), jnp.float32),  # running denom
-      pltpu.VMEM((BLOCK_Q, hd), jnp.float32),  # accumulator
-    ],
+    grid_spec=pltpu.PrefetchScalarGridSpec(
+      num_scalar_prefetch=1,
+      grid=(B, Hq // heads, Sq // block_q, kv_steps),
+      in_specs=in_specs,
+      out_specs=q_spec,
+      scratch_shapes=[
+        pltpu.VMEM((rows, 1), jnp.float32),  # running max
+        pltpu.VMEM((rows, 1), jnp.float32),  # running denom
+        pltpu.VMEM((rows, hd), jnp.float32),  # accumulator
+      ],
+    ),
+    compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     interpret=interpret,
+    name="flash_prefill",
   )(*operands)
   return jnp.moveaxis(out, 1, 2)  # [B, Sq, Hq, hd]
 
