@@ -5,6 +5,8 @@ each device op's ``op_name``, which is how ``benchmark/span_lib.py`` splits a
 decode step's device time by component. Two claims, both on the CPU compile of
 the tiny ``decode.paged_batch``: every name of the vocabulary reaches the
 compiled program, and the optimised program is the same with and without them.
+The flavour ``mixed`` is ``decode.mixed_paged_batch`` (ISSUE 55): the same two
+claims, and its prefill half under the one outer scope ``mixed.prefill``.
 """
 
 import contextlib
@@ -17,7 +19,7 @@ import numpy as np
 import pytest
 
 from xotorch_support_jetson_tpu.models.config import tiny_test_config
-from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, full_model_params
+from xotorch_support_jetson_tpu.models.decoder import MIXED_PREFILL_SCOPE, _fused_mixed_paged_batch_decode_impl, _fused_paged_batch_decode_impl, full_model_params
 from xotorch_support_jetson_tpu.models.quantize import quantize_params
 from xotorch_support_jetson_tpu.ops.paged import init_paged_pool
 
@@ -29,6 +31,7 @@ PS = 16
 COMMON = {"xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.dequant", "xot.head", "xot.sample"}
 CONFIGS = {
   "dense": (dict(n_layers=2, max_seq_len=128), "int8", COMMON | {"xot.ffn"}),
+  "mixed": (dict(n_layers=2, max_seq_len=128), "int8", COMMON | {"xot.ffn"}),  # the dense model's mixed tick: a slice of 11 tokens padded to 16 beside the two decode rows
   "mla_moe": (
     dict(
       n_layers=2, max_seq_len=128, n_heads=4, n_kv_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
@@ -40,20 +43,25 @@ CONFIGS = {
 }
 
 
-def _lowered(flavor: str):
-  """``decode.paged_batch`` for a tiny int8 model, lowered and not yet compiled."""
+def _traced(flavor: str):
+  """``decode.paged_batch`` (``decode.mixed_paged_batch`` for the flavour ``mixed``) for a tiny int8 model, traced and not yet lowered."""
   overrides, kv_quant, _ = CONFIGS[flavor]
   cfg = tiny_test_config(**overrides)
   params, shard = full_model_params(jax.random.PRNGKey(0), cfg)
   params = quantize_params(params)
   B, mp = 2, 128 // PS
-  pool = init_paged_pool(cfg, shard.n_shard_layers, 1 + B * mp, PS, quant=kv_quant)
+  pool = init_paged_pool(cfg, shard.n_shard_layers, 1 + B * mp + 2, PS, quant=kv_quant)
   bt = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
-  args = (
-    params, cfg, shard, jnp.ones((B, 1), jnp.int32), pool, bt, jnp.asarray([3, 5], jnp.int32), jnp.ones((B,), bool),
-    jnp.zeros((B,), jnp.float32), jnp.full((B,), 8, jnp.int32), 4, 8, PS, False, jax.random.PRNGKey(1), None,
-  )
-  return _fused_paged_batch_decode_impl.xot_jitted.lower(*args)
+  decode = (params, cfg, shard, jnp.ones((B, 1), jnp.int32), pool, bt, jnp.asarray([3, 5], jnp.int32), jnp.ones((B,), bool), jnp.zeros((B,), jnp.float32), jnp.full((B,), 8, jnp.int32))
+  static = (4, 8, PS, False, jax.random.PRNGKey(1), None)
+  if flavor != "mixed":
+    return _fused_paged_batch_decode_impl.xot_jitted.trace(*decode, *static)
+  pf_bt = jnp.asarray([[1 + B * mp, 2 + B * mp]], jnp.int32)  # the admission's own two pages: positions 4..14 of its prompt
+  return _fused_mixed_paged_batch_decode_impl.xot_jitted.trace(*decode, jnp.ones((1, 16), jnp.int32), pf_bt, jnp.asarray([4], jnp.int32), jnp.asarray([15], jnp.int32), *static, None)
+
+
+def _lowered(flavor: str):
+  return _traced(flavor).lower()
 
 
 @functools.cache
@@ -62,8 +70,12 @@ def _compiled(flavor: str) -> str:
   return _lowered(flavor).compile().as_text()
 
 
+def _op_names(text: str) -> list[str]:
+  return re.findall(r'op_name="([^"]*)"', text)
+
+
 def _scopes_in(text: str) -> set[str]:
-  return set(re.findall(r"xot\.[a-z_]+", " ".join(re.findall(r'op_name="([^"]*)"', text))))
+  return set(re.findall(r"xot\.[a-z_]+", " ".join(_op_names(text))))
 
 
 _METADATA = re.compile(r",? ?metadata=\{[^{}]*\}")
@@ -108,8 +120,28 @@ def test_scopes_do_not_change_the_optimised_program(flavor, monkeypatch):
     monkeypatch.undo()
     jax.clear_caches()
   without = lowered.compile().as_text()
-  assert not _scopes_in(without)
+  assert not _scopes_in(without) and MIXED_PREFILL_SCOPE not in " ".join(_op_names(without))
   assert _program(with_scopes) == _program(without)
+
+
+def test_a_mixed_ticks_prefill_half_lies_under_one_outer_mark_and_its_scan_under_none():
+  """``decode.mixed_paged_batch`` is two halves in sequence (ISSUE 55): every equation of the slice's gather, forward
+  and scatter carries ``mixed.prefill`` as the OUTERMOST part of its name stack, ahead of any ``xot.`` component —
+  ``benchmark/span_lib.component_of`` keeps the ``xot.`` parts and reads what it read, ``benchmark/half_lib.py`` splits
+  by the one part — and the decode scan, which is the plain program's, carries it nowhere. In the compiled program
+  the slice's ops read ``…/mixed.prefill/…xot.<component>…`` and the scan's ops, ``xot.sample`` among them, do not."""
+  eqns = _traced("mixed").jaxpr.eqns
+  stacks = [str(e.source_info.name_stack) for e in eqns]
+  scan = max(i for i, e in enumerate(eqns) if e.primitive.name == "scan")  # the decode chunk's steps: the last loop of the program
+  assert all(s.split("/")[0] == MIXED_PREFILL_SCOPE for s in stacks[:scan]) and scan > 10
+  assert not any(MIXED_PREFILL_SCOPE in s for s in stacks[scan:])  # the scan's own equations name their stacks from the scan inwards
+  names = [n.split("/") for n in _op_names(_compiled("mixed"))]
+  marked = [n for n in names if MIXED_PREFILL_SCOPE in n]
+  plain = [n for n in names if MIXED_PREFILL_SCOPE not in n]
+  scoped = lambda group: {p for n in group for p in n if p.startswith("xot.")}  # noqa: E731
+  assert scoped(marked) >= {"xot.embed", "xot.attn_proj", "xot.kv_write", "xot.attn", "xot.ffn", "xot.dequant"}
+  assert scoped(plain) >= COMMON | {"xot.ffn"} and "xot.sample" not in scoped(marked)  # an intermediate slice samples nothing
+  assert all(n.index(MIXED_PREFILL_SCOPE) < min(i for i, p in enumerate(n) if p.startswith("xot.")) for n in marked if scoped([n]))
 
 
 def test_kernel_path_scopes_the_token_write_and_the_attention_kernel():

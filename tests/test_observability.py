@@ -706,6 +706,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_requests_stalled_total",
   # Mixed prefill+decode ticks (ISSUE 14)
   "xot_tpu_sched_tick_prefill_tokens_total",
+  "xot_tpu_sched_tick_prefill_pad_tokens_total",  # the padded width of the same slices (ISSUE 55)
   "xot_tpu_sched_ticks_total",  # one per program dispatch of the scheduler loop (ISSUE 24)
   "xot_tpu_sched_dispatches_total",  # {queue}: a dispatch enqueued behind one not yet read back, or onto an empty queue (ISSUE 51)
   "xot_tpu_sched_phase_seconds_total",  # {phase}: host seconds of a tick by phase (ISSUE 24)
@@ -873,6 +874,7 @@ def test_metric_name_snapshot_after_serving():
   # prefill next to resident decode rows, so the mixed families stay
   # event-driven — materialize them at zero for the exposition pin.
   gm.inc("sched_tick_prefill_tokens_total", 0)
+  gm.inc("sched_tick_prefill_pad_tokens_total", 0)
   gm.observe_hist("mixed_tick_seconds", 0.0)
   gm.set_gauge("mixed_budget_tokens", 0)
   # Multi-LoRA (ISSUE 15): registry families are event-driven (a solo

@@ -133,6 +133,28 @@ def test_snapshot_carries_phases_ticks_and_steps():
   assert clock.snapshot()["seconds"]["decode"] == 0.0
 
 
+def test_inc_moves_a_count_and_its_metrics_counter_by_the_same_amount():
+  """ISSUE 55: the scheduler's counts ride the snapshots, and one call moves a count and its ``/metrics`` counter."""
+  now = _Time()
+  clock = SchedClock(now=now)
+  assert clock.snapshot()["counts"] == {}  # a count appears with its first increment
+  before = {q: metrics.counter_value("sched_dispatches_total", labels={"queue": q}) for q in ("behind", "empty")}
+  pages = metrics.counter_value("kv_pages_read_total")
+  clock.inc("sched_dispatches_total", count="dispatch_empty", labels={"queue": "empty"})
+  clock.inc("kv_pages_read_total", 40, count="kv_pages_read")
+  first = clock.snapshot()
+  now.t += 1.0
+  clock.inc("sched_dispatches_total", count="dispatch_behind", labels={"queue": "behind"})
+  clock.inc("sched_dispatches_total", count="dispatch_behind", labels={"queue": "behind"})
+  clock.inc("kv_pages_read_total", 2, count="kv_pages_read")
+  last = clock.snapshot()
+  assert first["counts"] == {"dispatch_empty": 1, "kv_pages_read": 40}  # a copy: the first does not move with the clock
+  assert last["counts"] == {"dispatch_empty": 1, "dispatch_behind": 2, "kv_pages_read": 42}
+  assert {q: metrics.counter_value("sched_dispatches_total", labels={"queue": q}) - n for q, n in before.items()} == {"behind": 2, "empty": 1}
+  assert metrics.counter_value("kv_pages_read_total") - pages == 42
+  assert abs(sum(last["seconds"].values()) - (last["t"] - clock.t_started)) < 1e-9  # counting books no time
+
+
 def test_resident_ms_sums_the_residencies_and_skips_an_open_one():
   def clock(decode, prefill, host, steps):
     return {"seconds": {"decode": decode, "mixed": 0.0, "spec": 0.0, "prefill": prefill, "host": host, "idle": 7.0}, "steps": steps}

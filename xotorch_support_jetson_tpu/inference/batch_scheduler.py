@@ -314,6 +314,7 @@ class _Chunk:
   mixed_ready: object = None  # _Ready | None
   mixed_start: int = 0
   mixed_end: int = 0
+  mixed_pad: int = 0  # the padded slice the program ran (a power of two >= mixed_end - mixed_start)
   # Device int32 scalar: the distinct held experts the chunk's rows chose, summed over its expert layers and steps
   # (ops/moe.py): read back with the tokens, it feeds ``moe_experts_visited_total``. None: a program that does not count.
   experts_visited: object = None
@@ -1045,7 +1046,12 @@ class BatchedServer:
     whatever else runs on that thread meanwhile is a synchronous section that
     begins after the span opened and ends before it closes, so the events still
     nest. ``stage`` on the executor thread runs to the end of the dispatch, so when
-    a dispatch compiles its seconds hold the compile (``program_compile_seconds``)."""
+    a dispatch compiles its seconds hold the compile (``program_compile_seconds``).
+    ``args`` land in the trace beside the device ops: ``tick`` and ``rows`` on every
+    tick's spans, and on a MIXED tick's executor-side ``stage`` also ``pf_tokens`` (the
+    slice's real tokens) and ``pf_pad`` (the padded slice its program runs) — what the
+    ops under the ``mixed.prefill`` path component of that dispatch carried (ISSUE 55;
+    a plain or speculative tick passes neither)."""
     t0 = time.perf_counter()
     try:
       with jax.profiler.TraceAnnotation(f"xot.sched.{name}", **args):
@@ -1783,9 +1789,11 @@ class BatchedServer:
 
   def _note_dispatch(self, kind: str) -> None:
     """One program handed to the device: onto an empty queue, or BEHIND one the loop has not read back yet
-    (``sched_dispatches_total{queue}``; the ``behind`` share is how often the host's work rode under the device's).
-    The clock's interval of the host ends here unless it is chained."""
-    metrics.inc("sched_dispatches_total", labels={"queue": "behind" if self.clock.queued else "empty"})
+    (``sched_dispatches_total{queue}`` and, on the clock's snapshots, ``dispatch_behind`` / ``dispatch_empty``; the
+    ``behind`` share is how often the host's work rode under the device's). The clock's interval of the host ends
+    here unless it is chained."""
+    queue = "behind" if self.clock.queued else "empty"
+    self.clock.inc("sched_dispatches_total", count=f"dispatch_{queue}", labels={"queue": queue})
     self.clock.dispatched(kind)
 
   def _group_settles_first(self, group: list[_Ready]) -> bool:
@@ -2632,7 +2640,8 @@ class BatchedServer:
     # recompile) and its page window pow2-buckets like _dispatch_group's.
     pf_tokens = pf_bt = pf_prefix = pf_end = None
     mixed_r = None
-    m_start = m_end = 0
+    m_start = m_end = pad = 0
+    stage_args = dict(tick=tick, rows=int(active.sum()))
     if plan.mixed is not None and not spec:
       mixed_r, m_start, m_end = plan.mixed
       s_slice = m_end - m_start
@@ -2649,6 +2658,7 @@ class BatchedServer:
       pf_bt[0, : len(row_pages)] = row_pages
       pf_prefix = np.asarray([m_start], dtype=np.int32)
       pf_end = np.asarray([m_end], dtype=np.int32)
+      stage_args.update(pf_tokens=int(s_slice), pf_pad=pad)  # what this dispatch's prefill half carries, on the device ops' clock
       tracer.stage(mixed_r.req.request_id, "prefill_chunk", {
         "tokens": s_slice, "mixed": True, "batched_with": int(plan.active.sum()),
       })
@@ -2660,7 +2670,7 @@ class BatchedServer:
       # transfers, the engine's own argument handling and the jitted call, which
       # the nested ``xot.program:<family>`` span marks (measured, PR 24: the call
       # is 0.5-1 ms of it, the engine's handling before it 1.4-2.7 ms).
-      with self._phase("stage", tick=tick, rows=int(active.sum())):
+      with self._phase("stage", **stage_args):
         counts = pos_dev = n_prop = None
         seen = ()  # a solo engine's plain and mixed programs also return their count of expert visits (a pp / sp ring's do not)
         # The draft cache rides the dispatch only when a MODEL-drafted row is
@@ -2731,7 +2741,7 @@ class BatchedServer:
       starved=frozenset(plan.starved),
       spec=spec, worst=worst, rounds=self.chunk if spec else 0, gammas=gammas,
       proposers=proposers,
-      mixed_ready=mixed_r, mixed_start=m_start, mixed_end=m_end,
+      mixed_ready=mixed_r, mixed_start=m_start, mixed_end=m_end, mixed_pad=pad,
       draw_skipped=not (temps > 0).any(),
     )
 
@@ -2767,8 +2777,9 @@ class BatchedServer:
     what a pool whose window layers held a window only would return in bytes."""
     lengths = np.asarray(positions)[np.asarray(active, bool)].astype(np.int64) + 1
     held = -(-lengths // self.page_size)
-    metrics.inc("kv_pages_resident_total", int(held.sum()) * len(self._windows))
-    metrics.inc("kv_pages_read_total", int(sum((held - np.maximum(lengths - w, 0) // self.page_size if w else held).sum() for w in self._windows)))
+    read = sum((held - np.maximum(lengths - w, 0) // self.page_size if w else held).sum() for w in self._windows)
+    self.clock.inc("kv_pages_resident_total", int(held.sum()) * len(self._windows), count="kv_pages_resident")
+    self.clock.inc("kv_pages_read_total", int(read), count="kv_pages_read")
 
   async def _dispatch_decode(self, plan: _Plan, inflight: _Chunk | None) -> _Chunk:
     tick = self._next_tick()
@@ -2848,8 +2859,8 @@ class BatchedServer:
       if counted:
         # Their quotient is the mean number of distinct held experts a decode step visits in one expert layer: how
         # far the grouped form (``moe_ffn_form``) engages — it reads those and no other.
-        metrics.inc("moe_experts_visited_total", visited)
-        metrics.inc("moe_expert_layer_steps_total", self._expert_layers * self.chunk)
+        self.clock.inc("moe_experts_visited_total", visited, count="experts_visited")
+        self.clock.inc("moe_expert_layer_steps_total", self._expert_layers * self.chunk, count="expert_layer_steps")
       self._settle_host(record, rows_host, counts_host, n_prop_host)
 
   def _settle_host(self, record: _Chunk, rows_host, counts_host, n_prop_host) -> None:
@@ -2872,7 +2883,8 @@ class BatchedServer:
       r = record.mixed_ready
       r.prefix_len = max(r.prefix_len, record.mixed_end)
       metrics.observe_hist("mixed_tick_seconds", chunk_dt)
-      metrics.inc("sched_tick_prefill_tokens_total", record.mixed_end - record.mixed_start)
+      self.clock.inc("sched_tick_prefill_tokens_total", record.mixed_end - record.mixed_start, count="slice_tokens")
+      self.clock.inc("sched_tick_prefill_pad_tokens_total", record.mixed_pad, count="slice_pad_tokens")  # the padded width the program ran: tokens / pad is the slices' fill
       if r.req.disagg_target and self.kv_stream is not None and self.paged:
         # Disagg overlap rides mixed ticks too: ship the slice's completed
         # full pages while the remaining prefill advances.

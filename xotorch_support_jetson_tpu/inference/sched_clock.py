@@ -20,7 +20,16 @@ The kinds' seconds therefore sum to ``t - t_started`` whatever the order of
 calls. ``/metrics`` carries them as ``sched_wall_seconds_total{kind}``;
 ``snapshot()`` is what a request's timeline carries at its first token and at
 its release, so two snapshots give an exact delta over any stretch of a run
-(``Tracer.timeline``'s ``resident_ms``; the benchmark's ``clock_lib``).
+(``Tracer.timeline``'s ``resident_ms``; the benchmark's ``clock_lib``). The
+snapshot also carries the scheduler's own counts (``counts``, ISSUE 55), each
+moved with its ``/metrics`` counter by ``inc()`` at the call site that counts:
+``dispatch_behind`` / ``dispatch_empty`` (``sched_dispatches_total{queue}``),
+``slice_tokens`` / ``slice_pad_tokens`` (``sched_tick_prefill_tokens_total`` /
+``sched_tick_prefill_pad_tokens_total``: a settled mixed tick's real and padded
+slice), ``kv_pages_read`` / ``kv_pages_resident`` (``kv_pages_*_total``),
+``experts_visited`` / ``expert_layer_steps`` (``moe_experts_visited_total`` /
+``moe_expert_layer_steps_total``) — the same delta over the same stretch, with
+no second door into the program. A count appears with its first increment.
 
 One writer at a time: the loop and the engine's executor thread take turns (a
 phase on one waits for the other), so nothing here locks.
@@ -50,6 +59,7 @@ class SchedClock:
     self.last: dict[str, float] = {}  # the newest closed interval of each device kind: what the next one of that kind is expected to take
     self.ticks = 0
     self.steps = 0  # decode steps read back (a chunk's worth per decode / mixed / spec interval)
+    self.counts: dict[str, int] = {}  # the scheduler's own counts, each moved with its /metrics counter by ``inc``
 
   def _book(self) -> float:
     now = self._now()
@@ -125,7 +135,15 @@ class SchedClock:
   def tick(self) -> None:
     self.ticks += 1
 
+  def inc(self, family: str, amount: int = 1, *, count: str, labels: dict | None = None) -> None:
+    """Move the ``/metrics`` counter ``family`` and the snapshots' count ``count`` by the same amount, in one call at
+    the boundary where the scheduler counts (ISSUE 55): an operator's scrape and a reader of two snapshots
+    (benchmark/half_lib.py) see the same growth over the same stretch. Spelled as ``metrics.inc`` so that the
+    family stays a literal at its call site (scripts/check_metrics_docs.py reads those)."""
+    self.counts[count] = self.counts.get(count, 0) + amount
+    metrics.inc(family, amount, labels=labels)
+
   def snapshot(self) -> dict:
     """Everything cumulative, booked up to ``t`` (``perf_counter`` seconds): ``sum(seconds.values()) == t - self.t_started``."""
     now = self._book()
-    return {"t": now, "ticks": self.ticks, "steps": self.steps, "seconds": dict(self.seconds), "intervals": dict(self.intervals), "phases": dict(self.phases)}
+    return {"t": now, "ticks": self.ticks, "steps": self.steps, "seconds": dict(self.seconds), "intervals": dict(self.intervals), "phases": dict(self.phases), "counts": dict(self.counts)}

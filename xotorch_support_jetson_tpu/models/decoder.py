@@ -2261,6 +2261,9 @@ def fused_paged_batch_decode(params, cfg: ModelConfig, shard: Shard, token, pool
 # token-identical to the alternating baseline by construction (test-pinned).
 
 
+MIXED_PREFILL_SCOPE = "mixed.prefill"  # the prefill half of a mixed tick in a device op's ``op_name``: a path component, and no ``xot.`` one
+
+
 @partial(tracked_jit, "decode.mixed_paged_batch", static_argnames=("cfg", "shard", "n_steps", "k_max", "page_size", "use_kernel"), donate_argnums=(4,))
 def _fused_mixed_paged_batch_decode_impl(params, cfg: ModelConfig, shard: Shard, token, pool, block_tables, positions, active, temps, top_ks, pf_tokens, pf_bt, pf_prefix, pf_end, n_steps: int, k_max: int, page_size: int, use_kernel: bool, key, adapter_ids, pf_adapter):
   from ..ops.paged import gather_row_pages, scatter_row_pages, touched_page_targets
@@ -2271,12 +2274,16 @@ def _fused_mixed_paged_batch_decode_impl(params, cfg: ModelConfig, shard: Shard,
   # through the ordinary admission path so first-token key-split semantics
   # are untouched). pf_prefix/pf_end are traced [1] scalars: slice length
   # changes within a pad bucket never recompile (the traced-budget contract).
+  # The whole half lies under ONE outer scope that is no ``xot.`` component (ISSUE 55): a device op's ``op_name`` reads
+  # ``…/mixed.prefill/xot.moe_experts/…``, so a reader of the profiler's trace tells the slice's work from the scan's by
+  # one path component (benchmark/half_lib.py) while the component readers, which keep the ``xot.`` parts, read what they read.
   S = pf_tokens.shape[1]
-  temp_c = {k: gather_row_pages(v, pf_bt) for k, v in pool.items()}
-  ppos = pf_prefix[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-  _, temp_c = shard_forward(params, cfg, shard, pf_tokens, ppos, temp_c, head_pos=pf_end - pf_prefix - 1, adapter_ids=pf_adapter)
-  target = touched_page_targets(pf_bt, pf_prefix, pf_end, page_size)
-  pool = {k: scatter_row_pages(pool[k], temp_c[k], target) for k in pool}
+  with jax.named_scope(MIXED_PREFILL_SCOPE):
+    temp_c = {k: gather_row_pages(v, pf_bt) for k, v in pool.items()}
+    ppos = pf_prefix[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    _, temp_c = shard_forward(params, cfg, shard, pf_tokens, ppos, temp_c, head_pos=pf_end - pf_prefix - 1, adapter_ids=pf_adapter)
+    target = touched_page_targets(pf_bt, pf_prefix, pf_end, page_size)
+    pool = {k: scatter_row_pages(pool[k], temp_c[k], target) for k in pool}
 
   # Decode half: the plain program's scan, verbatim (_paged_decode_scan).
   return _paged_decode_scan(params, cfg, shard, token, pool, block_tables, positions, active, temps, top_ks, n_steps, k_max, page_size, use_kernel, key, adapter_ids)

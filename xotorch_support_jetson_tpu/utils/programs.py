@@ -24,7 +24,9 @@ module turns them into a gated measurement (ISSUE 19):
   and the python body of a program being traced under
   ``xot.trace:<family>``, so a capture shows which host span a device idle
   gap fell into and tells a compiling dispatch from a steady one. Both cost
-  nothing while no capture runs.
+  nothing while no capture runs. The scheduler's ``xot.sched.stage`` span
+  around a MIXED tick's dispatch also carries ``pf_tokens`` / ``pf_pad``:
+  the real and the padded tokens of the prefill slice it hands over.
 - **Component scopes** (:func:`component_scope`, ``jax.named_scope``): the
   model code names its components in HLO metadata, one vocabulary for every
   program — ``xot.embed``, ``xot.attn_proj`` (norm, q/k/v or latent
@@ -36,6 +38,11 @@ module turns them into a gated measurement (ISSUE 19):
   did not fuse into its matmul), ``xot.head``, ``xot.sample``. The trace
   reducer (benchmark/span_lib.py) reads them from each device op's
   ``op_name``; they change no optimised program (tests/test_named_scopes.py).
+  One name is NOT a component: ``mixed.prefill`` (models/decoder.py
+  ``MIXED_PREFILL_SCOPE``) is the outer scope of a mixed tick's prefill half,
+  so its ops read ``…/mixed.prefill/xot.<component>/…``; the component
+  readers keep the ``xot.`` parts and see what they saw, benchmark/half_lib.py
+  splits the program's device time into its two halves by that one part.
 - **Warmup manifest**: the scheduler enumerates the program set expected for
   the active config; ``POST /v1/warmup`` pre-compiles it off the serving
   path and calls :meth:`ProgramLedger.mark_steady`.
