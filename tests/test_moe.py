@@ -303,6 +303,22 @@ def interpreted(monkeypatch):
   monkeypatch.setattr(moe, "INTERPRET", True)
 
 
+def _force_walk(monkeypatch, walk: str, tile: int | None = None):
+  """``ops/moe.py grouped_walk`` answering ``walk`` whatever the shapes (the rule itself is
+  ``test_the_walk_is_read_from_static_shapes``'s), at ``tile`` rows or the shared walk's height — ONE height for both
+  walks, so that this CPU's dot, whose sums follow the tile's height as the MXU's do not, gives them the same bits."""
+  from xotorch_support_jetson_tpu.ops import moe
+
+  monkeypatch.setattr(moe, "grouped_walk", lambda rows, *a, **kw: (walk, tile or (moe.ROW_TILE if rows >= moe.ROW_TILE else -(-rows // 16) * 16)))
+
+
+@pytest.fixture(params=["shared", "aligned"])
+def walk(request, monkeypatch):
+  """Both walks of the grouped form, whatever the shapes."""
+  _force_walk(monkeypatch, request.param)
+  return request.param
+
+
 def _both_forms(x, w_router, w_gate, w_up, w_down, k, held=None, scales=None, layer=None, **routing):
   """(block form, grouped form with its kernels interpreted) of one layer: each (out, aux, visited). The grouped form
   takes the leaves as a stack (of one layer, where ``layer`` is None) — codes with their ``scales`` —, the block form
@@ -331,7 +347,7 @@ def _assert_same(ref, got, rtol=1e-5, atol=1e-5):
 
 
 @pytest.mark.parametrize("kwargs", ROUTINGS)
-def test_moe_grouped_form_matches_block_form(kwargs, interpreted):
+def test_moe_grouped_form_matches_block_form(kwargs, interpreted, walk):
   """The grouped form (sorted assignments, two Mosaic kernels, interpreted here) computes the same outputs, auxiliary
   loss and count of experts visited as the dispatch/combine einsums, for every routing variant."""
   rng = np.random.default_rng(17)
@@ -353,9 +369,13 @@ def test_moe_grouped_form_matches_block_form(kwargs, interpreted):
     ("empty groups: 4 assignments over 32 experts", 2, 32, 2, None),
     ("a held range with choices outside it, some rows with none inside", 40, 16, 2, (4, 9)),
     ("a held range nobody chose: no visit at all", 3, 16, 1, (15, 16)),
+    ("unequal groups: one expert takes every token's first choice", 96, 8, 2, None),
+    ("a group of exactly one tile: 128 tokens, one choice, one expert", 128, 4, 1, None),
+    ("a group one row over a tile: 129 rows", 129, 4, 1, None),
+    ("most choices not held: 2 of 32 experts", 80, 32, 4, (30, 32)),
   ],
 )
-def test_moe_grouped_form_over_tiles_groups_and_held_ranges(what, T, E, k, held, interpreted):
+def test_moe_grouped_form_over_tiles_groups_and_held_ranges(what, T, E, k, held, interpreted, walk):
   rng = np.random.default_rng(T * 31 + E)
   D, F = 16, 24
   E_held = E if held is None else held[1] - held[0]
@@ -363,8 +383,13 @@ def test_moe_grouped_form_over_tiles_groups_and_held_ranges(what, T, E, k, held,
   if what.startswith("a held range nobody"):
     w_router = w_router.at[:, 15].set(-w_router[:, :15].sum(axis=1))  # expert 15 scores under every other where they score high
   x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+  if what.startswith(("unequal groups", "a group of exactly", "a group one row")):
+    x = jnp.abs(x)
+    w_router = w_router.at[:, 1].set(10.0)  # positive tokens: expert 1 scores over every other, for every token
   ref, got = _both_forms(x, w_router, w_gate, w_up, w_down, k, held=held, norm_topk=True)
   _assert_same(ref, got)
+  if what.startswith("a group"):
+    assert int(got[2]) == 1
   if what.startswith("empty groups"):
     assert int(got[2]) <= 4
   if held is not None:
@@ -372,7 +397,7 @@ def test_moe_grouped_form_over_tiles_groups_and_held_ranges(what, T, E, k, held,
     assert int(got[2]) <= min(E_held, int(whole[2]))
 
 
-def test_moe_grouped_form_leaves_garbage_rows_out_by_where(interpreted):
+def test_moe_grouped_form_leaves_garbage_rows_out_by_where(interpreted, walk):
   """Rows of an expert not held hold whatever the kernels found (here: NaN from an uninitialised output block): the
   result has none of it."""
   rng = np.random.default_rng(5)
@@ -384,7 +409,7 @@ def test_moe_grouped_form_leaves_garbage_rows_out_by_where(interpreted):
 
 
 @pytest.mark.parametrize("T", [1, 16, 130])
-def test_moe_grouped_form_takes_int8_codes_and_a_stacks_layer(T, interpreted):
+def test_moe_grouped_form_takes_int8_codes_and_a_stacks_layer(T, interpreted, walk):
   """int8 leaves go into the kernels as codes, their per-output-channel scales multiply the products' rows; the leaves
   are a stack's and the layer a traced scalar. Against the block form over the dequantised layer."""
   from xotorch_support_jetson_tpu.models.quantize import quantize_weight
@@ -402,7 +427,7 @@ def test_moe_grouped_form_takes_int8_codes_and_a_stacks_layer(T, interpreted):
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(alone[0]), rtol=1e-6, atol=1e-6)
 
 
-def test_moe_ffn_cuts_a_long_run_into_grouped_pieces(monkeypatch, interpreted):
+def test_moe_ffn_cuts_a_long_run_into_grouped_pieces(monkeypatch, interpreted, walk):
   """A run longer than ``GROUPED_MAX_TOKENS`` goes through the grouped form in pieces, by tokens: same result as the
   block form's blocks; the count of visits is summed over the pieces. ``moe_ffn`` takes the grouped form where it is
   handed a stack and a layer, the block form where it is handed a layer's leaves."""
@@ -423,6 +448,114 @@ def test_moe_ffn_cuts_a_long_run_into_grouped_pieces(monkeypatch, interpreted):
   pieces = [moe._moe_ffn_block(x[at : at + 32], w_router, w_gate[1], w_up[1], w_down[1], k, "softmax", False, None, 1.0, None, 1, 1, "none") for at in (0, 32, 64)]
   assert int(got[2]) == int(ref[2]) == sum(int(p[2]) for p in pieces)
   np.testing.assert_allclose(float(got[1]), sum(float(p[1]) * n for p, n in zip(pieces, (32, 32, 16))) / T, rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+  "what,T,E,k,held,form",
+  [
+    ("unequal groups over three tiles", 100, 8, 3, None, "gated"),
+    ("an expert with no row: 3 tokens over 16", 3, 16, 2, None, "gated"),
+    ("a group of exactly one tile and one a row over", 257, 2, 1, None, "gated"),
+    ("most choices not held", 80, 32, 4, (30, 32), "gated"),
+    ("int8 codes with scales", 70, 8, 2, None, "int8"),
+    ("the ungated form", 70, 8, 3, None, "ungated"),
+    ("the routing handed in", 70, 8, 3, None, "routed"),
+  ],
+)
+def test_the_aligned_walk_gives_the_shared_walks_rows_bit_for_bit(what, T, E, k, held, form, interpreted, monkeypatch):
+  """Where a row lies in its tile changes nothing of its dot products, and the combine adds the same k float32 terms
+  in the same order with exactly 0 for a choice not held: the two walks agree in every bit, at one tile height (a
+  CPU's dot sums by the tile's height; the chip's check at the tall tile is ``scripts/moe_grouped_bench.py --check``)
+  — and the aligned walk at a tile of its own (32 rows) agrees with the block form."""
+  from xotorch_support_jetson_tpu.models.quantize import quantize_weight
+  from xotorch_support_jetson_tpu.ops import moe
+
+  rng = np.random.default_rng(56)
+  D, F = 32, 64
+  E_held = E if held is None else held[1] - held[0]
+  w_router, *ws = _experts(rng, E, E_held, D, F, layers=2)
+  x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+  if what.startswith("a group of exactly"):  # a routing handed in: 128 tokens to expert 0, 129 to expert 1
+    routed = moe.Routed(jnp.zeros((T, E)), jnp.ones((T, 1)), (jnp.arange(T) >= 128).astype(jnp.int32)[:, None])
+  else:
+    routed = moe.route(x, w_router, k, "softmax", True) if form == "routed" else None
+  scales, gate = None, ws[0]
+  if form == "int8":
+    (gate, sg), (up, su), (down, sd) = (quantize_weight(w) for w in ws)
+    ws, scales = [gate, up, down], (sg, su, sd)
+  if form == "ungated":
+    gate, ws = None, [None, jnp.swapaxes(ws[1], -1, -2), ws[2]]
+  run = lambda: moe._moe_ffn_grouped(x, w_router, *ws, k, "softmax", True, None, 1.0, 1, 1, "none", held, scales, jnp.int32(1), "silu" if gate is not None else "relu2", routed)  # noqa: E731
+  got = {}
+  for walk in moe.WALKS:
+    _force_walk(monkeypatch, walk)
+    got[walk] = run()
+  np.testing.assert_array_equal(np.asarray(got["aligned"][0]), np.asarray(got["shared"][0]))
+  assert np.asarray(got["shared"][0]).any() and int(got["aligned"][2]) == int(got["shared"][2]) and float(got["aligned"][1]) == float(got["shared"][1])
+  _force_walk(monkeypatch, "aligned", 32)
+  np.testing.assert_allclose(np.asarray(run()[0]), np.asarray(got["shared"][0]), rtol=2e-5, atol=2e-5)
+
+
+def test_the_aligned_walk_names_its_calls_and_passes_over_no_product_twice(interpreted, monkeypatch):
+  """The aligned walk's Mosaic calls carry names of their own, its kernels read no output block and hold no ``where``,
+  and no op but the gather that picks each token's k rows takes the float32 products [rows, D]; the gauge counts the
+  call sites traced on each walk."""
+  from xotorch_support_jetson_tpu.ops import moe
+  from xotorch_support_jetson_tpu.utils.metrics import metrics
+
+  rng = np.random.default_rng(7)
+  w_router, w_gate, w_up, w_down = _experts(rng, 8, 8, 128, 128, layers=1)
+  x = jnp.asarray(rng.normal(size=(64, 128)), jnp.float32)
+  traced, before = {}, {walk: metrics.gauge_value("moe_grouped_walk", labels={"walk": walk}) or 0 for walk in moe.WALKS}
+  for walk in moe.WALKS:
+    _force_walk(monkeypatch, walk)
+    traced[walk] = jax.make_jaxpr(lambda x: moe.moe_ffn(x, w_router, w_gate, w_up, w_down, k=2, layer=0))(x)
+    assert metrics.gauge_value("moe_grouped_walk", labels={"walk": walk}) == before[walk] + 1
+
+  def eqns(jaxpr):  # every equation, those of nested programs too (a ``jnp.take`` is a ``pjit`` of a ``gather``), a kernel's body apart
+    for eqn in jaxpr.eqns:
+      inner = [] if eqn.primitive.name == "pallas_call" else [v for v in eqn.params.values() if hasattr(v, "jaxpr") or hasattr(v, "eqns")]
+      yield from ([eqn] if not inner else (e for v in inner for e in eqns(getattr(v, "jaxpr", v))))
+
+  names = lambda walk: sorted(e.params["name"] for e in eqns(traced[walk].jaxpr) if e.primitive.name == "pallas_call")  # noqa: E731
+  assert names("aligned") == ["moe_down_rows", "moe_gate_up_rows"] and names("shared") == ["moe_down", "moe_gate_up"]
+  products = ((64 * 2 // 128 + 8) * 128, 128)  # the aligned buffer: M // tm + E_held tiles of float32 rows
+  takers = [e.primitive.name for e in eqns(traced["aligned"].jaxpr) if e.primitive.name != "pallas_call" and any(getattr(v.aval, "shape", None) == products and v.aval.dtype == jnp.float32 for v in e.invars)]
+  assert takers == ["gather"], takers
+  for e in eqns(traced["aligned"].jaxpr):
+    if e.primitive.name == "pallas_call":
+      assert "select_n" not in str(e.params["jaxpr"]) and len(e.params["jaxpr"].eqns) < 16, e.params["name"]
+
+
+EXPERT_CELLS = [  # (cell, decode rows, k, router's width, experts held, a full slice's or prefill piece's tokens, its tile)
+  ("smallthinker", 32, 6, 64, 64, 2048, 224),  # 192 rows an expert: one tile of 224, not two of 128
+  ("laguna", 64, 8, 256, 256, 2048, 96),  # 64 rows an expert
+  ("ling", 64, 8, 512, 128, 8 * 512, 96),  # 64 rows an expert of the router's 512, whatever share is held
+  ("moonlight", 16, 6, 64, 64, 4096, 224),  # 384 rows an expert: two tiles of 224
+  ("nemotron", 64, 6, 128, 128, 4096, 224),  # 192
+]
+
+
+@pytest.mark.parametrize("cell,rows,k,E,E_held,full,tile", EXPERT_CELLS)
+def test_the_walk_is_read_from_static_shapes(cell, rows, k, E, E_held, full, tile):
+  """``grouped_walk`` — the one place the walk is chosen, a pure function of static shapes: shared at every decode shape
+  of the five expert cells (16 rows up to the cell's slots) and at a short group, aligned at each cell's full slice or
+  prefill piece, at the tile a group of the usual length costs least, where VMEM holds it; the threshold reads the
+  share of the router's experts the shard holds."""
+  import math
+
+  from xotorch_support_jetson_tpu.ops import moe
+
+  for T in sorted({16, 32, rows, 256}):
+    walk, tm = moe.grouped_walk(T * k, E, E_held)
+    assert walk == "shared" and tm == (moe.ROW_TILE if T * k >= moe.ROW_TILE else -(-T * k // 16) * 16), (cell, T)
+  assert moe.grouped_walk(full * k, E, E_held) == ("aligned", tile), cell
+  assert moe.grouped_walk(moe.GROUPED_MAX_TOKENS * k, E, E_held)[0] == "aligned"
+  short = math.ceil(moe.ALIGNED_MIN_ROWS * math.sqrt(E_held / E) * E)  # Ling's quarter: from 32 rows an expert
+  assert moe.grouped_walk(short - 1, E, E_held) == ("shared", moe.ROW_TILE) and moe.grouped_walk(short, E, E_held) == ("aligned", 96)
+  big = ((8192, 8192, 2), (8192, 8192, 1))  # blocks no tile taller than the shared walk's fits beside
+  assert moe.grouped_walk(192 * E, E, E) == ("aligned", 224) and moe.grouped_walk(192 * E, E, E, big, 2) == ("aligned", moe.ROW_TILE)
+  assert set(moe.WALKS) == {"aligned", "shared"} and moe.ROW_TILE in moe.ALIGNED_TILES and all(tm % 16 == 0 for tm in moe.ALIGNED_TILES)
 
 
 BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
@@ -574,7 +707,7 @@ def _ungated_forms(x, w_router, w_up_t, w_down, k, act="relu2", routed=None, **r
 
 @pytest.mark.parametrize("act", ["relu2", "relu", "silu"])
 @pytest.mark.parametrize("T", [1, 5, 40])
-def test_an_ungated_experts_two_forms_equal_a_per_token_loop(act, T, interpreted):
+def test_an_ungated_experts_two_forms_equal_a_per_token_loop(act, T, interpreted, walk):
   """``w_gate`` None: W_down act(W_up x) with both matrices stored [F, D]. The block form's two einsums, the grouped
   form's ``moe_up`` (a transposed contraction against the whole [F, D] block, the nonlinearity in the kernel) and
   ``moe_down``, and a loop over each token's chosen experts in numpy agree; relu² squares in float32. F = 24 is no
@@ -597,7 +730,7 @@ def test_an_ungated_experts_two_forms_equal_a_per_token_loop(act, T, interpreted
   assert act != "relu2" or np.allclose(np.asarray(EXPERT_ACTS["relu2"](jnp.asarray([-2.0, 3.0]))), [0.0, 9.0])
 
 
-def test_an_ungated_expert_layers_padded_row_chooses_no_expert(interpreted):
+def test_an_ungated_expert_layers_padded_row_chooses_no_expert(interpreted, walk):
   """A routing drawn elsewhere whose last row chose no expert at all (an id past the last: the padding of a long run's
   last block) adds nothing for that row and visits no expert for it, in both forms."""
   from xotorch_support_jetson_tpu.ops.moe import Routed, route

@@ -767,6 +767,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_moe_experts_routed",  # the router's width of the loaded shard's expert layers (0: dense) (ISSUE 36)
   "xot_tpu_moe_experts_held",  # how many of those experts' weights the shard holds: fewer for one chip's share of an expert-parallel deployment (ISSUE 36)
   "xot_tpu_moe_ffn_form",  # {form}: 1 on the form the routed experts' product takes in the pool's programs: grouped / block (ops/moe.py ffn_form, ISSUE 40)
+  "xot_tpu_moe_grouped_walk",  # {walk}: call sites of the grouped form traced on each walk since start: aligned (tiles of one expert: a prompt's slice, a prefill group) / shared (a decode step, a short group) (ops/moe.py grouped_walk, ISSUE 56)
   "xot_tpu_moe_experts_visited_total",  # distinct held experts the decode rows chose, summed over expert layers and steps; over the next: the mean a layer and step (ISSUE 40)
   "xot_tpu_moe_expert_layer_steps_total",  # expert layers x decode steps of the settled chunks (ISSUE 40)
   "xot_tpu_attention_layers",  # {kind}: the page pool's layers whose attention sees every position (full) / its last window (window) (ISSUE 46)
@@ -859,6 +860,7 @@ def test_metric_name_snapshot_after_serving():
   gm.set_gauge("recurrent_state_step", 0, labels={"form": "reference"})  # set when a pool with state leaves is made (ISSUE 35)
   gm.inc("recurrent_state_resets_total", 0)  # event-driven: only a configuration with recurrent layers resets a slot's state (ISSUE 34)
   gm.set_gauge("moe_ffn_form", 0, labels={"form": "block"})  # set when a pool is made for a model with routed experts (ISSUE 40)
+  gm.set_gauge("moe_grouped_walk", 0, labels={"walk": "shared"})  # set when a pool is made for a model with routed experts, and again whenever a program with the grouped form is traced (ISSUE 56)
   gm.inc("moe_experts_visited_total", 0)  # event-driven: only a model with routed experts visits any (ISSUE 40)
   gm.inc("moe_expert_layer_steps_total", 0)
   gm.set_gauge("attention_layers", 0, labels={"kind": "full"})  # set when a page pool is made (ISSUE 46)

@@ -207,6 +207,10 @@ def test_the_grouped_form_takes_the_routing_and_the_gate_through_its_mosaic_body
 # jaxpr each traced to at the parent of PR 50 (b6a476b), block form and grouped form: the operand and the gate this PR
 # adds go through code all three run, and their programs must not move. A change to ops/moe.py that means to move them
 # re-records these (``python tests/test_swa_nope_moe.py``); a change of the jax version may reword a jaxpr, too.
+# "grouped" is the shared walk — what a decode step takes, and all there was until ISSUE 56: its jaxprs, kernel bodies
+# and all, are still the parent of PR 50's. "aligned" is the other walk over the same shapes (300 tokens x top 8 or 6
+# over 32 experts: 56-75 rows an expert, on either side of the rule's threshold, so each walk is named here and the
+# rule has a test of its own, tests/test_moe.py), recorded at PR 56.
 _FAMILIES = {
   "laguna": dict(k=8, scoring="sigmoid", norm_topk=True, scale=2.5, bias=True),
   "ling": dict(k=8, scoring="sigmoid", norm_topk=True, scale=2.5, bias=True, n_group=8, topk_group=4, group_mode="top2sum", held=(8, 16)),
@@ -219,7 +223,11 @@ _RECORDED = {
   ("ling", "grouped"): "d2f8a0d70f0c03f438c9b36aeaf9b7f18dd73572cca43f0a006386e15954166d",
   ("moonlight", "block"): "aa40e754dbc190ea73d29d431ca10f7ad29df5b9209c4fba1ffc1d9dd7313d61",
   ("moonlight", "grouped"): "448109871c51dabd590ddac434dd509a1c09943f5b31bea161c211afda4ae39f",
+  ("laguna", "aligned"): "2a3d2301c38615eb7e2b092b2e936df9ee732adee3305deea6575e2cf20a596c",
+  ("ling", "aligned"): "d071aa1cd2a324df5df0d12b35715cf9ffdaf9a95a948f8e9c27ff14cc493374",
+  ("moonlight", "aligned"): "d7c5670d81181f77e5a4d97ed290e8589e46680c419412d9b58060ab094f7425",
 }
+_WALK = {"grouped": "shared", "aligned": "aligned"}  # the walk each recorded grouped-form jaxpr was traced on
 
 
 def _family_jaxpr(family: str, form: str) -> str:
@@ -228,18 +236,22 @@ def _family_jaxpr(family: str, form: str) -> str:
   Eh = held[1] - held[0] if held else E
   sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
   bias = jnp.zeros((E,), jnp.float32) if args.pop("bias") else None
-  lead = (2,) if form == "grouped" else ()
-  call = lambda x, w_router, w_gate, w_up, w_down: moe.moe_ffn(x, w_router, w_gate, w_up, w_down, selection_bias=bias, **args, **({"layer": 1} if form == "grouped" else {}))  # noqa: E731
-  with jax.default_matmul_precision("highest"):  # (named here, so that the text does not turn on who runs it)
-    return str(jax.make_jaxpr(call)(sds(300, 128), sds(128, E), sds(*lead, Eh, 128, 256), sds(*lead, Eh, 128, 256), sds(*lead, Eh, 256, 128)))
+  lead = () if form == "block" else (2,)
+  call = lambda x, w_router, w_gate, w_up, w_down: moe.moe_ffn(x, w_router, w_gate, w_up, w_down, selection_bias=bias, **args, **({} if form == "block" else {"layer": 1}))  # noqa: E731
+  rule, moe.grouped_walk = moe.grouped_walk, lambda rows, *a, **kw: (_WALK.get(form), moe.ROW_TILE)
+  try:
+    with jax.default_matmul_precision("highest"):  # (named here, so that the text does not turn on who runs it)
+      return str(jax.make_jaxpr(call)(sds(300, 128), sds(128, E), sds(*lead, Eh, 128, 256), sds(*lead, Eh, 128, 256), sds(*lead, Eh, 256, 128)))
+  finally:
+    moe.grouped_walk = rule
 
 
-@pytest.mark.parametrize("form", ["block", "grouped"])
+@pytest.mark.parametrize("form", ["block", "grouped", "aligned"])
 @pytest.mark.parametrize("family", list(_FAMILIES))
 def test_the_other_families_expert_programs_trace_to_the_jaxprs_they_had(family, form, monkeypatch):
   monkeypatch.setattr(moe, "INTERPRET", True)
   text = _family_jaxpr(family, form)
-  assert ("pallas_call" in text) == (form == "grouped") and "logistic" in text
+  assert ("pallas_call" in text) == (form != "block") and "logistic" in text and ("_rows" in text) == (form == "aligned")
   assert hashlib.sha256(text.encode()).hexdigest() == _RECORDED[family, form]
 
 

@@ -316,6 +316,64 @@ def test_expert_kernels_compile_for_v5e(chip, what, T, L, E, held, D, F, k, dtyp
   assert compiled.memory_analysis().temp_size_in_bytes < 64e6, what
 
 
+@pytest.mark.parametrize(
+  "what,T,L,E,held,D,F,k,dtype,gated,tile",
+  [
+    ("SmallThinker's slice: 192 rows an expert, one tile of 224", 2048, 8, 64, None, 2560, 768, 6, jnp.bfloat16, True, 224),
+    ("Laguna's slice: 64 rows an expert", 2048, 5, 256, None, 2048, 512, 8, jnp.bfloat16, True, 96),
+    ("Ling's prefill group: 64 rows an expert of the router's 512, a quarter of them held", 4096, 6, 512, (0, 128), 2560, 768, 8, jnp.bfloat16, True, 96),
+    ("Moonlight's prefill group: int8 codes, 384 rows an expert: two tiles of 224", 4096, 13, 64, None, 2048, 1408, 6, jnp.int8, True, 224),
+    ("Nemotron's prefill group: ungated, one [1856, 2688] block a visit", 4096, 4, 128, None, 2688, 1856, 6, jnp.bfloat16, False, 224),
+  ],
+)
+def test_a_prompts_expert_product_walks_aligned_tiles_on_v5e(chip, what, T, L, E, held, D, F, k, dtype, gated, tile, monkeypatch):
+  """A run of many rows an expert (ISSUE 56) as Mosaic and XLA:TPU lower it for a v5e at the five expert cells' full
+  slice or prefill piece: the aligned walk's calls under their own names, at the tile ``grouped_walk`` reads from the
+  shapes, inside the 64 MiB VMEM limit; the expert stacks go through the Mosaic calls only (no copy, no cut, no
+  relayout); ONE fusion takes the float32 products (the gather of the rows a token's choices name, a whole tile of
+  sublanes a token, which the weighted sum reads with no relayout). The temporaries' growth over the shared walk's, stated: at most the ``E_held`` tiles
+  the three row buffers are longer by — rows x ((D + F) activations + D float32) — and in fact about half of it, the
+  buffers not all living at once (126 MB at SmallThinker's slice, 264 MB at Nemotron's piece of 4096 tokens)."""
+  from xotorch_support_jetson_tpu.ops import moe
+
+  E_held = E if held is None else held[1] - held[0]
+  scaled = dtype == jnp.int8
+  first = [_sds(chip, (L, E_held, D, F), dtype)] * 2 if gated else [_sds(chip, (L, E_held, F, D), dtype)]
+  leaves = [*first, _sds(chip, (L, E_held, F, D), dtype)]
+  scales = [_sds(chip, (L, E_held, F), jnp.float32), _sds(chip, (L, E_held, F), jnp.float32), _sds(chip, (L, E_held, D), jnp.float32)] if scaled else []
+  monkeypatch.setattr(moe, "_on_tpu", lambda: True)  # (the backend here is the CPU)
+  assert moe.ffn_form(leaves[0], leaves[-1], None, True, scaled, gated=gated) == "grouped"
+  rule = moe.grouped_walk
+
+  def compiled(walk):
+    def layer(x, w_router, layer, *rest):  # (a function of its own a walk: a trace is cached by the function)
+      ws, sc = rest[: len(leaves)], rest[len(leaves) :]
+      return moe.moe_ffn(x, w_router, *(ws if gated else (None, *ws)), k=k, held=held, scales=sc or None, layer=layer, act="silu" if gated else "relu2")
+
+    if walk == "shared":
+      monkeypatch.setattr(moe, "grouped_walk", lambda rows, *a, **kw: ("shared", moe.ROW_TILE))
+    else:
+      seen = []
+      monkeypatch.setattr(moe, "grouped_walk", lambda *a, **kw: seen.append(rule(*a, **kw)) or seen[-1])
+    out = jax.jit(layer).lower(_sds(chip, (T, D), jnp.bfloat16), _sds(chip, (D, E), jnp.float32), _sds(chip, (), jnp.int32), *leaves, *scales).compile()
+    assert walk == "shared" or seen == [("aligned", tile)], seen
+    return out
+
+  aligned = compiled("aligned")
+  text = aligned.as_text()
+  assert sorted(_mosaic_calls(text)) == ["moe_down_rows", "moe_gate_up_rows" if gated else "moe_up_rows"], _mosaic_calls(text)
+  stack = rf"{'s8' if scaled else 'bf16'}\[{L},{E_held},({D},{F}|{F},{D})\]"
+  assert {op for _, op in _takers(text, stack)} == {"custom-call"}
+  rows = (T * k // tile + E_held) * tile
+  takers = _takers(text, rf"f32\[{rows},{D}\]")
+  assert [op for _, op in takers] == ["fusion"] * (2 if rows == T * 8 else 1), takers  # ONE taker: the gather of the rows a token's choices name (at Moonlight's shapes what it gathers has the products' shape, and a taker of its own)
+  assert not re.search(rf"= f32\[{T},8,{D}\]\S* (copy|reshape|transpose)\(", text)  # which are a whole tile of sublanes a token: no relayout ahead of the sum
+  grown = E_held * tile * ((D + F) * 2 + D * 4)
+  temp, before = aligned.memory_analysis().temp_size_in_bytes, compiled("shared").memory_analysis().temp_size_in_bytes
+  print(f"{what}: temporaries {before} shared -> {temp} aligned ({rows} rows for {T * k} assignments; stated growth {grown})")
+  assert 0 < temp - before < grown, (what, before, temp, grown)
+
+
 def test_training_a_lane_wide_moe_lowers_for_v5e(chip, monkeypatch):
   """``jax.grad`` of ``shard_forward_aux`` (train/trainer.py, parallel/train_step.py) for a MoE whose expert faces are
   whole lane groups — the served programs of the same weights take the grouped form on this chip — lowers and compiles

@@ -185,6 +185,7 @@ def test_kda_hybrid_prefill_group_at_the_cells_longest_fits_v5e(chip, monkeypatc
   experts = [line.strip()[:160] for line in text.splitlines() if re.search(r"= bf16\[(5,|1,)?128,(2560,768|768,2560)\]\S* (copy|copy-start|transpose|fusion|dynamic-slice)\(", line)]
   assert not experts, experts
   assert text.count('custom_call_target="tpu_custom_call"') >= 8  # (gate/up, down) x 2 pieces x 2 stacks' loops
+  assert {name for name in _mosaic_calls(text) if name.startswith("moe_")} == {"moe_gate_up_rows", "moe_down_rows"}  # 64 rows an expert of the router's 512: the aligned walk (ISSUE 56)
   mem = compiled.memory_analysis()
   print(f"prefill.pages_many_sampled ling K=8 S=1024: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
@@ -423,6 +424,10 @@ def test_swa_nope_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_se
   mem = compiled.memory_analysis()
   print(f"decode.mixed_paged_batch smallthinker pad=2048 window={mp}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert text.count("paged_decode_window") >= 1 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+  # A mixed tick's two halves walk their rows each its own way (ISSUE 56, ``ops/moe.py grouped_walk``): the decode
+  # half's 32 rows the shared walk, as ``decode.paged_batch`` above, the slice's 2048 tokens tiles of ONE expert.
+  names = _mosaic_calls(text)
+  assert [names.count(name) for name in ("moe_gate_up", "moe_down", "moe_gate_up_rows", "moe_down_rows")] == [4, 4, 4, 4], names
   # The prefill groups the ramp meets, the pool donated: ``_group_rows`` holds rows x page window to 8 first chunks' (256
   # pages in all), so 8 rows of a first chunk over 32 pages and ONE row of a 12 k-token prompt's last chunk over the
   # whole 256 are the most K/V a group gathers beside its activations (8 rows over 256 pages would be 4.2 GB of
@@ -438,6 +443,7 @@ def test_swa_nope_moe_decode_step_mixed_tick_and_longest_prefill_at_the_cells_se
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     flash = [line for line in kernels if "xot.moe_experts/moe_" not in line]
     assert len(flash) == 4 and all("flash_attention_prefill" in line for line in flash), [line.strip()[-300:] for line in flash]
+    assert {name for name in _mosaic_calls(text) if name.startswith("moe_")} == {"moe_gate_up_rows", "moe_down_rows"}
     print(f"prefill.pages_many_sampled smallthinker K={K} S={S} window={window}: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
     # That it compiled is the fit (its temporaries overlap the donated pool's buffers: PERF.md section 6, PR 44).
     assert mem.alias_size_in_bytes >= pool_bytes and mem.argument_size_in_bytes < 13.4e9
@@ -494,7 +500,7 @@ def test_hybrid_ssm_moe_decode_step_and_longest_prefill_at_the_cells_settings_fi
   )  # fmt: skip
   moved = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {experts}\S* (copy|copy-start|transpose|fusion|dynamic-slice)\(", line)]
   assert not moved, moved
-  assert {"moe_up", "moe_down"} <= set(_mosaic_calls(text)) and "flash_attention_prefill" in text, _mosaic_calls(text)
+  assert {name for name in _mosaic_calls(text) if name.startswith("moe_")} == {"moe_up_rows", "moe_down_rows"} and "flash_attention_prefill" in text, _mosaic_calls(text)  # pieces of 4096 tokens, 192 rows an expert: the aligned walk (ISSUE 56)
   mem = compiled.memory_analysis()
   print(f"prefill.pages_many_sampled nemotron K=8 S=2048: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -2751,7 +2751,7 @@ class BatchedServer:
     sequence-parallel ring holds the weights itself (``engine.params`` is None): block form, and its programs do not
     count their visits."""
     from ..models.decoder import served_expert_form
-    from ..ops.moe import EXPERT_ACTS, FFN_FORMS
+    from ..ops.moe import EXPERT_ACTS, FFN_FORMS, note_walk
 
     cfg, params = self.engine.cfg, getattr(self.engine, "params", None)
     self._expert_layers = sum(stack["w_experts_down"].shape[0] for stack in (params or {}).values() if isinstance(stack, dict) and "w_experts_down" in stack)  # the stacks THIS engine holds (a shard of the layers counts its own; gated or not, an expert has a down matrix)
@@ -2760,6 +2760,7 @@ class BatchedServer:
     form = served_expert_form(params, cfg)
     for name in FFN_FORMS:
       metrics.set_gauge("moe_ffn_form", int(name == form), labels={"form": name})
+    note_walk()  # ``moe_grouped_walk{walk}`` as it stands: the grouped form's call sites move it as they are traced
     # The expert layers by where their router reads (``cfg.router_input``: its experts' own input, or the attention's,
     # drawn ahead of it) and by their experts' gate (``cfg.expert_act``): one value a model today, a count so that a
     # model of two says so.

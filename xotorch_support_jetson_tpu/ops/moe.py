@@ -25,21 +25,49 @@ decision, made once a program. Every other caller — the cache-less forward
 ``--sp`` rings' own layer loops — hands a layer's leaves and gets the block form:
 
 - the **grouped** form (``_moe_ffn_grouped``): the T·k assignments are sorted
-  by expert, their token rows gathered once ([T·k, D], activations only), and
-  two Mosaic kernels after the design of megablox's ``gmm`` (gate and up with
-  the SwiGLU between them in one, down in the other) walk the sorted rows a
-  row tile at a time. Group offsets, and for every visit its expert and its
-  row tile, are scalar-prefetch operands; visits are ordered by expert, so an
-  expert whose group is empty is never read, one whose group spans several
-  row tiles is read once a tile, and rows behind the last held group cost
-  nothing (the grid's length is the number of visits, a traced scalar). The
-  kernels take the STACKED leaves [L, E, D, F] and the layer as a scalar: a
-  layer cut out of the stack ahead of a custom call would be a copy of the
-  layer's experts every step. int8 leaves go in as codes, are cast in VMEM
-  and the product's rows multiplied by their expert's scale row. The rows go
-  back to their tokens by the inverse permutation and are summed with the
-  router's weights in float32; an assignment to an expert this shard does
-  not hold, and a padded row, weigh exactly 0 (by ``where``).
+  by expert, their token rows gathered once (activations only), and two Mosaic
+  kernels after the design of megablox's ``gmm`` (gate and up with the SwiGLU
+  between them in one, down in the other) walk the sorted rows a row tile at a
+  time. For every visit its expert and its row tile are scalar-prefetch
+  operands; visits are ordered by expert, so an expert whose group is empty is
+  never read and rows behind the last held group cost nothing (the grid's
+  length is the number of visits, a traced scalar). The kernels take the
+  STACKED leaves [L, E, D, F] and the layer as a scalar: a layer cut out of the
+  stack ahead of a custom call would be a copy of the layer's experts every
+  step. int8 leaves go in as codes, are cast in VMEM and the product's rows
+  multiplied by their expert's scale row. The rows go back to their tokens by
+  the inverse permutation and are summed with the router's weights in float32;
+  an assignment to an expert this shard does not hold weighs exactly 0 (by
+  ``where``, never a product with a zero).
+
+  **The rows are walked in one of two ways, and ``grouped_walk`` is the one
+  place that says which**, for every call site from its static shapes alone —
+  rows an expert, T·k over the router's width, and what fits VMEM — (PERF.md
+  §5, PR 56: the kernel-alone table that set its thresholds). Both give every
+  row the same bits: a row's dot products do not depend on where its tile
+  starts, and the k terms are the same float32 values added in the same order.
+
+  - the **shared** walk (a decode step, a short group: a few rows an expert;
+    Mosaic calls ``moe_gate_up`` / ``moe_up`` / ``moe_down``): the sorted rows
+    lie with no gap in tiles of ``ROW_TILE``, a tile that holds several
+    experts' rows is visited once for each, every visit multiplies the whole
+    tile and keeps its own rows over what the output block held
+    (``_own_rows``); rows of experts not held sort behind the held groups,
+    and a ``where`` over the products takes them and the last tile's padding
+    out ahead of the gather back.
+  - the **aligned** walk (a prompt's slice, a prefill group: many rows an
+    expert; ``moe_gate_up_rows`` / ``moe_up_rows`` / ``moe_down_rows``): every
+    held expert's group starts at a multiple of the tile's height, in row
+    buffers ``E_held`` tiles longer, so a visit's tile is ONE expert's —
+    Σ ⌈rows_e / tile⌉ visits, each stored whole with no look at the output
+    block; rows past a group's end are computed and never read. The tile is
+    the one of ``ALIGNED_TILES`` that a group of the usual length costs
+    least (192 rows an expert: one visit of 224 rows, whose product is as
+    long as the next expert's fetch). A choice of an expert not held has no
+    row at all, and the ``where`` that takes it out stands on the rows a
+    token gathers — a whole tile of sublanes a token, [T, 8, D], the
+    gather's own layout — inside the weighted sum's fusion: the products
+    are read once, and no pass relays them.
 - the **block** form (``_moe_ffn_block``), the reference: GShard-style dense
   [T, E, C] dispatch/combine one-hots and three ``[E, C, D] x [E, D, F]``
   einsums over EVERY held expert. Position-in-expert is a cumulative-sum
@@ -224,6 +252,14 @@ _VMEM_LIMIT = 64 << 20  # v5e has 128 MiB; the default scoped limit (16 MiB) hol
 GROUPED_MAX_TOKENS = 4096
 _INNER_GROUP = 16  # what an ungated expert's inner width F is a whole number of: the sublanes of a packed bfloat16 tile (F lies along them in both of its matrices)
 FFN_FORMS = ("grouped", "block")  # what ``ffn_form`` answers
+WALKS = ("aligned", "shared")  # what ``grouped_walk`` answers: the label of the gauge ``moe_grouped_walk``
+# Rows an expert (T·k over the router's width) from which a run takes the aligned walk, the heights its tiles may have
+# and what a visit costs beside its rows, in rows (an expert's first visit waits for its weights): PERF.md §5, the
+# kernel-alone tables of PR 56 (scripts/moe_grouped_bench.py).
+ALIGNED_MIN_ROWS = 64
+ALIGNED_TILES = (96, 128, 160, 192, 224, 256)
+VISIT_ROWS = 64
+SUBLANES = 8  # of a float32 tile: the k products a token gathers lie along them
 INTERPRET = False  # the tests' switch: a CPU takes the grouped form too, its kernels interpreted
 
 
@@ -264,6 +300,53 @@ def ffn_form(w_gate, w_down, capacity_factor, mosaic_kernels: bool, scaled: bool
   return "grouped" if tiles else "block"
 
 
+def grouped_walk(rows: int, E: int, E_held: int, blocks: tuple = (), itemsize: int = 2) -> tuple[str, int]:
+  """How the grouped form walks ``rows`` = T·k sorted assignments, from static shapes alone: (one of ``WALKS``, the row
+  tile's height). ``E`` is the router's width and ``E_held`` the experts this shard holds: rows an expert are
+  ``rows / E`` whatever share of them is held. ``blocks``: for each of the two products the (K, tn, count) of the
+  weight blocks [K, tn] a visit multiplies its rows by — a tile taller than ``ROW_TILE`` is taken only where its rows,
+  blocks and float32 products fit ``_VMEM_LIMIT`` —, ``itemsize`` the leaves'.
+
+  - **shared** (a decode step, a short group: a few rows an expert): the rows lie sorted with no gap, a row tile of
+    ``ROW_TILE`` may hold several experts' rows and is visited once for each, every visit keeping its own rows.
+  - **aligned** (a prompt's slice, a prefill group: from ``ALIGNED_MIN_ROWS`` rows an expert — from half as many
+    where the shard holds a quarter of the router's experts: three in four choices then get no row at all, where the
+    shared walk carries every one): every held expert's group starts at a multiple of the tile's height, so a visit's
+    tile is ONE expert's and is stored whole; the row buffers are ``E_held`` tiles longer. The tile is the one of
+    ``ALIGNED_TILES`` that costs a group of the usual length (the mean and 1.2 standard deviations of a uniform
+    router's) least, a visit counted as its rows and ``VISIT_ROWS`` more: 192 rows an expert walk ONE tile of 224, not
+    two of 128 — the next expert's weights then arrive behind a product as long as their fetch —, 64 one of 96, 384
+    two of 224; where its blocks fit VMEM."""
+  mean = rows / E
+  if mean < ALIGNED_MIN_ROWS * math.sqrt(E_held / E):
+    return "shared", ROW_TILE if rows >= ROW_TILE else -(-rows // 16) * 16  # (a bfloat16 tile is 16 sublanes)
+  fits = [tm for tm in ALIGNED_TILES if tm <= ROW_TILE or all(_tile_fits(tm, K, tn, count, itemsize) for K, tn, count in blocks)]
+  group = mean + 1.2 * math.sqrt(mean)  # most groups are no longer
+  return "aligned", min(fits, key=lambda tm: (math.ceil(group / tm) * (tm + VISIT_ROWS), -tm))
+
+
+def _tile_fits(tm: int, K: int, tn: int, count: int, itemsize: int) -> bool:
+  """Whether a visit of ``tm`` rows fits three quarters of ``_VMEM_LIMIT``: its rows [tm, K] and its output block
+  [tm, tn] (float32 at most) and its ``count`` weight blocks [K, tn], each double-buffered, and a float32 product a
+  weight block."""
+  return 2 * tm * K * 4 + 2 * count * K * tn * itemsize + (2 + count) * tm * tn * 4 <= _VMEM_LIMIT * 3 // 4
+
+
+_walk_sites = dict.fromkeys(WALKS, 0)
+
+
+def note_walk(walk: str | None = None) -> None:
+  """The gauge ``moe_grouped_walk{walk}``: call sites of the grouped form traced on each walk since the process
+  started (a program's prefill half and its decode half are sites of their own). ``walk`` None: publish the counts as
+  they stand (a scheduler, when its pool is made)."""
+  from ..utils.metrics import metrics
+
+  if walk is not None:
+    _walk_sites[walk] += 1
+  for name, sites in _walk_sites.items():
+    metrics.set_gauge("moe_grouped_walk", sites, labels={"walk": name})
+
+
 def _visits(sizes, m: int, tm: int):
   """The walk over sorted rows: for ``sizes`` [E] rows of each expert in turn from row 0 and row tiles of ``tm``,
   (offsets [E+1], expert of each visit [V], row tile of each visit [V], the number of visits). A visit is one
@@ -292,6 +375,15 @@ def _own_rows(offsets_ref, group_ref, tile_ref, tm: int):
   return (rows >= offsets_ref[g]) & (rows < offsets_ref[g + 1])
 
 
+def _keep(out_ref, product, walk_refs, tm: int, aligned: bool):
+  """What a visit stores of its float32 ``product`` [tm, tn]. On the aligned walk the tile is this expert's alone: all
+  of it, with no look at the block (rows past the group's end are computed and never read). On the shared walk: its
+  own rows, over what the block held."""
+  if not aligned:
+    product = jnp.where(_own_rows(*walk_refs, tm), product, out_ref[...].astype(jnp.float32))
+  out_ref[...] = product.astype(out_ref.dtype)
+
+
 def _act_in_kernel(x, act: str):
   """``EXPERT_ACTS`` as a Mosaic body spells them, on float32."""
   if act == "relu":
@@ -301,7 +393,7 @@ def _act_in_kernel(x, act: str):
   return x * jax.nn.sigmoid(x)
 
 
-def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, wu_ref, *rest, tm: int, scaled: bool, act: str = "silu"):
+def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, wu_ref, *rest, tm: int, scaled: bool, aligned: bool, act: str = "silu"):
   del layer_ref  # the index maps read it
   (sg_ref, su_ref, out_ref) = rest if scaled else (None, None, *rest)
   x = x_ref[...]
@@ -309,32 +401,32 @@ def _gate_up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wg_ref, 
   up = jnp.dot(x, wu_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)
   if scaled:
     gate, up = gate * sg_ref[...], up * su_ref[...]
-  h = _act_in_kernel(gate, act) * up
-  out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), h, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+  _keep(out_ref, _act_in_kernel(gate, act) * up, (offsets_ref, group_ref, tile_ref), tm, aligned)
 
 
-def _up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wu_ref, out_ref, *, tm: int, scaled: bool, act: str):
+def _up_kernel(layer_ref, offsets_ref, group_ref, tile_ref, x_ref, wu_ref, out_ref, *, tm: int, scaled: bool, aligned: bool, act: str):
   """The ungated expert's first product with its nonlinearity: act(x W_upᵀ), the matrix [F, D] as stored."""
   del layer_ref, scaled  # (``ffn_form`` admits no codes here)
   x = x_ref[...]
   up = jax.lax.dot_general(x, wu_ref[...].astype(x.dtype), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-  out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), _act_in_kernel(up, act), out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+  _keep(out_ref, _act_in_kernel(up, act), (offsets_ref, group_ref, tile_ref), tm, aligned)
 
 
-def _down_kernel(layer_ref, offsets_ref, group_ref, tile_ref, h_ref, wd_ref, *rest, tm: int, scaled: bool):
+def _down_kernel(layer_ref, offsets_ref, group_ref, tile_ref, h_ref, wd_ref, *rest, tm: int, scaled: bool, aligned: bool):
   del layer_ref
   (sd_ref, out_ref) = rest if scaled else (None, *rest)
   h = h_ref[...]
   y = jnp.dot(h, wd_ref[...].astype(h.dtype), preferred_element_type=jnp.float32)
   if scaled:
     y = y * sd_ref[...]
-  out_ref[...] = jnp.where(_own_rows(offsets_ref, group_ref, tile_ref, tm), y, out_ref[...])
+  _keep(out_ref, y, (offsets_ref, group_ref, tile_ref), tm, aligned)
 
 
-def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: int, out_dtype, out_major: bool = False):
+def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: int, out_dtype, out_major: bool = False, aligned: bool = False):
   """``rows`` [M, K] against each visit's expert in the stacked ``weights`` ([L, E, K, N] each; the layer's ``scales``
   [E, 1, N] float32, or none) → [M, N]: rows no visit owns come back as the kernel found them. ``out_major``: the
-  weights are stored [L, E, N, K] and a block is an expert's whole matrix."""
+  weights are stored [L, E, N, K] and a block is an expert's whole matrix. ``aligned``: the walk's tiles are one
+  expert's each (``_keep``), and the call carries a name of its own, ``<name>_rows``."""
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
@@ -348,7 +440,7 @@ def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: 
   scale_block = pl.BlockSpec((None, 1, tn), lambda j, v, layer, offsets, group, tile: (group[v], 0, j))
   out_block = pl.BlockSpec((tm, tn), lambda j, v, layer, offsets, group, tile: (tile[v], j))
   return pl.pallas_call(
-    partial(kernel, tm=tm, scaled=bool(scales)),
+    partial(kernel, tm=tm, scaled=bool(scales), aligned=aligned),
     out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
     grid_spec=pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=4,
@@ -358,8 +450,48 @@ def _grouped_product(kernel, name: str, rows, weights, scales, layer, walk, tm: 
     ),
     compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
     interpret=INTERPRET,
-    name=name,
+    name=f"{name}_rows" if aligned else name,
   )(jnp.asarray(layer, jnp.int32).reshape(1), offsets, group, tile, rows, *weights, *scales)
+
+
+def _sort_by_expert(expert, E_held: int, real: int):
+  """``expert`` [M] (each row's held expert, ``E_held`` where none) sorted: (the sorted ids, the row each sorted place
+  holds, the sorted place of each of the first ``real`` rows, the rows of each held expert [E_held])."""
+  M = expert.shape[0]
+  expert, order = jax.lax.sort_key_val(expert, jnp.arange(M, dtype=jnp.int32))
+  place = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))[:real]
+  sizes = jnp.sum(expert[:, None] == jnp.arange(E_held, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
+  return expert, order, place, sizes
+
+
+def _shared_dispatch(expert, E_held: int, tm: int):
+  """The shared walk's order of ``expert`` [M] (each assignment's held expert, ``E_held`` where it is not held): (the
+  walk's tables, the assignment of every sorted row [Mp], each assignment's sorted row [M], the rows of each expert
+  [E_held], the expert of every sorted row [Mp])."""
+  M = expert.shape[0]
+  Mp = -(-M // tm) * tm
+  # (the rows that fill the last tile sort behind every held group, as an expert not held does)
+  expert, order, place, sizes = _sort_by_expert(jnp.pad(expert, (0, Mp - M), constant_values=E_held), E_held, M)
+  return _visits(sizes, Mp, tm), order, place, sizes, expert
+
+
+def _aligned_dispatch(expert, E_held: int, tm: int):
+  """The aligned walk's order of ``expert`` [M]: the sorted order with every held group moved up to the next multiple
+  of ``tm``, in a buffer of ``M // tm + E_held`` tiles (the most Σ ⌈rows_e / tm⌉ can be). An assignment to an expert
+  not held has no row. (the walk's tables — a visit is a tile —, the assignment of every row, each held assignment's
+  row [M], the rows of each expert [E_held]). A row past its group's end names a neighbour's assignment: its products
+  are finite, and nothing reads them."""
+  M = expert.shape[0]
+  tiles = M // tm + E_held
+  _, order, place, sizes = _sort_by_expert(expert, E_held, M)
+  of_group = (sizes + tm - 1) // tm
+  last = jnp.cumsum(of_group)
+  n = last[-1]
+  shift = (last - of_group) * tm - (jnp.cumsum(sizes) - sizes)  # how far each group's rows moved up
+  tile = jnp.arange(tiles, dtype=jnp.int32)
+  group = jnp.clip(jnp.searchsorted(last, jnp.minimum(tile, jnp.maximum(n - 1, 0)), side="right", method="compare_all"), 0, E_held - 1).astype(jnp.int32)
+  source = jnp.clip(jnp.arange(tiles * tm, dtype=jnp.int32) - jnp.repeat(shift[group], tm), 0, M - 1)  # the sorted place each row holds
+  return (jnp.pad(last * tm, (1, 0)).astype(jnp.int32), group, tile, n.astype(jnp.int32)), order[source], place + jnp.pad(shift, (0, 1))[expert], sizes
 
 
 def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode, held=None, scales=None, layer=0, act="silu", routed=None):
@@ -369,31 +501,46 @@ def _moe_ffn_grouped(x, w_router, w_gate, w_up, w_down, k, scoring, norm_topk, s
   codes. Returns (out, aux, visited)."""
   T, D = x.shape
   E, E_held, M = w_router.shape[-1], w_down.shape[1], T * k
-  tm = ROW_TILE if M >= ROW_TILE else -(-M // 16) * 16  # (a bfloat16 tile is 16 sublanes)
-  Mp = -(-M // tm) * tm
+  F, gated, size = w_down.shape[-2], w_gate is not None, w_down.dtype.itemsize
+  first = (D, _col_tile(D, F, size) or F, 2) if gated else (D, F, 1)
+  walk, tm = grouped_walk(M, E, E_held, (first, (F, _col_tile(F, D, size) or D, 1)), size)
+  aligned = walk == "aligned"
+  note_walk(walk)
   logits, weights, idx = routed or route(x, w_router, k, scoring, norm_topk, selection_bias, scale, n_group, topk_group, group_mode)
   with jax.named_scope("xot.moe_experts"):  # the dispatch (the sort, the walk, the rows' gather), the products and the combine
     expert = _held_index(idx, held).reshape(M)
     expert = jnp.where((expert >= 0) & (expert < E_held), expert, E_held)  # an expert this shard does not hold sorts behind every held group
-    expert = jnp.pad(expert, (0, Mp - M), constant_values=E_held)  # and so do the rows that fill the last tile
-    expert, order = jax.lax.sort_key_val(expert, jnp.arange(Mp, dtype=jnp.int32))
-    back = jnp.zeros((Mp,), jnp.int32).at[order].set(jnp.arange(Mp, dtype=jnp.int32))[:M]  # assignment (token-major) → its sorted row
-    sizes = jnp.sum(expert[:, None] == jnp.arange(E_held, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
-    walk = _visits(sizes, Mp, tm)
+    if aligned:
+      visits, order, back, sizes = _aligned_dispatch(expert, E_held, tm)
+    else:
+      visits, order, back, sizes, of_row = _shared_dispatch(expert, E_held, tm)
     visited = jnp.sum(sizes > 0, dtype=jnp.int32)
-    rows = jnp.take(x, order // k, axis=0, mode="clip")  # [Mp, D]: each assignment's token, in sorted order
+    rows = jnp.take(x, order // k, axis=0, mode="clip")  # each row's assignment's token, in the walk's order
     # (the layer's scales are cut out of their stack — kilobytes, where an expert leaf's layer is most of a GB — as
     # [E, 1, N]: a block is one expert's row)
     cut = tuple(jax.lax.dynamic_index_in_dim(s, layer, 0, keepdims=False).astype(jnp.float32)[:, None, :] for s in scales or ())
     if w_gate is None:  # an expert of two matrices, both stored [F, D]
-      h = _grouped_product(partial(_up_kernel, act=act), "moe_up", rows, (w_up,), (), layer, walk, tm, x.dtype, out_major=True)
+      h = _grouped_product(partial(_up_kernel, act=act), "moe_up", rows, (w_up,), (), layer, visits, tm, x.dtype, out_major=True, aligned=aligned)
     else:
-      h = _grouped_product(partial(_gate_up_kernel, act=act), "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, walk, tm, x.dtype)
-    y = _grouped_product(_down_kernel, "moe_down", h, (w_down,), cut[-1:], layer, walk, tm, jnp.float32)
-    # Rows of an expert not held, and rows that pad the last tile, hold whatever the kernels found there: they are
-    # taken out by ``where``, never multiplied by a zero.
-    y = jnp.where((expert < E_held)[:, None], y, 0.0)
-    out = jnp.sum(jnp.take(y, back, axis=0).reshape(T, k, D) * weights.astype(jnp.float32)[:, :, None], axis=1).astype(x.dtype)
+      h = _grouped_product(partial(_gate_up_kernel, act=act), "moe_gate_up", rows, (w_gate, w_up), cut[:2], layer, visits, tm, x.dtype, aligned=aligned)
+    y = _grouped_product(_down_kernel, "moe_down", h, (w_down,), cut[-1:], layer, visits, tm, jnp.float32, aligned=aligned)
+    # What no held expert owns — a choice of an expert not held, and on the shared walk the rows that pad the last
+    # tile — holds whatever the kernels found there: it is taken out by ``where``, never multiplied by a zero. The
+    # shared walk passes over every sorted row for that, gathers the assignments' rows [T·k, D] and relays them to
+    # [T, k, D] — k on the sublanes, where k is no whole tile of them: a copy — for the sum. The aligned walk has no
+    # row for such a choice (``back`` names a row of another's, or one nobody wrote), so its ``where`` stands on what
+    # the tokens gather, inside the weighted sum's fusion, and it gathers a whole tile of sublanes a token (the slots
+    # past k weigh exactly 0 too): [T, slots, D] is the gather's own layout, no copy, and the sum over the sublanes is
+    # the shared walk's — the same float32 terms in the same tree, and zeros.
+    w = weights.astype(jnp.float32)
+    if aligned:
+      slots = -(-k // SUBLANES) * SUBLANES
+      pad = lambda t: jnp.pad(t.reshape(T, k), ((0, 0), (0, slots - k)))  # noqa: E731
+      picked = jnp.take(y, pad(back).reshape(T * slots), axis=0, mode="clip").reshape(T, slots, D)
+      out = jnp.sum(jnp.where(pad(expert < E_held)[:, :, None], picked, 0.0) * pad(w)[:, :, None], axis=1).astype(x.dtype)
+    else:
+      y = jnp.where((of_row < E_held)[:, None], y, 0.0)
+      out = jnp.sum(jnp.take(y, back, axis=0).reshape(T, k, D) * w[:, :, None], axis=1).astype(x.dtype)
   with jax.named_scope("xot.moe_router"):
     aux = load_balancing_loss(logits, idx, E)
   return out, aux, visited
