@@ -64,7 +64,7 @@ LONG_FIRST = (
   "test_tpu_compile_cells", "test_moe", "test_hybrid_kda_moe_kind", "test_swa_gqa_moe", "test_hybrid_kda", "test_paged",
   "test_hybrid_gdn_kind", "test_tpu_compile_smoke", "test_diffusion", "test_pp_batch", "test_hybrid_ssm", "test_spec_decode",
   "test_paged_int4", "test_hybrid_gdn", "test_pp_lifecycle", "test_tpu_compile", "test_hybrid_ssm_kind", "test_mixed_tick",
-  "test_qkv_barrier", "test_hybrid_ssm_moe", "test_swa_nope_moe", "test_spec_ngram", "test_pp_serving", "test_spec_batch", "test_named_scopes", "test_image_api",
+  "test_qkv_barrier", "test_hybrid_conv_moe", "test_hybrid_ssm_moe", "test_swa_nope_moe", "test_spec_ngram", "test_pp_serving", "test_spec_batch", "test_named_scopes", "test_image_api",
   "test_batched", "test_add_cell", "test_paged_pool_inplace", "test_ring_training", "test_sp_paged", "test_sp_serving",
   "test_experts_touched", "test_parallel", "test_hf_golden", "test_ssm_state_step", "test_kv_tier", "test_kv_quant",
 )  # fmt: skip

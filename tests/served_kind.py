@@ -445,7 +445,9 @@ def test_the_scheduler_serves_interleaved_requests_as_the_reference_does(kind, s
   assert not moved("prefix_cache_hit_pages_total") and not served.cached
   assert server.tier is None and not server.spec and not server._mixed_active() and not server.ops.mixed_tick_supported() and server.ops.prefill_donates_pool
   assert moved("recurrent_state_resets_total") == len(served.prompts) + 1
-  assert served.after.gauge_value("recurrent_state_bytes") == 2 * cfg.recurrent_layers * 4 * (cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state + (cfg.ssm_conv - 1) * cfg.ssm_conv_dim)
+  matrix = cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state if cfg.state_matrix else 0  # (a kind whose whole state is its convolution's tail: no ``ssm`` leaf weighs in)
+  assert served.after.gauge_value("recurrent_state_bytes") == 2 * cfg.recurrent_layers * 4 * (matrix + (cfg.ssm_conv - 1) * cfg.ssm_conv_dim)
+  assert re.search(rf"\(2 slots of {cfg.recurrent_layers * 4 * (matrix + (cfg.ssm_conv - 1) * cfg.ssm_conv_dim)} bytes", served.out)  # the start-up line and the gauge agree
   forms = {form: served.after.gauge_value("recurrent_state_step", labels={"form": form}) for form in ssm_ops.STATE_STEP_FORMS}
   assert forms == {form: int(form == kind.state_step_form) for form in ssm_ops.STATE_STEP_FORMS}  # a CPU: the XLA expression
   assert served.out.count("keep a recurrent state per slot") == 1 and "prefix reuse, the host KV tier, speculation and mixed ticks are off" in served.out

@@ -244,3 +244,50 @@ def test_blocks_of_one_sublayer_leave_no_op_unscoped_that_granites_programs_do_n
   (decode, prefill), (granite_decode, granite_prefill), (gated_decode, _) = programs(nemotron), programs(granite), programs(gated)
   assert decode <= granite_decode | gated_decode, sorted(decode - granite_decode - gated_decode)
   assert prefill <= granite_prefill | gated_decode, sorted(prefill - granite_prefill - gated_decode)
+
+
+def test_a_gated_short_convolution_files_its_norm_and_projections_under_ssm_proj_and_everything_else_under_ssm():
+  """lfm2_moe's decode step and prefill group (ISSUE 57). The kind writes under the scopes the other recurrent kinds
+  write under, so ``decode_ssm_proj_device_ms.closed`` and ``decode_ssm_device_ms.closed`` read it with no new reader:
+  ``xot.ssm_proj`` holds the operator norm and the two projections (W_in, W_out: its only matrix products beside the
+  FFN's), ``xot.ssm`` the two gates, the taps, and the tail's read and write (the decode step's dynamic_update_slice of
+  the ``conv`` leaf; the prefill's scatter at the group's slots). No primitive stands outside the ``xot.`` scopes that
+  does not in granite's programs or in a gated-expert model's."""
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import _gated_conv_decode_step, paged_decode_forward, prefill_into_pages_many
+
+  base = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, vocab_size=256, intermediate_size=96, max_position_embeddings=128, torch_dtype="float32")
+  granite = config_from_hf(dict(
+    base, model_type="granitemoehybrid", num_hidden_layers=3, layer_types=["mamba", "attention", "mamba"], mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4,
+    mamba_chunk_size=16, shared_intermediate_size=96, position_embedding_type="nope",
+  ))  # fmt: skip
+  lfm2 = config_from_hf(dict(
+    base, model_type="lfm2_moe", num_hidden_layers=4, layer_types=["conv", "conv", "full_attention", "conv"], conv_L_cache=3, num_dense_layers=1, num_experts=8, num_experts_per_tok=2,
+    moe_intermediate_size=32, norm_topk_prob=True, routed_scaling_factor=1, use_expert_bias=True, norm_eps=1e-5, rope_theta=1e6,
+  ))  # fmt: skip
+  assert lfm2.layer_types == ("conv", "conv", "attention", "conv") and not lfm2.state_matrix and [lfm2.layer_stack(i) for i in range(4)] == ["ssm_layers", "ssm_moe_layers", "moe_layers", "ssm_moe_layers"]
+
+  def programs(cfg):
+    params, shard = full_model_params(jax.random.PRNGKey(0), cfg)
+    B, mp = 2, 128 // PS
+    pool = init_paged_pool(cfg, cfg.n_layers, 1 + B * mp, PS, n_slots=B)
+    bt = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
+    decode = jax.make_jaxpr(lambda pool: paged_decode_forward(params, cfg, shard, jnp.ones((B, 1), jnp.int32), jnp.asarray([[3], [5]], jnp.int32), pool, bt, PS, False))(pool)
+    lens = jnp.asarray([20, 32], jnp.int32)
+    prefill = jax.make_jaxpr(lambda pool: prefill_into_pages_many.xot_jitted.__wrapped__(params, cfg, shard, jnp.ones((B, 32), jnp.int32), pool, bt, jnp.zeros((B,), jnp.int32), lens, PS, None, jnp.arange(B, dtype=jnp.int32)))(pool)
+    return _unscoped_primitives(decode.jaxpr), _unscoped_primitives(prefill.jaxpr)
+
+  gated = tiny_test_config(n_layers=2, max_seq_len=128, n_experts=4, n_active_experts=2, moe_hidden_dim=32, shared_expert_dim=32)
+  (decode, prefill), (granite_decode, granite_prefill), (gated_decode, _) = programs(lfm2), programs(granite), programs(gated)
+  assert decode <= granite_decode | gated_decode, sorted(decode - granite_decode - gated_decode)
+  assert prefill <= granite_prefill | gated_decode, sorted(prefill - granite_prefill - gated_decode)
+
+  # one conv layer's decode step, equation by equation: which scope each primitive of the operator stands under
+  params, _ = full_model_params(jax.random.PRNGKey(0), lfm2)
+  lp = {name: leaf[0] for name, leaf in params["ssm_layers"].items() if name in ("ssm_norm", "w_in", "conv_w", "w_out")}  # (no FFN leaf: the step ends with the operator)
+  pool = init_paged_pool(lfm2, lfm2.n_layers, 3, PS, n_slots=2)
+  eqns = jax.make_jaxpr(lambda h, pool: _gated_conv_decode_step(h, pool, lp, 1, jnp.asarray([True, False]), lfm2))(jnp.ones((2, 1, 64), jnp.float32), pool).jaxpr.eqns
+  under = lambda prim: {next((part for part in str(e.source_info.name_stack).split("/") if part.startswith("xot.")), "") for e in eqns if e.primitive.name == prim}  # noqa: E731
+  assert under("dot_general") == {"xot.ssm_proj"} and under("dynamic_update_slice") == {"xot.ssm"} and under("dynamic_slice") == {"xot.ssm"}
+  assert "xot.ssm" in under("mul") and not under("logistic") and not under("exp")  # gates and taps are products and sums: the operator has no activation
+  assert all(any(part.startswith("xot.") for part in str(e.source_info.name_stack).split("/")) for e in eqns), [e.primitive.name for e in eqns if "xot." not in str(e.source_info.name_stack)]

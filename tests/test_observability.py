@@ -1179,3 +1179,50 @@ def test_an_ungated_expert_stack_and_a_step_without_an_ffn_are_counted_as_they_a
   assert stub._expert_layers == 3
   snap = Metrics.merged([metrics.snapshot()])
   assert snap.gauge_value("moe_expert_gate", labels={"act": "relu2"}) == 3 and snap.gauge_value("moe_expert_gate", labels={"act": "silu"}) == 0 and snap.gauge_value("moe_router_input", labels={"at": "ffn"}) == 3
+
+
+def test_a_kind_with_no_state_matrix_is_named_by_the_gauge_weighed_by_its_tail_alone_and_logged_so(capsys, monkeypatch):
+  """ISSUE 57: a server of gated-short-convolution layers (``cfg.state_matrix`` false) sets ``recurrent_state_step`` to
+  the form "no_state_matrix" and every other form to 0, ``recurrent_state_bytes`` to the ``conv`` leaf's bytes — the
+  pool has no ``ssm`` leaf to weigh —, and its start-up line gives the same bytes, a slot and in all. A Mamba server
+  beside it still names its rule's form and weighs both leaves."""
+  import jax
+  import numpy as np
+
+  from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer
+  from xotorch_support_jetson_tpu.inference.jax_engine import JaxShardedInferenceEngine
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.config import config_from_hf
+  from xotorch_support_jetson_tpu.models.decoder import full_model_params
+  from xotorch_support_jetson_tpu.ops.ssm import STATE_STEP_FORMS
+  from xotorch_support_jetson_tpu.utils.metrics import metrics
+
+  base = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2, vocab_size=64, intermediate_size=32, torch_dtype="float32", max_position_embeddings=64)
+  lfm2 = config_from_hf(dict(
+    base, model_type="lfm2_moe", num_hidden_layers=3, layer_types=["conv", "full_attention", "conv"], conv_L_cache=3, num_dense_layers=1, num_experts=4, num_experts_per_tok=2, moe_intermediate_size=16,
+    norm_topk_prob=True, routed_scaling_factor=1, use_expert_bias=True, norm_eps=1e-5, rope_theta=1e6,
+  ))  # fmt: skip
+  granite = config_from_hf(dict(
+    base, model_type="granitemoehybrid", num_hidden_layers=2, layer_types=["mamba", "attention"], mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8, mamba_d_conv=4, mamba_chunk_size=16,
+    shared_intermediate_size=32, position_embedding_type="nope",
+  ))  # fmt: skip
+  monkeypatch.setenv("XOT_TPU_BATCH_SLOTS", "3")
+  monkeypatch.setenv("XOT_TPU_PAGE_SIZE", "16")
+  read = {}
+  for name, cfg in (("lfm2", lfm2), ("granite", granite)):
+    engine = JaxShardedInferenceEngine(use_local_mesh=False)
+    engine.load_test_model(Shard(name, 0, cfg.n_layers - 1, cfg.n_layers), cfg, full_model_params(jax.random.PRNGKey(0), cfg)[0])
+    server = BatchedServer(engine)
+    try:
+      asyncio.run(server.submit(f"{name}-1", np.asarray([3, 4, 5], np.int32), max_tokens=2, temp=0.0, top_k=35, eos_ids=(), emit=lambda *_: None))
+      snap = Metrics.merged([metrics.snapshot()])
+      read[name] = (set(server.cache), snap.gauge_value("recurrent_state_bytes"), {form: snap.gauge_value("recurrent_state_step", labels={"form": form}) for form in STATE_STEP_FORMS}, capsys.readouterr().out)
+    finally:
+      server.shutdown()
+  leaves, weighed, forms, out = read["lfm2"]
+  assert leaves == {"k", "v", "conv"} and weighed == 2 * 3 * 2 * 32 * 4 == 1536  # 2 conv layers x 3 slots x 2 rows x 32 channels, float32
+  assert forms == {form: int(form == "no_state_matrix") for form in STATE_STEP_FORMS} and STATE_STEP_FORMS[-1] == "no_state_matrix"
+  assert "2 of 3 layers keep a recurrent state per slot (3 slots of 512 bytes, 1536 in all, beside" in out
+  leaves, weighed, forms, out = read["granite"]
+  assert leaves == {"k", "v", "ssm", "conv"} and weighed == 3 * 4 * (4 * 16 * 8 + 3 * (64 + 2 * 8)) and forms == {form: int(form == "reference") for form in STATE_STEP_FORMS}
+  assert f"(3 slots of {int(weighed) // 3} bytes, {int(weighed)} in all, beside" in out

@@ -262,7 +262,7 @@ def test_the_delta_predicate_reads_the_leaf_and_what_the_program_was_told(what, 
   leaf = jax.ShapeDtypeStruct(shape, dtype)
   assert ssm.delta_one_pass_supported(leaf, use_kernel) is want, what
   assert ssm.state_step_form(leaf, use_kernel, kind) == ("delta_one_pass" if want else "delta_reference")
-  assert set(ssm.STATE_STEP_FORMS) == {"one_pass", "reference", "delta_one_pass", "delta_reference"}
+  assert set(ssm.STATE_STEP_FORMS) == {"one_pass", "reference", "delta_one_pass", "delta_reference", "no_state_matrix"} and ssm.state_step_form(None, use_kernel, kind) == "no_state_matrix"  # (a pool with no ``ssm`` leaf: ISSUE 57)
 
 
 def test_the_cells_tiles():

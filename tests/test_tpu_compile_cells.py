@@ -504,3 +504,61 @@ def test_hybrid_ssm_moe_decode_step_and_longest_prefill_at_the_cells_settings_fi
   mem = compiled.memory_analysis()
   print(f"prefill.pages_many_sampled nemotron K=8 S=2048: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_hybrid_conv_moe_decode_step_and_a_prefill_group_at_the_cells_settings_fit_v5e(chip, monkeypatch):
+  """LFM2-8B-A1B's first stage as ``lfm2-8b-a1b.decode-closed-128`` serves it (ISSUE 57): 128 slots — twice any other
+  cell's —, 2049 pages of 4 attention layers (8 KV heads of 64, 4 queries a KV head: granite's geometry), bf16, all 32
+  SwiGLU experts of 14 expert layers held: 10.80 GB of weights, 1.07 GB of pages (3073, every row at its longest
+  context, is refused by 460 MiB: the kernel form of a pool of 64-channel heads is three more copies of it, two of them
+  lane-padded), and 12.6 MB of state — the pool has a
+  ``conv`` leaf [12, 128, 2, 2048] and NO ``ssm`` leaf, so the decode program has no state-step call at all. Its Mosaic
+  calls are the gated experts' two (``moe_gate_up``, ``moe_down``: the shared walk, 17 rows an expert) in each run of
+  expert layers and the attention layers' two. No ``copy(`` of a stacked expert leaf's shape, and no layer cut out of
+  one, stands ahead of the Mosaic calls; the tail's update is a dynamic-update-slice of the leaf in place, and the leaf
+  is copied twice a dispatch (relaid in, relaid out), by no layer. The largest prefill program the cell meets, a group of 8 rows padded to 1024 tokens with the pool donated, fits
+  beside the weights."""
+  from xotorch_support_jetson_tpu.inference.paging import pages_to_cover
+  from xotorch_support_jetson_tpu.inference.shard import Shard
+  from xotorch_support_jetson_tpu.models.decoder import _fused_paged_batch_decode_impl, prefill_into_pages_many_sampled_inplace, served_expert_form
+  from xotorch_support_jetson_tpu.ops.paged import paged_kernel_supported
+  from xotorch_support_jetson_tpu.ops.ssm import state_step_form
+
+  _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, "lfm2-8b-a1b-d16")
+  assert paged_kernel_supported(cfg, "tpu") and served_expert_form(params, cfg) == "grouped" and not cfg.state_matrix and state_step_form(pool.get("ssm"), True, cfg.recurrent_kind) == "no_state_matrix"
+  assert set(pool) == {"k", "v", "conv"} and pool["k"].shape == (4, 2049, 8, PS, 64) and pool["conv"].shape == (12, 128, 2, 2048) and pool["conv"].dtype == jnp.bfloat16
+  n_slots = pool["conv"].shape[1]
+  assert params["ssm_moe_layers"]["w_experts_gate"].shape == (10, 32, 2048, 1792) and params["moe_layers"]["w_experts_down"].shape == (4, 32, 1792, 2048) and params["ssm_layers"]["w_up"].shape == (2, 2048, 7168) and "lm_head" not in params
+  shard, rows = Shard("lfm2", 0, cfg.n_layers - 1, cfg.n_layers), _rows(chip, n_slots)
+  window = _sds(chip, (n_slots, pages_to_cover(cfg.max_seq_len, PS)), jnp.int32)
+  compiled, text = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), pool, window, rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
+    _sds(chip, (2,), jnp.uint32), None,
+  )  # fmt: skip
+  calls = _mosaic_calls(text)
+  assert sorted(set(calls)) == ["kv_token_write", "moe_down", "moe_gate_up"], calls  # no ``ssm_state_step`` / ``delta_state_step`` among them: there is no state matrix to step
+  experts = r"bf16\[(10,|4,)?32,(2048,1792|1792,2048)\]"
+  assert {op for _, op in _takers(text, experts)} == {"custom-call"}  # the kernels alone take the stacks: no fusion cuts a layer out of one
+  tail = r"bf16\[(12,)?128,2,2048\]"
+  copied = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= ({tail}|{experts})\S* (copy|copy-start|transpose)\(", line)]
+  # No expert stack is copied. The tail leaf is relaid once on the way in and once on the way out of a DISPATCH (12.6 MB
+  # each way for eight steps: XLA:TPU keeps the leaf in another layout inside the loops than the one it is handed in) and
+  # by no layer and no step: PERF.md section 7, From PR 57.
+  assert len(copied) == 2 and all(re.search(rf"= {tail}", line) for line in copied) and "copy(%pool__conv__" in copied[0], copied
+  mem = compiled.memory_analysis()
+  print(f"decode.paged_batch lfm2 B=128: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.alias_size_in_bytes >= 12 * 128 * 2 * 2048 * 2 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+  K, S = 8, 1024
+  rows = _rows(chip, K)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash gate asks the backend
+  compiled, text = _compile(
+    prefill_into_pages_many_sampled_inplace, params, cfg, shard, _sds(chip, (K, S), jnp.int32), pool, _sds(chip, (K, 16), jnp.int32), rows(jnp.int32), rows(jnp.int32), PS,
+    rows(jnp.float32), rows(jnp.int32), _sds(chip, (2,), jnp.uint32), 64, None, rows(jnp.int32),
+  )  # fmt: skip
+  moved = [line.strip()[:160] for line in text.splitlines() if re.search(rf"= {experts}\S* (copy|copy-start|transpose|fusion|dynamic-slice)\(", line)]
+  assert not moved, moved
+  assert {name for name in _mosaic_calls(text) if name.startswith("moe_")} == {"moe_gate_up_rows", "moe_down_rows"} and "flash_attention_prefill" in text, _mosaic_calls(text)  # 8192 tokens x 4 / 32: 1024 rows an expert, the aligned walk
+  mem = compiled.memory_analysis()
+  print(f"prefill.pages_many_sampled lfm2 K=8 S=1024: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -962,9 +962,10 @@ class BatchedServer:
       from ..ops.paged import paged_kernel_supported, state_leaves
       from ..ops.ssm import STATE_STEP_FORMS, state_step_form
 
-      metrics.set_gauge("recurrent_state_bytes", sum(leaf.size * leaf.dtype.itemsize for leaf in state_leaves(self.cache).values()))
+      state_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in state_leaves(self.cache).values())
+      metrics.set_gauge("recurrent_state_bytes", state_bytes)
       if recurrent:  # which form the decode programs of this pool step the state in: what they will observe, asked once
-        form = state_step_form(self.cache["ssm"], paged_kernel_supported(eng.cfg), eng.cfg.recurrent_kind)
+        form = state_step_form(self.cache.get("ssm"), paged_kernel_supported(eng.cfg), eng.cfg.recurrent_kind)
         for name in STATE_STEP_FORMS:
           metrics.set_gauge("recurrent_state_step", int(name == form), labels={"form": name})
       self._note_expert_form()
@@ -984,7 +985,7 @@ class BatchedServer:
         self.tier = None
         print(
           f"[batch_scheduler] {eng.cfg.recurrent_layers} of {eng.cfg.n_layers} layers keep a recurrent state per slot "
-          f"({self.n_slots} slots beside {n_pages - 1} pages of the {eng.cfg.n_attn_layers} attention layers): "
+          f"({self.n_slots} slots of {state_bytes // self.n_slots} bytes, {state_bytes} in all, beside {n_pages - 1} pages of the {eng.cfg.n_attn_layers} attention layers): "
           "prefix reuse, the host KV tier, speculation and mixed ticks are off; a preempted row resumes by recomputing"
         )
       elif self.tier is None and kv_tier_enabled():

@@ -128,6 +128,7 @@ class ModelConfig:
   shared_expert_gate: bool = False  # qwen2-moe: sigmoid gate on the shared expert
   first_k_dense: int = 0
   router_scoring: str = "softmax"  # "softmax" | "sigmoid" (deepseek-v3)
+  router_selection_bias: bool = True  # a sigmoid router's stacks hold the leaf ``router_bias``, added for the CHOICE only; False (lfm2_moe ``use_expert_bias`` false): no such leaf
   norm_topk_prob: bool = False
   routed_scaling_factor: float = 1.0
   moe_capacity_factor: float | None = None  # None ⇒ exact compute (no token drops)
@@ -193,7 +194,7 @@ class ModelConfig:
   # the page pool instead of K/V pages; a model's recurrent layers are all of
   # one kind, each with ``ssm_heads`` heads, a causal depthwise convolution of
   # ``ssm_conv`` taps and a chunked prefill of ``ssm_chunk`` positions
-  # (models/decoder.py). Three kinds, two update rules (ops/ssm.py):
+  # (models/decoder.py). Four kinds; three keep a state matrix (``state_matrix``), stepped by two update rules (ops/ssm.py):
   # - "mamba" (granitemoehybrid, nemotron_h): a Mamba-2 mixer; a head's state
   #   is [``ssm_head_dim`` channels x ``ssm_state``], decayed by one scalar;
   #   B and C come in ``ssm_groups`` groups (head h reads group
@@ -211,6 +212,10 @@ class ModelConfig:
   #   lower bound, beta in (0, ``gdn_beta_scale``). Its chunked prefill takes
   #   the pairwise decays exp(G_t - G_s) as they are, never above 1, so its
   #   ``ssm_chunk`` (64, the published chunk) needs no rule.
+  # - "conv" (lfm2_moe): a gated short convolution, out = W_out(C ⊙ conv(B ⊙ x)) with [B | C | x] = u W_in over
+  #   ``ssm_conv_dim`` = dim channels, ``ssm_conv`` taps, no bias and no activation. NO state matrix: all a slot keeps
+  #   is the convolution's tail, ``ssm_conv - 1`` rows of the gated product B ⊙ x (``ssm_heads`` / ``ssm_head_dim`` /
+  #   ``ssm_state`` stay 0, the pool has no ``ssm`` leaf, and there is no scan, so no ``ssm_chunk``).
   # The stacked parameters are named by (mixer, FFN)
   # pairing (``layer_stack``): ``layers`` / ``moe_layers`` the attention layers
   # with a dense / an expert FFN, ``ssm_layers`` / ``ssm_moe_layers`` the
@@ -302,6 +307,14 @@ class ModelConfig:
     return next((t for t in self.layer_types if t in RECURRENT_KINDS), "")
 
   @property
+  def state_matrix(self) -> bool:
+    """Whether the recurrent layers keep a per-slot state MATRIX (the pool's float32 ``ssm`` leaf) beside their
+    convolution's tail (``conv``): every kind but "conv", whose whole state is the tail. The one owner of that fact —
+    the pool (ops/paged.py ``init_paged_pool``), the layer loops (models/decoder.py) and the gauge (ops/ssm.py
+    ``state_step_form``) read it; nobody compares kinds."""
+    return self.recurrent_kind in STATE_MATRIX_KINDS
+
+  @property
   def n_held_experts(self) -> int:
     """Experts whose weights this shard holds: the expert axis of the expert leaves."""
     return self.experts_held[1] - self.experts_held[0] if self.experts_held else self.n_experts
@@ -347,7 +360,10 @@ class ModelConfig:
 
   @property
   def ssm_conv_dim(self) -> int:
-    """Channels the convolution runs over: x and every group's B and C ("mamba"); every head's q, k and v ("kda", "gdn")."""
+    """Channels the convolution runs over: x and every group's B and C ("mamba"); every head's q, k and v ("kda", "gdn");
+    the gated product B ⊙ x, as wide as the stream ("conv")."""
+    if not self.state_matrix:
+      return self.dim
     if self.recurrent_kind in ("kda", "gdn"):
       return self.ssm_heads * (2 * self.ssm_state + self.ssm_head_dim)
     return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
@@ -394,13 +410,14 @@ class ModelConfig:
     return replace(self, n_layers=n_layers)
 
 
-RECURRENT_KINDS = ("mamba", "kda", "gdn")  # the ``layer_types`` whose layers keep a per-slot state (``ModelConfig.recurrent_layers``)
+STATE_MATRIX_KINDS = ("mamba", "kda", "gdn")  # the recurrent kinds that keep a state matrix a slot (``ModelConfig.state_matrix``)
+RECURRENT_KINDS = (*STATE_MATRIX_KINDS, "conv")  # the ``layer_types`` whose layers keep a per-slot state (``ModelConfig.recurrent_layers``)
 
 # HF ``model_type`` (or, with its underscores dropped, the ``architectures`` entry) -> family; first match wins, so
 # a longer name stands before the one it contains. The one list of what ``config_from_hf`` knows.
 MODEL_FAMILIES = {
   "qwen3_moe": "qwen3-moe", "qwen3": "qwen3", "qwen2_moe": "qwen2-moe", "qwen2": "qwen2", "mixtral": "mixtral", "mistral": "mistral", "phi3": "phi3",
-  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "smallthinker": "smallthinker", "nemotron_h": "nemotron-h", "llama": "llama",
+  "deepseek_v3": "deepseek-v3", "deepseek_v2": "deepseek-v2", "gemma2": "gemma2", "granitemoehybrid": "granite-hybrid", "bailing_hybrid": "bailing-hybrid", "olmo_hybrid": "olmo-hybrid", "laguna": "laguna", "smallthinker": "smallthinker", "nemotron_h": "nemotron-h", "lfm2_moe": "lfm2-moe", "llama": "llama",
 }
 
 
@@ -522,7 +539,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     # deepseek group-limited routing: v3 is always sigmoid + top-2-sum group
     # scores (HF DeepseekV3TopkRouter); v2 keys it on topk_method.
     # (laguna's row names no score function: deepseek-v3's router, whose 256 / top-8 / 2.5 its keys repeat, is assumed)
-    scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family in ("deepseek-v3", "laguna", "nemotron-h")) else "softmax"
+    # (lfm2_moe's row names none either: the family's router is sigmoid scores under ``use_expert_bias``)
+    scoring = "sigmoid" if (hf.get("scoring_func") == "sigmoid" or family in ("deepseek-v3", "laguna", "nemotron-h", "lfm2-moe")) else "softmax"
     if family == "deepseek-v3" or hf.get("topk_method") == "noaux_tc":
       group_mode = "top2sum"
     elif hf.get("topk_method") == "group_limited_greedy":
@@ -544,7 +562,7 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
       moe_hidden_dim=moe_hidden,
       shared_expert_dim=shared_dim,
       shared_expert_gate=family == "qwen2-moe",
-      first_k_dense=_leading_dense(hf) if "mlp_layer_types" in hf else int(hf.get("first_k_dense_replace", 0)),
+      first_k_dense=_leading_dense(hf) if "mlp_layer_types" in hf else int(hf.get("first_k_dense_replace", hf.get("num_dense_layers", 0))),  # (lfm2_moe's name for it)
       router_scoring=scoring,
       norm_topk_prob=bool(hf.get("norm_topk_prob", family in ("mixtral", "laguna", "smallthinker"))),
       routed_scaling_factor=float(hf.get("routed_scaling_factor", hf.get("moe_routed_scaling_factor", 1.0))),
@@ -591,6 +609,8 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     hybrid = _smallthinker_fields(hf)
   if family == "nemotron-h":
     hybrid = _nemotron_h_fields(hf)
+  if family == "lfm2-moe":
+    hybrid = _lfm2_moe_fields(hf)
 
   n_heads = int(hf["num_attention_heads"])
   hybrid.setdefault("n_layers", int(hf["num_hidden_layers"]))  # (nemotron_h counts its layer STEPS: two published blocks can be one)
@@ -602,14 +622,14 @@ def config_from_hf(hf: dict, dtype=None) -> ModelConfig:
     # (smallthinker: every layer an expert layer, no dense FFN width at all)
     hidden_dim=int(hf.get("shared_intermediate_size") or hf["intermediate_size"]) if family == "granite-hybrid" else int(hf.get("intermediate_size") or 0) if family == "smallthinker" else int(hf["intermediate_size"]),
     head_dim=int(hf.get("head_dim") or 0),
-    norm_eps=float(hf.get("layer_norm_epsilon", hf.get("norm_eps", 1e-5)) if family == "nemotron-h" else hf.get("rms_norm_eps", 1e-5)),
+    norm_eps=float(hf.get("layer_norm_epsilon", hf.get("norm_eps", 1e-5)) if family in ("nemotron-h", "lfm2-moe") else hf.get("rms_norm_eps", 1e-5)),  # (lfm2_moe: ``norm_eps`` alone)
     rope_theta=float(hf.get("rope_theta") or 10000.0),
     rope_scaling=rope_scaling,
     max_seq_len=int(hf.get("max_position_embeddings", 8192)),
     qkv_bias=family in ("qwen2", "qwen2-moe") or bool(hf.get("attention_bias", False)),
-    qk_norm=family in ("qwen3", "qwen3-moe", "olmo-hybrid", "laguna"),  # (laguna: assumed; its row has no key for or against)
+    qk_norm=family in ("qwen3", "qwen3-moe", "olmo-hybrid", "laguna", "lfm2-moe"),  # (laguna: assumed; its row has no key for or against)
     partial_rotary_factor=float(hf.get("partial_rotary_factor", 1.0)),
-    tied_embedding=bool(hf.get("tie_word_embeddings", family in ("gemma2", "granite-hybrid") or (family == "qwen2" and int(hf["hidden_size"]) < 2048))),
+    tied_embedding=bool(hf.get("tie_word_embeddings", family in ("gemma2", "granite-hybrid", "lfm2-moe") or (family == "qwen2" and int(hf["hidden_size"]) < 2048))),
     family=family,
     dtype=dtype or dtype_map.get(torch_dtype, jnp.bfloat16),
     eos_token_ids=tuple(int(e) for e in eos),
@@ -874,6 +894,31 @@ def _nemotron_h_fields(hf: dict) -> dict:
     expert_act="relu2",
     mlp_act="relu2",
     use_rope=False,  # the family's attention applies no position term: the Mamba layers carry position (``rope_theta`` stands unread)
+  )
+
+
+def _lfm2_moe_fields(hf: dict) -> dict:
+  """``lfm2_moe`` (LFM2-8B-A1B) → the hybrid fields of ModelConfig: ``layer_types`` names each layer "conv" (a gated short
+  convolution of ``conv_L_cache`` taps over ``hidden_size`` channels, kind "conv": no state matrix, ``ssm_heads`` /
+  ``ssm_head_dim`` / ``ssm_state`` 0) or "full_attention" (GQA with an RMSNorm over each head's q and k before rope);
+  ``num_dense_layers`` leading layers have a dense SwiGLU of ``intermediate_size``, the rest ``num_experts`` experts of
+  ``moe_intermediate_size`` under a sigmoid router whose choice adds ``expert_bias`` (``use_expert_bias``; false: no
+  bias leaf); the norms' epsilon is ``norm_eps`` and the head is tied (``config_from_hf`` reads both by family). What
+  the decoder does not implement is refused here, by name."""
+  n_layers = int(hf["num_hidden_layers"])
+  names = {"conv": "conv", "full_attention": "attention"}
+  layer_types = tuple(hf.get("layer_types") or ())
+  if len(layer_types) != n_layers or set(layer_types) - set(names):
+    raise ValueError(f"lfm2_moe: layer_types must name num_hidden_layers = {n_layers} layers, each 'conv' or 'full_attention'; got {layer_types}")
+  on = [key for key in ("conv_bias", "attention_bias", "rope_scaling") if hf.get(key)]
+  if on:
+    raise ValueError(f"lfm2_moe: {', '.join(on)} is not supported")
+  if int(hf.get("conv_L_cache") or 0) < 2:
+    raise ValueError("lfm2_moe: conv_L_cache (the short convolution's taps) must be 2 or more")
+  return dict(
+    layer_types=tuple(names[t] for t in layer_types),
+    ssm_conv=int(hf["conv_L_cache"]),
+    router_selection_bias=bool(hf.get("use_expert_bias", True)),
   )
 
 

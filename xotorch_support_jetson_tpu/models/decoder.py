@@ -206,6 +206,8 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
   A "gdn" stack (Gated DeltaNet: H heads, N key and P value channels a head, N != P as published):
     w_qkv [L, D, H*(2N+P)]   conv_w [L, K, H*(2N+P)]   w_ab [L, D, 2H] (the decay's step a | β's b, one a head)
     A_log, dt_bias [L, H] f32   w_z [L, D, H*P] (the output gate)   o_norm [L, P]   w_out [L, H*P, D]
+  A "conv" stack (lfm2_moe's gated short convolution, K taps over D channels; no state matrix):
+    ssm_norm [L, D]   w_in [L, D, 3D] (B | C | x)   conv_w [L, K, D] (no bias)   w_out [L, D, D]
   Without ``cfg.pre_norms`` no stack has ``attn_norm`` / ``ssm_norm`` / ``mlp_norm``; with ``cfg.post_norms`` each has
   ``post_attn_norm`` (``post_ssm_norm`` in a recurrent stack) and ``post_mlp_norm`` [L, D].
   """
@@ -282,7 +284,7 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
     else:  # an ungated expert is two matrices, both stored [Fm, D] (ops/moe.py: the inner width never along the lanes); no ``w_shared_gate`` either
       moe["w_experts_up_t"] = w(next(keys), Lm, Eh, Fm, D, scale=D**-0.5)
     moe["w_experts_down"] = w(next(keys), Lm, Eh, Fm, D)
-    if cfg.router_scoring == "sigmoid":
+    if cfg.router_scoring == "sigmoid" and cfg.router_selection_bias:
       moe["router_bias"] = jnp.zeros((Lm, E), dtype=jnp.float32)
     if Fs:
       if cfg.ffn_gated:
@@ -339,6 +341,14 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       "w_out": w(next(keys), Ls, H * P, D),
     }
 
+  def conv_leaves(Ls):
+    return {
+      "ssm_norm": jnp.ones((Ls, D), dtype=dtype),
+      "w_in": w(next(keys), Ls, D, 3 * D),
+      "conv_w": w(next(keys), Ls, cfg.ssm_conv, D, scale=cfg.ssm_conv**-0.5),
+      "w_out": w(next(keys), Ls, D, D),
+    }
+
   def block_norms(stack: Params, n: int, mixer: str, ffn: bool = True) -> Params:
     """A hybrid stack's mixer leaves with the block's norms: those ahead of its two sublayers dropped without
     ``cfg.pre_norms``, those after them added with ``cfg.post_norms``; a step with no FFN (``ffn`` false) has the
@@ -356,7 +366,7 @@ def init_shard_params(key: jax.Array, cfg: ModelConfig, shard: Shard, dtype=None
       raise ValueError("a configuration whose layers differ in kind (recurrent layers, attention kinds of different shapes) is built whole: its stacks do not split by a layer range")
     keys = iter(jax.random.split(next(keys), 96))  # up to four stacks of up to 17 drawn leaves
     names = [cfg.layer_stack(i) for i in range(cfg.n_layers)]
-    recurrent_leaves = {"mamba": mamba_leaves, "kda": kda_leaves, "gdn": gdn_leaves}.get(cfg.recurrent_kind)
+    recurrent_leaves = {"mamba": mamba_leaves, "kda": kda_leaves, "gdn": gdn_leaves, "conv": conv_leaves}.get(cfg.recurrent_kind)
     for name in dict.fromkeys(names):  # in the order the model meets them
       n, recurrent, ffn = names.count(name), name.startswith("ssm_"), cfg.ffn_kind(names.index(name))
       kind = cfg.layer_attn[names.index(name)] if cfg.layer_attn else None  # (None at a recurrent layer too)
@@ -705,16 +715,17 @@ def _ssm_out(h, y, p, cfg: ModelConfig):
   return _residual(h, out, cfg)
 
 
-def _ssm_conv(xbc, conv0, p):
-  """The causal depthwise convolution as ``K`` shifted adds, then silu. xbc
+def _ssm_conv(xbc, conv0, p, act=jax.nn.silu):
+  """The causal depthwise convolution as ``K`` shifted adds, then ``act``
+  (None: none — the gated short convolution's) over the float32 sum. xbc
   [B,S,C]; conv0 [B,K-1,C] the rows before it (zeros at a prompt's start).
   Returns (activated [B,S,C], the padded input [B, K-1+S, C])."""
   K, S = p["conv_w"].shape[0], xbc.shape[1]
   xp = jnp.concatenate([conv0.astype(xbc.dtype), xbc], axis=1)
-  acc = p["conv_b"].astype(jnp.float32) if "conv_b" in p else 0.0  # (a "kda" layer's convolution has no bias)
+  acc = p["conv_b"].astype(jnp.float32) if "conv_b" in p else 0.0  # (a "kda", "gdn" or "conv" layer's convolution has no bias)
   for j in range(K):
     acc = acc + xp[:, j : j + S].astype(jnp.float32) * p["conv_w"][j].astype(jnp.float32)
-  return jax.nn.silu(acc).astype(xbc.dtype), xp
+  return (acc if act is None else act(acc)).astype(xbc.dtype), xp
 
 
 def _conv_tail(xp, S: int, seq_lens):
@@ -1113,6 +1124,66 @@ def _gdn_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bo
   return h, pool, visited
 
 
+# ------------------------------------------- gated short convolution mixer
+# (lfm2_moe's "conv" layers; HF ``Lfm2MoeShortConv``.) [B | C | x] = u W_in with u = rmsnorm(h), each as wide as the
+# stream; g = B ⊙ x; c_t = Σ_j w_j ⊙ g_{t-(K-1)+j}, a causal depthwise convolution of ``ssm_conv`` taps with no bias
+# and NO activation; out = (C ⊙ c) W_out. Gated on both sides of the taps — which is why what a row keeps between
+# calls is the last ``ssm_conv - 1`` rows of the gated product g, never of x: the pool's ``conv`` leaf
+# [Ls, slots, K-1, D] in the model dtype, and nothing else (``cfg.state_matrix`` false: no ``ssm`` leaf, no scan, no
+# chunk). The gates' products and the tap sum are float32; g is rounded to the model dtype where it joins the tail, so
+# that a decode step reads the rows a prefill would have handed it.
+
+
+@component_scope("xot.ssm_proj")
+def _gated_conv_in(h, p, cfg: ModelConfig):
+  """Norm and input projection: h [B,S,D] → B, C, x, each [B,S,D]."""
+  bcx = _mm(rms_norm(h, p["ssm_norm"], cfg.norm_eps), p, "w_in", cfg.quant_compute)
+  return jnp.split(bcx, 3, axis=-1)
+
+
+def _gated_conv(b, c, x, conv0, p):
+  """C ⊙ conv(B ⊙ x) over a sequence from the tail ``conv0`` [B,K-1,D] → (y [B,S,D], the padded gated product
+  [B, K-1+S, D], whose last rows are the next tail)."""
+  g = (b.astype(jnp.float32) * x.astype(jnp.float32)).astype(x.dtype)
+  taps, gp = _ssm_conv(g, conv0, p, act=None)
+  return (c.astype(jnp.float32) * taps.astype(jnp.float32)).astype(x.dtype), gp
+
+
+def _gated_conv_layer(h, p, cfg: ModelConfig, ssm0, conv0, seq_lens=None):
+  """One gated-short-convolution layer step over a sequence, in the recurrent kinds' one call shape: h [B,S,D], no
+  state matrix (``ssm0`` None, and None comes back) and conv0 [B,K-1,D] → (h, None, conv) after each row's
+  ``seq_lens`` tokens (None: all S). The tail is cut at the length, so padding moves nothing; a prompt shorter than
+  the tail keeps the newest of the old rows ahead of its own."""
+  b, c, x = _gated_conv_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    y, gp = _gated_conv(b, c, x, conv0, p)
+    conv = _conv_tail(gp, h.shape[1], seq_lens)
+  h, *_ = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, ssm0, conv.astype(conv0.dtype)
+
+
+def _gated_conv_decode_step(h, pool, p, layer, active, cfg: ModelConfig, use_kernel: bool = False):
+  """One token of one gated-short-convolution layer for every slot row: the pool's ``conv`` leaf [Ls,B,K-1,D] is read
+  and written in place at ``layer`` — 8 KB a row at the published widths —, an inactive row's tail bit for bit
+  (``_step_conv``). Returns (h, pool, the experts its FFN visited)."""
+  b, c, x = _gated_conv_in(h, p, cfg)
+  with jax.named_scope("xot.ssm"):
+    conv0 = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)
+    y, gp = _gated_conv(b, c, x, conv0, p)
+    pool = _step_conv(pool, gp, conv0, layer, active)
+  h, _, visited = _mlp_block(_ssm_out(h, y, p, cfg), p, cfg)
+  return h, pool, visited
+
+
+# A recurrent kind's two layer functions, (over a sequence, one decode step): the ONE place a kind is looked up
+# (``cfg.recurrent_kind``), by ``_hybrid_layers`` and ``paged_decode_forward``.
+_RECURRENT_LAYER = {
+  "mamba": (_ssm_layer, _ssm_decode_step),
+  "kda": (_kda_layer, _kda_decode_step),
+  "gdn": (_gdn_layer, _gdn_decode_step),
+  "conv": (_gated_conv_layer, _gated_conv_decode_step),
+}
+
 # A hybrid's latent-attention layers take a prefill's queries this many positions at a time (ops/attention.py
 # mla_absorbed_attention ``q_block``): its pool is donated with per-slot state beside the weights, and the float32 scores
 # of a whole group against the gathered window do not fit there (AOT, tests/test_tpu_compile.py; PERF.md §6, PR 36).
@@ -1140,7 +1211,8 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
   ``carry`` rides the layer loop as the page pool does in decode
   (``_scan_layers_over_pool``). Prefill: the rows' gathered K/V windows
   [La, K, S_tot, Hkv, hd] under the pool's page-leaf names, plus the POOL's
-  own state leaves ``ssm`` / ``conv``, read and written at (layer,
+  own state leaves ``ssm`` (where the kind keeps a state matrix: the
+  pool has the leaf) / ``conv``, read and written at (layer,
   ``slot_rows``) — a row whose ``fresh`` flag is set starts from zeros, so a
   slot's last tenant is never seen; a padding row names a slot past the last
   and its write is dropped. Cache-less: ``carry`` is empty, every row starts
@@ -1157,17 +1229,18 @@ def _hybrid_layers(h, params: Params, cfg: ModelConfig, positions, carry: Params
       kv = {name: jax.lax.dynamic_index_in_dim(carry[name], layer, 0, keepdims=False) for name in pages} or None
       h, kv, _ = _layer_step(h, lp, kv, positions, kv_positions, inv_freq, cfg, bool(pages), adapter_ids=adapter_ids, mla_q_block=_HYBRID_MLA_Q_BLOCK)
       return h, {**carry, **{name: jax.lax.dynamic_update_index_in_dim(carry[name], kv[name], layer, 0) for name in pages}}
-    over_sequence = _kda_layer if "w_f" in lp else _gdn_layer if "w_ab" in lp else _ssm_layer
-    if "ssm" not in carry:
-      ssm0 = jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+    over_sequence = _RECURRENT_LAYER[cfg.recurrent_kind][0]
+    if "conv" not in carry:
+      ssm0 = jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32) if cfg.state_matrix else None
       h, _, _ = over_sequence(h, lp, cfg, ssm0, jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_conv_dim), h.dtype), seq_lens)
       return h, carry
-    with jax.named_scope("xot.ssm"):
-      ssm0 = jnp.where(fresh[:, None, None, None], 0.0, _state_rows(carry["ssm"], layer, slot_rows)).astype(jnp.float32)
+    with jax.named_scope("xot.ssm"):  # (a kind with no state matrix: the pool has no ``ssm`` leaf, and the tail is all there is to read and write)
+      ssm0 = jnp.where(fresh[:, None, None, None], 0.0, _state_rows(carry["ssm"], layer, slot_rows)).astype(jnp.float32) if "ssm" in carry else None
       conv0 = jnp.where(fresh[:, None, None], 0, carry["conv"].at[layer, slot_rows].get(mode="clip"))
     h, ssm, conv = over_sequence(h, lp, cfg, ssm0, conv0, seq_lens)
     with jax.named_scope("xot.ssm"):
-      carry = {**carry, "ssm": carry["ssm"].at[layer, slot_rows].set(ssm.astype(carry["ssm"].dtype), mode="drop"), "conv": carry["conv"].at[layer, slot_rows].set(conv, mode="drop")}
+      stepped = {"ssm": carry["ssm"].at[layer, slot_rows].set(ssm.astype(carry["ssm"].dtype), mode="drop")} if "ssm" in carry else {}
+      carry = {**carry, **stepped, "conv": carry["conv"].at[layer, slot_rows].set(conv, mode="drop")}
     return h, carry
 
   # (a cache-less forward may be differentiated — training — and the experts' kernels have no derivative: it hands no
@@ -2149,8 +2222,8 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
 
   tokens [B, 1] int32 → (logits [B, 1, V], updated pool, experts visited). Full shard only
   (the batched server is single-node). The pool comes back in the form it
-  came in (``_paged_layer_step``). A hybrid's state-space layers step their
-  per-slot state leaves of the pool instead (``_ssm_decode_step``), for the
+  came in (``_paged_layer_step``). A hybrid's recurrent layers step their
+  per-slot state leaves of the pool instead (``_RECURRENT_LAYER``), for the
   rows ``active`` [B] names (None: all). The third result is how many
   distinct held experts the rows chose, summed over the expert layers (int32;
   what the grouped form of ops/moe.py visits; 0 without experts)."""
@@ -2161,12 +2234,8 @@ def paged_decode_forward(params, cfg: ModelConfig, shard: Shard, tokens, positio
 
   def step(carry, pool, lp, layer):
     h, seen = carry
-    if "w_xbc" in lp:
-      h, pool, visited = _ssm_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
-    elif "w_f" in lp:
-      h, pool, visited = _kda_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
-    elif "w_ab" in lp:
-      h, pool, visited = _gdn_decode_step(h, pool, lp, layer, active, cfg, use_kernel)
+    if "w_out" in lp:  # a recurrent layer (an attention layer's output projection is ``wo``): ``layer`` counts the pool's state layers
+      h, pool, visited = _RECURRENT_LAYER[cfg.recurrent_kind][1](h, pool, lp, layer, active, cfg, use_kernel)
     else:
       h, pool, visited = _paged_layer_step(h, pool, lp, layer, block_tables, positions, inv_freq, cfg, page_size, use_kernel, adapter_ids, kv_quant)
     return (h, seen + visited), pool

@@ -180,7 +180,7 @@ def _weight_files_for_shard(model_dir: Path, shard: Shard) -> list[Path]:
   return [model_dir / f for f in sorted(needed)]
 
 
-_NO_NAME_MAP = {"bailing-hybrid": "bailing_hybrid (Ling-3.0)", "olmo-hybrid": "olmo_hybrid (Olmo-Hybrid)", "laguna": "laguna (Laguna-XS.2)", "smallthinker": "smallthinker (SmallThinker-21BA3B)", "nemotron-h": "nemotron_h (Nemotron-3-Nano)"}  # family -> the model_type refused by name
+_NO_NAME_MAP = {"bailing-hybrid": "bailing_hybrid (Ling-3.0)", "olmo-hybrid": "olmo_hybrid (Olmo-Hybrid)", "laguna": "laguna (Laguna-XS.2)", "smallthinker": "smallthinker (SmallThinker-21BA3B)", "nemotron-h": "nemotron_h (Nemotron-3-Nano)", "lfm2-moe": "lfm2_moe (LFM2-8B-A1B)"}  # family -> the model_type refused by name
 
 
 def load_shard_weights(model_dir: str | Path, cfg: ModelConfig, shard: Shard) -> Params:

@@ -89,6 +89,9 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   ``ssm`` leaf is stepped in place at (layer) by ``ops/ssm.py
   ssm_state_step`` — float32 in both of its forms, the XLA expression and
   the one-pass Mosaic kernel — and ``conv`` by ``_ssm_decode_step`` itself.
+  A kind with no state matrix (``cfg.state_matrix`` false: the gated short
+  convolution, whose whole state is its tail) gets the ``conv`` leaf alone,
+  [Ls, n_slots, K-1, dim]: no ``ssm`` leaf, of any size.
   """
   from ..models.decoder import kv_quant_mode
 
@@ -96,10 +99,8 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   state = {}
   if cfg.recurrent_layers:
     n_shard_layers, Ls = cfg.n_attn_layers, cfg.recurrent_layers
-    state = {
-      "ssm": jnp.zeros((Ls, n_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
-      "conv": jnp.zeros((Ls, n_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype),
-    }
+    state = {"ssm": jnp.zeros((Ls, n_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)} if cfg.state_matrix else {}
+    state["conv"] = jnp.zeros((Ls, n_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
   mode = kv_quant_mode(cfg, quant)
   kd, vd = cfg.cache_k_dim, cfg.cache_v_dim
   if mode == "int4":

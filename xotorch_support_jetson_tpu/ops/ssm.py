@@ -1,4 +1,7 @@
-"""The decode step of a recurrent layer's per-slot state: one owner, two update rules, three kinds of layer.
+"""The decode step of a recurrent layer's per-slot state MATRIX: one owner, two update rules, three kinds of layer
+("mamba", "kda", "gdn": ``models/config.py STATE_MATRIX_KINDS``). The fourth recurrent kind, "conv" (a gated short
+convolution), keeps no matrix — its pool has no ``ssm`` leaf and nothing here is called for it; ``state_step_form``
+names that too, so the gauge ``recurrent_state_step`` says so.
 
 A hybrid's page pool carries, beside its K/V pages, the leaf ``ssm``
 [Ls, slots, H, P, N] in float32 (``ops/paged.py init_paged_pool``): each slot
@@ -112,12 +115,15 @@ def delta_one_pass_supported(ssm_leaf, use_kernel: bool) -> bool:
   return bool(use_kernel) and ssm_leaf.ndim == 5 and ssm_leaf.dtype == jnp.float32 and _delta_tile(*ssm_leaf.shape[2:]) is not None
 
 
-STATE_STEP_FORMS = ("one_pass", "reference", "delta_one_pass", "delta_reference")
+STATE_STEP_FORMS = ("one_pass", "reference", "delta_one_pass", "delta_reference", "no_state_matrix")
 
 
 def state_step_form(ssm_leaf, use_kernel: bool, kind: str = "mamba") -> str:
   """The name of the rule and form a decode program of ``kind`` layers ("mamba" | "kda" | "gdn") steps this leaf in: the
-  label of the gauge ``recurrent_state_step``."""
+  label of the gauge ``recurrent_state_step``. ``ssm_leaf`` None — a pool with no such leaf (``cfg.state_matrix``
+  false) — is "no_state_matrix": the decode step moves the convolution's tail and nothing else."""
+  if ssm_leaf is None:
+    return "no_state_matrix"
   if kind in ("kda", "gdn"):  # the delta rule, whatever the decay's and the face's shape
     return "delta_one_pass" if delta_one_pass_supported(ssm_leaf, use_kernel) else "delta_reference"
   return "one_pass" if one_pass_supported(ssm_leaf, use_kernel) else "reference"
