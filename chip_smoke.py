@@ -305,14 +305,16 @@ def kernels_child(rehearsal: bool) -> None:
       return fn(*args, **kwargs)
 
   atol = 1e-4 if interpret else 3e-2  # bf16 operands on the chip
-  for quant, pools in (("", (k.astype(dt), v.astype(dt))), ("int8", quantize_kv(k) + quantize_kv(v)), ("int4", quantize_kv_int4(k) + quantize_kv_int4(v))):
-    if quant:
+  pairs = lambda x: jnp.concatenate([x[:, 0::2], x[:, 1::2]], axis=-1)  # noqa: E731 — [P, Hkv/2, ps, 128]: how the served pool stores float heads of 64 (ops/paged.py, the module note)
+  for quant, pools in (("", (k.astype(dt), v.astype(dt))), ("bf16-pairs", (k.astype(dt), v.astype(dt))), ("int8", quantize_kv(k) + quantize_kv(v)), ("int4", quantize_kv_int4(k) + quantize_kv_int4(v))):
+    if quant in ("int8", "int4"):
       kc, ks, vc, vs = pools
       scales = {"k_scale_pool": ks, "v_scale_pool": vs}
     else:
       (kc, vc), scales = pools, {}
     tile = min(PAGE_TILE, mp)
-    got = paged_decode_attention(q, kc, vc, tables, lengths, ps, pages_per_step=tile, interpret=interpret, **scales)
+    stored = pairs if quant == "bf16-pairs" else lambda x: x  # the reference reads the pool a head a row, the kernel as served
+    got = paged_decode_attention(q, stored(kc), stored(vc), tables, lengths, ps, pages_per_step=tile, interpret=interpret, **scales)
     want = reference(paged_gqa_attention_ref, q[:, None], kc, vc, tables, lengths, ps, **scales)[:, 0]
     check(f"ops.paged_attention[{quant or 'bf16'},G={tile}]", got, want, atol)
   # Flash prefill: one PREFILL_BUCKET of queries against a longer cache.

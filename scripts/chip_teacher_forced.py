@@ -10,9 +10,9 @@ At the published widths and the cell's pool (slots and pages of the file's ``ser
 what the kind's ``long_prompt_tokens`` says where it says: 4160-4608 past a window of 4096)
 are prefilled as one group through ``prefill.pages_many`` (pool donated), then ``--steps`` decode
 steps run through ``paged_decode_forward`` over pool, state and pages, each fed the seeded next token (teacher-forced),
-the other slots inactive. A configuration without recurrent layers attends through whatever the served decode program
-does (``paged_kernel_supported``: on a TPU the Pallas paged kernel, with the layer's window); one with them keeps the
-gather it was measured with. Every step's log-softmax is compared with the float32 reference's full forward over prompt +
+the other slots inactive. The steps attend, write and step the recurrent state through whatever the served decode
+program does (``paged_kernel_supported``: on a TPU the Pallas paged kernel, with the layer's window, the Mosaic token write
+and the state-step kernels; until PR 58 a configuration with recurrent layers kept the XLA forms here). Every step's log-softmax is compared with the float32 reference's full forward over prompt +
 continuation (``jax.default_matmul_precision("highest")``, one row at a time): the largest and the mean |difference| over
 the reference's 64 likeliest tokens a position, and the reference's best log-prob minus its log-prob of the program's
 greedy token. ``--probes`` adds the same numbers against deliberately wrong references of the kind's ``probes`` and
@@ -74,7 +74,7 @@ def main() -> int:
   params = weights.build_params(hf, args.seed)
   cfg = common.model_config(hf)
   all_probes = {**kind.probes(hf), **getattr(kind, "long_probes", lambda _hf: {})(hf)}
-  use_kernel = not cfg.recurrent_layers and paged_kernel_supported(cfg)
+  use_kernel = paged_kernel_supported(cfg)  # what a server resolves on this device, recurrent layers or none (the docstring)
   shard = Shard("m", 0, cfg.n_layers - 1, cfg.n_layers)
   slots, ps = (int(hf["serving_env"]["XOT_TPU_BATCH_SLOTS"]), 64) if not args.cpu else (8, 16)
   mp = pages_to_cover(cfg.max_seq_len, ps)

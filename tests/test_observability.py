@@ -271,7 +271,7 @@ def test_labeled_histograms_render_snapshot_merge():
 # ------------------------------------------------------ scheduler telemetry
 
 
-def _tiny_batched_server(n_slots=2, chunk=2):
+def _tiny_batched_server(n_slots=2, chunk=2, **overrides):
   import jax
 
   from xotorch_support_jetson_tpu.inference.batch_scheduler import BatchedServer
@@ -279,11 +279,41 @@ def _tiny_batched_server(n_slots=2, chunk=2):
   from xotorch_support_jetson_tpu.models.config import tiny_test_config
   from xotorch_support_jetson_tpu.models.decoder import full_model_params
 
-  cfg = tiny_test_config(n_layers=2, max_seq_len=128)
+  cfg = tiny_test_config(n_layers=2, max_seq_len=128, **overrides)
   params, shard = full_model_params(jax.random.PRNGKey(0), cfg, "m")
   engine = JaxShardedInferenceEngine(use_local_mesh=False)
   engine.load_test_model(shard, cfg, params)
   return BatchedServer(engine, n_slots=n_slots, chunk=chunk)
+
+
+@pytest.mark.parametrize(
+  "overrides,kv_quant,k_lanes,v_lanes",
+  [
+    (dict(dim=2048, n_heads=32, n_kv_heads=8, hidden_dim=64), "", 1.0, 1.0),  # granite's and LFM2's attention geometry: heads of 64, stored in pairs
+    (dict(dim=512, n_heads=4, n_kv_heads=2, hidden_dim=64), "", 1.0, 1.0),  # heads of 128: whole lanes as they always were
+    (dict(dim=512, n_heads=4, n_kv_heads=2, hidden_dim=64), "int8", 1.0, 1.0),
+    (dict(dim=256, n_heads=4, n_kv_heads=2, hidden_dim=64), "int8", 0.5, 0.5),  # int8 codes of 64: a scale a token and head, no pairs — padded once a dispatch
+    (dict(dim=192, n_heads=3, n_kv_heads=3, hidden_dim=64), "", 0.5, 0.5),  # an odd head count
+    (dict(dim=64, n_heads=4, n_kv_heads=4, kv_lora_rank=128, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=64, v_head_dim=16), "", 1.0, 0.5),  # MLA: the latent leaf whole, the rope leaf padded
+  ],
+  ids=["heads-of-64-in-pairs", "heads-of-128", "heads-of-128-int8", "heads-of-64-int8", "odd-heads-of-64", "mla-rope-leaf"],
+)
+def test_kv_page_lanes_filled_says_what_of_a_pools_rows_the_kernel_reads_is_codes(monkeypatch, overrides, kv_quant, k_lanes, v_lanes):
+  """ISSUE 58: ``kv_page_lanes_filled{leaf}`` is set when the pool is made, from the leaves' own shapes (ops/paged.py
+  ``code_lanes_filled``): 1 where a code leaf's row is whole lane groups — float heads of 64 are stored two a lane group
+  for that —, 0.5 where a 64-wide row is padded to 128 in the decode programs' copy of the leaf."""
+  from xotorch_support_jetson_tpu.utils.metrics import metrics
+
+  monkeypatch.setenv("XOT_TPU_PAGED", "1")
+  monkeypatch.setenv("XOT_TPU_KV_QUANT", kv_quant)
+  server = _tiny_batched_server(**overrides)
+  try:
+    server._ensure_cache()
+    snap = Metrics.merged([metrics.snapshot()])
+    assert (snap.gauge_value("kv_page_lanes_filled", labels={"leaf": "k"}), snap.gauge_value("kv_page_lanes_filled", labels={"leaf": "v"})) == (k_lanes, v_lanes)
+    assert (server.cache["k"].shape[2] * 2 == server.engine.cfg.cache_kv_heads) == (overrides.get("n_kv_heads") == 8)
+  finally:
+    server.shutdown()
 
 
 @pytest.mark.parametrize(
@@ -775,6 +805,7 @@ EXPECTED_METRIC_NAMES = {
   "xot_tpu_attention_rope_layers",  # {rope}: the page pool's layers whose q and k carry a position term (rope) / none (none) (ISSUE 50)
   "xot_tpu_moe_router_input",  # {at}: the expert layers whose router reads its experts' own input (ffn) / the attention's, drawn ahead of it (attn) (ISSUE 50)
   "xot_tpu_moe_expert_gate",  # {act}: the expert layers by their experts' gate nonlinearity: silu / relu (ops/moe.py EXPERT_ACTS, ISSUE 50)
+  "xot_tpu_kv_page_lanes_filled",  # {leaf}: the share of a code leaf's row, as the decode kernel's DMA takes it, that holds codes: 1 for whole lane groups (heads of 128 / 256, paired heads of 64), 0.5 for a 64-wide row padded to 128 (ops/paged.py code_lanes_filled, ISSUE 58)
   "xot_tpu_kv_pages_resident_total",  # pages the decode rows hold in every layer that owns pages, summed a decode dispatch (ISSUE 46)
   "xot_tpu_kv_pages_read_total",  # of those, the pages the layers' windows let their attention read (ISSUE 46)
   "xot_tpu_mixed_budget_tokens",  # the tick planner's current prefill-slice budget (ISSUE 14)
@@ -868,6 +899,7 @@ def test_metric_name_snapshot_after_serving():
   gm.set_gauge("attention_rope_layers", 0, labels={"rope": "rope"})  # set when a page pool is made (ISSUE 50)
   gm.set_gauge("moe_router_input", 0, labels={"at": "ffn"})  # set when a pool is made for a model with routed experts (ISSUE 50)
   gm.set_gauge("moe_expert_gate", 0, labels={"act": "silu"})
+  gm.set_gauge("kv_page_lanes_filled", 0, labels={"leaf": "k"})  # set when a page pool is made (ISSUE 58)
   gm.inc("kv_pages_resident_total", 0)  # counted a paged decode dispatch
   gm.inc("kv_pages_read_total", 0)
   gm.set_gauge("kv_draft_slots", 0)

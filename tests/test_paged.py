@@ -95,9 +95,21 @@ def test_paged_kernel_int8kv_dequant_matches_gather_reference():
     paged_decode_attention(q, kp, vp, bt, lengths, ps, k_scale_pool=ks, interpret=True)
 
 
+def _in_pairs(x):
+  """A float code leaf [..., Hkv, ps, 64] as ``init_paged_pool`` stores heads of 64 since ISSUE 58, [..., Hkv/2, ps, 128]:
+  KV head 2j in lanes 0-63 of leaf head j, head 2j+1 in lanes 64-127 — written out here, not through ops/paged.py."""
+  return jnp.concatenate([x[..., 0::2, :, :], x[..., 1::2, :, :]], axis=-1)
+
+
+def _as_stored(quant: str, x):
+  """The leaf as the kernel is handed it: ``"pairs"`` pairs the heads, every other form is stored as it is built."""
+  return _in_pairs(x) if quant == "pairs" else x
+
+
 def _kernel_case_pools(rng, quant: str, P: int, Hkv: int, ps: int, hd: int):
-  """(k, v, scale kwargs) of a page pool in one of the three stored forms."""
-  if quant == "":
+  """(k, v, scale kwargs) of a page pool in one of the three stored forms (``"pairs"``: bfloat16 too — the tests hand
+  the kernel ``_as_stored`` of it and the reference the leaf as built here)."""
+  if quant in ("", "pairs"):
     return jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.bfloat16), jnp.asarray(rng.normal(size=(P, Hkv, ps, hd)), jnp.bfloat16), {}
   if quant == "int8":
     codes = lambda: jnp.asarray(rng.integers(-127, 128, size=(P, Hkv, ps, hd)), jnp.int8)  # noqa: E731
@@ -121,7 +133,7 @@ _RAGGED_ROWS = {  # the middle row of three; its neighbours hold ordinary contex
 
 
 @pytest.mark.parametrize("row", list(_RAGGED_ROWS))
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+@pytest.mark.parametrize("quant", ["", "int8", "int4", "pairs"])
 def test_paged_kernel_ragged_rows_match_reference(quant, row):
   """The kernel's loop is bounded by each row's own length: rows of very
   different occupancy in one batch, for every stored form of the pool,
@@ -132,7 +144,7 @@ def test_paged_kernel_ragged_rows_match_reference(quant, row):
   kp, vp, scales = _kernel_case_pools(rng, quant, P, Hkv, ps, hd)
   bt = jnp.asarray(1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp))
   lens = np.asarray([ps + 3, _RAGGED_ROWS[row], 4 * ps - 1], np.int32)
-  ker = np.asarray(paged_decode_attention(q, kp, vp, bt, jnp.asarray(lens), ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales))
+  ker = np.asarray(paged_decode_attention(q, _as_stored(quant, kp), _as_stored(quant, vp), bt, jnp.asarray(lens), ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales))
   ref = np.asarray(paged_gqa_attention_ref(q[:, None], kp, vp, bt, jnp.asarray(np.maximum(lens, 1)), ps, **scales)[:, 0])
   live = lens > 0
   assert np.allclose(ker[live], ref[live], atol=2e-5), np.abs(ker[live] - ref[live]).max()
@@ -167,6 +179,7 @@ def check_fold_boundary_case(quant: str, hd: int, g: int, row: str):
   P = 1 + B * mp
   q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
   kp, vp, scales = _kernel_case_pools(rng, quant, P, Hkv, ps, hd)
+  coded = bool(scales)  # integer codes: their poison is ±127; a float leaf's is NaN
   lens = np.asarray([2 * g * ps + 5, _FOLD_ROWS[row](g, ps), g * ps + ps + 2], np.int32)
   bt = 1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp)
   held, tail = np.zeros(P, bool), np.zeros((P, 1, ps, 1), bool)
@@ -183,13 +196,13 @@ def check_fold_boundary_case(quant: str, hd: int, g: int, row: str):
   def poisoned(x, where, bad):
     return jnp.where(where, jnp.asarray(bad, x.dtype), x), jnp.where(where, jnp.zeros((), x.dtype), x)
 
-  kp_bad, kp_ok = poisoned(kp, unwritten, sign if quant else jnp.nan)
+  kp_bad, kp_ok = poisoned(kp, unwritten, sign if coded else jnp.nan)
   # (float values a row has not written are probabilities 0 × whatever lies there, in the kernel as in the reference: those stay finite)
-  vp_bad, vp_ok = poisoned(vp, unwritten if quant else free, sign if quant else jnp.nan)
+  vp_bad, vp_ok = poisoned(vp, unwritten if coded else free, sign if coded else jnp.nan)
   bad, ok = {}, {}
   for name, x in scales.items():
     bad[name], ok[name] = poisoned(x, unwritten, jnp.nan)
-  ker = np.asarray(paged_decode_attention(q, kp_bad, vp_bad, jnp.asarray(bt), jnp.asarray(lens), ps, pages_per_step=g, interpret=True, **bad))
+  ker = np.asarray(paged_decode_attention(q, _as_stored(quant, kp_bad), _as_stored(quant, vp_bad), jnp.asarray(bt), jnp.asarray(lens), ps, pages_per_step=g, interpret=True, **bad))
   ref = np.asarray(paged_gqa_attention_ref(q[:, None], kp_ok, vp_ok, jnp.asarray(bt), jnp.asarray(np.maximum(lens, 1)), ps, **ok)[:, 0])
   live = lens > 0
   assert np.isfinite(ker).all()
@@ -199,34 +212,38 @@ def check_fold_boundary_case(quant: str, hd: int, g: int, row: str):
 
 @pytest.mark.parametrize("row", list(_FOLD_ROWS))
 @pytest.mark.parametrize("g", [4, 8])
-@pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("quant", ["", "int8"])
+@pytest.mark.parametrize("quant,hd", [("", 64), ("", 128), ("int8", 64), ("int8", 128), ("pairs", 64)], ids=["-64", "-128", "int8-64", "int8-128", "pairs-64"])
 def test_paged_kernel_fold_boundaries_match_reference(quant, hd, g, row):
   """One softmax update takes a tile of pages: lengths that end one token
   before, on and one token after a page and a tile, a length past two tiles
   and an empty row between two long ones equal the gather reference, and
   nothing a row does not hold — a page never fetched, a stale scale lane, a
-  slot the row before left — reaches its result (no NaN, no ±127 code)."""
+  slot the row before left — reaches its result (no NaN, no ±127 code). In a
+  pool of paired heads (ISSUE 58) a query's zeros meet the pair's OTHER head's
+  key at every position: at a position the row holds that key was written with
+  the token and is finite, and at every other the score is masked as before."""
   check_fold_boundary_case(quant, hd, g, row)
 
 
-@pytest.mark.parametrize("hq,hkv", [(2, 2), (6, 2), (8, 1)])
-def test_paged_kernel_head_groupings_match_reference(hq, hkv):
+@pytest.mark.parametrize("hq,hkv,quant", [(2, 2, "int8"), (6, 2, "int8"), (8, 1, "int8"), (4, 4, "pairs"), (12, 4, "pairs"), (32, 8, "pairs")])
+def test_paged_kernel_head_groupings_match_reference(hq, hkv, quant):
   """The kernel stacks every query head's scores for one softmax update and
   hands each kv head its own group's rows: MHA (group 1), a group that is
-  not a power of two, and MQA equal the gather reference."""
+  not a power of two, and MQA equal the gather reference — and so do paired
+  heads (ISSUE 58), whose leaf head's group is both KV heads' queries: MHA
+  (a group of 2), groups of 3 and granite's and LFM2's 32 / 8."""
   rng = np.random.default_rng(47)
   B, hd, ps, mp, P = 2, 64, _RAGGED_PS, _RAGGED_MP, 16
   q = jnp.asarray(rng.normal(size=(B, hq, hd)), jnp.float32)
-  kp, vp, scales = _kernel_case_pools(rng, "int8", P, hkv, ps, hd)
+  kp, vp, scales = _kernel_case_pools(rng, quant, P, hkv, ps, hd)
   bt = jnp.asarray(1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp))
   lens = jnp.asarray([3 * ps + 5, ps - 1], jnp.int32)
-  ker = paged_decode_attention(q, kp, vp, bt, lens, ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales)
+  ker = paged_decode_attention(q, _as_stored(quant, kp), _as_stored(quant, vp), bt, lens, ps, pages_per_step=_RAGGED_TILE, interpret=True, **scales)
   ref = paged_gqa_attention_ref(q[:, None], kp, vp, bt, lens, ps, **scales)[:, 0]
   assert jnp.allclose(ker, ref, atol=2e-5), jnp.abs(ker - ref).max()
 
 
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+@pytest.mark.parametrize("quant", ["", "int8", "int4", "pairs"])
 def test_paged_kernel_never_reads_past_a_rows_length(quant):
   """Block-table entries past a row's length point at a page whose codes
   and scales are poison (NaN where the dtype has one): nothing changes,
@@ -237,7 +254,7 @@ def test_paged_kernel_never_reads_past_a_rows_length(quant):
   kp, vp, scales = _kernel_case_pools(rng, quant, P, Hkv, ps, hd)
   poison = P - 1
   bad = lambda x: x.at[poison].set(jnp.nan if jnp.issubdtype(x.dtype, jnp.floating) else 127)  # noqa: E731
-  kp, vp, scales = bad(kp), bad(vp), {name: bad(x) for name, x in scales.items()}
+  kp, vp, scales = _as_stored(quant, bad(kp)), _as_stored(quant, bad(vp)), {name: bad(x) for name, x in scales.items()}
   lens = np.asarray([ps + 3, 0, 2 * ps], np.int32)  # a partial page, an empty row, a full last page
   clean = 1 + np.arange(B * mp, dtype=np.int32).reshape(B, mp)
   held = np.arange(mp)[None, :] * ps < lens[:, None]
@@ -245,6 +262,95 @@ def test_paged_kernel_never_reads_past_a_rows_length(quant):
   got = run(np.where(held, clean, poison).astype(np.int32))
   assert np.isfinite(got).all()
   assert np.array_equal(got, run(clean))
+
+
+# ------------------------------------------------- paired heads of 64 (ISSUE 58)
+
+_MLA_TINY = dict(n_heads=4, n_kv_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
+_STORED_FORMS = {  # config overrides, KV mode → the code leaves' trailing [heads, slots, lanes] and the gauge's k / v
+  "float-64-even-heads": (dict(dim=512, n_heads=8, n_kv_heads=4), "", (2, PS, 128), (2, PS, 128), 1.0, 1.0),  # THE paired form
+  "bf16-64-granite-lfm2": (dict(dim=2048, n_heads=32, n_kv_heads=8, dtype=jnp.bfloat16), "", (4, PS, 128), (4, PS, 128), 1.0, 1.0),
+  "int8-64": (dict(dim=512, n_heads=8, n_kv_heads=4), "int8", (4, PS, 64), (4, PS, 64), 0.5, 0.5),  # a scale is per token and head: as it was
+  "int4-64": (dict(dim=512, n_heads=8, n_kv_heads=4), "int4", (4, PS, 32), (4, PS, 32), 0.25, 0.25),
+  "float-64-odd-heads": (dict(dim=384, n_heads=6, n_kv_heads=3), "", (3, PS, 64), (3, PS, 64), 0.5, 0.5),  # no head to pair the last with
+  "float-128": (dict(dim=512, n_heads=4, n_kv_heads=2), "", (2, PS, 128), (2, PS, 128), 1.0, 1.0),  # whole lanes already: as it was
+  "int4-128": (dict(dim=512, n_heads=4, n_kv_heads=2), "int4", (2, PS, 64), (2, PS, 64), 0.5, 0.5),
+  "float-32": (dict(dim=256, n_heads=8, n_kv_heads=4), "", (4, PS, 32), (4, PS, 32), 0.25, 0.25),  # pairs are of 64 alone
+  "mla-latent-and-rope": (_MLA_TINY, "", (1, PS, 16), (1, PS, 8), 0.125, 0.0625),  # one head: nothing to pair
+}
+
+
+@pytest.mark.parametrize("case", list(_STORED_FORMS))
+def test_a_pool_stores_float_heads_of_64_in_pairs_and_every_other_pool_as_it_was(case):
+  """``init_paged_pool`` pairs the heads from ``cfg`` and the KV mode alone — unquantised, K and V heads of 64, an even
+  number of them: [L, P, Hkv/2, ps, 128], the same bytes — and leaves every other pool [L, P, Hkv, ps, hd]; the rule a
+  leaf's reader applies is ``leaf.shape[2] * 2 == cfg.cache_kv_heads``; ``code_lanes_filled`` (the gauge
+  ``kv_page_lanes_filled``) says what of a row the decode kernel's DMA carries is codes."""
+  from xotorch_support_jetson_tpu.ops.paged import code_lanes_filled, kernel_pool_form, pairs_kv_heads
+
+  overrides, quant, k_tail, v_tail, k_lanes, v_lanes = _STORED_FORMS[case]
+  cfg = tiny_test_config(n_layers=2, **overrides)
+  pool = init_paged_pool(cfg, 2, 5, PS, quant=quant)
+  assert pool["k"].shape == (2, 5, *k_tail) and pool["v"].shape == (2, 5, *v_tail)
+  paired = case in ("float-64-even-heads", "bf16-64-granite-lfm2")
+  assert pairs_kv_heads(cfg, quant) == paired == (pool["k"].shape[2] * 2 == cfg.cache_kv_heads)
+  assert pool["k"].size == 2 * 5 * cfg.cache_kv_heads * PS * cfg.cache_k_dim // (2 if quant == "int4" else 1)  # the bytes it had
+  assert (code_lanes_filled(pool["k"]), code_lanes_filled(pool["v"])) == (k_lanes, v_lanes)
+  if k_lanes == 1.0:  # whole lanes: the stored pool IS the kernel's form
+    assert all(kernel_pool_form(pool)[name].shape == pool[name].shape for name in ("k", "v"))
+
+
+def _paired_case(seed=53, L=3, B=3, Hq=8, Hkv=4, hd=64, ps=8, mp=4):
+  """Position-major K/V of L layers and B rows [L, B, mp·ps, Hkv, hd], the same in pages as they were stored until
+  ISSUE 58 ([L, P, Hkv, ps, hd], filled by hand) and in pairs, a table and a query."""
+  rng = np.random.default_rng(seed)
+  k, v = (jnp.asarray(rng.normal(size=(L, B, mp * ps, Hkv, hd)), jnp.float32) for _ in range(2))
+  bt = jnp.asarray(1 + rng.permutation(B * mp).reshape(B, mp), jnp.int32)
+  pages = lambda t: jnp.zeros((L, 1 + B * mp, Hkv, ps, hd), t.dtype).at[:, bt].set(jnp.swapaxes(t.reshape(L, B, mp, ps, Hkv, hd), 3, 4))  # noqa: E731
+  plain = {"k": pages(k), "v": pages(v)}
+  return k, v, bt, plain, {name: _in_pairs(leaf) for name, leaf in plain.items()}, jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32), ps
+
+
+@pytest.mark.parametrize("accessor", ["scatter_row_pages", "gather_row_pages", "gather_pages", "write_token_kv", "write_token_kv-kernel", "reference", "kernel-vs-unpaged"])
+def test_paired_pool_accessors_read_and_write_what_the_plain_pool_holds(accessor):
+  """Every accessor between pages and tokens, on a pool of paired heads against the same K/V stored a head a row (the
+  form until ISSUE 58): a prefill's scatter lays the pairs out as ``_in_pairs`` says, the gathers give back the model's
+  [.., Hkv, 64] bit for bit, a token write touches the same channels, the gather reference reads either form to the
+  same bits, and the kernel (interpret mode) agrees with attention over the unpaged K/V."""
+  from xotorch_support_jetson_tpu.ops.attention import gqa_attention
+  from xotorch_support_jetson_tpu.ops.paged import gather_pages, gather_row_pages, scatter_row_pages, write_token_kv
+
+  k, v, bt, plain, pairs, q, ps = _paired_case()
+  Hkv = k.shape[3]
+  same = lambda a, b: np.array_equal(np.asarray(a), np.asarray(b))  # noqa: E731
+  lens = jnp.asarray([5, 17, 32], jnp.int32)
+  if accessor == "scatter_row_pages":
+    got = scatter_row_pages(jnp.zeros_like(pairs["k"]), k, bt)
+    assert same(got, pairs["k"]) and same(scatter_row_pages(jnp.zeros_like(plain["k"]), k, bt), plain["k"])
+  elif accessor == "gather_row_pages":
+    assert same(gather_row_pages(pairs["k"], bt, Hkv), k) and same(gather_row_pages(plain["k"], bt, Hkv), k)
+    assert same(gather_row_pages(scatter_row_pages(jnp.zeros_like(pairs["v"]), v, bt), bt, Hkv), v)  # a prefill's round trip
+  elif accessor == "gather_pages":
+    assert same(gather_pages(pairs["k"], bt, 1, Hkv), k[1]) and same(gather_pages(pairs["k"][2], bt, None, Hkv), k[2])
+  elif accessor.startswith("write_token_kv"):
+    new = {"k": q[:, :Hkv] + 1, "v": q[:, Hkv:] - 1}  # [B, Hkv, 64], as the layer step hands them
+    pos = lens - 1
+    kwargs = dict(kernel=True, interpret=True) if accessor.endswith("kernel") else {}
+    wrote, want = write_token_kv(pairs, new, 1, bt, pos, ps, **kwargs), write_token_kv(plain, new, 1, bt, pos, ps)
+    for name in ("k", "v"):
+      assert same(wrote[name], _in_pairs(want[name])) and not same(wrote[name], pairs[name])
+      assert same(gather_row_pages(wrote[name], bt, Hkv)[1, np.arange(3), np.asarray(pos)], new[name])
+  elif accessor == "reference":
+    for layer in range(3):
+      assert same(paged_gqa_attention_ref(q[:, None], pairs["k"], pairs["v"], bt, lens, ps, layer=layer), paged_gqa_attention_ref(q[:, None], plain["k"], plain["v"], bt, lens, ps, layer=layer))
+  else:
+    for layer in range(3):
+      want = gqa_attention(q[:, None], k[layer], v[layer], (lens - 1)[:, None], jnp.arange(k.shape[2], dtype=jnp.int32))[:, 0]
+      named = paged_decode_attention(q, pairs["k"], pairs["v"], bt, lens, ps, interpret=True, layer=layer, kv_quant="", kv_heads=Hkv)  # as the layer step calls it
+      assert same(named, paged_decode_attention(q, pairs["k"], pairs["v"], bt, lens, ps, interpret=True, layer=layer))  # a direct caller's stored leaves say it themselves
+      assert jnp.allclose(named, want, atol=2e-5), jnp.abs(named - want).max()
+      padded = paged_decode_attention(q, plain["k"], plain["v"], bt, lens, ps, interpret=True, layer=layer)  # the form until ISSUE 58, padded per call
+      assert jnp.allclose(named, padded, atol=1e-6), jnp.abs(named - padded).max()
 
 
 # ------------------------------------------------- the kernel's latent body (absorbed MLA)
@@ -434,11 +540,11 @@ def test_decode_programs_resolve_use_kernel_through_the_owner(monkeypatch, progr
   assert seen == [can_run, not can_run]
 
 
-def _prefill_both(params, shard, prompts, n_slots, max_seq=128):
+def _prefill_both(params, shard, prompts, n_slots, max_seq=128, cfg=CFG):
   """Prefill the same prompts into a dense pool and a page pool."""
   mp = max_seq // PS
-  dense = init_kv_cache(CFG, shard.n_shard_layers, n_slots, max_seq)
-  pool = init_paged_pool(CFG, shard.n_shard_layers, 1 + n_slots * mp, PS)
+  dense = init_kv_cache(cfg, shard.n_shard_layers, n_slots, max_seq)
+  pool = init_paged_pool(cfg, shard.n_shard_layers, 1 + n_slots * mp, PS)
   bt = np.zeros((n_slots, mp), np.int32)
   nxt = 1
   firsts = []
@@ -446,33 +552,60 @@ def _prefill_both(params, shard, prompts, n_slots, max_seq=128):
     S = len(p)
     pad = np.zeros((1, 16 * ((S + 15) // 16)), np.int32)
     pad[0, :S] = p
-    last_d, dense = prefill_into_slot(params, CFG, shard, jnp.asarray(pad), dense, jnp.int32(r), jnp.int32(S))
+    last_d, dense = prefill_into_slot(params, cfg, shard, jnp.asarray(pad), dense, jnp.int32(r), jnp.int32(S))
     need = (S + 64) // PS + 1
     bt[r, :need] = range(nxt, nxt + need)
     nxt += need
-    last_p, pool = prefill_into_pages(params, CFG, shard, jnp.asarray(pad), pool, jnp.asarray(bt[r]), jnp.int32(0), jnp.int32(S), PS)
+    last_p, pool = prefill_into_pages(params, cfg, shard, jnp.asarray(pad), pool, jnp.asarray(bt[r]), jnp.int32(0), jnp.int32(S), PS)
     assert jnp.allclose(last_d, last_p, atol=1e-4), f"prefill logits diverge, row {r}"
     firsts.append(int(np.argmax(np.asarray(last_d)[0])))
   return dense, pool, bt, firsts
 
 
-def test_paged_decode_matches_dense_decode():
+PAIRS_CFG = tiny_test_config(n_layers=2, max_seq_len=128, dim=256, n_heads=4, n_kv_heads=2)  # heads of 64: the pool pairs them (ISSUE 58)
+
+
+@pytest.mark.parametrize("cfg", [CFG, PAIRS_CFG], ids=["heads-of-16", "paired-heads-of-64"])
+def test_paged_decode_matches_dense_decode(cfg):
   """Same prompts through both cache layouts -> identical greedy tokens,
   including an inactive row that must not advance (its table is pinned to
-  the trash page inside the program)."""
-  params, shard = full_model_params(KEY, CFG)
+  the trash page inside the program). Heads of 64 go through pages that hold
+  them in pairs: prefilled by ``scatter_row_pages``, written a token at a
+  time, read by the gather reference."""
+  params, shard = full_model_params(KEY, cfg)
   prompts = [[3, 25, 9], [7, 1, 88, 42, 5], [100]]
   n_slots = 3
-  dense, pool, bt, firsts = _prefill_both(params, shard, prompts, n_slots)
+  dense, pool, bt, firsts = _prefill_both(params, shard, prompts, n_slots, cfg=cfg)
+  assert (pool["k"].shape[2] * 2 == cfg.cache_kv_heads) == (cfg is PAIRS_CFG)
   tok = jnp.asarray([[f] for f in firsts], jnp.int32)
   positions = jnp.asarray([len(p) for p in prompts], jnp.int32)
   active = jnp.asarray([True, True, False])
   temps = jnp.zeros((n_slots,), jnp.float32)
-  td, _, pd, _ = fused_batch_decode(params, CFG, shard, tok, dense, positions, active, temps, 12)
-  tp, _, pp, _ = fused_paged_batch_decode(params, CFG, shard, tok, pool, jnp.asarray(bt), positions, active, temps, 12, page_size=PS, use_kernel=False)
+  td, _, pd, _ = fused_batch_decode(params, cfg, shard, tok, dense, positions, active, temps, 12)
+  tp, _, pp, _ = fused_paged_batch_decode(params, cfg, shard, tok, pool, jnp.asarray(bt), positions, active, temps, 12, page_size=PS, use_kernel=False)
   td, tp = np.asarray(td), np.asarray(tp)
   assert np.array_equal(td[:2], tp[:2])
   assert np.array_equal(np.asarray(pd), np.asarray(pp))
+
+
+def test_a_model_of_paired_heads_scores_the_same_through_the_kernels_and_the_gather():
+  """``paged_window_forward`` (speculation's verify: the one decode-side program with an ``interpret`` argument) over a
+  pool of paired heads, told the kernels — the Mosaic token write and ``paged_decode_attention`` as the layer steps call
+  them, the model's KV heads named — against the XLA scatter and the gather reference: the first layer's pages bit for
+  bit, the logits and the later layers' pages to rounding."""
+  from xotorch_support_jetson_tpu.models.decoder import paged_window_forward
+
+  params, shard = full_model_params(KEY, PAIRS_CFG)
+  prompts = [[3, 25, 9], [7, 1, 88, 42, 5] + [4] * 20, [100]]
+  _, pool, bt, firsts = _prefill_both(params, shard, prompts, 3, cfg=PAIRS_CFG)
+  window = jnp.concatenate([jnp.asarray(firsts, jnp.int32)[:, None], jnp.asarray([[17, 40], [2, 91], [64, 8]], jnp.int32)], axis=1)
+  wpos = jnp.asarray([len(p) for p in prompts], jnp.int32)[:, None] + jnp.arange(3, dtype=jnp.int32)[None, :]
+  ref_logits, ref_pool = paged_window_forward(params, PAIRS_CFG, shard, window, wpos, pool, jnp.asarray(bt), PS, use_kernel=False)
+  ker_logits, ker_pool = paged_window_forward(params, PAIRS_CFG, shard, window, wpos, pool, jnp.asarray(bt), PS, use_kernel=True, interpret=True)
+  assert ker_pool["k"].shape == pool["k"].shape == (2, pool["k"].shape[1], 1, PS, 128)
+  assert np.array_equal(np.asarray(ker_pool["k"][0, 1:]), np.asarray(ref_pool["k"][0, 1:])) and not np.array_equal(np.asarray(ker_pool["k"][0]), np.asarray(pool["k"][0]))
+  assert all(np.allclose(np.asarray(ker_pool[name][:, 1:]), np.asarray(ref_pool[name][:, 1:]), atol=1e-5) for name in pool)
+  assert jnp.allclose(ker_logits, ref_logits, atol=1e-4) and np.array_equal(np.asarray(jnp.argmax(ker_logits, -1)), np.asarray(jnp.argmax(ref_logits, -1)))
 
 
 @pytest.mark.parametrize("B", [16, 48])

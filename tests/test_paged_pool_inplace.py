@@ -175,8 +175,14 @@ def test_pool_and_tokens_equal_the_parents(case, recorded):
 
 
 def _stacked_pools(quant: str, L=5, P=9, Hkv=2, hd=32, seed=7):
-  """Random stacked leaves (every layer different) and a new token a row, in the pool's dtypes."""
+  """Random stacked leaves (every layer different) and a new token a row, in the pool's dtypes. ``"pairs"``: float
+  heads of 64 as the pool stores them since ISSUE 58, two a lane group ([L, P, Hkv/2, PS, 128]); the token's K/V come
+  as the layer step hands them, [B, Hkv, 64]."""
   rng = np.random.default_rng(seed)
+  if quant == "pairs":
+    val = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    pool = {"k": val(L, P, Hkv // 2, PS, 128), "v": val(L, P, Hkv // 2, PS, 128)}
+    return pool, {"k": val(3, Hkv, 64), "v": val(3, Hkv, 64)}, jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 0]], jnp.int32), rng
   kd = hd // 2 if quant == "int4" else hd
   if quant:
     code = lambda *shape: jnp.asarray(rng.integers(-128, 128, size=shape), jnp.int8)  # noqa: E731
@@ -191,31 +197,40 @@ def _stacked_pools(quant: str, L=5, P=9, Hkv=2, hd=32, seed=7):
   return pool, new, bt, rng
 
 
+def _unpaired(leaf):
+  """A leaf of paired heads [..., Hkv/2, PS, 128] a head a row, [..., Hkv, PS, 64], written out by hand."""
+  return jnp.stack([leaf[..., :64], leaf[..., 64:]], axis=-3).reshape(*leaf.shape[:-3], 2 * leaf.shape[-3], leaf.shape[-2], 64)
+
+
 @pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+@pytest.mark.parametrize("quant", ["", "int8", "int4", "pairs"])
 def test_kernel_on_a_stacked_pool_reads_its_layer(quant, layer):
   """``paged_decode_attention`` (interpret mode) on the stacked leaves with a
   layer scalar == the gather reference on that layer's slice alone."""
   from xotorch_support_jetson_tpu.ops.paged import paged_decode_attention, paged_gqa_attention_ref
 
-  pool, _, bt, rng = _stacked_pools(quant)
-  q = jnp.asarray(rng.normal(size=(3, 4, 32)), jnp.float32)
+  pool, new, bt, rng = _stacked_pools(quant)
+  q = jnp.asarray(rng.normal(size=(3, 4, new["k"].shape[-1] * (2 if quant == "int4" else 1))), jnp.float32)
   lengths = jnp.asarray([2 * PS + 3, PS, 3 * PS], jnp.int32)
-  scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if quant else {}
+  scales = {"k_scale_pool": pool["k_scale"], "v_scale_pool": pool["v_scale"]} if "k_scale" in pool else {}
   got = paged_decode_attention(q, pool["k"], pool["v"], bt, lengths, PS, layer=jnp.int32(layer), pages_per_step=2, interpret=True, **scales)
   one = {name: leaf[layer] for name, leaf in scales.items()}
   want = paged_gqa_attention_ref(q[:, None], pool["k"][layer], pool["v"][layer], bt, lengths, PS, **one)[:, 0]
   stacked_ref = paged_gqa_attention_ref(q[:, None], pool["k"], pool["v"], bt, lengths, PS, layer=jnp.int32(layer), **scales)[:, 0]
   assert np.array_equal(np.asarray(stacked_ref), np.asarray(want))  # the reference by (layer, page) is the reference on the slice
   np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+  if quant == "pairs":  # ... which reads the pairs as the heads they are: the same bits as off the layer stored a head a row
+    assert np.array_equal(np.asarray(want), np.asarray(paged_gqa_attention_ref(q[:, None], _unpaired(pool["k"][layer]), _unpaired(pool["v"][layer]), bt, lengths, PS)[:, 0]))
 
 
 @pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+@pytest.mark.parametrize("quant", ["", "int8", "int4", "pairs"])
 def test_token_write_kernel_equals_the_scatter(quant, layer):
   """The Mosaic token write (interpret mode), on the pool in the kernel's
   form, puts the bytes the XLA scatter puts, in that layer only; a row on
-  the trash page is skipped (nothing reads what it would write)."""
+  the trash page is skipped (nothing reads what it would write). A pool of
+  paired heads is its own kernel form, and both writes put each KV head's 64
+  channels in its half of the pair's row."""
   from xotorch_support_jetson_tpu.ops.paged import kernel_pool_form, stored_pool_form, write_token_kv
 
   pool, new, bt, _ = _stacked_pools(quant)
@@ -224,6 +239,10 @@ def test_token_write_kernel_equals_the_scatter(quant, layer):
   want = write_token_kv(pool, new, jnp.int32(layer), bt, pos, PS)
   kernel_form = kernel_pool_form(pool)
   assert all(leaf.shape[-1] % 128 == 0 for leaf in kernel_form.values()) and kernel_form["k"].ndim == 5
+  if quant == "pairs":
+    assert all(np.array_equal(kernel_form[name], pool[name]) for name in pool)
+    at = (layer, np.asarray([bt[0, 2], bt[2, 0]]), slice(None), np.asarray([5, PS - 1]))  # where rows 0 and 2 write: (page, every head, slot)
+    assert np.array_equal(np.asarray(_unpaired(want["k"]))[at], np.asarray(new["k"])[[0, 2]])
   got = stored_pool_form(write_token_kv(kernel_form, new, jnp.int32(layer), bt, pos, PS, kernel=True, interpret=True), pool)
   for name in pool:
     w, g, before = (np.array(x[name]) for x in (want, got, pool))
@@ -252,8 +271,11 @@ def _traced(program: str):
   """(jaxpr, pool) of a paged program on a tiny model whose pool shapes nothing else has."""
   from xotorch_support_jetson_tpu.models import decoder
 
+  kernel = program.endswith("/paired-heads-kernel")  # told the kernels: a trace holds the Mosaic calls and runs nothing
   if program == "decode.paged_batch/mla-two-stacks":
     cfg, quant = _cfg(first_k_dense=1, **MLA, **MOE), ""
+  elif kernel:
+    cfg, quant = _cfg(dim=256), ""  # heads of 64, bfloat16-or-float pages: the pool holds them in pairs (ISSUE 58)
   else:
     cfg, quant = _cfg(), "int8"
   params, shard = full_model_params(KEY, cfg, "m")
@@ -263,17 +285,20 @@ def _traced(program: str):
   rows = lambda dtype, fill=0: jnp.full((B,), fill, dtype)  # noqa: E731
   tok, key = jnp.ones((B, 1), jnp.int32), jax.random.PRNGKey(0)
   if program.startswith("decode.paged_batch"):
-    fn, args = decoder._fused_paged_batch_decode_impl.xot_jitted, (params, cfg, shard, tok, pool, bt, rows(jnp.int32, 5), rows(bool, True), rows(jnp.float32), rows(jnp.int32, 8), 4, 8, PS, False, key, None)
-  elif program == "decode.mixed_paged_batch":
+    fn, args = decoder._fused_paged_batch_decode_impl.xot_jitted, (params, cfg, shard, tok, pool, bt, rows(jnp.int32, 5), rows(bool, True), rows(jnp.float32), rows(jnp.int32, 8), 4, 8, PS, kernel, key, None)
+  elif program.startswith("decode.mixed_paged_batch"):
     fn = decoder._fused_mixed_paged_batch_decode_impl.xot_jitted
-    args = (params, cfg, shard, tok, pool, bt, rows(jnp.int32, 5), rows(bool, True), rows(jnp.float32), rows(jnp.int32, 8), jnp.ones((1, 16), jnp.int32), bt[2:3], jnp.asarray([0]), jnp.asarray([9]), 4, 8, PS, False, key, None, None)
+    args = (params, cfg, shard, tok, pool, bt, rows(jnp.int32, 5), rows(bool, True), rows(jnp.float32), rows(jnp.int32, 8), jnp.ones((1, 16), jnp.int32), bt[2:3], jnp.asarray([0]), jnp.asarray([9]), 4, 8, PS, kernel, key, None, None)
   else:  # the verify window, alone
-    fn = jax.jit(lambda params, window, wpos, pool: paged_window_forward(params, cfg, shard, window, wpos, pool, bt, PS))
+    fn = jax.jit(lambda params, window, wpos, pool: paged_window_forward(params, cfg, shard, window, wpos, pool, bt, PS, use_kernel=kernel))
     args = (params, jnp.ones((B, 3), jnp.int32), 5 + jnp.arange(3, dtype=jnp.int32)[None, :] + jnp.zeros((B, 1), jnp.int32), pool)
   return jax.make_jaxpr(fn, static_argnums=tuple(i for i, a in enumerate(args) if not hasattr(a, "shape") and not isinstance(a, dict) and a is not None))(*args).jaxpr, pool
 
 
-@pytest.mark.parametrize("program", ["decode.paged_batch/dense-int8", "decode.paged_batch/mla-two-stacks", "decode.mixed_paged_batch", "paged_window_forward"])
+_PAIRED = ["decode.paged_batch/paired-heads-kernel", "decode.mixed_paged_batch/paired-heads-kernel", "paged_window_forward/paired-heads-kernel"]
+
+
+@pytest.mark.parametrize("program", ["decode.paged_batch/dense-int8", "decode.paged_batch/mla-two-stacks", "decode.mixed_paged_batch", "paged_window_forward", *_PAIRED])
 def test_no_layer_of_the_pool_is_sliced_out_or_joined(program):
   """The pool is a loop carry addressed by (layer, page): in the traced
   program no scan takes or makes a pool-shaped ``xs``/``ys``, nothing
@@ -282,6 +307,15 @@ def test_no_layer_of_the_pool_is_sliced_out_or_joined(program):
   size, every step)."""
   jaxpr, pool = _traced(program)
   layer_pools = {leaf.shape[1:] for leaf in pool.values()}  # one layer's [P, Hkv, ps, hd]
+  if program in _PAIRED:
+    # Heads of 64 in pairs are whole lanes: the kernel path's programs — the plain chunk, the mixed tick, speculation's
+    # verify — make no other form of the pool (until ISSUE 58 a padded copy each of K and V once a dispatch, cut back
+    # at its end), so no pad, slice or transpose of theirs takes or makes a stacked leaf.
+    assert {leaf.shape for leaf in pool.values()} == {(3, 13, 1, PS, 128)}
+    whole = lambda v: tuple(getattr(v.aval, "shape", ()))[1:] in layer_pools  # noqa: E731
+    moved = [eqn for eqn in _eqns(jaxpr) if eqn.primitive.name in ("pad", "slice", "transpose", "copy", "reshape") and any(whole(v) for v in (*eqn.invars, *eqn.outvars) if hasattr(v, "aval"))]
+    assert not moved, moved
+    assert {"kv_token_write"} <= {eqn.params.get("name") for eqn in _eqns(jaxpr) if eqn.primitive.name == "pallas_call"}
 
   def of_pool(aval) -> bool:
     shape = tuple(getattr(aval, "shape", ()))

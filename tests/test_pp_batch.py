@@ -124,9 +124,11 @@ def test_pp_batch_decode_consecutive_chunks_stay_exact():
   assert int(pos[0]) == len(PROMPTS[0]) + 12
 
 
-@pytest.mark.parametrize("flavor", ["llama", "mla"])
+@pytest.mark.parametrize("flavor", ["llama", "mla", "paired-heads"])
 def test_pp_paged_batch_decode_matches_single_device(flavor):
-  if flavor == "mla":
+  if flavor == "paired-heads":  # 4 / 2 heads of 64: pages that hold the two KV heads side by side on the lanes (ops/paged.py, ISSUE 58)
+    cfg = tiny_test_config(n_layers=4, max_seq_len=MAX_SEQ, dim=256)
+  elif flavor == "mla":
     cfg = tiny_test_config(
       n_layers=4, max_seq_len=MAX_SEQ, n_heads=4, n_kv_heads=4, kv_lora_rank=16,
       q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,

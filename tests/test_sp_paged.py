@@ -37,6 +37,7 @@ GEMMA = tiny_test_config(
   attn_logit_softcap=50.0, final_logit_softcap=30.0, query_pre_attn_scalar=24.0,
   sliding_window=4, embed_scale=8.0, tied_embedding=True,
 )
+PAIRS = tiny_test_config(n_layers=2, max_seq_len=128, dim=256)  # 4 / 2 heads of 64: the pool stores the two KV heads side by side on the lanes (ops/paged.py, ISSUE 58)
 
 PS = 16
 PROMPTS = [[3, 25, 9], list(range(40, 60)), [9, 9, 9, 1], [100]]
@@ -67,7 +68,9 @@ def _prefill_all(cfg, params, shard, pool, prefill_many, mp):
   (DENSE, MeshPlan(sp=2, tp=2)),
   (MLA, MeshPlan(sp=2)),
   (GEMMA, MeshPlan(sp=2)),
-], ids=["dense-sp2", "dense-sp4", "dense-sp2tp2", "mla-sp2", "gemma-sp2"])
+  (PAIRS, MeshPlan(sp=2)),
+  (PAIRS, MeshPlan(sp=2, tp=2)),
+], ids=["dense-sp2", "dense-sp4", "dense-sp2tp2", "mla-sp2", "gemma-sp2", "paired-heads-sp2", "paired-heads-sp2tp2"])
 def test_sp_paged_prefill_and_decode_match_single_device(cfg, plan):
   params, shard = full_model_params(jax.random.PRNGKey(31), cfg, "tiny")
   spb = SPBatchedServing(SPServing(build_mesh(plan), cfg, params, plan.sp, True, True))

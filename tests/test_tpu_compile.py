@@ -28,16 +28,25 @@ HQ, HKV = 32, 8  # Llama-3.2-1B (hd 64) and Llama-3.1-8B (hd 128) share 32/8 hea
 def _compile_paged_kernel(chip, quant: str, batch: int, tile: int, hd: int, mp: int, n_pages: int, hq: int = HQ) -> str:
   from xotorch_support_jetson_tpu.ops.paged import _paged_decode_attention_impl
 
+  paired = quant == "pairs"  # bfloat16 heads of 64 as the pool stores them since ISSUE 58: two a lane group
+  quant = "" if paired else quant
   kd = hd // 2 if quant == "int4" else hd
   code = jnp.int8 if quant else jnp.bfloat16
   layers = 2  # the stacked leaves, read at a layer scalar
-  pool = _sds(chip, (layers, n_pages, HKV, PS, kd), code)
+  leaf = (layers, n_pages, HKV // 2, PS, 2 * hd) if paired else (layers, n_pages, HKV, PS, kd)
+  pool = _sds(chip, leaf, code)
   scales = [_sds(chip, (layers, n_pages, HKV, PS, 1), jnp.float32)] * 2 if quant else []
   _, text = _compile(
     _paged_decode_attention_impl,
     _sds(chip, (batch, hq, hd), jnp.bfloat16), _sds(chip, (batch, mp), jnp.int32), _sds(chip, (batch,), jnp.int32), _sds(chip, (1,), jnp.int32), pool, pool, *scales,
-    page_size=PS, pages_per_step=tile, kv_quant=quant, interpret=False,
+    page_size=PS, pages_per_step=tile, kv_quant=quant, interpret=False, **({"paired": True} if paired else {}),
   )  # fmt: skip
+  if leaf[-1] % 128 == 0:
+    # Code leaves of whole lanes — heads of 128 and 256 as they always were, heads of 64 in pairs — reach the Mosaic call
+    # as the operands they are: the call takes two values of the stored shape, and nothing pads or copies one first.
+    stored = f"{'s8' if quant else 'bf16'}[{','.join(map(str, leaf))}]"
+    call = next(line for line in text.splitlines() if "tpu_custom_call" in line)
+    assert call.count(stored) == 2 and not re.search(rf"= {re.escape(stored)}\S* (pad|copy|transpose|slice)\(", text), call[:400]
   return text
 
 
@@ -58,6 +67,8 @@ def test_paged_decode_kernel_compiles_for_v5e(chip, quant, batch, hd):
   [
     pytest.param("int8", 16, 8, 128, 64, 257, 32, id="mistral-7b-served"),  # the benchmark's cell: 32/8 heads, 4096-token window, 257 pages
     pytest.param("", 96, 16, 256, 128, 1025, 16, id="hd256-bf16-widest-tile"),  # 16 MiB of tile buffers: past the default scoped VMEM
+    pytest.param("pairs", 128, 8, 64, 64, 2049, 32, id="lfm2-8b-a1b-served"),  # 32 / 8 heads of 64 in pairs: to the kernel 4 heads of 128 under groups of 8 (ISSUE 58)
+    pytest.param("pairs", 64, 8, 64, 32, 1537, 32, id="granite-4.0-h-micro-served"),
   ],
 )
 def test_paged_decode_kernel_compiles_at_served_shapes(chip, quant, batch, tile, hd, mp, n_pages, hq):
@@ -86,18 +97,22 @@ def test_paged_decode_latent_body_compiles_at_served_shapes(chip, batch, heads, 
   assert _mosaic_calls(text) == ["paged_decode_latent"]
 
 
-@pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("quant", ["", "int8", "int4"])
+@pytest.mark.parametrize("quant,hd", [(q, hd) for hd in (64, 128) for q in ("", "int8", "int4")] + [("pairs", 64)])
 def test_token_write_kernel_compiles_for_v5e(chip, quant, hd):
   """The kernel path's token write (``write_token_kv`` ``kernel=True``) on a
   stacked pool in the kernel's form, every pool dtype and code width: groups
-  of 8/16/32 slots by DMA, the select in 32 bits, the pool aliased through."""
+  of 8/16/32 slots by DMA, the select in 32 bits, the pool aliased through.
+  ``pairs``: bfloat16 heads of 64 stored two a lane group (ISSUE 58) — the
+  token's [B, Hkv, 64] goes in as [B, Hkv/2, 128], an hd-128 model's write,
+  and the program pads and cuts nothing."""
   from xotorch_support_jetson_tpu.ops.paged import kernel_pool_form, stored_pool_form, write_token_kv
 
   layers, n_pages, batch, mp = 4, 65, 16, 16
+  paired, quant = quant == "pairs", "" if quant == "pairs" else quant
   kd = hd // 2 if quant == "int4" else hd
   code = jnp.int8 if quant else jnp.bfloat16
-  pool = {"k": _sds(chip, (layers, n_pages, HKV, PS, kd), code), "v": _sds(chip, (layers, n_pages, HKV, PS, kd), code)}
+  leaf = (layers, n_pages, HKV // 2, PS, 2 * kd) if paired else (layers, n_pages, HKV, PS, kd)
+  pool = {"k": _sds(chip, leaf, code), "v": _sds(chip, leaf, code)}
   new = {"k": _sds(chip, (batch, HKV, kd), code), "v": _sds(chip, (batch, HKV, kd), code)}
   if quant:
     pool.update({name: _sds(chip, (layers, n_pages, HKV, PS, 1), jnp.float32) for name in ("k_scale", "v_scale")})
@@ -108,6 +123,9 @@ def test_token_write_kernel_compiles_for_v5e(chip, quant, hd):
 
   text = jax.jit(write, donate_argnums=0).lower(pool, new, _sds(chip, (), jnp.int32), _sds(chip, (batch, mp), jnp.int32), _sds(chip, (batch,), jnp.int32)).compile().as_text()
   assert "tpu_custom_call" in text and "kv_token_write" in text
+  if paired:
+    moved = re.findall(r"[^\n]*= bf16\[[\d,]+\]\S* (?:pad|slice|copy)\([^\n]*", text)  # of K/V, the token's rows or a leaf
+    assert not moved, [line[:200] for line in moved[:3]]
 
 
 # The flash prefill kernel at every benchmark cell's head shapes (hq, hkv, hd, int8 codes, windows): one PREFILL_BUCKET of

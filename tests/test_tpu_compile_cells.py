@@ -15,6 +15,18 @@ import pytest
 from described_chip import PS, ROOT, _compile, _fp32_matmuls, _ling_at_the_cells_settings, _materialised, _mosaic_calls, _rows, _sds, _takers, chip  # noqa: F401 — the fixtures are taken in by name
 
 
+def _pool_sized(text: str, leaf) -> list[str]:
+  """The instructions of an optimised HLO text that PRODUCE a value with as many elements as a K/V pool leaf (or its
+  lane-padded double) by a ``pad``, ``slice``, ``copy`` or ``transpose``: what making the kernel's form of a pool of
+  64-channel heads once a dispatch cost until ISSUE 58 (a padded copy each of K and V, a relaid copy of V, two cuts back)."""
+  out = []
+  for line in text.splitlines():
+    m = re.match(r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* (pad|slice|copy|copy-start|transpose)\(", line)
+    if m and math.prod(int(d) for d in m.group(1).split(",")) in (leaf.size, 2 * leaf.size):
+      out.append(line.strip()[:160])
+  return out
+
+
 def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   """granite-4.0-h-micro whole, as ``granite-4.0-h-micro.decode-closed-64`` serves it (ISSUE 34): 64 slots, 1537
   pages, bf16, the kernel path. ``decode.paged_batch`` is accepted by XLA:TPU beside 6.4 GB of weights, 4.9 GB of
@@ -39,7 +51,7 @@ def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   on_chip = lambda tree: jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)  # noqa: E731
   params = on_chip(jax.eval_shape(lambda: full_model_params(jax.random.PRNGKey(0), cfg)[0]))
   pool = on_chip(jax.eval_shape(lambda: init_paged_pool(cfg, cfg.n_layers, n_pages, PS, n_slots=n_slots)))
-  assert pool["k"].shape == (4, n_pages, 8, PS, 64) and pool["ssm"].shape == (36, n_slots, 64, 64, 128) and pool["conv"].shape == (36, n_slots, 3, 4352)
+  assert pool["k"].shape == pool["v"].shape == (4, n_pages, 4, PS, 128) and pool["ssm"].shape == (36, n_slots, 64, 64, 128) and pool["conv"].shape == (36, n_slots, 3, 4352)
   rows = _rows(chip, n_slots)
   compiled, text = _compile(
     _fused_paged_batch_decode_impl, params, cfg, Shard("granite", 0, cfg.n_layers - 1, cfg.n_layers), _sds(chip, (n_slots, 1), jnp.int32), pool,
@@ -59,6 +71,9 @@ def test_hybrid_decode_step_at_the_cells_settings_fits_v5e(chip):
   assert not copied, copied
   relaid = [line.strip()[:160] for line in text.splitlines() if re.search(r"= bf16\[36,(2048|4096|8192),\d+\]\S* (copy|copy-start)\(", line) and "[36,2048,64]" not in line]
   assert not relaid, relaid  # (w_dt's 64 columns, 9 MB, are the one stack the TPU still relays)
+  # The pages' 8 KV heads of 64 are stored in pairs on the lanes (ops/paged.py, the module note, ISSUE 58): the stored
+  # pool is the kernel's form, and nothing pads, cuts, copies or relays a K/V leaf (until then 9 % of the cell's step).
+  assert not _pool_sized(text, pool["k"]), _pool_sized(text, pool["k"])
   mem = compiled.memory_analysis()
   print(f"decode.paged_batch granite B=64: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
@@ -509,9 +524,10 @@ def test_hybrid_ssm_moe_decode_step_and_longest_prefill_at_the_cells_settings_fi
 def test_hybrid_conv_moe_decode_step_and_a_prefill_group_at_the_cells_settings_fit_v5e(chip, monkeypatch):
   """LFM2-8B-A1B's first stage as ``lfm2-8b-a1b.decode-closed-128`` serves it (ISSUE 57): 128 slots — twice any other
   cell's —, 2049 pages of 4 attention layers (8 KV heads of 64, 4 queries a KV head: granite's geometry), bf16, all 32
-  SwiGLU experts of 14 expert layers held: 10.80 GB of weights, 1.07 GB of pages (3073, every row at its longest
-  context, is refused by 460 MiB: the kernel form of a pool of 64-channel heads is three more copies of it, two of them
-  lane-padded), and 12.6 MB of state — the pool has a
+  SwiGLU experts of 14 expert layers held: 10.80 GB of weights, 1.07 GB of pages — stored two KV heads a lane group,
+  [4, P, 4, 64, 128] (ops/paged.py, the module note, ISSUE 58), which IS the kernel's form: no instruction of the decode
+  program produces a value of a K/V leaf's size, and 3073 pages, every row at its longest context, fit beside the
+  weights (refused by 460 MiB while the form was made once a dispatch) — and 12.6 MB of state — the pool has a
   ``conv`` leaf [12, 128, 2, 2048] and NO ``ssm`` leaf, so the decode program has no state-step call at all. Its Mosaic
   calls are the gated experts' two (``moe_gate_up``, ``moe_down``: the shared walk, 17 rows an expert) in each run of
   expert layers and the attention layers' two. No ``copy(`` of a stacked expert leaf's shape, and no layer cut out of
@@ -526,7 +542,7 @@ def test_hybrid_conv_moe_decode_step_and_a_prefill_group_at_the_cells_settings_f
 
   _hf, cfg, params, pool = _ling_at_the_cells_settings(chip, monkeypatch, "lfm2-8b-a1b-d16")
   assert paged_kernel_supported(cfg, "tpu") and served_expert_form(params, cfg) == "grouped" and not cfg.state_matrix and state_step_form(pool.get("ssm"), True, cfg.recurrent_kind) == "no_state_matrix"
-  assert set(pool) == {"k", "v", "conv"} and pool["k"].shape == (4, 2049, 8, PS, 64) and pool["conv"].shape == (12, 128, 2, 2048) and pool["conv"].dtype == jnp.bfloat16
+  assert set(pool) == {"k", "v", "conv"} and pool["k"].shape == pool["v"].shape == (4, 2049, 4, PS, 128) and pool["conv"].shape == (12, 128, 2, 2048) and pool["conv"].dtype == jnp.bfloat16
   n_slots = pool["conv"].shape[1]
   assert params["ssm_moe_layers"]["w_experts_gate"].shape == (10, 32, 2048, 1792) and params["moe_layers"]["w_experts_down"].shape == (4, 32, 1792, 2048) and params["ssm_layers"]["w_up"].shape == (2, 2048, 7168) and "lm_head" not in params
   shard, rows = Shard("lfm2", 0, cfg.n_layers - 1, cfg.n_layers), _rows(chip, n_slots)
@@ -545,9 +561,17 @@ def test_hybrid_conv_moe_decode_step_and_a_prefill_group_at_the_cells_settings_f
   # each way for eight steps: XLA:TPU keeps the leaf in another layout inside the loops than the one it is handed in) and
   # by no layer and no step: PERF.md section 7, From PR 57.
   assert len(copied) == 2 and all(re.search(rf"= {tail}", line) for line in copied) and "copy(%pool__conv__" in copied[0], copied
+  assert not _pool_sized(text, pool["k"]), _pool_sized(text, pool["k"])
   mem = compiled.memory_analysis()
   print(f"decode.paged_batch lfm2 B=128: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes} alias={mem.alias_size_in_bytes}")
   assert mem.alias_size_in_bytes >= 12 * 128 * 2 * 2048 * 2 and mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+  roomy = {name: _sds(chip, (4, 3073, *leaf.shape[2:]), leaf.dtype) if name in "kv" else leaf for name, leaf in pool.items()}
+  mem = _compile(
+    _fused_paged_batch_decode_impl, params, cfg, shard, _sds(chip, (n_slots, 1), jnp.int32), roomy, window, rows(jnp.int32), rows(jnp.bool_), rows(jnp.float32), rows(jnp.int32), 8, 64, PS, True,
+    _sds(chip, (2,), jnp.uint32), None,
+  )[0].memory_analysis()  # fmt: skip
+  print(f"decode.paged_batch lfm2 B=128, 3073 pages: arguments={mem.argument_size_in_bytes} temp={mem.temp_size_in_bytes}")
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
   K, S = 8, 1024
   rows = _rows(chip, K)

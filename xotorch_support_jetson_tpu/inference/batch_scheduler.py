@@ -959,8 +959,11 @@ class BatchedServer:
       self.block_tables = np.zeros((self.n_slots, self.pages_per_row), dtype=np.int32)
       self.cache = self.ops.init_pool(n_pages, ps, **({"n_slots": self.n_slots} if recurrent else {}))
       metrics.set_gauge("page_pool_pages_total", n_pages - 1)  # page 0 = trash page
-      from ..ops.paged import paged_kernel_supported, state_leaves
+      from ..ops.paged import code_lanes_filled, paged_kernel_supported, state_leaves
       from ..ops.ssm import STATE_STEP_FORMS, state_step_form
+
+      for name in ("k", "v"):  # what the decode kernel's DMAs carry of each code leaf's rows: under 1, the rest is padding made once a dispatch
+        metrics.set_gauge("kv_page_lanes_filled", code_lanes_filled(self.cache[name]), labels={"leaf": name})
 
       state_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in state_leaves(self.cache).values())
       metrics.set_gauge("recurrent_state_bytes", state_bytes)

@@ -1867,7 +1867,7 @@ def prefill_into_pages_many(params, cfg: ModelConfig, shard: Shard, tokens, pool
 
   K, S = tokens.shape
   pages = page_leaves(pool)
-  temp = {key: gather_row_pages(val, bt_rows) for key, val in pages.items()}
+  temp = {key: gather_row_pages(val, bt_rows, cfg.cache_kv_heads) for key, val in pages.items()}
   positions = prefix_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
   if len(pages) < len(pool):
     h, carry = _hybrid_layers(
@@ -2173,7 +2173,8 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
 
   ``pool`` is the STACKED page dict: {"k", "v"} [L, P, Hkv, ps, hd]
   (+ "k_scale"/"v_scale" [L, P, Hkv, ps, 1] when quantized; in the kernel's
-  form on the kernel path — ops/paged.py ``kernel_pool_form``), ``layer``
+  form on the kernel path — ops/paged.py ``kernel_pool_form``; float heads of
+  64 stored in pairs, which only that module's accessors see: its note), ``layer``
   this layer's index into it; positions [B, 1]. ``kv_quant`` names the
   pool's mode where its shapes cannot (the kernel's form pads the code
   axis); None reads it off the stored shapes. Returns (h, pool, the experts
@@ -2209,7 +2210,7 @@ def _paged_layer_step(h, pool, p, layer, block_tables, positions, inv_freq, cfg:
       # 1 byte/element (0.5 for packed int4; the gather fallback below moves
       # the same quantized bytes but materializes the gathered window).
       window = _layer_window(p)
-      attn = paged_decode_attention(q[:, 0], pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, kv_quant=kv_quant, window=window, **scales)[:, None]
+      attn = paged_decode_attention(q[:, 0], pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, kv_quant=kv_quant, window=window, kv_heads=cfg.cache_kv_heads, **scales)[:, None]
     else:
       attn = paged_gqa_attention_ref(q, pool["k"], pool["v"], block_tables, lengths, page_size, layer=layer, **scales, **_attn_opts(cfg, p.get("is_sliding"), p.get("attn_kind")))
   h = _attn_out(h, x, attn, p, cfg)
@@ -2348,7 +2349,7 @@ def _fused_mixed_paged_batch_decode_impl(params, cfg: ModelConfig, shard: Shard,
   # one path component (benchmark/half_lib.py) while the component readers, which keep the ``xot.`` parts, read what they read.
   S = pf_tokens.shape[1]
   with jax.named_scope(MIXED_PREFILL_SCOPE):
-    temp_c = {k: gather_row_pages(v, pf_bt) for k, v in pool.items()}
+    temp_c = {k: gather_row_pages(v, pf_bt, cfg.cache_kv_heads) for k, v in pool.items()}
     ppos = pf_prefix[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     _, temp_c = shard_forward(params, cfg, shard, pf_tokens, ppos, temp_c, head_pos=pf_end - pf_prefix - 1, adapter_ids=pf_adapter)
     target = touched_page_targets(pf_bt, pf_prefix, pf_end, page_size)
@@ -2457,7 +2458,7 @@ def _paged_window_layer_step(h, pool, p, layer, block_tables, positions, inv_fre
     # by its own query's length. Token-exact against the gather route (A/B-pinned).
     attn = jnp.stack(
       [
-        paged_decode_attention(q[:, j], pool["k"], pool["v"], block_tables, positions[:, j] + 1, page_size, interpret=interpret, layer=layer, kv_quant=kv_quant, window=window, **scales)
+        paged_decode_attention(q[:, j], pool["k"], pool["v"], block_tables, positions[:, j] + 1, page_size, interpret=interpret, layer=layer, kv_quant=kv_quant, window=window, kv_heads=cfg.cache_kv_heads, **scales)
         for j in range(W)
       ],
       axis=1,
@@ -2741,28 +2742,15 @@ def prefill_into_pages(params, cfg: ModelConfig, shard: Shard, tokens, pool, bt_
   donated for the same reason as ``prefill_into_slot``: a failed prefill
   must leave the shared pool intact.
   """
+  from ..ops.paged import gather_row_pages, scatter_row_pages, touched_page_targets
+
   S = tokens.shape[1]
-  mp = bt_row.shape[0]
-
-  def row_gather(pool_part):  # [L, P, Hkv, ps, hd] → [L, 1, mp·ps, Hkv, hd]
-    g = jnp.take(pool_part, bt_row, axis=1)  # [L, mp, Hkv, ps, hd]
-    L, _, Hkv, ps, hd = g.shape
-    return jnp.swapaxes(g, 2, 3).reshape(L, 1, mp * ps, Hkv, hd)
-
-  temp = {key: row_gather(val) for key, val in pool.items()}
+  bt_rows = bt_row[None]
+  temp = {key: gather_row_pages(val, bt_rows, cfg.cache_kv_heads) for key, val in pool.items()}
   positions = (prefix_len + jnp.arange(S, dtype=jnp.int32))[None, :]
   logits, temp = shard_forward(params, cfg, shard, tokens, positions, temp)
-
-  page_ids = jnp.arange(mp, dtype=jnp.int32)
-  touched = (page_ids >= prefix_len // page_size) & (page_ids * page_size < prompt_len)
-  target = jnp.where(touched, bt_row, 0)  # trash page for the rest
-
-  def row_scatter(pool_part, t):  # write touched pages back
-    L, _, Stot, Hkv, hd = t.shape
-    pages = jnp.swapaxes(t.reshape(L, mp, page_size, Hkv, hd), 2, 3)  # [L, mp, Hkv, ps, hd]
-    return pool_part.at[:, target].set(pages.astype(pool_part.dtype))
-
-  pool = {key: row_scatter(pool[key], temp[key]) for key in pool}
+  target = touched_page_targets(bt_rows, jnp.reshape(prefix_len, (1,)), jnp.reshape(prompt_len, (1,)), page_size)  # trash page for the rest
+  pool = {key: scatter_row_pages(pool[key], temp[key], target) for key in pool}
   idx = (prompt_len - prefix_len - 1).reshape(1, 1, 1)
   last = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (1, 1, logits.shape[-1])), axis=1)[:, 0, :]
   return last, pool

@@ -31,6 +31,24 @@ is ``pool.at[layer, page]`` and the gather reference reads
 ``pool[layer, block_table]`` — no layer is ever sliced out of the pool or
 written back whole (PERF.md §6, PR 29).
 
+Paired heads (PR 58) — THE statement of the form; everything else points
+here. Mosaic slices a page out of an HBM operand only along whole lanes, so a
+head of 64 channels, stored alone, had to be padded to 128 in a copy of the
+whole pool once a dispatch and was then read at twice its bytes. An
+unquantised pool whose K and V heads are both 64 wide and even in number
+(``pairs_kv_heads``) is therefore STORED as ``[L, P, Hkv/2, ps, 128]``: KV
+head 2j in lanes 0-63 of leaf head j, head 2j+1 in lanes 64-127 — the same
+bytes in whole lanes, already the kernel's form. The rule is one of shapes,
+as packed int4's is: a leaf is paired iff ``leaf.shape[2] * 2`` is the
+model's ``cache_kv_heads``. Position-major, ``[…, slots, Hkv/2, 128]`` is
+``[…, slots, Hkv, 64]`` by a reshape (``heads_as``), and that is all the
+accessors between pages and tokens do about it (``write_token_kv``,
+``gather_pages``, ``gather_row_pages``, ``scatter_row_pages``); to the decode
+kernel such a pool is a GQA pool of Hkv/2 heads of 128 whose groups hold both
+heads' queries, zero-extended to their head's half (``_pair_queries``).
+Quantised pools (a scale is per token and head), MLA's leaves (one head), an
+odd head count and every other width keep ``[L, P, Hkv, ps, hd]``.
+
 Page 0 is reserved as a trash page: gathers of unallocated block-table entries
 read it (positionally masked anyway) and masked scatters dump there, which
 keeps every shape static without conditional writes.
@@ -78,6 +96,9 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
   scales, halving page bytes AGAIN vs int8 (~2x pages, ~2x effective pool
   read bandwidth, half the host-tier and wire bytes per page).
 
+  An unquantised pool of 64-channel heads, even in number, pairs them on
+  the lanes: [L, P, Hkv/2, ps, 128] (``pairs_kv_heads``; the module note).
+
   A configuration with recurrent layers has pages for its attention layers
   only (the layer axis is ``cfg.n_attn_layers``) and, beside them, the state
   of its state-space layers for each of ``n_slots`` rows: ``ssm``
@@ -107,8 +128,11 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
     if kd % 2 or vd % 2:
       raise ValueError(f"int4 KV pages need even cache dims; got k={kd} v={vd}")
     kd, vd = kd // 2, vd // 2
-  k_shape = (n_shard_layers, n_pages, cfg.cache_kv_heads, page_size, kd)
-  v_shape = (n_shard_layers, n_pages, cfg.cache_kv_heads, page_size, vd)
+  heads = cfg.cache_kv_heads
+  if pairs_kv_heads(cfg, mode):
+    heads, kd, vd = heads // 2, 2 * kd, 2 * vd
+  k_shape = (n_shard_layers, n_pages, heads, page_size, kd)
+  v_shape = (n_shard_layers, n_pages, heads, page_size, vd)
   if mode:
     scale_shape = k_shape[:-1] + (1,)
     return {
@@ -119,6 +143,25 @@ def init_paged_pool(cfg, n_shard_layers: int, n_pages: int, page_size: int, dtyp
       **state,
     }
   return {"k": jnp.zeros(k_shape, dtype=dtype), "v": jnp.zeros(v_shape, dtype=dtype), **state}
+
+
+def pairs_kv_heads(cfg, quant: str | None = "") -> bool:
+  """Whether a pool of ``cfg`` in KV mode ``quant`` stores two KV heads a lane group (the module note): float pages,
+  K and V heads both half a lane group wide, an even number of them."""
+  return not quant and cfg.cache_k_dim == cfg.cache_v_dim == 64 and cfg.cache_kv_heads % 2 == 0
+
+
+def heads_as(x: jnp.ndarray, heads: int | None) -> jnp.ndarray:
+  """Position-major K/V ``[…, H, w]`` with its head axis regrouped to ``heads`` (None: as it is): paired leaf heads
+  [Hkv/2, 128] ↔ the model's [Hkv, 64], either way a reshape of adjacent heads (the module note)."""
+  return x if heads in (None, x.shape[-2]) else x.reshape(*x.shape[:-2], heads, x.shape[-2] * x.shape[-1] // heads)
+
+
+def code_lanes_filled(leaf) -> float:
+  """The share of a code leaf's row, as the kernel's DMA takes it (``_kernel_leaf``), that holds codes: 1.0 for
+  whole lane groups (hd 128 / 256, paired heads of 64), 0.5 for a 64-wide row padded to 128 — the gauge
+  ``kv_page_lanes_filled``."""
+  return leaf.shape[-1] / (leaf.shape[-1] + -leaf.shape[-1] % 128)
 
 
 def _stacked(leaf, layer):
@@ -133,7 +176,8 @@ def write_token_kv(pool: dict, new: dict, layer, block_tables: jnp.ndarray, pos:
 
   pool: the stacked leaves {"k", "v"} [L, P, Hkv, ps, hd] (+ "k_scale" /
   "v_scale" [L, P, Hkv, ps, 1]); new: the same keys, [B, Hkv, hd]
-  ([B, Hkv, 1] for a scale); layer a traced scalar; block_tables [B, mp]
+  ([B, Hkv, 1] for a scale; a paired leaf takes its token as [B, Hkv/2, 128],
+  the module note); layer a traced scalar; block_tables [B, mp]
   int32; pos [B] int32 (the logical position being written). Only the
   ``B × Hkv`` token rows at ``(layer, page, :, slot)`` are touched. Rows own
   disjoint pages, so the writes never collide (inactive rows all land in the
@@ -147,6 +191,7 @@ def write_token_kv(pool: dict, new: dict, layer, block_tables: jnp.ndarray, pos:
   """
   page = jnp.take_along_axis(block_tables, (pos // page_size)[:, None], axis=1)[:, 0]  # [B]
   off = pos % page_size
+  new = {name: heads_as(new[name], leaf.shape[2]) for name, leaf in pool.items()}
   if kernel:
     return _write_token_kv_kernel(pool, new, layer, page, off, interpret)
   return {name: leaf.at[layer, page, :, off].set(new[name].astype(leaf.dtype)) for name, leaf in pool.items()}
@@ -242,7 +287,9 @@ def _write_token_kv_kernel(pool: dict, new: dict, layer, page, off, interpret: b
       groups.append(None)
       scratch.append(pltpu.VMEM((rows, *leaf.shape[2:]), leaf.dtype))
     else:
-      x = jnp.pad(x, [(0, 0), (0, 0), (0, leaf.shape[-1] - x.shape[-1])])[:, :, None, :]  # the leaf's lanes; a row of a group
+      if x.shape[-1] < leaf.shape[-1]:  # a leaf padded to whole lanes: the token's row too
+        x = jnp.pad(x, [(0, 0), (0, 0), (0, leaf.shape[-1] - x.shape[-1])])
+      x = x[:, :, None, :]  # a row of a group
       ps, tile = leaf.shape[3], 8 * (4 // leaf.dtype.itemsize)
       groups.append(tile if ps % tile == 0 else ps)
       scratch.append(pltpu.VMEM((rows, leaf.shape[2], groups[-1], leaf.shape[4]), leaf.dtype))
@@ -268,9 +315,10 @@ def _write_token_kv_kernel(pool: dict, new: dict, layer, page, off, interpret: b
   return stored_pool_form(dict(zip(names, out)), stored)
 
 
-def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray, layer=None) -> jnp.ndarray:
+def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray, layer=None, kv_heads: int | None = None) -> jnp.ndarray:
   """[L, P, Hkv, ps, hd] at ``layer`` × [B, mp] → position-ordered KV
-  [B, mp·ps, Hkv, hd].
+  [B, mp·ps, Hkv, hd]; ``kv_heads``: the model's, which unpairs a paired
+  leaf's heads (the module note; None: the leaf's own).
 
   The XLA fallback path (every backend off the TPU, ``use_kernel=False``
   callers): one gather by ``(layer, page)`` reads every page the TABLE names —
@@ -280,13 +328,14 @@ def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray, layer=None) -> jn
   pool, layer = _stacked(pool, layer)
   g = pool[layer, block_tables]  # [B, mp, Hkv, ps, hd]
   B, mp, Hkv, ps, hd = g.shape
-  return jnp.swapaxes(g, 2, 3).reshape(B, mp * ps, Hkv, hd)
+  return heads_as(jnp.swapaxes(g, 2, 3).reshape(B, mp * ps, Hkv, hd), kv_heads)
 
 
 @component_scope("xot.kv_write")
-def gather_row_pages(pool_part: jnp.ndarray, bt_rows: jnp.ndarray) -> jnp.ndarray:
+def gather_row_pages(pool_part: jnp.ndarray, bt_rows: jnp.ndarray, kv_heads: int | None = None) -> jnp.ndarray:
   """All-layer per-row page gather: [L, P, H, slots, hd] × [K, mp] →
-  position-ordered [L, K, mp·slots, H, hd].
+  position-ordered [L, K, mp·slots, H, hd]; ``kv_heads`` as in
+  ``gather_pages`` (a scale leaf's and a latent's heads are the model's: untouched).
 
   ``slots`` is the per-device page width: the full page_size on a single
   device, or ps/sp when the pool's page-slot axis is striped over sp
@@ -294,7 +343,7 @@ def gather_row_pages(pool_part: jnp.ndarray, bt_rows: jnp.ndarray) -> jnp.ndarra
   """
   g = jnp.take(pool_part, bt_rows, axis=1)  # [L, K, mp, H, slots, hd]
   L, K, mp, H, st, hd = g.shape
-  return jnp.swapaxes(g, 3, 4).reshape(L, K, mp * st, H, hd)
+  return heads_as(jnp.swapaxes(g, 3, 4).reshape(L, K, mp * st, H, hd), kv_heads)
 
 
 def touched_page_targets(bt_rows: jnp.ndarray, prefix_lens: jnp.ndarray, prompt_lens: jnp.ndarray, page_size: int) -> jnp.ndarray:
@@ -311,7 +360,9 @@ def touched_page_targets(bt_rows: jnp.ndarray, prefix_lens: jnp.ndarray, prompt_
 @component_scope("xot.kv_write")
 def scatter_row_pages(pool_part: jnp.ndarray, t: jnp.ndarray, target: jnp.ndarray) -> jnp.ndarray:
   """Inverse of ``gather_row_pages`` restricted to ``target`` pages:
-  t [L, K, mp·slots, H, hd] scatters back into [L, P, H, slots, hd]."""
+  t [L, K, mp·slots, H, hd] scatters back into [L, P, H, slots, hd] (a
+  paired leaf's heads read off the leaf: the module note)."""
+  t = heads_as(t, pool_part.shape[2])
   L, K, N, H, hd = t.shape
   mp = target.shape[1]
   st = pool_part.shape[3]
@@ -324,7 +375,8 @@ def paged_gqa_attention_ref(q, k_pool, v_pool, block_tables, lengths, page_size:
   """Reference paged decode attention via gather (q [B, Sq, Hq, hd]; Sq is 1
   on the decode path). The pools are stacked leaves [L, P, Hkv, ps, hd] read
   at ``layer`` (``layer`` None: one layer's [P, Hkv, ps, hd]); float pools
-  are cast to q's dtype after the gather. ``attn_opts`` forward gemma2's
+  are cast to q's dtype after the gather, and unpaired by it where their rows
+  are two of q's heads wide (stored leaves say so themselves: the module note). ``attn_opts`` forward gemma2's
   scale/softcap/sliding-window (models/decoder.py _attn_opts). With scale
   pools (int8/int4 KV), the gathered codes stay the einsum operand and the
   scales gather alongside — the page gather itself moves the quantized
@@ -334,8 +386,9 @@ def paged_gqa_attention_ref(q, k_pool, v_pool, block_tables, lengths, page_size:
   ``q_positions`` [B, Sq] overrides the single-query default — the batched
   speculative VERIFY window (models/decoder.py paged_window_forward) passes
   each row's own window positions."""
-  k = gather_pages(k_pool, block_tables, layer)
-  v = gather_pages(v_pool, block_tables, layer)
+  kv_heads = _stored_kv_heads(k_pool, q.shape[-1], k_scale_pool is not None)
+  k = gather_pages(k_pool, block_tables, layer, kv_heads)
+  v = gather_pages(v_pool, block_tables, layer, kv_heads)
   kv_positions = jnp.arange(k.shape[1], dtype=jnp.int32)
   if q_positions is None:
     q_positions = (lengths - 1)[:, None]  # current token's position
@@ -662,16 +715,48 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs, page_size: in
   o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+def _stored_kv_heads(k_pool, hd: int, quantized: bool) -> int:
+  """The model's KV heads, read off a STORED code leaf and q's head width: a float row twice that wide is a pair of
+  heads (the module note). A leaf in the kernel's form cannot say — its caller names the heads."""
+  heads, lanes = jnp.shape(k_pool)[-3], jnp.shape(k_pool)[-1]
+  return heads if quantized or lanes != 2 * hd else 2 * heads
+
+
+def _under_odd_head(Hq: int, pairs: int) -> jnp.ndarray:
+  """[Hq, 1] bool: the query heads whose KV head is the SECOND of its pair (the pair's query heads are contiguous, the
+  even head's first)."""
+  return (jnp.arange(Hq) // (Hq // (2 * pairs)) % 2 == 1)[:, None]
+
+
+def _pair_queries(q: jnp.ndarray, pairs: int) -> jnp.ndarray:
+  """q [B, Hq, hd] for a paired pool of ``pairs`` leaf heads (the module note): each query head zero-extended to the
+  pair's lanes, [q, 0] under an even KV head and [0, q] under an odd one — [B, Hq, 2·hd]. The kernel's group of leaf
+  head j is then the 2·Hq/Hkv query heads of both; a zero meets the other head's key of the same token, which is finite."""
+  odd, zero = _under_odd_head(q.shape[1], pairs), jnp.zeros_like(q)
+  return jnp.concatenate([jnp.where(odd, zero, q), jnp.where(odd, q, zero)], axis=-1)
+
+
+def _own_halves(out: jnp.ndarray, pairs: int) -> jnp.ndarray:
+  """Inverse of ``_pair_queries`` on the kernel's result [B, Hq, 2·hd]: each query head's own KV head's half of the
+  value product (the other half is the pair's other head's values under this head's probabilities: dropped). Two
+  slices and a select: picked head by head out of a reshape to [B, pairs, 2, group, 2·hd], XLA:TPU dropped the odd
+  heads' lane offset and handed them the even half (my chip runs, PR 58; the CPU compiled it right)."""
+  half = out.shape[-1] // 2
+  return jnp.where(_under_odd_head(out.shape[1], pairs), out[..., half:], out[..., :half])
+
+
 def _kernel_leaf(x: jnp.ndarray) -> jnp.ndarray:
   """A stacked pool leaf as the kernel's DMA takes it: Mosaic slices a page
   out of an HBM operand only along whole lanes, so the minor axis is padded
-  to a multiple of 128 (a no-op for hd 128/256 codes; sub-128 code axes —
-  hd 64, packed int4 — pay a copy of the leaf), and a scale leaf
+  to a multiple of 128 (a no-op for hd 128/256 codes and for paired heads of
+  64, the module note; the sub-128 code axes that are left — int8 at hd 64,
+  packed int4, MLA's rope leaf — pay a copy of the leaf), and a scale leaf
   [L, P, Hkv, ps, 1] puts its tokens on lanes first: [L, P, Hkv, ps → lanes].
   A leaf already in that form passes through untouched."""
   if x.ndim == 5 and x.shape[-1] == 1:
     x = x.reshape(x.shape[:-1])
-  return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -x.shape[-1] % 128)])
+  short = -x.shape[-1] % 128
+  return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)]) if short else x
 
 
 @component_scope("xot.kv_write")
@@ -679,9 +764,10 @@ def kernel_pool_form(pool: dict) -> dict:
   """The stacked pool in the kernel's form (``_kernel_leaf``), made ONCE a
   dispatch outside the step loop by the programs whose attention is the
   Pallas kernel: the token writes and the kernel's reads then share one
-  buffer per leaf from the first step to the last. Costs nothing for code
-  leaves of whole lanes (int8/bf16 at hd 128/256); the scale leaves (3 % of
-  an int8 pool) are relaid, and code leaves under 128 lanes copied, once."""
+  buffer per leaf from the first step to the last. The identity for code
+  leaves of whole lanes (int8/bf16 at hd 128/256, paired heads of 64: the
+  module note); the scale leaves (3 % of an int8 pool) are relaid, and code
+  leaves under 128 lanes copied, once."""
   return {name: leaf if name in STATE_LEAVES else _kernel_leaf(leaf) for name, leaf in pool.items()}
 
 
@@ -702,6 +788,7 @@ def stored_pool_form(pool: dict, like: dict) -> dict:
 def paged_decode_attention(
   q, k_pool, v_pool, block_tables, lengths, page_size: int,
   k_scale_pool=None, v_scale_pool=None, pages_per_step: int | None = None, interpret: bool = False, layer=None, kv_quant: str | None = None, window: int = 0,
+  kv_heads: int | None = None,
 ):
   """Decode attention off the page pool (dense GQA models).
 
@@ -718,8 +805,10 @@ def paged_decode_attention(
   the kernel's form (``kernel_pool_form``) are taken as they are — their
   padded code axis no longer tells int8 from packed int4, so their caller
   names the mode (``kv_quant``: "", "int8", "int4"; None reads it off stored
-  shapes); stored leaves that need it are converted per call — a copy of the
-  leaf, which a program with a layer loop must make outside it.
+  shapes) and, for a float pool, the model's KV heads (``kv_heads``: leaves of
+  half as many hold them paired, the module note; None reads that off stored
+  shapes too); stored leaves that need it are converted per call — a copy of
+  the leaf, which a program with a layer loop must make outside it.
   ``pages_per_step`` (static) overrides the tile (``PAGE_TILE`` clamped to
   the table's width: the same rule for a layer with a window and one
   without). ``window`` (static; 0: none): the layer's window — the row's
@@ -733,10 +822,12 @@ def paged_decode_attention(
   layer = jnp.asarray(pools[0][1], jnp.int32).reshape(1)
   if kv_quant is None:
     kv_quant = "" if k_scale_pool is None else "int4" if jnp.shape(k_pool)[-1] * 2 == jnp.shape(q)[-1] else "int8"
+  if kv_heads is None:
+    kv_heads = _stored_kv_heads(k_pool, jnp.shape(q)[-1], bool(kv_quant))
   G = pages_per_step or _page_tile(jnp.shape(block_tables)[1])
   return _paged_decode_attention_impl(
     q, block_tables, lengths, layer, *(x for x, _ in pools),
-    page_size=page_size, pages_per_step=G, kv_quant=kv_quant, interpret=interpret, window=int(window),
+    page_size=page_size, pages_per_step=G, kv_quant=kv_quant, interpret=interpret, window=int(window), paired=jnp.shape(k_pool)[-3] * 2 == kv_heads,
   )
 
 
@@ -761,11 +852,12 @@ def paged_latent_decode_attention(q_nope, q_pe, k_pool, v_pool, block_tables, le
   return jnp.einsum("bhr,rhv->bhv", ctx, w_v)[:, None].astype(q_nope.dtype)
 
 
-@functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret", "window", "latent_scale"))
-def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool, window: int = 0, latent_scale: float = 0.0):
+@functools.partial(tracked_jit, "ops.paged_attention", static_argnames=("page_size", "pages_per_step", "kv_quant", "interpret", "window", "latent_scale", "paired"))
+def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_size: int, pages_per_step: int, kv_quant: str, interpret: bool, window: int = 0, latent_scale: float = 0.0, paired: bool = False):
   """``latent_scale`` > 0 (static): the latent body — q is q_abs ‖ q_pe [B, H, rank + lanes], the pools the latent and
   the rope leaf, the result Σ p·latent [B, H, rank], and the scores' scale this, the model's (nope + rope)^-1/2, which
-  the operand's width says nothing of."""
+  the operand's width says nothing of. ``paired`` (static): the pools hold two KV heads a leaf head (the module note) —
+  the kernel is handed the pairs as heads of twice the width, q extended to them, under the scale of q's own width."""
   import jax.experimental.pallas as pl
   from jax.experimental.pallas import tpu as pltpu
 
@@ -778,6 +870,8 @@ def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_s
     # second) so the in-kernel two-dot uses contiguous halves; the output
     # comes back in the same layout and is re-interleaved below.
     q = jnp.concatenate([q[..., 0::2], q[..., 1::2]], axis=-1)
+  if paired:
+    q, hd = _pair_queries(q, pools[0].shape[2]), 2 * hd
 
   in_hbm = pl.BlockSpec(memory_space=pl.ANY)
   pools = [_kernel_leaf(x) for x in pools]  # k, v (+ their scales), stacked: [L, P, Hkv, ps, lanes] / [L, P, Hkv, lanes]
@@ -814,7 +908,7 @@ def _paged_decode_attention_impl(q, block_tables, lengths, layer, *pools, page_s
   if packed:
     # Undo the deinterleave: channel 2i from the even half, 2i+1 from the odd half.
     out = jnp.stack([out[..., : hd // 2], out[..., hd // 2 :]], axis=-1).reshape(B, Hq, hd)
-  return out
+  return _own_halves(out, pools[0].shape[2]) if paired else out
 
 
 def paged_kernel_supported(cfg, platform: str | None = None) -> bool:

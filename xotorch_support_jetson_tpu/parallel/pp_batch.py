@@ -197,7 +197,7 @@ class PPBatchedServing:
 
       stage_layers = {k: v[0] for k, v in stage_params.items()}
       target = touched_page_targets(bt_rows, prefix_lens, prompt_lens, page_size)
-      row_gather = lambda pool_part: gather_row_pages(pool_part, bt_rows)  # noqa: E731
+      row_gather = lambda pool_part: gather_row_pages(pool_part, bt_rows, cfg.cache_kv_heads)  # noqa: E731
       row_scatter = lambda pool_part, t: scatter_row_pages(pool_part, t, target)  # noqa: E731
 
       h0 = embed_tokens(head, cfg, tokens)

@@ -50,25 +50,30 @@ def _stripe_positions(mp: int, stripe: int, page_size: int, rank) -> jnp.ndarray
   return (j // stripe) * page_size + rank * stripe + (j % stripe)
 
 
-def _gather_local(pool_part: jnp.ndarray, bt: jnp.ndarray) -> jnp.ndarray:
+def _gather_local(pool_part: jnp.ndarray, bt: jnp.ndarray, kv_heads: int) -> jnp.ndarray:
   """[P, Hkv, stripe, hd] × [B, mp] → this rank's position-ordered slots
-  [B, mp·stripe, Hkv, hd] (cf. ops/paged.py gather_pages)."""
-  g = jnp.take(pool_part, bt, axis=0)  # [B, mp, Hkv, stripe, hd]
-  B, mp, Hkv, st, hd = g.shape
-  return jnp.swapaxes(g, 2, 3).reshape(B, mp * st, Hkv, hd)
+  [B, mp·stripe, Hkv, hd]: ops/paged.py ``gather_pages`` of one layer's
+  stripe (``kv_heads``, the model's, unpairs a leaf of paired heads: that
+  module's note)."""
+  from ..ops.paged import gather_pages
+
+  return gather_pages(pool_part, bt, kv_heads=kv_heads)
 
 
 def _write_token_local(pool_l: jnp.ndarray, new: jnp.ndarray, bt: jnp.ndarray, pos: jnp.ndarray, page_size: int, stripe: int, rank) -> jnp.ndarray:
   """One decode step's KV into this rank's stripe of the pool (one layer).
 
-  pool_l [P, Hkv, stripe, hd]; new [B, Hkv, hd]; pos [B]. The rank owning
+  pool_l [P, Hkv, stripe, hd]; new [B, Hkv, hd] (regrouped to the leaf's heads
+  where those are pairs); pos [B]. The rank owning
   ``pos % ps`` writes its page; every other rank writes its stripe of the
   trash page 0 (rows own disjoint pages, so real writes never collide)."""
+  from ..ops.paged import heads_as
+
   page = jnp.take_along_axis(bt, (pos // page_size)[:, None], axis=1)[:, 0]
   off = pos % page_size
   mine = (off // stripe) == rank
   page_eff = jnp.where(mine, page, 0)
-  return pool_l.at[page_eff, :, off % stripe].set(new.astype(pool_l.dtype))
+  return pool_l.at[page_eff, :, off % stripe].set(heads_as(new, pool_l.shape[1]).astype(pool_l.dtype))
 
 
 def _write_span_local(gathered: jnp.ndarray, new: jnp.ndarray, start: jnp.ndarray, kv_pos_local: jnp.ndarray) -> jnp.ndarray:
@@ -108,7 +113,7 @@ def _sp_paged_layer_decode(h, p, pool_l, bt, positions, kv_pos_local, inv_freq, 
     h, p, pool_l, positions, 0, inv_freq, cfg,
     kv_positions_local=kv_pos_local,
     write_one=lambda leaf, new, start: _write_token_local(leaf, new[:, 0], bt, start, page_size, stripe, rank),
-    read_one=lambda leaf: _gather_local(leaf, bt),
+    read_one=lambda leaf: _gather_local(leaf, bt, cfg.cache_kv_heads),
   )
 
 
@@ -220,7 +225,7 @@ class SPBatchedServing:
         scatter_l = lambda pool_part, t: scatter_row_pages(pool_part, t, target)  # noqa: E731
 
         h = embed_tokens(params, cfg, tokens)
-        temp = {key: gather_row_pages(val, bt_rows) for key, val in pool.items()}
+        temp = {key: gather_row_pages(val, bt_rows, cfg.cache_kv_heads) for key, val in pool.items()}
         off = 0
         parts = []
         for stack in stacks_of(params):
